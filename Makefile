@@ -4,7 +4,7 @@ PYTHON ?= python
 IMAGE_REGISTRY ?= ghcr.io/example
 IMAGE_TAG ?= latest
 
-.PHONY: test test-fast native bench lint images dryrun clean
+.PHONY: test test-fast native bench lint images dryrun chip-smoke clean
 
 # --durations mirrors the CI sweep: the tier-1 run is timeout-bound in
 # some containers (ROADMAP), so the slowest tests must be visible
@@ -18,14 +18,20 @@ native:
 	$(MAKE) -C native
 
 bench:
-	timeout 590 $(PYTHON) bench.py
+	$(PYTHON) bench.py
 
 # simulated actuation benchmark (no cluster, no TPU)
 bench-actuation:
 	$(PYTHON) -m llm_d_fast_model_actuation_tpu.benchmark --scenario all
 
+# eight virtual CPU devices: asked for, never fallen back to
 dryrun:
-	$(PYTHON) -c "import __graft_entry__ as g; g.dryrun_multichip(8)"
+	JAX_PLATFORMS=cpu $(PYTHON) -c "import __graft_entry__ as g; g.dryrun_multichip(8)"
+
+# the quickest proof the system still starts on the chip (run through the
+# chip tool; fails without a TPU)
+chip-smoke:
+	$(PYTHON) chip_smoke.py
 
 images:
 	docker build -f deploy/dockerfiles/Dockerfile.launcher -t $(IMAGE_REGISTRY)/fma-tpu-launcher:$(IMAGE_TAG) .

@@ -11,9 +11,12 @@ This module tears the PJRT client down *in process* and re-creates it later:
 
   release_devices()   — drop all compiled-executable caches, then destroy
                         every live backend client. Caller must have deleted /
-                        numpy-snapshotted every device array first: after
-                        this call any surviving jax.Array is a dangling
-                        reference to a dead client.
+                        numpy-snapshotted every device array AND dropped
+                        every reference to one first: a jax.Array, even a
+                        deleted one, keeps its sharding, the sharding its
+                        Device, the Device its client — and libtpu keeps the
+                        chip's vfio group open for as long as the client
+                        object lives. Returns whether the client really died.
   reacquire_devices() — re-initialize the backend (jax re-creates the PJRT
                         client on first use) and return the new devices. If
                         another process holds the chip this blocks/retries
@@ -37,6 +40,7 @@ from __future__ import annotations
 import gc
 import logging
 import time
+import weakref
 from typing import Any, Optional, Sequence, Tuple
 
 import jax
@@ -47,16 +51,30 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceShardin
 logger = logging.getLogger(__name__)
 
 
-def release_devices() -> None:
-    """Destroy this process's backend clients (all platforms)."""
+def release_devices() -> bool:
+    """Destroy this process's backend clients (all platforms). Returns True
+    when the default backend's client was really destroyed — only then has
+    libtpu closed the chip and can another process open it. False (logged
+    as an error) means some object still references the client: the chip is
+    NOT free, though this process must still reacquire before using it."""
+    client = weakref.ref(jax.extend.backend.get_backend())
     # Drop every cached executable first: live LoadedExecutables keep client
     # references, and tracing caches would hand back programs bound to the
     # dead client after re-init.
     jax.clear_caches()
     gc.collect()
     jax.extend.backend.clear_backends()
+    # client <-> device objects form a cycle: only a collection frees them
     gc.collect()
+    if client() is not None:
+        logger.error(
+            "backend client survived release (still referenced by: %s); "
+            "the chip is NOT free for another process",
+            sorted({type(r).__name__ for r in gc.get_referrers(client())}),
+        )
+        return False
     logger.info("released backend clients (TPU chip is now free)")
+    return True
 
 
 def reacquire_devices(
@@ -107,14 +125,11 @@ def _device_array(mesh_shape: Tuple[int, ...]) -> np.ndarray:
     n = int(np.prod(mesh_shape))
     devices = jax.devices()[:n]
     if devices[0].platform == "tpu":
-        try:
-            from jax.experimental import mesh_utils
+        from jax.experimental import mesh_utils
 
-            return mesh_utils.create_device_mesh(
-                tuple(mesh_shape), devices=list(devices)
-            )
-        except Exception:
-            pass  # odd topologies: flat ordering, same as make_mesh fallback
+        return mesh_utils.create_device_mesh(
+            tuple(mesh_shape), devices=list(devices)
+        )
     return np.asarray(devices).reshape(mesh_shape)
 
 

@@ -59,11 +59,10 @@ WARM_PROGRAMS = ("prefill", "suffix", "chunk", "mixed")
 
 
 def default_spill_dir() -> str:
-    """Where spilled executables live: the launcher exports
-    ``FMA_EXEC_SPILL_DIR`` next to its persistent XLA compile cache
-    (launcher/main.py preload), so children of one launcher share spilled
-    entries across restarts; standalone engines derive the same location
-    from ``JAX_COMPILATION_CACHE_DIR``."""
+    """Where spilled executables live: ``FMA_EXEC_SPILL_DIR``, which
+    follows the persistent XLA compile cache unless set
+    (utils/compile_cache.py), so children of one launcher share spilled
+    entries across restarts."""
     explicit = os.environ.get("FMA_EXEC_SPILL_DIR", "")
     if explicit:
         return explicit
@@ -114,10 +113,11 @@ def _normalize_cfg(cfg):
     """Thread the resolved attention impl into the model config exactly
     like InferenceEngine.__init__ does, so a signature computed from the
     service's pre-build config equals one computed from the live
-    engine.cfg."""
+    engine.cfg. (The service resolves ``auto`` against its tp degree
+    before any config reaches here — server.py:_engine_cfg_for.)"""
     from .engine import resolve_attention_impl
 
-    impl = resolve_attention_impl(cfg.attention_impl)
+    impl = resolve_attention_impl(cfg.attention_impl, cfg.model)
     m = cfg.model
     if m.attention_impl != impl:
         m = dataclasses.replace(m, attention_impl=impl)
@@ -252,18 +252,11 @@ def _abstract_state(cfg, mesh=None):
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from ..models.registry import logical_axes_for
-        from ..parallel.mesh import named_sharding
-
-        def put(s, axes):
-            sh = (
-                NamedSharding(mesh, P()) if axes is None
-                else named_sharding(mesh, axes)
-            )
-            return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh)
+        from ..parallel.mesh import logical_shardings
 
         params = jax.tree.map(
-            put, params, logical_axes_for(m),
-            is_leaf=lambda x: x is None,
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+            params, logical_shardings(mesh, logical_axes_for(m)),
         )
         kv_sharding = NamedSharding(mesh, P(None, None, None, "tp", None))
     kv = jax.ShapeDtypeStruct(
@@ -366,21 +359,14 @@ def compile_program(cfg, program: str, bucket: int, programs=None, mesh=None):
 
 
 def _program_set(cfg, mesh=None):
-    """A ProgramSet matching the live engine's for (cfg, mesh): the
-    mixed program's attention impl follows the device-kind x mesh x
-    impl-flag routing matrix (ops/attention.py:resolve_ragged_impl —
-    pallas stays pallas on meshes via the kernel's shard_map port,
-    interpret-incapable CPU builds fall back to the XLA twin), exactly
-    like InferenceEngine.__init__ — a warmup-compiled executable must
-    trace the identical program."""
-    from ..ops.attention import resolve_ragged_impl
+    """A ProgramSet matching the live engine's for (cfg, mesh), built
+    exactly like InferenceEngine.__init__ — a warmup-compiled executable
+    must trace the identical program."""
     from .engine import ProgramSet
 
     cfg = _normalize_cfg(cfg)
     return ProgramSet(
-        cfg.model, cfg.logprobs_topk, cfg.eos_token_id,
-        mixed_impl=resolve_ragged_impl(cfg.model.attention_impl, mesh),
-        mesh=mesh,
+        cfg.model, cfg.logprobs_topk, cfg.eos_token_id, mesh=mesh
     )
 
 

@@ -47,7 +47,7 @@ from prometheus_client import Counter, Gauge, Histogram
 from ..models import llama
 from ..models.moe import MoeConfig
 from ..utils import faults, tracing
-from .engine import EngineConfig, InferenceEngine
+from .engine import EngineConfig, InferenceEngine, resolve_attention_impl
 from .model_pool import HostModelPool
 from .sleep import (
     SwapRolledBack,
@@ -1453,6 +1453,19 @@ class EngineService:
             actual_bytes=self._last_build_stats.get("bytes_in", 0),
             actual_s=self._last_build_stats.get("h2d_s", 0.0),
         )
+        # what /v1/stats reports as the device: read once, while the client
+        # is certainly up (stats must answer while the devices are released)
+        dev = jax.devices()[0]
+        self._device_info = {
+            "platform": dev.platform,
+            "kind": dev.device_kind,
+            "count": jax.device_count(),
+        }
+        logger.info(
+            "serving %s on %s; attention impl %s",
+            args.model, self._device_info,
+            self.engine.attention_impl,
+        )
         if dist is not None and not self.is_follower:
             from .multihost import LockstepLeader
 
@@ -1804,7 +1817,9 @@ class EngineService:
             max_seq_len=args.max_model_len or 0,
             eos_token_id=eos_token_id,
             extra_eos_ids=extra_eos,
-            attention_impl=args.attention_impl,
+            attention_impl=resolve_attention_impl(
+                args.attention_impl, model_cfg, args.tensor_parallel_size
+            ),
             decode_chunk=args.decode_chunk
             or (32 if jax.default_backend() == "tpu" else 8),
             pipeline_decode=(
@@ -5953,6 +5968,10 @@ class EngineService:
             judged = met + violated
             out = {
                 "model": self.args.model,
+                "device": dict(self._device_info),
+                # the resolved implementation (engine.py:
+                # resolve_attention_impl), never "auto"
+                "attention_impl": self.engine.attention_impl,
                 "queue_depth": self.queue_depth(),
                 "arrival_rate_rps": round(self._arrival.rate(now), 6),
                 "slo": {
@@ -6004,6 +6023,10 @@ class EngineService:
         # accuracy from this row without a second endpoint, and the
         # launcher's fleet rollup carries it into ledger.costs
         out["costs"] = self.costs.summary()
+        from ..utils import compile_cache
+
+        out["compile_cache"] = compile_cache.stats()
+        out["hbm"] = self._hbm_rows()
         # co-resident set (docs/perf.md "Co-resident sibling variants"):
         # who is routable on this engine without an actuation, and what
         # the shared base is saving — the launcher ledger's resident row
@@ -6016,6 +6039,35 @@ class EngineService:
                 "saved_bytes": self.resident_ledger.bytes_saved(),
             }
         return out
+
+    def _hbm_rows(self) -> List[Dict[str, Any]]:
+        """Per local device: the bytes its allocator reports in use and the
+        bytes of this engine's state (param + KV shards) placed on it,
+        computed from shapes and shardings alone. Empty while asleep: the
+        state is off the device, and a stats read must not re-create a
+        released backend."""
+        if self.sleeper.is_sleeping:
+            return []
+        import jax
+        import numpy as np
+
+        state = (self.engine.params, self.engine.pool.as_tuple())
+        if state[0] is None:  # a sleep edge raced this read
+            return []
+        rows = {
+            d.id: {
+                "id": d.id,
+                "bytes_in_use": (d.memory_stats() or {}).get("bytes_in_use"),
+                "state_bytes": 0,
+            }
+            for d in jax.local_devices()
+        }
+        for leaf in jax.tree.leaves(state):
+            shard = leaf.sharding.shard_shape(leaf.shape)
+            nbytes = int(np.prod(shard)) * leaf.dtype.itemsize
+            for d in leaf.sharding.addressable_devices:
+                rows[d.id]["state_bytes"] += nbytes
+        return list(rows.values())
 
     def submit(
         self,
@@ -6356,8 +6408,6 @@ class EngineService:
                 def reinit():
                     import jax
 
-                    from ..models import llama as _llama
-                    from ..parallel.mesh import shard_pytree
                     from .kv_cache import PagePool
 
                     if self.checkpoint_dir:
@@ -6387,18 +6437,11 @@ class EngineService:
                             ) << 20,
                         )
                     else:
-                        from ..models.registry import (
-                            init_params_for,
-                            logical_axes_for,
-                        )
+                        from ..models.registry import init_params_placed
 
-                        params = init_params_for(
-                            jax.random.key(self.args.seed), m
+                        params = init_params_placed(
+                            jax.random.key(self.args.seed), m, eng.mesh
                         )
-                        if eng.mesh is not None:
-                            params = shard_pytree(
-                                params, eng.mesh, logical_axes_for(m)
-                            )
                     pool = PagePool.create(
                         m.num_layers,
                         eng.cfg.num_pages,
@@ -7793,6 +7836,11 @@ def build_app(service: EngineService) -> web.Application:
 def run_server(args: argparse.Namespace) -> None:
     """Blocking server main (the child process body)."""
     logging.basicConfig(level=logging.INFO)
+    # armed here, not only by the launcher's preload: a stand-alone server
+    # and a launcher child cache alike
+    from ..utils import compile_cache
+
+    logger.info("compile cache at %s", compile_cache.arm() or "(none)")
     service = EngineService(args)
     app = build_app(service)
     try:

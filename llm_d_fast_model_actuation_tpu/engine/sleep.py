@@ -125,11 +125,11 @@ class SleepLevel(enum.IntEnum):
 
 
 def _platform_supports_host_memory() -> bool:
-    try:
-        dev = jax.devices()[0]
-        return any(m.kind == "pinned_host" for m in dev.addressable_memories())
-    except Exception:
-        return False
+    """Does the backend expose a ``pinned_host`` memory space? An error here
+    is a backend that did not come up; it raises rather than silently
+    switching sleep to numpy staging."""
+    dev = jax.devices()[0]
+    return any(m.kind == "pinned_host" for m in dev.addressable_memories())
 
 
 @dataclass
@@ -226,6 +226,7 @@ class SleepManager:
         self._staged_meta: Optional[list] = None  # per-leaf (shape, sharding)
         self._treedef: Optional[Any] = None
         self._released = False
+        self._client_gone = False
         self._use_memory_kind = _platform_supports_host_memory()
         self.stats = _Stats()
 
@@ -239,7 +240,9 @@ class SleepManager:
 
     @property
     def devices_released(self) -> bool:
-        return self._released
+        """The chip is free for another process: the sleep released the
+        backend AND the client object really died (engine/device.py)."""
+        return self._released and self._client_gone
 
     def _notify_transfer(
         self, kind: str, nbytes: int, seconds: float
@@ -588,7 +591,10 @@ class SleepManager:
         del state
         self._set_state(None)
         if release:
-            release_devices()
+            # this frame's last references to (deleted) device arrays: a
+            # client that is still referenced keeps the chip open
+            leaves = leaf = None  # noqa: F841
+            self._client_gone = release_devices()
             self._released = True
             self.stats.releases_total += 1
         self._level = level
@@ -805,7 +811,15 @@ class SleepManager:
         return {
             "is_sleeping": self.is_sleeping,
             "level": int(self._level),
-            "devices_released": self._released,
+            "devices_released": self.devices_released,
+            # the slept state sits in pinned_host jax arrays (a released
+            # or gang-staged sleep stages through numpy instead)
+            "pinned_host": bool(
+                self._level == SleepLevel.L1_HOST_OFFLOAD
+                and self._use_memory_kind
+                and not self._released
+                and self._staged is None
+            ),
             "bytes_offloaded": self.stats.bytes_offloaded,
             "bytes_offloaded_full": self.stats.bytes_offloaded_full,
             "quant": self.stats.last_quant,

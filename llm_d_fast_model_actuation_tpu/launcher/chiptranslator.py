@@ -7,7 +7,9 @@ docs/launcher.md:656-696):
      keyed by NODE_NAME: the shared source of truth for hardware-less e2e;
   2. **naive mock** — N synthetic chips in a row topology;
   3. **real** — enumerate local TPU chips via the native telemetry shim
-     (``native/tpuinfo``, ctypes) with a /dev + sysfs fallback.
+     (``native/tpuinfo``, ctypes); the mode names the source the shim used
+     (``real:pci+vfio`` on a Cloud TPU v5e host, ``real:pci``,
+     ``real:devfs``), and a host where it finds nothing fails.
 
 Unlike the GPU original (flat UUID->index), the translator exposes the host
 *topology* so placement can demand ICI-contiguous sub-slices.
@@ -18,7 +20,7 @@ from __future__ import annotations
 import json
 import logging
 import os
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
 from ..parallel.topology import ChipMap, HostTopology
 
@@ -59,7 +61,9 @@ class ChipTranslator:
             host = HostTopology.make(topo, node=node or "mock")
             logger.info("naive mock: %s chips (topology %s)", len(host.chips), topo)
             return cls(host, mode="naive-mock")
-        return cls(_enumerate_real(), mode="real")
+        host, source = enumerate_real()
+        logger.info("real chips via %s: %s", source, [c.chip_id for c in host.chips])
+        return cls(host, mode=f"real:{source}")
 
     # -- queries -------------------------------------------------------------
 
@@ -87,36 +91,30 @@ def _default_topology(n: int) -> str:
     return str(n)
 
 
-def _enumerate_real() -> HostTopology:
-    """Real-hardware enumeration: native shim first, sysfs/devfs fallback."""
-    try:
-        from ..native import tpuinfo
+def enumerate_real() -> Tuple[HostTopology, str]:
+    """The chips this host may open, and the source the native shim found
+    them through. Raises where the shim is not built or finds no chip — a
+    launcher that guessed would pin engines to chips that are not there."""
+    from ..native import tpuinfo
+    from ..parallel.topology import ChipInfo
 
-        chips = tpuinfo.enumerate_chips()
-        if chips:
-            topo = tpuinfo.host_topology() or _default_topology(len(chips))
-            host = HostTopology.make(topo, node=os.environ.get("NODE_NAME", "local"))
-            # keep shim-reported IDs
-            from ..parallel.topology import ChipInfo
-
-            host.chips = [
-                ChipInfo(chip_id=c["chip_id"], index=c["index"], coords=tuple(c.get("coords", ())))
-                for c in chips
-            ]
-            return host
-    except Exception as e:  # shim not built / not on a TPU host
-        logger.debug("native tpuinfo unavailable: %s", e)
-    # /dev/accel* fallback (TPU VMs expose one accel device per chip)
-    accels = sorted(
-        int(name[5:])
-        for name in os.listdir("/dev")
-        if name.startswith("accel") and name[5:].isdigit()
-    ) if os.path.isdir("/dev") else []
-    if accels:
-        host = HostTopology.make(_default_topology(len(accels)), node="local")
-        return host
-    raise RuntimeError(
-        "no TPU chips found (native shim unavailable, no /dev/accel*); "
-        "use a mock backend (launcher: --mock-chips, requester: --backend "
-        "static/env) for hardware-less operation"
-    )
+    doc = tpuinfo.query()
+    chips, source = doc.get("chips", []), doc.get("source", "")
+    if not chips:
+        raise RuntimeError(
+            f"no TPU chips found (tpuinfo source {source!r}: no Google PCI "
+            "function with an openable /dev/vfio group, no /dev/accel*); "
+            "use a mock backend (launcher: --mock-chips, requester: "
+            "--backend static/env) for hardware-less operation"
+        )
+    topo = doc.get("topology") or _default_topology(len(chips))
+    host = HostTopology.make(topo, node=os.environ.get("NODE_NAME", "local"))
+    host.chips = [  # keep shim-reported IDs
+        ChipInfo(
+            chip_id=c["chip_id"],
+            index=c["index"],
+            coords=tuple(c.get("coords", ())),
+        )
+        for c in chips
+    ]
+    return host, source

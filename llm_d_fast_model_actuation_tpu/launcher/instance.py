@@ -149,6 +149,27 @@ def _close_inherited_sockets() -> None:
                 pass
 
 
+def _apply_jax_env(env_vars: Dict[str, str]) -> None:
+    """Point the already-imported jax at the instance's ``JAX_*`` variables.
+    jax read its environment when the launcher imported it, before the fork:
+    without this a child told ``JAX_PLATFORMS=cpu`` would still take the
+    chip. (libtpu reads the TPU_* pinning variables when the child creates
+    its client, which the launcher never does, so those need nothing.)"""
+    import jax
+
+    for key, raw in env_vars.items():
+        name = key.lower()
+        if not key.startswith("JAX_") or name not in jax.config.values:
+            continue
+        current = jax.config.values[name]
+        value: Any = str(raw)
+        if isinstance(current, bool):
+            value = value.lower() in ("1", "true", "yes", "on")
+        elif isinstance(current, (int, float)):
+            value = type(current)(value)
+        jax.config.update(name, value)
+
+
 def engine_kickoff(config: InstanceConfig, log_path: str) -> None:
     """Child-process body: new process group, stdio -> log file, env, then
     the engine server (modules already imported pre-fork = preloading)."""
@@ -161,6 +182,7 @@ def engine_kickoff(config: InstanceConfig, log_path: str) -> None:
         os.close(fd)
     for k, v in (config.env_vars or {}).items():
         os.environ[k] = str(v)
+    _apply_jax_env(config.env_vars or {})
     # per-instance FMA_FAULTS must win over (latched) launcher-level state
     from ..utils import faults, tracing
 

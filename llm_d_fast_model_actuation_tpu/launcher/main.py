@@ -5,7 +5,8 @@ Reference parity (launcher.py:900-967): ``--mock-gpus`` family becomes
 fork so children inherit warm modules, and it exports a persistent XLA
 compilation-cache directory shared by every instance (on TPU, compilation —
 not weight loading — dominates cold start; a shared cache turns repeat model
-launches into cache hits).
+launches into cache hits). It never initializes a JAX backend itself: on TPU
+a process that has one holds the chip, and its children could not.
 """
 
 from __future__ import annotations
@@ -19,29 +20,24 @@ from aiohttp import web
 logger = logging.getLogger(__name__)
 
 
-def preload(compile_cache_dir: str) -> None:
+def preload() -> None:
     """Import the heavy modules once, pre-fork, and arm the persistent
     compilation cache (the TPU analogue of the reference's 'launcher imported
-    vLLM before forking', launcher.py:836-885)."""
-    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", compile_cache_dir)
-    # Serialized-executable spill for the engine's AOT pool rides next to
-    # the XLA cache (engine/exec_pool.py): every child of this launcher
-    # shares the directory, so a pooled executable survives instance
-    # restarts and even seeds sibling instances of the same model.
-    os.environ.setdefault(
-        "FMA_EXEC_SPILL_DIR", os.path.join(compile_cache_dir, "exec-pool")
-    )
-    os.makedirs(compile_cache_dir, exist_ok=True)
-    import jax  # noqa: F401
+    vLLM before forking', launcher.py:836-885). Every child of this launcher
+    shares the directory (utils/compile_cache.py), and the engine's
+    serialized-executable spill rides next to it (engine/exec_pool.py), so a
+    pooled executable survives instance restarts and even seeds sibling
+    instances of the same model."""
+    import jax
 
-    try:
-        jax.config.update("jax_compilation_cache_dir", compile_cache_dir)
-    except Exception:
-        pass
     from ..engine import server as _server  # noqa: F401  (engine modules warm)
     from ..models import llama as _llama  # noqa: F401
+    from ..utils import compile_cache
 
-    logger.info("preloaded jax %s; compile cache at %s", jax.__version__, compile_cache_dir)
+    logger.info(
+        "preloaded jax %s; compile cache at %s",
+        jax.__version__, compile_cache.arm() or "(none: held to the CPU)",
+    )
 
 
 def main(argv=None) -> None:
@@ -63,7 +59,10 @@ def main(argv=None) -> None:
     p.add_argument("--chip-map-path", default="")
     p.add_argument("--log-dir", default="")
     p.add_argument(
-        "--compile-cache-dir", default="/tmp/fma-tpu-xla-cache"
+        "--compile-cache-dir",
+        default="",
+        help="default for JAX_COMPILATION_CACHE_DIR when the environment "
+        "does not set it (utils/compile_cache.py has the rule)",
     )
     p.add_argument("--no-preload", action="store_true")
     # Crash supervision (launcher/manager.py RestartPolicy): 0 keeps the
@@ -105,8 +104,12 @@ def main(argv=None) -> None:
     args = p.parse_args(argv)
 
     logging.basicConfig(level=getattr(logging, args.log_level.upper(), logging.INFO))
+    if args.compile_cache_dir:
+        os.environ.setdefault(
+            "JAX_COMPILATION_CACHE_DIR", args.compile_cache_dir
+        )
     if not args.no_preload:
-        preload(args.compile_cache_dir)
+        preload()
 
     from ..utils import faults
     from .chiptranslator import ChipTranslator

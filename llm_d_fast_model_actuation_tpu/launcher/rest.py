@@ -86,6 +86,12 @@ def build_app(manager: EngineProcessManager) -> web.Application:
             {
                 "name": "Multi-Instance Engine Management API (TPU)",
                 "version": "2.0",
+                # what this launcher enumerated and how ("real:pci+vfio",
+                # "naive-mock", ...): the ids a create's gpu_uuids may name
+                "chips": {
+                    "mode": manager.translator.mode,
+                    "ids": manager.translator.chip_ids(),
+                },
                 "endpoints": {
                     "index": "GET /",
                     "health": "GET /health",

@@ -353,6 +353,7 @@ def prefill(
     seq_lens: jnp.ndarray,  # [b] int32
     cache: Tuple[jnp.ndarray, jnp.ndarray],  # k/v pages [L, P, ps, kvh, hd]
     page_table: jnp.ndarray,  # [b, pages_per_seq] int32
+    mesh=None,  # tp mesh: the pallas attention impl runs under shard_map
 ):
     """Prefill a batch of prompts, writing KV into the paged cache.
 
@@ -377,7 +378,9 @@ def prefill(
         q, k, v = _project_qkv(cfg, lp, h, positions, cos_tab, sin_tab)
         kp = _scatter_prefill(kp, k, page_table, positions, valid, page_size)
         vp = _scatter_prefill(vp, v, page_table, positions, valid, page_size)
-        attn = causal_prefill_attention(q, k, v, seq_lens, impl=cfg.attention_impl)
+        attn = causal_prefill_attention(
+            q, k, v, seq_lens, impl=cfg.attention_impl, mesh=mesh
+        )
         x = x + _post(cfg, lp, "post_attn_norm", qmat(attn.reshape(b, s, cfg.q_dim), lp["wo"]))
         h = _norm(cfg, x, lp["mlp_norm"])
         x = x + _post(cfg, lp, "post_ffn_norm", _ffn(cfg, lp, h))
@@ -470,7 +473,7 @@ def mixed_step(
     the dropped entries were hard-masked exact zeros for every row).
     Under a sharded mesh the gather/scatter and einsums GSPMD-partition
     over the kv_heads/heads shards; the ragged op routes per
-    ops/attention.py:resolve_ragged_impl — the pallas kernel runs under
+    ops/attention.py:ragged_paged_attention — the pallas kernel runs under
     ``shard_map`` over ``mesh``'s tp axis, the XLA twin partitions
     without it.
 
@@ -525,6 +528,7 @@ def decode_step(
     cache: Tuple[jnp.ndarray, jnp.ndarray],
     page_table: jnp.ndarray,  # [b, pages_per_seq]
     active: "jnp.ndarray | None" = None,  # [b] bool; inactive rows write nothing
+    mesh=None,  # tp mesh: the pallas attention impl runs under shard_map
 ):
     """One decode step for the whole running batch.
 
@@ -565,7 +569,8 @@ def decode_step(
         )
         q, k, v = q[:, 0], k[:, 0], v[:, 0]  # [b, heads/kvh, hd]
         attn = paged_decode_attention_inline(
-            q, kp, vp, k, v, page_table, positions, impl=cfg.attention_impl
+            q, kp, vp, k, v, page_table, positions, impl=cfg.attention_impl,
+            mesh=mesh,
         )
         x = x + _post(cfg, lp, "post_attn_norm", qmat(attn.reshape(b, cfg.q_dim), lp["wo"]))
         h = _norm(cfg, x, lp["mlp_norm"])

@@ -258,7 +258,10 @@ def quantize_leaf(
     return q, TransferQuant(
         mode="int8",
         orig_dtype=orig,
-        scale=np.asarray(s, dtype=np.float32),
+        # an owned copy: on CPU-family backends np.asarray can alias the
+        # device buffer, and a scale that outlives a device release must
+        # not keep the client alive (engine/device.py)
+        scale=np.array(s, dtype=np.float32),
         spec=spec,
     )
 

@@ -8,6 +8,7 @@ logical-axes pytree differ per family.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict
 
 import jax
@@ -21,6 +22,32 @@ def init_params_for(key: jax.Array, cfg: llama.LlamaConfig) -> Dict[str, Any]:
     else:
         params = llama.init_params(key, cfg)
     return maybe_quantize(cfg, params)
+
+
+def init_params_placed(
+    key: jax.Array, cfg: llama.LlamaConfig, mesh: Any = None
+) -> Dict[str, Any]:
+    """Random-init params created directly in their serving placement:
+    sharded over `mesh` per the logical-axis rules, or committed to the
+    default device. One jitted init with ``out_shardings`` — each device
+    generates only its own shards, so a model that needs the mesh (16 GB of
+    bf16 at tp=4) never materializes whole on the first chip."""
+    from ..parallel.mesh import logical_shardings
+
+    if mesh is not None:
+        shardings = logical_shardings(mesh, logical_axes_for(cfg))
+    else:
+        shardings = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+    return jax.jit(_init_fn(cfg), out_shardings=shardings)(key)
+
+
+@functools.lru_cache(maxsize=None)
+def _init_fn(cfg: llama.LlamaConfig):
+    """One function object per config, so repeated builds of a config share
+    jit's trace and compile caches (keyed on function identity). Holds the
+    config only — never a device or a mesh, which would outlive a device
+    release (engine/device.py)."""
+    return lambda key: init_params_for(key, cfg)
 
 
 def logical_axes_for(cfg: llama.LlamaConfig) -> Dict[str, Any]:

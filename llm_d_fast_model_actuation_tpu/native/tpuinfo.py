@@ -6,9 +6,8 @@ shim is authored natively (SURVEY.md §2.9, §7): chip enumeration from the PCI
 tree / devfs and per-chip HBM usage where the runtime exposes it.
 
 The shared library is looked up at $FMA_TPUINFO_LIB, next to this file, or in
-the repo's native/build directory. All entry points raise RuntimeError when
-the shim isn't built — callers (ChipTranslator, requester) treat that as
-"fall back to mock/devfs".
+the repo's native/build directory (`make -C native`). All entry points raise
+RuntimeError when the shim isn't built.
 """
 
 from __future__ import annotations
@@ -41,11 +40,15 @@ def _lib() -> ctypes.CDLL:
                 _LIB = lib
                 break
         else:
-            raise RuntimeError("libtpuinfo.so not built")
+            raise RuntimeError(
+                "libtpuinfo.so not built: run `make -C native`"
+            )
     return _LIB
 
 
-def _query() -> Dict:
+def query() -> Dict:
+    """The shim's whole document: ``chips``, ``topology`` and the ``source``
+    the enumeration used ("pci+vfio", "pci", "devfs", "mock", "none")."""
     lib = _lib()
     ptr = lib.tpuinfo_query()
     if not ptr:
@@ -59,18 +62,18 @@ def _query() -> Dict:
 
 def enumerate_chips() -> List[Dict]:
     """[{chip_id, index, coords?, total_hbm_bytes?}] for local TPU chips."""
-    return _query().get("chips", [])
+    return query().get("chips", [])
 
 
 def host_topology() -> Optional[str]:
-    return _query().get("topology") or None
+    return query().get("topology") or None
 
 
 def hbm_usage() -> Dict[str, int]:
     """chip_id -> bytes of HBM in use (0 when the runtime hides it)."""
     return {
         c["chip_id"]: int(c.get("hbm_used_bytes", 0))
-        for c in _query().get("chips", [])
+        for c in query().get("chips", [])
     }
 
 
@@ -115,7 +118,7 @@ def main(argv: Optional[List[str]] = None) -> int:
             coords = ",".join(str(x) for x in (c.get("coords") or []))
             print(f"{c['index']} {c['chip_id']} {coords}".rstrip())
     else:
-        print(json.dumps(_query(), indent=2))
+        print(json.dumps(query(), indent=2))
     return 0
 
 

@@ -18,14 +18,28 @@ All softmax math is fp32 regardless of the io dtype.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30
 
-#: Selected implementation: "reference" (pure XLA) or "pallas" (TPU kernels,
-#: interpreter mode off-TPU). Read at trace time — switch before (re-)jitting.
+#: shard_map specs of the Pallas kernels' operands on a tp mesh
+#: (ops/pallas/decode.py:shard_over_tp): heads / KV heads sharded, page
+#: tables and lengths replicated
+_HEADS3 = P(None, "tp", None)  # [batch|tokens, heads, head_dim]
+_HEADS4 = P(None, None, "tp", None)  # pages, or [batch, seq, heads, head_dim]
+
+#: Selected implementation: "reference" (pure XLA) or "pallas" (TPU
+#: kernels). Read at trace time — switch before (re-)jitting.
 _IMPL = "reference"
+
+#: Pallas interpreter mode. Off unless a CPU test harness turns it on
+#: (tests/conftest.py): it is never inferred from the backend, so a serving
+#: process cannot end up emulating its kernels.
+_INTERPRET = False
 
 
 def set_attention_impl(impl: str) -> None:
@@ -39,8 +53,18 @@ def get_attention_impl() -> str:
     return _IMPL
 
 
+def set_pallas_interpret(on: bool) -> None:
+    global _INTERPRET
+    _INTERPRET = bool(on)
+
+
 def _pallas_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    if _INTERPRET and jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "Pallas interpret mode is on in a process whose backend is tpu; "
+            "kernels must compile for the chip"
+        )
+    return _INTERPRET
 
 
 def _repeat_kv(x: jnp.ndarray, n_rep: int, axis: int) -> jnp.ndarray:
@@ -56,17 +80,32 @@ def causal_prefill_attention(
     v: jnp.ndarray,  # [batch, seq, kv_heads, head_dim]
     seq_lens: jnp.ndarray,  # [batch] int32: valid prefix length per row
     impl: "str | None" = None,  # None -> module default
+    mesh=None,  # tp mesh: the pallas impl runs under shard_map
 ) -> jnp.ndarray:
     """Causal self-attention over a (right-padded) prefill batch."""
     if (impl or _IMPL) == "pallas":
         from .pallas import causal_prefill_attention_pallas
+        from .pallas.decode import shard_over_tp
 
+        # Right-pad the sequence to whole kernel blocks (a multiple of the
+        # bf16 sublane tile): padded keys sit past every seq_len, so they are
+        # masked, and padded query rows are sliced off.
         s = q.shape[1]
-        block_q = next((bq for bq in (128, 64, 32, 16, 8) if s % bq == 0), None)
-        if block_q is not None:
-            return causal_prefill_attention_pallas(
-                q, k, v, seq_lens, block_q=block_q, interpret=_pallas_interpret()
+        block_q = min(128, -(-s // 16) * 16)
+        pad = -s % block_q
+        if pad:
+            q, k, v = (
+                jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                for x in (q, k, v)
             )
+        kernel = functools.partial(
+            causal_prefill_attention_pallas,
+            block_q=block_q, interpret=_pallas_interpret(),
+        )
+        out = shard_over_tp(
+            mesh, kernel, (_HEADS4, _HEADS4, _HEADS4, P(None)), _HEADS4
+        )(q, k, v, seq_lens)
+        return out[:, :s]
     b, s, h, d = q.shape
     kvh = k.shape[2]
     k = _repeat_kv(k, h // kvh, axis=2)
@@ -97,6 +136,7 @@ def paged_decode_attention_inline(
     positions: jnp.ndarray,  # [batch] int32 — position of the new token;
     #                          cache entries < position are attended
     impl: "str | None" = None,
+    mesh=None,  # tp mesh: the pallas impl runs under shard_map
 ) -> jnp.ndarray:
     """Decode attention where the new token's K/V are passed *inline* instead
     of having been scattered into the cache first.
@@ -114,11 +154,18 @@ def paged_decode_attention_inline(
     """
     if (impl or _IMPL) == "pallas":
         from .pallas import paged_decode_attention_inline_pallas
+        from .pallas.decode import shard_over_tp
 
-        return paged_decode_attention_inline_pallas(
-            q, k_pages, v_pages, k_new, v_new, page_table, positions,
+        kernel = functools.partial(
+            paged_decode_attention_inline_pallas,
             interpret=_pallas_interpret(),
         )
+        return shard_over_tp(
+            mesh, kernel,
+            (_HEADS3, _HEADS4, _HEADS4, _HEADS3, _HEADS3, P(None, None),
+             P(None)),
+            _HEADS3,
+        )(q, k_pages, v_pages, k_new, v_new, page_table, positions)
     b, h, d = q.shape
     kvh = k_pages.shape[2]
     g = h // kvh
@@ -155,6 +202,7 @@ def paged_decode_attention(
     page_table: jnp.ndarray,  # [batch, pages_per_seq] int32
     seq_lens: jnp.ndarray,  # [batch] int32 (length INCLUDING the new token)
     impl: "str | None" = None,  # None -> module default
+    mesh=None,  # tp mesh: the pallas impl runs under shard_map
 ) -> jnp.ndarray:
     """One decode step of attention against the paged cache.
 
@@ -165,10 +213,15 @@ def paged_decode_attention(
     """
     if (impl or _IMPL) == "pallas":
         from .pallas import paged_decode_attention_pallas
+        from .pallas.decode import shard_over_tp
 
-        return paged_decode_attention_pallas(
-            q, k_pages, v_pages, page_table, seq_lens, interpret=_pallas_interpret()
+        kernel = functools.partial(
+            paged_decode_attention_pallas, interpret=_pallas_interpret()
         )
+        return shard_over_tp(
+            mesh, kernel,
+            (_HEADS3, _HEADS4, _HEADS4, P(None, None), P(None)), _HEADS3,
+        )(q, k_pages, v_pages, page_table, seq_lens)
     b, h, d = q.shape
     pages_per_seq = page_table.shape[1]
     page_size = k_pages.shape[1]
@@ -199,51 +252,6 @@ def paged_decode_attention(
 #: belongs to at most one sequence. Waste per packed segment is < this
 #: many rows — against up to 2x for the power-of-two prefill buckets.
 RAGGED_BLOCK = 8
-
-
-def resolve_ragged_impl(impl: str, mesh) -> str:
-    """The implementation the RAGGED op runs under for an engine on
-    `mesh` (None = single device) — the ONE routing decision for the
-    packed data plane, a matrix of device kind x mesh x impl flag:
-
-    ==========  ====================  =================================
-    impl flag   mesh=None             single-process tp mesh
-    ==========  ====================  =================================
-    pallas      Pallas kernel         Pallas kernel under ``shard_map``
-                (interpret on CPU)    over the ``tp`` axis
-                                      (ops/pallas/ragged.py:
-                                      ragged_paged_attention_pallas_
-                                      sharded) on TPU meshes — and in
-                                      interpreter mode on CPU meshes
-                                      whose jaxlib can lower it
-                                      (``pallas_interpret_supported``);
-                                      the XLA twin otherwise
-    grouped /   XLA twin              XLA twin — its gather/scatter
-    reference                         GSPMD-partitions: ``k_pages[pt]``
-                                      gathers on the replicated page
-                                      axis of a pool sharded over
-                                      kv_heads, so each device reads
-                                      only its own head shard, and the
-                                      einsums contract the head-sharded
-                                      axes in place
-    ==========  ====================  =================================
-
-    KV heads and the page pool are sharded over ``tp`` already
-    (``PagePool.create``), so the shard_map port gives each shard the
-    same scalar-prefetched block metadata over its own head slice of
-    the pool — no cross-shard softmax for head-sharded GQA. The
-    engine's bucketed programs keep their configured impl — only the
-    packed path routes here. Engines resolved to a non-pallas impl pack
-    densely (the twin computes every row independently, so RAGGED_BLOCK
-    alignment buys nothing); pallas engines keep the block alignment on
-    meshes too."""
-    if impl != "pallas" or mesh is None:
-        return impl
-    if jax.default_backend() == "tpu":
-        return "pallas"
-    from ..utils.compat import pallas_interpret_supported
-
-    return "pallas" if pallas_interpret_supported() else "grouped"
 
 
 def ragged_paged_attention(
@@ -284,21 +292,22 @@ def ragged_paged_attention(
     finite garbage the caller ignores.
     """
     if (impl or _IMPL) == "pallas":
-        if q.shape[0] % RAGGED_BLOCK == 0:
-            if mesh is not None:
-                from .pallas import ragged_paged_attention_pallas_sharded
+        # the kernel raises on a buffer that is not whole RAGGED_BLOCKs;
+        # the engine's token budget is rounded to it (packed_token_budget)
+        if mesh is not None:
+            from .pallas import ragged_paged_attention_pallas_sharded
 
-                return ragged_paged_attention_pallas_sharded(
-                    mesh, q, k_pages, v_pages, page_table, row_slot,
-                    positions, block_rows=RAGGED_BLOCK,
-                    interpret=_pallas_interpret(),
-                )
-            from .pallas import ragged_paged_attention_pallas
-
-            return ragged_paged_attention_pallas(
-                q, k_pages, v_pages, page_table, row_slot, positions,
-                block_rows=RAGGED_BLOCK, interpret=_pallas_interpret(),
+            return ragged_paged_attention_pallas_sharded(
+                mesh, q, k_pages, v_pages, page_table, row_slot,
+                positions, block_rows=RAGGED_BLOCK,
+                interpret=_pallas_interpret(),
             )
+        from .pallas import ragged_paged_attention_pallas
+
+        return ragged_paged_attention_pallas(
+            q, k_pages, v_pages, page_table, row_slot, positions,
+            block_rows=RAGGED_BLOCK, interpret=_pallas_interpret(),
+        )
     t, h, d = q.shape
     kvh = k_pages.shape[2]
     g = h // kvh

@@ -15,8 +15,12 @@ Layout contract (shared with the engine's KV pool):
   seq_lens:         [batch] int32, length INCLUDING the new token
   q:                [batch, heads, head_dim]
 
-For best MXU/VPU utilization pick page_size a multiple of 128 on real TPU
-(the engine's `page_size` knob); smaller pages still work, padded to lanes.
+Inside the kernel a page is a ``[page_size, kv_heads * head_dim]`` tile: the
+two minor axes are fused so the lane axis is kv_heads*head_dim wide, and a KV
+head is a static lane slice of it. Mosaic refuses to DMA or slice a memref
+whose minor dim is narrower than the 128-lane tile, so the unfused layout
+cannot serve head_dim 64; the fused one serves every shape with
+``kv_heads * head_dim % 128 == 0`` (:func:`check_kernel_shape`).
 """
 
 from __future__ import annotations
@@ -30,31 +34,77 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
+#: TPU lane width: the fused kv_heads*head_dim page row must fill whole lanes.
+LANES = 128
+
+
+def pallas_shape_ok(num_kv_heads: int, head_dim: int) -> bool:
+    """Can Mosaic compile the paged kernels (decode and ragged) for this
+    per-device KV shape?"""
+    return num_kv_heads > 0 and (num_kv_heads * head_dim) % LANES == 0
+
+
+def check_kernel_shape(num_kv_heads: int, head_dim: int) -> None:
+    if not pallas_shape_ok(num_kv_heads, head_dim):
+        raise ValueError(
+            f"pallas paged attention needs kv_heads * head_dim (per device) "
+            f"to be a multiple of {LANES}; got {num_kv_heads} * {head_dim}"
+        )
+
+
+def fuse_pages(pages: jnp.ndarray) -> jnp.ndarray:
+    """[num_pages, page_size, kv_heads, head_dim] -> lane-fused 3-D view."""
+    n, ps, kvh, d = pages.shape
+    return pages.reshape(n, ps, kvh * d)
+
+
+def shard_over_tp(mesh, kernel, in_specs, out_specs):
+    """``kernel`` (a per-device Pallas call) on a tp mesh; itself when
+    ``mesh`` is None. GSPMD cannot partition a Mosaic kernel, so it runs
+    under ``shard_map`` over the ``tp`` axis — the axis the engine shards
+    query heads, KV heads and the page pool over — each shard seeing its
+    own head slice and the replicated page table / lengths. Head-sharded
+    GQA needs no cross-shard softmax: every query head's softmax completes
+    inside the shard that owns its KV-head group (``kv_heads % tp == 0``,
+    as the NamedSharding placement already requires)."""
+    if mesh is None:
+        return kernel
+    # the pallas body is opaque to the varying-axes checker; the out_specs
+    # are the contract the caller relies on
+    return jax.shard_map(
+        kernel, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
+    )
+
 
 def _decode_kernel(
     # scalar prefetch
     page_table_ref,  # [batch, pages_per_seq] SMEM
-    seq_lens_ref,  # [batch] SMEM
+    len_ref,  # [batch] SMEM — cache entries attended (positions < len)
     # inputs
     q_ref,  # [1, heads, head_dim] VMEM
-    k_hbm,  # [num_pages, page_size, kv_heads, head_dim] HBM/ANY
-    v_hbm,  # same
-    # output
-    o_ref,  # [1, heads, head_dim] VMEM
-    # scratch
-    k_buf,  # [2, page_size, kv_heads, head_dim] VMEM
-    v_buf,  # same
-    sems,  # DMA sems [2, 2]
-    *,
+    *refs,
     page_size: int,
     num_heads: int,
     num_kv_heads: int,
     head_dim: int,
+    inline: bool,
 ):
+    """Online-softmax over the sequence's pages. With ``inline`` the new
+    token's K/V arrive as two extra inputs ([1, 1, kv_heads * head_dim]
+    VMEM, not yet in the cache — the engine defers cache scatters; see
+    ops/attention.py:paged_decode_attention_inline) and are folded into the
+    running (m, l, acc) state after the page walk."""
+    if inline:
+        knew_ref, vnew_ref, *refs = refs
+    # k_hbm, v_hbm: [num_pages, page_size, kv_heads * head_dim] HBM/ANY
+    # o_ref: [1, heads, head_dim] VMEM
+    # k_buf, v_buf: [2, page_size, kv_heads * head_dim] VMEM; sems: DMA [2, 2]
+    k_hbm, v_hbm, o_ref, k_buf, v_buf, sems = refs
     b = pl.program_id(0)
     group = num_heads // num_kv_heads
-    seq_len = seq_lens_ref[b]
-    num_pages = jax.lax.div(seq_len + page_size - 1, page_size)
+    kv_len = len_ref[b]
+    num_pages = jax.lax.div(kv_len + page_size - 1, page_size)
 
     def page_dma(buf, hbm, slot, p, sem_row):
         return pltpu.make_async_copy(
@@ -86,16 +136,17 @@ def _decode_kernel(
         page_dma(k_buf, k_hbm, slot, p, 0).wait()
         page_dma(v_buf, v_hbm, slot, p, 1).wait()
 
-        # tokens beyond seq_len in the (last) page are masked out
+        # tokens beyond kv_len in the (last) page are masked out
         tok0 = p * page_size
         tok_idx = tok0 + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
-        valid = tok_idx < seq_len  # [1, page_size]
+        valid = tok_idx < kv_len  # [1, page_size]
 
         new_ms, new_ls, new_accs = [], [], []
         for g in range(num_kv_heads):
+            lanes = pl.ds(g * head_dim, head_dim)
             qg = q[g * group : (g + 1) * group]  # [group, head_dim]
-            kg = k_buf[slot, :, g, :].astype(jnp.float32)  # [page, head_dim]
-            vg = v_buf[slot, :, g, :].astype(jnp.float32)
+            kg = k_buf[slot, :, lanes].astype(jnp.float32)  # [page, head_dim]
+            vg = v_buf[slot, :, lanes].astype(jnp.float32)
             logits = jax.lax.dot_general(
                 qg,
                 kg,
@@ -126,119 +177,76 @@ def _decode_kernel(
     )
     ms, ls, accs = jax.lax.fori_loop(0, num_pages, body, (m0, l0, acc0))
 
+    if inline:
+        # Fold the inline token (always valid; guarantees l > 0 at pos == 0).
+        ms, ls, accs = list(ms), list(ls), list(accs)
+        for g in range(num_kv_heads):
+            lanes = pl.ds(g * head_dim, head_dim)
+            qg = q[g * group : (g + 1) * group]
+            kn = knew_ref[0, :, lanes].astype(jnp.float32)  # [1, head_dim]
+            vn = vnew_ref[0, :, lanes].astype(jnp.float32)
+            logit = (qg * kn).sum(axis=-1, keepdims=True)  # [group, 1]
+            m_cur = jnp.maximum(ms[g], logit)
+            alpha = jnp.exp(ms[g] - m_cur)
+            p_self = jnp.exp(logit - m_cur)
+            ls[g] = ls[g] * alpha + p_self
+            accs[g] = accs[g] * alpha + p_self * vn
+            ms[g] = m_cur
+
     l = jnp.concatenate(ls, axis=0)  # [heads, 1]
     acc = jnp.concatenate(accs, axis=0)  # [heads, head_dim]
     out = jnp.where(l > 0, acc / jnp.where(l > 0, l, 1.0), 0.0)
     o_ref[0] = out.astype(o_ref.dtype)
 
 
-def _decode_kernel_inline(
-    # scalar prefetch
-    page_table_ref,  # [batch, pages_per_seq] SMEM
-    pos_ref,  # [batch] SMEM — position of the new token (cache holds < pos)
-    # inputs
-    q_ref,  # [1, heads, head_dim] VMEM
-    knew_ref,  # [1, kv_heads, head_dim] VMEM — the new token's K (not yet in cache)
-    vnew_ref,  # [1, kv_heads, head_dim] VMEM
-    k_hbm,  # [num_pages, page_size, kv_heads, head_dim] HBM/ANY
-    v_hbm,  # same
-    # output
-    o_ref,  # [1, heads, head_dim] VMEM
-    # scratch
-    k_buf,  # [2, page_size, kv_heads, head_dim] VMEM
-    v_buf,  # same
-    sems,  # DMA sems [2, 2]
-    *,
-    page_size: int,
-    num_heads: int,
-    num_kv_heads: int,
-    head_dim: int,
-):
-    """Decode attention with the new token's K/V passed inline (the engine
-    defers cache scatters; see ops/attention.py:paged_decode_attention_inline).
-    Identical online-softmax structure to `_decode_kernel`, plus one final
-    fold of the inline token into the running (m, l, acc) state."""
-    b = pl.program_id(0)
-    group = num_heads // num_kv_heads
-    pos = pos_ref[b]
-    num_pages = jax.lax.div(pos + page_size - 1, page_size)
+def _paged_decode(q, k_pages, v_pages, page_table, kv_lens, new_kv, interpret):
+    batch, num_heads, head_dim = q.shape
+    _, page_size, num_kv_heads, _ = k_pages.shape
+    if not interpret:  # the interpreter has no tiling to satisfy
+        check_kernel_shape(num_kv_heads, head_dim)
+    fused = num_kv_heads * head_dim
 
-    def page_dma(buf, hbm, slot, p, sem_row):
-        return pltpu.make_async_copy(
-            hbm.at[page_table_ref[b, p]],
-            buf.at[slot],
-            sems.at[sem_row, slot],
-        )
-
-    @pl.when(num_pages > 0)
-    def _():
-        page_dma(k_buf, k_hbm, 0, 0, 0).start()
-        page_dma(v_buf, v_hbm, 0, 0, 1).start()
-
-    q = q_ref[0].astype(jnp.float32) * (head_dim**-0.5)  # [heads, head_dim]
-
-    def body(p, carry):
-        ms, ls, accs = carry
-        slot = jax.lax.rem(p, 2)
-
-        @pl.when(p + 1 < num_pages)
-        def _():
-            nxt = jax.lax.rem(p + 1, 2)
-            page_dma(k_buf, k_hbm, nxt, p + 1, 0).start()
-            page_dma(v_buf, v_hbm, nxt, p + 1, 1).start()
-
-        page_dma(k_buf, k_hbm, slot, p, 0).wait()
-        page_dma(v_buf, v_hbm, slot, p, 1).wait()
-
-        tok0 = p * page_size
-        tok_idx = tok0 + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
-        valid = tok_idx < pos  # strictly past tokens
-
-        new_ms, new_ls, new_accs = [], [], []
-        for g in range(num_kv_heads):
-            qg = q[g * group : (g + 1) * group]
-            kg = k_buf[slot, :, g, :].astype(jnp.float32)
-            vg = v_buf[slot, :, g, :].astype(jnp.float32)
-            logits = jax.lax.dot_general(
-                qg, kg, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            logits = jnp.where(valid, logits, NEG_INF)
-            m_cur = jnp.maximum(ms[g], logits.max(axis=-1, keepdims=True))
-            alpha = jnp.exp(ms[g] - m_cur)
-            probs = jnp.exp(logits - m_cur)
-            l_cur = ls[g] * alpha + probs.sum(axis=-1, keepdims=True)
-            pv = jax.lax.dot_general(
-                probs, vg, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            new_ms.append(m_cur)
-            new_ls.append(l_cur)
-            new_accs.append(accs[g] * alpha + pv)
-        return tuple(new_ms), tuple(new_ls), tuple(new_accs)
-
-    m0 = tuple(jnp.full((group, 1), NEG_INF, jnp.float32) for _ in range(num_kv_heads))
-    l0 = tuple(jnp.zeros((group, 1), jnp.float32) for _ in range(num_kv_heads))
-    acc0 = tuple(
-        jnp.zeros((group, head_dim), jnp.float32) for _ in range(num_kv_heads)
+    kernel = functools.partial(
+        _decode_kernel,
+        page_size=page_size,
+        num_heads=num_heads,
+        num_kv_heads=num_kv_heads,
+        head_dim=head_dim,
+        inline=bool(new_kv),
     )
-    ms, ls, accs = jax.lax.fori_loop(0, num_pages, body, (m0, l0, acc0))
-
-    # Fold the inline token (always valid; guarantees l > 0 even at pos == 0).
-    out_rows = []
-    for g in range(num_kv_heads):
-        qg = q[g * group : (g + 1) * group]
-        kn = knew_ref[0, g, :].astype(jnp.float32)  # [head_dim]
-        vn = vnew_ref[0, g, :].astype(jnp.float32)
-        logit = (qg * kn[None, :]).sum(axis=-1, keepdims=True)  # [group, 1]
-        m_cur = jnp.maximum(ms[g], logit)
-        alpha = jnp.exp(ms[g] - m_cur)
-        p_self = jnp.exp(logit - m_cur)
-        l_cur = ls[g] * alpha + p_self
-        acc = accs[g] * alpha + p_self * vn[None, :]
-        out_rows.append(acc / l_cur)
-    out = jnp.concatenate(out_rows, axis=0)  # [heads, head_dim]
-    o_ref[0] = out.astype(o_ref.dtype)
+    row_spec = lambda shape: pl.BlockSpec(  # noqa: E731
+        shape, lambda b, *_: (b,) + (0,) * (len(shape) - 1), memory_space=pltpu.VMEM
+    )
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(batch,),
+        in_specs=[
+            row_spec((1, num_heads, head_dim)),
+            *(row_spec((1, 1, fused)) for _ in new_kv),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=row_spec((1, num_heads, head_dim)),
+        scratch_shapes=[
+            pltpu.VMEM((2, page_size, fused), k_pages.dtype),
+            pltpu.VMEM((2, page_size, fused), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        grid_spec=grid_spec,
+        interpret=interpret,
+        name="paged_decode_inline" if new_kv else "paged_decode",
+    )(
+        page_table.astype(jnp.int32),
+        kv_lens.astype(jnp.int32),
+        q,
+        *(x.reshape(batch, 1, fused) for x in new_kv),
+        fuse_pages(k_pages),
+        fuse_pages(v_pages),
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -249,52 +257,11 @@ def paged_decode_attention_inline_pallas(
     k_new: jnp.ndarray,  # [batch, kv_heads, head_dim]
     v_new: jnp.ndarray,
     page_table: jnp.ndarray,  # [batch, pages_per_seq] int32
-    positions: jnp.ndarray,  # [batch] int32
+    positions: jnp.ndarray,  # [batch] int32 — cache holds entries < position
     interpret: bool = False,
 ) -> jnp.ndarray:
-    batch, num_heads, head_dim = q.shape
-    _, page_size, num_kv_heads, _ = k_pages.shape
-
-    kernel = functools.partial(
-        _decode_kernel_inline,
-        page_size=page_size,
-        num_heads=num_heads,
-        num_kv_heads=num_kv_heads,
-        head_dim=head_dim,
-    )
-    row_spec = lambda shape: pl.BlockSpec(  # noqa: E731
-        shape, lambda b, *_: (b,) + (0,) * (len(shape) - 1), memory_space=pltpu.VMEM
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(batch,),
-        in_specs=[
-            row_spec((1, num_heads, head_dim)),
-            row_spec((1, num_kv_heads, head_dim)),
-            row_spec((1, num_kv_heads, head_dim)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=row_spec((1, num_heads, head_dim)),
-        scratch_shapes=[
-            pltpu.VMEM((2, page_size, num_kv_heads, head_dim), k_pages.dtype),
-            pltpu.VMEM((2, page_size, num_kv_heads, head_dim), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        grid_spec=grid_spec,
-        interpret=interpret,
-    )(
-        page_table.astype(jnp.int32),
-        positions.astype(jnp.int32),
-        q,
-        k_new,
-        v_new,
-        k_pages,
-        v_pages,
+    return _paged_decode(
+        q, k_pages, v_pages, page_table, positions, (k_new, v_new), interpret
     )
 
 
@@ -307,42 +274,6 @@ def paged_decode_attention_pallas(
     seq_lens: jnp.ndarray,  # [batch] int32
     interpret: bool = False,
 ) -> jnp.ndarray:
-    batch, num_heads, head_dim = q.shape
-    _, page_size, num_kv_heads, _ = k_pages.shape
-
-    kernel = functools.partial(
-        _decode_kernel,
-        page_size=page_size,
-        num_heads=num_heads,
-        num_kv_heads=num_kv_heads,
-        head_dim=head_dim,
+    return _paged_decode(
+        q, k_pages, v_pages, page_table, seq_lens, (), interpret
     )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(batch,),
-        in_specs=[
-            pl.BlockSpec(
-                (1, num_heads, head_dim),
-                lambda b, *_: (b, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, num_heads, head_dim),
-            lambda b, *_: (b, 0, 0),
-            memory_space=pltpu.VMEM,
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((2, page_size, num_kv_heads, head_dim), k_pages.dtype),
-            pltpu.VMEM((2, page_size, num_kv_heads, head_dim), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        grid_spec=grid_spec,
-        interpret=interpret,
-    )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32), q, k_pages, v_pages)

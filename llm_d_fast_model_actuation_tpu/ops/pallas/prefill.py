@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...utils.compat import tpu_compiler_params
-
 NEG_INF = -1e30
 
 
@@ -148,7 +146,7 @@ def causal_prefill_attention_pallas(
         kernel,
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         grid_spec=grid_spec,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

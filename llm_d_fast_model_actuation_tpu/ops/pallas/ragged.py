@@ -25,7 +25,12 @@ Packing contract (the engine's packer upholds it, engine/engine.py):
 
 Grid: one program per row block. GQA reads each KV head's page tile once
 per block and loops the query heads of its group over it — repeated KV
-heads are never materialized, mirroring the decode kernel.
+heads are never materialized, mirroring the decode kernel. Pages are
+lane-fused ``[page_size, kv_heads * head_dim]`` tiles as there (same
+Mosaic constraint, ``decode.check_kernel_shape``), and q / the output are
+passed head-major ``[heads, tokens, head_dim]`` so a query head is a
+leading-axis index, not a sublane-strided slice of a 3-D tile (which
+Mosaic cannot lay out at head_dim 64).
 
 Meshes: the kernel body is a single-device program (it walks the page
 pool with raw HBM DMA), and :func:`ragged_paged_attention_pallas_sharded`
@@ -36,7 +41,7 @@ None)``). Each shard walks its OWN head slice of the page pool with the
 same replicated block metadata; head-sharded GQA needs no cross-shard
 softmax, because every query head's softmax completes inside the shard
 that owns its KV-head group. Routing between the two entry points (and
-the XLA twin) lives in ``ops/attention.py:resolve_ragged_impl``.
+the XLA twin) lives in ``ops/attention.py:ragged_paged_attention``.
 """
 
 from __future__ import annotations
@@ -47,6 +52,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
+
+from .decode import check_kernel_shape, fuse_pages, shard_over_tp
 
 NEG_INF = -1e30
 
@@ -56,13 +64,13 @@ def _ragged_kernel(
     meta_ref,  # [num_blocks, 3] SMEM — (row_slot, pos0, nvalid) per block
     page_table_ref,  # [rows, pages_per_seq] SMEM
     # inputs
-    q_ref,  # [block_rows, heads, head_dim] VMEM
-    k_hbm,  # [num_pages, page_size, kv_heads, head_dim] HBM/ANY
+    q_ref,  # [heads, block_rows, head_dim] VMEM
+    k_hbm,  # [num_pages, page_size, kv_heads * head_dim] HBM/ANY
     v_hbm,  # same
     # output
-    o_ref,  # [block_rows, heads, head_dim] VMEM
+    o_ref,  # [heads, block_rows, head_dim] VMEM
     # scratch
-    k_buf,  # [2, page_size, kv_heads, head_dim] VMEM
+    k_buf,  # [2, page_size, kv_heads * head_dim] VMEM
     v_buf,  # same
     sems,  # DMA sems [2, 2]
     *,
@@ -94,7 +102,7 @@ def _ragged_kernel(
         page_dma(k_buf, k_hbm, 0, 0, 0).start()
         page_dma(v_buf, v_hbm, 0, 0, 1).start()
 
-    q = q_ref[...].astype(jnp.float32) * (head_dim**-0.5)  # [B, heads, d]
+    scale = head_dim**-0.5
     row = jax.lax.broadcasted_iota(jnp.int32, (block_rows, 1), 0)
     q_pos = pos0 + row  # [B, 1] absolute position per row
     row_valid = row < nvalid  # [B, 1]
@@ -127,11 +135,12 @@ def _ragged_kernel(
         new_ls = list(ls)
         new_accs = list(accs)
         for g in range(num_kv_heads):
-            kg = k_buf[buf_slot, :, g, :].astype(jnp.float32)  # [page, d]
-            vg = v_buf[buf_slot, :, g, :].astype(jnp.float32)
+            lanes = pl.ds(g * head_dim, head_dim)
+            kg = k_buf[buf_slot, :, lanes].astype(jnp.float32)  # [page, d]
+            vg = v_buf[buf_slot, :, lanes].astype(jnp.float32)
             for j in range(group):
                 h = g * group + j
-                qh = q[:, h, :]  # [B, d]
+                qh = q_ref[h].astype(jnp.float32) * scale  # [B, d]
                 logits = jax.lax.dot_general(
                     qh, kg, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32,
@@ -168,7 +177,7 @@ def _ragged_kernel(
     for h in range(num_heads):
         l = ls[h]
         out = jnp.where(l > 0, accs[h] / jnp.where(l > 0, l, 1.0), 0.0)
-        o_ref[:, h, :] = out.astype(o_ref.dtype)
+        o_ref[h] = out.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
@@ -189,6 +198,8 @@ def ragged_paged_attention_pallas(
             f"tokens ({tokens}) must be a multiple of block_rows "
             f"({block_rows}) — the engine pads the packed buffer"
         )
+    if not interpret:  # the interpreter has no tiling to satisfy
+        check_kernel_shape(num_kv_heads, head_dim)
     nb = tokens // block_rows
 
     # Per-block metadata from the per-row arrays, relying on the packing
@@ -210,35 +221,42 @@ def ragged_paged_attention_pallas(
         num_kv_heads=num_kv_heads,
         head_dim=head_dim,
     )
+    fused = num_kv_heads * head_dim
+    block_spec = pl.BlockSpec(
+        (num_heads, block_rows, head_dim),
+        lambda i, *_: (0, i, 0),
+        memory_space=pltpu.VMEM,
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(nb,),
         in_specs=[
-            pl.BlockSpec(
-                (block_rows, num_heads, head_dim),
-                lambda i, *_: (i, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
+            block_spec,
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec(
-            (block_rows, num_heads, head_dim),
-            lambda i, *_: (i, 0, 0),
-            memory_space=pltpu.VMEM,
-        ),
+        out_specs=block_spec,
         scratch_shapes=[
-            pltpu.VMEM((2, page_size, num_kv_heads, head_dim), k_pages.dtype),
-            pltpu.VMEM((2, page_size, num_kv_heads, head_dim), v_pages.dtype),
+            pltpu.VMEM((2, page_size, fused), k_pages.dtype),
+            pltpu.VMEM((2, page_size, fused), v_pages.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )
-    return pl.pallas_call(
+    qt = q.transpose(1, 0, 2)  # head-major: [heads, tokens, head_dim]
+    out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
-    )(meta, page_table.astype(jnp.int32), q, k_pages, v_pages)
+        name="ragged_paged_attention",
+    )(
+        meta,
+        page_table.astype(jnp.int32),
+        qt,
+        fuse_pages(k_pages),
+        fuse_pages(v_pages),
+    )
+    return out.transpose(1, 0, 2)
 
 
 def ragged_paged_attention_pallas_sharded(
@@ -252,19 +270,11 @@ def ragged_paged_attention_pallas_sharded(
     block_rows: int = 8,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """The kernel above on a tp mesh: ``shard_map`` over the ``tp`` axis.
-
-    Query heads, KV heads, and the page pool's kv_heads axis are all
-    sharded over ``tp`` (the engine's serving placement), so each shard
-    runs the unmodified single-device kernel over its own head slice of
-    the pool; the page table and the per-row (slot, position) metadata
+    """The kernel above on a tp mesh (``decode.shard_over_tp``): each
+    shard runs the unmodified single-device kernel over its own head slice
+    of the pool; the page table and the per-row (slot, position) metadata
     are replicated, and the per-block scalar-prefetch metadata is
-    recomputed identically on every shard. No cross-shard collective
-    runs inside the attention: with heads grouped to their KV head
-    (GQA), every softmax is complete within one shard — the reason a
-    head-sharded port needs no distributed online-softmax. Requires
-    ``num_kv_heads % tp == 0`` (the same divisibility the NamedSharding
-    placement already enforces).
+    recomputed identically on every shard.
 
     Composes with jit: the mixed program calls this inside its traced
     body and GSPMD reshards inputs to the declared specs (a no-op for
@@ -272,18 +282,14 @@ def ragged_paged_attention_pallas_sharded(
     per-shard kernel in interpreter mode — how CPU tp-meshes validate
     bit-exactness against the XLA twin (tests/test_ragged.py).
     """
-    from jax.sharding import PartitionSpec as P
-
-    from ...utils.compat import shard_map
-
     kernel = functools.partial(
         ragged_paged_attention_pallas,
         block_rows=block_rows,
         interpret=interpret,
     )
-    return shard_map(
+    return shard_over_tp(
+        mesh,
         kernel,
-        mesh=mesh,
         in_specs=(
             P(None, "tp", None),  # q: query heads sharded
             P(None, None, "tp", None),  # k_pages: kv heads sharded
@@ -293,7 +299,4 @@ def ragged_paged_attention_pallas_sharded(
             P(None),  # positions: replicated
         ),
         out_specs=P(None, "tp", None),
-        # the pallas body is opaque to the replication checker; the
-        # out_specs above are the contract the caller relies on
-        check_rep=False,
     )(q, k_pages, v_pages, page_table, row_slot, positions)

@@ -19,8 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..utils.compat import shard_map
-
 NEG_INF = -1e30
 
 
@@ -109,10 +107,10 @@ def ring_prefill_attention(
         return out.astype(q.dtype)
 
     seq = P(None, axis_name, None, None)
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(seq, seq, seq, P()),
         out_specs=seq,
-        check_rep=False,
+        check_vma=False,
     )(q, k, v, seq_lens)

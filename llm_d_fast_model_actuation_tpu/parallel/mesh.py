@@ -65,14 +65,13 @@ def make_mesh(
         raise ValueError(f"mesh plan {plan} needs {plan.size} devices, have {n}")
     shape = plan.axis_sizes()
     if devices[0].platform == "tpu":
-        try:
-            from jax.experimental import mesh_utils
+        # a topology mesh_utils cannot lay out raises: flat order on a real
+        # slice would silently put tp neighbours on non-adjacent chips
+        from jax.experimental import mesh_utils
 
-            dev_array = mesh_utils.create_device_mesh(shape, devices=list(devices))
-            return Mesh(dev_array, AXES)
-        except Exception:
-            pass  # fall back to flat ordering (e.g. odd topologies)
-    dev_array = np.asarray(list(devices)).reshape(shape)
+        dev_array = mesh_utils.create_device_mesh(shape, devices=list(devices))
+    else:
+        dev_array = np.asarray(list(devices)).reshape(shape)
     return Mesh(dev_array, AXES)
 
 
@@ -119,17 +118,20 @@ def named_sharding(
     return NamedSharding(mesh, spec_for(logical_axes, rules))
 
 
+def logical_shardings(mesh: Mesh, axes_tree: Any, rules=None) -> Any:
+    """Pytree of NamedShardings on `mesh` for a pytree of logical axis
+    tuples (None leaf = fully replicated)."""
+    return jax.tree.map(
+        lambda axes: named_sharding(mesh, axes or (), rules),
+        axes_tree,
+        is_leaf=lambda x: x is None or isinstance(x, tuple),
+    )
+
+
 def shard_pytree(tree: Any, mesh: Mesh, axes_tree: Any, rules=None) -> Any:
     """`jax.device_put` a pytree onto `mesh` per a matching pytree of logical
     axis tuples (None leaf = fully replicated)."""
-    def put(x, axes):
-        if axes is None:
-            sh = NamedSharding(mesh, P())
-        else:
-            sh = named_sharding(mesh, axes, rules)
-        return jax.device_put(x, sh)
-
-    return jax.tree.map(put, tree, axes_tree, is_leaf=lambda x: x is None)
+    return jax.device_put(tree, logical_shardings(mesh, axes_tree, rules))
 
 
 def serving_mesh(tp: int, devices: Optional[Sequence[jax.Device]] = None) -> Mesh:

@@ -67,7 +67,10 @@ class HostTopology:
         The TPU analogue of the reference's CUDA_VISIBLE_DEVICES injection
         (inference-server.go:1916-1923). Also sets process/chip bounds so
         multiple engine processes can share one host without the device
-        plugin arbitrating.
+        plugin arbitrating, and lifts libtpu's one-process-per-host
+        lockfile (/tmp/libtpu_lockfile, held until process exit — also
+        across a device release): the launcher's ChipLedger is what keeps
+        two awake engines off one chip. Checked on libtpu 0.0.34, v5e.
         """
         by_id = self.by_id()
         chips = [by_id[cid] for cid in chip_ids]
@@ -75,6 +78,7 @@ class HostTopology:
             "TPU_VISIBLE_DEVICES": ",".join(
                 str(i) for i in sorted(c.index for c in chips)
             ),
+            "ALLOW_MULTIPLE_LIBTPU_LOAD": "1",
             "TPU_PROCESS_BOUNDS": "1,1,1",
             "TPU_CHIPS_PER_PROCESS_BOUNDS": _chips_bounds(
                 [c.coords for c in chips], self.topology.dims

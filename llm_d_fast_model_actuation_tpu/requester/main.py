@@ -78,9 +78,9 @@ def resolve_chips(args: argparse.Namespace, should_stop=None):
         want = {int(i) for i in visible.split(",")}
         return [c.chip_id for c in host.chips if c.index in want], None
     # real
-    from ..launcher.chiptranslator import _enumerate_real
+    from ..launcher.chiptranslator import enumerate_real
 
-    return [c.chip_id for c in _enumerate_real().chips], None
+    return [c.chip_id for c in enumerate_real()[0].chips], None
 
 
 def memory_backend(args: argparse.Namespace, chip_ids: List[str]):

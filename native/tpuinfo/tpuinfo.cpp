@@ -15,14 +15,20 @@
 //   {"chips": [{"chip_id": str, "index": int, "pci_addr": str,
 //               "coords": [x,y,z], "total_hbm_bytes": int,
 //               "hbm_used_bytes": int}...],
-//    "topology": "2x4" | "" , "source": "pci"|"devfs"|"mock"}
+//    "topology": "2x4" | "" , "source": "pci"|"pci+vfio"|"devfs"|"mock"}
 //
 // Enumeration sources, highest priority first:
 //   1. mock: FMA_TPUINFO_MOCK_JSON (verbatim document) or
 //      FMA_TPUINFO_MOCK_COUNT=N (synthesized chips) — the hardware-free
 //      test path;
 //   2. PCI sysfs: /sys/bus/pci/devices/*/vendor == 0x1ae0 (Google). The
-//      device id keys a generation table for total HBM;
+//      device id keys a generation table for total HBM. sysfs lists every
+//      function of the machine, also inside a container that was handed
+//      only some of them: Cloud TPU v5e hosts bind chips to vfio-pci and a
+//      process opens a chip through /dev/vfio/<iommu group>. Where
+//      /dev/vfio exists, only chips whose group node exists are reported
+//      (source "pci+vfio") — the set, and the order, libtpu indexes with
+//      TPU_VISIBLE_DEVICES;
 //   3. devfs: /dev/accel<N> nodes (one per chip on Cloud TPU VMs).
 //
 // HBM usage: the TPU runtime does not expose per-process device memory to
@@ -131,16 +137,43 @@ uint64_t usage_for_chip(const std::string& usage_dir, const std::string& chip_id
 
 // --- enumeration sources -------------------------------------------------
 
-std::vector<Chip> enumerate_pci(std::string* topo) {
+std::string dev_root() {
+  std::string dev = getenv_str("FMA_TPUINFO_DEV_ROOT");
+  return dev.empty() ? "/dev" : dev;
+}
+
+bool path_exists(const std::string& path) {
+  struct stat st;
+  return ::stat(path.c_str(), &st) == 0;
+}
+
+// The iommu group of a PCI function ("" when it has none): the basename of
+// the .../iommu_group symlink.
+std::string iommu_group(const std::string& pci_dir) {
+  char buf[512];
+  ssize_t n = ::readlink((pci_dir + "/iommu_group").c_str(), buf, sizeof(buf) - 1);
+  if (n <= 0) return "";
+  std::string target(buf, static_cast<size_t>(n));
+  size_t slash = target.find_last_of('/');
+  return slash == std::string::npos ? target : target.substr(slash + 1);
+}
+
+std::vector<Chip> enumerate_pci(bool* vfio_filtered) {
   std::vector<Chip> chips;
   const std::string root =
       getenv_str("FMA_TPUINFO_SYSFS_ROOT").empty()
           ? "/sys/bus/pci/devices"
           : getenv_str("FMA_TPUINFO_SYSFS_ROOT");
+  const std::string vfio = dev_root() + "/vfio";
+  *vfio_filtered = path_exists(vfio);
   for (const auto& addr : list_dir(root)) {
     std::string vendor;
     if (!read_file(root + "/" + addr + "/vendor", &vendor)) continue;
     if (parse_u64(vendor) != 0x1ae0) continue;  // Google
+    if (*vfio_filtered) {
+      std::string group = iommu_group(root + "/" + addr);
+      if (group.empty() || !path_exists(vfio + "/" + group)) continue;
+    }
     std::string device;
     read_file(root + "/" + addr + "/device", &device);
     const Gen* g = gen_for(static_cast<uint16_t>(parse_u64(device)));
@@ -150,15 +183,12 @@ std::vector<Chip> enumerate_pci(std::string* topo) {
     c.chip_id = std::string("tpu-") + (g ? g->name : "unknown") + "-" + addr;
     chips.push_back(std::move(c));
   }
-  (void)topo;
   return chips;
 }
 
 std::vector<Chip> enumerate_devfs() {
   std::vector<Chip> chips;
-  const std::string dev =
-      getenv_str("FMA_TPUINFO_DEV_ROOT").empty() ? "/dev"
-                                                 : getenv_str("FMA_TPUINFO_DEV_ROOT");
+  const std::string dev = dev_root();
   std::vector<int> ids;
   for (const auto& name : list_dir(dev)) {
     if (name.rfind("accel", 0) == 0 && name.size() > 5 &&
@@ -276,7 +306,9 @@ const char* tpuinfo_query(void) {
     chips = enumerate_mock(::atoi(mock_count.c_str()));
     source = "mock";
   } else {
-    chips = enumerate_pci(&topo);
+    bool vfio_filtered = false;
+    chips = enumerate_pci(&vfio_filtered);
+    if (vfio_filtered) source = "pci+vfio";
     if (chips.empty()) {
       chips = enumerate_devfs();
       source = "devfs";

@@ -1,0 +1,341 @@
+"""AOT compiles of the Pallas kernels for a described (not attached) TPU v5e.
+
+The chip's compiler is installed in the CPU sandbox and compiles for a
+topology description, so what Mosaic would refuse on the chip (tiling,
+layout, VMEM) is refused here, at real head shapes, at no chip time —
+interpret mode hides all of it. Nothing runs: these tests say nothing about
+results or times. Skipped where the topology cannot be described.
+"""
+
+import json
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from llm_d_fast_model_actuation_tpu.ops import pallas as kernels
+
+#: (heads, kv_heads, head_dim): TinyLlama, Llama-3-8B, Gemma-3-4B widths
+HEAD_SHAPES = [(32, 4, 64), (32, 8, 128), (8, 4, 256)]
+PAGE, NUM_PAGES, BATCH, PAGES_PER_SEQ = 16, 512, 8, 128
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this install
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
+
+
+@pytest.fixture(autouse=True)
+def no_compile_cache():
+    # an AOT compile for a described chip is written to the persistent
+    # cache but cannot be read back without the chip (it warns and
+    # recompiles), so keep the cache out of these tests
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *args, **kw):
+    text = jax.jit(lambda *a: fn(*a, **kw)).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+def _kernel_args(kind, h, kvh, d, sharding):
+    def s(shape, dtype=jnp.bfloat16, sh=sharding):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    pages = s((NUM_PAGES, PAGE, kvh, d))
+    table = s((BATCH, PAGES_PER_SEQ), jnp.int32)
+    lens = s((BATCH,), jnp.int32)
+    if kind == "decode_inline":
+        new = s((BATCH, kvh, d))
+        return (s((BATCH, h, d)), pages, pages, new, new, table, lens)
+    if kind == "decode":
+        return (s((BATCH, h, d)), pages, pages, table, lens)
+    if kind == "ragged":
+        rows = s((256,), jnp.int32)
+        return (s((256, h, d)), pages, pages, table, rows, rows)
+    assert kind == "prefill"
+    kv = s((2, 512, kvh, d))
+    return (s((2, 512, h, d)), kv, kv, s((2,), jnp.int32))
+
+
+KERNELS = {
+    "decode_inline": kernels.paged_decode_attention_inline_pallas,
+    "decode": kernels.paged_decode_attention_pallas,
+    "ragged": kernels.ragged_paged_attention_pallas,
+    "prefill": kernels.causal_prefill_attention_pallas,
+}
+
+
+@pytest.mark.parametrize("shape", HEAD_SHAPES, ids=lambda s: "x".join(map(str, s)))
+@pytest.mark.parametrize("kind", list(KERNELS))
+def test_kernel_compiles_for_v5e(topo, kind, shape):
+    one = SingleDeviceSharding(topo.devices[0])
+    _compile(KERNELS[kind], *_kernel_args(kind, *shape, one))
+
+
+def _tp_mesh(topo, tp=4):
+    import numpy as np
+
+    from llm_d_fast_model_actuation_tpu.parallel.mesh import AXES
+
+    return Mesh(np.array(topo.devices[:tp]).reshape(1, 1, 1, tp, 1), AXES)
+
+
+@pytest.mark.parametrize("shape", [(32, 8, 128), (32, 8, 64)],
+                         ids=lambda s: "x".join(map(str, s)))
+@pytest.mark.parametrize("kind", ["decode_inline", "ragged", "prefill"])
+def test_sharded_kernel_compiles_for_v5e_2x2(topo, kind, shape):
+    """The shard_map ports on a 4-device tp mesh of described chips — per
+    shard 8 query heads over 2 KV heads (Llama-3-8B at tp=4) — with the
+    operand specs the serving dispatcher uses (ops/attention.py)."""
+    from llm_d_fast_model_actuation_tpu.ops import attention as attn
+    from llm_d_fast_model_actuation_tpu.ops.pallas.decode import shard_over_tp
+
+    mesh = _tp_mesh(topo)
+    table, lens = P(None, None), P(None)
+    in_specs = {
+        "decode_inline": (attn._HEADS3, attn._HEADS4, attn._HEADS4,
+                          attn._HEADS3, attn._HEADS3, table, lens),
+        "ragged": (attn._HEADS3, attn._HEADS4, attn._HEADS4, table, lens,
+                   lens),
+        "prefill": (attn._HEADS4, attn._HEADS4, attn._HEADS4, lens),
+    }[kind]
+    out_spec = attn._HEADS4 if kind == "prefill" else attn._HEADS3
+    args = [
+        jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=NamedSharding(mesh, spec)
+        )
+        for a, spec in zip(_kernel_args(kind, *shape, None), in_specs)
+    ]
+    _compile(shard_over_tp(mesh, KERNELS[kind], in_specs, out_spec), *args)
+
+
+@pytest.mark.parametrize("program,bucket", [("chunk", 4), ("prefill", 16)])
+def test_sharded_engine_program_compiles_for_v5e_2x2(topo, program, bucket):
+    """A whole serving program of a tp=4 engine under ``pallas``: GSPMD
+    cannot partition a Mosaic kernel, so any kernel the program reaches
+    outside a shard_map fails here (and only here: interpret mode lowers
+    to plain XLA ops, which partition fine)."""
+    from llm_d_fast_model_actuation_tpu.engine import EngineConfig, exec_pool
+    from llm_d_fast_model_actuation_tpu.models import llama
+
+    model = llama.LlamaConfig(
+        vocab_size=512, hidden_size=256, num_layers=2, num_heads=8,
+        num_kv_heads=4, head_dim=128, intermediate_size=512,
+        max_seq_len=256, attention_impl="pallas",
+    )
+    cfg = EngineConfig(
+        model=model, max_batch=4, num_pages=64, attention_impl="pallas",
+        decode_chunk=4,
+    )
+    from llm_d_fast_model_actuation_tpu.ops import attention as attn
+
+    attn.set_pallas_interpret(False)  # compile the kernels for the chip
+    try:
+        text = exec_pool.compile_program(
+            cfg, program, bucket, mesh=_tp_mesh(topo)
+        ).as_text()
+    finally:
+        attn.set_pallas_interpret(True)
+    assert "tpu_custom_call" in text
+
+
+def test_lane_constraint_is_named_not_a_mosaic_crash(topo):
+    """A per-device KV row narrower than the 128-lane tile is refused with
+    the constraint spelled out (TinyLlama at tp=4: one 64-wide KV head)."""
+    one = SingleDeviceSharding(topo.devices[0])
+    with pytest.raises(ValueError, match="multiple of 128"):
+        _compile(KERNELS["decode"], *_kernel_args("decode", 8, 1, 64, one))
+
+
+# -- CPU rehearsal of chip_smoke.py ---------------------------------------------
+#
+# The smoke's phase functions at tiny size, with the device they must find
+# injected here (``platform="cpu"``; Pallas in interpreter mode, the launcher
+# on one mock chip): wrong paths, arguments and control flow are found without
+# the chip (on-chip-measurement §2.1). What only the chip can refuse is above.
+
+
+@pytest.fixture(scope="module")
+def smoke():
+    import importlib.util
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(repo, "chip_smoke.py")
+    )
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules["chip_smoke"] = mod  # dataclasses resolves the module by name
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture(scope="module")
+def tiny_plan(smoke, tmp_path_factory):
+    smoke.LOG_DIR = str(tmp_path_factory.mktemp("chip-smoke-logs"))
+    return smoke.Plan(
+        platform="cpu",
+        model="tiny",
+        second_model="tiny-gemma",
+        engine_options="--num-pages 64 --max-batch 2 --page-size 8 "
+        "--max-model-len 128 --sleep-release-devices always",
+        launcher_args=("--mock-chips", "--mock-chip-count", "1"),
+        head_shapes=((4, 2, 16),),
+        page_size=8,
+        context=64,
+        interpret=True,
+        prompt_lens=(5, 40),
+        max_tokens=6,
+        ready_timeout_s=240.0,
+        tp_model="tiny",
+        tp=2,
+        tp_impls=("grouped", "reference"),
+    )
+
+
+def test_smoke_rehearsal_kernels(smoke, tiny_plan):
+    rows = smoke.check_kernels(tiny_plan)
+    assert [r["kernel"] for r in rows] == [
+        "decode", "decode_inline", "ragged", "prefill",
+    ]
+
+
+@pytest.fixture()
+def child_devices(monkeypatch):
+    """How many virtual CPU devices the smoke's children see (this process
+    keeps conftest's eight): the smoke holds each engine to its count."""
+
+    def set_count(n: int) -> None:
+        monkeypatch.setenv(
+            "XLA_FLAGS", f"--xla_force_host_platform_device_count={n}"
+        )
+
+    return set_count
+
+
+def test_smoke_rehearsal_server(smoke, tiny_plan, capsys, child_devices):
+    child_devices(1)
+    dev = smoke.phase_server(tiny_plan)
+    assert dev["platform"] == "cpu" and dev["count"] == 1
+    line = json.loads(capsys.readouterr().out.splitlines()[-1])
+    assert line["phase"] == "server" and line["same_tokens_after_wake"]
+
+
+def test_smoke_rehearsal_launcher_time_share(
+    smoke, tiny_plan, capsys, child_devices
+):
+    child_devices(1)
+    smoke.phase_launcher(tiny_plan)
+    line = json.loads(capsys.readouterr().out.splitlines()[-1])
+    assert line["release_in_process"] and line["same_tokens_after_reacquire"]
+    assert line["chip_mode"] == "naive-mock"
+
+
+def test_smoke_rehearsal_sharded_phase(smoke, tiny_plan, capsys, child_devices):
+    """The --chips 4 phase on virtual devices (tiny has two KV heads: tp=2):
+    meshes, sharded init and the per-chip byte check (§2.2)."""
+    child_devices(2)
+    dev = smoke.phase_four_chips(tiny_plan)
+    assert dev["count"] == 2
+    line = json.loads(capsys.readouterr().out.splitlines()[-1])
+    assert line["phase"] == "four_chips" and line["same_tokens"]
+    assert all(c["ok"] for c in line["compared"])
+
+
+def test_impl_comparison_accepts_only_demonstrated_near_ties(smoke):
+    """Two implementations may part ways where the reference itself scores
+    the other's token within the tolerance of its own, and nowhere else."""
+    def run(tokens, lps=(-1.0, -1.0, -1.0)):
+        return {"tokens": tokens, "logprobs": list(lps)}
+
+    def never(i):
+        raise AssertionError("nothing to score when the tokens agree")
+
+    a = run([5, 6, 7])
+    same = smoke.compare_impls(a, run([5, 6, 7]), never)
+    assert same["ok"] and same["tokens_agreeing"] == 3
+    # the reference gives a's token -1.02 where its own has -1.0: a tie
+    at_tie = smoke.compare_impls(a, run([5, 9, 7]), lambda i: -1.02)
+    assert at_tie["ok"] and at_tie["tokens_agreeing"] == 1
+    assert at_tie["tie_gap"] == 0.02
+    # ... and -2.0 is a clear loser: a real disagreement
+    assert not smoke.compare_impls(a, run([8, 6, 7]), lambda i: -2.0)["ok"]
+    drift = smoke.compare_impls(a, run([5, 6, 7], (-1.5, -1.0, -1.0)), never)
+    assert not drift["ok"] and drift["max_logprob_diff"] == 0.5
+
+
+def test_smoke_refuses_a_device_it_was_not_promised(smoke, tiny_plan):
+    """No fallback: the same run held to ``tpu`` fails on this CPU."""
+    import dataclasses
+
+    with pytest.raises(RuntimeError, match="needs a tpu device"):
+        smoke.check_kernels(dataclasses.replace(tiny_plan, platform="tpu"))
+
+
+# -- the rules this bring-up made explicit --------------------------------------
+
+
+def test_auto_attention_impl_is_a_rule_on_backend_and_shape(monkeypatch):
+    from llm_d_fast_model_actuation_tpu.engine import engine as eng
+    from llm_d_fast_model_actuation_tpu.engine.server import MODEL_CONFIGS
+    from llm_d_fast_model_actuation_tpu.models import llama
+
+    tinyllama = MODEL_CONFIGS["tinyllama-1.1b"]()  # 4 KV heads of 64
+    assert eng.resolve_attention_impl("auto", tinyllama) == "grouped"  # cpu
+    monkeypatch.setattr(eng.jax, "default_backend", lambda: "tpu")
+    assert eng.resolve_attention_impl("auto", tinyllama) == "pallas"
+    assert eng.resolve_attention_impl("auto", tinyllama, tp=2) == "pallas"
+    # one 64-wide KV head per device does not fill the 128 lanes
+    assert eng.resolve_attention_impl("auto", tinyllama, tp=4) == "grouped"
+    assert eng.resolve_attention_impl("auto", llama.LlamaConfig.tiny()) == "grouped"
+    assert eng.resolve_attention_impl("reference", tinyllama, tp=4) == "reference"
+
+
+def test_compile_cache_has_one_rule(monkeypatch, tmp_path):
+    from llm_d_fast_model_actuation_tpu.utils import compile_cache as cc
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for var in (cc.ENV, "FMA_EXEC_SPILL_DIR", "JAX_PLATFORMS",
+                "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"):
+        monkeypatch.delenv(var, raising=False)
+    was = (jax.config.jax_compilation_cache_dir,
+           jax.config.jax_persistent_cache_min_compile_time_secs)
+    try:
+        # set from outside: that directory, and the spill follows it
+        monkeypatch.setenv(cc.ENV, str(tmp_path / "x"))
+        assert cc.arm() == str(tmp_path / "x")
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path / "x")
+        assert os.environ["FMA_EXEC_SPILL_DIR"] == str(tmp_path / "x" / "exec-pool")
+        assert cc.stats()["dir"] == str(tmp_path / "x")
+        # unset, held to the CPU: nothing is armed
+        monkeypatch.delenv(cc.ENV)
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+        assert cc.arm() == "" and cc.ENV not in os.environ
+        # unset, a chip expected: one fixed path inside the checkout,
+        # exported for children
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+        assert cc.arm() == os.path.join(repo, ".xla-cache") == os.environ[cc.ENV]
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was[0])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", was[1])
+        compilation_cache.reset_cache()

@@ -106,7 +106,7 @@ def test_tpuinfo_table_cli_output_parses():
         ],
     }
     buf = io.StringIO()
-    with mock.patch.object(tpuinfo, "_query", return_value=fake):
+    with mock.patch.object(tpuinfo, "query", return_value=fake):
         with mock.patch.object(sys, "stdout", buf):
             tpuinfo.main(["--table"])
     parsed = ChipMap.parse({"local": buf.getvalue()})
@@ -156,7 +156,7 @@ def test_tpuinfo_table_emits_multihost_identity(monkeypatch, capsys):
     from llm_d_fast_model_actuation_tpu.native import tpuinfo
 
     monkeypatch.setattr(
-        tpuinfo, "_query",
+        tpuinfo, "query",
         lambda: {
             "topology": "2x4",
             "chips": [

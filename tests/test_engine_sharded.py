@@ -8,15 +8,6 @@ from llm_d_fast_model_actuation_tpu.engine import EngineConfig, InferenceEngine
 from llm_d_fast_model_actuation_tpu.engine.sleep import attach_sleep
 from llm_d_fast_model_actuation_tpu.models import llama
 from llm_d_fast_model_actuation_tpu.parallel.mesh import MeshPlan, make_mesh
-from llm_d_fast_model_actuation_tpu.utils.compat import (
-    pallas_interpret_supported,
-)
-
-needs_pallas = pytest.mark.skipif(
-    not pallas_interpret_supported(),
-    reason="this jaxlib cannot run Pallas interpret mode on CPU",
-)
-
 
 @pytest.fixture(scope="module")
 def tp2_mesh(devices8):
@@ -82,8 +73,8 @@ def test_pipeline_decode_matches_on_tp_mesh(tp2_mesh):
 # -- token-packed (mixed-batch) serving on a sharded mesh ---------------------
 #
 # --packed-serving composes with --tensor-parallel-size: the mixed
-# program's ragged attention routes per the device-kind x mesh x impl
-# matrix (ops/attention.py:resolve_ragged_impl — the Pallas kernel's
+# program's ragged attention routes by impl and mesh
+# (ops/attention.py:ragged_paged_attention — the Pallas kernel's
 # shard_map port for pallas engines, the GSPMD-partitioned XLA twin
 # otherwise) and the device-resident scheduler state — counts/bias
 # maintained by the program, page table sliced in-program — works
@@ -111,7 +102,6 @@ def test_packed_matches_bucketed_on_tp_mesh(tp2_mesh):
 
 
 @pytest.mark.ragged
-@needs_pallas
 def test_packed_pallas_shard_map_matches_bucketed_on_tp_mesh(tp2_mesh):
     """The shard_map ragged kernel through the full engine: a pallas
     packed engine on a 2-device CPU mesh (interpret mode) must generate
@@ -133,7 +123,7 @@ def test_packed_pallas_shard_map_matches_bucketed_on_tp_mesh(tp2_mesh):
     eng = make_engine(
         tp2_mesh, packed_serving=True, attention_impl="pallas"
     )
-    assert eng.programs.mixed_impl == "pallas"
+    assert eng.attention_impl == "pallas"
     assert eng._pack_align == RAGGED_BLOCK
     got = eng.generate(MIXED_PROMPTS, max_new_tokens=6)
     assert got == gold

@@ -472,3 +472,24 @@ def test_rest_watch_410(translator, tmp_path):
         run_async(_with_client(manager, scenario))
     finally:
         manager.stop_all_instances(timeout=2)
+
+
+def test_forked_child_applies_per_instance_jax_env():
+    """jax read JAX_* when the launcher imported it, before the fork: the
+    child must point the imported module at its instance's values, or a
+    child told JAX_PLATFORMS=cpu takes the chip."""
+    import jax
+
+    from llm_d_fast_model_actuation_tpu.launcher.instance import _apply_jax_env
+
+    was = (jax.config.jax_platforms, jax.config.jax_log_compiles)
+    try:
+        _apply_jax_env(
+            {"JAX_PLATFORMS": "cpu", "JAX_LOG_COMPILES": "1",
+             "TPU_VISIBLE_DEVICES": "0", "JAX_NOT_AN_OPTION": "x"}
+        )
+        assert jax.config.jax_platforms == "cpu"
+        assert jax.config.jax_log_compiles is True
+    finally:
+        jax.config.update("jax_platforms", was[0])
+        jax.config.update("jax_log_compiles", was[1])

@@ -52,9 +52,6 @@ def test_shard_pytree(devices8):
 
 def test_collective_under_mesh(devices8):
     # psum over tp via shard_map compiles and runs on the virtual mesh
-    # (utils/compat.py: jax.shard_map vs jax.experimental.shard_map drift)
-    from llm_d_fast_model_actuation_tpu.utils.compat import shard_map
-
     mesh = make_mesh(MeshPlan(dp=2, tp=4), devices8)
     x = jnp.arange(8.0).reshape(2, 4)
     xs = jax.device_put(x, named_sharding(mesh, ("batch", "heads")))
@@ -63,7 +60,7 @@ def test_collective_under_mesh(devices8):
         return jax.lax.psum(block, axis_name="tp")
 
     out = jax.jit(
-        shard_map(
+        jax.shard_map(
             f,
             mesh=mesh,
             in_specs=(P("dp", "tp"),),

@@ -101,6 +101,34 @@ def test_pci_enumeration(tpuinfo, monkeypatch, tmp_path):
     assert by_id["tpu-v5e-0000:00:01.0"]["pci_addr"] == "0000:00:01.0"
 
 
+def test_pci_enumeration_keeps_only_openable_vfio_groups(
+    tpuinfo, monkeypatch, tmp_path
+):
+    """A Cloud TPU v5e host as a container sees it (checked on the chip
+    machine, PR 21): sysfs lists all four chips, /dev/vfio holds the group
+    of the one this container may open — and no /dev/accel*."""
+    sysfs, dev = tmp_path / "sys", tmp_path / "dev"
+    groups = tmp_path / "iommu_groups"
+    for i, addr in enumerate(["0000:00:08.0", "0000:00:09.0", "0000:00:0a.0"]):
+        d = sysfs / addr
+        d.mkdir(parents=True)
+        (d / "vendor").write_text("0x1ae0\n")
+        (d / "device").write_text("0x0063\n")
+        (groups / str(i)).mkdir(parents=True)
+        (d / "iommu_group").symlink_to(groups / str(i))
+    (dev / "vfio").mkdir(parents=True)
+    (dev / "vfio" / "vfio").touch()  # the container node, not a group
+    (dev / "vfio" / "1").touch()
+    monkeypatch.setenv("FMA_TPUINFO_SYSFS_ROOT", str(sysfs))
+    monkeypatch.setenv("FMA_TPUINFO_DEV_ROOT", str(dev))
+    doc = tpuinfo.query()
+    assert doc["source"] == "pci+vfio"
+    assert [(c["chip_id"], c["index"]) for c in doc["chips"]] == [
+        ("tpu-v5e-0000:00:09.0", 0)
+    ]
+    assert doc["topology"] == "1"
+
+
 def test_cooperative_hbm_usage(tpuinfo, monkeypatch, tmp_path):
     """Publisher writes per-pid files; shim sums live writers, prunes dead."""
     from llm_d_fast_model_actuation_tpu.native.hbm_publisher import (

@@ -15,17 +15,6 @@ from llm_d_fast_model_actuation_tpu.ops.pallas import (
     causal_prefill_attention_pallas,
     paged_decode_attention_pallas,
 )
-from llm_d_fast_model_actuation_tpu.utils.compat import (
-    pallas_interpret_supported,
-)
-
-# capability probe (utils/compat.py): some jax/jaxlib pairs cannot lower
-# even interpret-mode pallas_call on the CPU backend — skip, don't fail
-pytestmark = pytest.mark.skipif(
-    not pallas_interpret_supported(),
-    reason="this jaxlib cannot run Pallas interpret mode on CPU",
-)
-
 
 def _rand(key, shape, dtype=jnp.float32):
     return jax.random.normal(key, shape, dtype=dtype)
@@ -220,3 +209,45 @@ def test_inline_decode_matches_scatter_then_attend(
     np.testing.assert_allclose(
         np.asarray(got_pallas), np.asarray(want), atol=2e-5, rtol=2e-5
     )
+
+
+@pytest.mark.parametrize("kind", ["decode", "decode_inline", "prefill"])
+def test_kernels_over_a_tp_mesh_match_reference(devices8, kind):
+    """The shard_map ports of the bucketed path's kernels (a tp engine's
+    decode chunk and prefill reach them through the dispatcher with a
+    mesh): each shard attends its own head slice; results equal the
+    unsharded reference, and a non-dividing prefill length is padded."""
+    from llm_d_fast_model_actuation_tpu.parallel.mesh import MeshPlan, make_mesh
+
+    mesh = make_mesh(MeshPlan(tp=2), devices8[:2])
+    batch, heads, kvh, d, ps, pps = 3, 8, 4, 32, 8, 3
+    ks = jax.random.split(jax.random.key(5), 6)
+    k_pages = _rand(ks[0], (batch * pps + 1, ps, kvh, d))
+    v_pages = _rand(ks[1], (batch * pps + 1, ps, kvh, d))
+    pt = jnp.asarray(
+        np.arange(1, 1 + batch * pps, dtype=np.int32).reshape(batch, pps)
+    )
+    lens = jnp.asarray([ps * pps, ps, 3], jnp.int32)
+    if kind == "decode":
+        fn = attn.paged_decode_attention
+        args = (_rand(ks[2], (batch, heads, d)), k_pages, v_pages, pt, lens)
+    elif kind == "decode_inline":
+        fn = attn.paged_decode_attention_inline
+        args = (
+            _rand(ks[2], (batch, heads, d)), k_pages, v_pages,
+            _rand(ks[3], (batch, kvh, d)), _rand(ks[4], (batch, kvh, d)),
+            pt, lens - 1,
+        )
+    else:
+        fn = attn.causal_prefill_attention
+        seq = 20  # not a multiple of the kernel block: padded, then sliced
+        args = (
+            _rand(ks[2], (2, seq, heads, d)), _rand(ks[3], (2, seq, kvh, d)),
+            _rand(ks[4], (2, seq, kvh, d)), jnp.asarray([seq, 7], jnp.int32),
+        )
+    got = jax.jit(lambda *a: fn(*a, impl="pallas", mesh=mesh))(*args)
+    want = fn(*args, impl="reference")
+    got, want = np.array(got), np.array(want)
+    if kind == "prefill":  # rows past seq_len are garbage by contract
+        got[1, 7:] = want[1, 7:] = 0
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)

@@ -201,7 +201,7 @@ def test_quantized_release_sleep_round_trip():
     client teardown, wake dequantizes on the fresh client."""
     m, box = _mgr(_params(3), kv_seed=2, quant_mode="int8")
     info = m.sleep(1, release=True)
-    assert info["devices_released"] and info["quant"] == "int8"
+    assert m._released and info["quant"] == "int8"
     m.wake_up()
     first = _leaves(box["state"])
     m.sleep(1, release=True)

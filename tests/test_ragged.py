@@ -7,8 +7,8 @@ retire edges; sampled outputs carry a logprob tolerance (the mixed
 program's attention reduces in a different order than the per-bucket
 programs, so logits differ at the last ulp and draws can flip at
 near-ties — the same caveat as speculative decoding). The Pallas ragged
-kernel must agree with the XLA reference twin wherever the backend can
-run it (interpreter mode on CPU, capability-probed).
+kernel must agree with the XLA reference twin (interpreter mode on CPU,
+turned on by tests/conftest.py).
 """
 
 import jax
@@ -20,9 +20,6 @@ from llm_d_fast_model_actuation_tpu.engine import EngineConfig, InferenceEngine
 from llm_d_fast_model_actuation_tpu.engine import exec_pool
 from llm_d_fast_model_actuation_tpu.models import llama
 from llm_d_fast_model_actuation_tpu.ops import attention as attn
-from llm_d_fast_model_actuation_tpu.utils.compat import (
-    pallas_interpret_supported,
-)
 
 pytestmark = pytest.mark.ragged
 
@@ -33,12 +30,6 @@ PROMPTS = [
     [4] * 16,  # exactly two pages at page_size 8 (page-boundary length)
     [7, 6, 5, 4, 3, 2, 1] * 3,
 ]
-
-needs_pallas = pytest.mark.skipif(
-    not pallas_interpret_supported(),
-    reason="this jaxlib cannot run Pallas interpret mode on CPU",
-)
-
 
 def _cfg(packed: bool, **kw) -> EngineConfig:
     base = dict(
@@ -118,7 +109,6 @@ def test_ragged_reference_matches_per_sequence_paths():
     )
 
 
-@needs_pallas
 @pytest.mark.parametrize(
     "heads,kv_heads,head_dim,page_size,pages_per_seq",
     [
@@ -154,7 +144,6 @@ def test_ragged_pallas_matches_reference(
     assert (np.asarray(got)[32:] == 0).all()
 
 
-@needs_pallas
 @pytest.mark.parametrize(
     "heads,kv_heads,head_dim,page_size,pages_per_seq",
     [
@@ -194,7 +183,7 @@ def test_ragged_pallas_sharded_matches_twin_tp2(
         mesh, qs, kps, vps, pt, row_slot, positions,
         block_rows=B, interpret=True,
     )
-    assert got.sharding.spec == P(None, "tp")  # heads stay sharded
+    assert got.sharding.spec == P(None, "tp", None)  # heads stay sharded
     valid = np.asarray(row_slot) >= 0
     np.testing.assert_allclose(
         np.asarray(got)[valid], np.asarray(want)[valid],
@@ -210,26 +199,6 @@ def test_ragged_pallas_sharded_matches_twin_tp2(
     )
 
 
-def test_resolve_ragged_impl_routing_matrix():
-    """The one-place routing decision (device kind x mesh x impl flag):
-    non-pallas impls pass through everywhere; pallas keeps the kernel on
-    meshes where it can run (shard_map port; interpret mode on capable
-    CPU builds) and falls back to the XLA twin only where it can't."""
-    from llm_d_fast_model_actuation_tpu.parallel.mesh import (
-        MeshPlan,
-        make_mesh,
-    )
-
-    mesh = make_mesh(MeshPlan(dp=1, tp=2), jax.devices()[:2])
-    for impl in ("reference", "grouped"):
-        assert attn.resolve_ragged_impl(impl, None) == impl
-        assert attn.resolve_ragged_impl(impl, mesh) == impl
-    assert attn.resolve_ragged_impl("pallas", None) == "pallas"
-    want = "pallas" if pallas_interpret_supported() else "grouped"
-    assert attn.resolve_ragged_impl("pallas", mesh) == want
-
-
-@needs_pallas
 def test_ragged_pallas_bf16_io_fp32_math():
     q, kp, vp, pt, row_slot, positions, B = _pack_scenario(
         jax.random.key(2), 4, 2, 32, 8, 2
@@ -282,9 +251,7 @@ def test_packed_greedy_across_attention_impls():
     same window as the bucketed cross-impl test (test_pallas_ops):
     per-call agreement is ~1e-5, so a long enough greedy run can hit an
     argmax near-tie; the kernel-identity tests above pin the math."""
-    impls = ["reference", "grouped"]
-    if pallas_interpret_supported():
-        impls.append("pallas")
+    impls = ["reference", "grouped", "pallas"]
     outs = {}
     for impl in impls:
         outs[impl], _ = _generate(True, attention_impl=impl, max_new=6)

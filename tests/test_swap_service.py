@@ -185,7 +185,7 @@ def test_release_sleep_drains_pool():
         assert len(svc.model_pool) == 1
         svc.release_on_sleep = True  # the TPU default, forced on CPU
         svc.sleep(1)
-        assert svc.sleeper.devices_released
+        assert svc.sleeper._released
         assert len(svc.model_pool) == 0 and svc.model_pool.evictions == 1
         svc.wake_up()
         out = svc.swap("tiny")  # survives: cold build, not a dead-pool hit
