@@ -41,6 +41,7 @@ from jax.sharding import Mesh
 
 from ..models import llama
 from ..parallel.mesh import shard_pytree
+from ..utils import tracing
 from .kv_cache import OutOfPages, PageAllocator, PagePool
 from .sampling import sample
 
@@ -1306,16 +1307,20 @@ class InferenceEngine:
         included — is the packed path's dirty-edge fallback; between
         dirty edges packed engines refresh only the small per-slot
         mirrors (_upload_sched_rows)."""
-        mirrors = self._sched_mirrors()
-        self.step_h2d_bytes[self._h2d_path()] += sum(
-            a.nbytes for a in mirrors.values()
-        )
-        self._dev = jax.device_put(mirrors, self._sched_sharding())
-        self._dirty = False
-        self._rows_stale = False
-        # the pushed [b, vocab] rows are authoritative for every slot;
-        # in-program fresh-slot zeroing would discard them
-        self._fresh_slots.clear()
+        with tracing.phase("sched.upload", self.chunk_in_flight) as ph:
+            mirrors = self._sched_mirrors()
+            nbytes = sum(a.nbytes for a in mirrors.values())
+            ph.set(
+                which="full", bytes=nbytes,
+                why="dirty" if self._dirty else "no_dev",
+            )
+            self.step_h2d_bytes[self._h2d_path()] += nbytes
+            self._dev = jax.device_put(mirrors, self._sched_sharding())
+            self._dirty = False
+            self._rows_stale = False
+            # the pushed [b, vocab] rows are authoritative for every slot;
+            # in-program fresh-slot zeroing would discard them
+            self._fresh_slots.clear()
 
     def _upload_sched_rows(self) -> None:
         """Refresh ONLY the small per-slot mirrors on device — everything
@@ -1323,19 +1328,20 @@ class InferenceEngine:
         programs maintain device-side between dirty edges. O(b ·
         pages_per_seq) bytes vs the full upload's O(b · vocab): this is
         what keeps a packed step's steady-state H2D at O(rows)."""
-        small = {
-            k: v
-            for k, v in self._sched_mirrors().items()
-            if k not in self._VOCAB_MIRRORS
-        }
-        self.step_h2d_bytes[self._h2d_path()] += sum(
-            a.nbytes for a in small.values()
-        )
-        up = jax.device_put(small, self._sched_sharding())
-        d = dict(self._dev)
-        d.update(up)
-        self._dev = d
-        self._rows_stale = False
+        with tracing.phase("sched.upload", self.chunk_in_flight) as ph:
+            small = {
+                k: v
+                for k, v in self._sched_mirrors().items()
+                if k not in self._VOCAB_MIRRORS
+            }
+            nbytes = sum(a.nbytes for a in small.values())
+            ph.set(which="rows", bytes=nbytes, why="rows_stale")
+            self.step_h2d_bytes[self._h2d_path()] += nbytes
+            up = jax.device_put(small, self._sched_sharding())
+            d = dict(self._dev)
+            d.update(up)
+            self._dev = d
+            self._rows_stale = False
 
     def _upload_sched_table(self) -> None:
         """Refresh ONLY the device page table — the one piece of device
@@ -1345,11 +1351,13 @@ class InferenceEngine:
         full small-tier refresh (it reads lt/pos/budget/... from
         device), but back-to-back packed steps stop re-uploading
         mirrors nobody reads."""
-        pt = self._page_table
-        self.step_h2d_bytes[self._h2d_path()] += pt.nbytes
-        d = dict(self._dev)
-        d["pt"] = jax.device_put(pt, self._sched_sharding())
-        self._dev = d
+        with tracing.phase("sched.upload", self.chunk_in_flight) as ph:
+            pt = self._page_table
+            ph.set(which="table", bytes=pt.nbytes, why="rows_stale")
+            self.step_h2d_bytes[self._h2d_path()] += pt.nbytes
+            d = dict(self._dev)
+            d["pt"] = jax.device_put(pt, self._sched_sharding())
+            self._dev = d
 
     def drop_device_sched_state(self) -> None:
         """Forget device scheduler arrays (sleep path). Host mirrors —
@@ -1620,6 +1628,23 @@ class InferenceEngine:
                 return i
         return None
 
+    def _try_admit(self, req: Request) -> bool:
+        """``_admit`` of the head of the waiting queue as the scheduler
+        loop calls it: the ``sched.admit`` phase, and the count of steps
+        in which the head was refused (a refusal ends a step's
+        admissions, so there is at most one a step)."""
+        with tracing.phase("sched.admit", self.chunk_in_flight) as ph:
+            admitted = self._admit(req)
+            if admitted:
+                ph.set(admitted=1)
+            else:
+                tracing.count_admit_blocked()
+                ph.set(
+                    admitted=0,
+                    blocked="slots" if self._free_slot() is None else "pages",
+                )
+        return admitted
+
     def _admit(self, req: Request) -> bool:
         slot = self._free_slot()
         if slot is None:
@@ -1794,121 +1819,131 @@ class InferenceEngine:
         return tok, lp, av, ai, plp, new_key
 
     def _run_prefill(self, req: Request) -> None:
-        n = len(req.prompt)
-        temp = np.asarray([req.temperature], dtype=np.float32)
-        topp = np.asarray([req.top_p], dtype=np.float32)
-        counts_row = self._token_counts[req.slot : req.slot + 1]
-        pres = np.asarray([req.presence_penalty], dtype=np.float32)
-        freq = np.asarray([req.frequency_penalty], dtype=np.float32)
-        k = req.cached_tokens
-        limit = self.cfg.max_prefill_tokens or (n - k)
-        if k == 0 and n <= limit:
-            # single cold segment: the flash-style causal program
-            table = self._page_table[req.slot : req.slot + 1]
-            bucket = self._prefill_bucket(n)
-            self.pad_waste_bytes["bucketed"] += (
-                (bucket - n) * self._pad_token_bytes
-            )
-            self.dispatch_tokens["bucketed"] += n
-            tokens = np.zeros((1, bucket), dtype=np.int32)
-            tokens[0, :n] = req.prompt
-            seq_lens = np.array([n], dtype=np.int32)
-            if self.lockstep is not None:
-                self.lockstep.prefill(
-                    req, bucket, want_plp=req.want_prompt_logprobs
+        overlapped = self.chunk_in_flight
+        with tracing.phase("sched.prefill_dispatch", overlapped) as ph:
+            n = len(req.prompt)
+            temp = np.asarray([req.temperature], dtype=np.float32)
+            topp = np.asarray([req.top_p], dtype=np.float32)
+            counts_row = self._token_counts[req.slot : req.slot + 1]
+            pres = np.asarray([req.presence_penalty], dtype=np.float32)
+            freq = np.asarray([req.frequency_penalty], dtype=np.float32)
+            k = req.cached_tokens
+            limit = self.cfg.max_prefill_tokens or (n - k)
+            if k == 0 and n <= limit:
+                # single cold segment: the flash-style causal program
+                table = self._page_table[req.slot : req.slot + 1]
+                bucket = self._prefill_bucket(n)
+                ph.set(program="prefill", bucket=bucket, prompt_tokens=n)
+                self.pad_waste_bytes["bucketed"] += (
+                    (bucket - n) * self._pad_token_bytes
                 )
-            self.step_h2d_bytes["bucketed"] += (
-                tokens.nbytes + seq_lens.nbytes + table.nbytes + temp.nbytes
-                + topp.nbytes + counts_row.nbytes + pres.nbytes + freq.nbytes
-                + self._slot_keys[req.slot].nbytes
-                + self._bias[req.slot : req.slot + 1].nbytes
-            )
-            tok, lp, av, ai, plp, cache, new_key = self._call_program(
-                "prefill_plp" if req.want_prompt_logprobs else "prefill",
-                bucket,
-                self.params,
-                tokens,
-                seq_lens,
-                self.pool.as_tuple(),
-                table,
-                temp,
-                topp,
-                counts_row,
-                pres,
-                freq,
-                self._slot_keys[req.slot],
-                self._bias[req.slot : req.slot + 1],
-            )
-            self.pool.replace(cache)
-            if req.want_prompt_logprobs:
-                # device refs only; fetched in the single batched sync below
-                plp_parts = [(plp, n - 1)]
-        else:
-            # prefix-cache hit and/or chunked prefill: run [k, n) through
-            # the continue program in segments of <= limit tokens; only the
-            # final segment's sample is consumed
-            pos = k
-            plp_parts = []
-            while pos < n:
-                seg = req.prompt[pos : min(n, pos + limit)]
-                final = pos + len(seg) >= n
-                tok, lp, av, ai, plp, seg_key = self._run_suffix_segment(
-                    req, pos, seg, temp, topp, counts_row, pres, freq,
-                    final=final,
+                self.dispatch_tokens["bucketed"] += n
+                tokens = np.zeros((1, bucket), dtype=np.int32)
+                tokens[0, :n] = req.prompt
+                seq_lens = np.array([n], dtype=np.int32)
+                if self.lockstep is not None:
+                    self.lockstep.prefill(
+                        req, bucket, want_plp=req.want_prompt_logprobs
+                    )
+                self.step_h2d_bytes["bucketed"] += (
+                    tokens.nbytes + seq_lens.nbytes + table.nbytes + temp.nbytes
+                    + topp.nbytes + counts_row.nbytes + pres.nbytes + freq.nbytes
+                    + self._slot_keys[req.slot].nbytes
+                    + self._bias[req.slot : req.slot + 1].nbytes
                 )
-                if final:
-                    new_key = seg_key
+                tok, lp, av, ai, plp, cache, new_key = self._call_program(
+                    "prefill_plp" if req.want_prompt_logprobs else "prefill",
+                    bucket,
+                    self.params,
+                    tokens,
+                    seq_lens,
+                    self.pool.as_tuple(),
+                    table,
+                    temp,
+                    topp,
+                    counts_row,
+                    pres,
+                    freq,
+                    self._slot_keys[req.slot],
+                    self._bias[req.slot : req.slot + 1],
+                )
+                self.pool.replace(cache)
                 if req.want_prompt_logprobs:
-                    # entries predict prompt[pos+1 .. pos+len(seg)]; the
-                    # final segment's last entry predicts nothing
-                    take = len(seg) if not final else len(seg) - 1
-                    plp_parts.append((plp, take))
-                pos += len(seg)
-        if self.prefix_cache is not None and req.variant == 0:
-            # the full prompt pages now hold prompt KV: make them
-            # reusable (base-variant KV only — see _admit's match gate)
-            self.prefix_cache.register(
-                req.prompt,
-                req.pages,
-                req.shared_pages,
-                known_hashes=getattr(req, "_prefix_hashes", ()),
-            )
-        # ONE batched host sync for everything the emit needs — separate
-        # np.asarray calls are separate round trips on high-latency links,
-        # and this is the tail of every TTFT measurement. Prompt-logprob
-        # rows (one per prefill segment) ride the same fetch.
-        fetch = [tok, lp, new_key]
-        if req.want_top_logprobs:
-            fetch += [av, ai]
-        if req.want_prompt_logprobs:
-            fetch += [p for p, _ in plp_parts]
-        vals = list(jax.device_get(tuple(fetch)))
-        tok_h, lp_h, key_h = vals[:3]
-        vals = vals[3:]
-        alts = None
-        if req.want_top_logprobs:
-            av_h, ai_h = vals[:2]
-            vals = vals[2:]
-            alts = [
-                (int(ai_h[0, j]), float(av_h[0, j]))
-                for j in range(av_h.shape[1])
-            ]
-        if req.want_prompt_logprobs:
-            req.prompt_logprobs = [None]  # nothing precedes token 0
-            for row, (_, take) in zip(vals, plp_parts):
-                req.prompt_logprobs.extend(
-                    float(row[0][i]) for i in range(take)
+                    # device refs only; fetched in the single batched sync below
+                    plp_parts = [(plp, n - 1)]
+            else:
+                # prefix-cache hit and/or chunked prefill: run [k, n) through
+                # the continue program in segments of <= limit tokens; only the
+                # final segment's sample is consumed
+                pos = k
+                plp_parts = []
+                ph.set(
+                    program="suffix", prompt_tokens=n, cached_tokens=k,
+                    bucket=self._prefill_bucket(min(limit, n - k)),
                 )
-        self._slot_keys[req.slot] = key_h
-        first = int(tok_h[0])
-        req.pos = n
-        self._emit(req, first, float(lp_h[0]), alts)
-        self._positions[req.slot] = req.pos  # position of the token to place
-        self._last_tokens[req.slot] = first
-        self._temps[req.slot] = req.temperature
-        self._topps[req.slot] = req.top_p
-        self._budgets[req.slot] = req.max_new_tokens - len(req.out_tokens)
-        self._dirty = True
+                while pos < n:
+                    seg = req.prompt[pos : min(n, pos + limit)]
+                    final = pos + len(seg) >= n
+                    tok, lp, av, ai, plp, seg_key = self._run_suffix_segment(
+                        req, pos, seg, temp, topp, counts_row, pres, freq,
+                        final=final,
+                    )
+                    if final:
+                        new_key = seg_key
+                    if req.want_prompt_logprobs:
+                        # entries predict prompt[pos+1 .. pos+len(seg)]; the
+                        # final segment's last entry predicts nothing
+                        take = len(seg) if not final else len(seg) - 1
+                        plp_parts.append((plp, take))
+                    pos += len(seg)
+            if self.prefix_cache is not None and req.variant == 0:
+                # the full prompt pages now hold prompt KV: make them
+                # reusable (base-variant KV only — see _admit's match gate)
+                self.prefix_cache.register(
+                    req.prompt,
+                    req.pages,
+                    req.shared_pages,
+                    known_hashes=getattr(req, "_prefix_hashes", ()),
+                )
+            # ONE batched host sync for everything the emit needs — separate
+            # np.asarray calls are separate round trips on high-latency links,
+            # and this is the tail of every TTFT measurement. Prompt-logprob
+            # rows (one per prefill segment) ride the same fetch.
+            fetch = [tok, lp, new_key]
+            if req.want_top_logprobs:
+                fetch += [av, ai]
+            if req.want_prompt_logprobs:
+                fetch += [p for p, _ in plp_parts]
+        with tracing.phase("sched.prefill_fetch", overlapped):
+            vals = list(jax.device_get(tuple(fetch)))
+        with tracing.phase("sched.emit", overlapped) as ph:
+            tok_h, lp_h, key_h = vals[:3]
+            vals = vals[3:]
+            alts = None
+            if req.want_top_logprobs:
+                av_h, ai_h = vals[:2]
+                vals = vals[2:]
+                alts = [
+                    (int(ai_h[0, j]), float(av_h[0, j]))
+                    for j in range(av_h.shape[1])
+                ]
+            if req.want_prompt_logprobs:
+                req.prompt_logprobs = [None]  # nothing precedes token 0
+                for row, (_, take) in zip(vals, plp_parts):
+                    req.prompt_logprobs.extend(
+                        float(row[0][i]) for i in range(take)
+                    )
+            self._slot_keys[req.slot] = key_h
+            first = int(tok_h[0])
+            req.pos = n
+            self._emit(req, first, float(lp_h[0]), alts)
+            self._positions[req.slot] = req.pos  # position of the token to place
+            self._last_tokens[req.slot] = first
+            self._temps[req.slot] = req.temperature
+            self._topps[req.slot] = req.top_p
+            self._budgets[req.slot] = req.max_new_tokens - len(req.out_tokens)
+            self._dirty = True
+            ph.set(tokens=1, finished=int(req.done))
 
     def _emit(
         self,
@@ -2052,8 +2087,6 @@ class InferenceEngine:
         and the buffer tail are padding rows (row_slot = -1) the model
         computes but nobody reads.
         """
-        from ..utils import tracing
-
         qb = self._pack_align
         T = self._token_budget
         b = self.cfg.max_batch
@@ -2132,7 +2165,7 @@ class InferenceEngine:
             if req.want_prompt_logprobs:
                 # echo requests need the full-bucket prompt-logprob
                 # scoring variants: bucketed fallback, same step
-                if not self._admit(req):
+                if not self._try_admit(req):
                     break
                 self._waiting.pop(0)
                 self._run_prefill(req)
@@ -2140,7 +2173,7 @@ class InferenceEngine:
                     self._retire(req)
                     finished.append(req)
                 continue
-            if not self._admit(req):
+            if not self._try_admit(req):
                 break
             self._waiting.pop(0)
             req.prefilling = True
@@ -2236,120 +2269,133 @@ class InferenceEngine:
             "step.packed", rows=shape, tokens=valid,
             decode_rows=len(decode_reqs), prefill_tokens=prefill_tokens,
         ):
-            if routed_rows:
-                tok, lp, av, ai, cache, counts_dev, bias_dev, skeys = (
-                    self.programs.mixed_multi(kvp)(
-                        self.params,
-                        self._variant_deltas(),
-                        tok_variant[:shape],
-                        tokens[:shape],
-                        row_slot[:shape],
-                        positions[:shape],
-                        count_row[:shape],
-                        sample_rows,
-                        sample_on,
-                        fresh_on,
-                        self.pool.as_tuple(),
-                        d["pt"],
-                        self._temps,
-                        self._topps,
-                        d["counts"],
-                        self._pres,
-                        self._freqs,
-                        self._slot_keys,
-                        d["bias"],
-                    )
+            with tracing.phase("sched.prefill_dispatch", False) as ph:
+                ph.set(
+                    program="mixed", bucket=shape,
+                    prompt_tokens=prefill_tokens,
                 )
-            else:
-                tok, lp, av, ai, cache, counts_dev, bias_dev, skeys = (
-                    self._call_program(
-                        "mixed", mixed_bucket(shape, kvp),
-                        self.params,
-                        tokens[:shape],
-                        row_slot[:shape],
-                        positions[:shape],
-                        count_row[:shape],
-                        sample_rows,
-                        sample_on,
-                        fresh_on,
-                        self.pool.as_tuple(),
-                        d["pt"],
-                        self._temps,
-                        self._topps,
-                        d["counts"],
-                        self._pres,
-                        self._freqs,
-                        self._slot_keys,
-                        d["bias"],
+                if routed_rows:
+                    tok, lp, av, ai, cache, counts_dev, bias_dev, skeys = (
+                        self.programs.mixed_multi(kvp)(
+                            self.params,
+                            self._variant_deltas(),
+                            tok_variant[:shape],
+                            tokens[:shape],
+                            row_slot[:shape],
+                            positions[:shape],
+                            count_row[:shape],
+                            sample_rows,
+                            sample_on,
+                            fresh_on,
+                            self.pool.as_tuple(),
+                            d["pt"],
+                            self._temps,
+                            self._topps,
+                            d["counts"],
+                            self._pres,
+                            self._freqs,
+                            self._slot_keys,
+                            d["bias"],
+                        )
                     )
+                else:
+                    tok, lp, av, ai, cache, counts_dev, bias_dev, skeys = (
+                        self._call_program(
+                            "mixed", mixed_bucket(shape, kvp),
+                            self.params,
+                            tokens[:shape],
+                            row_slot[:shape],
+                            positions[:shape],
+                            count_row[:shape],
+                            sample_rows,
+                            sample_on,
+                            fresh_on,
+                            self.pool.as_tuple(),
+                            d["pt"],
+                            self._temps,
+                            self._topps,
+                            d["counts"],
+                            self._pres,
+                            self._freqs,
+                            self._slot_keys,
+                            d["bias"],
+                        )
+                    )
+                self.pool.replace(cache)
+                # the program consumed (donated) and re-emitted the device-
+                # resident mirrors; they stay the between-dispatch truth
+                d["counts"] = counts_dev
+                d["bias"] = bias_dev
+                self._fresh_slots.clear()
+            with tracing.phase("sched.prefill_fetch", False):
+                # ONE batched host sync for the whole step's emits
+                tok_h, lp_h, av_h, ai_h, keys_h = jax.device_get(
+                    (tok, lp, av, ai, skeys)
                 )
-            self.pool.replace(cache)
-            # the program consumed (donated) and re-emitted the device-
-            # resident mirrors; they stay the between-dispatch truth
-            d["counts"] = counts_dev
-            d["bias"] = bias_dev
-            self._fresh_slots.clear()
-            # ONE batched host sync for the whole step's emits
-            tok_h, lp_h, av_h, ai_h, keys_h = jax.device_get(
-                (tok, lp, av, ai, skeys)
+        with tracing.phase("sched.emit", False) as ph:
+            emitted = self.total_tokens_emitted
+            before = len(finished)
+            # non-sampling slots' keys came back unchanged (in-program where)
+            self._slot_keys[:] = keys_h
+            # host count mirrors absorb the streamed prompt rows exactly as
+            # the program pre-added them on device (req.pos still pre-step)
+            for req, take, _final in segments:
+                if req.slot >= 0:
+                    np.add.at(
+                        self._token_counts[req.slot],
+                        req.prompt[req.pos : req.pos + take], 1,
+                    )
+
+            def alts_for(req: Request, slot: int):
+                if not req.want_top_logprobs:
+                    return None
+                return [
+                    (int(ai_h[slot, j]), float(av_h[slot, j]))
+                    for j in range(av_h.shape[1])
+                ]
+
+            # prefill segments advance; final segments emit their first token
+            for req, take, final in segments:
+                if req.done:  # aborted mid-step: pages already freed
+                    continue
+                slot = req.slot
+                req.pos += take
+                if not final:
+                    continue
+                req.prefilling = False
+                if self.prefix_cache is not None and req.variant == 0:
+                    # the full prompt's KV is now in pages: make it reusable
+                    # (base-variant KV only — see _admit's match gate)
+                    self.prefix_cache.register(
+                        req.prompt, req.pages, req.shared_pages,
+                        known_hashes=getattr(req, "_prefix_hashes", ()),
+                    )
+                first = int(tok_h[slot])
+                self._emit(req, first, float(lp_h[slot]), alts_for(req, slot))
+                self._positions[slot] = req.pos
+                self._last_tokens[slot] = first
+                self._budgets[slot] = req.max_new_tokens - len(req.out_tokens)
+                if req.done:
+                    self._retire(req)
+                    finished.append(req)
+            # decode rows emit one token each
+            for req in decode_reqs:
+                if req.done:
+                    continue
+                slot = req.slot
+                t = int(tok_h[slot])
+                req.pos += 1
+                self._positions[slot] = req.pos
+                self._last_tokens[slot] = t
+                self._emit(req, t, float(lp_h[slot]), alts_for(req, slot))
+                self._budgets[slot] = req.max_new_tokens - len(req.out_tokens)
+                if req.done:
+                    self._retire(req)
+                    finished.append(req)
+            ph.set(
+                tokens=self.total_tokens_emitted - emitted,
+                finished=len(finished) - before,
             )
-        # non-sampling slots' keys came back unchanged (in-program where)
-        self._slot_keys[:] = keys_h
-        # host count mirrors absorb the streamed prompt rows exactly as
-        # the program pre-added them on device (req.pos still pre-step)
-        for req, take, _final in segments:
-            if req.slot >= 0:
-                np.add.at(
-                    self._token_counts[req.slot],
-                    req.prompt[req.pos : req.pos + take], 1,
-                )
-
-        def alts_for(req: Request, slot: int):
-            if not req.want_top_logprobs:
-                return None
-            return [
-                (int(ai_h[slot, j]), float(av_h[slot, j]))
-                for j in range(av_h.shape[1])
-            ]
-
-        # prefill segments advance; final segments emit their first token
-        for req, take, final in segments:
-            if req.done:  # aborted mid-step: pages already freed
-                continue
-            slot = req.slot
-            req.pos += take
-            if not final:
-                continue
-            req.prefilling = False
-            if self.prefix_cache is not None and req.variant == 0:
-                # the full prompt's KV is now in pages: make it reusable
-                # (base-variant KV only — see _admit's match gate)
-                self.prefix_cache.register(
-                    req.prompt, req.pages, req.shared_pages,
-                    known_hashes=getattr(req, "_prefix_hashes", ()),
-                )
-            first = int(tok_h[slot])
-            self._emit(req, first, float(lp_h[slot]), alts_for(req, slot))
-            self._positions[slot] = req.pos
-            self._last_tokens[slot] = first
-            self._budgets[slot] = req.max_new_tokens - len(req.out_tokens)
-            if req.done:
-                self._retire(req)
-                finished.append(req)
-        # decode rows emit one token each
-        for req in decode_reqs:
-            if req.done:
-                continue
-            slot = req.slot
-            t = int(tok_h[slot])
-            req.pos += 1
-            self._positions[slot] = req.pos
-            self._last_tokens[slot] = t
-            self._emit(req, t, float(lp_h[slot]), alts_for(req, slot))
-            self._budgets[slot] = req.max_new_tokens - len(req.out_tokens)
-            if req.done:
-                self._retire(req)
-                finished.append(req)
         # the [b, vocab] device mirrors are already exact (the program
         # maintained them); only the small per-slot mirrors (last
         # tokens, positions, budgets — advanced by the emits above)
@@ -2457,14 +2503,21 @@ class InferenceEngine:
         self.step_h2d_bytes["bucketed"] += (
             tokens.nbytes + start.nbytes + window_len.nbytes + table.nbytes
         )
-        toks, lps_dev, avs_dev, ais_dev, cache = self._verify_fn(
-            self.params, tokens, start, window_len, self.pool.as_tuple(), table
-        )
-        self.pool.replace(cache)
-        # one batched host sync (4 separate np.asarray = 4 round trips)
-        o, o_lp, o_av, o_ai = (
-            x[0] for x in jax.device_get((toks, lps_dev, avs_dev, ais_dev))
-        )
+        # a verify forward is decode work: it is timed as a chunk of
+        # len(window) steps (speculation never runs beside a chunk in flight)
+        with tracing.phase("sched.chunk_dispatch", False) as ph:
+            ph.set(T=len(window), live_slots=1, program="verify")
+            toks, lps_dev, avs_dev, ais_dev, cache = self._verify_fn(
+                self.params, tokens, start, window_len,
+                self.pool.as_tuple(), table,
+            )
+            self.pool.replace(cache)
+        with tracing.phase("sched.chunk_fetch", False):
+            # one batched host sync (4 separate np.asarray = 4 round trips)
+            o, o_lp, o_av, o_ai = (
+                x[0]
+                for x in jax.device_get((toks, lps_dev, avs_dev, ais_dev))
+            )
         self.spec_proposed += len(props)
         accepted = 0
         emitted: List[Tuple[int, float, list]] = []
@@ -2495,16 +2548,18 @@ class InferenceEngine:
                 self._spec_miss_streak = 0
         else:
             self._spec_miss_streak = 0
-        for t, lp, alts in emitted:
-            req.pos += 1
-            self._positions[req.slot] = req.pos
-            self._last_tokens[req.slot] = t
-            self._budgets[req.slot] = max(
-                0, req.max_new_tokens - len(req.out_tokens) - 1
-            )
-            self._emit(req, t, lp, alts)
-            if req.done:
-                break
+        with tracing.phase("sched.emit", False) as ph:
+            for t, lp, alts in emitted:
+                req.pos += 1
+                self._positions[req.slot] = req.pos
+                self._last_tokens[req.slot] = t
+                self._budgets[req.slot] = max(
+                    0, req.max_new_tokens - len(req.out_tokens) - 1
+                )
+                self._emit(req, t, lp, alts)
+                if req.done:
+                    break
+            ph.set(tokens=len(emitted), finished=int(req.done))
         self._dirty = True  # device scheduler state is stale
         return True
 
@@ -2537,7 +2592,7 @@ class InferenceEngine:
         if not packed_mode:
             while self._waiting:
                 req = self._waiting[0]
-                if not self._admit(req):
+                if not self._try_admit(req):
                     break
                 self._waiting.pop(0)
                 self._run_prefill(req)
@@ -2591,13 +2646,14 @@ class InferenceEngine:
                     for r in running.values()
                 ):
                     nxt = self._dispatch_chunk(running)
-            inflight, self._inflight = self._inflight, None
+            # nxt, if any, runs on the device while this one drains: the
+            # drain's phases are host-only exactly when nothing is in flight
+            inflight, self._inflight = self._inflight, nxt
             ready, self._pending_retire = self._pending_retire, []
             finished.extend(self._drain_chunk(inflight, defer_retire=True))
             for r in ready:
                 # the chunk that could still write these slots has drained
                 self._retire(r)
-            self._inflight = nxt
             if nxt is None:
                 for r in self._pending_retire:
                     self._retire(r)
@@ -2643,86 +2699,108 @@ class InferenceEngine:
             # per-slot mirrors host-side (and admissions/retires touched
             # the page table); the [b, vocab] counts stay device-exact
             self._upload_sched_rows()
-        d = self._dev
-        # a routed slot switches the chunk to the multi-variant twin
-        # (the plain program would decode it with base weights); with
-        # none live the plain, possibly AOT-warmed chunk serves as ever
-        if any(r.variant != 0 for r in running.values()):
-            vmap_idx = self._variant_pass_index()
-            slot_variant = np.zeros((self.cfg.max_batch,), dtype=np.int32)
-            for slot, r in running.items():
-                if r.variant:
-                    slot_variant[slot] = vmap_idx[r.variant]
-            self.step_h2d_bytes[self._h2d_path()] += slot_variant.nbytes
-            (
-                toks_dev, lps_dev, avs_dev, ais_dev, lt, pos, budget,
-                cache, counts_dev, skeys_dev,
-            ) = self.programs.chunk_multi(T)(
-                self.params,
-                self._variant_deltas(),
-                slot_variant,
-                d["lt"],
-                d["pos"],
-                d["budget"],
-                self.pool.as_tuple(),
-                d["pt"],
-                d["temps"],
-                d["topp"],
-                d["counts"],
-                d["pres"],
-                d["freq"],
-                d["skeys"],
-                d["eos_on"],
-                d["bias"],
-            )
-        else:
-            (
-                toks_dev, lps_dev, avs_dev, ais_dev, lt, pos, budget,
-                cache, counts_dev, skeys_dev,
-            ) = self._chunk_fn(T)(
-                self.params,
-                d["lt"],
-                d["pos"],
-                d["budget"],
-                self.pool.as_tuple(),
-                d["pt"],
-                d["temps"],
-                d["topp"],
-                d["counts"],
-                d["pres"],
-                d["freq"],
-                d["skeys"],
-                d["eos_on"],
-                d["bias"],
-            )
-        self.pool.replace(cache)
-        self._dev = {
-            "lt": lt, "pos": pos, "budget": budget,
-            "pt": d["pt"], "temps": d["temps"], "topp": d["topp"],
-            "counts": counts_dev, "pres": d["pres"], "freq": d["freq"],
-            "skeys": skeys_dev, "eos_on": d["eos_on"], "bias": d["bias"],
-        }
+        with tracing.phase("sched.chunk_dispatch", self.chunk_in_flight) as ph:
+            ph.set(T=T, live_slots=len(running))
+            d = self._dev
+            # a routed slot switches the chunk to the multi-variant twin
+            # (the plain program would decode it with base weights); with
+            # none live the plain, possibly AOT-warmed chunk serves as ever
+            if any(r.variant != 0 for r in running.values()):
+                vmap_idx = self._variant_pass_index()
+                slot_variant = np.zeros((self.cfg.max_batch,), dtype=np.int32)
+                for slot, r in running.items():
+                    if r.variant:
+                        slot_variant[slot] = vmap_idx[r.variant]
+                self.step_h2d_bytes[self._h2d_path()] += slot_variant.nbytes
+                (
+                    toks_dev, lps_dev, avs_dev, ais_dev, lt, pos, budget,
+                    cache, counts_dev, skeys_dev,
+                ) = self.programs.chunk_multi(T)(
+                    self.params,
+                    self._variant_deltas(),
+                    slot_variant,
+                    d["lt"],
+                    d["pos"],
+                    d["budget"],
+                    self.pool.as_tuple(),
+                    d["pt"],
+                    d["temps"],
+                    d["topp"],
+                    d["counts"],
+                    d["pres"],
+                    d["freq"],
+                    d["skeys"],
+                    d["eos_on"],
+                    d["bias"],
+                )
+            else:
+                (
+                    toks_dev, lps_dev, avs_dev, ais_dev, lt, pos, budget,
+                    cache, counts_dev, skeys_dev,
+                ) = self._chunk_fn(T)(
+                    self.params,
+                    d["lt"],
+                    d["pos"],
+                    d["budget"],
+                    self.pool.as_tuple(),
+                    d["pt"],
+                    d["temps"],
+                    d["topp"],
+                    d["counts"],
+                    d["pres"],
+                    d["freq"],
+                    d["skeys"],
+                    d["eos_on"],
+                    d["bias"],
+                )
+            self.pool.replace(cache)
+            self._dev = {
+                "lt": lt, "pos": pos, "budget": budget,
+                "pt": d["pt"], "temps": d["temps"], "topp": d["topp"],
+                "counts": counts_dev, "pres": d["pres"], "freq": d["freq"],
+                "skeys": skeys_dev, "eos_on": d["eos_on"], "bias": d["bias"],
+            }
         return (toks_dev, lps_dev, avs_dev, ais_dev, skeys_dev, running, T)
 
     def _drain_chunk(self, inflight, defer_retire: bool = False):
         """Fetch one dispatched chunk's results (the single blocking host
         sync per chunk) and emit its tokens."""
         toks_dev, lps_dev, avs_dev, ais_dev, skeys_dev, running, T = inflight
-        finished: List[Request] = []
         # The key mirror rides the batched device_get: a dirty re-upload
         # must not rewind any slot's key stream to a pre-chunk state.
         # Pipelined: a later chunk's dispatch DONATES this chunk's skeys
         # output (is_deleted) — skip the stale sync; the later chunk's own
         # drain supplies the fresh mirror, and a re-upload is always
         # preceded by that drain (dirty state blocks pre-dispatch).
-        if skeys_dev.is_deleted():
-            toks, lps, avs, ais = jax.device_get(
-                (toks_dev, lps_dev, avs_dev, ais_dev)
+        overlapped = self.chunk_in_flight
+        with tracing.phase("sched.chunk_fetch", overlapped):
+            if skeys_dev.is_deleted():
+                skeys_host = None
+                toks, lps, avs, ais = jax.device_get(
+                    (toks_dev, lps_dev, avs_dev, ais_dev)
+                )
+            else:
+                toks, lps, avs, ais, skeys_host = jax.device_get(
+                    (toks_dev, lps_dev, avs_dev, ais_dev, skeys_dev)
+                )
+        with tracing.phase("sched.emit", overlapped) as ph:
+            emitted = self.total_tokens_emitted
+            finished = self._emit_chunk(
+                toks, lps, avs, ais, skeys_host, running, T, defer_retire
             )
-        else:
-            toks, lps, avs, ais, skeys_host = jax.device_get(
-                (toks_dev, lps_dev, avs_dev, ais_dev, skeys_dev)
+            ph.set(
+                tokens=self.total_tokens_emitted - emitted,
+                finished=len(finished),
             )
+        return finished
+
+    def _emit_chunk(
+        self, toks, lps, avs, ais, skeys_host, running, T, defer_retire
+    ) -> List[Request]:
+        """The host half of a drained chunk: key mirror, per-token emits,
+        retires. Returns the requests that finished."""
+        finished: List[Request] = []
+        if skeys_host is not None:
             # only the rows this chunk actually advanced: a request
             # admitted while the chunk was in flight had its key written
             # by prefill AFTER dispatch, and a wholesale copy would rewind
@@ -2783,6 +2861,13 @@ class InferenceEngine:
         for r in self._pending_retire:
             self._retire(r)
         self._pending_retire = []
+
+    @property
+    def chunk_in_flight(self) -> bool:
+        """A dispatched decode chunk has not been fetched yet (pipelined
+        decode): host work beside it does not hold the device back, which
+        is what ``tracing.phase``'s ``overlapped`` is told."""
+        return self._inflight is not None
 
     def has_work(self) -> bool:
         return (

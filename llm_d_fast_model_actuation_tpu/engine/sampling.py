@@ -18,6 +18,7 @@ import jax.numpy as jnp
 TOP_P_CANDIDATES = 64
 
 
+@jax.named_scope("sample")
 def sample(
     logits: jnp.ndarray,  # [b, vocab] fp32
     key: jax.Array,  # scalar key, or [b] per-row keys (per-request seeds)

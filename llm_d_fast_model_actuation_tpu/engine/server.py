@@ -1402,6 +1402,7 @@ class EngineService:
         # concurrent jax.profiler capture per process.
         self._profile_mu = threading.Lock()
         self._profile_dir: Optional[str] = None
+        self._profile_span: Any = tracing.NOOP_SPAN
         # Release-on-sleep is resolved BEFORE the first build: zero-drain
         # parking is off for device-releasing sleeps (the park's host
         # bundle survives, but the restore contract is the full-state
@@ -5467,11 +5468,21 @@ class EngineService:
 
     # -- on-demand deep profiling (POST/DELETE /v1/profile) -------------------
 
-    def start_profile(self, log_dir: str = "") -> Dict[str, Any]:
+    def start_profile(
+        self, log_dir: str = "", python_tracer: bool = False
+    ) -> Dict[str, Any]:
         """Start a jax.profiler capture (XLA device + host activity,
         viewable in Perfetto / TensorBoard) — the "why is THIS phase slow"
         microscope the span timeline points at. Gated to one concurrent
-        capture: the profiler is process-global state."""
+        capture: the profiler is process-global state.
+
+        The Python tracer is off unless ``python_tracer`` asks for it: it
+        slows the host it measures and the events it adds are per Python
+        call, while the scheduler phases (``tracing.phase``), jaxlib's
+        own host events and the device planes are all recorded without
+        it. While the capture runs every phase also leaves a span and a
+        ``TraceAnnotation``; ``fma.clock`` marks this process's span
+        clock in the capture."""
         import jax
 
         with self._profile_mu:
@@ -5485,10 +5496,23 @@ class EngineService:
                 "/tmp", f"fma-profile-{os.getpid()}-{int(time.time())}"
             )
             os.makedirs(log_dir, exist_ok=True)
-            jax.profiler.start_trace(log_dir)
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 1 if python_tracer else 0
+            jax.profiler.start_trace(log_dir, profiler_options=options)
             self._profile_dir = log_dir
-        logger.info("jax profiler capture started -> %s", log_dir)
-        return {"profiling": True, "log_dir": log_dir}
+            self._profile_span = tracing.begin(
+                "profile.capture", activate=False, log_dir=log_dir,
+                python_tracer=python_tracer,
+            )
+            tracing.capture_started()
+        logger.info(
+            "jax profiler capture started -> %s (python tracer %s)",
+            log_dir, "on" if python_tracer else "off",
+        )
+        return {
+            "profiling": True, "log_dir": log_dir,
+            "python_tracer": python_tracer,
+        }
 
     def stop_profile(self) -> Dict[str, Any]:
         import jax
@@ -5500,10 +5524,19 @@ class EngineService:
             # stop_trace (deleted log_dir, export error) must leave the
             # capture marked running so a retried DELETE can reach the
             # still-active process-global profiler — clearing first would
-            # wedge the API (409 forever, start_trace 500s) until restart
+            # wedge the API (409 forever, start_trace 500s) until restart.
+            # The phases stop annotating before the stop is attempted,
+            # whatever becomes of it: the capture span is start -> here.
+            tracing.capture_stopped()
+            self._profile_span.end()
+            t0 = time.monotonic()
             jax.profiler.stop_trace()
+            stop_s = time.monotonic() - t0
             log_dir, self._profile_dir = self._profile_dir, None
-        logger.info("jax profiler capture stopped (%s)", log_dir)
+        logger.info(
+            "jax profiler capture stopped (%s), trace written in %.3f s",
+            log_dir, stop_s,
+        )
         return {"profiling": False, "log_dir": log_dir}
 
     def profile_status(self) -> Dict[str, Any]:
@@ -5583,50 +5616,23 @@ class EngineService:
                 fut.cancel()
 
     def _run(self) -> None:
+        """The scheduler loop. Each part of an iteration is a
+        ``tracing.phase`` (docs/tracing.md "Scheduler phases"); the
+        engine's own parts are named inside ``engine.step``."""
         while not self._stop:
             stepped = False
             try:
                 with self._lock:
-                    self._drain_aborts()
-                    if not self.sleeper.is_sleeping:
-                        while self._pending:
-                            (
-                                prompt, max_tokens, temperature, fut,
-                                on_token, top_p, stop_seqs, presence, freq,
-                                want_alts, want_plp, seed, ignore_eos,
-                                logit_bias, submit_t, variant, trace,
-                            ) = self._pending.pop(0)
-                            try:
-                                seq_id = self.engine.add_request(
-                                    prompt, max_tokens, temperature,
-                                    top_p=top_p, stop_seqs=stop_seqs,
-                                    presence_penalty=presence,
-                                    frequency_penalty=freq,
-                                    on_token=on_token,
-                                    want_top_logprobs=want_alts,
-                                    want_prompt_logprobs=want_plp,
-                                    seed=seed,
-                                    ignore_eos=ignore_eos,
-                                    logit_bias=logit_bias,
-                                    submit_time=submit_t,
-                                    variant=variant,
-                                    trace=trace,
-                                )
-                                self._futures[seq_id] = fut
-                                self._fut_seq[id(fut)] = seq_id
-                            except Exception as e:
-                                if trace is not None:
-                                    # rejected at admission: tail-keep
-                                    # (an aborted lifecycle, however
-                                    # short, is exactly what to debug)
-                                    trace.finish(
-                                        submit_t, time.monotonic(),
-                                        keep=True, outcome="rejected",
-                                        error=f"{type(e).__name__}: {e}",
-                                    )
-                                fut.set_exception(e)
-                        if self.engine.has_work():
-                            for req in self.engine.step():
+                    self._intake()
+                    if (
+                        not self.sleeper.is_sleeping
+                        and self.engine.has_work()
+                    ):
+                        finished = self.engine.step()
+                        with tracing.phase(
+                            "sched.observe", self.engine.chunk_in_flight
+                        ):
+                            for req in finished:
                                 req.done_time = time.monotonic()
                                 # observe BEFORE resolving: the usage
                                 # block reads req.trace_id, stamped by
@@ -5639,22 +5645,71 @@ class EngineService:
                                         fut.set_result(req)
                             self._observe_kv_usage()
                             self._observe_step()
-                            stepped = True
+                        stepped = True
             except Exception as e:  # device/runtime failure: fail loudly
                 logger.exception("engine loop failed")
                 self.failure = f"{type(e).__name__}: {e}"
                 self._fail_all(RuntimeError(self.failure))
                 return
-            if stepped:
-                if self._admin_waiting:
+            if stepped and not self._admin_waiting:
+                continue
+            with tracing.phase("sched.wait"):
+                if stepped:
                     # hand the just-released lock to the waiting
                     # sleep/wake/swap instead of re-grabbing it hot — an
                     # unfair lock can starve the admin call for a whole
                     # generation
                     time.sleep(0.002)
-                continue
-            self._new_work.wait(timeout=0.05)
-            self._new_work.clear()
+                else:
+                    self._new_work.wait(timeout=0.05)
+                    self._new_work.clear()
+
+    def _intake(self) -> None:
+        """Aborts, then every pending request into the engine's waiting
+        queue (engine thread, service lock held)."""
+        with tracing.phase(
+            "sched.intake", self.engine.chunk_in_flight
+        ) as ph:
+            self._drain_aborts()
+            if self.sleeper.is_sleeping:
+                return
+            ph.set(requests=len(self._pending))
+            while self._pending:
+                (
+                    prompt, max_tokens, temperature, fut,
+                    on_token, top_p, stop_seqs, presence, freq,
+                    want_alts, want_plp, seed, ignore_eos,
+                    logit_bias, submit_t, variant, trace,
+                ) = self._pending.pop(0)
+                try:
+                    seq_id = self.engine.add_request(
+                        prompt, max_tokens, temperature,
+                        top_p=top_p, stop_seqs=stop_seqs,
+                        presence_penalty=presence,
+                        frequency_penalty=freq,
+                        on_token=on_token,
+                        want_top_logprobs=want_alts,
+                        want_prompt_logprobs=want_plp,
+                        seed=seed,
+                        ignore_eos=ignore_eos,
+                        logit_bias=logit_bias,
+                        submit_time=submit_t,
+                        variant=variant,
+                        trace=trace,
+                    )
+                    self._futures[seq_id] = fut
+                    self._fut_seq[id(fut)] = seq_id
+                except Exception as e:
+                    if trace is not None:
+                        # rejected at admission: tail-keep
+                        # (an aborted lifecycle, however
+                        # short, is exactly what to debug)
+                        trace.finish(
+                            submit_t, time.monotonic(),
+                            keep=True, outcome="rejected",
+                            error=f"{type(e).__name__}: {e}",
+                        )
+                    fut.set_exception(e)
 
     def _observe_finished(self, req) -> None:
         m = self.args.model
@@ -6026,6 +6081,9 @@ class EngineService:
         from ..utils import compile_cache
 
         out["compile_cache"] = compile_cache.stats()
+        # what the scheduler thread spent its time on, part by part, since
+        # the process started (docs/tracing.md "Scheduler phases")
+        out["scheduler"] = tracing.phase_stats()
         out["hbm"] = self._hbm_rows()
         # co-resident set (docs/perf.md "Co-resident sibling variants"):
         # who is routable on this engine without an actuation, and what
@@ -7757,7 +7815,7 @@ def build_app(service: EngineService) -> web.Application:
         return web.Response(status=status, text=body, content_type=ctype)
 
     async def profile_start(request: web.Request) -> web.Response:
-        log_dir = ""
+        log_dir, python_tracer = "", False
         if request.can_read_body:
             try:
                 body = await request.json()
@@ -7766,9 +7824,14 @@ def build_app(service: EngineService) -> web.Application:
             log_dir = body.get("log_dir") or ""
             if not isinstance(log_dir, str):
                 raise web.HTTPBadRequest(text="log_dir must be a string")
+            python_tracer = body.get("python_tracer", False)
+            if not isinstance(python_tracer, bool):
+                raise web.HTTPBadRequest(
+                    text="python_tracer must be true or false"
+                )
         try:
             info = await asyncio.get_running_loop().run_in_executor(
-                None, lambda: service.start_profile(log_dir)
+                None, lambda: service.start_profile(log_dir, python_tracer)
             )
         except ProfileConflict as e:
             raise web.HTTPConflict(text=str(e))
@@ -7842,6 +7905,9 @@ def run_server(args: argparse.Namespace) -> None:
 
     logger.info("compile cache at %s", compile_cache.arm() or "(none)")
     service = EngineService(args)
+    # built and about to answer /health: a compile from here on is one a
+    # request waits for, and the log names it
+    compile_cache.serving()
     app = build_app(service)
     try:
         web.run_app(

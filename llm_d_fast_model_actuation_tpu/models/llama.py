@@ -281,11 +281,12 @@ def _mlp(cfg, x, gate, up, down):
 
 def _ffn(cfg: "LlamaConfig", lp, x):
     """Dense SwiGLU or routed MoE, by config family (models/moe.py)."""
-    if getattr(cfg, "num_experts", 0) > 1:
-        from .moe import moe_ffn
+    with jax.named_scope("ffn"):
+        if getattr(cfg, "num_experts", 0) > 1:
+            from .moe import moe_ffn
 
-        return moe_ffn(cfg, lp, x)
-    return _mlp(cfg, x, lp["w_gate"], lp["w_up"], lp["w_down"])
+            return moe_ffn(cfg, lp, x)
+        return _mlp(cfg, x, lp["w_gate"], lp["w_up"], lp["w_down"])
 
 
 def _project_qkv(cfg: LlamaConfig, lp, x, positions, cos_tab, sin_tab):
@@ -375,13 +376,16 @@ def prefill(
     def layer(x, scanned):
         lp, kp, vp = scanned
         h = _norm(cfg, x, lp["attn_norm"])
-        q, k, v = _project_qkv(cfg, lp, h, positions, cos_tab, sin_tab)
-        kp = _scatter_prefill(kp, k, page_table, positions, valid, page_size)
-        vp = _scatter_prefill(vp, v, page_table, positions, valid, page_size)
-        attn = causal_prefill_attention(
-            q, k, v, seq_lens, impl=cfg.attention_impl, mesh=mesh
-        )
-        x = x + _post(cfg, lp, "post_attn_norm", qmat(attn.reshape(b, s, cfg.q_dim), lp["wo"]))
+        with jax.named_scope("attn"):
+            q, k, v = _project_qkv(cfg, lp, h, positions, cos_tab, sin_tab)
+        with jax.named_scope("kv_write"):
+            kp = _scatter_prefill(kp, k, page_table, positions, valid, page_size)
+            vp = _scatter_prefill(vp, v, page_table, positions, valid, page_size)
+        with jax.named_scope("attn"):
+            attn = causal_prefill_attention(
+                q, k, v, seq_lens, impl=cfg.attention_impl, mesh=mesh
+            )
+            x = x + _post(cfg, lp, "post_attn_norm", qmat(attn.reshape(b, s, cfg.q_dim), lp["wo"]))
         h = _norm(cfg, x, lp["mlp_norm"])
         x = x + _post(cfg, lp, "post_ffn_norm", _ffn(cfg, lp, h))
         return x, (kp, vp)
@@ -431,11 +435,14 @@ def prefill_continue(
     def layer(x, scanned):
         lp, kp, vp = scanned
         h = _norm(cfg, x, lp["attn_norm"])
-        q, k, v = _project_qkv(cfg, lp, h, positions, cos_tab, sin_tab)
-        kp = _scatter_prefill(kp, k, page_table, positions, valid, page_size)
-        vp = _scatter_prefill(vp, v, page_table, positions, valid, page_size)
-        attn = paged_suffix_attention(q, kp, vp, page_table, start)
-        x = x + _post(cfg, lp, "post_attn_norm", qmat(attn.reshape(b, s, cfg.q_dim), lp["wo"]))
+        with jax.named_scope("attn"):
+            q, k, v = _project_qkv(cfg, lp, h, positions, cos_tab, sin_tab)
+        with jax.named_scope("kv_write"):
+            kp = _scatter_prefill(kp, k, page_table, positions, valid, page_size)
+            vp = _scatter_prefill(vp, v, page_table, positions, valid, page_size)
+        with jax.named_scope("attn"):
+            attn = paged_suffix_attention(q, kp, vp, page_table, start)
+            x = x + _post(cfg, lp, "post_attn_norm", qmat(attn.reshape(b, s, cfg.q_dim), lp["wo"]))
         h = _norm(cfg, x, lp["mlp_norm"])
         x = x + _post(cfg, lp, "post_ffn_norm", _ffn(cfg, lp, h))
         return x, (kp, vp)
@@ -493,20 +500,23 @@ def mixed_step(
     def layer(x, scanned):
         lp, kp, vp = scanned
         h = _norm(cfg, x, lp["attn_norm"])
-        q, k, v = _project_qkv(
-            cfg, lp, h[None], positions[None], cos_tab, sin_tab
-        )
-        q, k, v = q[0], k[0], v[0]  # [T, heads/kvh, hd]
-        kp = _scatter_rows(kp, k, page_table, row_slot, positions, page_size)
-        vp = _scatter_rows(vp, v, page_table, row_slot, positions, page_size)
-        attn = ragged_paged_attention(
-            q, kp, vp, page_table, row_slot, positions,
-            impl=cfg.attention_impl, mesh=mesh,
-        )
-        x = x + _post(
-            cfg, lp, "post_attn_norm",
-            qmat(attn.reshape(T, cfg.q_dim), lp["wo"]),
-        )
+        with jax.named_scope("attn"):
+            q, k, v = _project_qkv(
+                cfg, lp, h[None], positions[None], cos_tab, sin_tab
+            )
+            q, k, v = q[0], k[0], v[0]  # [T, heads/kvh, hd]
+        with jax.named_scope("kv_write"):
+            kp = _scatter_rows(kp, k, page_table, row_slot, positions, page_size)
+            vp = _scatter_rows(vp, v, page_table, row_slot, positions, page_size)
+        with jax.named_scope("attn"):
+            attn = ragged_paged_attention(
+                q, kp, vp, page_table, row_slot, positions,
+                impl=cfg.attention_impl, mesh=mesh,
+            )
+            x = x + _post(
+                cfg, lp, "post_attn_norm",
+                qmat(attn.reshape(T, cfg.q_dim), lp["wo"]),
+            )
         h = _norm(cfg, x, lp["mlp_norm"])
         x = x + _post(cfg, lp, "post_ffn_norm", _ffn(cfg, lp, h))
         return x, (kp, vp)
@@ -564,15 +574,16 @@ def decode_step(
     def layer(x, scanned):
         lp, kp, vp = scanned
         h = _norm(cfg, x, lp["attn_norm"])
-        q, k, v = _project_qkv(
-            cfg, lp, h[:, None, :], positions[:, None], cos_tab, sin_tab
-        )
-        q, k, v = q[:, 0], k[:, 0], v[:, 0]  # [b, heads/kvh, hd]
-        attn = paged_decode_attention_inline(
-            q, kp, vp, k, v, page_table, positions, impl=cfg.attention_impl,
-            mesh=mesh,
-        )
-        x = x + _post(cfg, lp, "post_attn_norm", qmat(attn.reshape(b, cfg.q_dim), lp["wo"]))
+        with jax.named_scope("attn"):
+            q, k, v = _project_qkv(
+                cfg, lp, h[:, None, :], positions[:, None], cos_tab, sin_tab
+            )
+            q, k, v = q[:, 0], k[:, 0], v[:, 0]  # [b, heads/kvh, hd]
+            attn = paged_decode_attention_inline(
+                q, kp, vp, k, v, page_table, positions, impl=cfg.attention_impl,
+                mesh=mesh,
+            )
+            x = x + _post(cfg, lp, "post_attn_norm", qmat(attn.reshape(b, cfg.q_dim), lp["wo"]))
         h = _norm(cfg, x, lp["mlp_norm"])
         x = x + _post(cfg, lp, "post_ffn_norm", _ffn(cfg, lp, h))
         return x, (k, v)
@@ -581,18 +592,19 @@ def decode_step(
         layer, x, (params["layers"], k_pages, v_pages)
     )
     # One scatter for all layers: k_all/v_all are [L, b, kvh, hd].
-    L = k_all.shape[0]
-    page_of = positions // page_size
-    slot_of = positions % page_size
-    phys = jnp.take_along_axis(page_table, page_of[:, None], axis=1)[:, 0]
-    if active is not None:
-        phys = jnp.where(active, phys, num_pages)  # drop inactive rows
-    li = jnp.broadcast_to(jnp.arange(L)[:, None], (L, b)).reshape(-1)
-    pi = jnp.broadcast_to(phys[None, :], (L, b)).reshape(-1)
-    si = jnp.broadcast_to(slot_of[None, :], (L, b)).reshape(-1)
-    flat = (L * b, cfg.num_kv_heads, cfg.head_dim)
-    new_k = k_pages.at[li, pi, si].set(k_all.reshape(flat), mode="drop")
-    new_v = v_pages.at[li, pi, si].set(v_all.reshape(flat), mode="drop")
+    with jax.named_scope("kv_write"):
+        L = k_all.shape[0]
+        page_of = positions // page_size
+        slot_of = positions % page_size
+        phys = jnp.take_along_axis(page_table, page_of[:, None], axis=1)[:, 0]
+        if active is not None:
+            phys = jnp.where(active, phys, num_pages)  # drop inactive rows
+        li = jnp.broadcast_to(jnp.arange(L)[:, None], (L, b)).reshape(-1)
+        pi = jnp.broadcast_to(phys[None, :], (L, b)).reshape(-1)
+        si = jnp.broadcast_to(slot_of[None, :], (L, b)).reshape(-1)
+        flat = (L * b, cfg.num_kv_heads, cfg.head_dim)
+        new_k = k_pages.at[li, pi, si].set(k_all.reshape(flat), mode="drop")
+        new_v = v_pages.at[li, pi, si].set(v_all.reshape(flat), mode="drop")
 
     x = _norm(cfg, x, params["final_norm"])
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
@@ -633,12 +645,14 @@ def _decode_step_scatter_first(
             cfg, lp, h[:, None, :], positions[:, None], cos_tab, sin_tab
         )
         q, k, v = q[:, 0], k[:, 0], v[:, 0]  # [b, heads/kvh, hd]
-        kp = _scatter_decode(kp, k, table, positions, page_size)
-        vp = _scatter_decode(vp, v, table, positions, page_size)
-        attn = paged_decode_attention(
-            q, kp, vp, page_table, seq_lens, impl=cfg.attention_impl
-        )
-        x = x + _post(cfg, lp, "post_attn_norm", qmat(attn.reshape(b, cfg.q_dim), lp["wo"]))
+        with jax.named_scope("kv_write"):
+            kp = _scatter_decode(kp, k, table, positions, page_size)
+            vp = _scatter_decode(vp, v, table, positions, page_size)
+        with jax.named_scope("attn"):
+            attn = paged_decode_attention(
+                q, kp, vp, page_table, seq_lens, impl=cfg.attention_impl
+            )
+            x = x + _post(cfg, lp, "post_attn_norm", qmat(attn.reshape(b, cfg.q_dim), lp["wo"]))
         h = _norm(cfg, x, lp["mlp_norm"])
         x = x + _post(cfg, lp, "post_ffn_norm", _ffn(cfg, lp, h))
         return x, (kp, vp)

@@ -169,12 +169,13 @@ def moe_ffn(cfg: MoeConfig, lp: Dict[str, Any], x: jnp.ndarray) -> jnp.ndarray:
     x: [..., hidden]; lp["router"]: [h, E]; experts [E, h, f]/[E, f, h].
     """
     k = cfg.experts_per_token
-    logits = (x @ lp["router"]).astype(jnp.float32)  # [..., E]
-    top_vals, top_idx = jax.lax.top_k(logits, k)  # [..., k]
-    top_probs = jax.nn.softmax(top_vals, axis=-1)  # renormalized over top-k
-    # scatter the k probabilities back to a dense [.., E] weight vector
-    onehot = jax.nn.one_hot(top_idx, cfg.num_experts, dtype=jnp.float32)
-    weights = jnp.einsum("...k,...ke->...e", top_probs, onehot)
+    with jax.named_scope("router"):
+        logits = (x @ lp["router"]).astype(jnp.float32)  # [..., E]
+        top_vals, top_idx = jax.lax.top_k(logits, k)  # [..., k]
+        top_probs = jax.nn.softmax(top_vals, axis=-1)  # renormalized over top-k
+        # scatter the k probabilities back to a dense [.., E] weight vector
+        onehot = jax.nn.one_hot(top_idx, cfg.num_experts, dtype=jnp.float32)
+        weights = jnp.einsum("...k,...ke->...e", top_probs, onehot)
 
     g = _qeinsum("...h,ehf->...ef", x, lp["w_gate"])
     u = _qeinsum("...h,ehf->...ef", x, lp["w_up"])

@@ -173,8 +173,9 @@ def paged_decode_attention_inline(
     page_size = k_pages.shape[1]
     ctx = pages_per_seq * page_size
 
-    k = k_pages[page_table].reshape(b, ctx, kvh, d)
-    v = v_pages[page_table].reshape(b, ctx, kvh, d)
+    with jax.named_scope("kv_gather"):
+        k = k_pages[page_table].reshape(b, ctx, kvh, d)
+        v = v_pages[page_table].reshape(b, ctx, kvh, d)
     qg = (q.astype(jnp.float32) * (d**-0.5)).astype(q.dtype).reshape(b, kvh, g, d)
     logits = jnp.einsum(
         "bngd,bknd->bngk", qg, k, preferred_element_type=jnp.float32
@@ -229,8 +230,9 @@ def paged_decode_attention(
     ctx = pages_per_seq * page_size
 
     def flatten(pages):
-        g = pages[page_table]  # [b, pages_per_seq, page_size, kvh, d]
-        return g.reshape(b, ctx, kvh, d)
+        with jax.named_scope("kv_gather"):
+            g = pages[page_table]  # [b, pages_per_seq, page_size, kvh, d]
+            return g.reshape(b, ctx, kvh, d)
 
     k = _repeat_kv(flatten(k_pages), h // kvh, axis=2)  # [b, ctx, h, d]
     v = _repeat_kv(flatten(v_pages), h // kvh, axis=2)
@@ -317,8 +319,9 @@ def ragged_paged_attention(
 
     safe = jnp.clip(row_slot, 0, page_table.shape[0] - 1)
     pt = page_table[safe]  # [t, pages_per_seq]
-    k = k_pages[pt].reshape(t, ctx, kvh, d)
-    v = v_pages[pt].reshape(t, ctx, kvh, d)
+    with jax.named_scope("kv_gather"):
+        k = k_pages[pt].reshape(t, ctx, kvh, d)
+        v = v_pages[pt].reshape(t, ctx, kvh, d)
     qg = (q.astype(jnp.float32) * (d**-0.5)).astype(q.dtype).reshape(
         t, kvh, g, d
     )
@@ -361,8 +364,9 @@ def paged_suffix_attention(
     page_size = k_pages.shape[1]
     ctx = pages_per_seq * page_size
 
-    k = k_pages[page_table].reshape(b, ctx, kvh, d)
-    v = v_pages[page_table].reshape(b, ctx, kvh, d)
+    with jax.named_scope("kv_gather"):
+        k = k_pages[page_table].reshape(b, ctx, kvh, d)
+        v = v_pages[page_table].reshape(b, ctx, kvh, d)
     qg = (q.astype(jnp.float32) * (d**-0.5)).astype(q.dtype).reshape(
         b, s, kvh, g, d
     )

@@ -53,9 +53,12 @@ def check_kernel_shape(num_kv_heads: int, head_dim: int) -> None:
 
 
 def fuse_pages(pages: jnp.ndarray) -> jnp.ndarray:
-    """[num_pages, page_size, kv_heads, head_dim] -> lane-fused 3-D view."""
+    """[num_pages, page_size, kv_heads, head_dim] -> lane-fused 3-D view.
+    On the chip this is a relayout of the whole pool (PERF.md section 5),
+    so it has a scope of its own to be found by."""
     n, ps, kvh, d = pages.shape
-    return pages.reshape(n, ps, kvh * d)
+    with jax.named_scope("kv_gather"):
+        return pages.reshape(n, ps, kvh * d)
 
 
 def shard_over_tp(mesh, kernel, in_specs, out_specs):

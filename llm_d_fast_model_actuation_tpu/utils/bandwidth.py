@@ -1,9 +1,7 @@
 """Raw host<->device transfer bandwidth measurement.
 
-One implementation shared by bench.py and scripts/tpu_profile.py so the
-`host_device_*_gibps` numbers the two tools report are comparable: the
-link ceiling (PCIe on a TPU host) for checkpoint load and release-cycle
-numbers.
+bench.py's `host_device_*_gibps` numbers: the link ceiling (PCIe on a TPU
+host) for checkpoint load and release-cycle numbers.
 """
 
 from __future__ import annotations

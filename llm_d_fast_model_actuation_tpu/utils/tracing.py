@@ -182,7 +182,12 @@ def reset_after_fork() -> None:
     env_vars (FMA_TRACING / FMA_TRACE_BUFFER) win over inherited state.
     Request sampling resets to 0 (off): the child re-applies its own
     ``--trace-requests`` during engine construction."""
-    global _BUFFER, _enabled, _REQ_BUFFER, _req_frac
+    global _BUFFER, _enabled, _REQ_BUFFER, _req_frac, _capturing
+    global _admit_blocked
+    _capturing = False
+    _admit_blocked = 0
+    for name in PHASES:
+        _PHASES[name] = Phase(name)
     _BUFFER = TraceBuffer(_env_capacity())
     _REQ_BUFFER = TraceBuffer(_req_env_capacity())
     _req_frac = 0.0
@@ -570,6 +575,147 @@ def request_buffer_len() -> int:
 
 def clear_requests() -> None:
     _REQ_BUFFER.clear()
+
+
+# -- scheduler phases ---------------------------------------------------------
+#
+# The ``sched.*`` family (docs/tracing.md "Scheduler phases"): what the ONE
+# scheduler thread of an engine process is doing, part by part. A phase
+# always adds its seconds and a count to a per-process table (two monotonic
+# reads and a few additions on a preallocated object: ``GET /v1/stats``
+# ``scheduler``), and only while a profiler capture runs
+# (``POST /v1/profile`` .. ``DELETE``) also leaves a span of its name in the
+# actuation ring and a ``jax.profiler.TraceAnnotation`` of its name in the
+# capture's host plane, on the device trace's own clock.
+
+PHASES = (
+    "sched.intake", "sched.admit", "sched.prefill_dispatch",
+    "sched.prefill_fetch", "sched.upload", "sched.chunk_dispatch",
+    "sched.chunk_fetch", "sched.emit", "sched.observe", "sched.wait",
+)
+#: phases in which the loop itself waits, on the device or for work: never
+#: part of ``host_only_s``
+WAITING_PHASES = ("sched.prefill_fetch", "sched.chunk_fetch", "sched.wait")
+
+_capturing = False
+#: steps in which the head of the waiting queue was refused a slot or pages
+_admit_blocked = 0
+
+
+class Phase:
+    """One row of the table, and the context manager that fills it. One
+    object per name for the life of the process; phases do not nest and
+    belong to one thread at a time (the scheduler thread, or an admin
+    call that holds the service lock while that thread waits)."""
+
+    __slots__ = ("name", "seconds", "count", "host_only_s", "_overlapped",
+                 "_t0", "_span", "_ann")
+
+    def __init__(self, name: str) -> None:
+        self.name = name
+        self.seconds = 0.0
+        self.count = 0
+        #: the seconds of it in which no dispatched chunk was in flight
+        self.host_only_s = 0.0
+        self._overlapped = False
+        self._t0 = 0.0
+        self._span: Any = None
+        self._ann: Any = None
+
+    def __enter__(self) -> "Phase":
+        if _capturing:
+            self._span = begin(self.name, activate=False)
+            self._ann = _annotate(self.name)
+        self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        dt = time.monotonic() - self._t0
+        self.seconds += dt
+        self.count += 1
+        if not self._overlapped:
+            self.host_only_s += dt
+        if self._span is not None:
+            ann, self._ann = self._ann, None
+            ann.__exit__(exc_type, exc, tb)
+            span, self._span = self._span, None
+            span.end()
+        return False
+
+    def set(self, **attrs: Any) -> None:
+        """Attributes of the phase's span; outside a capture it costs the
+        call and does nothing."""
+        if self._span is not None:
+            self._span.set(**attrs)
+
+
+_PHASES: Dict[str, Phase] = {name: Phase(name) for name in PHASES}
+
+
+def phase(name: str, overlapped: bool = False) -> Phase:
+    """``with tracing.phase("sched.admit", chunk_in_flight): ...`` — the
+    phase named ``name`` (one of :data:`PHASES`). ``overlapped`` says a
+    dispatched decode chunk is still running on the device, so the phase's
+    seconds are not the host holding the chip back (``host_only_s``)."""
+    p = _PHASES[name]
+    p._overlapped = overlapped
+    return p
+
+
+def count_admit_blocked() -> None:
+    global _admit_blocked
+    _admit_blocked += 1
+
+
+def _annotate(name: str, **args: Any) -> Any:
+    """An entered ``jax.profiler.TraceAnnotation``. Imported here, not at
+    the top: only a process with a capture running (an engine, which has
+    jax) ever gets here, and the control plane imports this module too."""
+    from jax.profiler import TraceAnnotation
+
+    ann = TraceAnnotation(name, **args)
+    ann.__enter__()
+    return ann
+
+
+def capturing() -> bool:
+    return _capturing
+
+
+def capture_started() -> None:
+    """A profiler capture has just started in this process: phases record
+    spans and annotations from here on, and one zero-length ``fma.clock``
+    annotation carries this module's wall clock (the ``ts`` of every
+    ``GET /v1/traces`` event) into the capture, so that whoever reads both
+    can lay one on the other exactly."""
+    global _capturing
+    _annotate(
+        "fma.clock", wall_us=int(_wall(time.monotonic()) * 1e6)
+    ).__exit__(None, None, None)
+    _capturing = True
+
+
+def capture_stopped() -> None:
+    global _capturing
+    _capturing = False
+
+
+def phase_stats() -> Dict[str, Any]:
+    """The ``scheduler`` block of ``GET /v1/stats``, cumulative since the
+    process started: seconds and entries of each phase (keys without the
+    ``sched.`` prefix), ``host_only_s``, the seconds of every phase but
+    the waiting ones in which no dispatched chunk was in flight, and
+    ``admit_blocked``, the steps in which the head of the waiting queue
+    was refused a slot or pages."""
+    rows = [(p.name.partition(".")[2], p) for p in _PHASES.values()]
+    return {
+        "phase_s": {k: p.seconds for k, p in rows},
+        "phase_n": {k: p.count for k, p in rows},
+        "host_only_s": sum(
+            p.host_only_s for _, p in rows if p.name not in WAITING_PHASES
+        ),
+        "admit_blocked": _admit_blocked,
+    }
 
 
 # -- export -------------------------------------------------------------------
