@@ -392,8 +392,10 @@ def check_kernels(plan: Plan) -> List[Dict[str, Any]]:
 
         batch = 8
         num_pages = batch * pps + 1
-        k_pages = rand(num_pages, ps, kvh, d)
-        v_pages = rand(num_pages, ps, kvh, d)
+        # the stored layout (engine/kv_cache.py); the kernels read layer 1
+        layer = 1
+        k_pages = rand(2, num_pages, ps, kvh * d)
+        v_pages = rand(2, num_pages, ps, kvh * d)
         table = jnp.asarray(
             1 + np.random.default_rng(0).permutation(batch * pps).reshape(
                 batch, pps
@@ -409,12 +411,12 @@ def check_kernels(plan: Plan) -> List[Dict[str, Any]]:
         )
         compare(
             "decode", shape, attn.paged_decode_attention,
-            (rand(batch, h, d), k_pages, v_pages, table, lens),
+            (rand(batch, h, d), k_pages, v_pages, table, lens, layer),
         )
         compare(
             "decode_inline", shape, attn.paged_decode_attention_inline,
             (rand(batch, h, d), k_pages, v_pages, rand(batch, kvh, d),
-             rand(batch, kvh, d), table, lens - 1),
+             rand(batch, kvh, d), table, lens - 1, layer),
         )
         # one packed buffer: a decode row, a prefill segment from position 0
         # and a suffix continuation deep in the context, each on its own
@@ -432,7 +434,7 @@ def check_kernels(plan: Plan) -> List[Dict[str, Any]]:
         compare(
             "ragged", shape, attn.ragged_paged_attention,
             (rand(len(slot), h, d), k_pages, v_pages, table,
-             jnp.asarray(slot_a), jnp.asarray(pos_a)),
+             jnp.asarray(slot_a), jnp.asarray(pos_a), layer),
             valid=slot_a >= 0,
         )
         seq = ctx

@@ -232,6 +232,7 @@ def _abstract_state(cfg, mesh=None):
     import jax
 
     from ..models.registry import init_params_for
+    from .kv_cache import POOL_SPEC, PagePool
 
     m = cfg.model
     params = jax.eval_shape(
@@ -249,7 +250,7 @@ def _abstract_state(cfg, mesh=None):
         )
         kv_sharding = sharding
     else:
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        from jax.sharding import NamedSharding
 
         from ..models.registry import logical_axes_for
         from ..parallel.mesh import logical_shardings
@@ -258,10 +259,12 @@ def _abstract_state(cfg, mesh=None):
             lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
             params, logical_shardings(mesh, logical_axes_for(m)),
         )
-        kv_sharding = NamedSharding(mesh, P(None, None, None, "tp", None))
+        kv_sharding = NamedSharding(mesh, POOL_SPEC)
     kv = jax.ShapeDtypeStruct(
-        (m.num_layers, cfg.num_pages, cfg.page_size, m.num_kv_heads,
-         m.head_dim),
+        PagePool.pool_shape(
+            m.num_layers, cfg.num_pages, cfg.page_size, m.num_kv_heads,
+            m.head_dim,
+        ),
         m.dtype,
         sharding=kv_sharding,
     )

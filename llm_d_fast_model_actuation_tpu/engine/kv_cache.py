@@ -1,13 +1,20 @@
 """Paged KV cache: device page pools + host-side page allocator.
 
-Pool layout (per k and v): ``[num_layers, num_pages, page_size, kv_heads,
-head_dim]`` — one array for all layers so the layer axis can be scanned and
-the whole pool moved HBM<->host in one transfer on sleep/wake. kv_heads is
-sharded over `tp`; everything else replicated (pages are a node-local pool,
-like vLLM's block allocator, not a distributed object).
+Pool layout (per k and v): ``[num_layers, num_pages, page_size, kv_heads *
+head_dim]`` — the layout the attention kernels read. A page of one layer is
+a ``[page_size, kv_heads * head_dim]`` tile whose minor (lane) axis holds
+the KV heads one after another, so a KV head is a static lane slice and a
+kernel DMAs ``pool[layer, page]`` straight from the stored array: nothing
+pool-sized is sliced or re-laid-out per layer (ops/pallas/decode.py). One
+array for all layers, so the forward indexes it by layer and the whole pool
+moves HBM<->host in one transfer on sleep/wake. The fused axis is sharded
+over `tp` (:data:`POOL_SPEC`: a shard holds its ``kv_heads / tp`` heads,
+contiguous); everything else is replicated (pages are a node-local pool,
+like vLLM's block allocator, not a distributed object). Readers that want
+heads apart gather a context's pages first and split the minor axis of what
+they gathered.
 
-Page size defaults to 16 tokens: with head_dim 128 a (16, kvh_shard*128)
-page tile keeps the last dim at the TPU 128-lane boundary.
+Page size defaults to 16 tokens: a (16, kvh_shard * head_dim) page tile.
 """
 
 from __future__ import annotations
@@ -17,7 +24,9 @@ from typing import Any, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding
+
+from ..ops.attention import POOL_SPEC
 
 
 @dataclass
@@ -32,11 +41,12 @@ class PagePool:
         page_size: int,
         num_kv_heads: int,
         head_dim: int,
-    ) -> Tuple[int, int, int, int, int]:
+    ) -> Tuple[int, int, int, int]:
         """The per-direction (k or v) pool array shape — the ONE
-        definition shared by :meth:`create` and :meth:`estimate_nbytes`
-        (the cost oracle sizes a not-yet-built pool from it)."""
-        return (num_layers, num_pages, page_size, num_kv_heads, head_dim)
+        definition shared by :meth:`create`, :meth:`estimate_nbytes` (the
+        cost oracle sizes a not-yet-built pool from it) and the AOT
+        warm-up's avals (engine/exec_pool.py)."""
+        return (num_layers, num_pages, page_size, num_kv_heads * head_dim)
 
     @classmethod
     def estimate_nbytes(
@@ -95,7 +105,7 @@ class PagePool:
             num_layers, num_pages, page_size, num_kv_heads, head_dim
         )
         if mesh is not None:
-            sharding = NamedSharding(mesh, P(None, None, None, "tp", None))
+            sharding = NamedSharding(mesh, POOL_SPEC)
             zeros = jax.jit(
                 lambda: jnp.zeros(shape, dtype), out_shardings=sharding
             )

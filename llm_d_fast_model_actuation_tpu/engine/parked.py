@@ -44,8 +44,10 @@ DEFAULT_KV_CHUNK_BYTES = 256 << 20
 
 #: parked-bundle wire format version (GET/POST /v1/parked): bumped on
 #: any incompatible change so a mixed-version fleet rejects the handoff
-#: instead of mis-seating state
-WIRE_VERSION = 1
+#: instead of mis-seating state. 2: ``kv.shape`` is the pool's stored
+#: layout [layers, pages, page_size, kv_heads * head_dim] (1 had the two
+#: minor axes apart; the chunk bytes are the same)
+WIRE_VERSION = 2
 
 
 class ParkedResumeFailed(RuntimeError):
@@ -86,7 +88,8 @@ class ParkedRequests:
     waiting: List[Any] = field(default_factory=list)
     #: unique old-pool page ids in gather order (axis 1 of k/v_host)
     page_ids: List[int] = field(default_factory=list)
-    #: gathered live pages [num_layers, len(page_ids), page_size, kvh, hd]
+    #: gathered live pages, the pool's own layout (kv_cache.PagePool):
+    #: [num_layers, len(page_ids), page_size, kvh * hd]
     k_host: Optional[np.ndarray] = None
     v_host: Optional[np.ndarray] = None
     kv_nbytes: int = 0
@@ -109,7 +112,7 @@ class ParkedRequests:
 
 def _pool_page_nbytes(k_pages: Any, v_pages: Any) -> int:
     """Bytes one page occupies across k+v and all layers, derived from
-    the live pool arrays (shape [layers, num_pages, page_size, kvh, hd])."""
+    the live pool arrays (shape [layers, num_pages, page_size, kvh * hd])."""
     n = max(1, int(k_pages.shape[1]))
     return (int(k_pages.nbytes) + int(v_pages.nbytes)) // n
 
@@ -163,8 +166,8 @@ def gather_pages_d2h(
     per_page = _pool_page_nbytes(pool.k_pages, pool.v_pages)
     bucket = bucket_bytes or DEFAULT_KV_CHUNK_BYTES
     per_chunk = max(1, int(bucket) // max(1, per_page))
-    layers, _, ps, kvh, hd = pool.k_pages.shape
-    k_host = np.empty((layers, len(ids), ps, kvh, hd), pool.k_pages.dtype)
+    layers, _, ps, fused = pool.k_pages.shape
+    k_host = np.empty((layers, len(ids), ps, fused), pool.k_pages.dtype)
     v_host = np.empty_like(k_host)
     traced = tracing.enabled()
     parent = tracing.current_context() if traced else None
@@ -241,8 +244,8 @@ def scatter_pages_h2d(
             vh = np.ascontiguousarray(v_host[:, src])
             if sharding is not None:
                 # land the chunk pre-sharded like the pool it joins (the
-                # kvh axis is 'tp'-sharded on meshes; NamedSharding is
-                # shape-agnostic, so the pool's own sharding applies)
+                # fused kvh*hd axis is 'tp'-sharded on meshes; NamedSharding
+                # is shape-agnostic, so the pool's own sharding applies)
                 kd, vd = jax.device_put((kh, vh), (sharding, sharding))
             else:
                 kd, vd = jax.device_put((kh, vh))
@@ -471,7 +474,7 @@ def decode_wire(
     if page_ids:
         dtype = _np_dtype(kv["dtype"])
         shape = tuple(int(x) for x in kv["shape"])
-        if shape[1] != len(page_ids):
+        if len(shape) != 4 or shape[1] != len(page_ids):
             raise ValueError("KV shape does not match the page list")
         k_host = np.empty(shape, dtype)
         v_host = np.empty_like(k_host)

@@ -4,8 +4,11 @@ Design choices for the TPU/XLA compilation model:
   * layer params are **stacked** on a leading layer axis and the forward is a
     single ``lax.scan`` over layers — one compiled layer body regardless of
     depth (compile time O(1) in layers, the win that matters for wake-up);
-  * paged KV cache is threaded *through* the scan, so cache updates are
-    in-place (donated) scatters fused into the step;
+  * the paged KV cache is one array for all layers
+    (engine/kv_cache.py:PagePool.pool_shape) that the scan carries WHOLE and
+    indexes by layer — ``pool.at[layer, page, slot]`` writes, ``pool[layer,
+    page_table]`` reads — so cache updates are in-place (donated) scatters
+    and no layer of it is ever sliced out or stacked back;
   * all matmuls bf16 on the MXU, softmax/norm math fp32;
   * tensor-parallel sharding is expressed via logical axes only
     (`param_logical_axes`); GSPMD inserts the all-reduces.
@@ -309,42 +312,67 @@ def _project_qkv(cfg: LlamaConfig, lp, x, positions, cos_tab, sin_tab):
     return q, k, v
 
 
-def _scatter_prefill(pages, new, page_table, positions, valid, page_size):
-    """Write prefill K or V [b,s,kvh,hd] into the page pool.
+# The writers below take the WHOLE pool [L, P, page_size, kvh * hd] and a
+# layer index; what they fuse (the minor two axes of the new rows) is
+# token-sized, and the scatter updates the carried pool in place.
+
+
+def _scatter_prefill(
+    pages, layer, new, page_table, positions, valid, page_size
+):
+    """Write prefill K or V [b,s,kvh,hd] into ``pages[layer]``.
 
     Invalid (padding) positions scatter to an out-of-bounds page -> dropped.
     """
     b, s = positions.shape
-    num_pages = pages.shape[0]
+    num_pages = pages.shape[1]
     page_of = positions // page_size  # [b, s] logical page per token
     slot_of = positions % page_size
     phys = jnp.take_along_axis(page_table, page_of, axis=1)  # [b, s]
     phys = jnp.where(valid, phys, num_pages)
-    return pages.at[phys.reshape(-1), slot_of.reshape(-1)].set(
-        new.reshape((b * s,) + new.shape[2:]), mode="drop"
+    return pages.at[layer, phys.reshape(-1), slot_of.reshape(-1)].set(
+        new.reshape(b * s, -1), mode="drop"
     )
 
 
-def _scatter_rows(pages, new, page_table, row_slot, positions, page_size):
-    """Write a flat packed buffer's K or V [T, kvh, hd] into the page pool:
-    token t goes to its OWN sequence's page (``page_table[row_slot[t]]``)
-    at its own position. Padding rows (``row_slot < 0``) scatter to an
-    out-of-bounds page -> dropped."""
-    num_pages = pages.shape[0]
+def _scatter_rows(
+    pages, layer, new, page_table, row_slot, positions, page_size
+):
+    """Write a flat packed buffer's K or V [T, kvh, hd] into
+    ``pages[layer]``: token t goes to its OWN sequence's page
+    (``page_table[row_slot[t]]``) at its own position. Padding rows
+    (``row_slot < 0``) scatter to an out-of-bounds page -> dropped."""
+    num_pages = pages.shape[1]
     page_of = positions // page_size  # [T] logical page per token
     slot_of = positions % page_size
     safe = jnp.clip(row_slot, 0, page_table.shape[0] - 1)
     phys = page_table[safe, page_of]  # [T]
     phys = jnp.where(row_slot >= 0, phys, num_pages)
-    return pages.at[phys, slot_of].set(new, mode="drop")
+    return pages.at[layer, phys, slot_of].set(
+        new.reshape(new.shape[0], -1), mode="drop"
+    )
 
 
-def _scatter_decode(pages, new, page_table, positions, page_size):
-    """Write one token's K or V [b,kvh,hd] at `positions` [b]."""
+def _scatter_decode(pages, layer, new, page_table, positions, page_size):
+    """Write one token's K or V [b,kvh,hd] at `positions` [b] of
+    ``pages[layer]``."""
     page_of = positions // page_size
     slot_of = positions % page_size
     phys = jnp.take_along_axis(page_table, page_of[:, None], axis=1)[:, 0]
-    return pages.at[phys, slot_of].set(new, mode="drop")
+    return pages.at[layer, phys, slot_of].set(
+        new.reshape(new.shape[0], -1), mode="drop"
+    )
+
+
+def _scan_layers(layer_fn, x, params, cache):
+    """Scan ``layer_fn((x, k_pages, v_pages), (layer_params, layer))`` over
+    the stacked layers with the whole pool in the carry."""
+    k_pages, v_pages = cache
+    layers = jnp.arange(k_pages.shape[0], dtype=jnp.int32)
+    (x, k_pages, v_pages), _ = jax.lax.scan(
+        layer_fn, (x, k_pages, v_pages), (params["layers"], layers)
+    )
+    return x, (k_pages, v_pages)
 
 
 def prefill(
@@ -352,7 +380,7 @@ def prefill(
     cfg: LlamaConfig,
     tokens: jnp.ndarray,  # [b, s] int32, right-padded
     seq_lens: jnp.ndarray,  # [b] int32
-    cache: Tuple[jnp.ndarray, jnp.ndarray],  # k/v pages [L, P, ps, kvh, hd]
+    cache: Tuple[jnp.ndarray, jnp.ndarray],  # k/v pools [L, P, ps, kvh*hd]
     page_table: jnp.ndarray,  # [b, pages_per_seq] int32
     mesh=None,  # tp mesh: the pallas attention impl runs under shard_map
 ):
@@ -362,8 +390,7 @@ def prefill(
     seq_lens-1 to sample the first generated token.
     """
     b, s = tokens.shape
-    k_pages, v_pages = cache
-    page_size = k_pages.shape[2]
+    page_size = cache[0].shape[2]
     cos_tab, sin_tab = rope_table(
         cfg.max_seq_len, cfg.head_dim, cfg.rope_theta, cfg.rope_scaling
     )
@@ -373,14 +400,15 @@ def prefill(
 
     x = _embed_tokens(cfg, params, tokens)
 
-    def layer(x, scanned):
-        lp, kp, vp = scanned
+    def layer(carry, scanned):
+        x, kp, vp = carry
+        lp, li = scanned
         h = _norm(cfg, x, lp["attn_norm"])
         with jax.named_scope("attn"):
             q, k, v = _project_qkv(cfg, lp, h, positions, cos_tab, sin_tab)
         with jax.named_scope("kv_write"):
-            kp = _scatter_prefill(kp, k, page_table, positions, valid, page_size)
-            vp = _scatter_prefill(vp, v, page_table, positions, valid, page_size)
+            kp = _scatter_prefill(kp, li, k, page_table, positions, valid, page_size)
+            vp = _scatter_prefill(vp, li, v, page_table, positions, valid, page_size)
         with jax.named_scope("attn"):
             attn = causal_prefill_attention(
                 q, k, v, seq_lens, impl=cfg.attention_impl, mesh=mesh
@@ -388,15 +416,13 @@ def prefill(
             x = x + _post(cfg, lp, "post_attn_norm", qmat(attn.reshape(b, s, cfg.q_dim), lp["wo"]))
         h = _norm(cfg, x, lp["mlp_norm"])
         x = x + _post(cfg, lp, "post_ffn_norm", _ffn(cfg, lp, h))
-        return x, (kp, vp)
+        return (x, kp, vp), None
 
-    x, (new_k, new_v) = jax.lax.scan(
-        layer, x, (params["layers"], k_pages, v_pages)
-    )
+    x, cache = _scan_layers(layer, x, params, cache)
     x = _norm(cfg, x, params["final_norm"])
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = qmat(x, head).astype(jnp.float32)
-    return logits, (new_k, new_v)
+    return logits, cache
 
 
 def prefill_continue(
@@ -419,8 +445,7 @@ def prefill_continue(
     from ..ops.attention import paged_suffix_attention
 
     b, s = tokens.shape
-    k_pages, v_pages = cache
-    page_size = k_pages.shape[2]
+    page_size = cache[0].shape[2]
     cos_tab, sin_tab = rope_table(
         cfg.max_seq_len, cfg.head_dim, cfg.rope_theta, cfg.rope_scaling
     )
@@ -432,28 +457,27 @@ def prefill_continue(
 
     x = _embed_tokens(cfg, params, tokens)
 
-    def layer(x, scanned):
-        lp, kp, vp = scanned
+    def layer(carry, scanned):
+        x, kp, vp = carry
+        lp, li = scanned
         h = _norm(cfg, x, lp["attn_norm"])
         with jax.named_scope("attn"):
             q, k, v = _project_qkv(cfg, lp, h, positions, cos_tab, sin_tab)
         with jax.named_scope("kv_write"):
-            kp = _scatter_prefill(kp, k, page_table, positions, valid, page_size)
-            vp = _scatter_prefill(vp, v, page_table, positions, valid, page_size)
+            kp = _scatter_prefill(kp, li, k, page_table, positions, valid, page_size)
+            vp = _scatter_prefill(vp, li, v, page_table, positions, valid, page_size)
         with jax.named_scope("attn"):
-            attn = paged_suffix_attention(q, kp, vp, page_table, start)
+            attn = paged_suffix_attention(q, kp, vp, page_table, start, li)
             x = x + _post(cfg, lp, "post_attn_norm", qmat(attn.reshape(b, s, cfg.q_dim), lp["wo"]))
         h = _norm(cfg, x, lp["mlp_norm"])
         x = x + _post(cfg, lp, "post_ffn_norm", _ffn(cfg, lp, h))
-        return x, (kp, vp)
+        return (x, kp, vp), None
 
-    x, (new_k, new_v) = jax.lax.scan(
-        layer, x, (params["layers"], k_pages, v_pages)
-    )
+    x, cache = _scan_layers(layer, x, params, cache)
     x = _norm(cfg, x, params["final_norm"])
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = qmat(x, head).astype(jnp.float32)
-    return logits, (new_k, new_v)
+    return logits, cache
 
 
 def mixed_step(
@@ -489,16 +513,16 @@ def mixed_step(
     rows write nothing and produce garbage logits.
     """
     (T,) = tokens.shape
-    k_pages, v_pages = cache
-    page_size = k_pages.shape[2]
+    page_size = cache[0].shape[2]
     cos_tab, sin_tab = rope_table(
         cfg.max_seq_len, cfg.head_dim, cfg.rope_theta, cfg.rope_scaling
     )
 
     x = _embed_tokens(cfg, params, tokens)  # [T, h]
 
-    def layer(x, scanned):
-        lp, kp, vp = scanned
+    def layer(carry, scanned):
+        x, kp, vp = carry
+        lp, li = scanned
         h = _norm(cfg, x, lp["attn_norm"])
         with jax.named_scope("attn"):
             q, k, v = _project_qkv(
@@ -506,11 +530,11 @@ def mixed_step(
             )
             q, k, v = q[0], k[0], v[0]  # [T, heads/kvh, hd]
         with jax.named_scope("kv_write"):
-            kp = _scatter_rows(kp, k, page_table, row_slot, positions, page_size)
-            vp = _scatter_rows(vp, v, page_table, row_slot, positions, page_size)
+            kp = _scatter_rows(kp, li, k, page_table, row_slot, positions, page_size)
+            vp = _scatter_rows(vp, li, v, page_table, row_slot, positions, page_size)
         with jax.named_scope("attn"):
             attn = ragged_paged_attention(
-                q, kp, vp, page_table, row_slot, positions,
+                q, kp, vp, page_table, row_slot, positions, li,
                 impl=cfg.attention_impl, mesh=mesh,
             )
             x = x + _post(
@@ -519,15 +543,13 @@ def mixed_step(
             )
         h = _norm(cfg, x, lp["mlp_norm"])
         x = x + _post(cfg, lp, "post_ffn_norm", _ffn(cfg, lp, h))
-        return x, (kp, vp)
+        return (x, kp, vp), None
 
-    x, (new_k, new_v) = jax.lax.scan(
-        layer, x, (params["layers"], k_pages, v_pages)
-    )
+    x, cache = _scan_layers(layer, x, params, cache)
     x = _norm(cfg, x, params["final_norm"])
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = qmat(x, head).astype(jnp.float32)
-    return logits, (new_k, new_v)
+    return logits, cache
 
 
 def decode_step(
@@ -549,9 +571,10 @@ def decode_step(
         attending (2 scatters x num_layers; the baseline semantics).
       * ``grouped`` / ``pallas`` — the serving fast path: attention reads the
         pool for positions < pos and takes the new token's K/V inline, so all
-        layers' writes defer to ONE scatter after the layer scan. On TPU each
-        XLA pool scatter costs far more than the bytes it writes, so this is
-        the difference between ~480 and ~1100 tok/s on one v5e chip.
+        layers' writes defer to ONE scatter after the layer scan (on TPU
+        each XLA pool scatter costs far more than the bytes it writes). The
+        scan closes over the whole pool and hands attention a layer index,
+        so nothing pool-sized is produced per layer (PERF.md section 6, PR 26).
 
     ``active`` masks rows of a frozen slot (budget exhausted mid-chunk): their
     K/V writes drop (scatter to the out-of-bounds page) so replayed steps
@@ -571,8 +594,10 @@ def decode_step(
 
     x = _embed_tokens(cfg, params, tokens)  # [b, h]
 
+    L = k_pages.shape[0]
+
     def layer(x, scanned):
-        lp, kp, vp = scanned
+        lp, li = scanned
         h = _norm(cfg, x, lp["attn_norm"])
         with jax.named_scope("attn"):
             q, k, v = _project_qkv(
@@ -580,8 +605,8 @@ def decode_step(
             )
             q, k, v = q[:, 0], k[:, 0], v[:, 0]  # [b, heads/kvh, hd]
             attn = paged_decode_attention_inline(
-                q, kp, vp, k, v, page_table, positions, impl=cfg.attention_impl,
-                mesh=mesh,
+                q, k_pages, v_pages, k, v, page_table, positions, li,
+                impl=cfg.attention_impl, mesh=mesh,
             )
             x = x + _post(cfg, lp, "post_attn_norm", qmat(attn.reshape(b, cfg.q_dim), lp["wo"]))
         h = _norm(cfg, x, lp["mlp_norm"])
@@ -589,11 +614,10 @@ def decode_step(
         return x, (k, v)
 
     x, (k_all, v_all) = jax.lax.scan(
-        layer, x, (params["layers"], k_pages, v_pages)
+        layer, x, (params["layers"], jnp.arange(L, dtype=jnp.int32))
     )
     # One scatter for all layers: k_all/v_all are [L, b, kvh, hd].
     with jax.named_scope("kv_write"):
-        L = k_all.shape[0]
         page_of = positions // page_size
         slot_of = positions % page_size
         phys = jnp.take_along_axis(page_table, page_of[:, None], axis=1)[:, 0]
@@ -602,7 +626,7 @@ def decode_step(
         li = jnp.broadcast_to(jnp.arange(L)[:, None], (L, b)).reshape(-1)
         pi = jnp.broadcast_to(phys[None, :], (L, b)).reshape(-1)
         si = jnp.broadcast_to(slot_of[None, :], (L, b)).reshape(-1)
-        flat = (L * b, cfg.num_kv_heads, cfg.head_dim)
+        flat = (L * b, cfg.kv_dim)
         new_k = k_pages.at[li, pi, si].set(k_all.reshape(flat), mode="drop")
         new_v = v_pages.at[li, pi, si].set(v_all.reshape(flat), mode="drop")
 
@@ -623,9 +647,7 @@ def _decode_step_scatter_first(
 ):
     """The baseline decode step: per-layer scatter-then-attend."""
     b = tokens.shape[0]
-    k_pages, v_pages = cache
-    page_size = k_pages.shape[2]
-    num_pages = k_pages.shape[1]
+    _, num_pages, page_size, _ = cache[0].shape
     cos_tab, sin_tab = rope_table(
         cfg.max_seq_len, cfg.head_dim, cfg.rope_theta, cfg.rope_scaling
     )
@@ -638,29 +660,28 @@ def _decode_step_scatter_first(
 
     x = _embed_tokens(cfg, params, tokens)  # [b, h]
 
-    def layer(x, scanned):
-        lp, kp, vp = scanned
+    def layer(carry, scanned):
+        x, kp, vp = carry
+        lp, li = scanned
         h = _norm(cfg, x, lp["attn_norm"])
         q, k, v = _project_qkv(
             cfg, lp, h[:, None, :], positions[:, None], cos_tab, sin_tab
         )
         q, k, v = q[:, 0], k[:, 0], v[:, 0]  # [b, heads/kvh, hd]
         with jax.named_scope("kv_write"):
-            kp = _scatter_decode(kp, k, table, positions, page_size)
-            vp = _scatter_decode(vp, v, table, positions, page_size)
+            kp = _scatter_decode(kp, li, k, table, positions, page_size)
+            vp = _scatter_decode(vp, li, v, table, positions, page_size)
         with jax.named_scope("attn"):
             attn = paged_decode_attention(
-                q, kp, vp, page_table, seq_lens, impl=cfg.attention_impl
+                q, kp, vp, page_table, seq_lens, li, impl=cfg.attention_impl
             )
             x = x + _post(cfg, lp, "post_attn_norm", qmat(attn.reshape(b, cfg.q_dim), lp["wo"]))
         h = _norm(cfg, x, lp["mlp_norm"])
         x = x + _post(cfg, lp, "post_ffn_norm", _ffn(cfg, lp, h))
-        return x, (kp, vp)
+        return (x, kp, vp), None
 
-    x, (new_k, new_v) = jax.lax.scan(
-        layer, x, (params["layers"], k_pages, v_pages)
-    )
+    x, cache = _scan_layers(layer, x, params, cache)
     x = _norm(cfg, x, params["final_norm"])
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = qmat(x, head).astype(jnp.float32)
-    return logits, (new_k, new_v)
+    return logits, cache

@@ -2,16 +2,24 @@
 
 The serving engine keeps the KV cache *paged*: a global pool of fixed-size
 pages per layer, with per-sequence page tables — the vLLM paged-KV idea laid
-out for TPU: page_size is a multiple of the VPU lane tile, the kv_heads axis
-is sharded over the `tp` mesh axis, and the gather by page table lowers to a
+out for TPU: a page's minor axis holds the KV heads lane-fused, that axis is
+sharded over the `tp` mesh axis, and the gather by page table lowers to a
 dynamic-slice-friendly pattern XLA handles well (a Pallas ragged kernel can
 replace it behind the same signature; see `ops/pallas/`).
 
-Shapes (per layer):
-  k_pages, v_pages: [num_pages, page_size, kv_heads, head_dim]
+Shapes (the pool as stored, engine/kv_cache.py:PagePool.pool_shape):
+  k_pages, v_pages: [num_layers, num_pages, page_size, kv_heads * head_dim]
+                    — every paged op takes the WHOLE pool and a layer
+  layer:            int32 scalar; ops index ``pool[layer, page_table]``
+                    inside their gather or DMA, never slicing a layer out
+                    first (a pool-sized copy on the chip)
   page_table:       [batch, pages_per_seq] int32 (entries past the sequence
                     end are arbitrary; masked by seq_lens)
   seq_lens:         [batch] int32 — tokens currently in cache per sequence
+
+The XLA paths split the fused axis of what they GATHERED (context-sized)
+into [kv_heads, head_dim]; kv_heads comes from the fused width over q's
+head_dim.
 
 All softmax math is fp32 regardless of the io dtype.
 """
@@ -30,7 +38,11 @@ NEG_INF = -1e30
 #: (ops/pallas/decode.py:shard_over_tp): heads / KV heads sharded, page
 #: tables and lengths replicated
 _HEADS3 = P(None, "tp", None)  # [batch|tokens, heads, head_dim]
-_HEADS4 = P(None, None, "tp", None)  # pages, or [batch, seq, heads, head_dim]
+_HEADS4 = P(None, None, "tp", None)  # [batch, seq, heads, head_dim]
+#: the KV pool on a tp mesh, as stored (PagePool.create) and as the kernels'
+#: shard_map takes it: the lane-fused KV-head axis sharded, so a shard's
+#: slice is its kv_heads/tp heads, contiguous
+POOL_SPEC = P(None, None, None, "tp")
 
 #: Selected implementation: "reference" (pure XLA) or "pallas" (TPU
 #: kernels). Read at trace time — switch before (re-)jitting.
@@ -65,6 +77,16 @@ def _pallas_interpret() -> bool:
             "kernels must compile for the chip"
         )
     return _INTERPRET
+
+
+def _gather_context(pages, layer, page_table, head_dim):
+    """[n, pages_per_seq] page ids of ``pages[layer]`` ->
+    [n, ctx, kv_heads, head_dim]: the gather reads the pool in place and the
+    heads are split out of what it returned."""
+    with jax.named_scope("kv_gather"):
+        g = pages[layer, page_table]  # [n, pages_per_seq, page_size, fused]
+        n, pps, ps, fused = g.shape
+        return g.reshape(n, pps * ps, fused // head_dim, head_dim)
 
 
 def _repeat_kv(x: jnp.ndarray, n_rep: int, axis: int) -> jnp.ndarray:
@@ -128,13 +150,14 @@ def causal_prefill_attention(
 
 def paged_decode_attention_inline(
     q: jnp.ndarray,  # [batch, heads, head_dim] — the new token's queries
-    k_pages: jnp.ndarray,  # [num_pages, page_size, kv_heads, head_dim]
-    v_pages: jnp.ndarray,  # [num_pages, page_size, kv_heads, head_dim]
+    k_pages: jnp.ndarray,  # [layers, num_pages, page_size, kv_heads*head_dim]
+    v_pages: jnp.ndarray,  # same
     k_new: jnp.ndarray,  # [batch, kv_heads, head_dim] — the new token's K
     v_new: jnp.ndarray,  # [batch, kv_heads, head_dim] — the new token's V
     page_table: jnp.ndarray,  # [batch, pages_per_seq] int32
     positions: jnp.ndarray,  # [batch] int32 — position of the new token;
     #                          cache entries < position are attended
+    layer: jnp.ndarray,  # int32 scalar — the pool layer to read
     impl: "str | None" = None,
     mesh=None,  # tp mesh: the pallas impl runs under shard_map
 ) -> jnp.ndarray:
@@ -162,20 +185,15 @@ def paged_decode_attention_inline(
         )
         return shard_over_tp(
             mesh, kernel,
-            (_HEADS3, _HEADS4, _HEADS4, _HEADS3, _HEADS3, P(None, None),
-             P(None)),
+            (_HEADS3, POOL_SPEC, POOL_SPEC, _HEADS3, _HEADS3, P(None, None),
+             P(None), P()),
             _HEADS3,
-        )(q, k_pages, v_pages, k_new, v_new, page_table, positions)
+        )(q, k_pages, v_pages, k_new, v_new, page_table, positions, layer)
     b, h, d = q.shape
-    kvh = k_pages.shape[2]
+    k = _gather_context(k_pages, layer, page_table, d)
+    v = _gather_context(v_pages, layer, page_table, d)
+    ctx, kvh = k.shape[1:3]
     g = h // kvh
-    pages_per_seq = page_table.shape[1]
-    page_size = k_pages.shape[1]
-    ctx = pages_per_seq * page_size
-
-    with jax.named_scope("kv_gather"):
-        k = k_pages[page_table].reshape(b, ctx, kvh, d)
-        v = v_pages[page_table].reshape(b, ctx, kvh, d)
     qg = (q.astype(jnp.float32) * (d**-0.5)).astype(q.dtype).reshape(b, kvh, g, d)
     logits = jnp.einsum(
         "bngd,bknd->bngk", qg, k, preferred_element_type=jnp.float32
@@ -198,17 +216,18 @@ def paged_decode_attention_inline(
 
 def paged_decode_attention(
     q: jnp.ndarray,  # [batch, heads, head_dim] — one new token per sequence
-    k_pages: jnp.ndarray,  # [num_pages, page_size, kv_heads, head_dim]
-    v_pages: jnp.ndarray,  # [num_pages, page_size, kv_heads, head_dim]
+    k_pages: jnp.ndarray,  # [layers, num_pages, page_size, kv_heads*head_dim]
+    v_pages: jnp.ndarray,  # same
     page_table: jnp.ndarray,  # [batch, pages_per_seq] int32
     seq_lens: jnp.ndarray,  # [batch] int32 (length INCLUDING the new token)
+    layer: jnp.ndarray,  # int32 scalar — the pool layer to read
     impl: "str | None" = None,  # None -> module default
     mesh=None,  # tp mesh: the pallas impl runs under shard_map
 ) -> jnp.ndarray:
     """One decode step of attention against the paged cache.
 
-    Reference implementation: gather each sequence's pages, flatten to a
-    [batch, ctx, kv_heads, head_dim] view, mask past seq_len. ctx =
+    Reference implementation: gather each sequence's pages of the layer,
+    flatten to a [batch, ctx, kv_heads, head_dim] view, mask past seq_len. ctx =
     pages_per_seq * page_size is static, so the whole step is one fused
     region under jit — no dynamic shapes.
     """
@@ -221,21 +240,15 @@ def paged_decode_attention(
         )
         return shard_over_tp(
             mesh, kernel,
-            (_HEADS3, _HEADS4, _HEADS4, P(None, None), P(None)), _HEADS3,
-        )(q, k_pages, v_pages, page_table, seq_lens)
+            (_HEADS3, POOL_SPEC, POOL_SPEC, P(None, None), P(None), P()),
+            _HEADS3,
+        )(q, k_pages, v_pages, page_table, seq_lens, layer)
     b, h, d = q.shape
-    pages_per_seq = page_table.shape[1]
-    page_size = k_pages.shape[1]
-    kvh = k_pages.shape[2]
-    ctx = pages_per_seq * page_size
-
-    def flatten(pages):
-        with jax.named_scope("kv_gather"):
-            g = pages[page_table]  # [b, pages_per_seq, page_size, kvh, d]
-            return g.reshape(b, ctx, kvh, d)
-
-    k = _repeat_kv(flatten(k_pages), h // kvh, axis=2)  # [b, ctx, h, d]
-    v = _repeat_kv(flatten(v_pages), h // kvh, axis=2)
+    k = _gather_context(k_pages, layer, page_table, d)  # [b, ctx, kvh, d]
+    v = _gather_context(v_pages, layer, page_table, d)
+    ctx, kvh = k.shape[1:3]
+    k = _repeat_kv(k, h // kvh, axis=2)  # [b, ctx, h, d]
+    v = _repeat_kv(v, h // kvh, axis=2)
 
     qf = q.astype(jnp.float32) * (d**-0.5)
     logits = jnp.einsum("bhd,bkhd->bhk", qf, k.astype(jnp.float32))
@@ -258,12 +271,13 @@ RAGGED_BLOCK = 8
 
 def ragged_paged_attention(
     q: jnp.ndarray,  # [tokens, heads, head_dim] — flat packed token buffer
-    k_pages: jnp.ndarray,  # [num_pages, page_size, kv_heads, head_dim]
-    v_pages: jnp.ndarray,  # [num_pages, page_size, kv_heads, head_dim]
+    k_pages: jnp.ndarray,  # [layers, num_pages, page_size, kv_heads*head_dim]
+    v_pages: jnp.ndarray,  # same
     page_table: jnp.ndarray,  # [rows, pages_per_seq] int32
     row_slot: jnp.ndarray,  # [tokens] int32 — page_table row per token;
     #                         -1 marks a padding row (output is garbage)
     positions: jnp.ndarray,  # [tokens] int32 — absolute position per token
+    layer: jnp.ndarray,  # int32 scalar — the pool layer to read
     impl: "str | None" = None,  # None -> module default
     mesh=None,  # tp mesh for the pallas impl's shard_map port; the XLA
     #            twin never needs it (GSPMD partitions it in place)
@@ -301,27 +315,22 @@ def ragged_paged_attention(
 
             return ragged_paged_attention_pallas_sharded(
                 mesh, q, k_pages, v_pages, page_table, row_slot,
-                positions, block_rows=RAGGED_BLOCK,
+                positions, layer, block_rows=RAGGED_BLOCK,
                 interpret=_pallas_interpret(),
             )
         from .pallas import ragged_paged_attention_pallas
 
         return ragged_paged_attention_pallas(
-            q, k_pages, v_pages, page_table, row_slot, positions,
+            q, k_pages, v_pages, page_table, row_slot, positions, layer,
             block_rows=RAGGED_BLOCK, interpret=_pallas_interpret(),
         )
     t, h, d = q.shape
-    kvh = k_pages.shape[2]
-    g = h // kvh
-    pages_per_seq = page_table.shape[1]
-    page_size = k_pages.shape[1]
-    ctx = pages_per_seq * page_size
-
     safe = jnp.clip(row_slot, 0, page_table.shape[0] - 1)
     pt = page_table[safe]  # [t, pages_per_seq]
-    with jax.named_scope("kv_gather"):
-        k = k_pages[pt].reshape(t, ctx, kvh, d)
-        v = v_pages[pt].reshape(t, ctx, kvh, d)
+    k = _gather_context(k_pages, layer, pt, d)  # [t, ctx, kvh, d]
+    v = _gather_context(v_pages, layer, pt, d)
+    ctx, kvh = k.shape[1:3]
+    g = h // kvh
     qg = (q.astype(jnp.float32) * (d**-0.5)).astype(q.dtype).reshape(
         t, kvh, g, d
     )
@@ -341,10 +350,11 @@ def ragged_paged_attention(
 
 def paged_suffix_attention(
     q: jnp.ndarray,  # [batch, s, heads, head_dim] — suffix queries
-    k_pages: jnp.ndarray,  # [num_pages, page_size, kv_heads, head_dim]
-    v_pages: jnp.ndarray,  # [num_pages, page_size, kv_heads, head_dim]
+    k_pages: jnp.ndarray,  # [layers, num_pages, page_size, kv_heads*head_dim]
+    v_pages: jnp.ndarray,  # same
     page_table: jnp.ndarray,  # [batch, pages_per_seq] int32
     start: jnp.ndarray,  # [batch] int32 — absolute position of query 0
+    layer: jnp.ndarray,  # int32 scalar — the pool layer to read
 ) -> jnp.ndarray:
     """Causal attention for a prompt SUFFIX over the paged cache.
 
@@ -358,15 +368,10 @@ def paged_suffix_attention(
     one fused region.
     """
     b, s, h, d = q.shape
-    kvh = k_pages.shape[2]
+    k = _gather_context(k_pages, layer, page_table, d)  # [b, ctx, kvh, d]
+    v = _gather_context(v_pages, layer, page_table, d)
+    ctx, kvh = k.shape[1:3]
     g = h // kvh
-    pages_per_seq = page_table.shape[1]
-    page_size = k_pages.shape[1]
-    ctx = pages_per_seq * page_size
-
-    with jax.named_scope("kv_gather"):
-        k = k_pages[page_table].reshape(b, ctx, kvh, d)
-        v = v_pages[page_table].reshape(b, ctx, kvh, d)
     qg = (q.astype(jnp.float32) * (d**-0.5)).astype(q.dtype).reshape(
         b, s, kvh, g, d
     )

@@ -9,18 +9,22 @@ HBM->VMEM page DMA behind the per-page flash-attention accumulation, and
 never materializes repeated KV heads. Decode is HBM-bandwidth-bound, so
 bytes-not-read is time-not-spent.
 
-Layout contract (shared with the engine's KV pool):
-  k_pages, v_pages: [num_pages, page_size, kv_heads, head_dim]  (HBM)
+Layout contract (the engine's KV pool as it is stored,
+engine/kv_cache.py:PagePool.pool_shape):
+  k_pages, v_pages: [num_layers, num_pages, page_size, kv_heads * head_dim]
+                    (HBM; the WHOLE pool, never a layer cut out of it)
+  layer:            int32 scalar  (scalar-prefetched)
   page_table:       [batch, pages_per_seq] int32  (scalar-prefetched)
   seq_lens:         [batch] int32, length INCLUDING the new token
   q:                [batch, heads, head_dim]
 
-Inside the kernel a page is a ``[page_size, kv_heads * head_dim]`` tile: the
-two minor axes are fused so the lane axis is kv_heads*head_dim wide, and a KV
-head is a static lane slice of it. Mosaic refuses to DMA or slice a memref
-whose minor dim is narrower than the 128-lane tile, so the unfused layout
-cannot serve head_dim 64; the fused one serves every shape with
-``kv_heads * head_dim % 128 == 0`` (:func:`check_kernel_shape`).
+The kernel DMAs ``pool[layer, page]``, a ``[page_size, kv_heads * head_dim]``
+tile, straight from the stored array: the lane axis is kv_heads*head_dim
+wide and a KV head is a static lane slice of it. Mosaic refuses to DMA or
+slice a memref whose minor dim is narrower than the 128-lane tile, so a pool
+with head_dim minor could not serve head_dim 64; the fused one serves every
+shape with ``kv_heads * head_dim % 128 == 0`` (:func:`check_kernel_shape`,
+a constraint of these kernels, not of the stored layout).
 """
 
 from __future__ import annotations
@@ -52,24 +56,16 @@ def check_kernel_shape(num_kv_heads: int, head_dim: int) -> None:
         )
 
 
-def fuse_pages(pages: jnp.ndarray) -> jnp.ndarray:
-    """[num_pages, page_size, kv_heads, head_dim] -> lane-fused 3-D view.
-    On the chip this is a relayout of the whole pool (PERF.md section 5),
-    so it has a scope of its own to be found by."""
-    n, ps, kvh, d = pages.shape
-    with jax.named_scope("kv_gather"):
-        return pages.reshape(n, ps, kvh * d)
-
-
 def shard_over_tp(mesh, kernel, in_specs, out_specs):
     """``kernel`` (a per-device Pallas call) on a tp mesh; itself when
     ``mesh`` is None. GSPMD cannot partition a Mosaic kernel, so it runs
     under ``shard_map`` over the ``tp`` axis — the axis the engine shards
-    query heads, KV heads and the page pool over — each shard seeing its
-    own head slice and the replicated page table / lengths. Head-sharded
-    GQA needs no cross-shard softmax: every query head's softmax completes
-    inside the shard that owns its KV-head group (``kv_heads % tp == 0``,
-    as the NamedSharding placement already requires)."""
+    query heads, KV heads and the page pool's fused axis over — each shard
+    seeing its own head slice and the replicated page table / lengths.
+    Head-sharded GQA needs no cross-shard softmax: every query head's
+    softmax completes inside the shard that owns its KV-head group
+    (``kv_heads % tp == 0``, as the NamedSharding placement already
+    requires)."""
     if mesh is None:
         return kernel
     # the pallas body is opaque to the varying-axes checker; the out_specs
@@ -84,6 +80,7 @@ def _decode_kernel(
     # scalar prefetch
     page_table_ref,  # [batch, pages_per_seq] SMEM
     len_ref,  # [batch] SMEM — cache entries attended (positions < len)
+    layer_ref,  # [1] SMEM — the layer of the pool this call reads
     # inputs
     q_ref,  # [1, heads, head_dim] VMEM
     *refs,
@@ -100,18 +97,19 @@ def _decode_kernel(
     running (m, l, acc) state after the page walk."""
     if inline:
         knew_ref, vnew_ref, *refs = refs
-    # k_hbm, v_hbm: [num_pages, page_size, kv_heads * head_dim] HBM/ANY
+    # k_hbm, v_hbm: [layers, num_pages, page_size, kv_heads * head_dim] HBM/ANY
     # o_ref: [1, heads, head_dim] VMEM
     # k_buf, v_buf: [2, page_size, kv_heads * head_dim] VMEM; sems: DMA [2, 2]
     k_hbm, v_hbm, o_ref, k_buf, v_buf, sems = refs
     b = pl.program_id(0)
     group = num_heads // num_kv_heads
     kv_len = len_ref[b]
+    layer = layer_ref[0]
     num_pages = jax.lax.div(kv_len + page_size - 1, page_size)
 
     def page_dma(buf, hbm, slot, p, sem_row):
         return pltpu.make_async_copy(
-            hbm.at[page_table_ref[b, p]],
+            hbm.at[layer, page_table_ref[b, p]],
             buf.at[slot],
             sems.at[sem_row, slot],
         )
@@ -202,12 +200,14 @@ def _decode_kernel(
     o_ref[0] = out.astype(o_ref.dtype)
 
 
-def _paged_decode(q, k_pages, v_pages, page_table, kv_lens, new_kv, interpret):
+def _paged_decode(
+    q, k_pages, v_pages, page_table, kv_lens, layer, new_kv, interpret
+):
     batch, num_heads, head_dim = q.shape
-    _, page_size, num_kv_heads, _ = k_pages.shape
+    _, _, page_size, fused = k_pages.shape
+    num_kv_heads = fused // head_dim
     if not interpret:  # the interpreter has no tiling to satisfy
         check_kernel_shape(num_kv_heads, head_dim)
-    fused = num_kv_heads * head_dim
 
     kernel = functools.partial(
         _decode_kernel,
@@ -221,7 +221,7 @@ def _paged_decode(q, k_pages, v_pages, page_table, kv_lens, new_kv, interpret):
         shape, lambda b, *_: (b,) + (0,) * (len(shape) - 1), memory_space=pltpu.VMEM
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(batch,),
         in_specs=[
             row_spec((1, num_heads, head_dim)),
@@ -245,38 +245,42 @@ def _paged_decode(q, k_pages, v_pages, page_table, kv_lens, new_kv, interpret):
     )(
         page_table.astype(jnp.int32),
         kv_lens.astype(jnp.int32),
+        jnp.asarray(layer, jnp.int32).reshape(1),
         q,
         *(x.reshape(batch, 1, fused) for x in new_kv),
-        fuse_pages(k_pages),
-        fuse_pages(v_pages),
+        k_pages,
+        v_pages,
     )
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_attention_inline_pallas(
     q: jnp.ndarray,  # [batch, heads, head_dim]
-    k_pages: jnp.ndarray,  # [num_pages, page_size, kv_heads, head_dim]
+    k_pages: jnp.ndarray,  # [layers, num_pages, page_size, kv_heads*head_dim]
     v_pages: jnp.ndarray,
     k_new: jnp.ndarray,  # [batch, kv_heads, head_dim]
     v_new: jnp.ndarray,
     page_table: jnp.ndarray,  # [batch, pages_per_seq] int32
     positions: jnp.ndarray,  # [batch] int32 — cache holds entries < position
+    layer: jnp.ndarray,  # int32 scalar — the pool layer to read
     interpret: bool = False,
 ) -> jnp.ndarray:
     return _paged_decode(
-        q, k_pages, v_pages, page_table, positions, (k_new, v_new), interpret
+        q, k_pages, v_pages, page_table, positions, layer, (k_new, v_new),
+        interpret,
     )
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_attention_pallas(
     q: jnp.ndarray,  # [batch, heads, head_dim]
-    k_pages: jnp.ndarray,  # [num_pages, page_size, kv_heads, head_dim]
+    k_pages: jnp.ndarray,  # [layers, num_pages, page_size, kv_heads*head_dim]
     v_pages: jnp.ndarray,
     page_table: jnp.ndarray,  # [batch, pages_per_seq] int32
     seq_lens: jnp.ndarray,  # [batch] int32
+    layer: jnp.ndarray,  # int32 scalar — the pool layer to read
     interpret: bool = False,
 ) -> jnp.ndarray:
     return _paged_decode(
-        q, k_pages, v_pages, page_table, seq_lens, (), interpret
+        q, k_pages, v_pages, page_table, seq_lens, layer, (), interpret
     )

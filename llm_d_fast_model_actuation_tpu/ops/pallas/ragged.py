@@ -25,19 +25,20 @@ Packing contract (the engine's packer upholds it, engine/engine.py):
 
 Grid: one program per row block. GQA reads each KV head's page tile once
 per block and loops the query heads of its group over it — repeated KV
-heads are never materialized, mirroring the decode kernel. Pages are
-lane-fused ``[page_size, kv_heads * head_dim]`` tiles as there (same
-Mosaic constraint, ``decode.check_kernel_shape``), and q / the output are
-passed head-major ``[heads, tokens, head_dim]`` so a query head is a
-leading-axis index, not a sublane-strided slice of a 3-D tile (which
+heads are never materialized, mirroring the decode kernel. As there, the
+kernel takes the whole stored pool ``[layers, num_pages, page_size,
+kv_heads * head_dim]`` and a layer index and DMAs ``pool[layer, page]``
+tiles (same Mosaic constraint, ``decode.check_kernel_shape``); q / the
+output are passed head-major ``[heads, tokens, head_dim]`` so a query head
+is a leading-axis index, not a sublane-strided slice of a 3-D tile (which
 Mosaic cannot lay out at head_dim 64).
 
 Meshes: the kernel body is a single-device program (it walks the page
 pool with raw HBM DMA), and :func:`ragged_paged_attention_pallas_sharded`
 ports it to tp meshes by wrapping it in ``shard_map`` over the ``tp``
-axis — the axis the engine already shards KV heads and the page pool
-over (``PagePool.create`` places pages at ``P(None, None, None, 'tp',
-None)``). Each shard walks its OWN head slice of the page pool with the
+axis — the axis the engine already shards KV heads and the page pool's
+fused axis over (``ops/attention.py:POOL_SPEC``). Each shard walks its
+OWN head slice of the page pool with the
 same replicated block metadata; head-sharded GQA needs no cross-shard
 softmax, because every query head's softmax completes inside the shard
 that owns its KV-head group. Routing between the two entry points (and
@@ -54,7 +55,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
-from .decode import check_kernel_shape, fuse_pages, shard_over_tp
+from ..attention import POOL_SPEC
+from .decode import check_kernel_shape, shard_over_tp
 
 NEG_INF = -1e30
 
@@ -63,9 +65,10 @@ def _ragged_kernel(
     # scalar prefetch
     meta_ref,  # [num_blocks, 3] SMEM — (row_slot, pos0, nvalid) per block
     page_table_ref,  # [rows, pages_per_seq] SMEM
+    layer_ref,  # [1] SMEM — the layer of the pool this call reads
     # inputs
     q_ref,  # [heads, block_rows, head_dim] VMEM
-    k_hbm,  # [num_pages, page_size, kv_heads * head_dim] HBM/ANY
+    k_hbm,  # [layers, num_pages, page_size, kv_heads * head_dim] HBM/ANY
     v_hbm,  # same
     # output
     o_ref,  # [heads, block_rows, head_dim] VMEM
@@ -85,6 +88,7 @@ def _ragged_kernel(
     slot = jnp.maximum(meta_ref[i, 0], 0)  # clamped; nvalid=0 masks all
     pos0 = meta_ref[i, 1]
     nvalid = meta_ref[i, 2]
+    layer = layer_ref[0]
     # pages holding cache entries [0, pos_last + 1): the block's last
     # valid row sits at absolute position pos0 + nvalid - 1, and its own
     # KV was scattered before the kernel ran (scatter-first semantics)
@@ -92,7 +96,7 @@ def _ragged_kernel(
 
     def page_dma(buf, hbm, buf_slot, p, sem_row):
         return pltpu.make_async_copy(
-            hbm.at[page_table_ref[slot, p]],
+            hbm.at[layer, page_table_ref[slot, p]],
             buf.at[buf_slot],
             sems.at[sem_row, buf_slot],
         )
@@ -183,16 +187,18 @@ def _ragged_kernel(
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def ragged_paged_attention_pallas(
     q: jnp.ndarray,  # [tokens, heads, head_dim] — flat packed buffer
-    k_pages: jnp.ndarray,  # [num_pages, page_size, kv_heads, head_dim]
+    k_pages: jnp.ndarray,  # [layers, num_pages, page_size, kv_heads*head_dim]
     v_pages: jnp.ndarray,
     page_table: jnp.ndarray,  # [rows, pages_per_seq] int32
     row_slot: jnp.ndarray,  # [tokens] int32; -1 = padding row
     positions: jnp.ndarray,  # [tokens] int32 absolute positions
+    layer: jnp.ndarray,  # int32 scalar — the pool layer to read
     block_rows: int = 8,
     interpret: bool = False,
 ) -> jnp.ndarray:
     tokens, num_heads, head_dim = q.shape
-    _, page_size, num_kv_heads, _ = k_pages.shape
+    _, _, page_size, fused = k_pages.shape
+    num_kv_heads = fused // head_dim
     if tokens % block_rows != 0:
         raise ValueError(
             f"tokens ({tokens}) must be a multiple of block_rows "
@@ -221,14 +227,13 @@ def ragged_paged_attention_pallas(
         num_kv_heads=num_kv_heads,
         head_dim=head_dim,
     )
-    fused = num_kv_heads * head_dim
     block_spec = pl.BlockSpec(
         (num_heads, block_rows, head_dim),
         lambda i, *_: (0, i, 0),
         memory_space=pltpu.VMEM,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(nb,),
         in_specs=[
             block_spec,
@@ -252,9 +257,10 @@ def ragged_paged_attention_pallas(
     )(
         meta,
         page_table.astype(jnp.int32),
+        jnp.asarray(layer, jnp.int32).reshape(1),
         qt,
-        fuse_pages(k_pages),
-        fuse_pages(v_pages),
+        k_pages,
+        v_pages,
     )
     return out.transpose(1, 0, 2)
 
@@ -262,11 +268,12 @@ def ragged_paged_attention_pallas(
 def ragged_paged_attention_pallas_sharded(
     mesh,
     q: jnp.ndarray,  # [tokens, heads, head_dim]
-    k_pages: jnp.ndarray,  # [num_pages, page_size, kv_heads, head_dim]
+    k_pages: jnp.ndarray,  # [layers, num_pages, page_size, kv_heads*head_dim]
     v_pages: jnp.ndarray,
     page_table: jnp.ndarray,  # [rows, pages_per_seq] int32
     row_slot: jnp.ndarray,  # [tokens] int32; -1 = padding row
     positions: jnp.ndarray,  # [tokens] int32 absolute positions
+    layer: jnp.ndarray,  # int32 scalar — the pool layer to read
     block_rows: int = 8,
     interpret: bool = False,
 ) -> jnp.ndarray:
@@ -292,11 +299,12 @@ def ragged_paged_attention_pallas_sharded(
         kernel,
         in_specs=(
             P(None, "tp", None),  # q: query heads sharded
-            P(None, None, "tp", None),  # k_pages: kv heads sharded
-            P(None, None, "tp", None),  # v_pages
+            POOL_SPEC,  # k_pages: fused kv-head axis sharded
+            POOL_SPEC,  # v_pages
             P(None, None),  # page_table: replicated
             P(None),  # row_slot: replicated
             P(None),  # positions: replicated
+            P(),  # layer: replicated
         ),
         out_specs=P(None, "tp", None),
-    )(q, k_pages, v_pages, page_table, row_slot, positions)
+    )(q, k_pages, v_pages, page_table, row_slot, positions, layer)

@@ -8,7 +8,9 @@ results or times. Skipped where the topology cannot be described.
 """
 
 import json
+import math
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -61,17 +63,19 @@ def _kernel_args(kind, h, kvh, d, sharding):
     def s(shape, dtype=jnp.bfloat16, sh=sharding):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
 
-    pages = s((NUM_PAGES, PAGE, kvh, d))
+    # the pool as stored (engine/kv_cache.py), two layers of it
+    pages = s((2, NUM_PAGES, PAGE, kvh * d))
     table = s((BATCH, PAGES_PER_SEQ), jnp.int32)
     lens = s((BATCH,), jnp.int32)
+    layer = s((), jnp.int32)
     if kind == "decode_inline":
         new = s((BATCH, kvh, d))
-        return (s((BATCH, h, d)), pages, pages, new, new, table, lens)
+        return (s((BATCH, h, d)), pages, pages, new, new, table, lens, layer)
     if kind == "decode":
-        return (s((BATCH, h, d)), pages, pages, table, lens)
+        return (s((BATCH, h, d)), pages, pages, table, lens, layer)
     if kind == "ragged":
         rows = s((256,), jnp.int32)
-        return (s((256, h, d)), pages, pages, table, rows, rows)
+        return (s((256, h, d)), pages, pages, table, rows, rows, layer)
     assert kind == "prefill"
     kv = s((2, 512, kvh, d))
     return (s((2, 512, h, d)), kv, kv, s((2,), jnp.int32))
@@ -111,12 +115,12 @@ def test_sharded_kernel_compiles_for_v5e_2x2(topo, kind, shape):
     from llm_d_fast_model_actuation_tpu.ops.pallas.decode import shard_over_tp
 
     mesh = _tp_mesh(topo)
-    table, lens = P(None, None), P(None)
+    table, lens, layer = P(None, None), P(None), P()
     in_specs = {
-        "decode_inline": (attn._HEADS3, attn._HEADS4, attn._HEADS4,
-                          attn._HEADS3, attn._HEADS3, table, lens),
-        "ragged": (attn._HEADS3, attn._HEADS4, attn._HEADS4, table, lens,
-                   lens),
+        "decode_inline": (attn._HEADS3, attn.POOL_SPEC, attn.POOL_SPEC,
+                          attn._HEADS3, attn._HEADS3, table, lens, layer),
+        "ragged": (attn._HEADS3, attn.POOL_SPEC, attn.POOL_SPEC, table, lens,
+                   lens, layer),
         "prefill": (attn._HEADS4, attn._HEADS4, attn._HEADS4, lens),
     }[kind]
     out_spec = attn._HEADS4 if kind == "prefill" else attn._HEADS3
@@ -129,34 +133,130 @@ def test_sharded_kernel_compiles_for_v5e_2x2(topo, kind, shape):
     _compile(shard_over_tp(mesh, KERNELS[kind], in_specs, out_spec), *args)
 
 
+def _compile_engine_program(topo, program, bucket, tp, **engine):
+    """AOT-compile one serving program of a tiny-model engine under
+    ``pallas`` on a ``tp``-device mesh of described chips; returns
+    ``(compiled, engine config)``."""
+    from llm_d_fast_model_actuation_tpu.engine import EngineConfig, exec_pool
+    from llm_d_fast_model_actuation_tpu.models import llama
+    from llm_d_fast_model_actuation_tpu.ops import attention as attn
+
+    model = llama.LlamaConfig(
+        vocab_size=512, hidden_size=256, num_layers=3, num_heads=8,
+        num_kv_heads=4, head_dim=128, intermediate_size=512,
+        max_seq_len=256, attention_impl="pallas",
+    )
+    cfg = EngineConfig(
+        model=model, max_batch=4, attention_impl="pallas", decode_chunk=4,
+        **engine,
+    )
+    attn.set_pallas_interpret(False)  # compile the kernels for the chip
+    try:
+        return exec_pool.compile_program(
+            cfg, program, bucket, mesh=_tp_mesh(topo, tp)
+        ), cfg
+    finally:
+        attn.set_pallas_interpret(True)
+
+
 @pytest.mark.parametrize("program,bucket", [("chunk", 4), ("prefill", 16)])
 def test_sharded_engine_program_compiles_for_v5e_2x2(topo, program, bucket):
     """A whole serving program of a tp=4 engine under ``pallas``: GSPMD
     cannot partition a Mosaic kernel, so any kernel the program reaches
     outside a shard_map fails here (and only here: interpret mode lowers
     to plain XLA ops, which partition fine)."""
-    from llm_d_fast_model_actuation_tpu.engine import EngineConfig, exec_pool
-    from llm_d_fast_model_actuation_tpu.models import llama
-
-    model = llama.LlamaConfig(
-        vocab_size=512, hidden_size=256, num_layers=2, num_heads=8,
-        num_kv_heads=4, head_dim=128, intermediate_size=512,
-        max_seq_len=256, attention_impl="pallas",
+    compiled, _ = _compile_engine_program(
+        topo, program, bucket, tp=4, num_pages=64
     )
-    cfg = EngineConfig(
-        model=model, max_batch=4, num_pages=64, attention_impl="pallas",
-        decode_chunk=4,
-    )
-    from llm_d_fast_model_actuation_tpu.ops import attention as attn
+    assert "tpu_custom_call" in compiled.as_text()
 
-    attn.set_pallas_interpret(False)  # compile the kernels for the chip
-    try:
-        text = exec_pool.compile_program(
-            cfg, program, bucket, mesh=_tp_mesh(topo)
-        ).as_text()
-    finally:
-        attn.set_pallas_interpret(True)
-    assert "tpu_custom_call" in text
+
+# -- nothing pool-sized per layer --------------------------------------------
+#
+# The KV pool is stored as the kernels read it and the forward indexes it by
+# layer (engine/kv_cache.py), so no serving program may slice a layer out of
+# the pool, re-lay it out, or stack it back: on the chip each of those is a
+# copy of pool size per layer per step (PERF.md section 6, PR 26). The
+# compiled HLO decides, not the source.
+
+#: instructions that move no bytes, whatever their shape
+_FREE_OPS = {
+    "parameter", "get-tuple-element", "tuple", "bitcast", "while", "call",
+    "conditional", "opt-barrier",
+}
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%?[\w.\-]+ = (\(?[a-z0-9]+\[.*?) ([a-z][a-z\-]*)\("
+)
+
+
+def _pool_sized_ops(text, min_elems):
+    """(opcode, line) of every instruction of the compiled module, outside
+    fused computations (what a fusion computes inside is never materialized),
+    that writes ``min_elems`` elements or more — except the in-place cache
+    write, a ``kv_write`` scatter whose output aliases its operand."""
+    fused = set(re.findall(r"fusion\(.*calls=%?([\w.\-]+)", text))
+    found, skipping = [], False
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if head:
+            skipping = head.group(1) in fused
+            continue
+        m = _INSTRUCTION.match(line)
+        if skipping or not m or m.group(2) in _FREE_OPS:
+            continue
+        elems = max(
+            (
+                math.prod(int(d) for d in dims.split(",") if d)
+                for dims in re.findall(r"[a-z0-9]+\[([0-9,]*)\]", m.group(1))
+            ),
+            default=0,
+        )
+        in_place_write = "kv_write" in line and "aliasing" in line
+        if elems >= min_elems and not in_place_write:
+            found.append((m.group(2), line.strip()[:200]))
+    return found
+
+
+def test_pool_sized_op_finder_sees_a_relayout():
+    """The finder on two lines of the chat cell's chunk as PR 25 compiled it
+    (the copies this layout removed) and on what may stay."""
+    text = """
+%fused_computation.1 (p: bf16[8,6144,16,1024]) -> bf16[6144,16,1024] {
+  %inside = bf16[6144,16,1024]{2,1,0} dynamic-slice(%p), dynamic_slice_sizes={1,6144,16,1024}
+}
+
+ENTRY %main (a: bf16[8,6144,16,8,128]) -> bf16[6144,16,1024] {
+  %a = bf16[8,6144,16,8,128]{4,3,2,1,0} parameter(0)
+  %gte = bf16[8,6144,16,1024]{3,2,1,0} get-tuple-element(%t), index=3
+  %fusion.9 = bf16[1,6144,16,8,128]{4,3,2,1,0} fusion(%a), kind=kLoop, calls=%fused_computation.1
+  %reshape.556 = bf16[6144,16,1024]{2,1,0} reshape(%fusion.9)
+  %fusion.183 = bf16[8,6144,16,1024]{3,2,1,0} fusion(%gte), kind=kCustom, calls=%fused_computation.7, metadata={op_name="jit(chunk)/while/body/closed_call/kv_write/scatter"}, backend_config={"aliasing_operands":{"lists":[{"indices":["0","3"]}]}}
+  %small = bf16[32,4096]{1,0} copy(%x)
+}
+"""
+    found = _pool_sized_ops(text, 6144 * 16 * 1024)
+    assert [op for op, _ in found] == ["fusion", "reshape"]
+
+
+@pytest.mark.parametrize("tp", [1, 4], ids=["one_chip", "tp4"])
+@pytest.mark.parametrize(
+    "program,bucket", [("chunk", 4), ("prefill", 16), ("suffix", 16)]
+)
+def test_no_program_holds_a_pool_sized_copy(topo, program, bucket, tp):
+    """No ``copy``, ``reshape``, ``dynamic-slice``, fusion or other
+    materialized output of a serving program has a per-layer pool's element
+    count or more, and the program's temps stay under one per-layer pool.
+    The model is tiny and the pool is not, so only the pool is that large.
+    (One chip stands in as a one-device mesh: the engine's own single-device
+    path asks ``jax.devices()``, which is the CPU here.)"""
+    compiled, cfg = _compile_engine_program(
+        topo, program, bucket, tp, num_pages=1024
+    )
+    # one device's share of one layer of the pool, in elements
+    layer_pool = cfg.num_pages * cfg.page_size * cfg.model.kv_dim // tp
+    assert _pool_sized_ops(compiled.as_text(), layer_pool) == []
+    temps = compiled.memory_analysis().temp_size_in_bytes
+    assert temps < layer_pool * 2, temps  # bf16
 
 
 def test_lane_constraint_is_named_not_a_mosaic_crash(topo):

@@ -33,9 +33,14 @@ def test_tp_sharded_params(tp2_mesh):
     assert wq.sharding.num_devices == 2
     shard_shape = wq.sharding.shard_shape(wq.shape)
     assert shard_shape[-1] == wq.shape[-1] // 2
-    # kv pages sharded on the kv_heads axis
+    # kv pool [L, P, page, kv_heads * head_dim]: the fused axis sharded, a
+    # shard holding its kv_heads / tp heads
     kp = eng.pool.k_pages
-    assert kp.sharding.shard_shape(kp.shape)[3] == kp.shape[3] // 2
+    m = eng.cfg.model
+    assert kp.shape == (m.num_layers, 32, 8, m.num_kv_heads * m.head_dim)
+    assert kp.sharding.shard_shape(kp.shape) == kp.shape[:3] + (
+        m.num_kv_heads // 2 * m.head_dim,
+    )
 
 
 def test_tp_matches_single_device(tp2_mesh):

@@ -16,6 +16,7 @@ import pytest
 import transformers
 import torch
 
+from llm_d_fast_model_actuation_tpu.engine.kv_cache import PagePool
 from llm_d_fast_model_actuation_tpu.models import hf, llama
 
 TINY = dict(
@@ -44,7 +45,9 @@ def _save(tmp_path, hf_cfg_cls, model_cls, **kw):
 def _our_logits(cfg, params, tokens_np):
     b, s = tokens_np.shape
     num_pages, page_size = 16, 8
-    shape = (cfg.num_layers, num_pages, page_size, cfg.num_kv_heads, cfg.head_dim)
+    shape = PagePool.pool_shape(
+        cfg.num_layers, num_pages, page_size, cfg.num_kv_heads, cfg.head_dim
+    )
     cache = (jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype))
     pages_per_seq = -(-s // page_size)
     table = jnp.asarray(

@@ -16,7 +16,11 @@ def setup():
 
 
 def make_cache(cfg, num_pages=32, page_size=8):
-    shape = (cfg.num_layers, num_pages, page_size, cfg.num_kv_heads, cfg.head_dim)
+    from llm_d_fast_model_actuation_tpu.engine.kv_cache import PagePool
+
+    shape = PagePool.pool_shape(
+        cfg.num_layers, num_pages, page_size, cfg.num_kv_heads, cfg.head_dim
+    )
     return jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype)
 
 
@@ -231,8 +235,7 @@ def test_gemma_train_matches_serving_function():
     assert float(jnp.abs(logits_t).max()) > 0
 
     page_size, num_pages = 8, 16
-    cache_shape = (cfg.num_layers, num_pages, page_size, cfg.num_kv_heads, cfg.head_dim)
-    cache = (jnp.zeros(cache_shape, cfg.dtype), jnp.zeros(cache_shape, cfg.dtype))
+    cache = make_cache(cfg, num_pages, page_size)
     table = jnp.asarray(np.arange(1, 9, dtype=np.int32).reshape(1, 8))
     logits_s, _ = llama.prefill(params, cfg, jnp.asarray(tokens), jnp.asarray(seq_lens), cache, table)
     np.testing.assert_allclose(

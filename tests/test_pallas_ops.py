@@ -20,23 +20,42 @@ def _rand(key, shape, dtype=jnp.float32):
     return jax.random.normal(key, shape, dtype=dtype)
 
 
+def _pool(key, layers, num_pages, page_size, kv_heads, head_dim,
+          dtype=jnp.float32):
+    """A random K or V pool in the stored layout (PagePool.pool_shape)."""
+    from llm_d_fast_model_actuation_tpu.engine.kv_cache import PagePool
+
+    return _rand(
+        key,
+        PagePool.pool_shape(layers, num_pages, page_size, kv_heads, head_dim),
+        dtype,
+    )
+
+
+#: (batch, heads, kv_heads, head_dim, page_size, pages_per_seq, layers, layer):
+#: the kernels take the whole pool and read ``layer`` of it
+DECODE_CASES = [
+    (2, 4, 2, 16, 8, 4, 1, 0),
+    (3, 8, 8, 32, 16, 2, 3, 2),  # MHA (group=1)
+    (1, 8, 2, 64, 8, 3, 2, 1),  # GQA 4x
+    (2, 32, 8, 128, 16, 3, 2, 1),  # Llama-3-8B / Mistral-7B heads
+    (2, 32, 4, 64, 16, 3, 3, 1),  # TinyLlama heads
+]
+
+
 @pytest.mark.parametrize(
-    "batch,heads,kv_heads,head_dim,page_size,pages_per_seq",
-    [
-        (2, 4, 2, 16, 8, 4),
-        (3, 8, 8, 32, 16, 2),  # MHA (group=1)
-        (1, 8, 2, 64, 8, 3),  # GQA 4x
-    ],
+    "batch,heads,kv_heads,head_dim,page_size,pages_per_seq,layers,layer",
+    DECODE_CASES,
 )
 def test_paged_decode_matches_reference(
-    batch, heads, kv_heads, head_dim, page_size, pages_per_seq
+    batch, heads, kv_heads, head_dim, page_size, pages_per_seq, layers, layer
 ):
     key = jax.random.key(0)
     ks = jax.random.split(key, 4)
     num_pages = batch * pages_per_seq + 1  # page 0 unused by convention
     q = _rand(ks[0], (batch, heads, head_dim))
-    k_pages = _rand(ks[1], (num_pages, page_size, kv_heads, head_dim))
-    v_pages = _rand(ks[2], (num_pages, page_size, kv_heads, head_dim))
+    k_pages = _pool(ks[1], layers, num_pages, page_size, kv_heads, head_dim)
+    v_pages = _pool(ks[2], layers, num_pages, page_size, kv_heads, head_dim)
     page_table = jnp.asarray(
         np.arange(1, 1 + batch * pages_per_seq, dtype=np.int32).reshape(
             batch, pages_per_seq
@@ -48,11 +67,18 @@ def test_paged_decode_matches_reference(
     lens += [max_len // 2] * (batch - len(lens))
     seq_lens = jnp.asarray(lens, dtype=jnp.int32)
 
-    want = attn.paged_decode_attention(q, k_pages, v_pages, page_table, seq_lens)
+    want = attn.paged_decode_attention(
+        q, k_pages, v_pages, page_table, seq_lens, layer
+    )
     got = paged_decode_attention_pallas(
-        q, k_pages, v_pages, page_table, seq_lens, interpret=True
+        q, k_pages, v_pages, page_table, seq_lens, layer, interpret=True
     )
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5)
+    if layers > 1:  # another layer holds other pages: the index is read
+        other = attn.paged_decode_attention(
+            q, k_pages, v_pages, page_table, seq_lens, (layer + 1) % layers
+        )
+        assert not np.allclose(np.asarray(other), np.asarray(want), atol=1e-3)
 
 
 @pytest.mark.parametrize(
@@ -110,28 +136,41 @@ def test_bf16_io_fp32_math():
     ks = jax.random.split(key, 4)
     batch, heads, kvh, d, ps, pps = 2, 4, 2, 32, 8, 2
     q = _rand(ks[0], (batch, heads, d), jnp.bfloat16)
-    kp = _rand(ks[1], (batch * pps + 1, ps, kvh, d), jnp.bfloat16)
-    vp = _rand(ks[2], (batch * pps + 1, ps, kvh, d), jnp.bfloat16)
+    kp = _pool(ks[1], 2, batch * pps + 1, ps, kvh, d, jnp.bfloat16)
+    vp = _pool(ks[2], 2, batch * pps + 1, ps, kvh, d, jnp.bfloat16)
     pt = jnp.asarray(
         np.arange(1, 1 + batch * pps, dtype=np.int32).reshape(batch, pps)
     )
     seq_lens = jnp.asarray([ps * pps, ps + 3], dtype=jnp.int32)
-    want = attn.paged_decode_attention(q, kp, vp, pt, seq_lens)
-    got = paged_decode_attention_pallas(q, kp, vp, pt, seq_lens, interpret=True)
+    want = attn.paged_decode_attention(q, kp, vp, pt, seq_lens, 1)
+    got = paged_decode_attention_pallas(q, kp, vp, pt, seq_lens, 1, interpret=True)
     assert got.dtype == jnp.bfloat16
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32), atol=3e-2, rtol=3e-2
     )
 
 
-def test_engine_generates_identically_with_pallas_attention():
+@pytest.mark.parametrize(
+    "layers,decode_chunk,new_tokens,second_prompt",
+    # 9 = one token from prefill + 2 chunks of 4. With three layers the
+    # random-weight model puts [9, 8, 7] on a near-tie that grouped's bf16
+    # matmuls take the other way (before this layout too), so another prompt
+    [(2, 8, 6, [9, 8, 7]), (3, 4, 9, [2, 7, 1, 8])],
+    ids=["one_chunk", "three_layers_two_chunks"],
+)
+def test_engine_generates_identically_with_pallas_attention(
+    layers, decode_chunk, new_tokens, second_prompt
+):
     """Full engine generation with the Pallas kernels (interpret mode on CPU)
-    must produce the same greedy tokens as the XLA reference path."""
+    must produce the same greedy tokens as the XLA reference path — every
+    impl reads and writes the one stored pool layout, each layer its own."""
+    import dataclasses
+
     from llm_d_fast_model_actuation_tpu.engine import EngineConfig, InferenceEngine
     from llm_d_fast_model_actuation_tpu.models import llama
 
-    model = llama.LlamaConfig.tiny()
-    prompts = [[1, 2, 3, 4, 5], [9, 8, 7]]
+    model = dataclasses.replace(llama.LlamaConfig.tiny(), num_layers=layers)
+    prompts = [[1, 2, 3, 4, 5], second_prompt]
     outs = {}
     for impl in ("reference", "grouped", "pallas"):
         cfg = EngineConfig(
@@ -141,24 +180,21 @@ def test_engine_generates_identically_with_pallas_attention():
             num_pages=32,
             max_seq_len=64,
             attention_impl=impl,
+            decode_chunk=decode_chunk,
         )
         eng = InferenceEngine(cfg, seed=0)
-        outs[impl] = eng.generate(prompts, max_new_tokens=6)
+        outs[impl] = eng.generate(prompts, max_new_tokens=new_tokens)
     attn.set_attention_impl("reference")
     assert outs["pallas"] == outs["reference"]
     assert outs["grouped"] == outs["reference"]
 
 
 @pytest.mark.parametrize(
-    "batch,heads,kv_heads,head_dim,page_size,pages_per_seq",
-    [
-        (2, 4, 2, 16, 8, 4),
-        (3, 8, 8, 32, 16, 2),  # MHA (group=1)
-        (1, 8, 2, 64, 8, 3),  # GQA 4x
-    ],
+    "batch,heads,kv_heads,head_dim,page_size,pages_per_seq,layers,layer",
+    DECODE_CASES,
 )
 def test_inline_decode_matches_scatter_then_attend(
-    batch, heads, kv_heads, head_dim, page_size, pages_per_seq
+    batch, heads, kv_heads, head_dim, page_size, pages_per_seq, layers, layer
 ):
     """The deferred-scatter serving path: attend(cache[<pos], inline new K/V)
     must equal scatter-into-cache-then-attend — for both the grouped-XLA
@@ -171,8 +207,8 @@ def test_inline_decode_matches_scatter_then_attend(
     ks = jax.random.split(key, 6)
     num_pages = batch * pages_per_seq + 1
     q = _rand(ks[0], (batch, heads, head_dim))
-    k_pages = _rand(ks[1], (num_pages, page_size, kv_heads, head_dim))
-    v_pages = _rand(ks[2], (num_pages, page_size, kv_heads, head_dim))
+    k_pages = _pool(ks[1], layers, num_pages, page_size, kv_heads, head_dim)
+    v_pages = _pool(ks[2], layers, num_pages, page_size, kv_heads, head_dim)
     k_new = _rand(ks[3], (batch, kv_heads, head_dim))
     v_new = _rand(ks[4], (batch, kv_heads, head_dim))
     pt = jnp.asarray(
@@ -191,20 +227,22 @@ def test_inline_decode_matches_scatter_then_attend(
     page_of = pos_np // page_size
     slot_of = pos_np % page_size
     phys = np.asarray(pt)[np.arange(batch), page_of]
-    kp2 = k_pages.at[phys, slot_of].set(k_new)
-    vp2 = v_pages.at[phys, slot_of].set(v_new)
+    kp2 = k_pages.at[layer, phys, slot_of].set(k_new.reshape(batch, -1))
+    vp2 = v_pages.at[layer, phys, slot_of].set(v_new.reshape(batch, -1))
     want = attn.paged_decode_attention(
-        q, kp2, vp2, pt, jnp.asarray(pos_np + 1), impl="reference"
+        q, kp2, vp2, pt, jnp.asarray(pos_np + 1), layer, impl="reference"
     )
 
     got_grouped = attn.paged_decode_attention_inline(
-        q, k_pages, v_pages, k_new, v_new, pt, positions, impl="grouped"
+        q, k_pages, v_pages, k_new, v_new, pt, positions, layer,
+        impl="grouped",
     )
     np.testing.assert_allclose(
         np.asarray(got_grouped), np.asarray(want), atol=2e-5, rtol=2e-5
     )
     got_pallas = paged_decode_attention_inline_pallas(
-        q, k_pages, v_pages, k_new, v_new, pt, positions, interpret=True
+        q, k_pages, v_pages, k_new, v_new, pt, positions, layer,
+        interpret=True,
     )
     np.testing.assert_allclose(
         np.asarray(got_pallas), np.asarray(want), atol=2e-5, rtol=2e-5
@@ -222,21 +260,21 @@ def test_kernels_over_a_tp_mesh_match_reference(devices8, kind):
     mesh = make_mesh(MeshPlan(tp=2), devices8[:2])
     batch, heads, kvh, d, ps, pps = 3, 8, 4, 32, 8, 3
     ks = jax.random.split(jax.random.key(5), 6)
-    k_pages = _rand(ks[0], (batch * pps + 1, ps, kvh, d))
-    v_pages = _rand(ks[1], (batch * pps + 1, ps, kvh, d))
+    k_pages = _pool(ks[0], 2, batch * pps + 1, ps, kvh, d)
+    v_pages = _pool(ks[1], 2, batch * pps + 1, ps, kvh, d)
     pt = jnp.asarray(
         np.arange(1, 1 + batch * pps, dtype=np.int32).reshape(batch, pps)
     )
     lens = jnp.asarray([ps * pps, ps, 3], jnp.int32)
     if kind == "decode":
         fn = attn.paged_decode_attention
-        args = (_rand(ks[2], (batch, heads, d)), k_pages, v_pages, pt, lens)
+        args = (_rand(ks[2], (batch, heads, d)), k_pages, v_pages, pt, lens, 1)
     elif kind == "decode_inline":
         fn = attn.paged_decode_attention_inline
         args = (
             _rand(ks[2], (batch, heads, d)), k_pages, v_pages,
             _rand(ks[3], (batch, kvh, d)), _rand(ks[4], (batch, kvh, d)),
-            pt, lens - 1,
+            pt, lens - 1, 1,
         )
     else:
         fn = attn.causal_prefill_attention

@@ -47,15 +47,23 @@ def _generate(packed: bool, prompts=PROMPTS, max_new=8, **kw):
 # -- kernel-level identity ----------------------------------------------------
 
 
+#: the pool layer the kernel-level tests read (of the scenario's two)
+LAYER = 1
+
+
 def _pack_scenario(key, heads, kv_heads, head_dim, page_size, pages_per_seq):
-    """Random pages + a packed buffer mixing a cold prefill segment, a
-    decode row, and a mid-sequence suffix segment, with alignment gaps
-    and a padded tail (the engine's packing layout)."""
+    """Random two-layer pools in the stored layout + a packed buffer mixing
+    a cold prefill segment, a decode row, and a mid-sequence suffix
+    segment, with alignment gaps and a padded tail (the engine's packing
+    layout)."""
+    from llm_d_fast_model_actuation_tpu.engine.kv_cache import PagePool
+
     rows = 3
     num_pages = rows * pages_per_seq + 1
     ks = jax.random.split(key, 3)
-    kp = jax.random.normal(ks[0], (num_pages, page_size, kv_heads, head_dim))
-    vp = jax.random.normal(ks[1], (num_pages, page_size, kv_heads, head_dim))
+    shape = PagePool.pool_shape(2, num_pages, page_size, kv_heads, head_dim)
+    kp = jax.random.normal(ks[0], shape)
+    vp = jax.random.normal(ks[1], shape)
     pt = jnp.asarray(
         np.arange(1, 1 + rows * pages_per_seq, dtype=np.int32).reshape(
             rows, pages_per_seq
@@ -84,17 +92,19 @@ def test_ragged_reference_matches_per_sequence_paths():
     q, kp, vp, pt, row_slot, positions, _ = _pack_scenario(
         jax.random.key(0), 4, 2, 16, 8, 4
     )
-    out = attn.ragged_paged_attention(q, kp, vp, pt, row_slot, positions)
+    out = attn.ragged_paged_attention(
+        q, kp, vp, pt, row_slot, positions, LAYER
+    )
     # seq 0 prefill segment == suffix attention from start 0
     want0 = attn.paged_suffix_attention(
-        q[0:11][None], kp, vp, pt[0:1], jnp.asarray([0], jnp.int32)
+        q[0:11][None], kp, vp, pt[0:1], jnp.asarray([0], jnp.int32), LAYER
     )[0]
     np.testing.assert_allclose(
         np.asarray(out)[0:11], np.asarray(want0), atol=2e-5, rtol=2e-5
     )
     # seq 2 suffix segment == suffix attention from start 7
     want2 = attn.paged_suffix_attention(
-        q[24:29][None], kp, vp, pt[2:3], jnp.asarray([7], jnp.int32)
+        q[24:29][None], kp, vp, pt[2:3], jnp.asarray([7], jnp.int32), LAYER
     )[0]
     np.testing.assert_allclose(
         np.asarray(out)[24:29], np.asarray(want2), atol=2e-5, rtol=2e-5
@@ -102,7 +112,7 @@ def test_ragged_reference_matches_per_sequence_paths():
     # seq 1 decode row == paged decode attention at seq_len = pos + 1
     want1 = attn.paged_decode_attention(
         q[16:17], kp, vp, pt[1:2],
-        jnp.asarray([int(positions[16]) + 1], jnp.int32),
+        jnp.asarray([int(positions[16]) + 1], jnp.int32), LAYER,
     )
     np.testing.assert_allclose(
         np.asarray(out)[16:17], np.asarray(want1), atol=2e-5, rtol=2e-5
@@ -128,9 +138,12 @@ def test_ragged_pallas_matches_reference(
         jax.random.key(1), heads, kv_heads, head_dim, page_size,
         pages_per_seq,
     )
-    want = attn.ragged_paged_attention(q, kp, vp, pt, row_slot, positions)
+    want = attn.ragged_paged_attention(
+        q, kp, vp, pt, row_slot, positions, LAYER
+    )
     got = ragged_paged_attention_pallas(
-        q, kp, vp, pt, row_slot, positions, block_rows=B, interpret=True
+        q, kp, vp, pt, row_slot, positions, LAYER, block_rows=B,
+        interpret=True,
     )
     valid = np.asarray(row_slot) >= 0
     np.testing.assert_allclose(
@@ -175,12 +188,14 @@ def test_ragged_pallas_sharded_matches_twin_tp2(
         jax.random.key(3), heads, kv_heads, head_dim, page_size,
         pages_per_seq,
     )
-    want = attn.ragged_paged_attention(q, kp, vp, pt, row_slot, positions)
+    want = attn.ragged_paged_attention(
+        q, kp, vp, pt, row_slot, positions, LAYER
+    )
     qs = jax.device_put(q, NamedSharding(mesh, P(None, "tp", None)))
-    kps = jax.device_put(kp, NamedSharding(mesh, P(None, None, "tp", None)))
-    vps = jax.device_put(vp, NamedSharding(mesh, P(None, None, "tp", None)))
+    kps = jax.device_put(kp, NamedSharding(mesh, attn.POOL_SPEC))
+    vps = jax.device_put(vp, NamedSharding(mesh, attn.POOL_SPEC))
     got = ragged_paged_attention_pallas_sharded(
-        mesh, qs, kps, vps, pt, row_slot, positions,
+        mesh, qs, kps, vps, pt, row_slot, positions, LAYER,
         block_rows=B, interpret=True,
     )
     assert got.sharding.spec == P(None, "tp", None)  # heads stay sharded
@@ -191,7 +206,8 @@ def test_ragged_pallas_sharded_matches_twin_tp2(
     )
     # the dispatcher routes mesh + pallas through the shard_map port
     got2 = attn.ragged_paged_attention(
-        qs, kps, vps, pt, row_slot, positions, impl="pallas", mesh=mesh
+        qs, kps, vps, pt, row_slot, positions, LAYER, impl="pallas",
+        mesh=mesh,
     )
     np.testing.assert_allclose(
         np.asarray(got2)[valid], np.asarray(want)[valid],
@@ -208,9 +224,12 @@ def test_ragged_pallas_bf16_io_fp32_math():
     )
 
     qb, kpb, vpb = (x.astype(jnp.bfloat16) for x in (q, kp, vp))
-    want = attn.ragged_paged_attention(qb, kpb, vpb, pt, row_slot, positions)
+    want = attn.ragged_paged_attention(
+        qb, kpb, vpb, pt, row_slot, positions, LAYER
+    )
     got = ragged_paged_attention_pallas(
-        qb, kpb, vpb, pt, row_slot, positions, block_rows=B, interpret=True
+        qb, kpb, vpb, pt, row_slot, positions, LAYER, block_rows=B,
+        interpret=True,
     )
     assert got.dtype == jnp.bfloat16
     valid = np.asarray(row_slot) >= 0

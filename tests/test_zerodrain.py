@@ -17,8 +17,10 @@ The contract under test:
     resuming swaps (the parked-KV satellite).
 """
 
+import json
 import time
 
+import numpy as np
 import pytest
 
 from llm_d_fast_model_actuation_tpu.engine.engine import (
@@ -84,6 +86,67 @@ def test_park_resume_mid_decode_bit_exact():
     sid = eng.add_request([1, 2, 3, 4, 5], max_new_tokens=12)
     results = _interrupt_cycle(eng, steps=2)
     assert results[sid].out_tokens == gold
+
+
+def test_park_wire_resume_round_trip_keeps_the_pool_bytes():
+    """The stored pool layout (kv_cache.PagePool: [L, P, page, kvh * hd])
+    through a park -> migration wire -> resume on a FRESH engine: the
+    bundle holds the live pages' bytes in that layout, the wire document
+    carries and returns them bit-exact, the importer's pool holds them at
+    the re-mapped page ids, and the stream continues as uninterrupted."""
+    import dataclasses
+
+    from llm_d_fast_model_actuation_tpu.engine import parked
+    from llm_d_fast_model_actuation_tpu.engine.engine import Request
+
+    cfg = _tiny_cfg(
+        model=dataclasses.replace(llama.LlamaConfig.tiny(), num_layers=3)
+    )
+    m = cfg.model
+    gold = InferenceEngine(cfg, seed=0).generate(
+        [[1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11]], max_new_tokens=12
+    )[0]
+    src = InferenceEngine(cfg, seed=0)
+    sid = src.add_request([1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11], max_new_tokens=12)
+    results = {}
+    for _ in range(2):
+        for r in src.step():
+            results[r.seq_id] = r
+    k_before = np.asarray(src.pool.k_pages)
+    v_before = np.asarray(src.pool.v_pages)
+    bundle, _ = src.park_requests()
+    ids = bundle.page_ids
+    assert len(ids) == 2  # 13-14 tokens at page_size 8
+    assert bundle.k_host.shape == (
+        m.num_layers, len(ids), cfg.page_size, m.num_kv_heads * m.head_dim
+    )
+    np.testing.assert_array_equal(bundle.k_host, k_before[:, ids])
+    np.testing.assert_array_equal(bundle.v_host, v_before[:, ids])
+    assert np.abs(bundle.k_host.astype(np.float32)).sum() > 0
+
+    doc = parked.encode_wire(bundle, identity={}, chunk_bytes=1)  # 1 page/chunk
+    assert doc["version"] == parked.WIRE_VERSION == 2
+    assert doc["kv"]["shape"] == list(bundle.k_host.shape)
+    got, _ = parked.decode_wire(json.loads(json.dumps(doc)), Request)
+    np.testing.assert_array_equal(got.k_host, bundle.k_host)
+    np.testing.assert_array_equal(got.v_host, bundle.v_host)
+    stale = dict(doc, version=1)
+    with pytest.raises(ValueError, match="wire version"):
+        parked.decode_wire(stale, Request)
+
+    dst = InferenceEngine(cfg, seed=0)
+    dst.resume_parked(got)
+    (req,) = [r for r in dst._slots if r is not None]
+    new_ids = req.pages[: len(ids)]
+    np.testing.assert_array_equal(
+        np.asarray(dst.pool.k_pages)[:, new_ids], bundle.k_host
+    )
+    np.testing.assert_array_equal(
+        np.asarray(dst.pool.v_pages)[:, new_ids], bundle.v_host
+    )
+    _drain(dst, results)
+    (out,) = [r for r in results.values()]
+    assert out.out_tokens == gold and sid == out.seq_id
 
 
 def test_park_resume_penalties_bias_stop_seeded():
