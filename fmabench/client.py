@@ -147,6 +147,8 @@ def server_argv(
     ]
     if platform == "cpu":
         argv.append("--pallas-interpret")
+    if cell.data_dir:
+        argv += ["--data-dir", cell.data_dir]
     return argv + [
         "--", "--port", str(port), "--seed", str(seed),
         *cell.engine_options(traced),

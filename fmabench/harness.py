@@ -325,14 +325,24 @@ def sample_for_check(
     return [longest] + rest[: max(0, want - 1)]
 
 
+def reference_job(
+    cell: spec.Cell, seed: int, sample: List[client.Record], platform: str
+) -> Dict[str, Any]:
+    """What the reference child is told: the family whose ``reference.py``
+    it loads (and where a rehearsal keeps its own), that family's sizes,
+    and the prompts with their served tokens."""
+    return {
+        "family": cell.family.name, "data_dir": cell.data_dir,
+        "dims": cell.dims, "seed": seed, "platform": platform,
+        "requests": [{"prompt": r.prompt, "tokens": r.tokens} for r in sample],
+    }
+
+
 def run_reference(
     cell: spec.Cell, seed: int, sample: List[client.Record], platform: str,
     out_dir: str, control: str = "",
 ) -> Dict[str, Any]:
-    job = {
-        "dims": spec.model_dims(cell.config), "seed": seed, "platform": platform,
-        "requests": [{"prompt": r.prompt, "tokens": r.tokens} for r in sample],
-    }
+    job = reference_job(cell, seed, sample, platform)
     tagged = "control" if control else "reference"
     job_path = os.path.join(out_dir, f"{tagged}_job.json")
     res_path = os.path.join(out_dir, f"{tagged}_result.json")
@@ -411,7 +421,7 @@ def run_cell(args: Any, t_start: float) -> int:
     profile_dir = os.path.join(out_dir, "profile")
     if os.path.exists(memory_file):
         os.remove(memory_file)
-    dims = spec.model_dims(cell.config)
+    dims = cell.dims
     kind = cell.traffic["kind"]
     port = client.free_port()
     base = f"http://127.0.0.1:{port}"
@@ -421,6 +431,7 @@ def run_cell(args: Any, t_start: float) -> int:
     )
     hooks = Hooks(base, traced, profile_dir, float(args.seconds))
     hooks.ev.engine_option = cell.engine_option
+    hooks.ev.data_dir = cell.data_dir
     with client.Child("server", argv, out_dir) as child:
         ready_s = client.wait_healthy(base + "/health", child, 1100)
         stats = client.http("GET", base + "/v1/stats")

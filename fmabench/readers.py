@@ -24,8 +24,10 @@ Kinds
               ``steps_option``, or ``steps_default`` where the option is not
               given) | ``idle_pct`` (regex unused).
 ``roofline``  ``regex`` picks a kernel's events; ``function`` names a
-              function in ``fmabench/roofline.py`` that gives the bytes and
-              flops the algorithm needs per call from the cell's shapes;
+              function in ``fmabench/roofline.py``, or else a file
+              ``fmabench/rooflines/<function>.py`` that defines it, which
+              gives the bytes and flops the algorithm needs per call from
+              the cell's shapes (its family's sizes and the live contexts);
               the least time at the table's peaks over the measured time,
               as a percentage.
 """
@@ -36,6 +38,7 @@ import re
 from typing import Any, Callable, Dict, List, Optional
 
 from . import roofline as roofline_mod
+from . import spec
 from .traffic import percentile
 
 
@@ -60,6 +63,8 @@ class Evidence:
         self.shapes: Dict[str, Any] = {}
         self.peaks: Dict[str, Any] = {}
         self.engine_option: Callable[[str, Any], Any] = lambda flag, d=None: d
+        #: a rehearsal's own data files, looked in first (roofline files)
+        self.data_dir = ""
 
 
 def dig(doc: Any, path: str) -> Any:
@@ -167,7 +172,10 @@ def read_roofline(r: Dict[str, Any], ev: Evidence) -> Optional[float]:
     total_s, calls = tr.matching(re.compile(r["regex"]))
     if calls == 0 or total_s <= 0:
         return None
-    need = getattr(roofline_mod, r["function"])(ev.shapes)
+    fn = getattr(roofline_mod, r["function"], None) or spec.roofline_function(
+        r["function"], ev.data_dir
+    )
+    need = fn(ev.shapes)
     if need is None:
         return None
     least_s = max(

@@ -48,7 +48,7 @@ def main() -> int:
     from llm_d_fast_model_actuation_tpu.ops import attention
     from llm_d_fast_model_actuation_tpu.parallel.mesh import AXES
 
-    from . import serve, spec
+    from . import spec
 
     jax.config.update("jax_enable_compilation_cache", False)
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
@@ -60,7 +60,8 @@ def main() -> int:
         if args.workload and w["name"] not in args.workload:
             continue
         cell = spec.Cell(bench, w["name"])
-        model = serve.build_model_config(cell.config)
+        dims = cell.dims
+        model = cell.family.part("program").build(dims)
         eargs = server.make_arg_parser().parse_args(
             ["--model", "tiny", *cell.engine_options(False)]
         )
@@ -73,10 +74,10 @@ def main() -> int:
             decode_chunk=eargs.decode_chunk or 32,
             max_prefill_tokens=eargs.max_prefill_tokens,
         )
-        dims = spec.model_dims(cell.config)
-        kv_bytes = (2 * dims["num_layers"] * eargs.num_pages * eargs.page_size
-                    * dims["num_kv_heads"] * dims["head_dim"] * 2)
-        reckoned = spec.param_count(dims) * 2 + kv_bytes
+        keys = cell.family.keys
+        reckoned = keys.param_count(dims) * 2 + keys.kv_bytes(
+            dims, eargs.num_pages, eargs.page_size
+        )
         lens = cell.traffic["warmup"]["prompt_lens"]
         limit = eargs.max_prefill_tokens or max(lens)
         programs = [("chunk", cfg.decode_chunk),

@@ -3,7 +3,8 @@ cell's configuration registered under its name.
 
     python -m fmabench.serve --config-file <path> -- <engine.server options>
 
-It adds the configuration file's sizes to the server's ``MODEL_CONFIGS``
+It adds the configuration file, as its family's ``program.py`` builds it
+(``fmabench/families/<family>/``), to the server's ``MODEL_CONFIGS``
 and calls the server's own ``main``: the same entry, scheduler, cache and
 kernels as any other model (``python -m
 llm_d_fast_model_actuation_tpu.engine.server``). Two things here are the
@@ -24,26 +25,13 @@ import sys
 from typing import List
 
 
-def build_model_config(config: dict):
-    """The program's config object for an HF-keyed configuration file."""
-    from llm_d_fast_model_actuation_tpu.models import llama, moe
-
+def build_model_config(config: dict, data_dir: str = ""):
+    """The program's config object for a configuration file: its family's
+    ``program.py`` builds it from its family's sizes."""
     from . import spec
 
-    d = spec.model_dims(config)
-    common = dict(
-        vocab_size=d["vocab_size"], hidden_size=d["hidden_size"],
-        num_layers=d["num_layers"], num_heads=d["num_heads"],
-        num_kv_heads=d["num_kv_heads"], head_dim=d["head_dim"],
-        intermediate_size=d["intermediate_size"], rope_theta=d["rope_theta"],
-        rms_eps=d["rms_eps"], max_seq_len=d["max_context"],
-    )
-    if d["num_experts"] > 1:
-        return moe.MoeConfig(
-            num_experts=d["num_experts"],
-            experts_per_token=d["experts_per_token"], **common,
-        )
-    return llama.LlamaConfig(**common)
+    family = spec.family_of(config, data_dir)
+    return family.part("program").build(family.dims(config))
 
 
 def _dump_memory(path: str) -> None:
@@ -68,6 +56,8 @@ def main(argv: List[str]) -> None:
     p = argparse.ArgumentParser(prog="fmabench.serve")
     p.add_argument("--config-file", required=True)
     p.add_argument("--model-name", required=True)
+    p.add_argument("--data-dir", default="",
+                   help="a rehearsal's own data files, looked in first")
     p.add_argument("--memory-file", default="")
     p.add_argument("--require-platform", default="")
     p.add_argument("--pallas-interpret", action="store_true",
@@ -81,7 +71,9 @@ def main(argv: List[str]) -> None:
     from . import spec
 
     config = spec.config_file(args.config_file)
-    server.MODEL_CONFIGS[args.model_name] = lambda: build_model_config(config)
+    server.MODEL_CONFIGS[args.model_name] = lambda: build_model_config(
+        config, args.data_dir
+    )
     if args.pallas_interpret:
         from llm_d_fast_model_actuation_tpu.ops import attention
 

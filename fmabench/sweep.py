@@ -1,6 +1,6 @@
 """Find an open mix's knee, once, by a sweep on the chip (by hand).
 
-    python -m fmabench.sweep --workload mistral-7b.chat --rates 4,8,12,16 \
+    python -m fmabench.sweep --workload <open-loop cell> --rates 4,8,12,16 \
         --seconds 20 --seed 1
 
 One engine child serves every stage. A stage offers the mix at a fixed rate
@@ -46,7 +46,7 @@ def main() -> int:
         cell, config_path, port, args.seed, False,
         os.path.join(out_dir, "memory.json"), "tpu",
     )
-    vocab = spec.model_dims(cell.config)["vocab_size"]
+    vocab = cell.dims["vocab_size"]
     with client.Child("server", argv, out_dir) as child:
         client.wait_healthy(base + "/health", child, 1100)
         asyncio.run(harness.warmup_ladder(base, cell.traffic, vocab, args.seed))

@@ -1,8 +1,10 @@
 """How ``correct`` is decided, at a size a test run can hold (CPU).
 
-* the plain reference makes the program's weights from the seed alone and
-  agrees with what the program serves (prefill, then decode through the
-  paged cache) at tiny dense and MoE configurations;
+* each family's plain reference makes the program's weights from the seed
+  alone and agrees with what the program serves (prefill, then decode
+  through the paged cache) at tiny configurations of the two families under
+  ``fmabench/families/`` (``test_fmabench_family.py`` holds the same of the
+  family that exists under ``testdata/`` only, through the helpers here);
 * the control — the reference with int8 weights put in the program's
   place — reads wider gaps than the program does, and fails limits set the
   way PERF.md sets the cells' (above the sound runs' largest, below the
@@ -34,6 +36,10 @@ SIZES = {
     },
 }
 SIZES["moe"] = {**SIZES["dense"], "num_local_experts": 4, "num_experts_per_tok": 2}
+KINDS = sorted(SIZES)
+#: a rehearsal's own files are looked in first: a family that exists under
+#: testdata/ only (test_fmabench_family.py) is found there
+DATA_DIR = "fmabench/testdata"
 SEEDS = (1, 2, 3)
 #: limits for THIS size on the mean gap pooled over the seeds, set between
 #: the two readings the way the cells' limits are: the program reads
@@ -50,7 +56,7 @@ def served(config, seed):
     )
 
     eng = InferenceEngine(
-        EngineConfig(model=serve.build_model_config(config), max_batch=4,
+        EngineConfig(model=serve.build_model_config(config, DATA_DIR), max_batch=4,
                      page_size=16, num_pages=128, decode_chunk=8),
         seed=seed,
     )
@@ -61,57 +67,83 @@ def served(config, seed):
     return [{"prompt": p, "tokens": list(o)} for p, o in zip(prompts, outs)]
 
 
-@functools.lru_cache(maxsize=None)
-def readings(kind):
-    dims = spec.model_dims(SIZES[kind])
-    rows = []
-    for seed in SEEDS:
-        reqs = served(SIZES[kind], seed)
-        rows.append((
-            reference.compare(dims, seed, reqs),
-            reference.compare(dims, seed, reqs, control="int8"),
-        ))
-    return rows
+def family_reference(config):
+    return spec.family_of(config, DATA_DIR).part("reference")
 
 
-@pytest.mark.parametrize("kind", ["dense", "moe"])
-def test_reference_weights_are_the_programs_weights(kind):
+_READINGS = {}
+
+
+def readings(config):
+    """(program, int8 control) on each of SEEDS, once per configuration."""
+    key = json.dumps(config, sort_keys=True)
+    if key not in _READINGS:
+        dims = spec.model_dims(config, DATA_DIR)
+        fam = family_reference(config)
+        rows = []
+        for seed in SEEDS:
+            reqs = served(config, seed)
+            rows.append((
+                reference.compare(fam, dims, seed, reqs),
+                reference.compare(fam, dims, seed, reqs, control="int8"),
+            ))
+        _READINGS[key] = rows
+    return _READINGS[key]
+
+
+def reference_weights_are_the_programs(config):
     from llm_d_fast_model_actuation_tpu.models.registry import init_params_placed
 
-    dims = spec.model_dims(SIZES[kind])
+    dims = spec.model_dims(config, DATA_DIR)
+    fam = family_reference(config)
     for seed in (0, 3_000_000_019, 2**32 + 5):
-        mine = jax.jit(functools.partial(reference.init_weights, d=dims))(
+        mine = jax.jit(functools.partial(fam.init_weights, d=dims))(
             np.uint32(seed % 2**32)
         )
         theirs = init_params_placed(
-            jax.random.key(seed), serve.build_model_config(SIZES[kind])
+            jax.random.key(seed), serve.build_model_config(config, DATA_DIR)
         )
         theirs = dict(jax.tree_util.tree_leaves_with_path(theirs))
-        for path, leaf in jax.tree_util.tree_leaves_with_path(mine):
+        mine = jax.tree_util.tree_leaves_with_path(mine)
+        assert len(mine) == len(theirs)     # every leaf the program has
+        for path, leaf in mine:
             assert np.array_equal(
                 np.asarray(leaf, np.float32), np.asarray(theirs[path], np.float32)
             ), (seed, jax.tree_util.keystr(path))
 
 
-@pytest.mark.parametrize("kind", ["dense", "moe"])
-def test_program_agrees_with_the_reference(kind):
-    for prog, _ in readings(kind):
+def program_agrees_with_the_reference(config, limit):
+    for prog, _ in readings(config):
         assert prog["finite"] and prog["compared_tokens"] == 8 * 32
         # most served tokens ARE the reference's best
         assert prog["nonzero_share"] <= 0.08
-    pooled = np.mean([p["gap_mean"] for p, _ in readings(kind)])
-    assert pooled <= POOLED_MEAN_LIMIT[kind]
+    pooled = np.mean([p["gap_mean"] for p, _ in readings(config)])
+    assert pooled <= limit
 
 
-@pytest.mark.parametrize("kind", ["dense", "moe"])
-def test_int8_control_comes_out_not_correct(kind):
-    rows = readings(kind)
+def int8_control_comes_out_not_correct(config, limit):
+    rows = readings(config)
     prog = np.mean([p["gap_mean"] for p, _ in rows])
     ctrl = np.mean([c["gap_mean"] for _, c in rows])
-    assert ctrl > POOLED_MEAN_LIMIT[kind] > prog
+    assert ctrl > limit > prog
     assert np.mean([c["nonzero_share"] for _, c in rows]) > 1.5 * np.mean(
         [p["nonzero_share"] for p, _ in rows]
     )
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_reference_weights_are_the_programs_weights(kind):
+    reference_weights_are_the_programs(SIZES[kind])
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_program_agrees_with_the_reference(kind):
+    program_agrees_with_the_reference(SIZES[kind], POOLED_MEAN_LIMIT[kind])
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_int8_control_comes_out_not_correct(kind):
+    int8_control_comes_out_not_correct(SIZES[kind], POOLED_MEAN_LIMIT[kind])
 
 
 def test_decide_holds_each_number_to_its_own_limit():
@@ -206,23 +238,27 @@ def test_sample_is_seeded_and_holds_the_longest():
     assert harness.sample_for_check([], 7, 6) == []
 
 
-def rehearse(*extra):
-    """One whole run on the CPU: the harness's look for a chip is skipped
-    (--rehearse), everything else is the run the driver makes."""
+def rehearsal(cell, *extra, benchmark=os.path.join(TESTDATA, "benchmark.json")):
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=spec.ROOT)
     env.pop("XLA_FLAGS", None)
-    proc = subprocess.run(
+    return subprocess.run(
         [sys.executable, "-m", "fmabench", "--rehearse", "--benchmark",
-         os.path.join(TESTDATA, "benchmark.json"), "--workload", "tiny.chat",
+         benchmark, "--workload", cell,
          "--seed", str(2**31 + 12345), "--seconds", "2", "--trace", "0", *extra],
         cwd=spec.ROOT, env=env, capture_output=True, text=True, timeout=300,
     )
+
+
+def rehearse(cell, *extra):
+    """One whole run on the CPU: the harness's look for a chip is skipped
+    (--rehearse), everything else is the run the driver makes."""
+    proc = rehearsal(cell, *extra)
     assert proc.returncode == 0, proc.stderr[-2000:]
     return json.loads(proc.stdout.strip().splitlines()[-1]), proc.stderr
 
 
-def test_rehearsal_run_is_correct_and_names_the_cpu():
-    line, err = rehearse()
+def rehearsal_is_correct_and_names_the_cpu(cell):
+    line, err = rehearse(cell)
     assert list(line) == [
         "correct", "attempted", "failed", "metrics", "device", "checks"
     ]
@@ -233,11 +269,19 @@ def test_rehearsal_run_is_correct_and_names_the_cpu():
     assert "check gap_mean:" in err.splitlines()[-1]
 
 
-def test_broken_timed_path_comes_out_not_correct():
-    line, err = rehearse("--serve-module", "tests.fmabench.broken_serve")
+def test_rehearsal_run_is_correct_and_names_the_cpu():
+    rehearsal_is_correct_and_names_the_cpu("tiny.chat")
+
+
+def broken_timed_path_comes_out_not_correct(cell):
+    line, err = rehearse(cell, "--serve-module", "tests.fmabench.broken_serve")
     assert line["correct"] is False and line["failed"] == 0
     assert line["checks"]["gap_max"]["value"] > line["checks"]["gap_max"]["limit"]
     assert "FAILS" in err
+
+
+def test_broken_timed_path_comes_out_not_correct():
+    broken_timed_path_comes_out_not_correct("tiny.chat")
 
 
 def test_measured_path_never_falls_back_to_the_cpu():

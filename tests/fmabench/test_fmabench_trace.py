@@ -119,3 +119,18 @@ def test_counter_span_and_client_readers():
                  "scale_by_option": "--max-batch"}, ev) == 48.0
     with pytest.raises(ValueError):
         read({"kind": "no_such_kind"}, ev)
+
+
+def test_a_roofline_function_is_found_as_a_file(summary):
+    ev = evidence(summary)
+    ev.shapes = {"hidden_size": 4096, "vocab_size": 32000, "live_seqs": 50.0}
+    r = {"kind": "roofline", "regex": "paged_decode", "function": "lm_head_step"}
+    with pytest.raises(FileNotFoundError):      # not under fmabench/rooflines/
+        readers.read_metric(r, ev)
+    ev.data_dir = "fmabench/testdata/examples"
+    need_bytes = (4096 * 32000 + 50 * (4096 + 32000)) * 2
+    assert readers.read_metric(r, ev) == pytest.approx(
+        100 * (need_bytes / 819e9) / 0.002)
+    # a function of roofline.py still resolves there first, as before
+    ev.shapes.update(num_kv_heads=8, head_dim=128, num_heads=32, live_kv_tokens=4e4)
+    assert readers.read_metric({**r, "function": "paged_decode_step"}, ev) > 0
