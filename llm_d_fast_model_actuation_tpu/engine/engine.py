@@ -42,7 +42,7 @@ from jax.sharding import Mesh
 from ..models import llama
 from ..parallel.mesh import shard_pytree
 from ..utils import tracing
-from .kv_cache import OutOfPages, PageAllocator, PagePool
+from .kv_cache import KVLayout, OutOfPages, PageAllocator, PagePool
 from .sampling import sample
 
 
@@ -127,6 +127,20 @@ class EngineConfig:
     @property
     def pages_per_seq(self) -> int:
         return -(-self.seq_len // self.page_size)
+
+    @property
+    def kv_layout(self) -> KVLayout:
+        """The division of the KV state between pages and rings
+        (engine/kv_cache.py): from the model's window layers, the batch and
+        the segment limit — nothing an operator sets."""
+        n_window, window = llama.window_layers(self.model)
+        segment = prefill_bucket(
+            self.max_prefill_tokens or self.seq_len, self.seq_len
+        )
+        return KVLayout.plan(
+            self.model.num_layers, n_window, window, self.page_size,
+            self.seq_len, segment,
+        )
 
     @property
     def packed_token_budget(self) -> int:
@@ -403,6 +417,24 @@ def _subst_leaves(params: Any, delta: Dict[str, Any]) -> Any:
 
 class EngineAsleep(RuntimeError):
     """The engine's device state is offloaded; wake_up() before serving."""
+
+
+class WindowLayersUnsupported(ValueError):
+    """A path that cannot carry the rings of sliding-window layers was
+    asked of a model that has them."""
+
+
+def refuse_window_layers(model, what: str) -> None:
+    """Raise, naming the model and the path, if ``model`` has
+    sliding-window layers: ``what`` addresses the KV state as pages of one
+    kind and would read or move a window layer's ring as something else."""
+    n_window, window = llama.window_layers(model)
+    if n_window:
+        raise WindowLayersUnsupported(
+            f"{type(model).__name__} has {n_window} sliding-window layers "
+            f"(window {window}) whose K and V live in per-sequence rings: "
+            f"{what} cannot carry a ring yet and refuses this model"
+        )
 
 
 class ProgramSet:
@@ -969,6 +1001,12 @@ class InferenceEngine:
             from ..ops.pallas.decode import check_kernel_shape
 
             check_kernel_shape(m.num_kv_heads // tp, m.head_dim)
+        if cfg.prefix_caching:
+            refuse_window_layers(m, "the prefix cache (--prefix-caching on)")
+        if cfg.packed_serving:
+            refuse_window_layers(m, "the packed mixed_step path (--packed-serving on)")
+        if cfg.speculative_ngram:
+            refuse_window_layers(m, "--speculative-ngram")
         self.cfg = cfg
         self.mesh = mesh
         # thread the attention impl through the model config (per-engine, not
@@ -991,19 +1029,10 @@ class InferenceEngine:
             # committed arrays — starting committed keeps one compiled set.
             params = jax.device_put(params, jax.devices()[0])
         self.params = params
-        self.pool = PagePool.create(
-            m.num_layers,
-            cfg.num_pages,
-            cfg.page_size,
-            m.num_kv_heads,
-            m.head_dim,
-            dtype=m.dtype,
-            mesh=mesh,
-        )
-        if mesh is None:
-            self.pool.replace(
-                jax.device_put(self.pool.as_tuple(), jax.devices()[0])
-            )
+        #: pages for the full-attention layers, rings for the window layers
+        self.kv_layout = cfg.kv_layout
+        self._model_cfg = m
+        self._create_pool()
         self.allocator = PageAllocator(cfg.num_pages)
         if cfg.prefix_caching:
             from .prefix_cache import PrefixCache
@@ -1014,7 +1043,21 @@ class InferenceEngine:
         b, p = cfg.max_batch, cfg.pages_per_seq
         # Host mirrors of the device scheduler state (source of truth between
         # chunks; re-uploaded only after an admission/retire/prefill edge).
-        self._page_table = np.zeros((b, p), dtype=np.int32)
+        # A page-table row: the sequence's pages, then (window layers) the
+        # static columns of its slot's ring, never rewritten.
+        self._page_table = np.zeros(
+            (b, self.kv_layout.table_width), dtype=np.int32
+        )
+        self._page_table[:, p:] = self.kv_layout.ring_columns(b)
+        #: cumulative counters of the two caches and the routed layer,
+        #: counted on the host from what the scheduler knows (/v1/stats
+        #: "kv" and "moe"): positions that left a ring (overwritten by a
+        #: later position of their sequence), and tokens through the expert
+        #: layers with their (token, expert) assignments
+        self.window_tokens_evicted = 0
+        self.moe_tokens = 0
+        self._has_experts = getattr(m, "num_experts", 0) > 1
+        self._ring_len = self.kv_layout.ring_pages * cfg.page_size
         self._positions = np.zeros((b,), dtype=np.int32)
         self._last_tokens = np.zeros((b,), dtype=np.int32)
         self._temps = np.zeros((b,), dtype=np.float32)
@@ -1052,7 +1095,6 @@ class InferenceEngine:
         #: single-host or follower.
         self.lockstep: Optional[Any] = None
 
-        self._model_cfg = m
         #: the resolved attention implementation (never "auto")
         self.attention_impl = impl
 
@@ -1192,6 +1234,62 @@ class InferenceEngine:
         #: recorder records)
         self.variant_attaches = 0
         self.variant_detaches = 0
+
+    def _create_pool(self) -> None:
+        """A fresh device KV state (pages, and rings where the model has
+        window layers), committed to the engine's placement."""
+        m, cfg, lay = self._model_cfg, self.cfg, self.kv_layout
+        self.pool = PagePool.create(
+            lay.global_layers,
+            cfg.num_pages,
+            cfg.page_size,
+            m.num_kv_heads,
+            m.head_dim,
+            dtype=m.dtype,
+            mesh=self.mesh,
+            ring_shape=lay.ring_shape(
+                cfg.max_batch, cfg.page_size, m.num_kv_heads, m.head_dim
+            ),
+        )
+        if self.mesh is None:
+            self.pool.replace(
+                jax.device_put(self.pool.as_tuple(), jax.devices()[0])
+            )
+
+    def _count_forward(self, first: int, tokens: int) -> None:
+        """Host counters of the forwards that took one sequence from
+        position ``first`` through ``tokens`` more: tokens through the
+        expert layers, and positions overwritten in the sequence's ring."""
+        if self._has_experts:
+            self.moe_tokens += tokens
+        if self._ring_len:
+            self.window_tokens_evicted += max(
+                0, first + tokens - self._ring_len
+            ) - max(0, first - self._ring_len)
+
+    def cache_stats(self) -> Dict[str, Dict[str, int]]:
+        """The ``kv`` and ``moe`` blocks of ``/v1/stats``."""
+        m, lay = self._model_cfg, self.kv_layout
+        experts = getattr(m, "num_experts", 0)
+        per_token = m.num_layers * getattr(m, "experts_per_token", 0)
+        return {
+            "kv": {
+                "global_layers": lay.global_layers,
+                "window_layers": lay.window_layers,
+                "window": lay.window,
+                "ring_tokens": self._ring_len,
+                "global_pages_in_use": (
+                    self.cfg.num_pages - 1 - self.allocator.available
+                ),
+                "ring_bytes": self.pool.ring_nbytes(),
+                "window_tokens_evicted": self.window_tokens_evicted,
+            },
+            "moe": {
+                "experts": experts if experts > 1 else 0,
+                "tokens": self.moe_tokens,
+                "assignments": self.moe_tokens * per_token,
+            },
+        }
 
     # -- compiled-program dispatch (AOT executables > lazy jit) --------------
 
@@ -1418,6 +1516,7 @@ class InferenceEngine:
         is validated against the base leaf it replaces — a shape/dtype
         mismatch would otherwise surface as a trace error deep inside
         the multi program, unattributable to this attach."""
+        refuse_window_layers(self._model_cfg, "a co-resident variant attach")
         if not self._packed:
             raise ValueError(
                 "co-resident variants require packed serving: the "
@@ -1719,7 +1818,7 @@ class InferenceEngine:
             self._bias[slot, t] = v
         row = np.zeros((self.cfg.pages_per_seq,), dtype=np.int32)
         row[: len(req.pages)] = req.pages
-        self._page_table[slot] = row
+        self._page_table[slot, : row.size] = row
         # penalties count prompt tokens too (OpenAI "text so far")
         self._token_counts[slot] = 0
         np.add.at(self._token_counts[slot], req.prompt, 1)
@@ -1776,6 +1875,7 @@ class InferenceEngine:
             (bucket - len(seg)) * self._pad_token_bytes
         )
         self.dispatch_tokens["bucketed"] += len(seg)
+        self._count_forward(start_pos, len(seg))
         tokens = np.zeros((1, bucket), dtype=np.int32)
         tokens[0, : len(seg)] = seg
         # next prompt token at each segment position (prompt-logprob
@@ -1838,6 +1938,7 @@ class InferenceEngine:
                     (bucket - n) * self._pad_token_bytes
                 )
                 self.dispatch_tokens["bucketed"] += n
+                self._count_forward(0, n)
                 tokens = np.zeros((1, bucket), dtype=np.int32)
                 tokens[0, :n] = req.prompt
                 seq_lens = np.array([n], dtype=np.int32)
@@ -2036,7 +2137,7 @@ class InferenceEngine:
         else:
             self.allocator.free(req.pages)
         self._slots[req.slot] = None
-        self._page_table[req.slot] = 0
+        self._page_table[req.slot, : self.cfg.pages_per_seq] = 0
         self._positions[req.slot] = 0
         self._last_tokens[req.slot] = 0
         self._temps[req.slot] = 0.0
@@ -2813,6 +2914,8 @@ class InferenceEngine:
             # repeats, and abort already handled the retire
             if running[slot].done:
                 del running[slot]
+        # every token emitted below was a step that wrote its position
+        chunk_start = [(req, req.pos) for req in running.values()]
         for t in range(T):
             for slot, req in list(running.items()):
                 tok = int(toks[t, slot])
@@ -2838,6 +2941,8 @@ class InferenceEngine:
                         self._retire(req)
                     finished.append(req)
                     del running[slot]
+        for req, first in chunk_start:
+            self._count_forward(first, req.pos - first)
         return finished
 
     def _defer_retire(self, req: Request) -> None:
@@ -2921,6 +3026,7 @@ class InferenceEngine:
         queue instead of carrying KV: prefill is a pure function of the
         prompt and no RNG split is consumed before its final segment, so
         re-running it on resume reproduces identical output."""
+        refuse_window_layers(self._model_cfg, "a zero-drain park")
         from . import parked as parked_mod
 
         self.drain_inflight()
@@ -2992,7 +3098,7 @@ class InferenceEngine:
         # are rebuilt fresh by set_state/rebuild_kv_pool on restore)
         self._slots = [None] * self.cfg.max_batch
         self._waiting = []
-        self._page_table[:] = 0
+        self._page_table[:, : self.cfg.pages_per_seq] = 0
         self._positions[:] = 0
         self._last_tokens[:] = 0
         self._temps[:] = 0.0
@@ -3010,8 +3116,7 @@ class InferenceEngine:
         for leaf in self.pool.as_tuple():
             if leaf is not None:
                 leaf.delete()
-        self.pool.k_pages = None
-        self.pool.v_pages = None
+        self.pool.drop()
         self.kv_detached = True
         return bundle, finished
 
@@ -3019,20 +3124,7 @@ class InferenceEngine:
         """Fresh device KV pool + allocator after a zero-drain park
         dropped them (called by the sleeper's set_state when the restored
         state carries no "kv" subtree, and by rollback paths)."""
-        m = self._model_cfg
-        self.pool = PagePool.create(
-            m.num_layers,
-            self.cfg.num_pages,
-            self.cfg.page_size,
-            m.num_kv_heads,
-            m.head_dim,
-            dtype=m.dtype,
-            mesh=self.mesh,
-        )
-        if self.mesh is None:
-            self.pool.replace(
-                jax.device_put(self.pool.as_tuple(), jax.devices()[0])
-            )
+        self._create_pool()
         self.allocator = PageAllocator(self.cfg.num_pages)
         self.kv_detached = False
 
@@ -3137,7 +3229,7 @@ class InferenceEngine:
             self._slots[slot] = r
             row = np.zeros((self.cfg.pages_per_seq,), dtype=np.int32)
             row[: len(new_pages)] = new_pages
-            self._page_table[slot] = row
+            self._page_table[slot, : row.size] = row
             self._positions[slot] = r.pos
             self._last_tokens[slot] = (
                 r.out_tokens[-1] if r.out_tokens else 0

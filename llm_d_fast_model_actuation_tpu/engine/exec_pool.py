@@ -232,7 +232,7 @@ def _abstract_state(cfg, mesh=None):
     import jax
 
     from ..models.registry import init_params_for
-    from .kv_cache import POOL_SPEC, PagePool
+    from .kv_cache import POOL_SPEC, RING_SPEC, PagePool
 
     m = cfg.model
     params = jax.eval_shape(
@@ -248,7 +248,7 @@ def _abstract_state(cfg, mesh=None):
             ),
             params,
         )
-        kv_sharding = sharding
+        kv_sharding = ring_sharding = sharding
     else:
         from jax.sharding import NamedSharding
 
@@ -260,15 +260,26 @@ def _abstract_state(cfg, mesh=None):
             params, logical_shardings(mesh, logical_axes_for(m)),
         )
         kv_sharding = NamedSharding(mesh, POOL_SPEC)
+        ring_sharding = NamedSharding(mesh, RING_SPEC)
+    layout = cfg.kv_layout
     kv = jax.ShapeDtypeStruct(
         PagePool.pool_shape(
-            m.num_layers, cfg.num_pages, cfg.page_size, m.num_kv_heads,
-            m.head_dim,
+            layout.global_layers, cfg.num_pages, cfg.page_size,
+            m.num_kv_heads, m.head_dim,
         ),
         m.dtype,
         sharding=kv_sharding,
     )
-    return params, (kv, kv)
+    if not layout.window_layers:
+        return params, (kv, kv)
+    ring = jax.ShapeDtypeStruct(
+        layout.ring_shape(
+            cfg.max_batch, cfg.page_size, m.num_kv_heads, m.head_dim
+        ),
+        m.dtype,
+        sharding=ring_sharding,
+    )
+    return params, (kv, kv, ring, ring)
 
 
 def abstract_args(cfg, program: str, bucket: int, mesh=None) -> list:
@@ -293,7 +304,8 @@ def abstract_args(cfg, program: str, bucket: int, mesh=None) -> list:
         sched_sharding = NamedSharding(mesh, PartitionSpec())
     m = cfg.model
     V = m.vocab_size
-    b, p = cfg.max_batch, cfg.pages_per_seq
+    # a page-table row: the pages' columns, then a window model's ring's
+    b, p = cfg.max_batch, cfg.kv_layout.table_width
     params, cache = _abstract_state(cfg, mesh)
     A = jax.ShapeDtypeStruct
     f32, i32, u32 = jnp.float32, jnp.int32, jnp.uint32

@@ -15,6 +15,19 @@ heads apart gather a context's pages first and split the minor axis of what
 they gathered.
 
 Page size defaults to 16 tokens: a (16, kvh_shard * head_dim) page tile.
+
+A model with sliding-window layers (models/llama.py:window_layers) has two
+kinds of KV state (:class:`KVLayout`): the pool above for its
+full-attention layers alone, and for its window layers a RING per sequence
+slot, ``[window layers, slots, ring pages, page_size, kv_heads * head_dim]``
+— a page of it is the same tile, so the same kernels read it. A ring holds
+the window plus the longest prefill segment that is written before it is
+read, position p at ``p % ring_len``; it is addressed through static ring
+columns the engine appends to every page-table row (slot i owns pages
+``i * ring_pages ...`` of the rings' flat page axis), so an attention op
+reads either kind through a table row and a layer index. The rings are
+sized from ``max_batch``, the model's window and the segment limit; no
+allocator hands them out and no option sizes them.
 """
 
 from __future__ import annotations
@@ -29,10 +42,76 @@ from jax.sharding import Mesh, NamedSharding
 from ..ops.attention import POOL_SPEC
 
 
+@dataclass(frozen=True)
+class KVLayout:
+    """How an engine's KV state divides between the paged pool and the
+    rings; the ONE definition shared by the live build (engine/engine.py),
+    the AOT warm-up's avals (engine/exec_pool.py) and the stats."""
+
+    #: layers whose K and V live in the paged pool (all of them for a model
+    #: without window layers)
+    global_layers: int
+    #: layers whose K and V live in the rings
+    window_layers: int
+    #: their window, tokens (0 without window layers)
+    window: int
+    #: pages of one sequence's ring (0 without window layers)
+    ring_pages: int
+    #: page-table columns of the paged pool
+    pages_per_seq: int
+
+    @property
+    def table_width(self) -> int:
+        """Columns of a page-table row: the sequence's pages, then its
+        ring's."""
+        return self.pages_per_seq + self.ring_pages
+
+    @classmethod
+    def plan(
+        cls, num_layers: int, window_layers: int, window: int,
+        page_size: int, seq_len: int, segment: int,
+    ) -> "KVLayout":
+        """``segment``: the most positions one program writes before it
+        reads (the largest prefill bucket). A ring as long as the context
+        never wraps, so it is never longer than that."""
+        pps = -(-seq_len // page_size)
+        if not window_layers:
+            return cls(num_layers, 0, 0, 0, pps)
+        ring_len = min(window + segment, seq_len)
+        return cls(
+            num_layers - window_layers, window_layers, window,
+            -(-ring_len // page_size), pps,
+        )
+
+    def ring_shape(
+        self, slots: int, page_size: int, num_kv_heads: int, head_dim: int
+    ) -> Tuple[int, int, int, int, int]:
+        return (
+            self.window_layers, slots, self.ring_pages, page_size,
+            num_kv_heads * head_dim,
+        )
+
+    def ring_columns(self, slots: int):
+        """[slots, ring_pages] int32: the static ring part of the page
+        table, ids into the rings' flat (slots * ring_pages) page axis."""
+        import numpy as np
+
+        return np.arange(slots * self.ring_pages, dtype=np.int32).reshape(
+            slots, self.ring_pages
+        )
+
+
+#: the rings on a tp mesh: the lane-fused KV-head axis sharded, as POOL_SPEC
+RING_SPEC = jax.sharding.PartitionSpec(None, None, None, None, "tp")
+
+
 @dataclass
 class PagePool:
     k_pages: jnp.ndarray
     v_pages: jnp.ndarray
+    #: the window layers' rings (None for a model without window layers)
+    k_ring: Optional[jnp.ndarray] = None
+    v_ring: Optional[jnp.ndarray] = None
 
     @staticmethod
     def pool_shape(
@@ -100,18 +179,29 @@ class PagePool:
         head_dim: int,
         dtype: Any = jnp.bfloat16,
         mesh: Optional[Mesh] = None,
+        ring_shape: Optional[Tuple[int, ...]] = None,
     ) -> "PagePool":
+        """``num_layers`` counts the layers the paged pool serves;
+        ``ring_shape`` (:meth:`KVLayout.ring_shape`) adds the rings."""
+
+        def zeros(shape, spec):
+            if mesh is None:
+                return jnp.zeros(shape, dtype)
+            return jax.jit(
+                lambda: jnp.zeros(shape, dtype),
+                out_shardings=NamedSharding(mesh, spec),
+            )()
+
         shape = cls.pool_shape(
             num_layers, num_pages, page_size, num_kv_heads, head_dim
         )
-        if mesh is not None:
-            sharding = NamedSharding(mesh, POOL_SPEC)
-            zeros = jax.jit(
-                lambda: jnp.zeros(shape, dtype), out_shardings=sharding
-            )
-        else:
-            zeros = lambda: jnp.zeros(shape, dtype)  # noqa: E731
-        return cls(k_pages=zeros(), v_pages=zeros())
+        pool = cls(
+            k_pages=zeros(shape, POOL_SPEC), v_pages=zeros(shape, POOL_SPEC)
+        )
+        if ring_shape is not None and ring_shape[0]:
+            pool.k_ring = zeros(ring_shape, RING_SPEC)
+            pool.v_ring = zeros(ring_shape, RING_SPEC)
+        return pool
 
     @property
     def num_pages(self) -> int:
@@ -122,13 +212,29 @@ class PagePool:
         return self.k_pages.shape[2]
 
     def nbytes(self) -> int:
-        return self.k_pages.nbytes + self.v_pages.nbytes
+        return self.k_pages.nbytes + self.v_pages.nbytes + self.ring_nbytes()
 
-    def as_tuple(self) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        return self.k_pages, self.v_pages
+    def ring_nbytes(self) -> int:
+        if self.k_ring is None:
+            return 0
+        return self.k_ring.nbytes + self.v_ring.nbytes
 
-    def replace(self, kv: Tuple[jnp.ndarray, jnp.ndarray]) -> None:
-        self.k_pages, self.v_pages = kv
+    def as_tuple(self) -> Tuple[jnp.ndarray, ...]:
+        """The cache as the programs take it, and as sleep and wake move
+        it: (k, v) pages, and the (k, v) rings where the model has window
+        layers."""
+        if self.k_ring is None:
+            return self.k_pages, self.v_pages
+        return self.k_pages, self.v_pages, self.k_ring, self.v_ring
+
+    def replace(self, kv: Tuple[jnp.ndarray, ...]) -> None:
+        self.k_pages, self.v_pages = kv[:2]
+        if len(kv) > 2:
+            self.k_ring, self.v_ring = kv[2:]
+
+    def drop(self) -> None:
+        """Let go of every device array (a sleeping engine holds no HBM)."""
+        self.k_pages = self.v_pages = self.k_ring = self.v_ring = None
 
 
 class OutOfPages(Exception):

@@ -46,6 +46,7 @@ from prometheus_client import Counter, Gauge, Histogram
 
 from ..models import llama
 from ..models.moe import MoeConfig
+from ..models.smallthinker import SmallThinkerConfig
 from ..utils import faults, tracing
 from .engine import EngineConfig, InferenceEngine, resolve_attention_impl
 from .model_pool import HostModelPool
@@ -467,6 +468,8 @@ MODEL_CONFIGS = {
         rope_theta=10000.0,
         max_seq_len=2048,
     ),
+    "tiny-smallthinker": SmallThinkerConfig.tiny_smallthinker,
+    "smallthinker-21b-a3b": SmallThinkerConfig.smallthinker_21b_a3b,
 }
 
 
@@ -501,10 +504,12 @@ def make_arg_parser() -> argparse.ArgumentParser:
     )
     p.add_argument(
         "--prefix-caching",
-        default="on",
-        choices=["on", "off"],
+        default="auto",
+        choices=["auto", "on", "off"],
         help="automatic prefix caching: page-aligned KV reuse across "
-        "requests sharing a prompt prefix",
+        "requests sharing a prompt prefix (auto = on for every model "
+        "whose KV state the cache can hold, off for a model with "
+        "sliding-window layers, which an explicit on refuses by name)",
     )
     p.add_argument(
         "--decode-chunk",
@@ -1810,6 +1815,14 @@ class EngineService:
         args = self.args
         import jax  # deliberately not module-level: parse-time must not touch a backend
 
+        from .engine import refuse_window_layers
+
+        if self._zero_drain:
+            refuse_window_layers(model_cfg, "a zero-drain park (--zero-drain on)")
+        prefix_caching = args.prefix_caching == "on" or (
+            args.prefix_caching == "auto"
+            and not llama.window_layers(model_cfg)[0]
+        )
         return EngineConfig(
             model=model_cfg,
             max_batch=args.max_batch,
@@ -1827,7 +1840,7 @@ class EngineService:
                 getattr(args, "pipeline_decode", "off") == "on"
             ),
             drain_tail=getattr(args, "drain_tail", "auto"),
-            prefix_caching=args.prefix_caching == "on",
+            prefix_caching=prefix_caching,
             max_prefill_tokens=args.max_prefill_tokens,
             speculative_ngram=args.speculative_ngram,
             logprobs_topk=max(0, getattr(args, "logprobs_topk", 5)),
@@ -2699,6 +2712,12 @@ class EngineService:
             raise MigrationRejected(
                 "instance is sleeping; wake it before migrating"
             )
+        from .engine import WindowLayersUnsupported, refuse_window_layers
+
+        try:
+            refuse_window_layers(self.engine.cfg.model, "a live migration")
+        except WindowLayersUnsupported as e:
+            raise MigrationRejected(str(e)) from e
         if not self._zero_drain_parks():
             raise MigrationRejected(
                 "zero-drain parking unavailable (--zero-drain off, gang "
@@ -6081,6 +6100,10 @@ class EngineService:
         from ..utils import compile_cache
 
         out["compile_cache"] = compile_cache.stats()
+        # the two kinds of KV state and the expert layers, host-counted
+        # (engine.cache_stats): pages in use, ring bytes, positions that
+        # left a ring, tokens and assignments through the experts
+        out.update(self.engine.cache_stats())
         # what the scheduler thread spent its time on, part by part, since
         # the process started (docs/tracing.md "Scheduler phases")
         out["scheduler"] = tracing.phase_stats()

@@ -1798,8 +1798,7 @@ def attach_sleep(
     def set_state(state):
         if state is None:
             engine.params = None
-            engine.pool.k_pages = None
-            engine.pool.v_pages = None
+            engine.pool.drop()
             # Scheduler arrays (tokens/positions/budgets/key) are device
             # state too — a sleeping engine must hold zero HBM. Host mirrors
             # stay authoritative; the first post-wake chunk re-uploads them.

@@ -282,18 +282,51 @@ def _mlp(cfg, x, gate, up, down):
     return qmat((a.astype(x.dtype) * u), down)
 
 
-def _ffn(cfg: "LlamaConfig", lp, x):
-    """Dense SwiGLU or routed MoE, by config family (models/moe.py)."""
+def _ffn(cfg: "LlamaConfig", lp, x, router_logits=None, layer=None):
+    """Dense SwiGLU or an expert layer, by config family (models/moe.py:
+    every expert for every token, or the routed layer where the config
+    says ``routed_experts``). ``router_logits``: a family whose router
+    reads something else than ``x`` brings them; ``layer``: the expert
+    matrices in ``lp`` are whole stacks and this is the layer's index."""
     with jax.named_scope("ffn"):
         if getattr(cfg, "num_experts", 0) > 1:
-            from .moe import moe_ffn
+            from .moe import moe_ffn, routed_ffn
 
+            if cfg.routed_experts:
+                return routed_ffn(cfg, lp, x, router_logits, layer)
             return moe_ffn(cfg, lp, x)
         return _mlp(cfg, x, lp["w_gate"], lp["w_up"], lp["w_down"])
 
 
-def _project_qkv(cfg: LlamaConfig, lp, x, positions, cos_tab, sin_tab):
-    """x: [b, s, h] -> q [b,s,heads,hd], k/v [b,s,kvh,hd], roped."""
+def layer_pattern(cfg) -> "Tuple[Tuple[int, bool], ...] | None":
+    """A family whose layers differ gives the period of its pattern as
+    data: per layer, (attention window in tokens or 0 for the full causal
+    mask, RoPE or no positional encoding). None: every layer is the one
+    block of this file. (models/smallthinker.py is the patterned family.)"""
+    windows = getattr(cfg, "window_pattern", None)
+    if not windows:
+        return None
+    return tuple(zip(windows, cfg.rope_pattern))
+
+
+def window_layers(cfg) -> Tuple[int, int]:
+    """(number of sliding-window layers, their window in tokens): what the
+    engine sizes the rings of its KV state from (engine/kv_cache.py:
+    KVLayout). (0, 0) for a model whose every layer sees its whole context."""
+    pattern = layer_pattern(cfg)
+    if pattern is None:
+        return 0, 0
+    windows = [w for w, _ in pattern if w]
+    if not windows:
+        return 0, 0
+    return len(windows) * (cfg.num_layers // len(pattern)), max(windows)
+
+
+def _project_qkv(
+    cfg: LlamaConfig, lp, x, positions, cos_tab, sin_tab, rope: bool = True
+):
+    """x: [b, s, h] -> q [b,s,heads,hd], k/v [b,s,kvh,hd], roped (or,
+    for a layer without positional encoding, as projected)."""
     b, s, _ = x.shape
     q, k, v = qmat(x, lp["wq"]), qmat(x, lp["wk"]), qmat(x, lp["wv"])
     if cfg.attn_bias:
@@ -307,6 +340,8 @@ def _project_qkv(cfg: LlamaConfig, lp, x, positions, cos_tab, sin_tab):
         # per-head RMSNorm before RoPE (gemma-3 convention)
         q = rms_norm(q, lp["q_norm"], cfg.rms_eps, offset=cfg.norm_offset)
         k = rms_norm(k, lp["k_norm"], cfg.rms_eps, offset=cfg.norm_offset)
+    if not rope:
+        return q, k, v
     q = apply_rope(q, positions, cos_tab, sin_tab)
     k = apply_rope(k, positions, cos_tab, sin_tab)
     return q, k, v
@@ -389,6 +424,12 @@ def prefill(
     Returns (logits [b, s, vocab], new_cache). The caller reads logits at
     seq_lens-1 to sample the first generated token.
     """
+    if layer_pattern(cfg) is not None:
+        from . import smallthinker
+
+        return smallthinker.prefill(
+            params, cfg, tokens, seq_lens, cache, page_table, mesh=mesh
+        )
     b, s = tokens.shape
     page_size = cache[0].shape[2]
     cos_tab, sin_tab = rope_table(
@@ -444,6 +485,12 @@ def prefill_continue(
     """
     from ..ops.attention import paged_suffix_attention
 
+    if layer_pattern(cfg) is not None:
+        from . import smallthinker
+
+        return smallthinker.prefill_continue(
+            params, cfg, tokens, start, suffix_lens, cache, page_table
+        )
     b, s = tokens.shape
     page_size = cache[0].shape[2]
     cos_tab, sin_tab = rope_table(
@@ -512,6 +559,11 @@ def mixed_step(
     that sample (each segment's last token / each decode row). Padding
     rows write nothing and produce garbage logits.
     """
+    if layer_pattern(cfg) is not None:
+        raise NotImplementedError(
+            f"{type(cfg).__name__}: the packed mixed_step path has no ring "
+            "for sliding-window layers; serve this model on the bucketed path"
+        )
     (T,) = tokens.shape
     page_size = cache[0].shape[2]
     cos_tab, sin_tab = rope_table(
@@ -580,6 +632,13 @@ def decode_step(
     K/V writes drop (scatter to the out-of-bounds page) so replayed steps
     can't corrupt the cache; their logits are garbage the caller ignores.
     """
+    if layer_pattern(cfg) is not None:
+        from . import smallthinker
+
+        return smallthinker.decode_step(
+            params, cfg, tokens, positions, cache, page_table, active,
+            mesh=mesh,
+        )
     if cfg.attention_impl == "reference":
         return _decode_step_scatter_first(
             params, cfg, tokens, positions, cache, page_table, active

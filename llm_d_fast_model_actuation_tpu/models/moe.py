@@ -7,15 +7,21 @@ TPU/SPMD design:
   * expert weights are stacked ``[layers, experts, ...]`` and the experts
     axis carries the ``expert -> ep`` logical sharding rule
     (parallel/mesh.py LOGICAL_RULES): each ep shard holds E/ep experts;
-  * dispatch is DENSE-compute, sparse-weight: every expert runs on every
-    token and the router's (renormalized) top-k probabilities weight the
-    sum. Under ep sharding each device computes only its local experts and
+  * two expert layers. :func:`moe_ffn` is DENSE-compute, sparse-weight:
+    every expert runs on every token and the router's (renormalized)
+    top-k probabilities weight the sum. Under ep sharding each device computes only its local experts and
     the weighted sum's contraction over E becomes one psum over ep — no
     scatter/gather, no capacity factors, no dynamic shapes, which is
     exactly what XLA wants. The FLOPs cost vs token-dropping dispatch is
     E/k per device group, paid deliberately for static shapes (the
-    standard small-scale JAX MoE trade; swap in a ragged Pallas dispatch
-    when expert counts grow past the arithmetic-intensity break-even).
+    standard small-scale JAX MoE trade). :func:`routed_ffn` is the routed,
+    dropless one for families whose E/k makes that cost the layer's
+    (64/6 for SmallThinker): every (token, expert) assignment is sorted by
+    expert and the three matmuls are grouped ones (``jax.lax.ragged_dot``,
+    which the TPU compiler lowers to one kernel that computes each row
+    against its own expert only) — static shapes, no capacity factor,
+    nothing dropped however uneven the routing. A config picks its layer
+    by what it is (``routed_experts``), not by an engine flag.
 
 Reference parity: the reference serves MoE through vLLM's Mixtral support
 (SURVEY §2.9 model families); this is the TPU-native equivalent.
@@ -36,6 +42,11 @@ from . import llama
 class MoeConfig(llama.LlamaConfig):
     num_experts: int = 8
     experts_per_token: int = 2
+    #: the expert layer: False = :func:`moe_ffn` (every expert for every
+    #: token), True = :func:`routed_ffn` (each token's top-k only)
+    routed_experts: bool = False
+    #: the experts' gate activation in :func:`routed_ffn`: "silu" or "relu"
+    expert_activation: str = "silu"
 
     @classmethod
     def mixtral_8x7b(cls) -> "MoeConfig":
@@ -184,3 +195,95 @@ def moe_ffn(cfg: MoeConfig, lp: Dict[str, Any], x: jnp.ndarray) -> jnp.ndarray:
     # contraction over E: with experts ep-sharded this is the one psum
     out = jnp.einsum("...eh,...e->...h", y.astype(jnp.float32), weights)
     return out.astype(x.dtype)
+
+
+def route(cfg: MoeConfig, logits: jnp.ndarray):
+    """Router logits [..., E] (any float dtype) -> (top-k probabilities
+    [..., k] float32, renormalized by a softmax over the k kept logits,
+    and their expert indices [..., k])."""
+    top_vals, top_idx = jax.lax.top_k(
+        logits.astype(jnp.float32), cfg.experts_per_token
+    )
+    return jax.nn.softmax(top_vals, axis=-1), top_idx
+
+
+def _gate_act(cfg, g: jnp.ndarray) -> jnp.ndarray:
+    """The experts' gate activation, float32 math: SiLU (Mixtral) or ReLU
+    (SmallThinker's sparse ReGLU)."""
+    gf = g.astype(jnp.float32)
+    if cfg.expert_activation == "relu":
+        return jax.nn.relu(gf)
+    if cfg.expert_activation == "silu":
+        return jax.nn.silu(gf)
+    raise ValueError(f"unknown expert activation {cfg.expert_activation!r}")
+
+
+def _grouped(x: jnp.ndarray, w: Any, sizes: jnp.ndarray, layer):
+    """Rows of ``x``, sorted by expert, each against its own expert's
+    matrix: ``w`` is [E, in, out], ``sizes`` [E] the rows per expert. With
+    ``layer`` (an int32 scalar) ``w`` is the WHOLE stack [L, E, in, out]:
+    it goes to the grouped matmul as L * E groups of which only the
+    layer's own are given rows, so the kernel reads the layer's experts
+    where they are stored and no layer of the stack is sliced out first (a
+    copy of every expert of the layer, each step, on the chip)."""
+    from .quant import is_quantized
+
+    if is_quantized(w):
+        raise ValueError("the routed expert layer takes no quantized stack")
+    if layer is not None:
+        L, E = w.shape[:2]
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((L * E,), sizes.dtype), sizes, (layer * E,)
+        )
+        w = w.reshape((L * E,) + w.shape[2:])
+    return jax.lax.ragged_dot(x, w, sizes)
+
+
+def routed_ffn(
+    cfg: MoeConfig,
+    lp: Dict[str, Any],
+    x: jnp.ndarray,
+    router_logits: "jnp.ndarray | None" = None,
+    layer: "jnp.ndarray | None" = None,
+) -> jnp.ndarray:
+    """Top-k routed expert FFN that computes only the routed experts.
+
+    x: [..., hidden]. Each of a token's k assignments becomes one row; the
+    rows are sorted by expert (a stable argsort of N*k small integers), the
+    gate, up and down projections are grouped matmuls over the sorted rows,
+    and the k results of a token are gathered back and summed with the
+    router's probabilities in float32. Dropless: a row is never discarded,
+    whether an expert gets every token or none. ``router_logits`` [..., E]
+    are given by a family whose router reads something else than ``x``
+    (SmallThinker: the layer's input, before attention). ``layer``: the
+    expert matrices in ``lp`` are whole stacks [L, E, ...] and this is the
+    layer to compute (:func:`_grouped`).
+    """
+    k, E = cfg.experts_per_token, cfg.num_experts
+    lead, h = x.shape[:-1], x.shape[-1]
+    with jax.named_scope("router"):
+        if router_logits is None:
+            router_logits = x @ lp["router"]
+        probs, idx = route(cfg, router_logits)  # [..., k]
+    with jax.named_scope("experts"):
+        xf = x.reshape(-1, h)  # [N, h]
+        n = xf.shape[0]
+        expert = idx.reshape(n * k)
+        order = jnp.argsort(expert, stable=True)  # rows sorted by expert
+        sizes = jnp.sum(
+            jax.nn.one_hot(expert, E, dtype=jnp.int32), axis=0
+        )  # [E] rows per expert
+        rows = xf[order // k]  # [N*k, h]: row r is token order[r] // k
+        g = _grouped(rows, lp["w_gate"], sizes, layer)
+        u = _grouped(rows, lp["w_up"], sizes, layer)
+        act = _gate_act(cfg, g).astype(x.dtype) * u
+        y = _grouped(act, lp["w_down"], sizes, layer)  # [N*k, h]
+        # back to token order: a gather by the inverse permutation
+        inverse = jnp.zeros_like(order).at[order].set(
+            jnp.arange(n * k, dtype=order.dtype)
+        )
+        y = y[inverse].reshape(n, k, h)
+        out = jnp.einsum(
+            "nkh,nk->nh", y.astype(jnp.float32), probs.reshape(n, k)
+        )
+    return out.astype(x.dtype).reshape(*lead, h)

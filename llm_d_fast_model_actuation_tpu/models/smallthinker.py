@@ -1,0 +1,446 @@
+"""SmallThinker (``SmallThinkerForCausalLM``, PowerInfer) — the patterned
+family: the layers of a model are not one block repeated but a PERIOD of
+blocks repeated, and the period is data.
+
+What differs from the Llama trunk (models/llama.py), per layer:
+
+  * the layer pattern, ``window_pattern`` / ``rope_pattern``, one entry per
+    layer of a period (published: 4 layers, the first with the full causal
+    mask and NO positional encoding, the other three with a 4096-token
+    sliding window and rotate-half RoPE);
+  * two kinds of KV state (engine/kv_cache.py:KVLayout). Full-attention
+    layers write the paged pool ``[global layers, pages, page, kvh*hd]``
+    through the sequence's page table; window layers write a RING per
+    sequence, ``[window layers, slots, ring pages, page, kvh*hd]``,
+    position p at slot ``p % ring_len``, addressed through the ring columns
+    the engine appends to every page-table row — so every attention op
+    reads either kind through one interface, a table row and a layer index;
+  * the router reads the layer's INPUT (the residual stream before the
+    attention norm), and its top-k softmax weights the routed experts after
+    attention (models/moe.py:routed_ffn; ReLU gate, no shared expert).
+
+The forward scans over PERIODS; the body is the period's layers, unrolled
+statically, each with its own static window and RoPE flag: compile time
+stays O(1) in depth and no ``lax.cond`` picks a layer's kind at run time.
+The entry points keep the trunk's signatures, and ``llama.prefill`` /
+``prefill_continue`` / ``decode_step`` hand a patterned config here, so
+engine/engine.py's ``ProgramSet`` never branches on the family.
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass
+from typing import Any, Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ..ops.attention import (
+    causal_prefill_attention,
+    paged_decode_attention_inline,
+    paged_suffix_attention,
+)
+from ..ops.rope import rope_table
+from . import llama, moe
+from .quant import qmat
+
+#: pages a step of the decode kernel's walk reads together: this family's
+#: contexts run to 13k tokens (832 pages of 16), where a page a step is
+#: bound by DMA latency (ops/pallas/decode.py). The families of
+#: models/llama.py keep the page-a-step walk their cells were measured with.
+DECODE_BLOCK_PAGES = 8
+
+#: query rows the XLA suffix attention scores at a time: a 1,024-row
+#: segment against a 16k-token table row would otherwise hold 1.9 GB of
+#: float32 scores
+SUFFIX_Q_BLOCK = 128
+
+
+@dataclass(frozen=True)
+class SmallThinkerConfig(moe.MoeConfig):
+    routed_experts: bool = True
+    #: the experts' gate activation (sparse ReGLU)
+    expert_activation: str = "relu"
+    #: per layer of one period: attention window in tokens, 0 = full causal
+    window_pattern: Tuple[int, ...] = (0, 4096, 4096, 4096)
+    #: per layer of one period: rotate-half RoPE, or no positional encoding
+    rope_pattern: Tuple[bool, ...] = (False, True, True, True)
+
+    def __post_init__(self) -> None:
+        period = len(self.window_pattern)
+        if len(self.rope_pattern) != period:
+            raise ValueError("window_pattern and rope_pattern differ in length")
+        if self.num_layers % period:
+            raise ValueError(
+                f"{self.num_layers} layers are not whole periods of {period}"
+            )
+        if len({w for w in self.window_pattern if w}) > 1:
+            raise ValueError("window layers of one model share one window")
+        if self.quantization:
+            raise ValueError(
+                "SmallThinkerConfig: weight quantization is not carried by "
+                "the patterned forward"
+            )
+
+    @classmethod
+    def smallthinker_21b_a3b(cls) -> "SmallThinkerConfig":
+        """SmallThinker-21BA3B-Instruct as published."""
+        return cls(
+            vocab_size=151936,
+            hidden_size=2560,
+            num_layers=52,
+            num_heads=28,
+            num_kv_heads=4,
+            head_dim=128,
+            intermediate_size=768,
+            rope_theta=1.5e6,
+            rms_eps=1e-6,
+            max_seq_len=16384,
+            num_experts=64,
+            experts_per_token=6,
+        )
+
+    @classmethod
+    def tiny_smallthinker(cls, vocab: int = 256) -> "SmallThinkerConfig":
+        """CPU test size: two periods, a window shorter than the contexts."""
+        return cls(
+            vocab_size=vocab,
+            hidden_size=64,
+            num_layers=8,
+            num_heads=4,
+            num_kv_heads=2,
+            head_dim=16,
+            intermediate_size=32,
+            rope_theta=10000.0,
+            rms_eps=1e-6,
+            max_seq_len=128,
+            num_experts=8,
+            experts_per_token=3,
+            window_pattern=(0, 24, 24, 24),
+        )
+
+
+#: the stacked layer parameters are the expert family's (models/moe.py):
+#: same names, shapes and logical axes, so init, sharding rules and the
+#: registry need nothing of their own
+init_params = moe.init_params
+param_logical_axes = moe.param_logical_axes
+
+
+# -- the period ---------------------------------------------------------------
+
+
+def _plan(cfg: SmallThinkerConfig):
+    """Static facts of one period: per layer (window, rope, index among the
+    period's layers of its kind), and how many of each kind a period has."""
+    layers, n_global, n_window = [], 0, 0
+    for window, rope in llama.layer_pattern(cfg):
+        if window:
+            layers.append((window, rope, n_window))
+            n_window += 1
+        else:
+            layers.append((0, rope, n_global))
+            n_global += 1
+    return tuple(layers), n_global, n_window
+
+
+def _split_cache(cache, page_table):
+    """(global k, v pools, ring k, v pools as [layers, slots * ring pages,
+    page, fused], the table's global columns, its ring columns)."""
+    kp, vp, kr, vr = cache
+    ring_pages = kr.shape[2]
+    flat = (kr.shape[0], kr.shape[1] * ring_pages) + kr.shape[3:]
+    split = page_table.shape[1] - ring_pages
+    return (
+        kp, vp, kr.reshape(flat), vr.reshape(flat),
+        page_table[:, :split], page_table[:, split:],
+    )
+
+
+def _join_cache(cache, kp, vp, kr, vr):
+    """The cache tuple again, the rings back in their stored shape."""
+    return kp, vp, kr.reshape(cache[2].shape), vr.reshape(cache[3].shape)
+
+
+#: the expert matrices: never sliced by layer, the grouped matmul takes the
+#: whole stack and the layer's index (models/moe.py:_grouped)
+_EXPERT_STACKS = ("w_gate", "w_up", "w_down")
+
+
+def _periods(cfg):
+    """The period indices, the scan's xs. The body indexes the stacked
+    parameters by layer itself: a layer's small matrices as slices that
+    fuse into the matmuls that read them, the expert stacks whole."""
+    return jnp.arange(
+        cfg.num_layers // len(cfg.window_pattern), dtype=jnp.int32
+    )
+
+
+def _layer_params(cfg, params, pi, j):
+    """(layer index, the parameters of layer j of period pi)."""
+    li = pi * len(cfg.window_pattern) + j
+    return li, {
+        name: a if name in _EXPERT_STACKS else a[li]
+        for name, a in params["layers"].items()
+    }
+
+
+def _router_logits(x, lp):
+    """The layer's router, read from its input: float32 out of the MXU's
+    accumulator, so that near-ties among 64 logits are not rounded twice."""
+    with jax.named_scope("router"):
+        return jnp.einsum(
+            "...h,he->...e", x, lp["router"],
+            preferred_element_type=jnp.float32,
+        )
+
+
+def _logits(cfg, params, x):
+    x = llama._norm(cfg, x, params["final_norm"])
+    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+    return qmat(x, head).astype(jnp.float32)
+
+
+def _segment(params, cfg, tokens, positions, valid, cache, page_table, attend):
+    """The forward of one prefill segment [b, s] shared by the cold and the
+    continued program: per layer, project, write K and V into the layer's
+    own kind of cache, ``attend(q, k, v, pools, table, layer, window)``, the
+    routed experts."""
+    b, s = tokens.shape
+    layers, n_global, n_window = _plan(cfg)
+    kp, vp, kr, vr, gtable, rtable = _split_cache(cache, page_table)
+    page_size = kp.shape[2]
+    ring_len = rtable.shape[1] * page_size
+    ring_pos = positions % ring_len
+    cos_tab, sin_tab = rope_table(
+        cfg.max_seq_len, cfg.head_dim, cfg.rope_theta, cfg.rope_scaling
+    )
+    x = llama._embed_tokens(cfg, params, tokens)
+
+    def period(carry, pi):
+        x, kp, vp, kr, vr = carry
+        for j, (window, rope, nth) in enumerate(layers):
+            layer, lp = _layer_params(cfg, params, pi, j)
+            router = _router_logits(x, lp)
+            h = llama._norm(cfg, x, lp["attn_norm"])
+            scope = "attn.window" if window else "attn.global"
+            with jax.named_scope(scope):
+                q, k, v = llama._project_qkv(
+                    cfg, lp, h, positions, cos_tab, sin_tab, rope=rope
+                )
+            with jax.named_scope("kv_write"):
+                if window:
+                    li = pi * n_window + nth
+                    kr = llama._scatter_prefill(
+                        kr, li, k, rtable, ring_pos, valid, page_size)
+                    vr = llama._scatter_prefill(
+                        vr, li, v, rtable, ring_pos, valid, page_size)
+                    pools, table = (kr, vr), rtable
+                else:
+                    li = pi * n_global + nth
+                    kp = llama._scatter_prefill(
+                        kp, li, k, gtable, positions, valid, page_size)
+                    vp = llama._scatter_prefill(
+                        vp, li, v, gtable, positions, valid, page_size)
+                    pools, table = (kp, vp), gtable
+            with jax.named_scope(scope):
+                attn = attend(q, k, v, pools, table, li, window)
+                x = x + qmat(attn.reshape(b, s, cfg.q_dim), lp["wo"])
+            h = llama._norm(cfg, x, lp["mlp_norm"])
+            x = x + llama._ffn(cfg, lp, h, router_logits=router, layer=layer)
+        return (x, kp, vp, kr, vr), None
+
+    (x, kp, vp, kr, vr), _ = jax.lax.scan(
+        period, (x, kp, vp, kr, vr), _periods(cfg)
+    )
+    return _logits(cfg, params, x), _join_cache(cache, kp, vp, kr, vr)
+
+
+def prefill(params, cfg, tokens, seq_lens, cache, page_table, mesh=None):
+    """``llama.prefill`` for a patterned model: a cold segment attends over
+    its own K and V (the flash kernel, with the layer's window), and writes
+    both kinds of cache for what follows."""
+    b, s = tokens.shape
+    positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
+    valid = positions < seq_lens[:, None]
+
+    def attend(q, k, v, pools, table, layer, window):
+        return causal_prefill_attention(
+            q, k, v, seq_lens, impl=cfg.attention_impl, mesh=mesh,
+            window=window,
+        )
+
+    return _segment(
+        params, cfg, tokens, positions, valid, cache, page_table, attend
+    )
+
+
+def prefill_continue(
+    params, cfg, tokens, start, suffix_lens, cache, page_table
+):
+    """``llama.prefill_continue`` for a patterned model: a later segment of
+    a chunked prefill. Full-attention layers attend over the sequence's
+    pages, window layers over its ring, into which the segment has just
+    been written (a ring holds window + one segment, so nothing a query of
+    the segment still sees has been overwritten)."""
+    b, s = tokens.shape
+    offs = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
+    positions = start[:, None] + offs
+    valid = offs < suffix_lens[:, None]
+
+    def attend(q, k, v, pools, table, layer, window):
+        return paged_suffix_attention(
+            q, pools[0], pools[1], table, start, layer, window=window,
+            q_block=SUFFIX_Q_BLOCK,
+        )
+
+    return _segment(
+        params, cfg, tokens, positions, valid, cache, page_table, attend
+    )
+
+
+def decode_step(
+    params, cfg, tokens, positions, cache, page_table, active=None, mesh=None
+):
+    """``llama.decode_step`` for a patterned model, always by the deferred
+    write: attention reads each layer's own cache for positions before the
+    token's and takes the token's K and V inline; after the scan, ONE
+    scatter per kind of cache and direction writes every layer's new row
+    (pages of the full-attention layers, ring slot ``position % ring_len``
+    of the window layers). Window layers read only the pages of the ring
+    that hold a visible key (ops/pallas/decode.py)."""
+    b = tokens.shape[0]
+    layers, n_global, n_window = _plan(cfg)
+    kp, vp, kr, vr, gtable, rtable = _split_cache(cache, page_table)
+    page_size = kp.shape[2]
+    ring_len = rtable.shape[1] * page_size
+    cos_tab, sin_tab = rope_table(
+        cfg.max_seq_len, cfg.head_dim, cfg.rope_theta, cfg.rope_scaling
+    )
+    x = llama._embed_tokens(cfg, params, tokens)  # [b, h]
+
+    def period(x, pi):
+        new_k, new_v = [], []
+        for j, (window, rope, nth) in enumerate(layers):
+            layer, lp = _layer_params(cfg, params, pi, j)
+            router = _router_logits(x, lp)
+            h = llama._norm(cfg, x, lp["attn_norm"])
+            with jax.named_scope("attn.window" if window else "attn.global"):
+                q, k, v = llama._project_qkv(
+                    cfg, lp, h[:, None, :], positions[:, None], cos_tab,
+                    sin_tab, rope=rope,
+                )
+                q, k, v = q[:, 0], k[:, 0], v[:, 0]  # [b, heads/kvh, hd]
+                if window:
+                    attn = paged_decode_attention_inline(
+                        q, kr, vr, k, v, rtable, positions,
+                        pi * n_window + nth, impl=cfg.attention_impl,
+                        mesh=mesh, window=window,
+                        block_pages=DECODE_BLOCK_PAGES,
+                    )
+                else:
+                    attn = paged_decode_attention_inline(
+                        q, kp, vp, k, v, gtable, positions,
+                        pi * n_global + nth, impl=cfg.attention_impl,
+                        mesh=mesh, block_pages=DECODE_BLOCK_PAGES,
+                    )
+                x = x + qmat(attn.reshape(b, cfg.q_dim), lp["wo"])
+            h = llama._norm(cfg, x, lp["mlp_norm"])
+            x = x + llama._ffn(cfg, lp, h, router_logits=router, layer=layer)
+            new_k.append(k)
+            new_v.append(v)
+        return x, (jnp.stack(new_k), jnp.stack(new_v))
+
+    # k_all, v_all: [periods, layers of a period, b, kvh, hd]
+    x, (k_all, v_all) = jax.lax.scan(period, x, _periods(cfg))
+
+    def write(pool, new, table, pos, js):
+        """One scatter: the rows ``new[:, js]`` of every layer of one kind
+        at ``pos`` of ``table``; inactive rows go to the out-of-bounds page
+        and are dropped."""
+        rows = new[:, jnp.asarray(js)]  # [periods, kind's layers, b, ...]
+        L = rows.shape[0] * rows.shape[1]
+        phys = jnp.take_along_axis(
+            table, (pos // page_size)[:, None], axis=1
+        )[:, 0]
+        if active is not None:
+            phys = jnp.where(active, phys, pool.shape[1])
+        li = jnp.broadcast_to(jnp.arange(L)[:, None], (L, b)).reshape(-1)
+        pi = jnp.broadcast_to(phys[None, :], (L, b)).reshape(-1)
+        si = jnp.broadcast_to((pos % page_size)[None, :], (L, b)).reshape(-1)
+        return pool.at[li, pi, si].set(
+            rows.reshape(L * b, cfg.kv_dim), mode="drop"
+        )
+
+    with jax.named_scope("kv_write"):
+        global_js = [j for j, (w, _, _) in enumerate(layers) if not w]
+        window_js = [j for j, (w, _, _) in enumerate(layers) if w]
+        if global_js:
+            kp = write(kp, k_all, gtable, positions, global_js)
+            vp = write(vp, v_all, gtable, positions, global_js)
+        if window_js:
+            ring_pos = positions % ring_len
+            kr = write(kr, k_all, rtable, ring_pos, window_js)
+            vr = write(vr, v_all, rtable, ring_pos, window_js)
+    return _logits(cfg, params, x), _join_cache(cache, kp, vp, kr, vr)
+
+
+def reference_logits(
+    params: Dict[str, Any], cfg: SmallThinkerConfig, tokens: jnp.ndarray
+) -> jnp.ndarray:
+    """The repo's plain reference of this family: float32, no cache, no
+    kernels, the expert sum computed densely over all experts and weighted
+    by the top-k softmax, masks written out. tokens [s] -> logits [s, vocab].
+    What the cached, routed, kernel-backed path above is tested against."""
+    f32 = jnp.float32
+    s = tokens.shape[0]
+    pos = jnp.arange(s)
+    heads, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    cos_tab, sin_tab = rope_table(cfg.max_seq_len, hd, cfg.rope_theta)
+    pattern = llama.layer_pattern(cfg)
+
+    def rms(x, w):
+        var = jnp.mean(x * x, axis=-1, keepdims=True)
+        return x / jnp.sqrt(var + cfg.rms_eps) * w.astype(f32)
+
+    def rope(x):  # [s, n, hd]
+        half = hd // 2
+        cos, sin = cos_tab[pos][:, None, :], sin_tab[pos][:, None, :]
+        x1, x2 = x[..., :half], x[..., half:]
+        return jnp.concatenate(
+            [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
+        )
+
+    x = params["embed"][tokens].astype(f32)
+    with jax.default_matmul_precision("highest"):
+        for l in range(cfg.num_layers):
+            window, roped = pattern[l % len(pattern)]
+            lp = jax.tree.map(lambda a: a[l].astype(f32), params["layers"])
+            probs, idx = moe.route(cfg, x @ lp["router"])
+            weights = jnp.sum(
+                jax.nn.one_hot(idx, cfg.num_experts, dtype=f32)
+                * probs[..., None], axis=-2,
+            )  # [s, E], zero off the top k
+            h = rms(x, lp["attn_norm"])
+            q = (h @ lp["wq"]).reshape(s, heads, hd)
+            k = (h @ lp["wk"]).reshape(s, kvh, hd)
+            v = (h @ lp["wv"]).reshape(s, kvh, hd)
+            if roped:
+                q, k = rope(q), rope(k)
+            mask = pos[None, :] <= pos[:, None]
+            if window:
+                mask = mask & (pos[None, :] > pos[:, None] - window)
+            qg = q.reshape(s, kvh, heads // kvh, hd) * hd**-0.5
+            scores = jnp.einsum("skgd,tkd->kgst", qg, k)
+            scores = jnp.where(mask[None, None], scores, -jnp.inf)
+            attn = jnp.einsum(
+                "kgst,tkd->skgd", jax.nn.softmax(scores, axis=-1), v
+            )
+            x = x + attn.reshape(s, heads * hd) @ lp["wo"]
+            h = rms(x, lp["mlp_norm"])
+            g = jnp.einsum("sh,ehf->sef", h, lp["w_gate"])
+            u = jnp.einsum("sh,ehf->sef", h, lp["w_up"])
+            y = jnp.einsum("sef,efh->seh", jax.nn.relu(g) * u, lp["w_down"])
+            x = x + jnp.einsum("seh,se->sh", y, weights)
+        x = rms(x, params["final_norm"])
+        return x @ params["lm_head"].astype(f32)

@@ -21,6 +21,14 @@ The XLA paths split the fused axis of what they GATHERED (context-sized)
 into [kv_heads, head_dim]; kv_heads comes from the fused width over q's
 head_dim.
 
+Sliding-window layers (``window`` > 0 on an op): query i sees keys j with
+i - window < j <= i, its own position among them. Their table rows are
+RINGS: position p lives in logical page ``(p // page_size) % pages_per_seq``
+of the row, so a row holds the last ``pages_per_seq * page_size`` positions
+and a row as long as the context is the ring that never wraps
+(engine/kv_cache.py:KVLayout sizes them). ``window`` = 0 is the full causal
+mask over a plain row, and traces exactly what it traced before windows.
+
 All softmax math is fp32 regardless of the io dtype.
 """
 
@@ -89,6 +97,15 @@ def _gather_context(pages, layer, page_table, head_dim):
         return g.reshape(n, pps * ps, fused // head_dim, head_dim)
 
 
+def _ring_positions(ring_len: int, last: jnp.ndarray) -> jnp.ndarray:
+    """The position each slot of a ring holds once positions 0..``last``
+    ([n] int32) have been written in order: slot r holds the largest
+    p <= last with p % ring_len == r (negative: never written).
+    -> [n, ring_len]."""
+    r = jnp.arange(ring_len, dtype=jnp.int32)[None, :]
+    return last[:, None] - jnp.mod(last[:, None] - r, ring_len)
+
+
 def _repeat_kv(x: jnp.ndarray, n_rep: int, axis: int) -> jnp.ndarray:
     """GQA: repeat kv heads to match query heads."""
     if n_rep == 1:
@@ -103,6 +120,7 @@ def causal_prefill_attention(
     seq_lens: jnp.ndarray,  # [batch] int32: valid prefix length per row
     impl: "str | None" = None,  # None -> module default
     mesh=None,  # tp mesh: the pallas impl runs under shard_map
+    window: int = 0,  # > 0: sliding window (see the module docstring)
 ) -> jnp.ndarray:
     """Causal self-attention over a (right-padded) prefill batch."""
     if (impl or _IMPL) == "pallas":
@@ -123,6 +141,7 @@ def causal_prefill_attention(
         kernel = functools.partial(
             causal_prefill_attention_pallas,
             block_q=block_q, interpret=_pallas_interpret(),
+            **({"window": window} if window else {}),
         )
         out = shard_over_tp(
             mesh, kernel, (_HEADS4, _HEADS4, _HEADS4, P(None)), _HEADS4
@@ -138,6 +157,8 @@ def causal_prefill_attention(
 
     pos = jnp.arange(s)
     causal = pos[None, :, None] >= pos[None, None, :]  # [1, q, k]
+    if window:
+        causal = causal & (pos[None, None, :] > pos[None, :, None] - window)
     valid = pos[None, None, :] < seq_lens[:, None, None]  # [b, 1, k]
     mask = (causal & valid)[:, None, :, :]  # [b, 1, q, k]
     logits = jnp.where(mask, logits, NEG_INF)
@@ -160,6 +181,8 @@ def paged_decode_attention_inline(
     layer: jnp.ndarray,  # int32 scalar — the pool layer to read
     impl: "str | None" = None,
     mesh=None,  # tp mesh: the pallas impl runs under shard_map
+    window: int = 0,  # > 0: sliding window; table rows are rings
+    block_pages: int = 1,  # pallas: pages a step of the walk reads together
 ) -> jnp.ndarray:
     """Decode attention where the new token's K/V are passed *inline* instead
     of having been scattered into the cache first.
@@ -182,6 +205,8 @@ def paged_decode_attention_inline(
         kernel = functools.partial(
             paged_decode_attention_inline_pallas,
             interpret=_pallas_interpret(),
+            **({"window": window} if window else {}),
+            **({"block_pages": block_pages} if block_pages > 1 else {}),
         )
         return shard_over_tp(
             mesh, kernel,
@@ -198,7 +223,12 @@ def paged_decode_attention_inline(
     logits = jnp.einsum(
         "bngd,bknd->bngk", qg, k, preferred_element_type=jnp.float32
     )
-    valid = jnp.arange(ctx)[None, :] < positions[:, None]  # strictly past
+    if window:
+        # the row is a ring of ctx slots holding positions <= position - 1
+        kpos = _ring_positions(ctx, positions - 1)
+        valid = (kpos >= 0) & (kpos > positions[:, None] - window)
+    else:
+        valid = jnp.arange(ctx)[None, :] < positions[:, None]  # strictly past
     logits = jnp.where(valid[:, None, None, :], logits, NEG_INF)
     self_logit = jnp.einsum(
         "bngd,bnd->bng", qg, k_new.astype(qg.dtype),
@@ -355,6 +385,8 @@ def paged_suffix_attention(
     page_table: jnp.ndarray,  # [batch, pages_per_seq] int32
     start: jnp.ndarray,  # [batch] int32 — absolute position of query 0
     layer: jnp.ndarray,  # int32 scalar — the pool layer to read
+    window: int = 0,  # > 0: sliding window; table rows are rings
+    q_block: int = 0,  # > 0: score this many query rows at a time
 ) -> jnp.ndarray:
     """Causal attention for a prompt SUFFIX over the paged cache.
 
@@ -375,15 +407,41 @@ def paged_suffix_attention(
     qg = (q.astype(jnp.float32) * (d**-0.5)).astype(q.dtype).reshape(
         b, s, kvh, g, d
     )
-    logits = jnp.einsum(
-        "bsngd,bknd->bsngk", qg, k, preferred_element_type=jnp.float32
-    )
     qpos = start[:, None] + jnp.arange(s)[None, :]  # [b, s] absolute
-    mask = jnp.arange(ctx)[None, None, :] <= qpos[:, :, None]  # [b, s, ctx]
-    logits = jnp.where(mask[:, :, None, None, :], logits, NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum(
-        "bsngk,bknd->bsngd", probs.astype(v.dtype), v,
-        preferred_element_type=jnp.float32,
-    )
+    if window:
+        # the row is a ring of ctx slots into which the segment (all s
+        # positions of its bucket: a slot of a padded position reads as
+        # that position, later than every real query) has been written
+        kpos = _ring_positions(ctx, start + s - 1)[:, None, :]  # [b, 1, ctx]
+    else:
+        kpos = jnp.arange(ctx)[None, None, :]
+
+    def attend(qg, qpos):  # [b, n, kvh, g, d], [b, n] -> [b, n, kvh, g, d]
+        logits = jnp.einsum(
+            "bsngd,bknd->bsngk", qg, k, preferred_element_type=jnp.float32
+        )
+        mask = kpos <= qpos[:, :, None]  # [b, n, ctx]
+        if window:
+            mask = mask & (kpos >= 0) & (kpos > qpos[:, :, None] - window)
+        logits = jnp.where(mask[:, :, None, None, :], logits, NEG_INF)
+        probs = jax.nn.softmax(logits, axis=-1)
+        return jnp.einsum(
+            "bsngk,bknd->bsngd", probs.astype(v.dtype), v,
+            preferred_element_type=jnp.float32,
+        )
+
+    if q_block and s > q_block and s % q_block == 0:
+        # query rows in blocks, one after another: the [rows, ctx] scores
+        # of a long context exist for one block at a time
+        nb = s // q_block
+        out = jax.lax.map(
+            lambda blk: attend(*blk),
+            (
+                qg.reshape(b, nb, q_block, kvh, g, d).swapaxes(0, 1),
+                qpos.reshape(b, nb, q_block).swapaxes(0, 1),
+            ),
+        )  # [nb, b, q_block, kvh, g, d]
+        out = out.swapaxes(0, 1)
+    else:
+        out = attend(qg, qpos)
     return out.reshape(b, s, h, d).astype(q.dtype)

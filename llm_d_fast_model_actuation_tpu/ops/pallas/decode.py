@@ -89,35 +89,82 @@ def _decode_kernel(
     num_kv_heads: int,
     head_dim: int,
     inline: bool,
+    window: int = 0,
+    block_pages: int = 1,
 ):
     """Online-softmax over the sequence's pages. With ``inline`` the new
     token's K/V arrive as two extra inputs ([1, 1, kv_heads * head_dim]
     VMEM, not yet in the cache — the engine defers cache scatters; see
     ops/attention.py:paged_decode_attention_inline) and are folded into the
-    running (m, l, acc) state after the page walk."""
+    running (m, l, acc) state after the page walk.
+
+    With ``window`` (a layer of sliding-window attention) the query sees
+    only the last ``window`` positions, its own among them: the walk STARTS
+    at the first page that holds a visible key, and a row of the page table
+    is read as a ring, logical page p at column ``p % pages_per_seq`` — a
+    plain full-length row is the ring that never wraps.
+
+    With ``block_pages`` > 1 a step of the walk is that many pages: their
+    DMAs are in flight together, each into its own rows of one
+    ``[block_pages * page_size, ...]`` tile, and the softmax update runs
+    once over the tile. A context of thousands of tokens is hundreds of
+    16-token pages; at one page a step the walk is bound by the latency of
+    a 16 KB DMA and the fixed cost of a step, not by bytes."""
     if inline:
         knew_ref, vnew_ref, *refs = refs
     # k_hbm, v_hbm: [layers, num_pages, page_size, kv_heads * head_dim] HBM/ANY
     # o_ref: [1, heads, head_dim] VMEM
-    # k_buf, v_buf: [2, page_size, kv_heads * head_dim] VMEM; sems: DMA [2, 2]
+    # k_buf, v_buf: [2, block_pages * page_size, kv_heads * head_dim] VMEM
+    # sems: DMA [2, 2] (one page a step) or [2, 2, block_pages]
     k_hbm, v_hbm, o_ref, k_buf, v_buf, sems = refs
+    blocked = block_pages > 1
+    tile = block_pages * page_size
     b = pl.program_id(0)
     group = num_heads // num_kv_heads
     kv_len = len_ref[b]
     layer = layer_ref[0]
     num_pages = jax.lax.div(kv_len + page_size - 1, page_size)
+    if window:
+        # the query's own position is kv_len (inline) or kv_len - 1
+        lo = jnp.maximum(kv_len + (1 if inline else 0) - window, 0)
+        first_page = jax.lax.div(lo, page_size)
+        width = page_table_ref.shape[1]
+        num_pages = num_pages - first_page  # pages walked
+    # steps of the walk
+    num_steps = (
+        jax.lax.div(num_pages + block_pages - 1, block_pages)
+        if blocked else num_pages
+    )
 
-    def page_dma(buf, hbm, slot, p, sem_row):
+    def page_dma(buf, hbm, slot, p, sem_row, j=0):
+        if blocked:
+            # the last step's spare pages read its last page again: their
+            # positions lie past kv_len and are masked
+            p = jnp.minimum(p, num_pages - 1)
+        if window:
+            p = jax.lax.rem(first_page + p, width)
         return pltpu.make_async_copy(
             hbm.at[layer, page_table_ref[b, p]],
-            buf.at[slot],
-            sems.at[sem_row, slot],
+            buf.at[slot, pl.ds(j * page_size, page_size)] if blocked
+            else buf.at[slot],
+            sems.at[sem_row, slot, j] if blocked else sems.at[sem_row, slot],
         )
 
-    @pl.when(num_pages > 0)
+    def step_dmas(slot, step):
+        """The K and V copies of one step of the walk."""
+        if not blocked:
+            return [page_dma(k_buf, k_hbm, slot, step, 0),
+                    page_dma(v_buf, v_hbm, slot, step, 1)]
+        return [
+            page_dma(buf, hbm, slot, step * block_pages + j, row, j)
+            for j in range(block_pages)
+            for row, (buf, hbm) in enumerate(((k_buf, k_hbm), (v_buf, v_hbm)))
+        ]
+
+    @pl.when(num_steps > 0)
     def _():
-        page_dma(k_buf, k_hbm, 0, 0, 0).start()
-        page_dma(v_buf, v_hbm, 0, 0, 1).start()
+        for dma in step_dmas(0, 0):
+            dma.start()
 
     q = q_ref[0].astype(jnp.float32) * (head_dim**-0.5)  # [heads, head_dim]
 
@@ -128,19 +175,22 @@ def _decode_kernel(
         ms, ls, accs = carry  # tuples of [group,1], [group,1], [group,d]
         slot = jax.lax.rem(p, 2)
 
-        @pl.when(p + 1 < num_pages)
+        @pl.when(p + 1 < num_steps)
         def _():
             nxt = jax.lax.rem(p + 1, 2)
-            page_dma(k_buf, k_hbm, nxt, p + 1, 0).start()
-            page_dma(v_buf, v_hbm, nxt, p + 1, 1).start()
+            for dma in step_dmas(nxt, p + 1):
+                dma.start()
 
-        page_dma(k_buf, k_hbm, slot, p, 0).wait()
-        page_dma(v_buf, v_hbm, slot, p, 1).wait()
+        for dma in step_dmas(slot, p):
+            dma.wait()
 
         # tokens beyond kv_len in the (last) page are masked out
-        tok0 = p * page_size
-        tok_idx = tok0 + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
-        valid = tok_idx < kv_len  # [1, page_size]
+        page0 = p * block_pages if blocked else p
+        tok0 = (first_page + page0 if window else page0) * page_size
+        tok_idx = tok0 + jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
+        valid = tok_idx < kv_len  # [1, tile]
+        if window:
+            valid = valid & (tok_idx >= lo)
 
         new_ms, new_ls, new_accs = [], [], []
         for g in range(num_kv_heads):
@@ -153,7 +203,7 @@ def _decode_kernel(
                 kg,
                 (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            )  # [group, page_size]
+            )  # [group, tile]
             logits = jnp.where(valid, logits, NEG_INF)
 
             m_cur = jnp.maximum(ms[g], logits.max(axis=-1, keepdims=True))
@@ -176,7 +226,7 @@ def _decode_kernel(
     acc0 = tuple(
         jnp.zeros((group, head_dim), jnp.float32) for _ in range(num_kv_heads)
     )
-    ms, ls, accs = jax.lax.fori_loop(0, num_pages, body, (m0, l0, acc0))
+    ms, ls, accs = jax.lax.fori_loop(0, num_steps, body, (m0, l0, acc0))
 
     if inline:
         # Fold the inline token (always valid; guarantees l > 0 at pos == 0).
@@ -201,7 +251,8 @@ def _decode_kernel(
 
 
 def _paged_decode(
-    q, k_pages, v_pages, page_table, kv_lens, layer, new_kv, interpret
+    q, k_pages, v_pages, page_table, kv_lens, layer, new_kv, interpret,
+    window=0, block_pages=1,
 ):
     batch, num_heads, head_dim = q.shape
     _, _, page_size, fused = k_pages.shape
@@ -216,7 +267,10 @@ def _paged_decode(
         num_kv_heads=num_kv_heads,
         head_dim=head_dim,
         inline=bool(new_kv),
+        **({"window": int(window)} if window else {}),
+        **({"block_pages": int(block_pages)} if block_pages > 1 else {}),
     )
+    tile = block_pages * page_size
     row_spec = lambda shape: pl.BlockSpec(  # noqa: E731
         shape, lambda b, *_: (b,) + (0,) * (len(shape) - 1), memory_space=pltpu.VMEM
     )
@@ -231,9 +285,11 @@ def _paged_decode(
         ],
         out_specs=row_spec((1, num_heads, head_dim)),
         scratch_shapes=[
-            pltpu.VMEM((2, page_size, fused), k_pages.dtype),
-            pltpu.VMEM((2, page_size, fused), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((2, tile, fused), k_pages.dtype),
+            pltpu.VMEM((2, tile, fused), v_pages.dtype),
+            pltpu.SemaphoreType.DMA(
+                (2, 2, block_pages) if block_pages > 1 else (2, 2)
+            ),
         ],
     )
     return pl.pallas_call(
@@ -253,7 +309,9 @@ def _paged_decode(
     )
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(
+    jax.jit, static_argnames=("interpret", "window", "block_pages")
+)
 def paged_decode_attention_inline_pallas(
     q: jnp.ndarray,  # [batch, heads, head_dim]
     k_pages: jnp.ndarray,  # [layers, num_pages, page_size, kv_heads*head_dim]
@@ -264,14 +322,16 @@ def paged_decode_attention_inline_pallas(
     positions: jnp.ndarray,  # [batch] int32 — cache holds entries < position
     layer: jnp.ndarray,  # int32 scalar — the pool layer to read
     interpret: bool = False,
+    window: int = 0,  # > 0: sliding window; table rows are read as rings
+    block_pages: int = 1,  # pages a step of the walk reads together
 ) -> jnp.ndarray:
     return _paged_decode(
         q, k_pages, v_pages, page_table, positions, layer, (k_new, v_new),
-        interpret,
+        interpret, window, block_pages,
     )
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "window"))
 def paged_decode_attention_pallas(
     q: jnp.ndarray,  # [batch, heads, head_dim]
     k_pages: jnp.ndarray,  # [layers, num_pages, page_size, kv_heads*head_dim]
@@ -280,7 +340,9 @@ def paged_decode_attention_pallas(
     seq_lens: jnp.ndarray,  # [batch] int32
     layer: jnp.ndarray,  # int32 scalar — the pool layer to read
     interpret: bool = False,
+    window: int = 0,  # > 0: sliding window; table rows are read as rings
 ) -> jnp.ndarray:
     return _paged_decode(
-        q, k_pages, v_pages, page_table, seq_lens, layer, (), interpret
+        q, k_pages, v_pages, page_table, seq_lens, layer, (), interpret,
+        window,
     )

@@ -37,6 +37,7 @@ def _prefill_kernel(
     block_q: int,
     block_k: int,
     head_dim: int,
+    window: int = 0,
 ):
     b = pl.program_id(0)
     qi = pl.program_id(2)
@@ -52,7 +53,13 @@ def _prefill_kernel(
 
     # causal: this K block contributes only if its first position can be seen
     # by the last query position of the q block
-    @pl.when(ki * block_k <= qi * block_q + block_q - 1)
+    live = ki * block_k <= qi * block_q + block_q - 1
+    if window:
+        # sliding window: and if its last position is still inside the
+        # window of the q block's first query (which counts itself)
+        live = live & (ki * block_k + block_k - 1 > qi * block_q - window)
+
+    @pl.when(live)
     def _():
         q = q_ref[0, 0].astype(jnp.float32) * (head_dim**-0.5)  # [Bq, d]
         k = k_ref[0, 0].astype(jnp.float32)  # [Bk, d]
@@ -67,6 +74,8 @@ def _prefill_kernel(
             jnp.int32, (1, block_k), 1
         )
         mask = (k_pos <= q_pos) & (k_pos < seq_len)
+        if window:
+            mask = mask & (k_pos > q_pos - window)
         logits = jnp.where(mask, logits, NEG_INF)
 
         m_prev = m_scr[:]
@@ -87,7 +96,7 @@ def _prefill_kernel(
         o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("block_q", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_q", "interpret", "window"))
 def causal_prefill_attention_pallas(
     q: jnp.ndarray,  # [batch, seq, heads, head_dim]
     k: jnp.ndarray,  # [batch, seq, kv_heads, head_dim]
@@ -95,6 +104,7 @@ def causal_prefill_attention_pallas(
     seq_lens: jnp.ndarray,  # [batch] int32
     block_q: int = 128,
     interpret: bool = False,
+    window: int = 0,  # > 0: query i sees keys j with i - window < j <= i
 ) -> jnp.ndarray:
     batch, seq, num_heads, head_dim = q.shape
     num_kv_heads = k.shape[2]
@@ -105,7 +115,8 @@ def causal_prefill_attention_pallas(
         raise ValueError(f"seq ({seq}) must be a multiple of block_q ({block_q})")
 
     kernel = functools.partial(
-        _prefill_kernel, block_q=block_q, block_k=block_k, head_dim=head_dim
+        _prefill_kernel, block_q=block_q, block_k=block_k, head_dim=head_dim,
+        **({"window": int(window)} if window else {}),
     )
     # head-major layout so the tiled (last two) dims are [seq, head_dim]
     qt = q.transpose(0, 2, 1, 3)  # [b, h, s, d]

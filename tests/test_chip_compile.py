@@ -133,23 +133,22 @@ def test_sharded_kernel_compiles_for_v5e_2x2(topo, kind, shape):
     _compile(shard_over_tp(mesh, KERNELS[kind], in_specs, out_spec), *args)
 
 
-def _compile_engine_program(topo, program, bucket, tp, **engine):
-    """AOT-compile one serving program of a tiny-model engine under
-    ``pallas`` on a ``tp``-device mesh of described chips; returns
-    ``(compiled, engine config)``."""
+def _compile_engine_program(topo, program, bucket, tp, model=None, **engine):
+    """AOT-compile one serving program of an engine (of a tiny model unless
+    one is given) under ``pallas`` on a ``tp``-device mesh of described
+    chips; returns ``(compiled, engine config)``."""
     from llm_d_fast_model_actuation_tpu.engine import EngineConfig, exec_pool
     from llm_d_fast_model_actuation_tpu.models import llama
     from llm_d_fast_model_actuation_tpu.ops import attention as attn
 
-    model = llama.LlamaConfig(
+    model = model or llama.LlamaConfig(
         vocab_size=512, hidden_size=256, num_layers=3, num_heads=8,
         num_kv_heads=4, head_dim=128, intermediate_size=512,
         max_seq_len=256, attention_impl="pallas",
     )
-    cfg = EngineConfig(
-        model=model, max_batch=4, attention_impl="pallas", decode_chunk=4,
-        **engine,
-    )
+    engine.setdefault("max_batch", 4)
+    engine.setdefault("decode_chunk", 4)
+    cfg = EngineConfig(model=model, attention_impl="pallas", **engine)
     attn.set_pallas_interpret(False)  # compile the kernels for the chip
     try:
         return exec_pool.compile_program(
@@ -193,9 +192,17 @@ def _pool_sized_ops(text, min_elems):
     """(opcode, line) of every instruction of the compiled module, outside
     fused computations (what a fusion computes inside is never materialized),
     that writes ``min_elems`` elements or more — except the in-place cache
-    write, a ``kv_write`` scatter whose output aliases its operand."""
+    write, a scatter whose output aliases its operand (named ``kv_write``,
+    or, where an unrolled clone lost its name, a fusion that aliases an
+    operand and whose computation is a scatter)."""
     fused = set(re.findall(r"fusion\(.*calls=%?([\w.\-]+)", text))
-    found, skipping = [], False
+    found, skipping, scatters, inside = [], False, set(), None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if head:
+            inside = head.group(1)
+        elif " scatter(" in line:
+            scatters.add(inside)
     for line in text.splitlines():
         head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
         if head:
@@ -211,7 +218,10 @@ def _pool_sized_ops(text, min_elems):
             ),
             default=0,
         )
-        in_place_write = "kv_write" in line and "aliasing" in line
+        calls = re.search(r"calls=%?([\w.\-]+)", line)
+        in_place_write = "aliasing" in line and (
+            "kv_write" in line or (calls and calls.group(1) in scatters)
+        )
         if elems >= min_elems and not in_place_write:
             found.append((m.group(2), line.strip()[:200]))
     return found
@@ -257,6 +267,108 @@ def test_no_program_holds_a_pool_sized_copy(topo, program, bucket, tp):
     assert _pool_sized_ops(compiled.as_text(), layer_pool) == []
     temps = compiled.memory_analysis().temp_size_in_bytes
     assert temps < layer_pool * 2, temps  # bf16
+
+
+@pytest.mark.parametrize(
+    "program,bucket", [("chunk", 4), ("prefill", 16), ("suffix", 16)]
+)
+def test_no_patterned_program_holds_a_copy_of_either_pool(topo, program, bucket):
+    """The same of a model with window layers, whose KV state is two pools
+    (pages of the full-attention layers, rings of the window layers), and
+    of its expert stacks: the rings go through the programs in their stored
+    shape and back, and the grouped matmuls read a layer's experts out of
+    the whole stack (models/moe.py:_grouped), so nothing the size of a
+    layer of either pool, or of a layer's experts, is materialized."""
+    from llm_d_fast_model_actuation_tpu.models import smallthinker
+
+    model = smallthinker.SmallThinkerConfig(
+        vocab_size=512, hidden_size=256, num_layers=8, num_heads=8,
+        num_kv_heads=4, head_dim=128, intermediate_size=128, max_seq_len=4096,
+        num_experts=8, experts_per_token=2, attention_impl="pallas",
+        window_pattern=(0, 1024, 1024, 1024),
+    )
+    # pools too large for the compiler to move whole into fast memory, as
+    # it does with arrays of a few MB
+    compiled, cfg = _compile_engine_program(
+        topo, program, bucket, tp=1, model=model, num_pages=4096,
+        max_batch=64, max_prefill_tokens=64, prefix_caching=False,
+    )
+    lay = cfg.kv_layout
+    assert (lay.global_layers, lay.window_layers) == (2, 6)
+    layer_pool = cfg.num_pages * cfg.page_size * model.kv_dim
+    layer_ring = cfg.max_batch * lay.ring_pages * cfg.page_size * model.kv_dim
+    # the model is tiny (all its experts together are smaller than a layer
+    # of the rings; the cell's real sizes are compiled below), the pools not
+    all_experts = model.num_layers * (
+        model.num_experts * model.hidden_size * model.intermediate_size
+    )
+    smaller = min(layer_ring, layer_pool)
+    assert all_experts < smaller
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3  # attention and grouped matmuls
+    assert _pool_sized_ops(text, smaller) == []
+    temps = compiled.memory_analysis().temp_size_in_bytes
+    assert temps < smaller * 2, temps  # bf16
+
+
+@pytest.mark.parametrize(
+    "program,bucket", [("chunk", 8), ("prefill", 1024), ("suffix", 1024)]
+)
+def test_longmix_cell_programs_fit_the_chip(topo, program, bucket):
+    """The programs of the cell ``smallthinker-21b.longmix`` at its real
+    sizes and engine options, compiled for the described chip: kernels in
+    (attention, and the three grouped expert matmuls), nothing the size of a
+    layer of either pool or of a layer's experts copied, arguments + temps
+    inside the chip's 16 GB beside the 11.7 GB of weights, pages and rings,
+    and the expert layers' flops those of 6 experts a token, not of 64."""
+    import dataclasses
+
+    from fmabench import spec
+    from llm_d_fast_model_actuation_tpu.engine import server
+
+    cell = spec.Cell(spec.benchmark(), "smallthinker-21b.longmix")
+    d = cell.dims
+    model = dataclasses.replace(
+        cell.family.part("program").build(d), attention_impl="pallas"
+    )
+    args = server.make_arg_parser().parse_args(
+        ["--model", "tiny", *cell.engine_options(False)]
+    )
+    compiled, cfg = _compile_engine_program(
+        topo, program, bucket, tp=1, model=model, max_batch=args.max_batch,
+        page_size=args.page_size, num_pages=args.num_pages,
+        decode_chunk=args.decode_chunk,
+        max_prefill_tokens=args.max_prefill_tokens, prefix_caching=False,
+    )
+    lay = cfg.kv_layout
+    assert lay.ring_pages * cfg.page_size == 4096 + 1024
+    keys = cell.family.keys
+    state = (
+        2 * keys.param_count(d)
+        + keys.kv_bytes(d, cfg.num_pages, cfg.page_size)
+        + keys.ring_bytes(d, cfg.max_batch, 1024)
+    )
+    assert 11.6e9 < state < 11.8e9
+    ma = compiled.memory_analysis()
+    assert state <= ma.argument_size_in_bytes < state + 0.1e9
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 13e9
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    layer_experts = d["num_experts"] * d["hidden_size"] * d["expert_size"]
+    # ... but for the logits of a whole segment, which the prefill programs
+    # of every family compute before they take the last (PERF.md section 5)
+    logits = f"bf16[{bucket},{d['vocab_size']}]"
+    assert [
+        row for row in _pool_sized_ops(text, layer_experts)
+        if logits not in row[1]
+    ] == []
+    # XLA counts a loop's body once: one period of four layers, one step
+    rows = cfg.max_batch if program == "chunk" else bucket
+    per_expert = 3 * 2 * d["hidden_size"] * d["expert_size"]
+    dense_experts = 4 * rows * d["num_experts"] * per_expert
+    routed_experts = 4 * rows * d["experts_per_token"] * per_expert
+    flops = compiled.cost_analysis()["flops"]
+    assert routed_experts < flops < routed_experts + 0.5 * dense_experts
 
 
 def test_lane_constraint_is_named_not_a_mosaic_crash(topo):
