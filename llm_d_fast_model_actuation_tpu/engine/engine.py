@@ -292,18 +292,25 @@ class Request:
     #: stamped by the serving loop when the finished request leaves the
     #: engine (SLO TPOT judgment + the usage block's decode_tpot_s)
     done_time: Optional[float] = None
-    #: Streaming hook: called as on_token(req, token) for every emitted
-    #: token, on the engine thread. Keep it cheap (enqueue, don't compute).
-    #: Tokens that could be the start of a stop sequence are held back
-    #: until disambiguated, so streamed output never contains stripped
-    #: stop-sequence content (OpenAI semantics).
-    on_token: Optional[Callable[["Request", int], None]] = None
-    #: tokens already delivered to on_token (stop-prefix holdback cursor)
+    #: Streaming hook: called as on_tokens(req, tokens) on the engine
+    #: thread, once for each run of tokens the request is emitted (the
+    #: first token, then what every drained decode chunk held for it).
+    #: Keep it cheap (enqueue, don't compute). Tokens that could be the
+    #: start of a stop sequence are held back until disambiguated, so
+    #: streamed output never contains stripped stop-sequence content
+    #: (OpenAI semantics).
+    on_tokens: Optional[Callable[["Request", List[int]], None]] = None
+    #: tokens already delivered to on_tokens (stop-prefix holdback cursor)
     streamed: int = 0
     #: external early-stop request (e.g. a stop STRING matched on decoded
     #: text in the server layer): the engine finishes the request at the
     #: next emitted token instead of decoding to eos/max_tokens
     stop_requested: bool = False
+    #: per-token watcher, asked ``stop_watch(token) -> bool`` on the engine
+    #: thread about every token as the stop-sequence hold-back releases
+    #: it; True sets ``stop_requested``. A request that carries one has
+    #: the end of each run decided token by token (engine._run_end).
+    stop_watch: Optional[Callable[[int], bool]] = None
     #: packed serving: admitted but the prompt is not fully in cache yet
     #: (req.pos tracks progress); excluded from decode dispatch until the
     #: final prefill segment samples the first token
@@ -359,6 +366,14 @@ def validate_logit_bias(lb, vocab_size: int) -> "Dict[int, float] | None":
             raise ValueError(f"logit_bias value {fv} outside [-100, 100]")
         out[t] = fv
     return out
+
+
+def deliver_tokens(req: Request, end: int) -> None:
+    """Hand ``req.out_tokens[req.streamed:end]`` to the request's hook in
+    ONE call and advance the cursor by what was delivered: a hook that
+    raises leaves the whole run to the next delivery."""
+    req.on_tokens(req, req.out_tokens[req.streamed:end])
+    req.streamed = end
 
 
 def _stop_holdback(out: List[int], stop_seqs) -> int:
@@ -1008,6 +1023,7 @@ class InferenceEngine:
         if cfg.speculative_ngram:
             refuse_window_layers(m, "--speculative-ngram")
         self.cfg = cfg
+        self._eos_ids = frozenset((cfg.eos_token_id, *cfg.extra_eos_ids))
         self.mesh = mesh
         # thread the attention impl through the model config (per-engine, not
         # a process global — two engines must not clobber each other)
@@ -1608,7 +1624,8 @@ class InferenceEngine:
         stop_seqs: Seq[Seq[int]] = (),
         presence_penalty: float = 0.0,
         frequency_penalty: float = 0.0,
-        on_token: Optional[Callable[[Request, int], None]] = None,
+        on_tokens: Optional[Callable[[Request, List[int]], None]] = None,
+        stop_watch: Optional[Callable[[int], bool]] = None,
         want_top_logprobs: bool = False,
         want_prompt_logprobs: bool = False,
         seed: Optional[int] = None,
@@ -1678,7 +1695,8 @@ class InferenceEngine:
             stop_seqs=tuple(tuple(int(t) for t in s) for s in stop_seqs),
             presence_penalty=float(presence_penalty),
             frequency_penalty=float(frequency_penalty),
-            on_token=on_token,
+            on_tokens=on_tokens,
+            stop_watch=stop_watch,
             want_top_logprobs=want_top_logprobs,
             want_prompt_logprobs=want_prompt_logprobs,
             seed=seed,
@@ -2035,14 +2053,10 @@ class InferenceEngine:
                         float(row[0][i]) for i in range(take)
                     )
             self._slot_keys[req.slot] = key_h
-            first = int(tok_h[0])
-            req.pos = n
-            self._emit(req, first, float(lp_h[0]), alts)
-            self._positions[req.slot] = req.pos  # position of the token to place
-            self._last_tokens[req.slot] = first
+            req.pos = n  # position of the token to place
+            self._emit(req, int(tok_h[0]), float(lp_h[0]), alts, placed=False)
             self._temps[req.slot] = req.temperature
             self._topps[req.slot] = req.top_p
-            self._budgets[req.slot] = req.max_new_tokens - len(req.out_tokens)
             self._dirty = True
             ph.set(tokens=1, finished=int(req.done))
 
@@ -2052,7 +2066,39 @@ class InferenceEngine:
         token: int,
         logprob: float = 0.0,
         alts: Optional[list] = None,
+        placed: bool = True,
     ) -> None:
+        """One token: a run of one (a prefill's first token, a packed
+        step's row, a speculation round's tokens), counted and streamed
+        at once."""
+        self._emit_run(req, [token], [logprob], [alts or []], placed)
+        if req.slot >= 0:
+            # host counts mirror the device copy the chunk program updates
+            # (stop-stripped tokens stay counted on both sides)
+            self._token_counts[req.slot, token] += 1
+        self._stream(req)
+
+    def _emit_run(
+        self,
+        req: Request,
+        toks: List[int],
+        lps: List[float],
+        alts: Optional[List[list]] = None,
+        placed: bool = True,
+    ) -> int:
+        """Emit one request's run: the leading tokens of ``toks`` (what its
+        slot sampled in one drained chunk) up to where the request ends —
+        its budget, the first eos unless ``ignore_eos``, a stop asked for
+        from outside, a stop sequence completing. The output lists and the
+        slot's small mirrors are written once. Left to the caller, which
+        knows whether it has one token or a chunk's worth: the slot's
+        token counts, and ``_stream``, which hands the newly safe tokens
+        to the streaming hook in one call. ``placed`` says each token of
+        the run was a decode step that wrote its position (not so a
+        prefill's first token, sampled and yet to be placed). Returns the
+        run's length, the tokens a stop sequence strips included.
+        Per-token Python runs only for a request that carries stop
+        sequences or a ``stop_watch``."""
         if req.first_token_time is None:
             req.first_token_time = time.monotonic()
             if (
@@ -2071,65 +2117,94 @@ class InferenceEngine:
                     cached_tokens=req.cached_tokens,
                     packed=bool(self._packed),
                 )
-        req.out_tokens.append(token)
-        req.out_logprobs.append(logprob)
-        req.out_top_logprobs.append(alts or [])
-        self.total_tokens_emitted += 1
-        if req.slot >= 0:
-            # host counts mirror the device copy the chunk program updates
-            # (stop-stripped tokens stay counted on both sides)
-            self._token_counts[req.slot, token] += 1
-        for seq in req.stop_seqs:
-            if len(req.out_tokens) >= len(seq) and tuple(
-                req.out_tokens[-len(seq):]
-            ) == seq:
-                # OpenAI semantics: finish on the stop sequence and strip it
-                del req.out_tokens[-len(seq):]
-                del req.out_logprobs[-len(seq):]
-                del req.out_top_logprobs[-len(seq):]
-                req.done = True
-                req.finish_reason = "stop"
-                break
-        if not req.done:
-            eos_hit = (
-                token == self.cfg.eos_token_id
-                or token in self.cfg.extra_eos_ids
-            ) and not req.ignore_eos
-            if req.stop_requested or eos_hit:
-                req.done = True
-                req.finish_reason = "stop"
-            elif len(req.out_tokens) >= req.max_new_tokens:
-                req.done = True
-                req.finish_reason = "length"
-        self._stream(req)
+        out = req.out_tokens
+        n = max(1, min(len(toks), req.max_new_tokens - len(out)))
+        reason = "length" if len(out) + n >= req.max_new_tokens else ""
+        if not req.ignore_eos and not self._eos_ids.isdisjoint(toks[:n]):
+            n = next(i for i, t in enumerate(toks) if t in self._eos_ids) + 1
+            reason = "stop"
+        if req.stop_requested:
+            n, reason = 1, "stop"
+        strip = 0
+        if req.stop_seqs or req.stop_watch is not None:
+            n, reason, strip = self._run_end(req, toks, n, reason)
+        run = toks[:n]
+        out.extend(run)
+        req.out_logprobs.extend(lps[:n])
+        req.out_top_logprobs.extend(
+            alts[:n] if alts is not None else [[] for _ in run]
+        )
+        self.total_tokens_emitted += n
+        if strip:
+            # OpenAI semantics: finish on the stop sequence and strip it
+            # (its head may have come with an earlier run, held back)
+            del out[-strip:]
+            del req.out_logprobs[-strip:]
+            del req.out_top_logprobs[-strip:]
+        if placed:
+            req.pos += n
+        slot = req.slot
+        if slot >= 0:
+            self._positions[slot] = req.pos
+            self._last_tokens[slot] = run[-1]
+            # keep the budget mirror exact: a dirty re-upload with a
+            # stale budget would un-freeze finished slots on device
+            self._budgets[slot] = req.max_new_tokens - len(out)
+        if reason:
+            req.done = True
+            req.finish_reason = reason
+        return n
+
+    def _run_end(
+        self, req: Request, toks: List[int], n: int, reason: str
+    ) -> Tuple[int, str, int]:
+        """Where a run of at most ``n`` tokens ends for a request that has
+        to be asked token by token: at the token that completes a stop
+        sequence (returned with the sequence's length, to strip), or at
+        the token after the one at which ``stop_watch`` asked for a stop
+        (``stop_requested`` finishes a request at its NEXT token). The
+        watch sees each token once, when the stop-sequence hold-back
+        releases it, as the hook would have streamed it."""
+        seqs, watch = req.stop_seqs, req.stop_watch
+        cand = req.out_tokens + toks[:n]
+        start = len(req.out_tokens)
+        seen = start - _stop_holdback(req.out_tokens, seqs)
+        i = 0
+        while i < n:
+            end = start + i + 1
+            for seq in seqs:
+                if end >= len(seq) and tuple(cand[end - len(seq):end]) == seq:
+                    return i + 1, "stop", len(seq)
+            if watch is not None:
+                last = i == n - 1
+                safe = end if last and reason else end - _stop_holdback(
+                    cand[:end], seqs
+                )
+                for t in cand[seen:safe]:
+                    if watch(t):
+                        req.stop_requested = True
+                seen = max(seen, safe)
+                if req.stop_requested and not last:
+                    n, reason = i + 2, "stop"
+            i += 1
+        return n, reason, 0
 
     def _stream(self, req: Request) -> None:
-        """Deliver newly-safe tokens to the streaming hook.
+        """Deliver the newly safe tokens to the streaming hook, in one call.
 
         Tokens forming a suffix of the output that is a proper prefix of a
         stop sequence are held back — they may yet be stripped. On finish,
         everything that survived stripping is flushed; consumers see
-        `req.done` only on the final delivered token (the SSE writer keys
-        its terminator on it)."""
-        if req.on_token is None:
+        `req.done` only on the delivery that holds the final token (the
+        SSE writer keys its terminator on it)."""
+        if req.on_tokens is None:
             return
-        if req.done:
-            tail = req.out_tokens[req.streamed:]
-        else:
-            hold = _stop_holdback(req.out_tokens, req.stop_seqs)
-            tail = req.out_tokens[req.streamed : len(req.out_tokens) - hold]
-        if not tail:
-            return
-        # advance the cursor per delivered token: an on_token exception
-        # mid-flush must leave the rest re-flushable on the next emit
-        was_done = req.done
-        try:
-            for i, t in enumerate(tail):
-                req.done = was_done and i == len(tail) - 1
-                req.on_token(req, t)
-                req.streamed += 1
-        finally:
-            req.done = was_done
+        end = len(req.out_tokens)
+        if req.stop_seqs and not req.done:
+            end -= _stop_holdback(req.out_tokens, req.stop_seqs)
+        if end > req.streamed:
+            tracing.count_emit_delivery(end - req.streamed)
+            deliver_tokens(req, end)
 
     def _retire(self, req: Request) -> None:
         if self.prefix_cache is not None:
@@ -2471,11 +2546,10 @@ class InferenceEngine:
                         req.prompt, req.pages, req.shared_pages,
                         known_hashes=getattr(req, "_prefix_hashes", ()),
                     )
-                first = int(tok_h[slot])
-                self._emit(req, first, float(lp_h[slot]), alts_for(req, slot))
-                self._positions[slot] = req.pos
-                self._last_tokens[slot] = first
-                self._budgets[slot] = req.max_new_tokens - len(req.out_tokens)
+                self._emit(
+                    req, int(tok_h[slot]), float(lp_h[slot]),
+                    alts_for(req, slot), placed=False,
+                )
                 if req.done:
                     self._retire(req)
                     finished.append(req)
@@ -2484,12 +2558,10 @@ class InferenceEngine:
                 if req.done:
                     continue
                 slot = req.slot
-                t = int(tok_h[slot])
-                req.pos += 1
-                self._positions[slot] = req.pos
-                self._last_tokens[slot] = t
-                self._emit(req, t, float(lp_h[slot]), alts_for(req, slot))
-                self._budgets[slot] = req.max_new_tokens - len(req.out_tokens)
+                self._emit(
+                    req, int(tok_h[slot]), float(lp_h[slot]),
+                    alts_for(req, slot),
+                )
                 if req.done:
                     self._retire(req)
                     finished.append(req)
@@ -2526,7 +2598,7 @@ class InferenceEngine:
         r = active[0]
         # only transforms that shift the argmax gate exactness: at
         # temperature 0 sampling is the full-vocab argmax regardless of
-        # top_p, and streaming (on_token) already receives multi-token
+        # top_p, and streaming (on_tokens) already receives multi-token
         # bursts from the chunk path — but repetition penalties DO move
         # the argmax, and the verify program doesn't apply them
         if (
@@ -2651,12 +2723,6 @@ class InferenceEngine:
             self._spec_miss_streak = 0
         with tracing.phase("sched.emit", False) as ph:
             for t, lp, alts in emitted:
-                req.pos += 1
-                self._positions[req.slot] = req.pos
-                self._last_tokens[req.slot] = t
-                self._budgets[req.slot] = max(
-                    0, req.max_new_tokens - len(req.out_tokens) - 1
-                )
                 self._emit(req, t, lp, alts)
                 if req.done:
                     break
@@ -2866,7 +2932,7 @@ class InferenceEngine:
     def _drain_chunk(self, inflight, defer_retire: bool = False):
         """Fetch one dispatched chunk's results (the single blocking host
         sync per chunk) and emit its tokens."""
-        toks_dev, lps_dev, avs_dev, ais_dev, skeys_dev, running, T = inflight
+        toks_dev, lps_dev, avs_dev, ais_dev, skeys_dev, running, _ = inflight
         # The key mirror rides the batched device_get: a dirty re-upload
         # must not rewind any slot's key stream to a pre-chunk state.
         # Pipelined: a later chunk's dispatch DONATES this chunk's skeys
@@ -2886,21 +2952,28 @@ class InferenceEngine:
                 )
         with tracing.phase("sched.emit", overlapped) as ph:
             emitted = self.total_tokens_emitted
+            delivered = tracing.emit_deliveries()
             finished = self._emit_chunk(
-                toks, lps, avs, ais, skeys_host, running, T, defer_retire
+                toks, lps, avs, ais, skeys_host, running, defer_retire
             )
             ph.set(
                 tokens=self.total_tokens_emitted - emitted,
+                deliveries=tracing.emit_deliveries() - delivered,
                 finished=len(finished),
             )
         return finished
 
     def _emit_chunk(
-        self, toks, lps, avs, ais, skeys_host, running, T, defer_retire
+        self, toks, lps, avs, ais, skeys_host, running, defer_retire
     ) -> List[Request]:
-        """The host half of a drained chunk: key mirror, per-token emits,
-        retires. Returns the requests that finished."""
-        finished: List[Request] = []
+        """The host half of a drained chunk: key mirror, one run of tokens
+        a request (``_emit_run``, which writes the slot's small mirrors),
+        the token counts of all runs in one call, retires, and LAST the
+        streaming hooks, one call a request, back to back: the first of
+        them wakes the server's loop, and a writer that shares the GIL
+        should find this thread past its bookkeeping. Returns the requests
+        that finished, in the order a walk step by step would finish
+        them."""
         if skeys_host is not None:
             # only the rows this chunk actually advanced: a request
             # admitted while the chunk was in flight had its key written
@@ -2908,41 +2981,47 @@ class InferenceEngine:
             # it to the pre-admission (zero) snapshot
             for slot in running:
                 self._slot_keys[slot] = skeys_host[slot]
-        running = dict(running)
-        for slot in list(running):
+        # [T, slots] -> one list of T a slot, converted once
+        toks_l = toks.T.tolist()
+        lps_l = lps.T.tolist()
+        ended: List[Tuple[int, Request]] = []
+        emitted: List[Request] = []
+        taken = np.zeros(toks.shape[1], dtype=np.int64)
+        for slot, req in running.items():
             # aborted between dispatch and drain: its tokens are frozen
             # repeats, and abort already handled the retire
-            if running[slot].done:
-                del running[slot]
-        # every token emitted below was a step that wrote its position
-        chunk_start = [(req, req.pos) for req in running.values()]
-        for t in range(T):
-            for slot, req in list(running.items()):
-                tok = int(toks[t, slot])
-                req.pos += 1
-                self._positions[slot] = req.pos
-                self._last_tokens[slot] = tok
-                self._emit(
-                    req, tok, float(lps[t, slot]),
-                    [
-                        (int(ais[t, slot, j]), float(avs[t, slot, j]))
-                        for j in range(avs.shape[2])
-                    ]
-                    if req.want_top_logprobs
-                    else None,
-                )
-                # keep the budget mirror exact: a dirty re-upload with a
-                # stale budget would un-freeze finished slots on device
-                self._budgets[slot] = req.max_new_tokens - len(req.out_tokens)
-                if req.done:
-                    if defer_retire:
-                        self._defer_retire(req)
-                    else:
-                        self._retire(req)
-                    finished.append(req)
-                    del running[slot]
-        for req, first in chunk_start:
-            self._count_forward(first, req.pos - first)
+            if req.done:
+                continue
+            alts = None
+            if req.want_top_logprobs:
+                alts = [
+                    list(zip(i, v))
+                    for i, v in zip(
+                        ais[:, slot].tolist(), avs[:, slot].tolist()
+                    )
+                ]
+            first = req.pos
+            n = self._emit_run(req, toks_l[slot], lps_l[slot], alts)
+            # every token of the run was a step that wrote its position
+            self._count_forward(first, n)
+            taken[slot] = n
+            emitted.append(req)
+            if req.done:
+                ended.append((n, req))
+        # host counts mirror the device copy the chunk program updates
+        # (stop-stripped tokens stay counted on both sides): every run's
+        # tokens, toks[:taken[slot], slot], in one call
+        step, slot = np.nonzero(np.arange(toks.shape[0])[:, None] < taken)
+        np.add.at(self._token_counts, (slot, toks[step, slot]), 1)
+        ended.sort(key=lambda e: e[0])
+        finished = [req for _, req in ended]
+        for req in finished:
+            if defer_retire:
+                self._defer_retire(req)
+            else:
+                self._retire(req)
+        for req in emitted:
+            self._stream(req)
         return finished
 
     def _defer_retire(self, req: Request) -> None:

@@ -48,7 +48,12 @@ from ..models import llama
 from ..models.moe import MoeConfig
 from ..models.smallthinker import SmallThinkerConfig
 from ..utils import faults, tracing
-from .engine import EngineConfig, InferenceEngine, resolve_attention_impl
+from .engine import (
+    EngineConfig,
+    InferenceEngine,
+    deliver_tokens,
+    resolve_attention_impl,
+)
 from .model_pool import HostModelPool
 from .sleep import (
     SwapRolledBack,
@@ -2585,9 +2590,10 @@ class EngineService:
         future and streaming hook stay behind on the source (the proxy
         leg resolves them); ``submit_time`` is deliberately dropped —
         the importer stamps its own clock."""
-        (prompt, max_tokens, temperature, _fut, _on_token, top_p,
+        (prompt, max_tokens, temperature, _fut, _on_tokens, top_p,
          stop_seqs, presence, freq, want_alts, want_plp, seed,
-         ignore_eos, logit_bias, _submit_t, variant, trace) = entry
+         ignore_eos, logit_bias, _submit_t, variant, trace,
+         _stop_watch) = entry
         spec = {
             "prompt": [int(t) for t in prompt],
             "max_tokens": int(max_tokens),
@@ -2652,6 +2658,7 @@ class EngineService:
             time.monotonic(),
             int(spec.get("variant", 0)),
             trace,
+            None,
         )
 
     def price_migrate(self) -> Dict[str, Any]:
@@ -3362,7 +3369,7 @@ class EngineService:
             int(t): float(v) for t, v in spec["logit_bias"].items()
         }
         req.variant = int(spec["variant"])
-        req.on_token = entry[4]
+        req.on_tokens = entry[4]
         req.submit_time = entry[14]
         return req
 
@@ -3429,28 +3436,22 @@ class EngineService:
 
     def _proxy_stream(self, req: Any, done: bool) -> None:
         """Deliver proxied tokens through the original streaming hook
-        with engine._stream's exact contract: ``req.done`` is True only
-        on the final delivered token (the SSE writer keys its terminator
-        on it). Claim snapshots are already holdback-safe."""
-        if req.on_token is None:
+        with engine._stream's exact contract: one call for what a claim
+        view brought, ``req.done`` True only on the delivery that holds
+        the final token (the SSE writer keys its terminator on it). Claim
+        snapshots are already holdback-safe."""
+        req.done = done
+        if req.on_tokens is None:
             req.streamed = len(req.out_tokens)
-            req.done = done
-            return
-        tail = req.out_tokens[req.streamed:]
-        try:
-            for i, t in enumerate(tail):
-                req.done = done and i == len(tail) - 1
-                req.on_token(req, t)
-                req.streamed += 1
-        finally:
-            req.done = done
+        elif len(req.out_tokens) > req.streamed:
+            deliver_tokens(req, len(req.out_tokens))
 
     def _watch_claim(
         self, dest: str, claim_id: str, req: Any, fut: Any
     ) -> None:
         """Source-side proxy for one migrated stream: poll the
         destination's claim, forward newly-safe tokens through the
-        original ``on_token`` hook, and resolve the original future with
+        original ``on_tokens`` hook, and resolve the original future with
         the finished request. Destination-side aborts and a destination
         that stays unreachable surface as the existing ``state_loss``
         abort — never a silent hang."""
@@ -5696,9 +5697,9 @@ class EngineService:
             while self._pending:
                 (
                     prompt, max_tokens, temperature, fut,
-                    on_token, top_p, stop_seqs, presence, freq,
+                    on_tokens, top_p, stop_seqs, presence, freq,
                     want_alts, want_plp, seed, ignore_eos,
-                    logit_bias, submit_t, variant, trace,
+                    logit_bias, submit_t, variant, trace, stop_watch,
                 ) = self._pending.pop(0)
                 try:
                     seq_id = self.engine.add_request(
@@ -5706,7 +5707,8 @@ class EngineService:
                         top_p=top_p, stop_seqs=stop_seqs,
                         presence_penalty=presence,
                         frequency_penalty=freq,
-                        on_token=on_token,
+                        on_tokens=on_tokens,
+                        stop_watch=stop_watch,
                         want_top_logprobs=want_alts,
                         want_prompt_logprobs=want_plp,
                         seed=seed,
@@ -6155,7 +6157,7 @@ class EngineService:
         prompt: List[int],
         max_tokens: int,
         temperature: float,
-        on_token: Optional[Any] = None,
+        on_tokens: Optional[Any] = None,
         top_p: float = 1.0,
         stop_seqs: Any = (),
         presence_penalty: float = 0.0,
@@ -6167,11 +6169,16 @@ class EngineService:
         logit_bias: "Dict[int, float] | None" = None,
         variant: int = 0,
         trace_ctx: "tracing.SpanContext | None" = None,
+        stop_watch: Optional[Any] = None,
     ) -> concurrent.futures.Future:
-        """Enqueue a request. `on_token(req, tok)` — if given — fires on the
-        engine thread for every emitted token (the streaming hook); keep it
-        to an enqueue. ``variant`` routes to a co-resident sibling
-        (resolve_request_model) — 0 is the base model. ``trace_ctx`` is
+        """Enqueue a request. `on_tokens(req, tokens)` — if given — fires on
+        the engine thread once for every run of tokens the request is
+        emitted: its first token, then what each drained decode chunk held
+        for it (the streaming hook); keep it to an enqueue.
+        `stop_watch(token) -> bool` is asked about every token and ends the
+        request at the next one when it says True. ``variant`` routes to a
+        co-resident sibling (resolve_request_model) — 0 is the base model.
+        ``trace_ctx`` is
         the client's ``traceparent`` (completions handlers): it forces a
         lifecycle trace even at --trace-requests 0 and parents it on the
         caller's span."""
@@ -6203,10 +6210,10 @@ class EngineService:
                 parent=trace_ctx,
             )
         self._pending.append(
-            (prompt, max_tokens, temperature, fut, on_token, top_p, stop_seqs,
+            (prompt, max_tokens, temperature, fut, on_tokens, top_p, stop_seqs,
              presence_penalty, frequency_penalty, want_top_logprobs,
              want_prompt_logprobs, seed, ignore_eos, logit_bias, now,
-             int(variant), trace)
+             int(variant), trace, stop_watch)
         )
         self._new_work.set()
         ENGINE_QUEUE_DEPTH.labels(model=self.args.model).set(self.queue_depth())
@@ -6721,8 +6728,43 @@ class _CurrentTokenizer:
         return getattr(self._service.tokenizer, name)
 
 
+class _Mailbox:
+    """Engine thread -> event loop, for streamed tokens: ``post`` queues an
+    item for an ``asyncio.Queue`` and wakes the loop only if no wake-up is
+    already pending, so the 64 deliveries of one drained chunk cost the
+    scheduler thread one write to the loop's wake-up pipe, not 64 — each
+    of those hands the GIL to the writer for one event's work while the
+    device waits for the loop to go on. Nothing waits longer: the loop
+    drains everything that queued before it ran."""
+
+    def __init__(self) -> None:
+        self._items: deque = deque()
+        self._wake_pending = False
+
+    def post(self, loop, q: asyncio.Queue, item: Any) -> None:
+        # append BEFORE reading the flag; drain clears the flag BEFORE it
+        # pops: an item that saw the flag set is seen by that drain
+        self._items.append((q, item))
+        if not self._wake_pending:
+            self._wake_pending = True
+            try:
+                loop.call_soon_threadsafe(self._drain)
+            except BaseException:
+                # a closed loop: the next post must try again, not wait
+                # for a drain that was never scheduled
+                self._wake_pending = False
+                raise
+
+    def _drain(self) -> None:
+        self._wake_pending = False
+        while self._items:
+            q, item = self._items.popleft()
+            q.put_nowait(item)
+
+
 def build_app(service: EngineService) -> web.Application:
     app = web.Application()
+    mailbox = _Mailbox()
     # read per-request, never captured: both change on a model hot-swap
     tok = _CurrentTokenizer(service)
 
@@ -7155,10 +7197,12 @@ def build_app(service: EngineService) -> web.Application:
         trace_ctx=None,
         usage_chunk=None,
     ) -> web.StreamResponse:
-        """OpenAI-style SSE stream: one `data: {json}` event per emitted
-        token, `data: [DONE]` terminator. Tokens cross the engine-thread ->
-        event-loop boundary via call_soon_threadsafe into an asyncio queue,
-        so delivery granularity is the engine's decode chunk.
+        """OpenAI-style SSE stream: one `data: {json}` event per run of
+        tokens the engine emits the request (its first token, then what
+        each drained decode chunk held for it, all in `token_ids`),
+        `data: [DONE]` terminator. A run crosses the engine-thread ->
+        event-loop boundary as ONE item of the app's mailbox, which wakes
+        this loop once for all the runs of a chunk (``_Mailbox``).
 
         Chunk text comes from an incremental detokenizer; stop STRINGS are
         matched here on the decoded text (held back until disambiguated)
@@ -7176,11 +7220,11 @@ def build_app(service: EngineService) -> web.Application:
         loop = asyncio.get_running_loop()
         q: asyncio.Queue = asyncio.Queue()
 
-        def on_token(req, tok: int) -> None:
-            loop.call_soon_threadsafe(q.put_nowait, (tok, req.done))
+        def on_tokens(req, run: List[int]) -> None:
+            mailbox.post(loop, q, (run, req.done))
 
         fut = service.submit(
-            tokens, max_tokens, temperature, on_token=on_token,
+            tokens, max_tokens, temperature, on_tokens=on_tokens,
             top_p=top_p, stop_seqs=stop_seqs,
             presence_penalty=presence, frequency_penalty=frequency,
             seed=seed, ignore_eos=ignore_eos, logit_bias=logit_bias,
@@ -7207,13 +7251,22 @@ def build_app(service: EngineService) -> web.Application:
                     {qtask, afut}, return_when=asyncio.FIRST_COMPLETED
                 )
                 if qtask in done_set:
-                    t, req_done = qtask.result()
+                    run, req_done = qtask.result()
                     qtask = None
                     if filt is not None:
                         # the filter tracks id<->text attribution through
                         # its hold-back window: every emission's ids are
                         # exactly the tokens whose decoded text it contains
-                        text, ids, matched = filt.push(t)
+                        text, ids, matched = "", [], False
+                        for i, t in enumerate(run):
+                            new, tids, matched = filt.push(t)
+                            text += new
+                            ids += tids
+                            if matched:
+                                # the request ended only if the match is
+                                # at the run's last token: else abort it
+                                req_done = req_done and i == len(run) - 1
+                                break
                         if not matched and req_done:
                             tail, tids, matched = filt.flush()
                             text += tail
@@ -7237,10 +7290,10 @@ def build_app(service: EngineService) -> web.Application:
                         if not text and not req_done:
                             continue  # held back: ids stay in the filter
                     else:
-                        text = dec.push(t)
+                        text = dec.push_run(run)
                         if req_done:
                             text += dec.flush()
-                        ids = [t]
+                        ids = run
                     payload = json.dumps(make_chunk(text, ids, index))
                     index += 1
                     await resp.write(f"data: {payload}\n\n".encode())
@@ -7355,13 +7408,7 @@ def build_app(service: EngineService) -> web.Application:
         from .tokenizer import TextStopStream
 
         filt = TextStopStream(tok, stop_texts)
-
-        def on_token(req, t: int) -> None:
-            _, _, matched = filt.push(t)
-            if matched:
-                req.stop_requested = True
-
-        return on_token
+        return lambda t: filt.push(t)[2]
 
     async def _gather_n(
         n: int, tokens, max_tokens, temperature, top_p, stop_seqs,
@@ -7377,7 +7424,7 @@ def build_app(service: EngineService) -> web.Application:
                 tokens, max_tokens, temperature,
                 top_p=top_p, stop_seqs=stop_seqs,
                 presence_penalty=presence, frequency_penalty=frequency,
-                on_token=(
+                stop_watch=(
                     _text_stop_watcher(stop_texts) if stop_texts else None
                 ),
                 want_top_logprobs=want_alts,

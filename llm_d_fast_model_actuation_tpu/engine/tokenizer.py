@@ -125,7 +125,13 @@ class IncrementalDecoder:
         self._read = 0  # tokens whose text has been emitted
 
     def push(self, token: int) -> str:
-        self._tokens.append(int(token))
+        return self.push_run((token,))
+
+    def push_run(self, tokens: Sequence[int]) -> str:
+        """A run of tokens at once: one decode of the window for the whole
+        run. What ends in a not-yet-complete byte sequence is held, all of
+        it, until a later push completes it."""
+        self._tokens.extend(int(t) for t in tokens)
         ctx = self._tok.decode(self._tokens[self._prefix : self._read])
         full = self._tok.decode(self._tokens[self._prefix :])
         # a trailing U+FFFD marks a split multi-byte sequence: hold until

@@ -183,9 +183,10 @@ def reset_after_fork() -> None:
     Request sampling resets to 0 (off): the child re-applies its own
     ``--trace-requests`` during engine construction."""
     global _BUFFER, _enabled, _REQ_BUFFER, _req_frac, _capturing
-    global _admit_blocked
+    global _admit_blocked, _emit_deliveries, _emit_tokens
     _capturing = False
     _admit_blocked = 0
+    _emit_deliveries = _emit_tokens = 0
     for name in PHASES:
         _PHASES[name] = Phase(name)
     _BUFFER = TraceBuffer(_env_capacity())
@@ -600,6 +601,11 @@ WAITING_PHASES = ("sched.prefill_fetch", "sched.chunk_fetch", "sched.wait")
 _capturing = False
 #: steps in which the head of the waiting queue was refused a slot or pages
 _admit_blocked = 0
+#: calls of a request's streaming hook from ``sched.emit``, and the tokens
+#: they carried: tokens / deliveries is about ``--decode-chunk`` at steady
+#: decode and 1 if a delivery is ever made a token at a time
+_emit_deliveries = 0
+_emit_tokens = 0
 
 
 class Phase:
@@ -667,6 +673,16 @@ def count_admit_blocked() -> None:
     _admit_blocked += 1
 
 
+def count_emit_delivery(tokens: int) -> None:
+    global _emit_deliveries, _emit_tokens
+    _emit_deliveries += 1
+    _emit_tokens += tokens
+
+
+def emit_deliveries() -> int:
+    return _emit_deliveries
+
+
 def _annotate(name: str, **args: Any) -> Any:
     """An entered ``jax.profiler.TraceAnnotation``. Imported here, not at
     the top: only a process with a capture running (an engine, which has
@@ -706,7 +722,8 @@ def phase_stats() -> Dict[str, Any]:
     ``sched.`` prefix), ``host_only_s``, the seconds of every phase but
     the waiting ones in which no dispatched chunk was in flight, and
     ``admit_blocked``, the steps in which the head of the waiting queue
-    was refused a slot or pages."""
+    was refused a slot or pages, and ``emit_deliveries`` / ``emit_tokens``,
+    the streaming-hook calls of ``sched.emit`` and the tokens they carried."""
     rows = [(p.name.partition(".")[2], p) for p in _PHASES.values()]
     return {
         "phase_s": {k: p.seconds for k, p in rows},
@@ -715,6 +732,8 @@ def phase_stats() -> Dict[str, Any]:
             p.host_only_s for _, p in rows if p.name not in WAITING_PHASES
         ),
         "admit_blocked": _admit_blocked,
+        "emit_deliveries": _emit_deliveries,
+        "emit_tokens": _emit_tokens,
     }
 
 

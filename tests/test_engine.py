@@ -242,7 +242,7 @@ def _stream_req(engine, stop_seqs, max_new_tokens=16):
         prompt=[1],
         max_new_tokens=max_new_tokens,
         stop_seqs=tuple(tuple(s) for s in stop_seqs),
-        on_token=lambda r, t: seen.append((t, r.done)),
+        on_tokens=lambda r, run: seen.append((run, r.done)),
     )
     return req, seen
 
@@ -252,12 +252,12 @@ def test_stream_holds_back_stop_prefix_until_disambiguated(engine):
     until the next token rules the match out — then both flush."""
     req, seen = _stream_req(engine, [(5, 6)])
     engine._emit(req, 1)
-    assert seen == [(1, False)]
+    assert seen == [([1], False)]
     engine._emit(req, 5)  # possible start of (5, 6): held back
-    assert seen == [(1, False)]
-    engine._emit(req, 7)  # disambiguated: 5 then 7 both stream
-    assert [t for t, _ in seen] == [1, 5, 7] == req.out_tokens
-    assert not req.done
+    assert seen == [([1], False)]
+    engine._emit(req, 7)  # disambiguated: 5 then 7 stream in one delivery
+    assert seen == [([1], False), ([5, 7], False)]
+    assert req.out_tokens == [1, 5, 7] and not req.done
 
 
 def test_stream_never_emits_stripped_stop_content(engine):
@@ -267,7 +267,7 @@ def test_stream_never_emits_stripped_stop_content(engine):
     assert req.done and req.finish_reason == "stop"
     assert req.out_tokens == [1]
     # the held-back 5 and the matching 6 were stripped, never streamed
-    assert seen == [(1, False)]
+    assert seen == [([1], False)]
 
 
 def test_stream_flushes_survivors_on_other_stop_match(engine):
@@ -278,7 +278,7 @@ def test_stream_flushes_survivors_on_other_stop_match(engine):
     assert seen == []
     engine._emit(req, 7)  # stop (7,) matches; 5 survives into the output
     assert req.done and req.out_tokens == [5]
-    assert seen == [(5, True)]
+    assert seen == [([5], True)]
 
 
 def test_stream_holdback_overlapping_prefix(engine):
@@ -287,7 +287,7 @@ def test_stream_holdback_overlapping_prefix(engine):
         engine._emit(req, t)
     assert req.done and req.finish_reason == "stop"
     assert req.out_tokens == [5]
-    assert [t for t, _ in seen] == [5]
+    assert [run for run, _ in seen] == [[5]]
 
 
 def test_stream_flushes_held_tokens_on_eos_and_length(engine):
@@ -296,13 +296,14 @@ def test_stream_flushes_held_tokens_on_eos_and_length(engine):
     for t in (1, 5, eos):
         engine._emit(req, t)
     assert req.done and req.out_tokens == [1, 5, eos]
-    assert seen == [(1, False), (5, False), (eos, True)]
+    # the held 5 flushes with the eos, `done` on that one delivery
+    assert seen == [([1], False), ([5, eos], True)]
 
     req, seen = _stream_req(engine, [(5, 6)], max_new_tokens=2)
     engine._emit(req, 1)
     engine._emit(req, 5)  # budget exhausted: held 5 flushes with done
     assert req.done and req.finish_reason == "length"
-    assert seen == [(1, False), (5, True)]
+    assert seen == [([1], False), ([5], True)]
 
 
 # ------------------------------------------------------- per-request seeds
@@ -617,3 +618,296 @@ def test_drain_tail_chunk_matches_single():
         return sorted(tuple(r.out_tokens) for r in done)
 
     assert run("single") == run("chunk")
+
+
+# ------------------------------------------- a drained chunk, run by run
+
+EOS = 7
+
+
+@pytest.fixture(scope="module")
+def emit_engine():
+    cfg = EngineConfig(
+        model=llama.LlamaConfig.tiny(), max_batch=4, page_size=8,
+        num_pages=64, max_seq_len=64, eos_token_id=EOS,
+    )
+    return InferenceEngine(cfg, seed=0)
+
+
+def _holdback(out, stop_seqs):
+    """Longest suffix of `out` that is a proper prefix of a stop sequence."""
+    return max(
+        (
+            k
+            for seq in stop_seqs
+            for k in range(1, min(len(seq) - 1, len(out)) + 1)
+            if out[-k:] == list(seq[:k])
+        ),
+        default=0,
+    )
+
+
+def _per_token_reference(specs, chunks, aborts):
+    """What a drained chunk means, written out token by token: walk the
+    chunk step by step and slot by slot; a token is appended, counted, may
+    complete a stop sequence (strip it, finish), may be eos, may meet a
+    stop asked for at an earlier token, may use up the budget; whatever the
+    stop-sequence hold-back releases is then streamed, and the text-stop
+    watch (here: "the k-th streamed token") asks for a stop as it sees
+    it. Returns a state per request and the order they finished in."""
+    # the state of one request: `streamed` is the tokens its hook was given
+    state = [
+        dict(
+            out=[], lps=[], tops=[], done=False, reason="", pos=s["pos"],
+            streamed=[], stop_requested=False, counts={}, aborted=False,
+        )
+        for s in specs
+    ]
+    order = []
+    for ci, (toks, lps, ais, avs) in enumerate(chunks):
+        for slot in aborts.get(ci, ()):
+            state[slot]["done"] = state[slot]["aborted"] = True
+        at_chunk_start = [len(st["streamed"]) for st in state]
+        for t in range(len(toks)):
+            for slot, (s, st) in enumerate(zip(specs, state)):
+                if st["done"]:
+                    continue
+                tok = int(toks[t][slot])
+                st["pos"] += 1
+                st["out"].append(tok)
+                st["lps"].append(float(lps[t][slot]))
+                st["tops"].append(
+                    [
+                        (int(i), float(v))
+                        for i, v in zip(ais[t][slot], avs[t][slot])
+                    ]
+                    if s.get("want_top")
+                    else []
+                )
+                st["counts"][tok] = st["counts"].get(tok, 0) + 1
+                for seq in s.get("stop_seqs", ()):
+                    if st["out"][-len(seq):] == list(seq):
+                        for key in ("out", "lps", "tops"):
+                            del st[key][-len(seq):]
+                        st["done"], st["reason"] = True, "stop"
+                        break
+                if not st["done"]:
+                    if st["stop_requested"] or (
+                        tok == EOS and not s.get("ignore_eos")
+                    ):
+                        st["done"], st["reason"] = True, "stop"
+                    elif len(st["out"]) >= s["max_new"]:
+                        st["done"], st["reason"] = True, "length"
+                safe = len(st["out"])
+                if not st["done"]:
+                    safe -= _holdback(st["out"], s.get("stop_seqs", ()))
+                for tok in st["out"][len(st["streamed"]):safe]:
+                    st["streamed"].append(tok)
+                    if len(st["streamed"]) == s.get("watch_stops_at"):
+                        st["stop_requested"] = True
+                if st["done"]:
+                    order.append(slot)
+                    # a stop sequence that strips all that was held back
+                    # over a chunk's end leaves nothing to stream with
+                    # the finish
+                    st["finish_streamed"] = safe > at_chunk_start[slot]
+    return state, order
+
+
+def _chunk(rows, topk):
+    """[T][slots] token rows -> (toks, lps, ais, avs) as a chunk's fetch
+    gives them; logprobs and alternatives are made from the token."""
+    toks = np.array(rows, dtype=np.int32)
+    lps = -(toks.astype(np.float32) + 0.5) / 8
+    ais = (toks[:, :, None] + np.arange(topk, dtype=np.int32)) % 97
+    avs = lps[:, :, None] - np.arange(topk, dtype=np.float32)
+    return toks, lps, ais, avs
+
+
+EMIT_CHUNK_CASES = {
+    # slot 0 has 5 tokens of budget and stops at the chunk's fifth step;
+    # slot 1 takes all eight
+    "budget_ends_mid_chunk": dict(
+        specs=[dict(max_new=5), dict(max_new=30)],
+        chunks=[[[20 + t, 40 + t] for t in range(8)]],
+    ),
+    "eos_mid_chunk": dict(
+        specs=[dict(max_new=30), dict(max_new=30)],
+        chunks=[[[20, 40], [21, 41], [EOS, 42], [23, 43], [24, EOS],
+                 [25, 45], [26, 46], [27, 47]]],
+    ),
+    "eos_mid_chunk_ignored": dict(
+        specs=[dict(max_new=30, ignore_eos=True), dict(max_new=6, ignore_eos=True)],
+        chunks=[[[20, 40], [21, 41], [EOS, 42], [23, 43], [24, EOS],
+                 [25, EOS], [26, 46], [27, 47]]],
+    ),
+    # (5, 6) completes at step 4: 5 was held back, both are stripped, the
+    # rest of the chunk is not the request's
+    "stop_sequence_mid_chunk": dict(
+        specs=[dict(max_new=30, stop_seqs=[(5, 6)]), dict(max_new=30)],
+        chunks=[[[20, 40], [5, 41], [22, 42], [5, 43], [6, 44],
+                 [25, 45], [26, 46], [27, 47]]],
+    ),
+    # the first chunk ends on 5, 5 (held back over the boundary); the
+    # second begins with 6: (5, 5, 6) is stripped from what chunk one gave
+    "stop_sequence_straddles_two_chunks": dict(
+        specs=[dict(max_new=30, stop_seqs=[(5, 5, 6), (9, 9)]), dict(max_new=30)],
+        chunks=[
+            [[20, 40], [9, 41], [22, 42], [23, 43], [5, 44], [24, 45],
+             [5, 46], [5, 47]],
+            [[6, 50], [30, 51], [31, 52], [32, 53], [33, 54], [34, 55],
+             [35, 56], [36, 57]],
+        ],
+    ),
+    # the watch asks for the stop at the third streamed token: the request
+    # ends at the fourth ("stop"), which it keeps
+    "stop_requested_by_watch_at_token_3_of_8": dict(
+        specs=[dict(max_new=30, watch_stops_at=3), dict(max_new=30)],
+        chunks=[[[20 + t, 40 + t] for t in range(8)]],
+    ),
+    # the watch sees tokens as the hold-back releases them: 5 is held at
+    # step 3, released with 23 at step 4 as the third streamed token
+    "watch_behind_a_stop_sequence_holdback": dict(
+        specs=[dict(max_new=30, watch_stops_at=3, stop_seqs=[(5, 6)])],
+        chunks=[[[20], [21], [5], [23], [24], [25], [26], [27]]],
+    ),
+    # asked for at the chunk's last token: the next chunk's first ends it
+    "watch_at_the_chunk_s_last_token": dict(
+        specs=[dict(max_new=30, watch_stops_at=8)],
+        chunks=[[[20 + t] for t in range(8)], [[30 + t] for t in range(8)]],
+    ),
+    "want_top_logprobs": dict(
+        specs=[dict(max_new=6, want_top=True), dict(max_new=30),
+               dict(max_new=30, want_top=True, stop_seqs=[(43, 44)])],
+        chunks=[[[20 + t, 30 + t, 40 + t] for t in range(8)]],
+    ),
+    # slot 1 is aborted after the dispatch: its column is frozen repeats
+    "aborted_between_dispatch_and_drain": dict(
+        specs=[dict(max_new=30), dict(max_new=30), dict(max_new=3)],
+        chunks=[[[20 + t, 40 + t, 60 + t] for t in range(8)],
+                [[30 + t, 47, 67] for t in range(8)]],
+        aborts={1: [1]},
+    ),
+    # slots 2, 0, 1 finish at steps 2, 4, 4: retires wait, in that order
+    "defer_retire": dict(
+        specs=[dict(max_new=4), dict(max_new=30), dict(max_new=2),
+               dict(max_new=30)],
+        chunks=[[[20, 40, 60, 80], [21, 41, 61, 81], [22, 42, 62, 82],
+                 [23, EOS, 63, 83], [24, 44, 64, 84], [25, 45, 65, 85],
+                 [26, 46, 66, 86], [27, 47, 67, 87]]],
+        defer_retire=True,
+    ),
+    # the hook fails on its second call: nothing of that run counts as
+    # delivered, and the next delivery brings it again with the new run
+    "hook_raises_on_its_second_delivery": dict(
+        specs=[dict(max_new=30)],
+        chunks=[[[20 + t] for t in range(8)], [[30 + t] for t in range(8)],
+                [[40 + t] for t in range(8)]],
+        hook_raises_on=2,
+    ),
+}
+
+
+@pytest.mark.parametrize("name", sorted(EMIT_CHUNK_CASES))
+def test_emit_chunk_matches_per_token_semantics(emit_engine, name):
+    """`_emit_chunk` emits a drained chunk one run a request; what every
+    request ends up with is what the walk token by token gives."""
+    from llm_d_fast_model_actuation_tpu.engine.engine import Request
+
+    eng = emit_engine
+    case = EMIT_CHUNK_CASES[name]
+    specs = [dict(pos=10 + 3 * i, **s) for i, s in enumerate(case["specs"])]
+    topk = eng.cfg.logprobs_topk
+    chunks = [_chunk(rows, topk) for rows in case["chunks"]]
+    aborts = case.get("aborts", {})
+    want, want_order = _per_token_reference(specs, chunks, aborts)
+
+    deliveries = [[] for _ in specs]  # (run, done) in the order delivered
+    calls = [0]
+
+    def hook(req, run):
+        calls[0] += 1
+        if calls[0] == case.get("hook_raises_on"):
+            raise RuntimeError("client went away")
+        deliveries[req.seq_id].append((list(run), req.done))
+
+    reqs = []
+    for slot, s in enumerate(specs):
+        seen = []
+        req = Request(
+            seq_id=slot, prompt=[1], max_new_tokens=s["max_new"],
+            stop_seqs=tuple(s.get("stop_seqs", ())),
+            ignore_eos=bool(s.get("ignore_eos")),
+            want_top_logprobs=bool(s.get("want_top")),
+            on_tokens=hook,
+        )
+        if "watch_stops_at" in s:
+            def watch(tok, seen=seen, at=s["watch_stops_at"]):
+                seen.append(tok)
+                return len(seen) == at
+
+            req.stop_watch = watch
+        req.slot, req.pos = slot, s["pos"]
+        eng._slots[slot] = req
+        eng._token_counts[slot] = 0
+        reqs.append(req)
+    finished = []
+    raised = 0
+    try:
+        for ci, (toks, lps, ais, avs) in enumerate(chunks):
+            for slot in aborts.get(ci, ()):
+                assert eng.abort(slot)
+            # what the dispatch saw as running: aborted slots among them
+            live = {
+                s: r for s, r in enumerate(reqs)
+                if not r.done or s in aborts.get(ci, ())
+            }
+            try:
+                finished += eng._emit_chunk(
+                    toks, lps, avs, ais, None, live,
+                    case.get("defer_retire", False),
+                )
+            except RuntimeError:
+                raised += 1
+        for slot, st in enumerate(want):
+            if not st["done"]:
+                # a live slot's mirrors are what the next upload sends
+                assert eng._positions[slot] == st["pos"]
+                assert eng._last_tokens[slot] == st["out"][-1]
+                assert eng._budgets[slot] == (
+                    specs[slot]["max_new"] - len(st["out"])
+                )
+                assert {
+                    int(t): int(eng._token_counts[slot, t])
+                    for t in np.nonzero(eng._token_counts[slot])[0]
+                } == st["counts"]
+        if case.get("defer_retire"):
+            assert [r.seq_id for r in eng._pending_retire] == want_order
+            assert all(eng._slots[s] is reqs[s] for s in want_order)
+            assert all(eng._budgets[s] == 0 for s in want_order) and eng._dirty
+        assert raised == (1 if "hook_raises_on" in case else 0)
+        assert [r.seq_id for r in finished] == want_order
+        for req, st, got in zip(reqs, want, deliveries):
+            assert req.out_tokens == st["out"]
+            assert req.out_logprobs == st["lps"]
+            assert req.out_top_logprobs == st["tops"]
+            assert req.pos == st["pos"]
+            if st["aborted"]:
+                assert req.done and req.error == "aborted"
+                continue
+            assert (req.done, req.finish_reason) == (st["done"], st["reason"])
+            assert req.stop_requested == st["stop_requested"]
+            # every streamed token once and in order, one delivery a chunk
+            # at most, `done` on the delivery that holds the last token
+            assert [t for run, _ in got for t in run] == st["streamed"]
+            assert req.streamed == len(st["streamed"])
+            assert len(got) <= len(chunks)
+            assert [d for _, d in got] == [False] * (len(got) - 1) + (
+                [st["done"] and st["finish_streamed"]] if got else []
+            )
+    finally:
+        eng._pending_retire = []
+        for req in reqs:
+            if req.slot >= 0:
+                eng._retire(req)
+        eng._dirty = True

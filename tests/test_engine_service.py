@@ -171,7 +171,7 @@ def test_streaming_completion_delivers_every_token(service):
         # token chunks carry choices; the final usage chunk (OpenAI
         # include_usage shape: empty choices) closes the stream
         tok_events = [e for e in events if e.get("choices")]
-        toks = [e["choices"][0]["token_ids"][0] for e in tok_events]
+        toks = [t for e in tok_events for t in e["choices"][0]["token_ids"]]
         assert len(toks) == 5
         tails = [e for e in events if not e.get("choices")]
         assert len(tails) == 1 and events[-1] is tails[0]
@@ -187,6 +187,115 @@ def test_streaming_completion_delivers_every_token(service):
         assert body["choices"][0]["token_ids"] == toks
 
     run_async(_client(service, scenario))
+
+
+def test_streamed_events_carry_one_run_a_chunk():
+    """A streamed completion gets one event for its first token and one
+    for every decode chunk after, each carrying that chunk's ids; the ids
+    in order are the non-streamed output; `done` only on the last; the
+    scheduler counts one hook delivery an event."""
+    svc = EngineService(parse_engine_options(
+        "--model tiny --num-pages 32 --page-size 8 --max-batch 2 "
+        "--max-model-len 64 --decode-chunk 4"
+    ))
+
+    async def scenario(client):
+        before = (await (await client.get("/v1/stats")).json())["scheduler"]
+        r = await client.post(
+            "/v1/completions",
+            json={"prompt": [1, 2, 3], "max_tokens": 13, "stream": True,
+                  "ignore_eos": True},
+        )
+        assert r.status == 200
+        events, done = await _read_sse(r)
+        assert done
+        choices = [e["choices"][0] for e in events if e.get("choices")]
+        runs = [c["token_ids"] for c in choices]
+        # the first token is its own event (ttft is what it was), then
+        # twelve tokens in three chunks of four
+        assert [len(run) for run in runs] == [1, 4, 4, 4]
+        # the writer saw `done` on the last run and on no earlier one: the
+        # usage event (written only after a `done`) follows all four
+        assert [bool(e.get("choices")) for e in events] == [True] * 4 + [False]
+        assert events[-1]["usage"]["completion_tokens"] == 13
+        # the text an event holds back (bytes that are no character yet)
+        # comes with a later one: in all, the decode of all the ids
+        assert "".join(c["text"] for c in choices) == svc_tok.decode(
+            [t for run in runs for t in run]
+        )
+        after = (await (await client.get("/v1/stats")).json())["scheduler"]
+        assert after["emit_deliveries"] - before["emit_deliveries"] == 4
+        assert after["emit_tokens"] - before["emit_tokens"] == 13
+
+        r2 = await client.post(
+            "/v1/completions",
+            json={"prompt": [1, 2, 3], "max_tokens": 13, "ignore_eos": True},
+        )
+        body = await r2.json()
+        assert body["choices"][0]["token_ids"] == [t for run in runs for t in run]
+        # a request nobody streams makes no delivery
+        last = (await (await client.get("/v1/stats")).json())["scheduler"]
+        assert last["emit_deliveries"] == after["emit_deliveries"]
+
+    from llm_d_fast_model_actuation_tpu.engine.tokenizer import ByteTokenizer
+
+    svc_tok = ByteTokenizer()
+    try:
+        run_async(_client(svc, scenario))
+    finally:
+        svc.shutdown()
+
+
+def test_mailbox_loses_nothing_and_wakes_the_loop_less_than_once_an_item():
+    """Threads post runs for their own queues while the loop drains: every
+    queue gets all of its items, in order, and the loop was woken fewer
+    times than items were posted (a burst shares one wake-up)."""
+    import sys
+    import threading
+
+    from llm_d_fast_model_actuation_tpu.engine.server import _Mailbox
+
+    posters, items = 12, 400
+
+    async def scenario():
+        loop = asyncio.get_running_loop()
+        box = _Mailbox()
+        wakes = [0]
+        drain = box._drain
+
+        def counting_drain():
+            wakes[0] += 1
+            drain()
+
+        box._drain = counting_drain
+        queues = [asyncio.Queue() for _ in range(posters)]
+
+        def post_all(q):
+            for i in range(items):
+                box.post(loop, q, i)
+
+        threads = [
+            threading.Thread(target=post_all, args=(q,)) for q in queues
+        ]
+        for t in threads:
+            t.start()
+        got = [
+            [await asyncio.wait_for(q.get(), timeout=30) for _ in range(items)]
+            for q in queues
+        ]
+        for t in threads:
+            t.join(timeout=30)
+            assert not t.is_alive()
+        assert got == [list(range(items))] * posters
+        assert all(q.empty() for q in queues) and not box._items
+        assert 1 <= wakes[0] < posters * items
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        run_async(scenario())
+    finally:
+        sys.setswitchinterval(old)
 
 
 def test_streaming_submit_error_is_sse_error_event(service):

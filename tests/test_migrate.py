@@ -106,20 +106,20 @@ def _balance(svc: EngineService) -> None:
 
 
 def _live_stream(svc: EngineService, prompt, max_tokens=8, **kw):
-    """A stream that is provably mid-decode at export time: on_token
+    """A stream that is provably mid-decode at export time: on_tokens
     runs inline in the decode loop, so the sleep throttles the whole
     batch while the export parks it."""
     toks: list = []
     started = threading.Event()
 
-    def slow(req, tok):
-        toks.append(tok)
+    def slow(req, run):
+        toks.extend(run)
         started.set()
-        time.sleep(0.05)
+        time.sleep(0.05 * len(run))
 
     fut = svc.submit(
         list(prompt), max_tokens, kw.pop("temperature", 0.0),
-        on_token=slow, **kw,
+        on_tokens=slow, **kw,
     )
     assert started.wait(timeout=60), "stream never produced a token"
     return fut, toks

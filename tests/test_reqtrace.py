@@ -168,18 +168,18 @@ def _wire(src: EngineService, dst: EngineService) -> None:
 
 def _live_stream(svc: EngineService, prompt, max_tokens=8, **kw):
     """A stream provably mid-decode at export time (test_migrate's
-    idiom): the inline on_token sleep throttles the batch."""
+    idiom): the inline on_tokens sleep throttles the batch."""
     toks: list = []
     started = threading.Event()
 
-    def slow(req, tok):
-        toks.append(tok)
+    def slow(req, run):
+        toks.extend(run)
         started.set()
-        time.sleep(0.05)
+        time.sleep(0.05 * len(run))
 
     fut = svc.submit(
         list(prompt), max_tokens, kw.pop("temperature", 0.0),
-        on_token=slow, **kw,
+        on_tokens=slow, **kw,
     )
     assert started.wait(timeout=60), "stream never produced a token"
     return fut, toks

@@ -174,6 +174,67 @@ def test_capture_flag_leaves_one_span_and_one_annotation_per_phase(service):
     assert _sched(service)["phase_n"]["emit"] > 0
 
 
+def test_emit_counts_one_delivery_a_request_a_chunk(service):
+    """`emit_deliveries` counts calls of the streaming hook, `emit_tokens`
+    what they carried: the first token alone, then one run a drained
+    chunk; a chunk's `sched.emit` span says how many it made."""
+    runs = []
+    before = _sched(service)
+    with mock.patch("jax.profiler.TraceAnnotation"):
+        tracing.capture_started()
+        futs = [
+            service.submit(
+                PROMPT, 21, 0.0, on_tokens=lambda r, run: runs.append(len(run))
+            ),
+            service.submit(PROMPT, 21, 0.0),  # nobody listens: no delivery
+        ]
+        for f in futs:
+            assert len(f.result(timeout=120).out_tokens) == 21
+        tracing.capture_stopped()
+    after = _sched(service)
+    # 1 + 20 tokens in five chunks of --decode-chunk 4
+    assert runs == [1, 4, 4, 4, 4, 4]
+    assert after["emit_deliveries"] - before["emit_deliveries"] == 6
+    assert after["emit_tokens"] - before["emit_tokens"] == 21
+    chunk_emits = [
+        s.attrs for s in tracing.snapshot()
+        if s.name == "sched.emit" and "deliveries" in s.attrs
+    ]
+    assert sum(a["deliveries"] for a in chunk_emits) == 5
+    assert sum(a["tokens"] for a in chunk_emits) == 40
+
+
+@pytest.mark.parametrize(
+    "name, cell",
+    [
+        ("sched_emit_s.batch", "mixtral-8x7b.batch"),
+        ("sched_emit_s.longmix", "smallthinker-21b.longmix"),
+    ],
+)
+def test_sched_emit_metric_reads_the_emit_phase_of_its_cell(name, cell):
+    """The benchmark's `sched_emit_s.*` are data files that read this
+    module's `phase_s.emit`, close less open, in the one cell they name."""
+    from fmabench import readers, spec
+
+    bench = spec.benchmark()
+    rows = {m["name"]: m for m in spec.Cell(bench, cell).per_layer()}
+    assert rows[name]["layer"] == "scheduler"
+    assert rows[name]["moves"] == "out_tokens_per_s"
+    assert rows[name]["workloads"] == [cell]
+    ev = readers.Evidence()
+    ev.stats_open = {"scheduler": {"phase_s": {"emit": 0.5, "upload": 1.0}}}
+    ev.stats_close = {"scheduler": {"phase_s": {"emit": 1.75, "upload": 9.0}}}
+    assert readers.read_metric(rows[name]["reader"], ev) == 1.25
+    # a program with no such phase gives nothing, not a zero
+    ev.stats_open = ev.stats_close = {}
+    assert readers.read_metric(rows[name]["reader"], ev) is None
+    for w in bench["workloads"]:
+        if w["name"] != cell:
+            assert name not in {
+                m["name"] for m in spec.Cell(bench, w["name"]).per_layer()
+            }
+
+
 def _run_client(app, scenario):
     import asyncio
 

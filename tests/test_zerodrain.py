@@ -261,9 +261,9 @@ def zd_service():
 
 
 def _slow_stream(seen, delay=0.03):
-    def cb(req, tok):
-        seen.append(tok)
-        time.sleep(delay)
+    def cb(req, run):
+        seen.extend(run)
+        time.sleep(delay * len(run))
 
     return cb
 
@@ -273,7 +273,7 @@ def _live_request(svc, prompt=(1, 2, 3, 4), max_tokens=24, min_tokens=3):
     (the throttle keeps it live while the admin verb takes the lock)."""
     seen: list = []
     fut = svc.submit(
-        list(prompt), max_tokens, 0.0, on_token=_slow_stream(seen)
+        list(prompt), max_tokens, 0.0, on_tokens=_slow_stream(seen)
     )
     deadline = time.time() + 60
     while len(seen) < min_tokens and time.time() < deadline:
