@@ -3,6 +3,7 @@
 PYTHON ?= python
 IMAGE_REGISTRY ?= ghcr.io/example
 IMAGE_TAG ?= latest
+CELL ?= mistral-7b.chat
 
 .PHONY: test test-fast native bench lint images dryrun chip-smoke clean
 
@@ -17,10 +18,13 @@ test-fast:
 native:
 	$(MAKE) -C native
 
+# the benchmark (BENCHMARK.json): one untraced run of one cell, on the chip
+# (run through the chip tool; fails without a TPU). The record is
+# PERF_LEDGER.jsonl and PERF.md
 bench:
-	$(PYTHON) bench.py
+	$(PYTHON) -m fmabench --workload $(CELL) --seed 1 --seconds 50 --trace 0
 
-# simulated actuation benchmark (no cluster, no TPU)
+# the control plane's T_actuation harness, simulated (no cluster, no TPU)
 bench-actuation:
 	$(PYTHON) -m llm_d_fast_model_actuation_tpu.benchmark --scenario all
 

@@ -6,23 +6,13 @@ baseline / scaling / new-variant scenarios, in `simulated` mode (in-memory
 control plane + latency-injected fakes) or against a live stack.
 """
 
-from .fleet import (
-    Arrival,
-    FleetTrafficConfig,
-    generate_arrivals,
-    trace_digest,
-)
 from .harness import ActuationBenchmark, BenchmarkConfig
 from .scenarios import run_baseline, run_new_variant, run_scaling
 
 __all__ = [
     "ActuationBenchmark",
-    "Arrival",
     "BenchmarkConfig",
-    "FleetTrafficConfig",
-    "generate_arrivals",
     "run_baseline",
     "run_scaling",
     "run_new_variant",
-    "trace_digest",
 ]

@@ -1207,11 +1207,10 @@ class InferenceEngine:
         #: upload makes the zeroing moot and clears the set
         self._fresh_slots: set = set()
         #: cumulative host->device scheduler/dispatch bytes per serving
-        #: path (fma_engine_step_h2d_bytes_total; the decode bench's
-        #: step_h2d_bytes_per_tok): "packed" counts mixed-program inputs
-        #: plus every scheduler upload of a packed engine, "bucketed"
-        #: counts the bucketed prefill/suffix/spec dispatch inputs and a
-        #: bucketed engine's scheduler uploads
+        #: path (fma_engine_step_h2d_bytes_total): "packed" counts
+        #: mixed-program inputs plus every scheduler upload of a packed
+        #: engine, "bucketed" counts the bucketed prefill/suffix/spec
+        #: dispatch inputs and a bucketed engine's scheduler uploads
         self.step_h2d_bytes: Dict[str, int] = {"packed": 0, "bucketed": 0}
         #: bytes per padded activation row (pad-waste accounting):
         #: one embedding row of the model dtype
@@ -1222,9 +1221,9 @@ class InferenceEngine:
         #: every computed-but-invalid row of the mixed buffer
         self.pad_waste_bytes: Dict[str, int] = {"packed": 0, "bucketed": 0}
         #: valid-token accounting mirrors for the same two paths (the
-        #: bench's pad_waste_frac denominators)
+        #: denominators of a pad-waste fraction)
         self.dispatch_tokens: Dict[str, int] = {"packed": 0, "bucketed": 0}
-        #: packed-step lifetime counters (observability / bench)
+        #: packed-step lifetime counters (observability)
         self.packed_steps = 0
         self.packed_tokens_total = 0
         #: per-step stats of the most recent step() (None when the step

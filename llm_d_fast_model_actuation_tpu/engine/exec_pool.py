@@ -14,8 +14,7 @@ weight movement off it (docs/perf.md):
     launcher's persistent compile-cache directory, so a pool entry
     survives an instance restart (TPU only by default: the XLA CPU
     backend has produced numerically different executables when
-    deserialized across clients — the same reason the persistent cache is
-    TPU-only in bench.py; set ``FMA_EXEC_SPILL=1`` to force).
+    deserialized across clients; set ``FMA_EXEC_SPILL=1`` to force).
 
   * :class:`WarmupTask` — a background thread that AOT-compiles the
     incoming model's programs via ``jax.jit(...).lower(...).compile()``
@@ -661,8 +660,8 @@ class WarmupTask:
 
     ``overlap_stats(window)`` reports how much of the compile work rode
     under a transfer window — ``hidden_frac`` is compile seconds hidden
-    under transfer ÷ total compile seconds, the headline the swap bench
-    emits as ``overlap_hidden_compile_frac``.
+    under transfer ÷ total compile seconds (the ``warmup`` block of
+    ``GET /v1/swap``).
     """
 
     def __init__(

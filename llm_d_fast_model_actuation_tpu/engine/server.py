@@ -162,7 +162,7 @@ ENGINE_SPEC_ACCEPTED = Gauge(
 
 # SLO / goodput telemetry (docs/perf.md "Fleet benchmarking and goodput"):
 # the request-lifecycle observables the multi-model scheduler (ROADMAP
-# item 1) optimizes and the fleet harness (`bench.py fleet`) reports.
+# item 1) optimizes and a load generator reads from `/v1/stats`.
 # Queue wait separates "sat behind other work / an actuation" from "the
 # prefill itself was slow" inside the existing TTFT histogram.
 ENGINE_QUEUE_WAIT = Histogram(
@@ -2971,8 +2971,9 @@ class EngineService:
                     if r.trace_parent and tracing.enabled():
                         # join the origin trace: same trace_id, spans
                         # parented on the source's lifecycle root.
-                        # Always retained — the bench's shared-trace_id
-                        # acceptance reads both sides' /v1/traces.
+                        # Always retained — a migrated request's spans
+                        # are read from both sides' /v1/traces under one
+                        # trace_id (tests/test_reqtrace.py).
                         r.trace = tracing.RequestTrace(
                             sampled=True,
                             parent=tracing.SpanContext(
@@ -5155,8 +5156,7 @@ class EngineService:
                 "builds_total": self.builds_total,
                 "pool": self.model_pool.describe(),
                 # hidden-compile accounting (None on a slept-runtime pool
-                # hit — its executables rode the pooled engine): what the
-                # bench reports as overlap_hidden_compile_frac
+                # hit — its executables rode the pooled engine)
                 "warmup": warm_stats,
                 "exec_pool": self.exec_pool.describe(),
             }
@@ -5827,9 +5827,9 @@ class EngineService:
 
     def _request_legs(self, req, now: float) -> Dict[str, float]:
         """Decompose submit→done into the leg durations the SLO
-        exemplars (and bench.py's slo_attribution) bucket by. Preemption
-        wall time is INSIDE the raw queue/prefill/decode windows (the
-        stamps don't pause while parked), so it is carved out — the
+        exemplars bucket by. Preemption wall time is INSIDE the raw
+        queue/prefill/decode windows (the stamps don't pause while
+        parked), so it is carved out — the
         pre-first-token share from queue first, then prefill; the rest
         from decode — leaving {queue, prefill, decode, preempt} a
         partition of the request's server-side wall time."""
@@ -6682,7 +6682,7 @@ def _validate_messages(messages: Any) -> List[Dict[str, Any]]:
 def _lifecycle_usage(req: Any) -> Dict[str, Any]:
     """Per-request lifecycle extras for the OpenAI usage block — the
     engine-side measurements an open-loop load harness needs without
-    streaming (`bench.py fleet` reads these): queue wait (submit ->
+    streaming (part of the completions API's reply): queue wait (submit ->
     first scheduled, the leg an actuation stall lands in) and decode
     TPOT (mean inter-token seconds after the first token)."""
     qw = None

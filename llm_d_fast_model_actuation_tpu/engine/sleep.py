@@ -1149,8 +1149,8 @@ def swap_states(
 
     ``overlapped=False`` runs the identical code path on a strictly
     sequential schedule (every outgoing bucket lands before the first
-    incoming one is issued) — the measured apples-to-apples baseline the
-    swap sub-bench compares against (bench.py).
+    incoming one is issued) — the reference tests/test_swap.py and
+    tests/test_quant_swap.py compare the overlapped schedule against.
 
     **Delta-aware** (``out_digests``/``in_digests``, flat weight key ->
     content digest — engine/chunk_store.py): leaves the two models share
@@ -1384,7 +1384,7 @@ def swap_states(
     # multi-threaded parent inherits a single-threaded snapshot whose
     # other-thread lock state is frozen mid-flight, and spawning transfer
     # threads there intermittently aborts the child — the threaded overlap
-    # is a bench-scale concern on this fallback, not a serving-path one.
+    # matters at large transfers on this fallback, not on the serving path.
     import multiprocessing
 
     use_thread = (

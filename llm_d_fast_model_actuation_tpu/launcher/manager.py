@@ -57,7 +57,7 @@ LAUNCHER_RPC_SECONDS = Histogram(
 # goodput / demand aggregates over every live engine child's GET
 # /v1/stats, refreshed by fleet_rollup() on instance-list and /metrics
 # reads — the one-scrape fleet view the multi-model scheduler (ROADMAP
-# item 1) and the fleet bench consume.
+# item 1) consumes.
 LAUNCHER_FLEET_INSTANCES = Gauge(
     "fma_launcher_fleet_instances",
     "Engine instances by stats-poll outcome",

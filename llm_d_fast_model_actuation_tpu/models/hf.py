@@ -479,8 +479,8 @@ def load_params(
         buffer's host copy is freed as its transfer lands.
 
     ``streaming=False`` runs the identical machinery on a strictly
-    sequential schedule (all reads, then all transfers) — the paired
-    baseline ``bench.py coldload`` compares against. ``place=False``
+    sequential schedule (all reads, then all transfers) — the reference
+    tests/test_coldload.py compares against. ``place=False``
     skips device placement entirely and returns the host-staged plain
     (unquantized) numpy tree — the background-prefetch path, which must
     never touch HBM; ``place_staged_params`` is its deferred second half.

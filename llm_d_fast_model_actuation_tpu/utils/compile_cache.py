@@ -3,7 +3,7 @@
 On TPU compile is the larger half of a cold start, and a wake after device
 release re-lowers every program through the persistent XLA cache
 (engine/device.py), so every entry point — engine server, launcher preload,
-bench.py, chip_smoke.py — arms the cache through :func:`arm` and no other
+chip_smoke.py — arms the cache through :func:`arm` and no other
 code names a cache directory:
 
   * ``JAX_COMPILATION_CACHE_DIR`` set: that directory, and nothing else is

@@ -92,9 +92,9 @@ def _service(ckpt_dir: str, extra: str = "--resident-variants 3"):
 
 
 def _pool_siblings(svc, dirs):
-    """Swap through each sibling and back to the base — the pre-warm the
-    fleet bench does, leaving every sibling pooled (slept, digests known)
-    so attach resolves from the ``pool`` tier."""
+    """Swap through each sibling and back to the base, leaving every
+    sibling pooled (slept, digests known) so attach resolves from the
+    ``pool`` tier."""
     for d in dirs[1:]:
         svc.swap("tiny", checkpoint_dir=d)
     svc.swap("tiny", checkpoint_dir=dirs[0])

@@ -6,7 +6,7 @@ a second process's client init blocks until the first exits). Release-mode
 sleep destroys the client (`engine/device.py`); these tests exercise the
 full state machine on the CPU backend (whose client supports the same
 destroy/re-create cycle), and the real-chip exclusivity handoff is driven by
-`bench.py`'s time-share phase on TPU hardware.
+`chip_smoke.py` on TPU hardware.
 
 Reference contract: a slept server frees the accelerator for another server
 (docs/dual-pods.md:20-56; sleep actuation inference-server.go:1710-1718).

@@ -8,7 +8,7 @@ read back — the engine's /v1/stats and /metrics, and the launcher's
 GET /v2/vllm/instances ``fleet`` block and fma_launcher_fleet_* gauges.
 
 Marked ``slow`` (on top of ``e2e``): the timeout-bound tier-1 sweep skips
-it; CI's e2e job and the `bench.py fleet` sanity step cover the path.
+it; CI's e2e job covers the path.
 """
 
 import os

@@ -146,7 +146,7 @@ def test_debug_server_endpoints():
 
 # ---------------------------------------------------------------------------
 # Request-lifecycle SLO/goodput telemetry (engine/server.py; docs/perf.md
-# "Fleet benchmarking and goodput"): what `bench.py fleet` and the
+# "Fleet benchmarking and goodput"): what a load generator and the
 # launcher's fleet rollup consume. Exposition-level asserts: the numbers
 # must land in the actual Prometheus samples, not just internal state.
 # ---------------------------------------------------------------------------
@@ -455,7 +455,7 @@ def test_swap_abort_attribution_and_stale_series():
 # ---------------------------------------------------------------------------
 # Launcher fleet rollup (launcher/manager.py): aggregation + gauges,
 # with the engine polls faked — the live path is covered by the fleet
-# e2e (tests/test_fleet.py) and the CI `bench.py fleet` sanity step.
+# e2e (tests/test_fleet.py).
 # ---------------------------------------------------------------------------
 
 
@@ -574,56 +574,3 @@ def test_fleet_rollup_aggregates_and_mirrors_gauges(
     # default instance reads stay fleet-free (the notifier's lister runs
     # on the event loop and must never block on child polls)
     assert "fleet" not in manager.get_all_instances_status()
-
-
-# ---------------------------------------------------------------------------
-# Fleet arrival generator (benchmark/fleet.py): seeded determinism — the
-# contract the CI sanity step and cross-run comparisons rest on.
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.fleet
-def test_fleet_arrival_generator_seeded_determinism():
-    from llm_d_fast_model_actuation_tpu.benchmark import fleet
-
-    cfg = fleet.FleetTrafficConfig(seed=7, duration_s=20.0, num_models=4)
-    a = fleet.generate_arrivals(cfg)
-    b = fleet.generate_arrivals(cfg)
-    assert a == b  # identical trace, element for element
-    assert fleet.trace_digest(a) == fleet.trace_digest(b)
-    assert fleet.generate_arrivals(
-        fleet.FleetTrafficConfig(seed=8, duration_s=20.0, num_models=4)
-    ) != a
-
-    assert all(0 <= x.t_s < cfg.duration_s for x in a)
-    assert all(x.t_s <= y.t_s for x, y in zip(a, a[1:]))  # time-ordered
-    assert all(0 <= x.model < 4 for x in a)
-    assert all(
-        cfg.prompt_len_min <= len(x.prompt) <= cfg.prompt_len_max
-        for x in a
-    )
-    assert all(1 <= t < cfg.vocab for x in a for t in x.prompt)
-
-    # Zipf skew: the head model out-draws the tail model
-    from collections import Counter
-
-    by_model = Counter(x.model for x in a)
-    assert by_model[0] > by_model[3]
-
-
-@pytest.mark.fleet
-def test_fleet_traffic_config_validation():
-    from llm_d_fast_model_actuation_tpu.benchmark import fleet
-
-    with pytest.raises(ValueError):
-        fleet.generate_arrivals(fleet.FleetTrafficConfig(num_models=0))
-    with pytest.raises(ValueError):
-        fleet.generate_arrivals(fleet.FleetTrafficConfig(duration_s=0))
-    with pytest.raises(ValueError):
-        fleet.generate_arrivals(
-            fleet.FleetTrafficConfig(burst_hot_frac=1.5)
-        )
-    with pytest.raises(ValueError):
-        fleet.generate_arrivals(
-            fleet.FleetTrafficConfig(prompt_len_min=0)
-        )

@@ -537,6 +537,36 @@ def test_service_quantized_swap_cycle_bytes_and_numerics():
         q.shutdown()
 
 
+def test_service_int8_hot_head_off_bytes_drift_and_price():
+    """Hot head off, the most bytes int8 saves: a pool-hit swap moves
+    under 0.6x its full-precision bytes, the oracle priced exactly the
+    bytes that crossed (tier "pool"), and the logprobs of the same greedy
+    tokens stay within the documented 0.25 of the full-precision ones."""
+    q = _service("--sleep-quant int8 --sleep-quant-hot-head off")
+    try:
+        gold = q.submit([1, 2, 3], 4, 0.0).result(timeout=120)
+        q.swap("tiny-gemma")  # parks tiny quantized
+        q.swap("tiny")  # the first cycle primes the bandwidth EWMAs
+        q.swap("tiny-gemma")
+        pred = q.price_swap("tiny")
+        out = q.swap("tiny")
+        assert out["quant"] == "int8" and out["pool_hit"]
+        assert out["bytes_moved"] < 0.6 * out["bytes_full"], out
+        assert pred["tier"] == "pool" and pred["measured"] is True
+        assert pred["predicted_bytes"] == out["bytes_moved"]
+        rec = out["costs"]
+        assert rec["predicted_bytes"] == rec["actual_bytes"], rec
+        got = q.submit([1, 2, 3], 4, 0.0).result(timeout=120)
+        assert got.out_tokens == gold.out_tokens
+        assert len(got.out_logprobs) == len(gold.out_logprobs) == 4
+        drift = max(
+            abs(a - b) for a, b in zip(got.out_logprobs, gold.out_logprobs)
+        )
+        assert drift < 0.25, drift
+    finally:
+        q.shutdown()
+
+
 def test_service_quant_metrics_and_pool_accounting():
     q = _service("--sleep-quant int8 --sleep-quant-hot-head off")
     try:

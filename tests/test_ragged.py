@@ -515,20 +515,30 @@ def test_packed_bias_admission_mid_stream_exact():
     assert run(True) == run(False)
 
 
-def test_packed_steady_state_h2d_o_rows():
+@pytest.mark.parametrize("tp", [1, 2])
+def test_packed_steady_state_h2d_o_rows(tp):
     """The headline: steady-state packed decode moves O(rows) H2D per
     step — no [max_batch, vocab] mirror re-upload. With a vocab big
     enough to dominate, the packed path's per-step bytes must be at
     least 10x below what per-step mirror re-uploads (the pre-device-
     resident behavior, and what admission-heavy bucketed serving still
-    pays) would cost."""
+    pays) would cost. The same count holds on a tp=2 mesh, where the
+    scheduler arrays are replicated."""
+    from llm_d_fast_model_actuation_tpu.parallel.mesh import (
+        MeshPlan,
+        make_mesh,
+    )
+
+    mesh = (
+        make_mesh(MeshPlan(dp=1, tp=2), jax.devices()[:2]) if tp == 2 else None
+    )
     model = llama.LlamaConfig.tiny(vocab=4096)
     cfg = EngineConfig(
         model=model, max_batch=4, page_size=8, num_pages=64,
         max_seq_len=128, packed_serving=True, token_budget=96,
         prefix_caching=False,
     )
-    eng = InferenceEngine(cfg, seed=0)
+    eng = InferenceEngine(cfg, mesh=mesh, seed=0)
     prompts = [[i + 1, i + 2, i + 3, i + 4, i + 5] for i in range(4)]
     eng.generate(prompts, max_new_tokens=4)  # warm + first full upload
     eng.step_h2d_bytes = {"packed": 0, "bucketed": 0}

@@ -334,6 +334,30 @@ def test_swap_records_device_transfer_spans(swap_service):
 
 
 @pytest.mark.tracing
+def test_traced_swap_exports_chrome_json_with_transfer_children(swap_service):
+    """The artifact an operator loads in Perfetto: a pool-hit swap's tree
+    exported as Chrome trace-event JSON, every event complete ("X") and of
+    one trace, swap.d2h / swap.h2d under swap.transfer with their bytes."""
+    svc = swap_service
+    with tracing.span("test.root") as root:
+        svc.swap("tiny-gemma")
+        svc.swap("tiny")  # pool hit: chunked two-direction transfer
+    status, body, _ = tracing.export_http("chrome", trace_id=root.trace_id)
+    assert status == 200
+    evs = json.loads(body)["traceEvents"]
+    assert evs
+    for e in evs:
+        assert {"name", "ph", "ts", "dur", "pid", "tid", "args"} <= set(e)
+        assert e["ph"] == "X" and e["args"]["trace_id"] == root.trace_id
+    by_span = {e["args"]["span_id"]: e for e in evs}
+    moved = [e for e in evs if e["name"] in ("swap.d2h", "swap.h2d")]
+    assert {e["name"] for e in moved} == {"swap.d2h", "swap.h2d"}
+    for e in moved:
+        assert by_span[e["args"]["parent_id"]]["name"] == "swap.transfer"
+        assert e["args"]["bytes"] > 0
+
+
+@pytest.mark.tracing
 def test_disabled_tracing_records_nothing_on_swap(swap_service):
     svc = swap_service
     tracing.disable()

@@ -494,6 +494,7 @@ def test_zero_drain_off_is_inert():
             "preempted": 0,
             "resumed": 0,
             "aborted": 0,
+            "migrated": 0,
             "parked_kv_bytes": 0,
         }
     finally:
