@@ -44,12 +44,6 @@ from ..ops.rope import rope_table
 from . import llama, moe
 from .quant import qmat
 
-#: pages a step of the decode kernel's walk reads together: this family's
-#: contexts run to 13k tokens (832 pages of 16), where a page a step is
-#: bound by DMA latency (ops/pallas/decode.py). The families of
-#: models/llama.py keep the page-a-step walk their cells were measured with.
-DECODE_BLOCK_PAGES = 8
-
 #: query rows the XLA suffix attention scores at a time: a 1,024-row
 #: segment against a 16k-token table row would otherwise hold 1.9 GB of
 #: float32 scores
@@ -336,13 +330,12 @@ def decode_step(
                         q, kr, vr, k, v, rtable, positions,
                         pi * n_window + nth, impl=cfg.attention_impl,
                         mesh=mesh, window=window,
-                        block_pages=DECODE_BLOCK_PAGES,
                     )
                 else:
                     attn = paged_decode_attention_inline(
                         q, kp, vp, k, v, gtable, positions,
                         pi * n_global + nth, impl=cfg.attention_impl,
-                        mesh=mesh, block_pages=DECODE_BLOCK_PAGES,
+                        mesh=mesh,
                     )
                 x = x + qmat(attn.reshape(b, cfg.q_dim), lp["wo"])
             h = llama._norm(cfg, x, lp["mlp_norm"])

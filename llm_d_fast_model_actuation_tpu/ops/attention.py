@@ -182,7 +182,6 @@ def paged_decode_attention_inline(
     impl: "str | None" = None,
     mesh=None,  # tp mesh: the pallas impl runs under shard_map
     window: int = 0,  # > 0: sliding window; table rows are rings
-    block_pages: int = 1,  # pallas: pages a step of the walk reads together
 ) -> jnp.ndarray:
     """Decode attention where the new token's K/V are passed *inline* instead
     of having been scattered into the cache first.
@@ -196,7 +195,8 @@ def paged_decode_attention_inline(
 
     GQA is handled by *grouping* query heads [b, kvh, group, d] — no
     materialized `repeat` of K/V, matmuls run bf16 on the MXU with fp32
-    accumulation.
+    accumulation. The pallas kernel walks the pages a 128-token tile a step
+    for every caller (ops/pallas/decode.py:decode_block_pages).
     """
     if (impl or _IMPL) == "pallas":
         from .pallas import paged_decode_attention_inline_pallas
@@ -206,7 +206,6 @@ def paged_decode_attention_inline(
             paged_decode_attention_inline_pallas,
             interpret=_pallas_interpret(),
             **({"window": window} if window else {}),
-            **({"block_pages": block_pages} if block_pages > 1 else {}),
         )
         return shard_over_tp(
             mesh, kernel,

@@ -41,6 +41,19 @@ NEG_INF = -1e30
 #: TPU lane width: the fused kv_heads*head_dim page row must fill whole lanes.
 LANES = 128
 
+#: tokens a step of the inline decode kernel's walk holds in one VMEM tile
+DECODE_TILE_TOKENS = 128
+
+
+def decode_block_pages(page_size: int) -> int:
+    """Pages a step of the inline decode kernel's walk keeps in flight: as
+    many as fill a 128-token tile of the pool it is handed (16-token pages:
+    8). At a page a step the walk is bound by the latency of one page's DMA
+    and a step's fixed cost, not by bytes, at every context a cell has
+    (PERF.md section 6, PRs 28 and 31); a context shorter than a tile costs
+    at most the spare pages of one step, read again and masked."""
+    return max(1, DECODE_TILE_TOKENS // page_size)
+
 
 def pallas_shape_ok(num_kv_heads: int, head_dim: int) -> bool:
     """Can Mosaic compile the paged kernels (decode and ragged) for this
@@ -107,9 +120,10 @@ def _decode_kernel(
     With ``block_pages`` > 1 a step of the walk is that many pages: their
     DMAs are in flight together, each into its own rows of one
     ``[block_pages * page_size, ...]`` tile, and the softmax update runs
-    once over the tile. A context of thousands of tokens is hundreds of
+    once over the tile. A context of a thousand tokens is scores of
     16-token pages; at one page a step the walk is bound by the latency of
-    a 16 KB DMA and the fixed cost of a step, not by bytes."""
+    a 16 KB DMA and the fixed cost of a step, not by bytes. The serving
+    path's value is :func:`decode_block_pages`."""
     if inline:
         knew_ref, vnew_ref, *refs = refs
     # k_hbm, v_hbm: [layers, num_pages, page_size, kv_heads * head_dim] HBM/ANY
@@ -323,8 +337,12 @@ def paged_decode_attention_inline_pallas(
     layer: jnp.ndarray,  # int32 scalar — the pool layer to read
     interpret: bool = False,
     window: int = 0,  # > 0: sliding window; table rows are read as rings
-    block_pages: int = 1,  # pages a step of the walk reads together
+    # pages a step of the walk reads together; None: a 128-token tile of
+    # this pool's pages, the one value the serving path has
+    block_pages: "int | None" = None,
 ) -> jnp.ndarray:
+    if block_pages is None:
+        block_pages = decode_block_pages(k_pages.shape[2])
     return _paged_decode(
         q, k_pages, v_pages, page_table, positions, layer, (k_new, v_new),
         interpret, window, block_pages,

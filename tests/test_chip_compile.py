@@ -311,6 +311,34 @@ def test_no_patterned_program_holds_a_copy_of_either_pool(topo, program, bucket)
     assert temps < smaller * 2, temps  # bf16
 
 
+def _compile_cell_program(topo, name, program, bucket=None):
+    """One serving program of a benchmark cell at its real sizes and engine
+    options (``bucket`` None: the cell's ``--decode-chunk``), compiled for
+    the described chip; returns ``(compiled, engine config, cell, model)``."""
+    import dataclasses
+
+    from fmabench import spec
+    from llm_d_fast_model_actuation_tpu.engine import server
+    from llm_d_fast_model_actuation_tpu.models import llama
+
+    cell = spec.Cell(spec.benchmark(), name)
+    model = dataclasses.replace(
+        cell.family.part("program").build(cell.dims), attention_impl="pallas"
+    )
+    args = server.make_arg_parser().parse_args(
+        ["--model", "tiny", *cell.engine_options(False)]
+    )
+    compiled, cfg = _compile_engine_program(
+        topo, program, bucket or args.decode_chunk, tp=1, model=model,
+        max_batch=args.max_batch, page_size=args.page_size,
+        num_pages=args.num_pages, decode_chunk=args.decode_chunk,
+        max_prefill_tokens=args.max_prefill_tokens,
+        # the prefix cache refuses a model with window layers
+        prefix_caching=llama.layer_pattern(model) is None,
+    )
+    return compiled, cfg, cell, model
+
+
 @pytest.mark.parametrize(
     "program,bucket", [("chunk", 8), ("prefill", 1024), ("suffix", 1024)]
 )
@@ -321,25 +349,10 @@ def test_longmix_cell_programs_fit_the_chip(topo, program, bucket):
     layer of either pool or of a layer's experts copied, arguments + temps
     inside the chip's 16 GB beside the 11.7 GB of weights, pages and rings,
     and the expert layers' flops those of 6 experts a token, not of 64."""
-    import dataclasses
-
-    from fmabench import spec
-    from llm_d_fast_model_actuation_tpu.engine import server
-
-    cell = spec.Cell(spec.benchmark(), "smallthinker-21b.longmix")
+    compiled, cfg, cell, _ = _compile_cell_program(
+        topo, "smallthinker-21b.longmix", program, bucket
+    )
     d = cell.dims
-    model = dataclasses.replace(
-        cell.family.part("program").build(d), attention_impl="pallas"
-    )
-    args = server.make_arg_parser().parse_args(
-        ["--model", "tiny", *cell.engine_options(False)]
-    )
-    compiled, cfg = _compile_engine_program(
-        topo, program, bucket, tp=1, model=model, max_batch=args.max_batch,
-        page_size=args.page_size, num_pages=args.num_pages,
-        decode_chunk=args.decode_chunk,
-        max_prefill_tokens=args.max_prefill_tokens, prefix_caching=False,
-    )
     lay = cfg.kv_layout
     assert lay.ring_pages * cfg.page_size == 4096 + 1024
     keys = cell.family.keys
@@ -369,6 +382,64 @@ def test_longmix_cell_programs_fit_the_chip(topo, program, bucket):
     routed_experts = 4 * rows * d["experts_per_token"] * per_expert
     flops = compiled.cost_analysis()["flops"]
     assert routed_experts < flops < routed_experts + 0.5 * dense_experts
+
+
+def _kernel_vmem_args(text, name):
+    """For every Mosaic kernel called ``name`` in a compiled program's HLO
+    text, the shapes of its VMEM operands in order (blocks in, blocks out,
+    then scratch), read from the kernel's own serialized module."""
+    import base64
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    ctx = mlir.make_ir_context()  # the compiler's own kernels are text
+    tpu.register_dialect(ctx)
+    ctx.allow_unregistered_dialects = True
+    found = []
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        body = re.search(r'"custom_call_config":\{"body":"([^"]+)"', line)
+        with ctx:
+            asm = ir.Module.parse(base64.b64decode(body.group(1))).operation.get_asm()
+        if not asm.startswith(f"module @{name} "):
+            continue
+        args = asm[asm.index("^bb0(") : asm.index("\n", asm.index("^bb0("))]
+        found.append([
+            tuple(int(n) for n in shape.split("x"))
+            for shape in re.findall(
+                r"memref<([0-9x]+)x[a-z0-9]+, #tpu.memory_space<vmem>>", args
+            )
+        ])
+    return found
+
+
+@pytest.mark.parametrize(
+    "name",
+    ["mistral-7b.chat", "mixtral-8x7b.batch", "smallthinker-21b.longmix"],
+)
+def test_accepted_cells_decode_walks_a_128_token_tile(topo, name):
+    """The ``chunk`` program of each accepted cell at its real sizes and
+    engine options, compiled for the described chip: every inline decode
+    kernel in it (one of the scan's body; one a layer of a period of the
+    patterned family) has K and V scratch of two 128-token tiles, eight
+    16-token pages a step, which no caller chose (PERF.md section 6,
+    PR 31), and still nothing the size of a layer of the pool is copied."""
+    compiled, cfg, _, model = _compile_cell_program(topo, name, "chunk")
+    assert cfg.page_size == 16
+    text = compiled.as_text()
+    tile = (2, 128, model.kv_dim)
+    kernels_found = _kernel_vmem_args(text, "paged_decode_inline")
+    assert kernels_found and all(k[-2:] == [tile, tile] for k in kernels_found)
+    layer_pool = cfg.num_pages * cfg.page_size * model.kv_dim
+    # ... but for the chat chunk's copy of ``wq`` into another layout, once
+    # a chunk and larger than a layer of that cell's pool (PERF.md section 7)
+    assert [
+        row for row in _pool_sized_ops(text, layer_pool)
+        if "copy(%params__layers____wq__" not in row[1]
+    ] == []
 
 
 def test_lane_constraint_is_named_not_a_mosaic_crash(topo):

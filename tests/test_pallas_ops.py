@@ -289,3 +289,154 @@ def test_kernels_over_a_tp_mesh_match_reference(devices8, kind):
     if kind == "prefill":  # rows past seq_len are garbage by contract
         got[1, 7:] = want[1, 7:] = 0
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+
+# -- the serving tile of the inline decode kernel -------------------------------
+#
+# Every caller of the inline kernel gets a 128-token tile a step of the walk
+# (ops/pallas/decode.py:decode_block_pages); nobody chooses it.
+
+
+def _inline_scratch(fn, *args):
+    """Scratch shapes of every ``paged_decode_inline`` kernel that
+    ``fn(*args)`` traces to, loops and nested calls included."""
+    from jax.extend import core as jex_core
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if (eqn.primitive.name == "pallas_call"
+                    and eqn.params["name"] == "paged_decode_inline"):
+                n = eqn.params["grid_mapping"].num_scratch_operands
+                yield [tuple(v.aval.shape) for v in eqn.params["jaxpr"].invars[-n:]]
+            for sub in jax.tree.leaves(
+                list(eqn.params.values()),
+                is_leaf=lambda x: isinstance(
+                    x, (jex_core.Jaxpr, jex_core.ClosedJaxpr)
+                ),
+            ):
+                sub = getattr(sub, "jaxpr", sub)  # a ClosedJaxpr's own
+                if isinstance(sub, jex_core.Jaxpr):
+                    yield from walk(sub)
+
+    return list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+@pytest.mark.parametrize(
+    "page_size,block_pages", [(16, 8), (32, 4), (128, 1), (256, 1)]
+)
+def test_serving_path_walks_a_128_token_tile(page_size, block_pages):
+    """What the dispatcher hands the kernel follows from the pool's page
+    size alone: as many pages a step as fill 128 tokens, a page a step
+    where a page is that long or longer."""
+    from llm_d_fast_model_actuation_tpu.ops.pallas.decode import (
+        decode_block_pages,
+    )
+
+    assert decode_block_pages(page_size) == block_pages
+    heads, kvh, d, batch = 8, 2, 64, 2
+    s = jax.ShapeDtypeStruct
+    pool = s((2, 5, page_size, kvh * d), jnp.float32)
+    new = s((batch, kvh, d), jnp.float32)
+    tile = block_pages * page_size
+    sems = (2, 2, block_pages) if block_pages > 1 else (2, 2)
+    assert _inline_scratch(
+        lambda *a: attn.paged_decode_attention_inline(*a, impl="pallas"),
+        s((batch, heads, d), jnp.float32), pool, pool, new, new,
+        s((batch, 4), jnp.int32), s((batch,), jnp.int32), s((), jnp.int32),
+    ) == [[(2, tile, kvh * d), (2, tile, kvh * d), sems]]
+
+
+def _masked_decode(q, k, v, positions):
+    """Float32 softmax of q [b, heads, d] over keys 0..positions[b] of
+    k, v [b, ctx, kvh, d], written without pages."""
+    b, heads, d = q.shape
+    kk = jnp.repeat(k, heads // k.shape[2], axis=2)
+    vv = jnp.repeat(v, heads // v.shape[2], axis=2)
+    scores = jnp.einsum("bhd,bthd->bht", q * d**-0.5, kk)
+    mask = jnp.arange(k.shape[1])[None, :] <= positions[:, None]
+    scores = jnp.where(mask[:, None, :], scores, -jnp.inf)
+    return jnp.einsum("bht,bthd->bhd", jax.nn.softmax(scores, axis=-1), vv)
+
+
+@pytest.mark.parametrize(
+    "contexts",
+    [(0,), (1,), (15,), (16,), (127,), (128,), (129,), (1000,), (0, 1000, 130)],
+    ids=lambda c: "ctx" + "_".join(map(str, c)),
+)
+def test_inline_decode_at_the_serving_tile_mistral_heads(contexts):
+    """The inline kernel as the serving path calls it, at Mistral's and
+    Mixtral's head layout (32 heads over 8 KV heads of 128, 16-token pages:
+    eight pages a step), against the masked form: no cached position at
+    all, contexts shorter than a tile, at its edges, of several tiles with
+    spare pages in the last, and an empty slot beside a long sequence."""
+    heads, kvh, d, page = 32, 8, 128, 16
+    batch, longest = len(contexts), max(contexts)
+    pages_per_seq = longest // page + 1
+    ks = jax.random.split(jax.random.key(31), 3)
+    q = _rand(ks[0], (batch, heads, d))
+    k = _rand(ks[1], (batch, longest + 1, kvh, d))
+    v = _rand(ks[2], (batch, longest + 1, kvh, d))
+    positions = jnp.asarray(contexts, jnp.int32)
+    # pages in a scattered order, page 0 left unused; a row holds garbage
+    # past its sequence's context, which the kernel must never weigh in
+    order = np.random.default_rng(7).permutation(batch * pages_per_seq) + 1
+    table = jnp.asarray(order.reshape(batch, pages_per_seq), jnp.int32)
+
+    def pool(x):
+        rows = jnp.pad(
+            x.reshape(batch, longest + 1, kvh * d),
+            ((0, 0), (0, pages_per_seq * page - longest - 1), (0, 0)),
+        ).reshape(batch * pages_per_seq, page, kvh * d)
+        stored = jnp.zeros((2, batch * pages_per_seq + 1, page, kvh * d))
+        return stored.at[1, table.reshape(-1)].set(rows)
+
+    at = jnp.arange(batch)
+    got = attn.paged_decode_attention_inline(
+        q, pool(k), pool(v), k[at, positions], v[at, positions], table,
+        positions, jnp.int32(1), impl="pallas",
+    )
+    np.testing.assert_allclose(
+        got, _masked_decode(q, k, v, positions), atol=2e-5, rtol=2e-5
+    )
+
+
+@pytest.mark.parametrize("preset", ["tiny", "tiny_moe"])
+def test_decode_step_logits_pallas_matches_grouped(preset):
+    """``llama.decode_step`` of both accepted families under ``pallas``
+    (the kernel at the serving tile, which nobody passes) against the XLA
+    form by logits: contexts of none, a few, one tile and several tiles of
+    cached positions in one batch."""
+    import dataclasses
+
+    from llm_d_fast_model_actuation_tpu.models import llama, moe
+    from llm_d_fast_model_actuation_tpu.models.registry import init_params_for
+
+    base = (
+        llama.LlamaConfig.tiny() if preset == "tiny"
+        else moe.MoeConfig.tiny_moe()
+    )
+    # float32 weights and cache: what is left between the two forms is the
+    # order of summation, not bfloat16's rounding of the XLA form's operands
+    base = dataclasses.replace(base, max_seq_len=512, dtype=jnp.float32)
+    params = init_params_for(jax.random.key(2), base)
+    page, pages_per_seq = 16, 20
+    positions = jnp.asarray([0, 5, 128, 300], jnp.int32)
+    batch = positions.shape[0]
+    shape = (base.num_layers, batch * pages_per_seq + 1, page, base.kv_dim)
+    ks = jax.random.split(jax.random.key(3), 2)
+    cache = (_rand(ks[0], shape), _rand(ks[1], shape))
+    table = jnp.arange(1, 1 + batch * pages_per_seq, dtype=jnp.int32).reshape(
+        batch, pages_per_seq
+    )
+    args = (jnp.asarray([7, 11, 13, 17], jnp.int32), positions, cache, table)
+
+    def step(impl):
+        cfg = dataclasses.replace(base, attention_impl=impl)
+        return lambda *a: llama.decode_step(params, cfg, *a)
+
+    tile = (2, 128, base.kv_dim)
+    assert _inline_scratch(step("pallas"), *args) == [[tile, tile, (2, 2, 8)]]
+    np.testing.assert_allclose(
+        step("pallas")(*args)[0], step("grouped")(*args)[0],
+        atol=1e-4, rtol=1e-4,
+    )

@@ -196,9 +196,25 @@ def _qkv(ctx, seed=0):
     return q, k, v
 
 
+def _decode_inline(impl, block, *args, **kw):
+    """The decode entry as the forward calls it (``block`` None: the kernel
+    walks the serving tile, which no caller chooses), or the kernel itself
+    at ``block`` pages a step."""
+    if block is None:
+        return attention.paged_decode_attention_inline(*args, impl=impl, **kw)
+    from llm_d_fast_model_actuation_tpu.ops.pallas import (
+        paged_decode_attention_inline_pallas,
+    )
+
+    return paged_decode_attention_inline_pallas(
+        *args, interpret=True, block_pages=block, **kw
+    )
+
+
 @pytest.mark.parametrize(
-    "impl,block", [("grouped", 1), ("pallas", 1), ("pallas", 4)],
-    ids=["grouped", "pallas", "pallas_4_pages_a_step"],
+    "impl,block",
+    [("grouped", None), ("pallas", 1), ("pallas", 4), ("pallas", None)],
+    ids=["grouped", "pallas", "pallas_4_pages_a_step", "pallas_serving_tile"],
 )
 @pytest.mark.parametrize(
     "pos", [5, WINDOW - 1, WINDOW, 39, 40, 41, 97],
@@ -206,14 +222,15 @@ def _qkv(ctx, seed=0):
 )
 def test_decode_window_mask(impl, block, pos):
     """The decode entry (XLA form and the kernel, which starts its walk at
-    the first page with a visible key, a page or four a step) over a ring,
+    the first page with a visible key: a page or four a step, and the
+    serving tile of 128 tokens, which is longer than this ring) over a ring,
     at contexts below, at and past the window and past the ring's length."""
     q, k, v = _qkv(100)
     table = jnp.arange(RING_PAGES, dtype=jnp.int32)[None, :]
-    got = attention.paged_decode_attention_inline(
+    got = _decode_inline(
+        impl, block,
         q[pos][None], _ring_of(k, pos), _ring_of(v, pos), k[pos][None],
-        v[pos][None], table, jnp.asarray([pos]), jnp.int32(1), impl=impl,
-        window=WINDOW, block_pages=block,
+        v[pos][None], table, jnp.asarray([pos]), jnp.int32(1), window=WINDOW,
     )
     want = _masked_attention(
         q[pos][None], k[: pos + 1], v[: pos + 1], jnp.asarray([pos]), WINDOW
@@ -221,19 +238,22 @@ def test_decode_window_mask(impl, block, pos):
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
 
 
+@pytest.mark.parametrize("block", [4, None], ids=["4_pages_a_step", "serving_tile"])
 @pytest.mark.parametrize("pos", [0, 1, 15, 16, 17, 63, 64, 99], ids=lambda p: f"pos{p}")
-def test_decode_kernel_several_pages_a_step_over_plain_pages(pos):
-    """The full causal mask over a plain table row, four pages a step: whole
-    steps, a last step with spare pages, a context shorter than one step,
-    and no cached position at all."""
+def test_decode_kernel_several_pages_a_step_over_plain_pages(pos, block):
+    """The full causal mask over a plain table row, four pages a step and
+    the serving tile (32 of these 4-token pages): whole steps, a last step
+    with spare pages, a context shorter than one step, and no cached
+    position at all."""
     q, k, v = _qkv(100, seed=3)
     pages = 25
     pool = lambda x: jnp.zeros((2, pages * PAGE, KVH * HD)).at[1, :pos].set(  # noqa: E731
         x[:pos].reshape(pos, KVH * HD)).reshape(2, pages, PAGE, KVH * HD)
     table = jnp.arange(pages, dtype=jnp.int32)[None, :]
-    got = attention.paged_decode_attention_inline(
+    got = _decode_inline(
+        "pallas", block,
         q[pos][None], pool(k), pool(v), k[pos][None], v[pos][None], table,
-        jnp.asarray([pos]), jnp.int32(1), impl="pallas", block_pages=4,
+        jnp.asarray([pos]), jnp.int32(1),
     )
     want = _masked_attention(
         q[pos][None], k[: pos + 1], v[: pos + 1], jnp.asarray([pos]), 10**6
