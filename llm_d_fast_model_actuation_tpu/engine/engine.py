@@ -138,7 +138,7 @@ class EngineConfig:
             self.max_prefill_tokens or self.seq_len, self.seq_len
         )
         return KVLayout.plan(
-            self.model.num_layers, n_window, window, self.page_size,
+            self.model.cache_layers, n_window, window, self.page_size,
             self.seq_len, segment,
         )
 
@@ -1072,6 +1072,11 @@ class InferenceEngine:
         #: layers with their (token, expert) assignments
         self.window_tokens_evicted = 0
         self.moe_tokens = 0
+        #: layer applications the dispatched programs ran, counted on the
+        #: host once a program (/v1/stats "stack"): a prefill segment, a
+        #: packed step or a verify is one forward, a decode chunk T, and a
+        #: forward is ``cache_layers`` of them (a looped stack's every pass)
+        self.layer_passes = 0
         self._has_experts = getattr(m, "num_experts", 0) > 1
         self._ring_len = self.kv_layout.ring_pages * cfg.page_size
         self._positions = np.zeros((b,), dtype=np.int32)
@@ -1282,13 +1287,27 @@ class InferenceEngine:
                 0, first + tokens - self._ring_len
             ) - max(0, first - self._ring_len)
 
+    def _count_passes(self, forwards: int) -> None:
+        """``forwards`` whole forwards were dispatched (one a prefill
+        segment, T a decode chunk), each every layer of every pass."""
+        self.layer_passes += forwards * self._model_cfg.cache_layers
+
     def cache_stats(self) -> Dict[str, Dict[str, int]]:
-        """The ``kv`` and ``moe`` blocks of ``/v1/stats``."""
+        """The ``stack``, ``kv`` and ``moe`` blocks of ``/v1/stats``."""
         m, lay = self._model_cfg, self.kv_layout
         experts = getattr(m, "num_experts", 0)
         per_token = m.num_layers * getattr(m, "experts_per_token", 0)
         return {
+            "stack": {
+                "num_layers": m.num_layers,
+                "loop_steps": m.loop_steps,
+                "cache_layers": m.cache_layers,
+                "layer_passes": self.layer_passes,
+            },
             "kv": {
+                "bytes_per_token": PagePool.page_nbytes(
+                    m.cache_layers, 1, m.num_kv_heads, m.head_dim, dtype=m.dtype
+                ),
                 "global_layers": lay.global_layers,
                 "window_layers": lay.window_layers,
                 "window": lay.window,
@@ -1893,6 +1912,7 @@ class InferenceEngine:
         )
         self.dispatch_tokens["bucketed"] += len(seg)
         self._count_forward(start_pos, len(seg))
+        self._count_passes(1)
         tokens = np.zeros((1, bucket), dtype=np.int32)
         tokens[0, : len(seg)] = seg
         # next prompt token at each segment position (prompt-logprob
@@ -1956,6 +1976,7 @@ class InferenceEngine:
                 )
                 self.dispatch_tokens["bucketed"] += n
                 self._count_forward(0, n)
+                self._count_passes(1)
                 tokens = np.zeros((1, bucket), dtype=np.int32)
                 tokens[0, :n] = req.prompt
                 seq_lens = np.array([n], dtype=np.int32)
@@ -2449,6 +2470,7 @@ class InferenceEngine:
                     program="mixed", bucket=shape,
                     prompt_tokens=prefill_tokens,
                 )
+                self._count_passes(1)
                 if routed_rows:
                     tok, lp, av, ai, cache, counts_dev, bias_dev, skeys = (
                         self.programs.mixed_multi(kvp)(
@@ -2679,6 +2701,7 @@ class InferenceEngine:
         # len(window) steps (speculation never runs beside a chunk in flight)
         with tracing.phase("sched.chunk_dispatch", False) as ph:
             ph.set(T=len(window), live_slots=1, program="verify")
+            self._count_passes(1)
             toks, lps_dev, avs_dev, ais_dev, cache = self._verify_fn(
                 self.params, tokens, start, window_len,
                 self.pool.as_tuple(), table,
@@ -2867,6 +2890,7 @@ class InferenceEngine:
             self._upload_sched_rows()
         with tracing.phase("sched.chunk_dispatch", self.chunk_in_flight) as ph:
             ph.set(T=T, live_slots=len(running))
+            self._count_passes(T)
             d = self._dev
             # a routed slot switches the chunk to the multi-variant twin
             # (the plain program would decode it with base weights); with

@@ -475,6 +475,8 @@ MODEL_CONFIGS = {
     ),
     "tiny-smallthinker": SmallThinkerConfig.tiny_smallthinker,
     "smallthinker-21b-a3b": SmallThinkerConfig.smallthinker_21b_a3b,
+    "tiny-ouro": llama.LlamaConfig.tiny_ouro,
+    "ouro-2.6b": llama.LlamaConfig.ouro_2_6b,
 }
 
 
@@ -2224,7 +2226,7 @@ class EngineService:
         eng = self.engine
         m = eng.cfg.model
         per_page = PagePool.page_nbytes(
-            m.num_layers,
+            m.cache_layers,
             eng.cfg.page_size,
             m.num_kv_heads,
             m.head_dim,
@@ -3562,7 +3564,7 @@ class EngineService:
         from .kv_cache import PagePool
 
         return PagePool.estimate_nbytes(
-            model_cfg.num_layers,
+            model_cfg.cache_layers,
             self.args.num_pages,
             self.args.page_size,
             model_cfg.num_kv_heads,
@@ -6102,9 +6104,10 @@ class EngineService:
         from ..utils import compile_cache
 
         out["compile_cache"] = compile_cache.stats()
-        # the two kinds of KV state and the expert layers, host-counted
-        # (engine.cache_stats): pages in use, ring bytes, positions that
-        # left a ring, tokens and assignments through the experts
+        # the layer stack, the two kinds of KV state and the expert layers,
+        # host-counted (engine.cache_stats): layer passes dispatched, pages
+        # in use, bytes a token, ring bytes, positions that left a ring,
+        # tokens and assignments through the experts
         out.update(self.engine.cache_stats())
         # what the scheduler thread spent its time on, part by part, since
         # the process started (docs/tracing.md "Scheduler phases")
@@ -6531,7 +6534,7 @@ class EngineService:
                             jax.random.key(self.args.seed), m, eng.mesh
                         )
                     pool = PagePool.create(
-                        m.num_layers,
+                        m.cache_layers,
                         eng.cfg.num_pages,
                         eng.cfg.page_size,
                         m.num_kv_heads,

@@ -57,6 +57,9 @@ _SHAPE_FIELDS = (
     "embed_scale",
     "post_norms",
     "qk_norm",
+    # a looped stack: the passes change the K and V a token holds and the
+    # parameter set (the exit gate)
+    "loop_steps",
 )
 
 

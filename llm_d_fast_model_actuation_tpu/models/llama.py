@@ -73,6 +73,30 @@ class LlamaConfig:
     qk_norm: bool = False
     #: biases on the q/k/v projections (Qwen2 convention)
     attn_bias: bool = False
+    #: a looped stack (Ouro): the SAME ``num_layers`` layers applied this
+    #: many times a token, the final norm closing every pass and opening the
+    #: next; pass u's layer l keeps its own K and V, cache layer
+    #: ``u * num_layers + l`` (:attr:`cache_layers`). 1 = the stack once.
+    loop_steps: int = 1
+    #: the looped family's published exit threshold: a token leaves the loop
+    #: once its gate's cumulated exit probability reaches it. At 1 no token
+    #: leaves early; a step whose depth depends on the data is not built.
+    early_exit_threshold: float = 1.0
+
+    def __post_init__(self) -> None:
+        if self.loop_steps < 1:
+            raise ValueError(f"loop_steps {self.loop_steps}: at least one pass")
+        if self.early_exit_threshold < 1:
+            raise ValueError(
+                f"early_exit_threshold {self.early_exit_threshold}: every "
+                "token takes all loop_steps passes here; adaptive exit below "
+                "a threshold of 1 is not built"
+            )
+        if self.loop_steps > 1 and type(self) is not LlamaConfig:
+            raise ValueError(
+                f"{type(self).__name__}: loop_steps {self.loop_steps}; the "
+                "looped stack is the dense family's"
+            )
 
     @classmethod
     def tiny_gemma(cls, vocab: int = 256) -> "LlamaConfig":
@@ -112,6 +136,37 @@ class LlamaConfig:
         )
 
     @classmethod
+    def ouro_2_6b(cls) -> "LlamaConfig":
+        """Ouro-2.6B as published (ByteDance/Ouro-2.6B ``config.json``):
+        48 layers applied four times a token, multi-head attention,
+        sandwich norms. ``max_seq_len`` is a serving choice below the
+        published 65,536 positions: a token holds 1.5 MiB of K and V."""
+        return cls(
+            vocab_size=49152,
+            hidden_size=2048,
+            num_layers=48,
+            num_heads=16,
+            num_kv_heads=16,
+            head_dim=128,
+            intermediate_size=5632,
+            rope_theta=1e6,
+            rms_eps=1e-6,
+            max_seq_len=4096,
+            post_norms=True,
+            loop_steps=4,
+        )
+
+    @classmethod
+    def tiny_ouro(cls, vocab: int = 256) -> "LlamaConfig":
+        """CPU test size of the looped family: 3 layers x 3 passes."""
+        import dataclasses
+
+        return dataclasses.replace(
+            cls.tiny(vocab), num_layers=3, num_kv_heads=4, rms_eps=1e-6,
+            post_norms=True, loop_steps=3,
+        )
+
+    @classmethod
     def llama3_8b(cls) -> "LlamaConfig":
         return cls()
 
@@ -148,6 +203,13 @@ class LlamaConfig:
     def kv_dim(self) -> int:
         return self.num_kv_heads * self.head_dim
 
+    @property
+    def cache_layers(self) -> int:
+        """Layers of K and V a token holds: the ONE number every KV size in
+        the engine comes from (engine/kv_cache.py). A looped stack keeps a
+        layer for each (pass, layer)."""
+        return self.loop_steps * self.num_layers
+
     def num_params(self) -> int:
         per_layer = (
             2 * self.hidden_size  # norms
@@ -161,11 +223,14 @@ class LlamaConfig:
         if self.qk_norm:
             per_layer += 2 * self.head_dim
         head = 0 if self.tie_embeddings else self.hidden_size * self.vocab_size
+        # the looped family's exit gate, Linear(hidden -> 1) with bias
+        gate = self.hidden_size + 1 if self.loop_steps > 1 else 0
         return (
             self.vocab_size * self.hidden_size
             + self.num_layers * per_layer
             + self.hidden_size
             + head
+            + gate
         )
 
 
@@ -215,6 +280,14 @@ def init_params(key: jax.Array, cfg: LlamaConfig) -> Dict[str, Any]:
     }
     if not cfg.tie_embeddings:
         params["lm_head"] = dense_init(k_head, (h, cfg.vocab_size), h)
+    if cfg.loop_steps > 1:
+        # the exit gate read on each pass's output. Held (sleep, swap and an
+        # importer carry it) and never evaluated: at the threshold this
+        # config admits (1) it changes no served token.
+        params["early_exit_gate"] = {
+            "w": dense_init(jax.random.fold_in(k_head, 1), (h, 1), h),
+            "b": jnp.zeros((1,), dtype=cfg.dtype),
+        }
     return params
 
 
@@ -248,6 +321,8 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
     }
     if not cfg.tie_embeddings:
         axes["lm_head"] = ("embed", "vocab")
+    if cfg.loop_steps > 1:
+        axes["early_exit_gate"] = {"w": ("embed", None), "b": (None,)}
     return axes
 
 
@@ -399,15 +474,37 @@ def _scatter_decode(pages, layer, new, page_table, positions, page_size):
     )
 
 
-def _scan_layers(layer_fn, x, params, cache):
-    """Scan ``layer_fn((x, k_pages, v_pages), (layer_params, layer))`` over
-    the stacked layers with the whole pool in the carry."""
-    k_pages, v_pages = cache
-    layers = jnp.arange(k_pages.shape[0], dtype=jnp.int32)
-    (x, k_pages, v_pages), _ = jax.lax.scan(
-        layer_fn, (x, k_pages, v_pages), (params["layers"], layers)
-    )
-    return x, (k_pages, v_pages)
+def _scan_layers(cfg: LlamaConfig, layer_fn, carry, params):
+    """Scan ``layer_fn(carry, (layer_params, cache_layer))`` over the stacked
+    layers and close with the final norm on ``carry[0]``, the hidden state
+    (a carry that holds the whole pool keeps it beside). Returns (carry,
+    ys), ys stacked by cache layer.
+
+    A looped stack (``cfg.loop_steps`` > 1) scans the SAME layers
+    ``loop_steps`` times, as one outer scan over the passes: pass u reads
+    and writes cache layer ``u * num_layers + l``, and the final norm closes
+    every pass, its output opening the next."""
+    L = cfg.num_layers
+    layers = jnp.arange(L, dtype=jnp.int32)
+
+    def close(carry):
+        return (_norm(cfg, carry[0], params["final_norm"]), *carry[1:])
+
+    if cfg.loop_steps == 1:
+        carry, ys = jax.lax.scan(layer_fn, carry, (params["layers"], layers))
+        return close(carry), ys
+
+    def one_pass(carry, u):
+        carry, ys = jax.lax.scan(
+            layer_fn, carry, (params["layers"], u * L + layers)
+        )
+        return close(carry), ys
+
+    with jax.named_scope("loop"):
+        carry, ys = jax.lax.scan(
+            one_pass, carry, jnp.arange(cfg.loop_steps, dtype=jnp.int32)
+        )
+    return carry, jax.tree.map(lambda y: y.reshape(-1, *y.shape[2:]), ys)
 
 
 def prefill(
@@ -459,11 +556,10 @@ def prefill(
         x = x + _post(cfg, lp, "post_ffn_norm", _ffn(cfg, lp, h))
         return (x, kp, vp), None
 
-    x, cache = _scan_layers(layer, x, params, cache)
-    x = _norm(cfg, x, params["final_norm"])
+    (x, *cache), _ = _scan_layers(cfg, layer, (x, *cache), params)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = qmat(x, head).astype(jnp.float32)
-    return logits, cache
+    return logits, tuple(cache)
 
 
 def prefill_continue(
@@ -520,11 +616,10 @@ def prefill_continue(
         x = x + _post(cfg, lp, "post_ffn_norm", _ffn(cfg, lp, h))
         return (x, kp, vp), None
 
-    x, cache = _scan_layers(layer, x, params, cache)
-    x = _norm(cfg, x, params["final_norm"])
+    (x, *cache), _ = _scan_layers(cfg, layer, (x, *cache), params)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = qmat(x, head).astype(jnp.float32)
-    return logits, cache
+    return logits, tuple(cache)
 
 
 def mixed_step(
@@ -597,11 +692,10 @@ def mixed_step(
         x = x + _post(cfg, lp, "post_ffn_norm", _ffn(cfg, lp, h))
         return (x, kp, vp), None
 
-    x, cache = _scan_layers(layer, x, params, cache)
-    x = _norm(cfg, x, params["final_norm"])
+    (x, *cache), _ = _scan_layers(cfg, layer, (x, *cache), params)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = qmat(x, head).astype(jnp.float32)
-    return logits, cache
+    return logits, tuple(cache)
 
 
 def decode_step(
@@ -653,10 +747,10 @@ def decode_step(
 
     x = _embed_tokens(cfg, params, tokens)  # [b, h]
 
-    L = k_pages.shape[0]
+    L = cfg.cache_layers
 
-    def layer(x, scanned):
-        lp, li = scanned
+    def layer(carry, scanned):
+        (x,), (lp, li) = carry, scanned
         h = _norm(cfg, x, lp["attn_norm"])
         with jax.named_scope("attn"):
             q, k, v = _project_qkv(
@@ -670,12 +764,10 @@ def decode_step(
             x = x + _post(cfg, lp, "post_attn_norm", qmat(attn.reshape(b, cfg.q_dim), lp["wo"]))
         h = _norm(cfg, x, lp["mlp_norm"])
         x = x + _post(cfg, lp, "post_ffn_norm", _ffn(cfg, lp, h))
-        return x, (k, v)
+        return (x,), (k, v)
 
-    x, (k_all, v_all) = jax.lax.scan(
-        layer, x, (params["layers"], jnp.arange(L, dtype=jnp.int32))
-    )
-    # One scatter for all layers: k_all/v_all are [L, b, kvh, hd].
+    (x,), (k_all, v_all) = _scan_layers(cfg, layer, (x,), params)
+    # One scatter for all cache layers: k_all/v_all are [L, b, kvh, hd].
     with jax.named_scope("kv_write"):
         page_of = positions // page_size
         slot_of = positions % page_size
@@ -689,7 +781,6 @@ def decode_step(
         new_k = k_pages.at[li, pi, si].set(k_all.reshape(flat), mode="drop")
         new_v = v_pages.at[li, pi, si].set(v_all.reshape(flat), mode="drop")
 
-    x = _norm(cfg, x, params["final_norm"])
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = qmat(x, head).astype(jnp.float32)
     return logits, (new_k, new_v)
@@ -739,8 +830,7 @@ def _decode_step_scatter_first(
         x = x + _post(cfg, lp, "post_ffn_norm", _ffn(cfg, lp, h))
         return (x, kp, vp), None
 
-    x, cache = _scan_layers(layer, x, params, cache)
-    x = _norm(cfg, x, params["final_norm"])
+    (x, *cache), _ = _scan_layers(cfg, layer, (x, *cache), params)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = qmat(x, head).astype(jnp.float32)
-    return logits, cache
+    return logits, tuple(cache)

@@ -61,6 +61,7 @@ class SmallThinkerConfig(moe.MoeConfig):
     rope_pattern: Tuple[bool, ...] = (False, True, True, True)
 
     def __post_init__(self) -> None:
+        super().__post_init__()
         period = len(self.window_pattern)
         if len(self.rope_pattern) != period:
             raise ValueError("window_pattern and rope_pattern differ in length")

@@ -27,6 +27,7 @@ from .llama import (  # noqa: F401
     _norm,
     _post,
     _project_qkv,
+    _scan_layers,
     param_logical_axes,
 )
 from ..ops.rope import rope_table
@@ -70,8 +71,11 @@ def forward_train(
         return x, None
 
     body = jax.checkpoint(layer) if remat else layer
-    x, _ = jax.lax.scan(body, x, params["layers"])
-    x = _norm(cfg, x, params["final_norm"])
+    # the serving forward's stack: once, or a looped config's passes with
+    # the final norm closing each (no cache here, so no cache layer)
+    (x,), _ = _scan_layers(
+        cfg, lambda c, scanned: ((body(c[0], scanned[0])[0],), None), (x,), params
+    )
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     return (x @ head).astype(jnp.float32)
 

@@ -384,6 +384,53 @@ def test_longmix_cell_programs_fit_the_chip(topo, program, bucket):
     assert routed_experts < flops < routed_experts + 0.5 * dense_experts
 
 
+@pytest.mark.parametrize(
+    "program,bucket",
+    [("chunk", 8), ("prefill", 32), ("prefill", 64), ("prefill", 128),
+     ("prefill", 256)],
+)
+def test_loopchat_cell_programs_fit_the_chip(topo, program, bucket):
+    """The programs of the cell ``ouro-2.6b.loopchat`` at its real sizes and
+    engine options, compiled for the described chip: the decode chunk and
+    every prefill bucket its prompts (32-256 tokens) meet. The pool is 192
+    cache layers deep (a layer for each of 4 passes x 48 layers) and the
+    stack is a scan of passes over a scan of layers: the kernels are in,
+    nothing the size of a cache layer of the pool is copied, and arguments +
+    temps are 13.01 GB of the chip's 16: 12.21 GB of weights and pages, and
+    0.81 GB that is two copies, once a program, of the ``wq`` and ``wk``
+    stacks into another layout (the chat cell's ``wq`` copy, PERF.md section
+    7; with 16 KV heads ``wk`` is as large as ``wq``). ISSUE 34 reckoned
+    under 13 GB without them; nothing else is as large as a megabyte."""
+    compiled, cfg, cell, model = _compile_cell_program(
+        topo, "ouro-2.6b.loopchat", program, bucket
+    )
+    d = cell.dims
+    assert cfg.kv_layout.global_layers == model.cache_layers == 192
+    keys = cell.family.keys
+    state = 2 * keys.param_count(d) + keys.kv_bytes(d, cfg.num_pages, cfg.page_size)
+    assert state == 2 * 2_667_974_657 + 6_870_269_952
+    ma = compiled.memory_analysis()
+    assert state <= ma.argument_size_in_bytes < state + 0.1e9
+    relayouts = 2 * 2 * d["num_layers"] * d["hidden_size"] * model.q_dim
+    assert ma.temp_size_in_bytes < relayouts + 3e6
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 13.1e9
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    layer_pool = cfg.num_pages * cfg.page_size * model.kv_dim
+    # ... and the logits of a whole segment, which the prefill programs of
+    # every family compute before they take the last (PERF.md section 5)
+    logits = f"bf16[{bucket},{d['vocab_size']}]"
+    assert [
+        row for row in _pool_sized_ops(text, layer_pool)
+        if logits not in row[1]
+        and not re.search(r"copy\(%params__layers____w[qk]__", row[1])
+    ] == []
+    if program == "chunk":
+        tile = (2, 128, model.kv_dim)
+        kernels_found = _kernel_vmem_args(text, "paged_decode_inline")
+        assert kernels_found and all(k[-2:] == [tile, tile] for k in kernels_found)
+
+
 def _kernel_vmem_args(text, name):
     """For every Mosaic kernel called ``name`` in a compiled program's HLO
     text, the shapes of its VMEM operands in order (blocks in, blocks out,
