@@ -39,7 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
-from ..models import llama
+from ..models import llama, moe
 from ..parallel.mesh import shard_pytree
 from ..utils import tracing
 from .kv_cache import KVLayout, OutOfPages, PageAllocator, PagePool
@@ -606,7 +606,7 @@ class ProgramSet:
         ):
             logits, cache = llama.prefill_continue(
                 params, model_cfg, tokens, start, suffix_lens, cache,
-                page_table,
+                page_table, mesh=self.mesh,
             )
             tok, lp, av, ai, skey = self._sample_last(
                 logits, suffix_lens, temp, topp, counts, pres, freq,
@@ -634,7 +634,7 @@ class ProgramSet:
             API must not degrade under speculation)."""
             logits, cache = llama.prefill_continue(
                 params, model_cfg, tokens, start, window_len, cache,
-                page_table,
+                page_table, mesh=self.mesh,
             )
             norm = logits - jax.scipy.special.logsumexp(
                 logits, axis=-1, keepdims=True
@@ -1068,10 +1068,13 @@ class InferenceEngine:
         #: cumulative counters of the two caches and the routed layer,
         #: counted on the host from what the scheduler knows (/v1/stats
         #: "kv" and "moe"): positions that left a ring (overwritten by a
-        #: later position of their sequence), and tokens through the expert
-        #: layers with their (token, expert) assignments
+        #: later position of their sequence), tokens through the expert
+        #: layers with their (token, expert) assignments, and those of them
+        #: whose program ran the layers as grouped matmuls, each token
+        #: against its own experts only (models/moe.py:takes_grouped)
         self.window_tokens_evicted = 0
         self.moe_tokens = 0
+        self.moe_routed_tokens = 0
         #: layer applications the dispatched programs ran, counted on the
         #: host once a program (/v1/stats "stack"): a prefill segment, a
         #: packed step or a verify is one forward, a decode chunk T, and a
@@ -1276,12 +1279,19 @@ class InferenceEngine:
                 jax.device_put(self.pool.as_tuple(), jax.devices()[0])
             )
 
-    def _count_forward(self, first: int, tokens: int) -> None:
+    def _count_forward(self, first: int, tokens: int, rows: int) -> None:
         """Host counters of the forwards that took one sequence from
-        position ``first`` through ``tokens`` more: tokens through the
-        expert layers, and positions overwritten in the sequence's ring."""
+        position ``first`` through ``tokens`` more, in a program traced
+        with ``rows`` rows (a prefill bucket, the decode batch): tokens
+        through the expert layers, routed or not by the rule the trace
+        went by, and positions overwritten in the sequence's ring."""
         if self._has_experts:
             self.moe_tokens += tokens
+            if moe.takes_grouped(
+                self._model_cfg, rows, self.params["layers"]["w_gate"],
+                self.mesh,
+            ):
+                self.moe_routed_tokens += tokens
         if self._ring_len:
             self.window_tokens_evicted += max(
                 0, first + tokens - self._ring_len
@@ -1321,6 +1331,7 @@ class InferenceEngine:
             "moe": {
                 "experts": experts if experts > 1 else 0,
                 "tokens": self.moe_tokens,
+                "routed_tokens": self.moe_routed_tokens,
                 "assignments": self.moe_tokens * per_token,
             },
         }
@@ -1911,7 +1922,7 @@ class InferenceEngine:
             (bucket - len(seg)) * self._pad_token_bytes
         )
         self.dispatch_tokens["bucketed"] += len(seg)
-        self._count_forward(start_pos, len(seg))
+        self._count_forward(start_pos, len(seg), bucket)
         self._count_passes(1)
         tokens = np.zeros((1, bucket), dtype=np.int32)
         tokens[0, : len(seg)] = seg
@@ -1975,7 +1986,7 @@ class InferenceEngine:
                     (bucket - n) * self._pad_token_bytes
                 )
                 self.dispatch_tokens["bucketed"] += n
-                self._count_forward(0, n)
+                self._count_forward(0, n, bucket)
                 self._count_passes(1)
                 tokens = np.zeros((1, bucket), dtype=np.int32)
                 tokens[0, :n] = req.prompt
@@ -3026,7 +3037,7 @@ class InferenceEngine:
             first = req.pos
             n = self._emit_run(req, toks_l[slot], lps_l[slot], alts)
             # every token of the run was a step that wrote its position
-            self._count_forward(first, n)
+            self._count_forward(first, n, self.cfg.max_batch)
             taken[slot] = n
             emitted.append(req)
             if req.done:

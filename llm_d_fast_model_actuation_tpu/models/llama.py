@@ -357,20 +357,33 @@ def _mlp(cfg, x, gate, up, down):
     return qmat((a.astype(x.dtype) * u), down)
 
 
-def _ffn(cfg: "LlamaConfig", lp, x, router_logits=None, layer=None):
+def _ffn(
+    cfg: "LlamaConfig", lp, x, router_logits=None, layer=None, stacks=None,
+    mesh=None,
+):
     """Dense SwiGLU or an expert layer, by config family (models/moe.py:
-    every expert for every token, or the routed layer where the config
-    says ``routed_experts``). ``router_logits``: a family whose router
-    reads something else than ``x`` brings them; ``layer``: the expert
-    matrices in ``lp`` are whole stacks and this is the layer's index."""
+    ``expert_ffn`` computes every expert for every token or only each
+    token's own, by the config and by the rows ``x`` is traced with).
+    ``router_logits``: a family whose router reads something else than
+    ``x`` brings them; ``layer``: the STACK's layer index (not the cache
+    layer); ``stacks``: the stacked layer parameters, whose expert matrices
+    the grouped form reads whole; ``mesh``: the mesh the program runs on."""
     with jax.named_scope("ffn"):
         if getattr(cfg, "num_experts", 0) > 1:
-            from .moe import moe_ffn, routed_ffn
+            from .moe import expert_ffn
 
-            if cfg.routed_experts:
-                return routed_ffn(cfg, lp, x, router_logits, layer)
-            return moe_ffn(cfg, lp, x)
+            return expert_ffn(cfg, lp, x, router_logits, layer, stacks, mesh)
         return _mlp(cfg, x, lp["w_gate"], lp["w_up"], lp["w_down"])
+
+
+def _scanned_ffn(cfg: "LlamaConfig", params, lp, li, h, mesh):
+    """The FFN block of a layer function of :func:`_scan_layers`: ``lp``
+    the scanned slice of the stack, ``li`` the cache layer it was scanned
+    with, which under ``loop_steps`` > 1 counts the passes too, where the
+    stack's layer (what the whole expert stacks are indexed by) does not."""
+    layer = li if cfg.loop_steps == 1 else li % cfg.num_layers
+    y = _ffn(cfg, lp, h, layer=layer, stacks=params["layers"], mesh=mesh)
+    return _post(cfg, lp, "post_ffn_norm", y)
 
 
 def layer_pattern(cfg) -> "Tuple[Tuple[int, bool], ...] | None":
@@ -553,7 +566,7 @@ def prefill(
             )
             x = x + _post(cfg, lp, "post_attn_norm", qmat(attn.reshape(b, s, cfg.q_dim), lp["wo"]))
         h = _norm(cfg, x, lp["mlp_norm"])
-        x = x + _post(cfg, lp, "post_ffn_norm", _ffn(cfg, lp, h))
+        x = x + _scanned_ffn(cfg, params, lp, li, h, mesh)
         return (x, kp, vp), None
 
     (x, *cache), _ = _scan_layers(cfg, layer, (x, *cache), params)
@@ -570,6 +583,7 @@ def prefill_continue(
     suffix_lens: jnp.ndarray,  # [b] int32 — valid suffix length per row
     cache: Tuple[jnp.ndarray, jnp.ndarray],
     page_table: jnp.ndarray,  # [b, pages_per_seq] int32
+    mesh=None,  # the mesh the program runs on (the expert layer's form)
 ):
     """Prefill a prompt SUFFIX against a cache whose first `start` tokens
     are already present (the prefix-caching hit path,
@@ -613,7 +627,7 @@ def prefill_continue(
             attn = paged_suffix_attention(q, kp, vp, page_table, start, li)
             x = x + _post(cfg, lp, "post_attn_norm", qmat(attn.reshape(b, s, cfg.q_dim), lp["wo"]))
         h = _norm(cfg, x, lp["mlp_norm"])
-        x = x + _post(cfg, lp, "post_ffn_norm", _ffn(cfg, lp, h))
+        x = x + _scanned_ffn(cfg, params, lp, li, h, mesh)
         return (x, kp, vp), None
 
     (x, *cache), _ = _scan_layers(cfg, layer, (x, *cache), params)
@@ -689,7 +703,7 @@ def mixed_step(
                 qmat(attn.reshape(T, cfg.q_dim), lp["wo"]),
             )
         h = _norm(cfg, x, lp["mlp_norm"])
-        x = x + _post(cfg, lp, "post_ffn_norm", _ffn(cfg, lp, h))
+        x = x + _scanned_ffn(cfg, params, lp, li, h, mesh)
         return (x, kp, vp), None
 
     (x, *cache), _ = _scan_layers(cfg, layer, (x, *cache), params)
@@ -735,7 +749,7 @@ def decode_step(
         )
     if cfg.attention_impl == "reference":
         return _decode_step_scatter_first(
-            params, cfg, tokens, positions, cache, page_table, active
+            params, cfg, tokens, positions, cache, page_table, active, mesh
         )
     b = tokens.shape[0]
     k_pages, v_pages = cache
@@ -763,7 +777,7 @@ def decode_step(
             )
             x = x + _post(cfg, lp, "post_attn_norm", qmat(attn.reshape(b, cfg.q_dim), lp["wo"]))
         h = _norm(cfg, x, lp["mlp_norm"])
-        x = x + _post(cfg, lp, "post_ffn_norm", _ffn(cfg, lp, h))
+        x = x + _scanned_ffn(cfg, params, lp, li, h, mesh)
         return (x,), (k, v)
 
     (x,), (k_all, v_all) = _scan_layers(cfg, layer, (x,), params)
@@ -794,6 +808,7 @@ def _decode_step_scatter_first(
     cache: Tuple[jnp.ndarray, jnp.ndarray],
     page_table: jnp.ndarray,
     active: "jnp.ndarray | None" = None,
+    mesh=None,
 ):
     """The baseline decode step: per-layer scatter-then-attend."""
     b = tokens.shape[0]
@@ -827,7 +842,7 @@ def _decode_step_scatter_first(
             )
             x = x + _post(cfg, lp, "post_attn_norm", qmat(attn.reshape(b, cfg.q_dim), lp["wo"]))
         h = _norm(cfg, x, lp["mlp_norm"])
-        x = x + _post(cfg, lp, "post_ffn_norm", _ffn(cfg, lp, h))
+        x = x + _scanned_ffn(cfg, params, lp, li, h, mesh)
         return (x, kp, vp), None
 
     (x, *cache), _ = _scan_layers(cfg, layer, (x, *cache), params)

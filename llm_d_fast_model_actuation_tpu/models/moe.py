@@ -7,21 +7,42 @@ TPU/SPMD design:
   * expert weights are stacked ``[layers, experts, ...]`` and the experts
     axis carries the ``expert -> ep`` logical sharding rule
     (parallel/mesh.py LOGICAL_RULES): each ep shard holds E/ep experts;
-  * two expert layers. :func:`moe_ffn` is DENSE-compute, sparse-weight:
-    every expert runs on every token and the router's (renormalized)
-    top-k probabilities weight the sum. Under ep sharding each device computes only its local experts and
-    the weighted sum's contraction over E becomes one psum over ep — no
-    scatter/gather, no capacity factors, no dynamic shapes, which is
-    exactly what XLA wants. The FLOPs cost vs token-dropping dispatch is
-    E/k per device group, paid deliberately for static shapes (the
-    standard small-scale JAX MoE trade). :func:`routed_ffn` is the routed,
-    dropless one for families whose E/k makes that cost the layer's
-    (64/6 for SmallThinker): every (token, expert) assignment is sorted by
-    expert and the three matmuls are grouped ones (``jax.lax.ragged_dot``,
-    which the TPU compiler lowers to one kernel that computes each row
-    against its own expert only) — static shapes, no capacity factor,
-    nothing dropped however uneven the routing. A config picks its layer
-    by what it is (``routed_experts``), not by an engine flag.
+  * two forms of the one expert layer (a token's top-k experts, weighted
+    by the router's renormalized probabilities). :func:`moe_ffn` is
+    DENSE-compute, sparse-weight: every expert runs on every token and the
+    router's top-k probabilities weight the sum. Under ep sharding each
+    device computes only its local experts and the weighted sum's
+    contraction over E becomes one psum over ep — no scatter/gather, no
+    capacity factors, no dynamic shapes. :func:`routed_ffn` is the routed,
+    dropless one: every (token, expert) assignment is sorted by expert and
+    the three matmuls are grouped ones (``jax.lax.ragged_dot``, which the
+    TPU compiler lowers to one kernel that computes each row against its
+    own expert only) — static shapes, no capacity factor, nothing dropped
+    however uneven the routing;
+  * :func:`expert_ffn` picks the form, by what a trace can see
+    (:func:`takes_grouped`). A family whose E/k makes the dense form's
+    cost the layer's says so in its config (``routed_experts``: 64/6 for
+    SmallThinker) and always routes. Every other family (Mixtral, 8/2)
+    goes by the row count the program is traced with: under
+    ``GROUPED_MIN_ROWS`` every expert's weights are read either way and
+    the dense form is bound by those reads (the decode chunk, prefill and
+    suffix segments of up to 256 rows: the program is the dense one, to
+    the instruction); at or over it the dense form is bound by arithmetic
+    of which (E - k)/E is multiplied by a router weight of zero, and the
+    rows go to their own experts (the prefill and suffix programs of 512
+    and 1,024 rows). The grouped form reads the expert stacks WHOLE,
+    ``[L, E, ...]`` with the layer's index (:func:`_grouped`): a custom
+    call cannot fuse the slice a layer scan hands it, and a layer's
+    experts copied out every layer is what made Mixtral on ``routed_ffn``
+    41% slower in PR 28. A quantized stack, a mesh that shards the
+    ``expert`` or ``mlp`` axis, and a caller that brings no stacks
+    (models/train.py) keep the dense form;
+  * the grouped matmul itself is the Pallas one (megablox ``gmm``) where
+    the program runs Pallas kernels and the expert widths are whole tiles
+    (:func:`_gmm_fits`: Mixtral's 4,096 x 14,336), and XLA's
+    ``ragged_dot`` lowering everywhere else (SmallThinker's 2,560 x 768,
+    every other backend): at Mixtral's widths the XLA lowering runs at 30%
+    of the MXU's peak and loses to the dense form under 1,024 rows.
 
 Reference parity: the reference serves MoE through vLLM's Mixtral support
 (SURVEY §2.9 model families); this is the TPU-native equivalent.
@@ -29,21 +50,25 @@ Reference parity: the reference serves MoE through vLLM's Mixtral support
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 
+from ..parallel.mesh import LOGICAL_RULES
 from . import llama
+from .quant import is_quantized
 
 
 @dataclass(frozen=True)
 class MoeConfig(llama.LlamaConfig):
     num_experts: int = 8
     experts_per_token: int = 2
-    #: the expert layer: False = :func:`moe_ffn` (every expert for every
-    #: token), True = :func:`routed_ffn` (each token's top-k only)
+    #: True: this family always routes (:func:`routed_ffn`, each token's
+    #: top-k only, whatever the row count). False: :func:`expert_ffn` goes
+    #: by the rows of the trace (:func:`takes_grouped`)
     routed_experts: bool = False
     #: the experts' gate activation in :func:`routed_ffn`: "silu" or "relu"
     expert_activation: str = "silu"
@@ -166,8 +191,6 @@ def _qeinsum(spec: str, x: jnp.ndarray, w: Any) -> jnp.ndarray:
     the per-expert per-output-channel scale ([E, 1, out] after the layer
     slice) rescales the einsum RESULT, so no dequantized expert stack ever
     materializes — the same fusion argument as qmat."""
-    from .quant import is_quantized
-
     if is_quantized(w):
         out = jnp.einsum(spec, x, w["q"].astype(x.dtype))
         return out * jnp.squeeze(w["s"], axis=-2).astype(out.dtype)
@@ -218,16 +241,42 @@ def _gate_act(cfg, g: jnp.ndarray) -> jnp.ndarray:
     raise ValueError(f"unknown expert activation {cfg.expert_activation!r}")
 
 
-def _grouped(x: jnp.ndarray, w: Any, sizes: jnp.ndarray, layer):
+#: (rows, in, out) tiles of the Pallas grouped matmul (megablox ``gmm``).
+#: A [2048, 1024] tile of an expert matrix is 4 MB and is read once for
+#: every 256-row tile of the rows its expert was given: at 128 assignment
+#: rows an expert and more (a 512-token segment of a top-2 of 8) a layer's
+#: matrices are read about once. On the chip at Mixtral's widths, a 1,024-
+#: token segment of two layers: dense 33.3 ms of expert matmuls,
+#: ``jax.lax.ragged_dot`` 24.8, this kernel 15.4 at these tiles (16.3 at
+#: (256, 1024, 1024), 22 at 128 or 512 rows a tile; PERF.md section 6,
+#: PR 35). 12 MB of the 16 MB of VMEM a kernel may take.
+GMM_TILES = (256, 2048, 1024)
+
+
+def _gmm_fits(cfg: MoeConfig) -> bool:
+    """Whether the expert matmuls of a program of ``cfg`` run as the Pallas
+    grouped matmul: the program runs Pallas kernels at all (the config's
+    resolved ``attention_impl``), and both widths are whole tiles either
+    way round (a ragged width it would mask, at tiles tuned for none).
+    Every other shape (SmallThinker's 2,560 x 768 experts) and every other
+    backend goes to ``jax.lax.ragged_dot``."""
+    _, tk, tn = GMM_TILES
+    h, f = cfg.hidden_size, cfg.intermediate_size
+    return cfg.attention_impl == "pallas" and all(
+        width % tk == 0 and width % tn == 0 for width in (h, f)
+    )
+
+
+def _grouped(x: jnp.ndarray, w: Any, sizes: jnp.ndarray, layer, kernel=False):
     """Rows of ``x``, sorted by expert, each against its own expert's
     matrix: ``w`` is [E, in, out], ``sizes`` [E] the rows per expert. With
     ``layer`` (an int32 scalar) ``w`` is the WHOLE stack [L, E, in, out]:
     it goes to the grouped matmul as L * E groups of which only the
     layer's own are given rows, so the kernel reads the layer's experts
     where they are stored and no layer of the stack is sliced out first (a
-    copy of every expert of the layer, each step, on the chip)."""
-    from .quant import is_quantized
-
+    copy of every expert of the layer, each step, on the chip).
+    ``kernel``: the Pallas grouped matmul (:func:`_gmm_fits`; the rows a
+    multiple of its row tile), else XLA's."""
     if is_quantized(w):
         raise ValueError("the routed expert layer takes no quantized stack")
     if layer is not None:
@@ -236,6 +285,15 @@ def _grouped(x: jnp.ndarray, w: Any, sizes: jnp.ndarray, layer):
             jnp.zeros((L * E,), sizes.dtype), sizes, (layer * E,)
         )
         w = w.reshape((L * E,) + w.shape[2:])
+    if kernel:
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+        from ..ops.attention import _pallas_interpret
+
+        return gmm(
+            x, w, sizes, preferred_element_type=x.dtype, tiling=GMM_TILES,
+            interpret=_pallas_interpret(),
+        )
     return jax.lax.ragged_dot(x, w, sizes)
 
 
@@ -273,11 +331,16 @@ def routed_ffn(
         sizes = jnp.sum(
             jax.nn.one_hot(expert, E, dtype=jnp.int32), axis=0
         )  # [E] rows per expert
-        rows = xf[order // k]  # [N*k, h]: row r is token order[r] // k
-        g = _grouped(rows, lp["w_gate"], sizes, layer)
-        u = _grouped(rows, lp["w_up"], sizes, layer)
+        kernel = _gmm_fits(cfg)
+        # the kernel takes whole row tiles: the rows past the last expert's
+        # are given to no expert, stored by none and gathered by nobody
+        pad = -(n * k) % GMM_TILES[0] if kernel else 0
+        padded = jnp.pad(order, (0, pad)) if pad else order
+        rows = xf[padded // k]  # [N*k (+ pad), h]: row r is token order[r] // k
+        g = _grouped(rows, lp["w_gate"], sizes, layer, kernel)
+        u = _grouped(rows, lp["w_up"], sizes, layer, kernel)
         act = _gate_act(cfg, g).astype(x.dtype) * u
-        y = _grouped(act, lp["w_down"], sizes, layer)  # [N*k, h]
+        y = _grouped(act, lp["w_down"], sizes, layer, kernel)  # [N*k (+), h]
         # back to token order: a gather by the inverse permutation
         inverse = jnp.zeros_like(order).at[order].set(
             jnp.arange(n * k, dtype=order.dtype)
@@ -287,3 +350,74 @@ def routed_ffn(
             "nkh,nk->nh", y.astype(jnp.float32), probs.reshape(n, k)
         )
     return out.astype(x.dtype).reshape(*lead, h)
+
+
+#: the names of the expert matrices in a layer's parameters
+EXPERT_STACKS = ("w_gate", "w_up", "w_down")
+
+#: rows (tokens of one traced program) from which a family that does not
+#: always route takes the grouped form. The reckoning, per layer of E
+#: experts of 3hf weights each, k kept a token: the dense form costs
+#: max(weight reads, 2 * rows * E * 3hf / peak), the grouped one
+#: max(weight reads, 2 * rows * k * 3hf / peak) plus a sort and two
+#: gathers. Up to the row count at which the dense form leaves the
+#: weight-read floor, rows = peak / bandwidth = 197e12 / 819e9 = 240 for
+#: bfloat16 on a TPU v5e, the two read the same bytes and the dense form
+#: has no sort; past it the dense form's time grows with E * rows and the
+#: grouped one's stays near the floor until rows = 240 * E / k. The chip
+#: (TPU v5e, Mixtral's widths, two layers' expert matmuls a segment;
+#: PERF.md section 6, PR 35) puts the crossing at about 300 rows, because
+#: the grouped kernel's floor stands a tenth over the dense form's: 128
+#: rows 8.1 ms dense / 8.6 grouped, 256 rows 8.9 / 9.6, 512 rows 17.3 /
+#: 11.2, 1,024 rows 33.3 / 15.4.
+GROUPED_MIN_ROWS = 320
+
+
+def _shards_experts(mesh) -> bool:
+    """Whether ``mesh`` splits an expert stack's ``expert`` or ``mlp`` axis
+    (parallel/mesh.py LOGICAL_RULES: ``ep``, ``tp``)."""
+    if mesh is None:
+        return False
+    return any(
+        mesh.shape.get(LOGICAL_RULES[axis], 1) > 1 for axis in ("expert", "mlp")
+    )
+
+
+def takes_grouped(cfg: MoeConfig, rows: int, w: Any, mesh=None) -> bool:
+    """THE rule: whether the expert layers of a program traced with
+    ``rows`` tokens run as grouped matmuls (:func:`routed_ffn`) or densely
+    (:func:`moe_ffn`). ``w`` is one of the expert stacks as stored, ``mesh``
+    the mesh the program runs on. The model step asks it while tracing
+    (:func:`expert_ffn`), the engine for its counter with the bucket it
+    dispatched (``/v1/stats.moe.routed_tokens``)."""
+    if cfg.routed_experts:
+        return True
+    # a grouped matmul over int8 stacks, or over a sharded group axis, is
+    # not built
+    if is_quantized(w) or _shards_experts(mesh):
+        return False
+    return rows >= GROUPED_MIN_ROWS
+
+
+def expert_ffn(
+    cfg: MoeConfig,
+    lp: Dict[str, Any],
+    x: jnp.ndarray,
+    router_logits: "jnp.ndarray | None" = None,
+    layer: "jnp.ndarray | None" = None,
+    stacks: "Dict[str, Any] | None" = None,
+    mesh=None,
+) -> jnp.ndarray:
+    """The expert layer of every family: the form :func:`takes_grouped`
+    names for ``x``'s rows. ``lp`` holds the layer's parameters; a family
+    that always routes puts its whole expert stacks there already
+    (models/smallthinker.py). Any other brings ``stacks`` (the stacked
+    layer parameters, ``[L, ...]``) and ``layer``, the STACK's layer index:
+    where the grouped form is taken the expert matrices are read from
+    there whole, and the slices in ``lp`` are dead."""
+    if not cfg.routed_experts:
+        rows = math.prod(x.shape[:-1])
+        if stacks is None or not takes_grouped(cfg, rows, lp["w_gate"], mesh):
+            return moe_ffn(cfg, lp, x)
+        lp = {**lp, **{name: stacks[name] for name in EXPERT_STACKS}}
+    return routed_ffn(cfg, lp, x, router_logits, layer)

@@ -157,11 +157,6 @@ def _join_cache(cache, kp, vp, kr, vr):
     return kp, vp, kr.reshape(cache[2].shape), vr.reshape(cache[3].shape)
 
 
-#: the expert matrices: never sliced by layer, the grouped matmul takes the
-#: whole stack and the layer's index (models/moe.py:_grouped)
-_EXPERT_STACKS = ("w_gate", "w_up", "w_down")
-
-
 def _periods(cfg):
     """The period indices, the scan's xs. The body indexes the stacked
     parameters by layer itself: a layer's small matrices as slices that
@@ -175,7 +170,9 @@ def _layer_params(cfg, params, pi, j):
     """(layer index, the parameters of layer j of period pi)."""
     li = pi * len(cfg.window_pattern) + j
     return li, {
-        name: a if name in _EXPERT_STACKS else a[li]
+        # the expert matrices are never sliced by layer: the grouped matmul
+        # takes the whole stack and the layer's index (models/moe.py:_grouped)
+        name: a if name in moe.EXPERT_STACKS else a[li]
         for name, a in params["layers"].items()
     }
 

@@ -489,6 +489,51 @@ def test_accepted_cells_decode_walks_a_128_token_tile(topo, name):
     ] == []
 
 
+@pytest.mark.parametrize(
+    "program,bucket",
+    [("prefill", 1024), ("suffix", 1024), ("suffix", 16), ("chunk", 8)],
+)
+def test_batch_cell_prompt_rows_go_to_their_own_experts(topo, program, bucket):
+    """The programs of the cell ``mixtral-8x7b.batch`` at its real sizes and
+    engine options, compiled for the described chip. A segment of 1,024 rows
+    runs its expert layers as Pallas grouped matmuls over the WHOLE expert
+    stacks: no layer's experts are copied out for the custom call (what made
+    PR 28's trial 41% slower), and the flops are those of 2 experts a token,
+    not of 8. The decode chunk (64 rows) and a 16-row suffix segment stay the
+    dense form, bound by the same weight reads either way: no grouped matmul
+    in them (models/moe.py:takes_grouped; PERF.md section 6, PR 35)."""
+    from llm_d_fast_model_actuation_tpu.models import moe
+
+    compiled, cfg, _, model = _compile_cell_program(
+        topo, "mixtral-8x7b.batch", program, bucket
+    )
+    text = compiled.as_text()
+    rows = cfg.max_batch if program == "chunk" else bucket
+    grouped = re.findall(r"%(gmm|ragged-dot)[\w.\-]* = ", text)
+    if rows < moe.GROUPED_MIN_ROWS:
+        assert grouped == []
+        return
+    assert grouped == ["gmm"] * 3  # a scan's body: one layer
+    layer_experts = (
+        model.num_experts * model.hidden_size * model.intermediate_size
+    )
+    # ... but for the logits of a whole segment, which the prefill programs
+    # of every family compute before they take the last (PERF.md section 5)
+    logits = f"bf16[{bucket},{model.vocab_size}]"
+    assert [
+        row for row in _pool_sized_ops(text, layer_experts)
+        if logits not in row[1]
+    ] == []
+    ma = compiled.memory_analysis()
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 9e9
+    # XLA counts a loop's body once: one layer
+    per_expert = 3 * 2 * model.hidden_size * model.intermediate_size
+    dense = rows * model.num_experts * per_expert
+    routed = rows * model.experts_per_token * per_expert
+    flops = compiled.cost_analysis()["flops"]
+    assert routed < flops < routed + 0.5 * dense
+
+
 def test_lane_constraint_is_named_not_a_mosaic_crash(topo):
     """A per-device KV row narrower than the 128-lane tile is refused with
     the constraint spelled out (TinyLlama at tp=4: one 64-wide KV head)."""
