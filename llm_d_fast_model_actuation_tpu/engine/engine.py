@@ -130,16 +130,17 @@ class EngineConfig:
 
     @property
     def kv_layout(self) -> KVLayout:
-        """The division of the KV state between pages and rings
-        (engine/kv_cache.py): from the model's window layers, the batch and
-        the segment limit — nothing an operator sets."""
+        """The division of the sequence state between pages, rings and
+        recurrent state (engine/kv_cache.py): from the model's window and
+        linear-attention layers, the batch and the segment limit — nothing
+        an operator sets."""
         n_window, window = llama.window_layers(self.model)
         segment = prefill_bucket(
             self.max_prefill_tokens or self.seq_len, self.seq_len
         )
         return KVLayout.plan(
             self.model.cache_layers, n_window, window, self.page_size,
-            self.seq_len, segment,
+            self.seq_len, segment, llama.recurrent_state(self.model),
         )
 
     @property
@@ -434,21 +435,41 @@ class EngineAsleep(RuntimeError):
     """The engine's device state is offloaded; wake_up() before serving."""
 
 
-class WindowLayersUnsupported(ValueError):
-    """A path that cannot carry the rings of sliding-window layers was
-    asked of a model that has them."""
+class SlotStateUnsupported(ValueError):
+    """A path that knows pages alone was asked of a model that keeps
+    per-slot sequence state beside them: the rings of sliding-window layers
+    or the recurrent state of linear-attention layers."""
 
 
-def refuse_window_layers(model, what: str) -> None:
-    """Raise, naming the model and the path, if ``model`` has
-    sliding-window layers: ``what`` addresses the KV state as pages of one
-    kind and would read or move a window layer's ring as something else."""
+def slot_state_kinds(model) -> List[str]:
+    """What ``model`` keeps per sequence slot beside its pages, in words:
+    empty for a model whose whole sequence state is pages."""
     n_window, window = llama.window_layers(model)
+    recurrent = llama.recurrent_state(model)
+    kinds = []
     if n_window:
-        raise WindowLayersUnsupported(
-            f"{type(model).__name__} has {n_window} sliding-window layers "
-            f"(window {window}) whose K and V live in per-sequence rings: "
-            f"{what} cannot carry a ring yet and refuses this model"
+        kinds.append(
+            f"{n_window} sliding-window layers (window {window}) whose K "
+            "and V live in per-sequence rings"
+        )
+    if recurrent is not None:
+        kinds.append(
+            f"{recurrent[0]} linear-attention layers whose recurrent state "
+            "and convolution tail live in per-slot arrays"
+        )
+    return kinds
+
+
+def refuse_slot_state(model, what: str) -> None:
+    """Raise, naming the model, its state and the path, if ``model`` keeps
+    per-slot sequence state: ``what`` addresses a sequence's state as pages
+    of one kind and would read, share or move a ring or a slot's recurrent
+    state as something else, or not at all."""
+    kinds = slot_state_kinds(model)
+    if kinds:
+        raise SlotStateUnsupported(
+            f"{type(model).__name__} has {' and '.join(kinds)}: {what} "
+            "cannot carry per-slot state yet and refuses this model"
         )
 
 
@@ -1017,11 +1038,11 @@ class InferenceEngine:
 
             check_kernel_shape(m.num_kv_heads // tp, m.head_dim)
         if cfg.prefix_caching:
-            refuse_window_layers(m, "the prefix cache (--prefix-caching on)")
+            refuse_slot_state(m, "the prefix cache (--prefix-caching on)")
         if cfg.packed_serving:
-            refuse_window_layers(m, "the packed mixed_step path (--packed-serving on)")
+            refuse_slot_state(m, "the packed mixed_step path (--packed-serving on)")
         if cfg.speculative_ngram:
-            refuse_window_layers(m, "--speculative-ngram")
+            refuse_slot_state(m, "--speculative-ngram")
         self.cfg = cfg
         self._eos_ids = frozenset((cfg.eos_token_id, *cfg.extra_eos_ids))
         self.mesh = mesh
@@ -1059,12 +1080,13 @@ class InferenceEngine:
         b, p = cfg.max_batch, cfg.pages_per_seq
         # Host mirrors of the device scheduler state (source of truth between
         # chunks; re-uploaded only after an admission/retire/prefill edge).
-        # A page-table row: the sequence's pages, then (window layers) the
-        # static columns of its slot's ring, never rewritten.
+        # A page-table row: the sequence's pages, then the static columns,
+        # never rewritten: its slot's ring (window layers), its slot's index
+        # (recurrent state).
         self._page_table = np.zeros(
             (b, self.kv_layout.table_width), dtype=np.int32
         )
-        self._page_table[:, p:] = self.kv_layout.ring_columns(b)
+        self._page_table[:, p:] = self.kv_layout.static_columns(b)
         #: cumulative counters of the two caches and the routed layer,
         #: counted on the host from what the scheduler knows (/v1/stats
         #: "kv" and "moe"): positions that left a ring (overwritten by a
@@ -1078,8 +1100,14 @@ class InferenceEngine:
         #: layer applications the dispatched programs ran, counted on the
         #: host once a program (/v1/stats "stack"): a prefill segment, a
         #: packed step or a verify is one forward, a decode chunk T, and a
-        #: forward is ``cache_layers`` of them (a looped stack's every pass)
+        #: forward is every layer of every pass, ``loop_steps x num_layers``
         self.layer_passes = 0
+        #: the recurrent state's counters (/v1/stats "state"), host-counted
+        #: once a program: tokens x linear layers dispatched, and prefill
+        #: segments that started from zero or resumed from the slot's state
+        self.state_token_updates = 0
+        self.state_first_segments = 0
+        self.state_resumed_segments = 0
         self._has_experts = getattr(m, "num_experts", 0) > 1
         self._ring_len = self.kv_layout.ring_pages * cfg.page_size
         self._positions = np.zeros((b,), dtype=np.int32)
@@ -1259,8 +1287,9 @@ class InferenceEngine:
         self.variant_detaches = 0
 
     def _create_pool(self) -> None:
-        """A fresh device KV state (pages, and rings where the model has
-        window layers), committed to the engine's placement."""
+        """A fresh device sequence state (pages; rings where the model has
+        window layers; zeroed recurrent state where it has linear-attention
+        layers), committed to the engine's placement."""
         m, cfg, lay = self._model_cfg, self.cfg, self.kv_layout
         self.pool = PagePool.create(
             lay.global_layers,
@@ -1273,6 +1302,7 @@ class InferenceEngine:
             ring_shape=lay.ring_shape(
                 cfg.max_batch, cfg.page_size, m.num_kv_heads, m.head_dim
             ),
+            state_shapes=lay.state_shapes(cfg.max_batch),
         )
         if self.mesh is None:
             self.pool.replace(
@@ -1296,15 +1326,30 @@ class InferenceEngine:
             self.window_tokens_evicted += max(
                 0, first + tokens - self._ring_len
             ) - max(0, first - self._ring_len)
+        self.state_token_updates += tokens * self.kv_layout.state_layers
+
+    def _count_segment(self, start_pos: int) -> None:
+        """A prefill segment at ``start_pos`` was dispatched: with recurrent
+        state it starts from zero or resumes from its slot's."""
+        if self.kv_layout.state_layers:
+            if start_pos:
+                self.state_resumed_segments += 1
+            else:
+                self.state_first_segments += 1
 
     def _count_passes(self, forwards: int) -> None:
         """``forwards`` whole forwards were dispatched (one a prefill
         segment, T a decode chunk), each every layer of every pass."""
-        self.layer_passes += forwards * self._model_cfg.cache_layers
+        m = self._model_cfg
+        self.layer_passes += forwards * m.loop_steps * m.num_layers
 
     def cache_stats(self) -> Dict[str, Dict[str, int]]:
-        """The ``stack``, ``kv`` and ``moe`` blocks of ``/v1/stats``."""
+        """The ``stack``, ``kv``, ``state`` and ``moe`` blocks of
+        ``/v1/stats``."""
         m, lay = self._model_cfg, self.kv_layout
+        state_bytes = lay.state_nbytes(
+            self.cfg.max_batch, jnp.dtype(m.dtype).itemsize
+        )
         experts = getattr(m, "num_experts", 0)
         per_token = m.num_layers * getattr(m, "experts_per_token", 0)
         return {
@@ -1327,6 +1372,14 @@ class InferenceEngine:
                 ),
                 "ring_bytes": self.pool.ring_nbytes(),
                 "window_tokens_evicted": self.window_tokens_evicted,
+            },
+            "state": {
+                "layers": lay.state_layers,
+                "bytes_per_slot": state_bytes // self.cfg.max_batch,
+                "bytes": state_bytes,
+                "token_updates": self.state_token_updates,
+                "first_segments": self.state_first_segments,
+                "resumed_segments": self.state_resumed_segments,
             },
             "moe": {
                 "experts": experts if experts > 1 else 0,
@@ -1561,7 +1614,7 @@ class InferenceEngine:
         is validated against the base leaf it replaces — a shape/dtype
         mismatch would otherwise surface as a trace error deep inside
         the multi program, unattributable to this attach."""
-        refuse_window_layers(self._model_cfg, "a co-resident variant attach")
+        refuse_slot_state(self._model_cfg, "a co-resident variant attach")
         if not self._packed:
             raise ValueError(
                 "co-resident variants require packed serving: the "
@@ -1923,6 +1976,7 @@ class InferenceEngine:
         )
         self.dispatch_tokens["bucketed"] += len(seg)
         self._count_forward(start_pos, len(seg), bucket)
+        self._count_segment(start_pos)
         self._count_passes(1)
         tokens = np.zeros((1, bucket), dtype=np.int32)
         tokens[0, : len(seg)] = seg
@@ -1987,6 +2041,7 @@ class InferenceEngine:
                 )
                 self.dispatch_tokens["bucketed"] += n
                 self._count_forward(0, n, bucket)
+                self._count_segment(0)
                 self._count_passes(1)
                 tokens = np.zeros((1, bucket), dtype=np.int32)
                 tokens[0, :n] = req.prompt
@@ -3139,7 +3194,7 @@ class InferenceEngine:
         queue instead of carrying KV: prefill is a pure function of the
         prompt and no RNG split is consumed before its final segment, so
         re-running it on resume reproduces identical output."""
-        refuse_window_layers(self._model_cfg, "a zero-drain park")
+        refuse_slot_state(self._model_cfg, "a zero-drain park")
         from . import parked as parked_mod
 
         self.drain_inflight()

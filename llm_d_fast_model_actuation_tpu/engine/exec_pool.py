@@ -229,9 +229,10 @@ def _abstract_state(cfg, mesh=None):
     come from the registry's init (the same source of truth as the HF
     loader), so no weights are touched."""
     import jax
+    import jax.numpy as jnp
 
     from ..models.registry import init_params_for
-    from .kv_cache import POOL_SPEC, RING_SPEC, PagePool
+    from .kv_cache import POOL_SPEC, RING_SPEC, STATE_SPEC, PagePool
 
     m = cfg.model
     params = jax.eval_shape(
@@ -247,7 +248,7 @@ def _abstract_state(cfg, mesh=None):
             ),
             params,
         )
-        kv_sharding = ring_sharding = sharding
+        kv_sharding = ring_sharding = state_sharding = sharding
     else:
         from jax.sharding import NamedSharding
 
@@ -260,6 +261,7 @@ def _abstract_state(cfg, mesh=None):
         )
         kv_sharding = NamedSharding(mesh, POOL_SPEC)
         ring_sharding = NamedSharding(mesh, RING_SPEC)
+        state_sharding = NamedSharding(mesh, STATE_SPEC)
     layout = cfg.kv_layout
     kv = jax.ShapeDtypeStruct(
         PagePool.pool_shape(
@@ -269,16 +271,23 @@ def _abstract_state(cfg, mesh=None):
         m.dtype,
         sharding=kv_sharding,
     )
-    if not layout.window_layers:
-        return params, (kv, kv)
-    ring = jax.ShapeDtypeStruct(
-        layout.ring_shape(
-            cfg.max_batch, cfg.page_size, m.num_kv_heads, m.head_dim
-        ),
-        m.dtype,
-        sharding=ring_sharding,
-    )
-    return params, (kv, kv, ring, ring)
+    cache = (kv, kv)
+    if layout.window_layers:
+        ring = jax.ShapeDtypeStruct(
+            layout.ring_shape(
+                cfg.max_batch, cfg.page_size, m.num_kv_heads, m.head_dim
+            ),
+            m.dtype,
+            sharding=ring_sharding,
+        )
+        cache += (ring, ring)
+    shapes = layout.state_shapes(cfg.max_batch)
+    if shapes is not None:
+        cache += (
+            jax.ShapeDtypeStruct(shapes[0], jnp.float32, sharding=state_sharding),
+            jax.ShapeDtypeStruct(shapes[1], m.dtype, sharding=state_sharding),
+        )
+    return params, cache
 
 
 def abstract_args(cfg, program: str, bucket: int, mesh=None) -> list:

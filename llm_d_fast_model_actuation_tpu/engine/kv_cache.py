@@ -28,6 +28,15 @@ columns the engine appends to every page-table row (slot i owns pages
 reads either kind through a table row and a layer index. The rings are
 sized from ``max_batch``, the model's window and the segment limit; no
 allocator hands them out and no option sizes them.
+
+A model with linear-attention layers (models/llama.py:recurrent_state) has a
+third kind: RECURRENT state, small, of fixed size, not addressed by pages and
+overwritten in place every token. Per slot and linear layer, a float32 matrix
+a head, ``state [linear layers, slots, heads, d_k, d_v]``, and the last
+inputs of the layer's causal convolution, ``conv_tail [linear layers, slots,
+kernel - 1, channels]`` in the model's dtype. Sized from ``max_batch`` like
+the rings; a program finds its slot by the one static column the engine
+appends to every page-table row after the ring columns (the slot's index).
 """
 
 from __future__ import annotations
@@ -59,28 +68,38 @@ class KVLayout:
     ring_pages: int
     #: page-table columns of the paged pool
     pages_per_seq: int
+    #: layers that keep recurrent state (0 without linear-attention layers)
+    state_layers: int = 0
+    #: what ONE slot holds for one of them: the state's shape (float32) and
+    #: the convolution tail's (the model's dtype)
+    state_shape: Tuple[int, ...] = ()
+    tail_shape: Tuple[int, ...] = ()
 
     @property
     def table_width(self) -> int:
         """Columns of a page-table row: the sequence's pages, then its
-        ring's."""
-        return self.pages_per_seq + self.ring_pages
+        ring's, then (recurrent state) its slot."""
+        return self.pages_per_seq + self.ring_pages + bool(self.state_layers)
 
     @classmethod
     def plan(
         cls, num_layers: int, window_layers: int, window: int,
-        page_size: int, seq_len: int, segment: int,
+        page_size: int, seq_len: int, segment: int, recurrent=None,
     ) -> "KVLayout":
-        """``segment``: the most positions one program writes before it
-        reads (the largest prefill bucket). A ring as long as the context
-        never wraps, so it is never longer than that."""
+        """``num_layers``: the layers that keep K and V; ``segment``: the
+        most positions one program writes before it reads (the largest
+        prefill bucket). A ring as long as the context never wraps, so it
+        is never longer than that. ``recurrent``: what
+        ``llama.recurrent_state`` gives."""
         pps = -(-seq_len // page_size)
+        layers, state, tail = recurrent or (0, (), ())
+        kinds = dict(state_layers=layers, state_shape=state, tail_shape=tail)
         if not window_layers:
-            return cls(num_layers, 0, 0, 0, pps)
+            return cls(num_layers, 0, 0, 0, pps, **kinds)
         ring_len = min(window + segment, seq_len)
         return cls(
             num_layers - window_layers, window_layers, window,
-            -(-ring_len // page_size), pps,
+            -(-ring_len // page_size), pps, **kinds,
         )
 
     def ring_shape(
@@ -100,9 +119,52 @@ class KVLayout:
             slots, self.ring_pages
         )
 
+    def static_columns(self, slots: int):
+        """[slots, table_width - pages_per_seq] int32: what follows a row's
+        pages and is never rewritten, the ring columns and then the slot's
+        own index for a model with recurrent state."""
+        import numpy as np
+
+        cols = [self.ring_columns(slots)]
+        if self.state_layers:
+            cols.append(np.arange(slots, dtype=np.int32)[:, None])
+        return np.concatenate(cols, axis=1)
+
+    def state_shapes(self, slots: int):
+        """(shape of the recurrent state, shape of the convolution tails)
+        for ``slots`` slots, or None without recurrent layers."""
+        if not self.state_layers:
+            return None
+        lead = (self.state_layers, slots)
+        return lead + tuple(self.state_shape), lead + tuple(self.tail_shape)
+
+    def state_nbytes(self, slots: int, itemsize: int) -> int:
+        """Bytes of the recurrent state and the tails (``itemsize``: the
+        model's dtype, the tail's)."""
+        return recurrent_nbytes(
+            (self.state_layers, self.state_shape, self.tail_shape),
+            slots, itemsize,
+        )
+
+
+def recurrent_nbytes(recurrent, slots: int, itemsize: int) -> int:
+    """Device bytes of the recurrent state (float32) and the convolution
+    tails (``itemsize`` bytes an element) of ``slots`` slots, from what
+    ``llama.recurrent_state`` gives (None: no such layers, 0) — the ONE
+    count shared by the stats and the cost oracle's cold-tier prediction
+    (engine/server.py:_kv_pool_nbytes), beside
+    :meth:`PagePool.estimate_nbytes` for the pages."""
+    import math
+
+    layers, state, tail = recurrent or (0, (), ())
+    return layers * slots * (4 * math.prod(state) + itemsize * math.prod(tail))
+
 
 #: the rings on a tp mesh: the lane-fused KV-head axis sharded, as POOL_SPEC
 RING_SPEC = jax.sharding.PartitionSpec(None, None, None, None, "tp")
+#: the recurrent state and its tails on a mesh: replicated, as the linear
+#: mixers' weights are (models/olmo_hybrid.py:param_logical_axes)
+STATE_SPEC = jax.sharding.PartitionSpec()
 
 
 @dataclass
@@ -112,6 +174,19 @@ class PagePool:
     #: the window layers' rings (None for a model without window layers)
     k_ring: Optional[jnp.ndarray] = None
     v_ring: Optional[jnp.ndarray] = None
+    #: the linear-attention layers' recurrent state and convolution tails
+    #: (None for a model without them)
+    state: Optional[jnp.ndarray] = None
+    conv_tail: Optional[jnp.ndarray] = None
+    #: which per-slot kinds this pool carries, kept across :meth:`drop`
+    #: (a woken engine's tuple is split by it)
+    kinds: Tuple[str, ...] = ()
+
+    def __post_init__(self) -> None:
+        if not self.kinds:
+            self.kinds = (("ring",) if self.k_ring is not None else ()) + (
+                ("state",) if self.state is not None else ()
+            )
 
     @staticmethod
     def pool_shape(
@@ -180,11 +255,14 @@ class PagePool:
         dtype: Any = jnp.bfloat16,
         mesh: Optional[Mesh] = None,
         ring_shape: Optional[Tuple[int, ...]] = None,
+        state_shapes: Optional[Tuple[Tuple[int, ...], ...]] = None,
     ) -> "PagePool":
         """``num_layers`` counts the layers the paged pool serves;
-        ``ring_shape`` (:meth:`KVLayout.ring_shape`) adds the rings."""
+        ``ring_shape`` (:meth:`KVLayout.ring_shape`) adds the rings,
+        ``state_shapes`` (:meth:`KVLayout.state_shapes`) the recurrent
+        state (float32) and its convolution tails."""
 
-        def zeros(shape, spec):
+        def zeros(shape, spec, dtype=dtype):
             if mesh is None:
                 return jnp.zeros(shape, dtype)
             return jax.jit(
@@ -201,6 +279,11 @@ class PagePool:
         if ring_shape is not None and ring_shape[0]:
             pool.k_ring = zeros(ring_shape, RING_SPEC)
             pool.v_ring = zeros(ring_shape, RING_SPEC)
+            pool.kinds += ("ring",)
+        if state_shapes is not None:
+            pool.state = zeros(state_shapes[0], STATE_SPEC, jnp.float32)
+            pool.conv_tail = zeros(state_shapes[1], STATE_SPEC)
+            pool.kinds += ("state",)
         return pool
 
     @property
@@ -212,29 +295,44 @@ class PagePool:
         return self.k_pages.shape[2]
 
     def nbytes(self) -> int:
-        return self.k_pages.nbytes + self.v_pages.nbytes + self.ring_nbytes()
+        return (
+            self.k_pages.nbytes + self.v_pages.nbytes + self.ring_nbytes()
+            + self.state_nbytes()
+        )
 
     def ring_nbytes(self) -> int:
         if self.k_ring is None:
             return 0
         return self.k_ring.nbytes + self.v_ring.nbytes
 
+    def state_nbytes(self) -> int:
+        if self.state is None:
+            return 0
+        return self.state.nbytes + self.conv_tail.nbytes
+
     def as_tuple(self) -> Tuple[jnp.ndarray, ...]:
         """The cache as the programs take it, and as sleep and wake move
-        it: (k, v) pages, and the (k, v) rings where the model has window
-        layers."""
-        if self.k_ring is None:
-            return self.k_pages, self.v_pages
-        return self.k_pages, self.v_pages, self.k_ring, self.v_ring
+        it: (k, v) pages, then the (k, v) rings where the model has window
+        layers, then (state, conv_tail) where it has recurrent ones."""
+        out = (self.k_pages, self.v_pages)
+        if "ring" in self.kinds:
+            out += (self.k_ring, self.v_ring)
+        if "state" in self.kinds:
+            out += (self.state, self.conv_tail)
+        return out
 
     def replace(self, kv: Tuple[jnp.ndarray, ...]) -> None:
         self.k_pages, self.v_pages = kv[:2]
-        if len(kv) > 2:
-            self.k_ring, self.v_ring = kv[2:]
+        rest = kv[2:]
+        if "ring" in self.kinds:
+            self.k_ring, self.v_ring, rest = rest[0], rest[1], rest[2:]
+        if "state" in self.kinds:
+            self.state, self.conv_tail = rest
 
     def drop(self) -> None:
         """Let go of every device array (a sleeping engine holds no HBM)."""
         self.k_pages = self.v_pages = self.k_ring = self.v_ring = None
+        self.state = self.conv_tail = None
 
 
 class OutOfPages(Exception):

@@ -46,6 +46,7 @@ from prometheus_client import Counter, Gauge, Histogram
 
 from ..models import llama
 from ..models.moe import MoeConfig
+from ..models.olmo_hybrid import OlmoHybridConfig
 from ..models.smallthinker import SmallThinkerConfig
 from ..utils import faults, tracing
 from .engine import (
@@ -477,6 +478,8 @@ MODEL_CONFIGS = {
     "smallthinker-21b-a3b": SmallThinkerConfig.smallthinker_21b_a3b,
     "tiny-ouro": llama.LlamaConfig.tiny_ouro,
     "ouro-2.6b": llama.LlamaConfig.ouro_2_6b,
+    "tiny-olmo-hybrid": OlmoHybridConfig.tiny_olmo_hybrid,
+    "olmo-hybrid-7b": OlmoHybridConfig.olmo_hybrid_7b,
 }
 
 
@@ -1822,13 +1825,12 @@ class EngineService:
         args = self.args
         import jax  # deliberately not module-level: parse-time must not touch a backend
 
-        from .engine import refuse_window_layers
+        from .engine import refuse_slot_state, slot_state_kinds
 
         if self._zero_drain:
-            refuse_window_layers(model_cfg, "a zero-drain park (--zero-drain on)")
+            refuse_slot_state(model_cfg, "a zero-drain park (--zero-drain on)")
         prefix_caching = args.prefix_caching == "on" or (
-            args.prefix_caching == "auto"
-            and not llama.window_layers(model_cfg)[0]
+            args.prefix_caching == "auto" and not slot_state_kinds(model_cfg)
         )
         return EngineConfig(
             model=model_cfg,
@@ -2721,11 +2723,11 @@ class EngineService:
             raise MigrationRejected(
                 "instance is sleeping; wake it before migrating"
             )
-        from .engine import WindowLayersUnsupported, refuse_window_layers
+        from .engine import SlotStateUnsupported, refuse_slot_state
 
         try:
-            refuse_window_layers(self.engine.cfg.model, "a live migration")
-        except WindowLayersUnsupported as e:
+            refuse_slot_state(self.engine.cfg.model, "a live migration")
+        except SlotStateUnsupported as e:
             raise MigrationRejected(str(e)) from e
         if not self._zero_drain_parks():
             raise MigrationRejected(
@@ -3557,19 +3559,27 @@ class EngineService:
         return model_cfg
 
     def _kv_pool_nbytes(self, model_cfg) -> int:
-        """Device bytes of the KV page pool a runtime for `model_cfg`
+        """Device bytes of the sequence state a runtime for `model_cfg`
         creates — counted in a cold build's ``bytes_in``, so the
-        oracle's cold predictions must count it identically (the layout
-        lives in ONE place: PagePool.estimate_nbytes)."""
-        from .kv_cache import PagePool
+        oracle's cold predictions must count it identically (the layouts
+        live in ONE place: PagePool.estimate_nbytes for the pages,
+        kv_cache.recurrent_nbytes for a linear-attention model's recurrent
+        state). A windowed model's rings are not counted (ROADMAP D4)."""
+        import jax.numpy as jnp
 
-        return PagePool.estimate_nbytes(
+        from .kv_cache import PagePool, recurrent_nbytes
+
+        pages = PagePool.estimate_nbytes(
             model_cfg.cache_layers,
             self.args.num_pages,
             self.args.page_size,
             model_cfg.num_kv_heads,
             model_cfg.head_dim,
             dtype=model_cfg.dtype,
+        )
+        return pages + recurrent_nbytes(
+            llama.recurrent_state(model_cfg), self.args.max_batch,
+            jnp.dtype(model_cfg.dtype).itemsize,
         )
 
     def _offload_wire_bytes(self) -> int:

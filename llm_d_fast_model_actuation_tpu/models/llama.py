@@ -410,6 +410,30 @@ def window_layers(cfg) -> Tuple[int, int]:
     return len(windows) * (cfg.num_layers // len(pattern)), max(windows)
 
 
+def recurrent_state(cfg) -> "Tuple[int, Tuple[int, ...], Tuple[int, ...]] | None":
+    """A family with linear-attention layers keeps a third kind of sequence
+    state (engine/kv_cache.py:KVLayout): (its layers, the shape of one
+    layer's recurrent state for one slot, the shape of its convolution tail).
+    None for a model whose every layer keeps K and V alone.
+    (models/olmo_hybrid.py is that family.)"""
+    return getattr(cfg, "recurrent_state", None)
+
+
+def patterned(cfg):
+    """The module whose forward carries a config whose layers are of more
+    than one kind, on the trunk's signatures; None for the one block of this
+    file."""
+    if recurrent_state(cfg) is not None:
+        from . import olmo_hybrid
+
+        return olmo_hybrid
+    if layer_pattern(cfg) is not None:
+        from . import smallthinker
+
+        return smallthinker
+    return None
+
+
 def _project_qkv(
     cfg: LlamaConfig, lp, x, positions, cos_tab, sin_tab, rope: bool = True
 ):
@@ -520,6 +544,85 @@ def _scan_layers(cfg: LlamaConfig, layer_fn, carry, params):
     return carry, jax.tree.map(lambda y: y.reshape(-1, *y.shape[2:]), ys)
 
 
+# -- what the patterned forwards share (models/smallthinker.py,
+# models/olmo_hybrid.py): a scan over PERIODS of layers whose body unrolls
+# the period's layers statically, each kind with its own state ------------
+
+#: query rows the XLA suffix attention scores at a time: a 1,024-row
+#: segment against a 16k-token table row would otherwise hold 1.9 GB of
+#: float32 scores
+SUFFIX_Q_BLOCK = 128
+
+
+def period_indices(cfg, period: int):
+    """The period indices, the scan's xs. The body indexes the stacked
+    parameters by layer itself: a layer's matrices as slices that fuse into
+    the matmuls that read them."""
+    return jnp.arange(cfg.num_layers // period, dtype=jnp.int32)
+
+
+def lm_logits(cfg, params, x):
+    """The final norm and the head, float32 logits."""
+    x = _norm(cfg, x, params["final_norm"])
+    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+    return qmat(x, head).astype(jnp.float32)
+
+
+def cold_segment(cfg, tokens, seq_lens, mesh):
+    """(positions, valid, attend) of a cold first segment [b, s]: it attends
+    over its own K and V (the flash kernel, with the layer's window)."""
+    b, s = tokens.shape
+    positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
+    valid = positions < seq_lens[:, None]
+
+    def attend(q, k, v, pools, table, layer, window):
+        return causal_prefill_attention(
+            q, k, v, seq_lens, impl=cfg.attention_impl, mesh=mesh,
+            window=window,
+        )
+
+    return positions, valid, attend
+
+
+def suffix_segment(tokens, start, suffix_lens):
+    """(positions, valid, attend) of a later segment of a chunked prefill:
+    it attends over the layer's own cache, into which the segment has just
+    been written."""
+    from ..ops.attention import paged_suffix_attention
+
+    b, s = tokens.shape
+    offs = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
+    positions = start[:, None] + offs
+    valid = offs < suffix_lens[:, None]
+
+    def attend(q, k, v, pools, table, layer, window):
+        return paged_suffix_attention(
+            q, pools[0], pools[1], table, start, layer, window=window,
+            q_block=SUFFIX_Q_BLOCK,
+        )
+
+    return positions, valid, attend
+
+
+def scatter_decode_rows(pool, rows, table, pos, active, page_size):
+    """The deferred write of a decode step, ONE scatter: ``rows``
+    [layers of the pool in order ..., b, kvh, hd] at ``pos`` of ``table``;
+    inactive rows go to the out-of-bounds page and are dropped."""
+    b = pos.shape[0]
+    L = rows.size // (b * pool.shape[-1])
+    phys = jnp.take_along_axis(
+        table, (pos // page_size)[:, None], axis=1
+    )[:, 0]
+    if active is not None:
+        phys = jnp.where(active, phys, pool.shape[1])
+    li = jnp.broadcast_to(jnp.arange(L)[:, None], (L, b)).reshape(-1)
+    pi = jnp.broadcast_to(phys[None, :], (L, b)).reshape(-1)
+    si = jnp.broadcast_to((pos % page_size)[None, :], (L, b)).reshape(-1)
+    return pool.at[li, pi, si].set(
+        rows.reshape(L * b, pool.shape[-1]), mode="drop"
+    )
+
+
 def prefill(
     params: Dict[str, Any],
     cfg: LlamaConfig,
@@ -534,10 +637,9 @@ def prefill(
     Returns (logits [b, s, vocab], new_cache). The caller reads logits at
     seq_lens-1 to sample the first generated token.
     """
-    if layer_pattern(cfg) is not None:
-        from . import smallthinker
-
-        return smallthinker.prefill(
+    family = patterned(cfg)
+    if family is not None:
+        return family.prefill(
             params, cfg, tokens, seq_lens, cache, page_table, mesh=mesh
         )
     b, s = tokens.shape
@@ -595,10 +697,9 @@ def prefill_continue(
     """
     from ..ops.attention import paged_suffix_attention
 
-    if layer_pattern(cfg) is not None:
-        from . import smallthinker
-
-        return smallthinker.prefill_continue(
+    family = patterned(cfg)
+    if family is not None:
+        return family.prefill_continue(
             params, cfg, tokens, start, suffix_lens, cache, page_table
         )
     b, s = tokens.shape
@@ -668,10 +769,12 @@ def mixed_step(
     that sample (each segment's last token / each decode row). Padding
     rows write nothing and produce garbage logits.
     """
-    if layer_pattern(cfg) is not None:
+    if patterned(cfg) is not None:
         raise NotImplementedError(
-            f"{type(cfg).__name__}: the packed mixed_step path has no ring "
-            "for sliding-window layers; serve this model on the bucketed path"
+            f"{type(cfg).__name__}: the packed mixed_step path addresses "
+            "sequence state as pages alone and has neither a ring for "
+            "sliding-window layers nor a slot's recurrent state; serve this "
+            "model on the bucketed path"
         )
     (T,) = tokens.shape
     page_size = cache[0].shape[2]
@@ -740,10 +843,9 @@ def decode_step(
     K/V writes drop (scatter to the out-of-bounds page) so replayed steps
     can't corrupt the cache; their logits are garbage the caller ignores.
     """
-    if layer_pattern(cfg) is not None:
-        from . import smallthinker
-
-        return smallthinker.decode_step(
+    family = patterned(cfg)
+    if family is not None:
+        return family.decode_step(
             params, cfg, tokens, positions, cache, page_table, active,
             mesh=mesh,
         )

@@ -13,12 +13,14 @@ from typing import Any, Dict
 
 import jax
 
-from . import llama, moe
+from . import llama, moe, olmo_hybrid
 
 
 def init_params_for(key: jax.Array, cfg: llama.LlamaConfig) -> Dict[str, Any]:
     if isinstance(cfg, moe.MoeConfig):
         params = moe.init_params(key, cfg)
+    elif isinstance(cfg, olmo_hybrid.OlmoHybridConfig):
+        params = olmo_hybrid.init_params(key, cfg)
     else:
         params = llama.init_params(key, cfg)
     return maybe_quantize(cfg, params)
@@ -53,6 +55,8 @@ def _init_fn(cfg: llama.LlamaConfig):
 def logical_axes_for(cfg: llama.LlamaConfig) -> Dict[str, Any]:
     if isinstance(cfg, moe.MoeConfig):
         axes = moe.param_logical_axes(cfg)
+    elif isinstance(cfg, olmo_hybrid.OlmoHybridConfig):
+        axes = olmo_hybrid.param_logical_axes(cfg)
     else:
         axes = llama.param_logical_axes(cfg)
     if getattr(cfg, "quantization", "") == "int8":

@@ -35,19 +35,10 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops.attention import (
-    causal_prefill_attention,
-    paged_decode_attention_inline,
-    paged_suffix_attention,
-)
+from ..ops.attention import paged_decode_attention_inline
 from ..ops.rope import rope_table
 from . import llama, moe
 from .quant import qmat
-
-#: query rows the XLA suffix attention scores at a time: a 1,024-row
-#: segment against a 16k-token table row would otherwise hold 1.9 GB of
-#: float32 scores
-SUFFIX_Q_BLOCK = 128
 
 
 @dataclass(frozen=True)
@@ -158,12 +149,9 @@ def _join_cache(cache, kp, vp, kr, vr):
 
 
 def _periods(cfg):
-    """The period indices, the scan's xs. The body indexes the stacked
-    parameters by layer itself: a layer's small matrices as slices that
-    fuse into the matmuls that read them, the expert stacks whole."""
-    return jnp.arange(
-        cfg.num_layers // len(cfg.window_pattern), dtype=jnp.int32
-    )
+    """The scan's xs (a layer's small matrices are sliced by the body, the
+    expert stacks read whole)."""
+    return llama.period_indices(cfg, len(cfg.window_pattern))
 
 
 def _layer_params(cfg, params, pi, j):
@@ -185,12 +173,6 @@ def _router_logits(x, lp):
             "...h,he->...e", x, lp["router"],
             preferred_element_type=jnp.float32,
         )
-
-
-def _logits(cfg, params, x):
-    x = llama._norm(cfg, x, params["final_norm"])
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    return qmat(x, head).astype(jnp.float32)
 
 
 def _segment(params, cfg, tokens, positions, valid, cache, page_table, attend):
@@ -245,23 +227,14 @@ def _segment(params, cfg, tokens, positions, valid, cache, page_table, attend):
     (x, kp, vp, kr, vr), _ = jax.lax.scan(
         period, (x, kp, vp, kr, vr), _periods(cfg)
     )
-    return _logits(cfg, params, x), _join_cache(cache, kp, vp, kr, vr)
+    return llama.lm_logits(cfg, params, x), _join_cache(cache, kp, vp, kr, vr)
 
 
 def prefill(params, cfg, tokens, seq_lens, cache, page_table, mesh=None):
     """``llama.prefill`` for a patterned model: a cold segment attends over
     its own K and V (the flash kernel, with the layer's window), and writes
     both kinds of cache for what follows."""
-    b, s = tokens.shape
-    positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
-    valid = positions < seq_lens[:, None]
-
-    def attend(q, k, v, pools, table, layer, window):
-        return causal_prefill_attention(
-            q, k, v, seq_lens, impl=cfg.attention_impl, mesh=mesh,
-            window=window,
-        )
-
+    positions, valid, attend = llama.cold_segment(cfg, tokens, seq_lens, mesh)
     return _segment(
         params, cfg, tokens, positions, valid, cache, page_table, attend
     )
@@ -275,17 +248,7 @@ def prefill_continue(
     pages, window layers over its ring, into which the segment has just
     been written (a ring holds window + one segment, so nothing a query of
     the segment still sees has been overwritten)."""
-    b, s = tokens.shape
-    offs = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
-    positions = start[:, None] + offs
-    valid = offs < suffix_lens[:, None]
-
-    def attend(q, k, v, pools, table, layer, window):
-        return paged_suffix_attention(
-            q, pools[0], pools[1], table, start, layer, window=window,
-            q_block=SUFFIX_Q_BLOCK,
-        )
-
+    positions, valid, attend = llama.suffix_segment(tokens, start, suffix_lens)
     return _segment(
         params, cfg, tokens, positions, valid, cache, page_table, attend
     )
@@ -346,21 +309,10 @@ def decode_step(
     x, (k_all, v_all) = jax.lax.scan(period, x, _periods(cfg))
 
     def write(pool, new, table, pos, js):
-        """One scatter: the rows ``new[:, js]`` of every layer of one kind
-        at ``pos`` of ``table``; inactive rows go to the out-of-bounds page
-        and are dropped."""
+        """The rows ``new[:, js]`` of every layer of one kind, one scatter."""
         rows = new[:, jnp.asarray(js)]  # [periods, kind's layers, b, ...]
-        L = rows.shape[0] * rows.shape[1]
-        phys = jnp.take_along_axis(
-            table, (pos // page_size)[:, None], axis=1
-        )[:, 0]
-        if active is not None:
-            phys = jnp.where(active, phys, pool.shape[1])
-        li = jnp.broadcast_to(jnp.arange(L)[:, None], (L, b)).reshape(-1)
-        pi = jnp.broadcast_to(phys[None, :], (L, b)).reshape(-1)
-        si = jnp.broadcast_to((pos % page_size)[None, :], (L, b)).reshape(-1)
-        return pool.at[li, pi, si].set(
-            rows.reshape(L * b, cfg.kv_dim), mode="drop"
+        return llama.scatter_decode_rows(
+            pool, rows, table, pos, active, page_size
         )
 
     with jax.named_scope("kv_write"):
@@ -373,7 +325,7 @@ def decode_step(
             ring_pos = positions % ring_len
             kr = write(kr, k_all, rtable, ring_pos, window_js)
             vr = write(vr, v_all, rtable, ring_pos, window_js)
-    return _logits(cfg, params, x), _join_cache(cache, kp, vp, kr, vr)
+    return llama.lm_logits(cfg, params, x), _join_cache(cache, kp, vp, kr, vr)
 
 
 def reference_logits(

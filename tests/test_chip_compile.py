@@ -333,8 +333,8 @@ def _compile_cell_program(topo, name, program, bucket=None):
         max_batch=args.max_batch, page_size=args.page_size,
         num_pages=args.num_pages, decode_chunk=args.decode_chunk,
         max_prefill_tokens=args.max_prefill_tokens,
-        # the prefix cache refuses a model with window layers
-        prefix_caching=llama.layer_pattern(model) is None,
+        # the prefix cache refuses a model with per-slot state
+        prefix_caching=llama.patterned(model) is None,
     )
     return compiled, cfg, cell, model
 
@@ -429,6 +429,49 @@ def test_loopchat_cell_programs_fit_the_chip(topo, program, bucket):
         tile = (2, 128, model.kv_dim)
         kernels_found = _kernel_vmem_args(text, "paged_decode_inline")
         assert kernels_found and all(k[-2:] == [tile, tile] for k in kernels_found)
+
+
+@pytest.mark.parametrize(
+    "program,bucket", [("chunk", 8), ("prefill", 1024), ("suffix", 1024)]
+)
+def test_hybridmix_cell_programs_fit_the_chip(topo, program, bucket):
+    """The programs of the cell ``olmo-hybrid-7b.hybridmix`` at its real
+    sizes and engine options, compiled for the described chip: the pool is
+    the 4 full-attention layers', the 12 linear layers' recurrent state
+    stands beside it (stored with its minor axis of 192 laid out in 128-lane
+    tiles, a third larger than reckoned), the kernels are in where the
+    family runs them, nothing the size of a layer of the pool or of the
+    whole state is copied (the state's layers are rewritten in place), and
+    arguments + temps are under ISSUE 36's 14.5 GB."""
+    compiled, cfg, cell, model = _compile_cell_program(
+        topo, "olmo-hybrid-7b.hybridmix", program, bucket
+    )
+    d, lay, keys = cell.dims, cfg.kv_layout, cell.family.keys
+    assert (lay.global_layers, lay.state_layers) == (4, 12) == (
+        model.cache_layers, model.linear_layers)
+    assert lay.table_width == 4096 // 16 + 1
+    pages = keys.kv_bytes(d, cfg.num_pages, cfg.page_size)
+    recurrent = keys.state_bytes(d, cfg.max_batch)
+    assert pages == 4112 * 16 * 4 * 15_360 and recurrent == 16 * 12 * 2_280_960
+    assert recurrent == lay.state_nbytes(cfg.max_batch, 2)
+    state = 2 * keys.param_count(d) + pages + recurrent
+    assert 12.6e9 < state < 12.8e9
+    ma = compiled.memory_analysis()
+    # the padded minor axis: 192 -> 256 lanes of float32
+    padded = recurrent + 12 * 16 * 30 * 96 * 64 * 4
+    assert state <= ma.argument_size_in_bytes < state + (padded - recurrent) + 0.05e9
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 14.5e9
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) == (program != "suffix")
+    layer_pool = cfg.num_pages * cfg.page_size * model.kv_dim
+    whole_state = 12 * 16 * 30 * 96 * 192
+    assert whole_state < layer_pool
+    # what writes something the size of the state is a linear layer's update
+    # of the state itself, in place: its output aliases the carried state
+    lines = {line.strip()[:200]: line for line in text.splitlines()}
+    sized = _pool_sized_ops(text, whole_state)
+    assert [row for row in sized if "aliasing" not in lines[row[1]]] == []
+    assert all("f32[12,16,30,96,192]" in row[1] for row in sized)
 
 
 def _kernel_vmem_args(text, name):

@@ -31,7 +31,7 @@ import pytest
 
 from llm_d_fast_model_actuation_tpu.engine import EngineConfig, InferenceEngine
 from llm_d_fast_model_actuation_tpu.engine.engine import (
-    WindowLayersUnsupported,
+    SlotStateUnsupported,
 )
 from llm_d_fast_model_actuation_tpu.engine.kv_cache import KVLayout, PagePool
 from llm_d_fast_model_actuation_tpu.engine.sleep import attach_sleep
@@ -487,7 +487,7 @@ def _refusals():
 
 @pytest.mark.parametrize("what", sorted(_refusals()))
 def test_what_cannot_carry_a_ring_refuses_the_model_by_name(what):
-    with pytest.raises((WindowLayersUnsupported, NotImplementedError)) as err:
+    with pytest.raises((SlotStateUnsupported, NotImplementedError)) as err:
         _refusals()[what]()
     assert "SmallThinkerConfig" in str(err.value)
 
