@@ -176,6 +176,16 @@ def resolve_attention_impl(impl: str, model, tp: int = 1) -> str:
     return "grouped"
 
 
+def _host_device():
+    """The CPU backend's device, or None (no override: the default device)
+    where this process was started without that backend. Looked up at
+    every use: a device release tears the backends down."""
+    try:
+        return jax.devices("cpu")[0]
+    except RuntimeError:
+        return None
+
+
 def prefill_bucket(n: int, seq_len: int) -> int:
     """Power-of-two prefill shape bucket (floor 16) clamped to seq_len —
     the ONE definition shared by live dispatch and the AOT warmup plan
@@ -1813,13 +1823,19 @@ class InferenceEngine:
                 req.rng_key_data, dtype=np.uint32
             )
             return
-        if req.seed is not None:
-            k = jax.random.key(int(req.seed))
-        else:
-            k = jax.random.fold_in(
-                jax.random.key(self._seed + 1), req.seq_id
-            )
-        self._slot_keys[req.slot] = np.asarray(jax.random.key_data(k))
+        # Three tiny programs and a read-back, on the HOST's backend: the
+        # chip runs programs in launch order, so on it the read-back of a
+        # step's second admission would return when the first prompt's
+        # prefill has ended (_prefill_waiting). Threefry is integer
+        # arithmetic: the key is the same bit for bit wherever it is made.
+        with jax.default_device(_host_device()):
+            if req.seed is not None:
+                k = jax.random.key(int(req.seed))
+            else:
+                k = jax.random.fold_in(
+                    jax.random.key(self._seed + 1), req.seq_id
+                )
+            self._slot_keys[req.slot] = np.asarray(jax.random.key_data(k))
 
     def _free_slot(self) -> Optional[int]:
         for i, s in enumerate(self._slots):
@@ -1827,12 +1843,18 @@ class InferenceEngine:
                 return i
         return None
 
-    def _try_admit(self, req: Request) -> bool:
+    def _try_admit(
+        self, req: Request, prefill_unfetched: bool = False
+    ) -> bool:
         """``_admit`` of the head of the waiting queue as the scheduler
         loop calls it: the ``sched.admit`` phase, and the count of steps
         in which the head was refused (a refusal ends a step's
-        admissions, so there is at most one a step)."""
-        with tracing.phase("sched.admit", self.chunk_in_flight) as ph:
+        admissions, so there is at most one a step). ``prefill_unfetched``
+        says the step's previous prefill is still running on the device
+        (``_prefill_waiting``): the admission then holds nothing back."""
+        with tracing.phase(
+            "sched.admit", prefill_unfetched or self.chunk_in_flight
+        ) as ph:
             admitted = self._admit(req)
             if admitted:
                 ph.set(admitted=1)
@@ -2021,8 +2043,25 @@ class InferenceEngine:
         return tok, lp, av, ai, plp, new_key
 
     def _run_prefill(self, req: Request) -> None:
-        overlapped = self.chunk_in_flight
+        """One prompt, dispatched and finished at once: the packed
+        path's echo fallback. The bucketed loop runs the two halves one
+        request apart (``_prefill_waiting``)."""
+        self._finish_prefill(self._dispatch_prefill(req))
+
+    def _dispatch_prefill(
+        self, req: Request, prefill_unfetched: bool = False
+    ) -> Tuple[Request, list, list]:
+        """The dispatch half of a prompt: every program of it (one cold
+        segment, or the suffix segments in order) is on the device's
+        queue when this returns and nothing of it has been read. Returns
+        what ``_finish_prefill`` fetches. ``prefill_unfetched`` says the
+        step's previous prompt is still running there: the pool threads
+        this one behind it, and the host's work here holds nothing back."""
+        overlapped = prefill_unfetched or self.chunk_in_flight
         with tracing.phase("sched.prefill_dispatch", overlapped) as ph:
+            if prefill_unfetched:
+                tracing.count_prefill_overlapped()
+            ph.set(overlapped=int(prefill_unfetched))
             n = len(req.prompt)
             temp = np.asarray([req.temperature], dtype=np.float32)
             topp = np.asarray([req.top_p], dtype=np.float32)
@@ -2031,6 +2070,8 @@ class InferenceEngine:
             freq = np.asarray([req.frequency_penalty], dtype=np.float32)
             k = req.cached_tokens
             limit = self.cfg.max_prefill_tokens or (n - k)
+            # (device ref, entries taken) a segment, if the request asks
+            plp_parts = []
             if k == 0 and n <= limit:
                 # single cold segment: the flash-style causal program
                 table = self._page_table[req.slot : req.slot + 1]
@@ -2081,7 +2122,6 @@ class InferenceEngine:
                 # the continue program in segments of <= limit tokens; only the
                 # final segment's sample is consumed
                 pos = k
-                plp_parts = []
                 ph.set(
                     program="suffix", prompt_tokens=n, cached_tokens=k,
                     bucket=self._prefill_bucket(min(limit, n - k)),
@@ -2119,6 +2159,20 @@ class InferenceEngine:
                 fetch += [av, ai]
             if req.want_prompt_logprobs:
                 fetch += [p for p, _ in plp_parts]
+        return req, fetch, plp_parts
+
+    def _finish_prefill(
+        self,
+        dispatched: Tuple[Request, list, list],
+        prefill_unfetched: bool = False,
+    ) -> None:
+        """The finish half: the one blocking read of a dispatched
+        prompt's results, then its first token (and with it the slot's
+        key and sampling mirrors). ``prefill_unfetched`` says the NEXT
+        prompt of the step is already on the device's queue."""
+        req, fetch, plp_parts = dispatched
+        n = len(req.prompt)
+        overlapped = prefill_unfetched or self.chunk_in_flight
         with tracing.phase("sched.prefill_fetch", overlapped):
             vals = list(jax.device_get(tuple(fetch)))
         with tracing.phase("sched.emit", overlapped) as ph:
@@ -2845,15 +2899,7 @@ class InferenceEngine:
             self._step_packed(finished)
 
         if not packed_mode:
-            while self._waiting:
-                req = self._waiting[0]
-                if not self._try_admit(req):
-                    break
-                self._waiting.pop(0)
-                self._run_prefill(req)
-                if req.done:
-                    self._retire(req)
-                    finished.append(req)
+            self._prefill_waiting(finished)
 
         # speculation never interleaves with an in-flight chunk: a verify
         # forward would race the chunk's decode of the same slot
@@ -2918,6 +2964,40 @@ class InferenceEngine:
         if running:
             self._inflight = self._dispatch_chunk(running)
         return finished
+
+    def _prefill_waiting(self, finished: List[Request]) -> None:
+        """Admit and prefill the waiting queue from its head until a
+        request is refused, one prompt ahead of the device: request i+1
+        is admitted and dispatched BEFORE request i's results are read,
+        so the host's work for i+1 runs under i's prefill and not before
+        an idle chip. Nothing i+1's admission and dispatch need is a
+        result of i, or waits for the device (a slot, pages, a table row,
+        the prompt's counts, a key made on the host's backend), the
+        pool threads i+1 behind i on the device, and a slot's key and
+        sampling mirrors are written in its own finish half, before any
+        upload. Depth one: i's first token waits for one successor's
+        admission, never for the queue. Every prompt is finished before
+        this returns; the pipeline is this loop's local. A request that
+        ends on its first token frees its slot and pages one admission
+        later than if it were fetched first."""
+        unfetched = None
+        while True:
+            dispatched = None
+            if self._waiting and self._try_admit(
+                self._waiting[0], unfetched is not None
+            ):
+                dispatched = self._dispatch_prefill(
+                    self._waiting.pop(0), unfetched is not None
+                )
+            if unfetched is not None:
+                self._finish_prefill(unfetched, dispatched is not None)
+                req = unfetched[0]
+                if req.done:
+                    self._retire(req)
+                    finished.append(req)
+            if dispatched is None:
+                return
+            unfetched = dispatched
 
     def _running(self) -> Dict[int, Request]:
         # mid-prefill slots (packed serving) are not decodable yet: their

@@ -183,9 +183,10 @@ def reset_after_fork() -> None:
     Request sampling resets to 0 (off): the child re-applies its own
     ``--trace-requests`` during engine construction."""
     global _BUFFER, _enabled, _REQ_BUFFER, _req_frac, _capturing
-    global _admit_blocked, _emit_deliveries, _emit_tokens
+    global _admit_blocked, _emit_deliveries, _emit_tokens, _prefills_overlapped
     _capturing = False
     _admit_blocked = 0
+    _prefills_overlapped = 0
     _emit_deliveries = _emit_tokens = 0
     for name in PHASES:
         _PHASES[name] = Phase(name)
@@ -606,6 +607,10 @@ _admit_blocked = 0
 #: decode and 1 if a delivery is ever made a token at a time
 _emit_deliveries = 0
 _emit_tokens = 0
+#: prefills dispatched while an earlier prefill of the same step was still
+#: unfetched (``engine.py:_prefill_waiting``); ``phase_n.prefill_dispatch``
+#: is its denominator
+_prefills_overlapped = 0
 
 
 class Phase:
@@ -621,7 +626,7 @@ class Phase:
         self.name = name
         self.seconds = 0.0
         self.count = 0
-        #: the seconds of it in which no dispatched chunk was in flight
+        #: the seconds of it in which no dispatched program was in flight
         self.host_only_s = 0.0
         self._overlapped = False
         self._t0 = 0.0
@@ -659,10 +664,11 @@ _PHASES: Dict[str, Phase] = {name: Phase(name) for name in PHASES}
 
 
 def phase(name: str, overlapped: bool = False) -> Phase:
-    """``with tracing.phase("sched.admit", chunk_in_flight): ...`` — the
+    """``with tracing.phase("sched.admit", program_in_flight): ...`` — the
     phase named ``name`` (one of :data:`PHASES`). ``overlapped`` says a
-    dispatched decode chunk is still running on the device, so the phase's
-    seconds are not the host holding the chip back (``host_only_s``)."""
+    dispatched program is still unfetched, a decode chunk or the step's
+    previous prefill, so the phase's seconds are not the host holding the
+    chip back (``host_only_s``)."""
     p = _PHASES[name]
     p._overlapped = overlapped
     return p
@@ -671,6 +677,11 @@ def phase(name: str, overlapped: bool = False) -> Phase:
 def count_admit_blocked() -> None:
     global _admit_blocked
     _admit_blocked += 1
+
+
+def count_prefill_overlapped() -> None:
+    global _prefills_overlapped
+    _prefills_overlapped += 1
 
 
 def count_emit_delivery(tokens: int) -> None:
@@ -720,10 +731,12 @@ def phase_stats() -> Dict[str, Any]:
     """The ``scheduler`` block of ``GET /v1/stats``, cumulative since the
     process started: seconds and entries of each phase (keys without the
     ``sched.`` prefix), ``host_only_s``, the seconds of every phase but
-    the waiting ones in which no dispatched chunk was in flight, and
-    ``admit_blocked``, the steps in which the head of the waiting queue
-    was refused a slot or pages, and ``emit_deliveries`` / ``emit_tokens``,
-    the streaming-hook calls of ``sched.emit`` and the tokens they carried."""
+    the waiting ones in which no dispatched program (a decode chunk, the
+    step's previous prefill) was in flight, ``admit_blocked``, the steps
+    in which the head of the waiting queue was refused a slot or pages,
+    ``prefills_overlapped``, the prefills dispatched under an earlier one
+    of their step, and ``emit_deliveries`` / ``emit_tokens``, the
+    streaming-hook calls of ``sched.emit`` and the tokens they carried."""
     rows = [(p.name.partition(".")[2], p) for p in _PHASES.values()]
     return {
         "phase_s": {k: p.seconds for k, p in rows},
@@ -732,6 +745,7 @@ def phase_stats() -> Dict[str, Any]:
             p.host_only_s for _, p in rows if p.name not in WAITING_PHASES
         ),
         "admit_blocked": _admit_blocked,
+        "prefills_overlapped": _prefills_overlapped,
         "emit_deliveries": _emit_deliveries,
         "emit_tokens": _emit_tokens,
     }

@@ -93,11 +93,104 @@ def test_every_phase_counts_and_the_sum_is_the_loops_wall_time(service):
         s for k, s in spent.items()
         if "sched." + k not in tracing.WAITING_PHASES
     )
-    # the default loop is synchronous: nothing is in flight during any
-    # phase, so host-only is every phase but the fetches and the wait
-    assert host_only == pytest.approx(not_waiting, rel=1e-6, abs=1e-6)
+    # the default loop has no chunk in flight during any phase: host-only
+    # is every phase but the fetches and the wait, less what a step's
+    # second and later prompts spend under the one before them
+    assert 0 < host_only <= not_waiting + 1e-6
+    assert "prefills_overlapped" in after
     assert spent["wait"] > 0 and spent["chunk_fetch"] > 0
     assert host_only < sum(spent.values()) - spent["wait"]
+
+
+def _engine(slots: int = 4):
+    from llm_d_fast_model_actuation_tpu.engine import (
+        EngineConfig,
+        InferenceEngine,
+    )
+    from llm_d_fast_model_actuation_tpu.models import llama
+
+    return InferenceEngine(
+        EngineConfig(
+            model=llama.LlamaConfig.tiny(), max_batch=slots, page_size=8,
+            num_pages=64, max_seq_len=64, decode_chunk=4,
+        ),
+        seed=0,
+    )
+
+
+def _phase_rows(names):
+    return {
+        n: (
+            tracing._PHASES["sched." + n].seconds,
+            tracing._PHASES["sched." + n].host_only_s,
+            tracing._PHASES["sched." + n].count,
+        )
+        for n in names
+    }
+
+
+def test_host_only_keeps_a_steps_first_prompt_and_leaves_out_the_overlapped():
+    """`sched.admit` and `sched.prefill_dispatch` of a step's first prompt
+    run before an idle chip and are `host_only_s`; those of its second and
+    third run under the prompt before them and are not; nor is a first
+    token's `sched.emit` with the next prompt dispatched."""
+    eng = _engine()
+    eng.generate([PROMPT], max_new_tokens=2)  # compiled
+    names = ("admit", "prefill_dispatch", "emit")
+    me = threading.current_thread().name
+    # one waiting: the step is the serial one, every second host-only
+    before, n0 = _phase_rows(names), tracing.phase_stats()["prefills_overlapped"]
+    eng.add_request(PROMPT, 2)
+    eng.step()
+    after = _phase_rows(names)
+    for n in names:
+        seconds, host_only, count = (a - b for a, b in zip(after[n], before[n]))
+        assert count >= 1 and seconds > 0
+        assert host_only == pytest.approx(seconds, abs=1e-9), n
+    assert tracing.phase_stats()["prefills_overlapped"] == n0
+    while eng.has_work():
+        eng.step()
+    # three waiting, read through a capture's spans
+    with mock.patch("jax.profiler.TraceAnnotation"):
+        tracing.capture_started()
+        before = _phase_rows(names)
+        for i in range(3):
+            eng.add_request(PROMPT[i:], 2)
+        eng.step()
+        after = _phase_rows(names)
+        tracing.capture_stopped()
+    assert tracing.phase_stats()["prefills_overlapped"] == n0 + 2
+    spans = sorted(
+        (
+            s for s in tracing.snapshot()
+            if s.name.startswith("sched.") and s.thread == me
+        ),
+        key=lambda s: s.start_s,
+    )
+    order = [s.name.partition(".")[2] for s in spans]
+    assert order == [
+        "admit", "prefill_dispatch",
+        "admit", "prefill_dispatch", "prefill_fetch", "emit",
+        "admit", "prefill_dispatch", "prefill_fetch", "emit",
+        "prefill_fetch", "emit",
+        "upload", "chunk_dispatch", "chunk_fetch", "emit",
+    ]
+    # phases do not nest: each ends before the next begins
+    for a, b in zip(spans, spans[1:]):
+        assert a.end_s <= b.start_s, (a.name, b.name)
+    dispatches = [s for s in spans if s.name == "sched.prefill_dispatch"]
+    assert [s.attrs["overlapped"] for s in dispatches] == [0, 1, 1]
+    for n in ("admit", "prefill_dispatch"):
+        seconds, host_only, count = (a - b for a, b in zip(after[n], before[n]))
+        first = next(s for s in spans if s.name == "sched." + n)
+        assert count == 3
+        # the span encloses the phase's own two clock reads
+        assert 0 < host_only <= first.duration_s
+        assert host_only < seconds
+    seconds, host_only, count = (
+        a - b for a, b in zip(after["emit"], before["emit"])
+    )
+    assert count == 4 and 0 < host_only < seconds
 
 
 def test_admit_blocked_counts_steps_with_more_waiting_than_slots(service):
@@ -205,15 +298,25 @@ def test_emit_counts_one_delivery_a_request_a_chunk(service):
 
 
 @pytest.mark.parametrize(
-    "name, cell",
+    "name, cell, at_open, at_close, want",
     [
-        ("sched_emit_s.batch", "mixtral-8x7b.batch"),
-        ("sched_emit_s.longmix", "smallthinker-21b.longmix"),
+        ("sched_emit_s.batch", "mixtral-8x7b.batch",
+         {"phase_s": {"emit": 0.5, "upload": 1.0}},
+         {"phase_s": {"emit": 1.75, "upload": 9.0}}, 1.25),
+        ("sched_emit_s.longmix", "smallthinker-21b.longmix",
+         {"phase_s": {"emit": 0.5, "upload": 1.0}},
+         {"phase_s": {"emit": 1.75, "upload": 9.0}}, 1.25),
+        ("prefills_overlapped.batch", "mixtral-8x7b.batch",
+         {"prefills_overlapped": 40, "admit_blocked": 1},
+         {"prefills_overlapped": 425, "admit_blocked": 9}, 385),
     ],
 )
-def test_sched_emit_metric_reads_the_emit_phase_of_its_cell(name, cell):
-    """The benchmark's `sched_emit_s.*` are data files that read this
-    module's `phase_s.emit`, close less open, in the one cell they name."""
+def test_scheduler_metric_reads_its_counter_in_the_one_cell_it_names(
+    name, cell, at_open, at_close, want
+):
+    """The benchmark's `sched_emit_s.*` and `prefills_overlapped.batch` are
+    data files that read this module's `scheduler` block, close less open,
+    in the one cell they name."""
     from fmabench import readers, spec
 
     bench = spec.benchmark()
@@ -222,11 +325,12 @@ def test_sched_emit_metric_reads_the_emit_phase_of_its_cell(name, cell):
     assert rows[name]["moves"] == "out_tokens_per_s"
     assert rows[name]["workloads"] == [cell]
     ev = readers.Evidence()
-    ev.stats_open = {"scheduler": {"phase_s": {"emit": 0.5, "upload": 1.0}}}
-    ev.stats_close = {"scheduler": {"phase_s": {"emit": 1.75, "upload": 9.0}}}
-    assert readers.read_metric(rows[name]["reader"], ev) == 1.25
-    # a program with no such phase gives nothing, not a zero
-    ev.stats_open = ev.stats_close = {}
+    ev.stats_open = {"scheduler": at_open}
+    ev.stats_close = {"scheduler": at_close}
+    assert readers.read_metric(rows[name]["reader"], ev) == want
+    # a program with no such counter (the parent of the PR that brought
+    # it) gives nothing, not a zero
+    ev.stats_open = ev.stats_close = {"scheduler": {"admit_blocked": 3}}
     assert readers.read_metric(rows[name]["reader"], ev) is None
     for w in bench["workloads"]:
         if w["name"] != cell:
