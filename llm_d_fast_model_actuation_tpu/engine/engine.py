@@ -1062,24 +1062,37 @@ class InferenceEngine:
             import dataclasses
 
             m = dataclasses.replace(m, attention_impl=impl)
-        if params is None:
-            from ..models.registry import init_params_placed
+        # The weights, then the pool, each a stage of the process's start
+        # (utils/tracing.py: no-ops once the server listens). A stage ends
+        # when its arrays are READY, so that the next does not inherit its
+        # device time; a later build (swap, wake) keeps its asynchrony.
+        with tracing.stage("start.weights") as st:
+            if params is None:
+                from ..models.registry import init_params_placed
 
-            params = init_params_placed(jax.random.key(seed), m, mesh)
-        elif mesh is not None:
-            from ..models.registry import logical_axes_for
+                params = init_params_placed(jax.random.key(seed), m, mesh)
+            elif mesh is not None:
+                from ..models.registry import logical_axes_for
 
-            params = shard_pytree(params, mesh, logical_axes_for(m))
-        else:
-            # Commit to the default device: committed-ness is part of the jit
-            # cache key, and the post-wake device_put restore produces
-            # committed arrays — starting committed keeps one compiled set.
-            params = jax.device_put(params, jax.devices()[0])
+                params = shard_pytree(params, mesh, logical_axes_for(m))
+            else:
+                # Commit to the default device: committed-ness is part of
+                # the jit cache key, and the post-wake device_put restore
+                # produces committed arrays — starting committed keeps one
+                # compiled set.
+                params = jax.device_put(params, jax.devices()[0])
+            if st.timing:
+                jax.block_until_ready(params)
+                st.set(bytes=sum(x.nbytes for x in jax.tree.leaves(params)))
         self.params = params
         #: pages for the full-attention layers, rings for the window layers
         self.kv_layout = cfg.kv_layout
         self._model_cfg = m
-        self._create_pool()
+        with tracing.stage("start.pool") as st:
+            self._create_pool()
+            if st.timing:
+                jax.block_until_ready(self.pool.as_tuple())
+                st.set(bytes=self.pool.nbytes())
         self.allocator = PageAllocator(cfg.num_pages)
         if cfg.prefix_caching:
             from .prefix_cache import PrefixCache
@@ -1163,9 +1176,10 @@ class InferenceEngine:
         # One ProgramSet per engine (jit caches key on function identity,
         # so two engines never share a cache); the flat _*_fn attributes
         # keep the historical names the lockstep follower replays through.
-        self.programs = ProgramSet(
-            m, cfg.logprobs_topk, cfg.eos_token_id, mesh=mesh
-        )
+        with tracing.stage("start.programs"):
+            self.programs = ProgramSet(
+                m, cfg.logprobs_topk, cfg.eos_token_id, mesh=mesh
+            )
         self._prefill_fn = self.programs.prefill
         self._prefill_plp_fn = self.programs.prefill_plp
         self._suffix_prefill_fn = self.programs.suffix

@@ -1425,10 +1425,13 @@ class EngineService:
         # flag — which pricing peeks read — depends on this answer.
         import jax  # deliberately not module-level: parse-time must not touch a backend
 
+        # the first touch of the backend: PJRT client, libtpu (near 0 where
+        # the entry point already made the client)
+        with tracing.stage("start.backend"):
+            backend = jax.default_backend()
         mode = getattr(args, "sleep_release_devices", "auto")
         self.release_on_sleep = (
-            mode == "always"
-            or (mode == "auto" and jax.default_backend() == "tpu")
+            mode == "always" or (mode == "auto" and backend == "tpu")
         )
         if dist is not None:
             # gang sleep is offload-only: device release would require
@@ -1439,20 +1442,15 @@ class EngineService:
             raise ValueError(
                 "--zero-drain is not supported for multi-host gangs"
             )
-        # The startup span parents on FMA_TRACEPARENT when the spawning
-        # launcher stamped one (utils/tracing.py): the child's initial
-        # build joins the create-instance trace across the fork.
-        with tracing.span(
-            "engine.start",
-            parent=tracing.env_context(),
-            model=args.model,
-            pid=os.getpid(),
-        ):
-            self._install_runtime(
-                self._build_runtime(
-                    args.model, getattr(args, "checkpoint_dir", "") or ""
-                )
+        # Under run_server's engine.start span, which parents on
+        # FMA_TRACEPARENT when the spawning launcher stamped one
+        # (utils/tracing.py:startup_begin): the child's initial build joins
+        # the create-instance trace across the fork.
+        self._install_runtime(
+            self._build_runtime(
+                args.model, getattr(args, "checkpoint_dir", "") or ""
             )
+        )
         # first flight-recorder row: the initial cold build — trigger
         # "restart" when a supervising launcher re-spawned this child
         # (launcher/instance.py stamps FMA_RESTARTED around the fork), so
@@ -1488,12 +1486,24 @@ class EngineService:
             self.engine.lockstep = LockstepLeader(self.engine)
         self._publisher = self._make_publisher()
         self._publish_usage()
+        tracing.describe_slow_entries_with(self._describe_slow_entry)
         self._thread = threading.Thread(
             target=self._run_follower if self.is_follower else self._run,
             daemon=True,
             name="engine-loop",
         )
         self._thread.start()
+
+    def _describe_slow_entry(self, overlapped: bool) -> str:
+        """What a slow scheduler entry's log line says of the engine
+        (utils/tracing.py:_count_slow; scheduler thread only)."""
+        eng = self.engine
+        return "live sequences %d, waiting %d, in flight: %s" % (
+            sum(1 for s in eng._slots if s is not None),
+            len(eng._waiting),
+            "chunk" if eng.chunk_in_flight
+            else "prefill" if overlapped else "nothing",
+        )
 
     def _count_abort(self, cause: str, n: int = 1) -> None:
         """One abort-accounting choke point: the Prometheus counter's
@@ -1946,9 +1956,15 @@ class EngineService:
         an already-computed ``_resolve_model`` tuple (the swap path
         resolves once and shares it with the warmup kick)."""
         args = self.args
-        if resolved is None:
-            resolved = self._resolve_model(model_id)
-        model_cfg, eos_token_id, extra_eos, hf_dir, tokenizer = resolved
+        with tracing.stage("start.resolve"):
+            if resolved is None:
+                resolved = self._resolve_model(model_id)
+            model_cfg, eos_token_id, extra_eos, hf_dir, tokenizer = resolved
+            # resolving the attention implementation imports the kernels
+            # (jax's Pallas: a second of a process's first build)
+            engine_cfg = self._engine_cfg_for(
+                model_cfg, eos_token_id, extra_eos
+            )
         mesh = None
         if args.tensor_parallel_size > 1:
             from ..parallel.mesh import serving_mesh
@@ -1971,102 +1987,113 @@ class EngineService:
         #: tiered pool's and the delta-swap's weight identity
         digests: Optional[Dict[str, str]] = staged_digests
         t_load0 = time.monotonic()
-        if checkpoint_dir and staged_params is None:
-            from ..models import checkpoint
+        # a checkpoint or HF load is the first part of start.weights; the
+        # engine's constructor times the rest (placement, or the seeded init)
+        loads = bool(checkpoint_dir or hf_dir or staged_params is not None)
+        with (
+            tracing.stage("start.weights") if loads else tracing.NOOP_SPAN
+        ) as weights_stage:
+            if checkpoint_dir and staged_params is None:
+                from ..models import checkpoint
 
-            ckpt_stats: Dict[str, Any] = {}
-            params = checkpoint.load_params(
-                checkpoint_dir, model_cfg, mesh=mesh, stats_out=ckpt_stats
-            )
-            if self._content_hash:
-                digests = ckpt_stats.get("digests") or None
-            # Orbax restores each leaf straight into its device placement:
-            # the restore wall IS the cold H2D window (read inseparable)
-            build_stats["h2d_s"] = ckpt_stats.get(
-                "restore_s", time.monotonic() - t_load0
-            )
-            import jax as _jax
-
-            self.costs.observe_transfer(
-                "coldload.h2d",
-                sum(x.nbytes for x in _jax.tree.leaves(params)),
-                build_stats["h2d_s"],
-            )
-        elif hf_dir or staged_params is not None:
-            from ..models import hf as hf_models
-
-            lstats = hf_models.LoadStats()
-            if staged_params is not None:
-                # prefetched host weights: no disk read, just the stream in
-                params = hf_models.place_staged_params(
-                    staged_params, model_cfg, mesh=mesh,
-                    max_inflight_bytes=inflight, stats=lstats,
-                )
-                if staged_quant is not None:
-                    # quantized staging (--sleep-quant prefetch): the
-                    # placement streamed int8/fp8 payloads (half the PCIe
-                    # bytes); expand to serving precision on device,
-                    # aligned by flatten order with the staged tree
-                    import jax
-
-                    from ..models import quant as transfer_quant
-
-                    leaves, treedef = jax.tree.flatten(params)
-                    if len(staged_quant) != len(leaves):
-                        # fail LOUD: serving raw int8 payloads as weights
-                        # would be silent garbage, never a slow path
-                        raise RuntimeError(
-                            "quantized staging metadata does not align "
-                            f"with the placed tree ({len(staged_quant)} "
-                            f"metas vs {len(leaves)} leaves)"
-                        )
-                    payloads = []
-                    for i, meta in enumerate(staged_quant):
-                        if meta is None:
-                            continue
-                        payloads.append(leaves[i])
-                        leaves[i] = transfer_quant.dequantize_leaf(
-                            leaves[i], meta
-                        )
-                    params = jax.tree.unflatten(treedef, leaves)
-                    params = jax.block_until_ready(params)
-                    for p in payloads:
-                        p.delete()
-            else:
-                # pipelined cold load: parallel shard readers + streaming
-                # placement straight into the serving sharding
-                params = hf_models.load_params(
-                    hf_dir, model_cfg, mesh=mesh,
-                    workers=getattr(args, "load_workers", 0) or None,
-                    max_inflight_bytes=inflight, stats=lstats,
-                    want_digests=self._content_hash,
+                ckpt_stats: Dict[str, Any] = {}
+                params = checkpoint.load_params(
+                    checkpoint_dir, model_cfg, mesh=mesh, stats_out=ckpt_stats
                 )
                 if self._content_hash:
-                    digests = dict(lstats.digests) or None
-                for phase, v in (
-                    ("read", lstats.read_s),
-                    ("convert", lstats.convert_s),
-                    ("h2d", lstats.h2d_s),
-                    ("total", lstats.total_s),
-                ):
-                    ENGINE_COLDLOAD_PHASE_SECONDS.labels(
-                        model=model_id, phase=phase
-                    ).set(v)
-                ENGINE_COLDLOAD_OVERLAP_FRAC.labels(model=model_id).set(
-                    lstats.overlap_frac
+                    digests = ckpt_stats.get("digests") or None
+                # Orbax restores each leaf straight into its device placement:
+                # the restore wall IS the cold H2D window (read inseparable)
+                build_stats["h2d_s"] = ckpt_stats.get(
+                    "restore_s", time.monotonic() - t_load0
                 )
-            build_stats.update(
-                h2d_s=lstats.h2d_s,
-                buckets_in=lstats.buckets_h2d,
-                overlap_s=lstats.overlap_s,
-                overlap_frac=lstats.overlap_frac,
-            )
-            for kind, b, s in lstats.transfer_figures():
-                self.costs.observe_transfer(kind, b, s)
+                import jax as _jax
+
+                self.costs.observe_transfer(
+                    "coldload.h2d",
+                    sum(x.nbytes for x in _jax.tree.leaves(params)),
+                    build_stats["h2d_s"],
+                )
+            elif hf_dir or staged_params is not None:
+                from ..models import hf as hf_models
+
+                lstats = hf_models.LoadStats()
+                if staged_params is not None:
+                    # prefetched host weights: no disk read, just the stream in
+                    params = hf_models.place_staged_params(
+                        staged_params, model_cfg, mesh=mesh,
+                        max_inflight_bytes=inflight, stats=lstats,
+                    )
+                    if staged_quant is not None:
+                        # quantized staging (--sleep-quant prefetch): the
+                        # placement streamed int8/fp8 payloads (half the PCIe
+                        # bytes); expand to serving precision on device,
+                        # aligned by flatten order with the staged tree
+                        import jax
+
+                        from ..models import quant as transfer_quant
+
+                        leaves, treedef = jax.tree.flatten(params)
+                        if len(staged_quant) != len(leaves):
+                            # fail LOUD: serving raw int8 payloads as weights
+                            # would be silent garbage, never a slow path
+                            raise RuntimeError(
+                                "quantized staging metadata does not align "
+                                f"with the placed tree ({len(staged_quant)} "
+                                f"metas vs {len(leaves)} leaves)"
+                            )
+                        payloads = []
+                        for i, meta in enumerate(staged_quant):
+                            if meta is None:
+                                continue
+                            payloads.append(leaves[i])
+                            leaves[i] = transfer_quant.dequantize_leaf(
+                                leaves[i], meta
+                            )
+                        params = jax.tree.unflatten(treedef, leaves)
+                        params = jax.block_until_ready(params)
+                        for p in payloads:
+                            p.delete()
+                else:
+                    # pipelined cold load: parallel shard readers + streaming
+                    # placement straight into the serving sharding
+                    params = hf_models.load_params(
+                        hf_dir, model_cfg, mesh=mesh,
+                        workers=getattr(args, "load_workers", 0) or None,
+                        max_inflight_bytes=inflight, stats=lstats,
+                        want_digests=self._content_hash,
+                    )
+                    if self._content_hash:
+                        digests = dict(lstats.digests) or None
+                    for phase, v in (
+                        ("read", lstats.read_s),
+                        ("convert", lstats.convert_s),
+                        ("h2d", lstats.h2d_s),
+                        ("total", lstats.total_s),
+                    ):
+                        ENGINE_COLDLOAD_PHASE_SECONDS.labels(
+                            model=model_id, phase=phase
+                        ).set(v)
+                    ENGINE_COLDLOAD_OVERLAP_FRAC.labels(model=model_id).set(
+                        lstats.overlap_frac
+                    )
+                    weights_stage.set(
+                        read_s=round(lstats.read_s, 6),
+                        convert_s=round(lstats.convert_s, 6),
+                        h2d_s=round(lstats.h2d_s, 6),
+                    )
+                build_stats.update(
+                    h2d_s=lstats.h2d_s,
+                    buckets_in=lstats.buckets_h2d,
+                    overlap_s=lstats.overlap_s,
+                    overlap_frac=lstats.overlap_frac,
+                )
+                for kind, b, s in lstats.transfer_figures():
+                    self.costs.observe_transfer(kind, b, s)
         import jax  # deliberately not module-level: parse-time must not touch a backend
 
         engine = InferenceEngine(
-            self._engine_cfg_for(model_cfg, eos_token_id, extra_eos),
+            engine_cfg,
             params=params,
             mesh=mesh,
             seed=args.seed,
@@ -2091,43 +2118,45 @@ class EngineService:
             from .exec_pool import exec_signature, mesh_shape
 
             t_transfer1 = time.monotonic()
-            if warmup.signature == exec_signature(
-                engine.cfg, mesh_shape(engine.mesh)
-            ):
-                warmup.install(engine, timeout=600)
-            else:
-                warmup.abort()
-                warmup.wait(5)
-                warmup.stats["errors"].append(
-                    "signature mismatch with built engine; not installed"
-                )
+            with tracing.stage("start.programs"):
+                if warmup.signature == exec_signature(
+                    engine.cfg, mesh_shape(engine.mesh)
+                ):
+                    warmup.install(engine, timeout=600)
+                else:
+                    warmup.abort()
+                    warmup.wait(5)
+                    warmup.stats["errors"].append(
+                        "signature mismatch with built engine; not installed"
+                    )
             build_stats["warmup"] = warmup.overlap_stats(
                 window_t1=t_transfer1
             )
             self._last_warmup = warmup
         self._last_build_stats = build_stats
-        sleeper = attach_sleep(
-            engine,
-            bucket_bytes=self._swap_bucket_bytes,
-            quant_mode=self._sleep_quant,
-            quant_hot_head=self._sleep_quant_hot_head,
-            on_transfer=self.costs.observe_transfer,
-        )
-        if self._sleep_quant != "off" and not self.is_gang:
-            # move the quantize/dequantize op compiles off the first
-            # actuation's transfer window (and out of the cost oracle's
-            # first bandwidth measurements) — the build already pays
-            # compile time, this rides with it
-            try:
-                sleeper.warm_quant_ops()
-            except Exception:  # noqa: BLE001 — warmup is best-effort
-                logger.warning(
-                    "transfer-quant op warmup failed", exc_info=True
-                )
-        # zero-drain pricing contract (engine/sleep.py peek_state): the
-        # oracle's offload peeks exclude the KV pool exactly when an
-        # actual offload of this engine will park first
-        engine.zero_drain_park = self._zero_drain_parks()
+        with tracing.stage("start.sleeper"):
+            sleeper = attach_sleep(
+                engine,
+                bucket_bytes=self._swap_bucket_bytes,
+                quant_mode=self._sleep_quant,
+                quant_hot_head=self._sleep_quant_hot_head,
+                on_transfer=self.costs.observe_transfer,
+            )
+            if self._sleep_quant != "off" and not self.is_gang:
+                # move the quantize/dequantize op compiles off the first
+                # actuation's transfer window (and out of the cost oracle's
+                # first bandwidth measurements) — the build already pays
+                # compile time, this rides with it
+                try:
+                    sleeper.warm_quant_ops()
+                except Exception:  # noqa: BLE001 — warmup is best-effort
+                    logger.warning(
+                        "transfer-quant op warmup failed", exc_info=True
+                    )
+            # zero-drain pricing contract (engine/sleep.py peek_state): the
+            # oracle's offload peeks exclude the KV pool exactly when an
+            # actual offload of this engine will park first
+            engine.zero_drain_park = self._zero_drain_parks()
         self.builds_total += 1
         return _ModelRuntime(
             model_id=model_id,
@@ -6122,6 +6151,10 @@ class EngineService:
         # what the scheduler thread spent its time on, part by part, since
         # the process started (docs/tracing.md "Scheduler phases")
         out["scheduler"] = tracing.phase_stats()
+        # where the seconds from the OS starting this process to its
+        # listener accepting went, stage by stage (docs/tracing.md
+        # "Start-up stages and program compiles"); frozen from then on
+        out["startup"] = tracing.startup_stats()
         out["hbm"] = self._hbm_rows()
         # co-resident set (docs/perf.md "Co-resident sibling variants"):
         # who is routable on this engine without an actuation, and what
@@ -6777,6 +6810,13 @@ class _Mailbox:
 
 def build_app(service: EngineService) -> web.Application:
     app = web.Application()
+
+    async def listening(_app: web.Application) -> None:
+        # the runner is set up and the listener is next: the process is
+        # ready, and the start-up table (utils/tracing.py) is frozen
+        tracing.startup_ready()
+
+    app.on_startup.append(listening)
     mailbox = _Mailbox()
     # read per-request, never captured: both change on a model hot-swap
     tok = _CurrentTokenizer(service)
@@ -7984,13 +8024,25 @@ def run_server(args: argparse.Namespace) -> None:
     logging.basicConfig(level=logging.INFO)
     # armed here, not only by the launcher's preload: a stand-alone server
     # and a launcher child cache alike
+    from jax._src import xla_bridge
+
     from ..utils import compile_cache
 
+    # engine.start opens here, back-dated to the process's start; whether
+    # the entry point already made the backend's client (the benchmark's
+    # child does, to check its platform) says which stage holds those seconds
+    tracing.startup_begin(
+        xla_bridge.backends_are_initialized(),
+        model=args.model,
+        pid=os.getpid(),
+    )
     logger.info("compile cache at %s", compile_cache.arm() or "(none)")
     service = EngineService(args)
     # built and about to answer /health: a compile from here on is one a
     # request waits for, and the log names it
     compile_cache.serving()
+    # closed by the app's on_startup hook (build_app), with the table
+    tracing.stage("start.listen").__enter__()
     app = build_app(service)
     try:
         web.run_app(
@@ -8005,6 +8057,9 @@ def main(argv: Optional[List[str]] = None) -> None:
     validate_parsed_args(args)
     run_server(args)
 
+
+# interpreter, jax, aiohttp and the package are in: start.import ends here
+tracing.server_imported()
 
 if __name__ == "__main__":
     main()

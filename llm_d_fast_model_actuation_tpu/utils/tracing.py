@@ -41,6 +41,7 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import logging
 import os
 import random
 import threading
@@ -48,6 +49,8 @@ import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Dict, Iterator, List, Optional, Tuple
+
+logger = logging.getLogger(__name__)
 
 #: env toggles: FMA_TRACING=off|0|false disables at import; FMA_TRACE_BUFFER
 #: overrides the ring capacity (spans retained per process).
@@ -184,12 +187,16 @@ def reset_after_fork() -> None:
     ``--trace-requests`` during engine construction."""
     global _BUFFER, _enabled, _REQ_BUFFER, _req_frac, _capturing
     global _admit_blocked, _emit_deliveries, _emit_tokens, _prefills_overlapped
+    global _slow_entries, _slow_s
     _capturing = False
     _admit_blocked = 0
     _prefills_overlapped = 0
     _emit_deliveries = _emit_tokens = 0
+    _slow_entries, _slow_s = 0, 0.0
+    _slow_by_phase.clear()
     for name in PHASES:
         _PHASES[name] = Phase(name)
+    reset_startup()
     _BUFFER = TraceBuffer(_env_capacity())
     _REQ_BUFFER = TraceBuffer(_req_env_capacity())
     _req_frac = 0.0
@@ -368,6 +375,26 @@ class SpanHandle:
         return False
 
 
+def _new_span(
+    name: str,
+    parent: Optional[SpanContext],
+    start_s: float,
+    attrs: Dict[str, Any],
+) -> Span:
+    """A span of this thread under ``parent``, or under the current context."""
+    ctx = parent if parent is not None else _current.get()
+    return Span(
+        trace_id=ctx.trace_id if ctx else _new_trace_id(),
+        span_id=_new_span_id(),
+        parent_id=ctx.span_id if ctx else "",
+        name=name,
+        start_s=start_s,
+        attrs=attrs,
+        pid=os.getpid(),
+        thread=threading.current_thread().name,
+    )
+
+
 def begin(
     name: str,
     parent: Optional[SpanContext] = None,
@@ -381,17 +408,7 @@ def begin(
     thread need to avoid misparenting each other."""
     if not _enabled:
         return NOOP_SPAN
-    ctx = parent if parent is not None else _current.get()
-    span = Span(
-        trace_id=ctx.trace_id if ctx else _new_trace_id(),
-        span_id=_new_span_id(),
-        parent_id=ctx.span_id if ctx else "",
-        name=name,
-        start_s=time.monotonic(),
-        attrs=dict(attrs) if attrs else {},
-        pid=os.getpid(),
-        thread=threading.current_thread().name,
-    )
+    span = _new_span(name, parent, time.monotonic(), attrs)
     token = None
     if activate:
         token = _current.set(SpanContext(span.trace_id, span.span_id))
@@ -404,6 +421,29 @@ def span(
     """``with tracing.span("engine.swap", model=m): ...`` — begin +
     activate, ended (and attrs stamped with any exception) on exit."""
     return begin(name, parent=parent, activate=True, **attrs)
+
+
+def record_span(
+    name: str,
+    start_s: float,
+    end_s: float,
+    parent: Optional[SpanContext] = None,
+    **attrs: Any,
+) -> None:
+    """A finished span from explicit monotonic times, child of ``parent``
+    or of the current context: for work that was timed by someone else
+    (jax's compile events) or before any span could be open (the import)."""
+    if not _enabled:
+        return
+    span = _new_span(name, parent, start_s, attrs)
+    span.end_s = end_s
+    _BUFFER.add(span)
+
+
+def mono_of_wall(wall_s: float) -> float:
+    """A ``time.time()`` reading on the ring's monotonic axis, through the
+    module's anchor (jax times its compile events with ``time.time()``)."""
+    return _ANCHOR_MONO + (wall_s - _ANCHOR_WALL)
 
 
 def snapshot(trace_id: Optional[str] = None) -> List[Span]:
@@ -579,6 +619,182 @@ def clear_requests() -> None:
     _REQ_BUFFER.clear()
 
 
+# -- start-up stages ----------------------------------------------------------
+#
+# The ``start.*`` family (docs/tracing.md "Start-up stages and program
+# compiles"): where the seconds go between the OS starting an engine process
+# and its listener accepting. Like a phase, a stage always adds its seconds
+# to a per-process table (two monotonic reads on a preallocated object:
+# ``GET /v1/stats`` ``startup``); between :func:`startup_begin` and
+# :func:`startup_ready` it is also a span of its name under ``engine.start``.
+# Once the listener accepts the table is frozen: a later swap or wake builds
+# through the same code and writes nothing here (it has spans of its own).
+
+STAGES = (
+    "start.import", "start.entry", "start.backend", "start.resolve",
+    "start.weights", "start.pool", "start.programs", "start.sleeper",
+    "start.listen",
+)
+
+
+def _process_start() -> Tuple[float, str]:
+    """(when the OS started this process, on the monotonic clock; where
+    that was read): ``/proc/self/stat`` field 22, ticks since boot, against
+    ``CLOCK_BOOTTIME`` (``proc``), else this module's import (``import``)."""
+    try:
+        with open("/proc/self/stat", "rb") as f:
+            # the fields after the command's closing bracket start at 3
+            ticks = int(f.read().rpartition(b")")[2].split()[19])
+        age = time.clock_gettime(time.CLOCK_BOOTTIME) - ticks / os.sysconf(
+            "SC_CLK_TCK"
+        )
+        if age >= 0:
+            return time.monotonic() - age, "proc"
+    except (OSError, ValueError, IndexError, AttributeError):
+        pass
+    return _ANCHOR_MONO, "import"
+
+
+class Stage:
+    """One row of the start-up table, and the context manager that fills
+    it. Stages do not nest; one may be entered more than once (the weights
+    are loaded by the server and placed by the engine) and adds up."""
+
+    __slots__ = ("name", "seconds", "_t0", "_span")
+
+    def __init__(self, name: str) -> None:
+        self.name = name
+        self.seconds = 0.0
+        self._t0: Optional[float] = None
+        self._span: Any = NOOP_SPAN
+
+    @property
+    def timing(self) -> bool:
+        """Entered and counting: False once the table is frozen."""
+        return self._t0 is not None
+
+    def __enter__(self) -> "Stage":
+        if _startup.ready_s is None:
+            if _startup.span is not None:
+                # under engine.start itself, not under engine.build_runtime
+                self._span = begin(self.name, parent=_startup.span.context())
+            self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._t0 is not None:
+            self.seconds += time.monotonic() - self._t0
+            self._t0 = None
+            span, self._span = self._span, NOOP_SPAN
+            span.__exit__(exc_type, exc, tb)
+        return False
+
+    def set(self, **attrs: Any) -> None:
+        """Attributes of the stage's span (``bytes``); nothing without one."""
+        self._span.set(**attrs)
+
+
+class _Startup:
+    """The table and what closes it; one per process (a forked child's is
+    made anew by :func:`reset_startup`)."""
+
+    def __init__(self) -> None:
+        self.process_start, self.source = _process_start()
+        self.stages = {name: Stage(name) for name in STAGES}
+        #: the end of ``start.import``; None in a process that had the
+        #: server imported before it was forked
+        self.imported_at: Optional[float] = None
+        self.backend_made_in = "backend"
+        #: ``engine.start`` once :func:`startup_begin` has opened it
+        self.span: Any = None
+        #: process start to listener accepting; None until then
+        self.ready_s: Optional[float] = None
+
+
+_startup = _Startup()
+
+
+def reset_startup() -> None:
+    global _startup
+    _startup = _Startup()
+
+
+def stage(name: str) -> Stage:
+    """``with tracing.stage("start.weights") as st: ...`` — the stage named
+    ``name`` (one of :data:`STAGES`)."""
+    return _startup.stages[name]
+
+
+def server_imported() -> None:
+    """The last line of ``engine/server.py``: interpreter, jax, aiohttp and
+    the package are in. Closes ``start.import``."""
+    st = _startup
+    if st.imported_at is None and st.ready_s is None:
+        st.imported_at = time.monotonic()
+        st.stages["start.import"].seconds = st.imported_at - st.process_start
+
+
+def startup_begin(backend_ready: bool, **attrs: Any) -> None:
+    """The entry of ``run_server``. Closes ``start.entry`` (what the entry
+    point did between importing the server and calling it: the benchmark's
+    child makes the backend's client there, and ``backend_ready`` says so)
+    and opens ``engine.start``, back-dated to the process's start, under
+    the ``FMA_TRACEPARENT`` parent the spawning launcher stamped."""
+    st = _startup
+    if st.span is not None or st.ready_s is not None:
+        return
+    now = time.monotonic()
+    imported = st.process_start if st.imported_at is None else st.imported_at
+    st.stages["start.entry"].seconds = now - imported
+    st.backend_made_in = "entry" if backend_ready else "backend"
+    st.span = begin(
+        "engine.start",
+        parent=env_context(),
+        process_start_unix=round(_wall(st.process_start), 3),
+        **attrs,
+    )
+    if st.span is not NOOP_SPAN:
+        st.span._span.start_s = st.process_start
+        record_span("start.import", st.process_start, imported)
+        record_span("start.entry", imported, now)
+
+
+def startup_ready() -> None:
+    """The listener accepts: closes ``start.listen`` and ``engine.start``
+    and freezes the table."""
+    st = _startup
+    if st.ready_s is not None:
+        return
+    st.stages["start.listen"].__exit__(None, None, None)
+    st.ready_s = time.monotonic() - st.process_start
+    if st.span is not None:
+        st.span.end()
+
+
+def startup_stats() -> Dict[str, Any]:
+    """The ``startup`` block of ``GET /v1/stats``. ``stage_s`` by stage
+    (keys without the ``start.`` prefix), ``ready_s`` from the process's
+    start to the listener accepting (to now, while it does not yet), and
+    ``other_s``, what of that no stage covers. ``backend_s`` is the stage
+    in which the backend's client was made, ``backend_made_in`` its name."""
+    st = _startup
+    stage_s = {
+        name.partition(".")[2]: s.seconds for name, s in st.stages.items()
+    }
+    ready_s = st.ready_s
+    if ready_s is None:
+        ready_s = time.monotonic() - st.process_start
+    return {
+        "process_start_unix": _wall(st.process_start),
+        "process_start_source": st.source,
+        "ready_s": ready_s,
+        "backend_made_in": st.backend_made_in,
+        "backend_s": stage_s[st.backend_made_in],
+        "stage_s": stage_s,
+        "other_s": ready_s - sum(stage_s.values()),
+    }
+
+
 # -- scheduler phases ---------------------------------------------------------
 #
 # The ``sched.*`` family (docs/tracing.md "Scheduler phases"): what the ONE
@@ -611,6 +827,17 @@ _emit_tokens = 0
 #: unfetched (``engine.py:_prefill_waiting``); ``phase_n.prefill_dispatch``
 #: is its denominator
 _prefills_overlapped = 0
+#: an entry of a phase that took longer than a steady one ever does (steady
+#: entries are 0.06-19 ms, a fetch 86-300 ms): a stall names itself.
+#: ``sched.wait`` is never slow
+SLOW_ENTRY_S = 0.05
+SLOW_FETCH_S = 1.0
+_slow_entries = 0
+_slow_s = 0.0
+_slow_by_phase: Dict[str, float] = {}
+#: what the engine says of itself in a slow entry's log line (live
+#: sequences, the program in flight); set by the one engine of the process
+_slow_describer: Optional[Any] = None
 
 
 class Phase:
@@ -620,7 +847,7 @@ class Phase:
     call that holds the service lock while that thread waits)."""
 
     __slots__ = ("name", "seconds", "count", "host_only_s", "_overlapped",
-                 "_t0", "_span", "_ann")
+                 "_slow_after", "_t0", "_span", "_ann")
 
     def __init__(self, name: str) -> None:
         self.name = name
@@ -629,6 +856,11 @@ class Phase:
         #: the seconds of it in which no dispatched program was in flight
         self.host_only_s = 0.0
         self._overlapped = False
+        self._slow_after = (
+            SLOW_ENTRY_S if name not in WAITING_PHASES
+            else SLOW_FETCH_S if name != "sched.wait"
+            else float("inf")
+        )
         self._t0 = 0.0
         self._span: Any = None
         self._ann: Any = None
@@ -646,6 +878,8 @@ class Phase:
         self.count += 1
         if not self._overlapped:
             self.host_only_s += dt
+        if dt > self._slow_after:
+            _count_slow(self, dt)
         if self._span is not None:
             ann, self._ann = self._ann, None
             ann.__exit__(exc_type, exc, tb)
@@ -672,6 +906,34 @@ def phase(name: str, overlapped: bool = False) -> Phase:
     p = _PHASES[name]
     p._overlapped = overlapped
     return p
+
+
+def _count_slow(p: Phase, dt: float) -> None:
+    global _slow_entries, _slow_s
+    _slow_entries += 1
+    _slow_s += dt
+    key = p.name.partition(".")[2]
+    _slow_by_phase[key] = _slow_by_phase.get(key, 0.0) + dt
+    if _startup.ready_s is None:
+        return  # still starting: the first compiles are slow and known
+    what = f"program unfetched: {p._overlapped}"
+    if _slow_describer is not None:
+        try:
+            what = _slow_describer(p._overlapped)
+        except Exception as err:  # noqa: BLE001 — a log line must not stop the loop
+            what += f" (no description: {err!r})"
+    logger.warning(
+        "slow scheduler entry: %s took %.3f s; %s", p.name, dt, what
+    )
+
+
+def describe_slow_entries_with(fn: Optional[Any]) -> None:
+    """``fn(overlapped)`` gives the words a slow entry's log line ends in:
+    the live sequences and the program in flight, as the engine's owner
+    knows them (``overlapped``: the phase ran with a program unfetched).
+    Called on the scheduler thread, inside the slow entry's own exit."""
+    global _slow_describer
+    _slow_describer = fn
 
 
 def count_admit_blocked() -> None:
@@ -736,7 +998,10 @@ def phase_stats() -> Dict[str, Any]:
     in which the head of the waiting queue was refused a slot or pages,
     ``prefills_overlapped``, the prefills dispatched under an earlier one
     of their step, and ``emit_deliveries`` / ``emit_tokens``, the
-    streaming-hook calls of ``sched.emit`` and the tokens they carried."""
+    streaming-hook calls of ``sched.emit`` and the tokens they carried;
+    ``slow_entries`` / ``slow_s`` / ``slow_by_phase``, the entries that took
+    longer than :data:`SLOW_ENTRY_S` (a fetch: :data:`SLOW_FETCH_S`), their
+    seconds, and those by phase: a stall shows here under the phase it sat in."""
     rows = [(p.name.partition(".")[2], p) for p in _PHASES.values()]
     return {
         "phase_s": {k: p.seconds for k, p in rows},
@@ -748,6 +1013,9 @@ def phase_stats() -> Dict[str, Any]:
         "prefills_overlapped": _prefills_overlapped,
         "emit_deliveries": _emit_deliveries,
         "emit_tokens": _emit_tokens,
+        "slow_entries": _slow_entries,
+        "slow_s": _slow_s,
+        "slow_by_phase": dict(_slow_by_phase),
     }
 
 

@@ -25,6 +25,21 @@ _attn.set_pallas_interpret(True)
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
+def stop_listening_to_compiles() -> None:
+    """Undo ``utils/compile_cache.listen()`` for the tests that follow in
+    this process: with the listeners on, every compile leaves ``program.*``
+    spans in their rings."""
+    from jax._src import monitoring
+
+    from llm_d_fast_model_actuation_tpu.utils import compile_cache
+
+    if compile_cache._listening:
+        monitoring.unregister_event_listener(compile_cache._on_event)
+        monitoring.unregister_event_duration_listener(compile_cache._on_duration)
+        monitoring.unregister_event_time_span_listener(compile_cache._on_time_span)
+        compile_cache._listening = False
+
+
 def cpu_subprocess_env(**extra) -> dict:
     """Environment for a CPU-only child process (launcher/requester/engine)."""
     env = dict(os.environ)

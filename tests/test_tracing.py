@@ -545,3 +545,252 @@ def test_launcher_rpc_metric_and_traceparent_header(tmp_path):
     finally:
         manager_mod.urllib.request.urlopen = orig
         m.stop_all_instances(timeout=2)
+
+
+# -- start-up stages, program compiles, slow scheduler entries -----------------
+
+
+@pytest.fixture
+def fresh_startup():
+    """The start-up table is process-global and frozen by the first app
+    that listened in this process: each of these tests starts with a new
+    one and leaves a new one."""
+    from conftest import stop_listening_to_compiles
+
+    from llm_d_fast_model_actuation_tpu.utils import compile_cache
+
+    def clean():
+        tracing.reset_startup()
+        compile_cache._by_program.clear()
+        compile_cache._thread.__dict__.clear()
+
+    clean()
+    listening = compile_cache._listening
+    yield tracing
+    clean()
+    if not listening:
+        stop_listening_to_compiles()
+
+
+def _start_an_engine(sleep=lambda s: None):
+    """What run_server, EngineService and InferenceEngine do, in order."""
+    tracing.server_imported()
+    tracing.startup_begin(False, model="m")
+    for name in tracing.STAGES[2:-1]:
+        with tracing.stage(name) as st:
+            st.set(bytes=7)
+            sleep(name)
+    tracing.stage("start.listen").__enter__()
+    tracing.startup_ready()
+
+
+@pytest.mark.tracing
+def test_stages_fill_the_table_and_close_it(fresh_startup):
+    import time
+
+    _start_an_engine(lambda name: time.sleep(0.002))
+    block = tracing.startup_stats()
+    assert set(block["stage_s"]) == {
+        "import", "entry", "backend", "resolve", "weights", "pool",
+        "programs", "sleeper", "listen",
+    }
+    for name in ("backend", "resolve", "weights", "pool", "programs", "sleeper"):
+        assert block["stage_s"][name] >= 0.002
+    assert block["stage_s"]["import"] > 0
+    assert sum(block["stage_s"].values()) + block["other_s"] == pytest.approx(
+        block["ready_s"]
+    )
+    assert 0 <= block["other_s"] < block["ready_s"]
+    assert block["process_start_source"] in ("proc", "import")
+    assert block["backend_made_in"] == "backend"
+    assert block["backend_s"] == block["stage_s"]["backend"]
+    # engine.start, back-dated to the process's start, and its nine children
+    spans = tracing.snapshot()
+    (start,) = [s for s in spans if s.name == "engine.start"]
+    assert start.duration_s == pytest.approx(block["ready_s"], abs=1e-3)
+    assert start.attrs["process_start_unix"] == pytest.approx(
+        block["process_start_unix"], abs=1e-3
+    )
+    kids = [s for s in spans if s.parent_id == start.span_id]
+    assert sorted(s.name for s in kids) == sorted(tracing.STAGES)
+    assert {s.attrs.get("bytes") for s in kids if s.name == "start.pool"} == {7}
+
+
+@pytest.mark.tracing
+def test_the_table_is_frozen_once_the_listener_accepts(fresh_startup):
+    _start_an_engine()
+    frozen = tracing.startup_stats()
+    n = tracing.buffer_len()
+    # a later swap builds through the same code: nothing is rewritten
+    with tracing.stage("start.weights") as st:
+        assert not st.timing
+        st.set(bytes=1)
+    tracing.startup_begin(True)
+    tracing.startup_ready()
+    assert tracing.startup_stats() == frozen
+    assert tracing.buffer_len() == n
+    # until the listener accepts, ready_s is the seconds so far
+    tracing.reset_startup()
+    a = tracing.startup_stats()["ready_s"]
+    assert tracing.startup_stats()["ready_s"] > a
+
+
+@pytest.mark.tracing
+def test_backend_made_by_the_entry_point_is_read_from_start_entry(fresh_startup):
+    tracing.server_imported()
+    tracing.startup_begin(True)
+    block = tracing.startup_stats()
+    assert block["backend_made_in"] == "entry"
+    assert block["backend_s"] == block["stage_s"]["entry"] > 0
+
+
+@pytest.mark.tracing
+def test_tracing_off_fills_the_table_and_records_no_span(fresh_startup):
+    import jax
+    import jax.numpy as jnp
+
+    from llm_d_fast_model_actuation_tpu.utils import compile_cache
+
+    compile_cache.listen()
+    tracing.disable()
+
+    def work(name):
+        if name == "start.weights":
+            jax.jit(lambda x: x * 2 + 1)(jnp.ones(3)).block_until_ready()
+
+    _start_an_engine(work)
+    block = tracing.startup_stats()
+    assert block["stage_s"]["weights"] > 0 and block["ready_s"] > 0
+    cc = compile_cache.stats()
+    assert cc["by_program"]["jit(<lambda>)"]["n"] == 1
+    assert cc["trace_s"] > 0 and cc["lower_s"] > 0 and cc["backend_s"] > 0
+    assert tracing.buffer_len() == 0
+    # and with it on, the same work leaves its spans under the stage
+    tracing.enable()
+    tracing.reset_startup()
+    _start_an_engine(
+        lambda name: name == "start.weights"
+        and jax.jit(lambda x: x * 3 - 1)(jnp.ones(3)).block_until_ready()
+    )
+    spans = tracing.snapshot()
+    (weights,) = [s for s in spans if s.name == "start.weights"]
+    under = [s for s in spans if s.parent_id == weights.span_id]
+    assert {"program.trace", "program.lower", "program.compile"} <= {
+        s.name for s in under
+    }
+    assert all(s.attrs["program"].startswith("jit(") for s in under)
+    (compiled,) = [s for s in under if s.attrs["program"] == "jit(<lambda>)"
+                   and s.name == "program.compile"]
+    assert compiled.attrs["cache_hit"] is False
+    assert weights.start_s <= compiled.start_s <= compiled.end_s <= weights.end_s + 1e-3
+
+
+TRACE = "/jax/core/compile/jaxpr_trace_duration"
+LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+COMPILE = "/jax/core/compile/backend_compile_duration"
+
+
+@pytest.mark.tracing
+def test_a_nested_traced_function_is_counted_once(fresh_startup):
+    """jax's events end innermost first; a thread's seconds are the union
+    of its intervals, each second under the innermost event's kind and the
+    outermost program's name."""
+    from llm_d_fast_model_actuation_tpu.utils import compile_cache as cc
+
+    # chunk traces 0..10; inside it `_where` 1..2 and `inner` 3..7, inside
+    # which `multiply` 4..5; and a constant folded eagerly, 8..9.5: traced
+    # 8..8.5, lowered 8.5..9, compiled 9..9.5
+    for event, start, end, name in (
+        (TRACE, 1.0, 2.0, "_where"),
+        (TRACE, 4.0, 5.0, "multiply"),
+        (TRACE, 3.0, 7.0, "inner"),
+        (TRACE, 8.0, 8.5, "iota"),
+        (LOWER, 8.5, 9.0, "jit(iota)"),
+        (COMPILE, 9.0, 9.5, "jit(iota)"),
+        (TRACE, 0.0, 10.0, "chunk"),
+        (LOWER, 10.0, 12.0, "jit(chunk)"),
+        (COMPILE, 12.0, 12.25, "jit(chunk)"),
+    ):
+        cc._on_time_span(event, start, end, fun_name=name)
+    block = cc.stats()
+    assert set(block["by_program"]) == {"jit(chunk)", "jit(iota)"}
+    assert block["by_program"]["jit(chunk)"] == {
+        "n": 1, "seconds": 0.25, "trace_s": 9.0, "lower_s": 2.5,
+    }
+    assert block["by_program"]["jit(iota)"] == {
+        "n": 1, "seconds": 0.5, "trace_s": 0.0, "lower_s": 0.0,
+    }
+    # 12.25 s of one thread, each counted once
+    assert block["trace_s"] + block["lower_s"] + block["backend_s"] == 12.25
+    assert block["backend_s"] == sum(
+        p["seconds"] for p in block["by_program"].values()
+    )
+    # the real thing: every jnp function a program calls is traced inside it
+    import jax
+    import jax.numpy as jnp
+
+    cc.listen()
+    before = set(cc.stats()["by_program"])
+
+    @jax.jit
+    def helper(x):
+        return jnp.where(x > 0, x, 0) * 2
+
+    def outer_program(x):
+        return helper(x) + jnp.clip(x, 0, 1)
+
+    jax.jit(outer_program)(jnp.ones(4)).block_until_ready()
+    after = cc.stats()["by_program"]
+    new = set(after) - before
+    assert "jit(outer_program)" in new
+    assert not {"jit(helper)", "jit(_where)", "jit(clip)"} & new
+    assert after["jit(outer_program)"]["trace_s"] > 0
+
+
+@pytest.mark.tracing
+@pytest.mark.parametrize("fun_name", ["chunk", "jit_chunk", "jit(chunk)"])
+def test_the_three_names_jax_gives_a_program_fold_to_one_key(fun_name):
+    from llm_d_fast_model_actuation_tpu.utils import compile_cache as cc
+
+    assert cc.program_name(fun_name) == "jit(chunk)"
+    assert cc.program_name("pmap_step") == "pmap(step)"
+    assert cc.program_name("<lambda>") == "jit(<lambda>)"
+
+
+@pytest.mark.tracing
+@pytest.mark.parametrize("name,seconds,slow", [
+    ("sched.intake", 0.06, True),       # PR 37's stalled run: 2.7 s here
+    ("sched.admit", 0.019, False),      # the longest steady entry
+    ("sched.chunk_fetch", 0.3, False),  # a chunk is 86-300 ms
+    ("sched.chunk_fetch", 1.2, True),
+    ("sched.prefill_fetch", 1.2, True),
+    ("sched.wait", 30.0, False),        # idle is not a stall
+])
+def test_a_slow_phase_entry_counts_and_a_fast_one_does_not(
+    name, seconds, slow, fresh_startup, caplog
+):
+    import logging
+
+    tracing.reset_after_fork()
+    tracing.startup_ready()     # serving: a slow entry is also logged
+    tracing.describe_slow_entries_with(
+        lambda overlapped: f"live sequences 3, in flight: {overlapped}"
+    )
+    try:
+        with caplog.at_level(logging.WARNING, logger=tracing.logger.name):
+            with tracing.phase(name) as p:
+                p._t0 -= seconds
+        block = tracing.phase_stats()
+        key = name.partition(".")[2]
+        if slow:
+            assert block["slow_entries"] == 1
+            assert block["slow_s"] == pytest.approx(seconds, abs=0.01)
+            assert block["slow_by_phase"] == {key: block["slow_s"]}
+            assert name in caplog.text and "live sequences 3" in caplog.text
+        else:
+            assert block["slow_entries"] == 0 and block["slow_s"] == 0.0
+            assert block["slow_by_phase"] == {} and not caplog.text
+        assert block["phase_n"][key] == 1
+    finally:
+        tracing.describe_slow_entries_with(None)
+        tracing.reset_after_fork()
