@@ -30,6 +30,7 @@ only holds if the hot path is entirely pre-compiled programs.
 from __future__ import annotations
 
 import functools
+import logging
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence as Seq, Tuple
@@ -44,6 +45,8 @@ from ..parallel.mesh import shard_pytree
 from ..utils import tracing
 from .kv_cache import KVLayout, OutOfPages, PageAllocator, PagePool
 from .sampling import sample
+
+logger = logging.getLogger(__name__)
 
 
 @dataclass(frozen=True)
@@ -1191,10 +1194,18 @@ class InferenceEngine:
             "suffix": self.programs.suffix,
             "suffix_plp": self.programs.suffix_plp,
         }
-        #: AOT-warmed executables keyed by (program, shape bucket / chunk
-        #: T), installed by the exec-pool warmup driver; dispatch prefers
-        #: them, a missing entry just means first-touch jit compile
+        #: executables keyed by (program, shape bucket / chunk T), installed
+        #: by the exec-pool warmup driver or adopted from the pool at first
+        #: touch (use_exec_pool); dispatch prefers them, a missing entry
+        #: with no pool just means first-touch jit compile
         self._aot: Dict[Tuple[str, int], Any] = {}
+        #: the pool a first touch asks before it traces (use_exec_pool),
+        #: this engine's signature in it, and the (program, bucket) keys
+        #: whose executable failed or refused this engine's arguments:
+        #: those stay on the lazy jit
+        self._exec_pool: Optional[Any] = None
+        self._exec_signature = ""
+        self._jit_only: set = set()
         #: speculative decoding counters (observability)
         self.spec_proposed = 0
         self.spec_accepted = 0
@@ -1428,14 +1439,79 @@ class InferenceEngine:
         (or recompiles lazily) on wake."""
         self._aot.clear()
 
+    def use_exec_pool(self, pool: Any) -> None:
+        """From here on a serving program's first touch asks `pool` before
+        anything is traced (``_adopt_program``). Only a pool whose entries
+        outlive the process counts (``ExecutablePool.persistent``: on, and
+        this backend's serialized executables trusted — the TPU); any
+        other leaves the engine on the lazy jit, exactly as without one."""
+        from .exec_pool import exec_signature, mesh_shape
+
+        if pool is None or not pool.persistent:
+            self._exec_pool = None
+            return
+        self._exec_pool = pool
+        self._exec_signature = exec_signature(self.cfg, mesh_shape(self.mesh))
+
+    def _jit_program(self, program: str, bucket: int):
+        if program == "chunk":
+            return self.programs.chunk(bucket)
+        if program == "mixed":
+            # bucket = mixed_bucket(rows, kvp): the page-table slice
+            # width picks the jitted specialization (engine.mixed_bucket)
+            return self.programs.mixed(bucket & 0xFFFF)
+        return self._jit_programs[program]
+
+    def _pool_key(self, program: str, bucket: int) -> str:
+        from .exec_pool import exec_key
+
+        return exec_key(self._exec_signature, program, bucket)
+
+    def _adoptable(self, key: Tuple[str, int]) -> bool:
+        return self._exec_pool is not None and key not in self._jit_only
+
+    def _adopt_program(self, program: str, bucket: int, args) -> Optional[Any]:
+        """First touch of (program, bucket) with a pool attached: the
+        pool's executable, from memory or reloaded from its spill
+        directory with nothing traced; on a miss the program is lowered
+        from this call's own arguments (so its avals are the live ones by
+        construction), compiled once through the persistent cache, and
+        spilled for the next start. Installed either way. Any failure
+        leaves the key to the lazy jit for this engine's life."""
+        from ..utils import compile_cache
+
+        pool, fn = self._exec_pool, self._jit_program(program, bucket)
+        key = self._pool_key(program, bucket)
+        try:
+            t0 = time.time()  # jax's compile events are on this clock too
+            comp = pool.get(key)
+            if comp is not None:
+                compile_cache.count_reload(fn.__name__, t0, time.time())
+            else:
+                comp = fn.lower(*args).compile()
+                pool.put(key, comp, compile_s=time.time() - t0)
+        except Exception:  # noqa: BLE001 — the lazy jit is always there
+            logger.warning(
+                "no executable for %s@%s: serving it through jit",
+                program, bucket, exc_info=True,
+            )
+            self._jit_only.add((program, bucket))
+            return None
+        self._aot[(program, bucket)] = comp
+        return comp
+
     def _call_program(self, program: str, bucket: int, *args):
-        """Dispatch one compiled program: the AOT-warmed executable when
-        the warmup installed one for this (program, bucket), else the
-        lazily-jitted default. An aval/sharding mismatch from the
-        executable (e.g. a level-2 wake rebuilt params uncommitted) raises
-        TypeError BEFORE execution starts, so the donated cache is
-        untouched — drop the stale entry and re-dispatch through jit."""
-        comp = self._aot.get((program, bucket))
+        """Dispatch one compiled program: the executable installed for
+        this (program, bucket) — by the warmup, or adopted from the pool
+        at this first touch — else the lazily-jitted default. An
+        aval/sharding mismatch from the executable (e.g. a level-2 wake
+        rebuilt params uncommitted) raises TypeError BEFORE execution
+        starts, so the donated cache is untouched — drop the stale entry,
+        in the pool too, and re-dispatch through jit from then on."""
+        key = (program, bucket)
+        comp = self._aot.get(key)
+        if comp is None and self._adoptable(key):
+            comp = self._adopt_program(program, bucket, args)
         if comp is not None:
             try:
                 return comp(*args)
@@ -1444,22 +1520,26 @@ class InferenceEngine:
                 # = TypeError, input-sharding mismatch = ValueError), so
                 # the donated state is untouched — drop the stale entry
                 # and re-dispatch through jit
-                self._aot.pop((program, bucket), None)
-        if program == "chunk":
-            return self.programs.chunk(bucket)(*args)
-        if program == "mixed":
-            # bucket = mixed_bucket(rows, kvp): the page-table slice
-            # width picks the jitted specialization (engine.mixed_bucket)
-            return self.programs.mixed(bucket & 0xFFFF)(*args)
-        return self._jit_programs[program](*args)
+                self._aot.pop(key, None)
+                if self._exec_pool is not None:
+                    logger.warning(
+                        "the executable for %s@%s refused its arguments: "
+                        "serving it through jit", program, bucket,
+                        exc_info=True,
+                    )
+                    self._jit_only.add(key)
+                    self._exec_pool.discard(self._pool_key(program, bucket))
+        return self._jit_program(program, bucket)(*args)
 
     def _chunk_fn(self, T: int):
         """The T-step decode dispatch target. Gang followers replay this
         name directly (engine/multihost.py) — they never carry AOT
-        entries (warmup skips meshes), so they get the bare jit program;
-        a single-host engine with an installed chunk executable routes
-        through _call_program's AOT-prefer/TypeError-drop dispatch."""
-        if ("chunk", T) not in self._aot:
+        entries nor a pool (warmup and the service skip gangs), so they
+        get the bare jit program; a single-host engine with an installed
+        chunk executable, or a pool to adopt one from, routes through
+        _call_program's AOT-prefer/TypeError-drop dispatch."""
+        key = ("chunk", T)
+        if key not in self._aot and not self._adoptable(key):
             return self.programs.chunk(T)
         return functools.partial(self._call_program, "chunk", T)
 

@@ -2098,6 +2098,12 @@ class EngineService:
             mesh=mesh,
             seed=args.seed,
         )
+        if not self.is_gang:
+            # a serving program's first touch asks the pool's spill
+            # directory before it traces (engine.py:_adopt_program); no
+            # gang member carries executables (_start_warmup)
+            with tracing.stage("start.programs"):
+                engine.use_exec_pool(self.exec_pool)
         if params is None:
             # random init lands on device inside engine construction: the
             # whole build window is device-state creation
@@ -6143,6 +6149,10 @@ class EngineService:
         from ..utils import compile_cache
 
         out["compile_cache"] = compile_cache.stats()
+        # the pool a start reloads its serving programs from: spill_hits
+        # are this start's reloads, spill_errors the blobs that did not
+        # load or fit and went back to the lazy jit
+        out["exec_pool"] = self.exec_pool.describe()
         # the layer stack, the two kinds of KV state and the expert layers,
         # host-counted (engine.cache_stats): layer passes dispatched, pages
         # in use, bytes a token, ring bytes, positions that left a ring,

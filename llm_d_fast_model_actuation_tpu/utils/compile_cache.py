@@ -21,7 +21,8 @@ compile time there is noise.
 The module also counts: how the cache fared (``requests`` / ``hits`` /
 ``writes``) and, by program (``by_program``, under the name jax gives the
 compile, ``jit(chunk)``), the three things jax does before a program can
-run: tracing it (``trace_s``), lowering the jaxpr to an MLIR module
+run (a serving program that the executable pool reloads skips the first
+two, :func:`count_reload`): tracing it (``trace_s``), lowering the jaxpr to an MLIR module
 (``lower_s``) and the backend compile or the cache load that stood in for
 one (``n``, ``seconds``). jax times each with its own event; a thread's
 events nest (every ``jnp`` function a program calls is traced inside the
@@ -229,6 +230,27 @@ def _on_time_span(
         )
 
 
+def count_reload(fun_name: str, start: float, end: float) -> None:
+    """A serving program was taken from the executable pool
+    (engine/exec_pool.py) with nothing traced or lowered: to the account
+    it is what a load from jax's own cache is, a request and a hit whose
+    seconds (wall clock, `start` to `end`) stand under the program's
+    ``seconds`` and so in ``backend_s``, and one of
+    ``programs_reloaded``."""
+    name = program_name(fun_name)
+    with _mu:
+        _counts["requests"] += 1
+        _counts["hits"] += 1
+        _counts["reloaded"] += 1
+        _credit(name, "compile", end - start)
+        _by_program[name]["n"] += 1
+    if tracing.enabled():
+        _record(
+            "program.compile", start, end,
+            program=name, cache_hit=True, reloaded=True,
+        )
+
+
 def _record(span: str, start: float, end: float, **attrs: object) -> None:
     tracing.record_span(
         span, tracing.mono_of_wall(start), tracing.mono_of_wall(end), **attrs
@@ -238,7 +260,9 @@ def _record(span: str, start: float, end: float, **attrs: object) -> None:
 def stats() -> Dict[str, object]:
     """Where this process caches and how its compiles fared: ``requests``
     went through the cache, ``hits`` were read from it, ``writes`` were
-    compiled and stored; ``by_program`` has, by the name jax gives the
+    compiled and stored; ``programs_reloaded`` of the hits were serving
+    programs reloaded as executables with nothing traced
+    (:func:`count_reload`); ``by_program`` has, by the name jax gives the
     program, its compiles and cache loads (``n``, ``seconds``) and the
     seconds it took to trace and to lower; ``trace_s`` / ``lower_s`` /
     ``backend_s`` are their sums, and ``retrieval_s`` the part of
@@ -267,6 +291,7 @@ def stats() -> Dict[str, object]:
         "requests": _counts["requests"],
         "hits": _counts["hits"],
         "writes": _counts["writes"],
+        "programs_reloaded": _counts["reloaded"],
         "trace_s": total("trace_s"),
         "lower_s": total("lower_s"),
         "backend_s": total("seconds"),
