@@ -133,10 +133,10 @@ class EngineConfig:
 
     @property
     def kv_layout(self) -> KVLayout:
-        """The division of the sequence state between pages, rings and
-        recurrent state (engine/kv_cache.py): from the model's window and
-        linear-attention layers, the batch and the segment limit — nothing
-        an operator sets."""
+        """The division of the sequence state between pages (of K and V, or
+        latent ones), rings and recurrent state (engine/kv_cache.py): from
+        the model's window, linear-attention and latent-attention layers,
+        the batch and the segment limit — nothing an operator sets."""
         n_window, window = llama.window_layers(self.model)
         segment = prefill_bucket(
             self.max_prefill_tokens or self.seq_len, self.seq_len
@@ -144,6 +144,7 @@ class EngineConfig:
         return KVLayout.plan(
             self.model.cache_layers, n_window, window, self.page_size,
             self.seq_len, segment, llama.recurrent_state(self.model),
+            llama.latent_cache(self.model),
         )
 
     @property
@@ -173,10 +174,20 @@ def resolve_attention_impl(impl: str, model, tp: int = 1) -> str:
     from ..ops.pallas.decode import pallas_shape_ok
 
     if jax.default_backend() == "tpu" and pallas_shape_ok(
-        model.num_kv_heads // tp, model.head_dim
+        *kernel_kv_shape(model, tp)
     ):
         return "pallas"
     return "grouped"
+
+
+def kernel_kv_shape(model, tp: int = 1) -> Tuple[int, int]:
+    """(KV heads, head dim) of a page's row as the paged kernels on one
+    device read it: the model's KV heads over ``tp``; a latent page is one
+    shared head as wide as its stored row (models/llama.py:latent_cache)."""
+    latent = llama.latent_cache(model)
+    if latent is not None:
+        return 1, latent[1]
+    return model.num_kv_heads // tp, model.head_dim
 
 
 def _host_device():
@@ -449,17 +460,24 @@ class EngineAsleep(RuntimeError):
 
 
 class SlotStateUnsupported(ValueError):
-    """A path that knows pages alone was asked of a model that keeps
-    per-slot sequence state beside them: the rings of sliding-window layers
-    or the recurrent state of linear-attention layers."""
+    """A path that knows pages of K and V alone was asked of a model whose
+    sequence state is something else too: the rings of sliding-window
+    layers, the recurrent state of linear-attention layers, the latent pages
+    of latent-attention layers."""
 
 
 def slot_state_kinds(model) -> List[str]:
-    """What ``model`` keeps per sequence slot beside its pages, in words:
-    empty for a model whose whole sequence state is pages."""
+    """What ``model`` keeps of a sequence beside pages of K and V, in
+    words: empty for a model whose whole sequence state is such pages."""
     n_window, window = llama.window_layers(model)
     recurrent = llama.recurrent_state(model)
+    latent = llama.latent_cache(model)
     kinds = []
+    if latent is not None:
+        kinds.append(
+            f"{model.cache_layers} latent-attention layers whose pages are "
+            f"one array of {latent[0]} values a token, not a K and a V"
+        )
     if n_window:
         kinds.append(
             f"{n_window} sliding-window layers (window {window}) whose K "
@@ -475,14 +493,15 @@ def slot_state_kinds(model) -> List[str]:
 
 def refuse_slot_state(model, what: str) -> None:
     """Raise, naming the model, its state and the path, if ``model`` keeps
-    per-slot sequence state: ``what`` addresses a sequence's state as pages
-    of one kind and would read, share or move a ring or a slot's recurrent
-    state as something else, or not at all."""
+    per-slot sequence state or latent pages: ``what`` addresses a
+    sequence's state as pages of K and V and would read, share or move a
+    ring, a slot's recurrent state or a latent page as something else, or
+    not at all."""
     kinds = slot_state_kinds(model)
     if kinds:
         raise SlotStateUnsupported(
             f"{type(model).__name__} has {' and '.join(kinds)}: {what} "
-            "cannot carry per-slot state yet and refuses this model"
+            "cannot carry that state yet and refuses this model"
         )
 
 
@@ -1049,7 +1068,7 @@ class InferenceEngine:
         if impl == "pallas" and jax.default_backend() == "tpu":
             from ..ops.pallas.decode import check_kernel_shape
 
-            check_kernel_shape(m.num_kv_heads // tp, m.head_dim)
+            check_kernel_shape(*kernel_kv_shape(m, tp))
         if cfg.prefix_caching:
             refuse_slot_state(m, "the prefix cache (--prefix-caching on)")
         if cfg.packed_serving:
@@ -1322,9 +1341,10 @@ class InferenceEngine:
         self.variant_detaches = 0
 
     def _create_pool(self) -> None:
-        """A fresh device sequence state (pages; rings where the model has
-        window layers; zeroed recurrent state where it has linear-attention
-        layers), committed to the engine's placement."""
+        """A fresh device sequence state (pages of K and V, or latent ones;
+        rings where the model has window layers; zeroed recurrent state
+        where it has linear-attention layers), committed to the engine's
+        placement."""
         m, cfg, lay = self._model_cfg, self.cfg, self.kv_layout
         self.pool = PagePool.create(
             lay.global_layers,
@@ -1338,6 +1358,7 @@ class InferenceEngine:
                 cfg.max_batch, cfg.page_size, m.num_kv_heads, m.head_dim
             ),
             state_shapes=lay.state_shapes(cfg.max_batch),
+            latent_width=lay.latent_width,
         )
         if self.mesh is None:
             self.pool.replace(
@@ -1353,7 +1374,7 @@ class InferenceEngine:
         if self._has_experts:
             self.moe_tokens += tokens
             if moe.takes_grouped(
-                self._model_cfg, rows, self.params["layers"]["w_gate"],
+                self._model_cfg, rows, self.params["layers"].get("w_gate"),
                 self.mesh,
             ):
                 self.moe_routed_tokens += tokens
@@ -1386,7 +1407,10 @@ class InferenceEngine:
             self.cfg.max_batch, jnp.dtype(m.dtype).itemsize
         )
         experts = getattr(m, "num_experts", 0)
-        per_token = m.num_layers * getattr(m, "experts_per_token", 0)
+        expert_layers = getattr(m, "expert_layers", m.num_layers)
+        per_token = expert_layers * getattr(m, "experts_per_token", 0)
+        itemsize = jnp.dtype(m.dtype).itemsize
+        latent_layers = m.cache_layers if lay.latent_width else 0
         return {
             "stack": {
                 "num_layers": m.num_layers,
@@ -1396,7 +1420,18 @@ class InferenceEngine:
             },
             "kv": {
                 "bytes_per_token": PagePool.page_nbytes(
-                    m.cache_layers, 1, m.num_kv_heads, m.head_dim, dtype=m.dtype
+                    m.cache_layers, 1, m.num_kv_heads, m.head_dim,
+                    dtype=m.dtype, latent_width=lay.latent_width,
+                ),
+                # latent pages: what the algorithm reads of a token (as
+                # counted) and what its rows take as stored (as laid out,
+                # ``bytes_per_token``), all latent layers together
+                "latent_layers": latent_layers,
+                "latent_bytes_per_token": (
+                    latent_layers * lay.latent_counted * itemsize
+                ),
+                "latent_bytes_per_token_laid_out": (
+                    latent_layers * lay.latent_width * itemsize
                 ),
                 "global_layers": lay.global_layers,
                 "window_layers": lay.window_layers,
@@ -1418,6 +1453,12 @@ class InferenceEngine:
             },
             "moe": {
                 "experts": experts if experts > 1 else 0,
+                # a config that holds a share of its router's experts
+                # (models/moe.py): how many are here, of how many routed over
+                "experts_held": experts if experts > 1 else 0,
+                "router_width": (
+                    getattr(m, "router_outputs", 0) if experts > 1 else 0
+                ),
                 "tokens": self.moe_tokens,
                 "routed_tokens": self.moe_routed_tokens,
                 "assignments": self.moe_tokens * per_token,

@@ -323,12 +323,13 @@ def _abstract_state(cfg, mesh=None):
     kv = jax.ShapeDtypeStruct(
         PagePool.pool_shape(
             layout.global_layers, cfg.num_pages, cfg.page_size,
-            m.num_kv_heads, m.head_dim,
+            m.num_kv_heads, m.head_dim, layout.latent_width,
         ),
         m.dtype,
-        sharding=kv_sharding,
+        # latent pages: one array, replicated as the state is
+        sharding=state_sharding if layout.latent_width else kv_sharding,
     )
-    cache = (kv, kv)
+    cache = (kv,) if layout.latent_width else (kv, kv)
     if layout.window_layers:
         ring = jax.ShapeDtypeStruct(
             layout.ring_shape(

@@ -37,6 +37,20 @@ inputs of the layer's causal convolution, ``conv_tail [linear layers, slots,
 kernel - 1, channels]`` in the model's dtype. Sized from ``max_batch`` like
 the rings; a program finds its slot by the one static column the engine
 appends to every page-table row after the ring columns (the slot's index).
+
+A model with latent attention (models/llama.py:latent_cache) has a fourth
+kind, the LATENT page: ONE array a page, not a K and a V. A token of a layer
+holds its compressed key-value vector and the key part every head shares
+(``kv_lora_rank + qk_rope_head_dim`` values: 512 + 64 = 576, 1,152 B in
+bfloat16, "as counted"), which the decode kernel reads once as key (the whole
+row) and as value (its first ``kv_lora_rank`` lanes). 576 is no multiple of
+the 128 lanes a kernel's DMA and lane slices take, so the row is STORED 640
+wide, ``[c | k_pe | 64 zeros]`` (1,280 B "as laid out", a ninth more): the
+shared key part keeps a 128-lane tile of its own, the zeros score nothing
+against a query padded alike, and the value is the lane-aligned slice
+``[:512]``. ``[latent layers, pages, page_size, 640]``, indexed by the same
+page table and allocator as K-and-V pages; such a model keeps no K-and-V
+pages at all.
 """
 
 from __future__ import annotations
@@ -74,6 +88,10 @@ class KVLayout:
     #: the convolution tail's (the model's dtype)
     state_shape: Tuple[int, ...] = ()
     tail_shape: Tuple[int, ...] = ()
+    #: lanes of a latent page's row as stored (0: the pages hold K and V),
+    #: and the values of it the algorithm needs (``latent_cache``)
+    latent_width: int = 0
+    latent_counted: int = 0
 
     @property
     def table_width(self) -> int:
@@ -85,15 +103,21 @@ class KVLayout:
     def plan(
         cls, num_layers: int, window_layers: int, window: int,
         page_size: int, seq_len: int, segment: int, recurrent=None,
+        latent=None,
     ) -> "KVLayout":
         """``num_layers``: the layers that keep K and V; ``segment``: the
         most positions one program writes before it reads (the largest
         prefill bucket). A ring as long as the context never wraps, so it
         is never longer than that. ``recurrent``: what
-        ``llama.recurrent_state`` gives."""
+        ``llama.recurrent_state`` gives; ``latent``: what
+        ``llama.latent_cache`` gives."""
         pps = -(-seq_len // page_size)
         layers, state, tail = recurrent or (0, (), ())
-        kinds = dict(state_layers=layers, state_shape=state, tail_shape=tail)
+        counted, stored = latent or (0, 0)
+        kinds = dict(
+            state_layers=layers, state_shape=state, tail_shape=tail,
+            latent_width=stored, latent_counted=counted,
+        )
         if not window_layers:
             return cls(num_layers, 0, 0, 0, pps, **kinds)
         ring_len = min(window + segment, seq_len)
@@ -169,8 +193,10 @@ STATE_SPEC = jax.sharding.PartitionSpec()
 
 @dataclass
 class PagePool:
+    #: K pages; the latent pages of a pool of that kind (``"latent"`` in
+    #: :attr:`kinds`), which has no ``v_pages``
     k_pages: jnp.ndarray
-    v_pages: jnp.ndarray
+    v_pages: Optional[jnp.ndarray]
     #: the window layers' rings (None for a model without window layers)
     k_ring: Optional[jnp.ndarray] = None
     v_ring: Optional[jnp.ndarray] = None
@@ -178,8 +204,9 @@ class PagePool:
     #: (None for a model without them)
     state: Optional[jnp.ndarray] = None
     conv_tail: Optional[jnp.ndarray] = None
-    #: which per-slot kinds this pool carries, kept across :meth:`drop`
-    #: (a woken engine's tuple is split by it)
+    #: what this pool carries beside or instead of pages of K and V
+    #: ("latent", "ring", "state"), kept across :meth:`drop` (a woken
+    #: engine's tuple is split by it)
     kinds: Tuple[str, ...] = ()
 
     def __post_init__(self) -> None:
@@ -195,12 +222,18 @@ class PagePool:
         page_size: int,
         num_kv_heads: int,
         head_dim: int,
+        latent_width: int = 0,
     ) -> Tuple[int, int, int, int]:
         """The per-direction (k or v) pool array shape — the ONE
         definition shared by :meth:`create`, :meth:`estimate_nbytes` (the
         cost oracle sizes a not-yet-built pool from it) and the AOT
-        warm-up's avals (engine/exec_pool.py)."""
-        return (num_layers, num_pages, page_size, num_kv_heads * head_dim)
+        warm-up's avals (engine/exec_pool.py). With ``latent_width``
+        (:attr:`KVLayout.latent_width`) it is the shape of the one array a
+        latent page is, whatever the heads."""
+        return (
+            num_layers, num_pages, page_size,
+            latent_width or num_kv_heads * head_dim,
+        )
 
     @classmethod
     def estimate_nbytes(
@@ -211,21 +244,25 @@ class PagePool:
         num_kv_heads: int,
         head_dim: int,
         dtype: Any = jnp.bfloat16,
+        latent_width: int = 0,
     ) -> int:
         """Device bytes a :meth:`create` with these arguments allocates
-        (k + v), without allocating — what the actuation cost oracle
-        counts into cold-tier predictions (engine/server.py
-        _kv_pool_nbytes), kept here so a pool-layout change can never
-        silently drift the prediction from the build's bytes_in."""
+        (k + v, or the one array of latent pages), without allocating —
+        what the actuation cost oracle counts into cold-tier predictions
+        (engine/server.py _kv_pool_nbytes), kept here so a pool-layout
+        change can never silently drift the prediction from the build's
+        bytes_in."""
         import numpy as np
 
         shape = cls.pool_shape(
-            num_layers, num_pages, page_size, num_kv_heads, head_dim
+            num_layers, num_pages, page_size, num_kv_heads, head_dim,
+            latent_width,
         )
         elems = 1
         for d in shape:
             elems *= int(d)
-        return 2 * elems * int(np.dtype(dtype).itemsize)
+        arrays = 1 if latent_width else 2
+        return arrays * elems * int(np.dtype(dtype).itemsize)
 
     @classmethod
     def page_nbytes(
@@ -235,13 +272,15 @@ class PagePool:
         num_kv_heads: int,
         head_dim: int,
         dtype: Any = jnp.bfloat16,
+        latent_width: int = 0,
     ) -> int:
         """Device bytes ONE page occupies across all layers, k and v —
         what the zero-drain park (engine/parked.py) and its pre-transfer
         pricing multiply by the live page count, kept next to
         :meth:`estimate_nbytes` so both derive from the one pool layout."""
         return cls.estimate_nbytes(
-            num_layers, 1, page_size, num_kv_heads, head_dim, dtype=dtype
+            num_layers, 1, page_size, num_kv_heads, head_dim, dtype=dtype,
+            latent_width=latent_width,
         )
 
     @classmethod
@@ -256,11 +295,14 @@ class PagePool:
         mesh: Optional[Mesh] = None,
         ring_shape: Optional[Tuple[int, ...]] = None,
         state_shapes: Optional[Tuple[Tuple[int, ...], ...]] = None,
+        latent_width: int = 0,
     ) -> "PagePool":
         """``num_layers`` counts the layers the paged pool serves;
         ``ring_shape`` (:meth:`KVLayout.ring_shape`) adds the rings,
         ``state_shapes`` (:meth:`KVLayout.state_shapes`) the recurrent
-        state (float32) and its convolution tails."""
+        state (float32) and its convolution tails; with ``latent_width``
+        (:attr:`KVLayout.latent_width`) the pages are latent ones, one
+        array in ``k_pages`` and no ``v_pages``."""
 
         def zeros(shape, spec, dtype=dtype):
             if mesh is None:
@@ -271,11 +313,20 @@ class PagePool:
             )()
 
         shape = cls.pool_shape(
-            num_layers, num_pages, page_size, num_kv_heads, head_dim
+            num_layers, num_pages, page_size, num_kv_heads, head_dim,
+            latent_width,
         )
-        pool = cls(
-            k_pages=zeros(shape, POOL_SPEC), v_pages=zeros(shape, POOL_SPEC)
-        )
+        if latent_width:
+            # replicated on a mesh, as the one "KV head" it is read as
+            pool = cls(
+                k_pages=zeros(shape, STATE_SPEC), v_pages=None,
+                kinds=("latent",),
+            )
+        else:
+            pool = cls(
+                k_pages=zeros(shape, POOL_SPEC),
+                v_pages=zeros(shape, POOL_SPEC),
+            )
         if ring_shape is not None and ring_shape[0]:
             pool.k_ring = zeros(ring_shape, RING_SPEC)
             pool.v_ring = zeros(ring_shape, RING_SPEC)
@@ -296,9 +347,15 @@ class PagePool:
 
     def nbytes(self) -> int:
         return (
-            self.k_pages.nbytes + self.v_pages.nbytes + self.ring_nbytes()
+            sum(a.nbytes for a in self._pages()) + self.ring_nbytes()
             + self.state_nbytes()
         )
+
+    def _pages(self) -> Tuple[jnp.ndarray, ...]:
+        """The page arrays: (k, v), or the one array of latent pages."""
+        if "latent" in self.kinds:
+            return (self.k_pages,)
+        return (self.k_pages, self.v_pages)
 
     def ring_nbytes(self) -> int:
         if self.k_ring is None:
@@ -312,9 +369,10 @@ class PagePool:
 
     def as_tuple(self) -> Tuple[jnp.ndarray, ...]:
         """The cache as the programs take it, and as sleep and wake move
-        it: (k, v) pages, then the (k, v) rings where the model has window
-        layers, then (state, conv_tail) where it has recurrent ones."""
-        out = (self.k_pages, self.v_pages)
+        it: (k, v) pages or the one array of latent pages, then the (k, v)
+        rings where the model has window layers, then (state, conv_tail)
+        where it has recurrent ones."""
+        out = self._pages()
         if "ring" in self.kinds:
             out += (self.k_ring, self.v_ring)
         if "state" in self.kinds:
@@ -322,8 +380,10 @@ class PagePool:
         return out
 
     def replace(self, kv: Tuple[jnp.ndarray, ...]) -> None:
-        self.k_pages, self.v_pages = kv[:2]
-        rest = kv[2:]
+        n = len(self._pages())
+        self.k_pages, rest = kv[0], kv[n:]
+        if n == 2:
+            self.v_pages = kv[1]
         if "ring" in self.kinds:
             self.k_ring, self.v_ring, rest = rest[0], rest[1], rest[2:]
         if "state" in self.kinds:

@@ -47,6 +47,7 @@ from prometheus_client import Counter, Gauge, Histogram
 from ..models import llama
 from ..models.moe import MoeConfig
 from ..models.olmo_hybrid import OlmoHybridConfig
+from ..models.kimi_linear import KimiLinearConfig
 from ..models.smallthinker import SmallThinkerConfig
 from ..utils import faults, tracing
 from .engine import (
@@ -480,6 +481,8 @@ MODEL_CONFIGS = {
     "ouro-2.6b": llama.LlamaConfig.ouro_2_6b,
     "tiny-olmo-hybrid": OlmoHybridConfig.tiny_olmo_hybrid,
     "olmo-hybrid-7b": OlmoHybridConfig.olmo_hybrid_7b,
+    "tiny-kimi-linear": KimiLinearConfig.tiny_kimi_linear,
+    "kimi-linear-48b-a3b": KimiLinearConfig.kimi_linear_48b_a3b,
 }
 
 
@@ -3599,7 +3602,9 @@ class EngineService:
         oracle's cold predictions must count it identically (the layouts
         live in ONE place: PagePool.estimate_nbytes for the pages,
         kv_cache.recurrent_nbytes for a linear-attention model's recurrent
-        state). A windowed model's rings are not counted (ROADMAP D4)."""
+        state; a latent-attention model's pages are one array of its stored
+        row's width). A windowed model's rings are not counted (ROADMAP
+        D4)."""
         import jax.numpy as jnp
 
         from .kv_cache import PagePool, recurrent_nbytes
@@ -3611,6 +3616,7 @@ class EngineService:
             model_cfg.num_kv_heads,
             model_cfg.head_dim,
             dtype=model_cfg.dtype,
+            latent_width=(llama.latent_cache(model_cfg) or (0, 0))[1],
         )
         return pages + recurrent_nbytes(
             llama.recurrent_state(model_cfg), self.args.max_batch,
@@ -6552,8 +6558,6 @@ class EngineService:
                 def reinit():
                     import jax
 
-                    from .kv_cache import PagePool
-
                     if self.checkpoint_dir:
                         # level-2 wake = reload from disk (the reference's
                         # L2 wake re-reads weights; README.md:16-26);
@@ -6586,16 +6590,10 @@ class EngineService:
                         params = init_params_placed(
                             jax.random.key(self.args.seed), m, eng.mesh
                         )
-                    pool = PagePool.create(
-                        m.cache_layers,
-                        eng.cfg.num_pages,
-                        eng.cfg.page_size,
-                        m.num_kv_heads,
-                        m.head_dim,
-                        dtype=m.dtype,
-                        mesh=eng.mesh,
-                    )
-                    return {"params": params, "kv": pool.as_tuple()}
+                    # the engine's own build: every kind of sequence state
+                    # the model has (pages, rings, recurrent state)
+                    eng._create_pool()
+                    return {"params": params, "kv": eng.pool.as_tuple()}
 
                 out = self.sleeper.wake_up(reinit=reinit)
             else:

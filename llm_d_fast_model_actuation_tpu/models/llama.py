@@ -415,14 +415,27 @@ def recurrent_state(cfg) -> "Tuple[int, Tuple[int, ...], Tuple[int, ...]] | None
     state (engine/kv_cache.py:KVLayout): (its layers, the shape of one
     layer's recurrent state for one slot, the shape of its convolution tail).
     None for a model whose every layer keeps K and V alone.
-    (models/olmo_hybrid.py is that family.)"""
+    (models/olmo_hybrid.py and models/kimi_linear.py are those families.)"""
     return getattr(cfg, "recurrent_state", None)
+
+
+def latent_cache(cfg) -> "Tuple[int, int] | None":
+    """A family with latent attention keeps a fourth kind of sequence state
+    (engine/kv_cache.py:KVLayout), the latent page, one array and no K and V:
+    (the values a token of a layer holds as the algorithm counts them, the
+    lanes its row is stored in). None for a model whose pages hold K and V.
+    (models/kimi_linear.py is that family.)"""
+    return getattr(cfg, "latent_cache", None)
 
 
 def patterned(cfg):
     """The module whose forward carries a config whose layers are of more
     than one kind, on the trunk's signatures; None for the one block of this
     file."""
+    if latent_cache(cfg) is not None:
+        from . import kimi_linear
+
+        return kimi_linear
     if recurrent_state(cfg) is not None:
         from . import olmo_hybrid
 
@@ -772,9 +785,10 @@ def mixed_step(
     if patterned(cfg) is not None:
         raise NotImplementedError(
             f"{type(cfg).__name__}: the packed mixed_step path addresses "
-            "sequence state as pages alone and has neither a ring for "
-            "sliding-window layers nor a slot's recurrent state; serve this "
-            "model on the bucketed path"
+            "sequence state as pages of K and V alone and has neither a ring "
+            "for sliding-window layers, a slot's recurrent state, nor the "
+            "latent pages of latent-attention layers; serve this model on "
+            "the bucketed path"
         )
     (T,) = tokens.shape
     page_size = cache[0].shape[2]

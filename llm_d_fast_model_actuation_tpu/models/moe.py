@@ -72,6 +72,28 @@ class MoeConfig(llama.LlamaConfig):
     routed_experts: bool = False
     #: the experts' gate activation in :func:`routed_ffn`: "silu" or "relu"
     expert_activation: str = "silu"
+    #: the router's outputs, where ``num_experts`` is the SHARE of them this
+    #: config holds (one chip of several that share each layer: experts
+    #: ``share_index * num_experts ...`` of ``router_width``); 0: all of them
+    router_width: int = 0
+    share_index: int = 0
+    #: :func:`route`: "softmax" over the k kept logits, or "sigmoid" scores
+    #: renormalised over the k kept and scaled by ``routed_scaling``
+    router_scoring: str = "softmax"
+    routed_scaling: float = 1.0
+
+    def __post_init__(self) -> None:
+        super().__post_init__()
+        if (self.share_index + 1) * self.num_experts > self.router_outputs:
+            raise ValueError(
+                f"share {self.share_index} of {self.num_experts} experts lies "
+                f"outside a router of {self.router_outputs}"
+            )
+
+    @property
+    def router_outputs(self) -> int:
+        """The experts the router chooses among, held here or not."""
+        return self.router_width or self.num_experts
 
     @classmethod
     def mixtral_8x7b(cls) -> "MoeConfig":
@@ -220,14 +242,27 @@ def moe_ffn(cfg: MoeConfig, lp: Dict[str, Any], x: jnp.ndarray) -> jnp.ndarray:
     return out.astype(x.dtype)
 
 
-def route(cfg: MoeConfig, logits: jnp.ndarray):
-    """Router logits [..., E] (any float dtype) -> (top-k probabilities
-    [..., k] float32, renormalized by a softmax over the k kept logits,
-    and their expert indices [..., k])."""
-    top_vals, top_idx = jax.lax.top_k(
-        logits.astype(jnp.float32), cfg.experts_per_token
+def route(cfg: MoeConfig, logits: jnp.ndarray, bias=None):
+    """Router logits [..., router width] (any float dtype) -> (the k kept
+    experts' weights [..., k] float32, their indices [..., k]). "softmax":
+    the k largest logits, a softmax over them. "sigmoid": scores
+    sigmoid(logits); the k largest of score + ``bias`` (a selection bias
+    [router width], which chooses and does not weigh) over the router's
+    whole width, one group; the kept scores over their sum, times
+    ``routed_scaling``."""
+    logits = logits.astype(jnp.float32)
+    if cfg.router_scoring == "softmax":
+        top_vals, top_idx = jax.lax.top_k(logits, cfg.experts_per_token)
+        return jax.nn.softmax(top_vals, axis=-1), top_idx
+    if cfg.router_scoring != "sigmoid":
+        raise ValueError(f"unknown router scoring {cfg.router_scoring!r}")
+    scores = jax.nn.sigmoid(logits)
+    _, top_idx = jax.lax.top_k(
+        scores if bias is None else scores + bias, cfg.experts_per_token
     )
-    return jax.nn.softmax(top_vals, axis=-1), top_idx
+    kept = jnp.take_along_axis(scores, top_idx, axis=-1)
+    weights = kept / jnp.sum(kept, axis=-1, keepdims=True)
+    return weights * cfg.routed_scaling, top_idx
 
 
 def _gate_act(cfg, g: jnp.ndarray) -> jnp.ndarray:
@@ -316,17 +351,31 @@ def routed_ffn(
     (SmallThinker: the layer's input, before attention). ``layer``: the
     expert matrices in ``lp`` are whole stacks [L, E, ...] and this is the
     layer to compute (:func:`_grouped`).
+
+    A config that holds a SHARE of the router's experts (``num_experts`` E
+    of ``router_width``, from ``share_index * E``) routes over the whole
+    width and computes the terms of its own experts: an assignment to an
+    expert that is not here is sorted past the last group, belongs to no
+    group of the matmuls (their sizes are the held experts' alone) and adds
+    nothing to its token's sum. What the absent experts would add is left
+    out. With every expert held this is the layer it was.
     """
     k, E = cfg.experts_per_token, cfg.num_experts
+    share = E < cfg.router_outputs
     lead, h = x.shape[:-1], x.shape[-1]
     with jax.named_scope("router"):
         if router_logits is None:
             router_logits = x @ lp["router"]
-        probs, idx = route(cfg, router_logits)  # [..., k]
+        probs, idx = route(cfg, router_logits, lp.get("router_bias"))  # [..., k]
     with jax.named_scope("experts"):
         xf = x.reshape(-1, h)  # [N, h]
         n = xf.shape[0]
         expert = idx.reshape(n * k)
+        if share:
+            expert = expert - cfg.share_index * E
+            here = (expert >= 0) & (expert < E)
+            # E is no expert's index: past every group, one-hot of nothing
+            expert = jnp.where(here, expert, E)
         order = jnp.argsort(expert, stable=True)  # rows sorted by expert
         sizes = jnp.sum(
             jax.nn.one_hot(expert, E, dtype=jnp.int32), axis=0
@@ -346,6 +395,9 @@ def routed_ffn(
             jnp.arange(n * k, dtype=order.dtype)
         )
         y = y[inverse].reshape(n, k, h)
+        if share:
+            # a row of no group holds whatever the matmul left there
+            y = jnp.where(here.reshape(n, k, 1), y, 0)
         out = jnp.einsum(
             "nkh,nk->nh", y.astype(jnp.float32), probs.reshape(n, k)
         )

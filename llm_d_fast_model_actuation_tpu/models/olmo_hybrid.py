@@ -320,7 +320,19 @@ def recurrence_step(q, k, v, beta, alpha, S):
     """One token: q, k [b, H, d_k], v [b, H, d_v], beta and alpha [b, H], S
     [b, H, d_k, d_v], all float32 -> (o [b, H, d_v], the new S). S is read
     twice and written once: S^T k and S^T q in one pass, then the update, and
-    o = alpha S^T q + (k.q) u is S_t^T q without a pass over the new S."""
+    o = alpha S^T q + (k.q) u is S_t^T q without a pass over the new S.
+
+    ``alpha`` [b, H, d_k], a decay a CHANNEL (models/kimi_linear.py): the
+    state decays row by row, S' = Diag(alpha) S, so the decay goes into the
+    two vectors the pass contracts with, S'^T k = S^T (alpha k), and the
+    update scales each row of S by its own alpha."""
+    if alpha.ndim == k.ndim:
+        s_k = jnp.sum(S * (alpha * k)[..., None], axis=-2)
+        s_q = jnp.sum(S * (alpha * q)[..., None], axis=-2)
+        u = beta[..., None] * (v - s_k)
+        S = alpha[..., None] * S + k[..., None] * u[..., None, :]
+        o = s_q + jnp.sum(k * q, axis=-1, keepdims=True) * u
+        return o, S
     s_k = jnp.sum(S * k[..., None], axis=-2)
     s_q = jnp.sum(S * q[..., None], axis=-2)
     a = alpha[..., None]
@@ -353,6 +365,11 @@ def _conv(lp, ext, rows: int):
     return jax.nn.silu(acc)
 
 
+def unit(x):
+    """x over its L2 norm along the last axis (L2_EPS inside the root)."""
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
+
+
 def _heads(cfg, c):
     """The convolution's output [..., C] -> q, k [..., H, d_k] (normalised,
     q scaled), v [..., H, d_v]."""
@@ -360,10 +377,6 @@ def _heads(cfg, c):
     q, k, v = jnp.split(c, [H * dk, 2 * H * dk], axis=-1)
     q = q.reshape(*q.shape[:-1], H, dk)
     k = k.reshape(*k.shape[:-1], H, dk)
-
-    def unit(x):
-        return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
-
     return unit(q) * dk**-0.5, unit(k), v.reshape(*v.shape[:-1], H, dv)
 
 
@@ -416,6 +429,31 @@ def _close(cfg, lp, x, mixed):
     return x + llama._norm(cfg, y, lp["post_ffn_norm"])
 
 
+def load_slots(arr, li, slots, fresh, dtype):
+    """Layer ``li`` of a per-slot array [layers, slots, ...] for the rows'
+    ``slots`` [b] -> [b, ...] in ``dtype``; a ``fresh`` row reads zero,
+    whatever its slot holds."""
+    b = slots.shape[0]
+    rows = jnp.concatenate([
+        jax.lax.dynamic_slice(
+            arr, (li, slots[r]) + (0,) * (arr.ndim - 2),
+            (1, 1) + arr.shape[2:],
+        )[0]
+        for r in range(b)
+    ]).astype(dtype)
+    return jnp.where(fresh.reshape(b, *[1] * (rows.ndim - 1)), 0, rows)
+
+
+def store_slots(arr, li, slots, new):
+    """``new`` [b, ...] written to layer ``li`` of the rows' ``slots``."""
+    for r in range(slots.shape[0]):
+        arr = jax.lax.dynamic_update_slice(
+            arr, new[r][None, None].astype(arr.dtype),
+            (li, slots[r]) + (0,) * (arr.ndim - 2),
+        )
+    return arr
+
+
 def _segment(
     params, cfg, tokens, positions, valid, lens, fresh, cache, page_table,
     attend,
@@ -435,22 +473,10 @@ def _segment(
     keep = valid[..., None]
 
     def load(arr, li, dtype):
-        rows = jnp.concatenate([
-            jax.lax.dynamic_slice(
-                arr, (li, slots[r]) + (0,) * (arr.ndim - 2),
-                (1, 1) + arr.shape[2:],
-            )[0]
-            for r in range(b)
-        ]).astype(dtype)
-        return jnp.where(fresh.reshape(b, *[1] * (rows.ndim - 1)), 0, rows)
+        return load_slots(arr, li, slots, fresh, dtype)
 
     def store(arr, li, new):
-        for r in range(b):
-            arr = jax.lax.dynamic_update_slice(
-                arr, new[r][None, None].astype(arr.dtype),
-                (li, slots[r]) + (0,) * (arr.ndim - 2),
-            )
-        return arr
+        return store_slots(arr, li, slots, new)
 
     def linear(x, lp, li, state, tail):
         u, beta, g = _gates(cfg, lp, x)

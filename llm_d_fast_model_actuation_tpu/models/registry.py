@@ -13,11 +13,13 @@ from typing import Any, Dict
 
 import jax
 
-from . import llama, moe, olmo_hybrid
+from . import kimi_linear, llama, moe, olmo_hybrid
 
 
 def init_params_for(key: jax.Array, cfg: llama.LlamaConfig) -> Dict[str, Any]:
-    if isinstance(cfg, moe.MoeConfig):
+    if isinstance(cfg, kimi_linear.KimiLinearConfig):
+        params = kimi_linear.init_params(key, cfg)
+    elif isinstance(cfg, moe.MoeConfig):
         params = moe.init_params(key, cfg)
     elif isinstance(cfg, olmo_hybrid.OlmoHybridConfig):
         params = olmo_hybrid.init_params(key, cfg)
@@ -53,7 +55,9 @@ def _init_fn(cfg: llama.LlamaConfig):
 
 
 def logical_axes_for(cfg: llama.LlamaConfig) -> Dict[str, Any]:
-    if isinstance(cfg, moe.MoeConfig):
+    if isinstance(cfg, kimi_linear.KimiLinearConfig):
+        axes = kimi_linear.param_logical_axes(cfg)
+    elif isinstance(cfg, moe.MoeConfig):
         axes = moe.param_logical_axes(cfg)
     elif isinstance(cfg, olmo_hybrid.OlmoHybridConfig):
         axes = olmo_hybrid.param_logical_axes(cfg)

@@ -243,6 +243,56 @@ def paged_decode_attention_inline(
     return out.reshape(b, h, d).astype(q.dtype)
 
 
+def latent_decode_attention_inline(
+    q: jnp.ndarray,  # [batch, heads, width] — absorbed queries, padded as rows
+    pages: jnp.ndarray,  # [layers, num_pages, page_size, width] latent pages
+    new: jnp.ndarray,  # [batch, width] — the new token's latent row
+    page_table: jnp.ndarray,  # [batch, pages_per_seq] int32
+    positions: jnp.ndarray,  # [batch] int32 — cache entries < position count
+    layer: jnp.ndarray,  # int32 scalar — the pool layer to read
+    latent: int,  # lanes of a row that are its value
+    scale: float,  # what the scores are scaled by
+    impl: "str | None" = None,
+) -> jnp.ndarray:
+    """Latent attention in its absorbed form, one decode step with the new
+    token's row inline (:func:`paged_decode_attention_inline`'s contract):
+    every query head scores the ONE row a token of the layer holds
+    (engine/kv_cache.py, the latent page), and that row's first ``latent``
+    lanes are its value. -> [batch, heads, latent]. The pallas kernel reads a
+    page once for both (ops/pallas/decode.py); this XLA twin gathers the
+    table's pages."""
+    if (impl or _IMPL) == "pallas":
+        from .pallas.decode import latent_decode_attention_inline_pallas
+
+        return latent_decode_attention_inline_pallas(
+            q, pages, new, page_table, positions, layer, latent=latent,
+            scale=scale, interpret=_pallas_interpret(),
+        )
+    b = q.shape[0]
+    with jax.named_scope("kv_gather"):
+        rows = pages[layer, page_table].reshape(b, -1, pages.shape[-1])
+    ctx = rows.shape[1]
+    qs = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    logits = jnp.einsum(
+        "bhw,bkw->bhk", qs, rows, preferred_element_type=jnp.float32
+    )
+    valid = jnp.arange(ctx)[None, :] < positions[:, None]  # strictly past
+    logits = jnp.where(valid[:, None, :], logits, NEG_INF)
+    self_logit = jnp.einsum(
+        "bhw,bw->bh", qs, new.astype(qs.dtype),
+        preferred_element_type=jnp.float32,
+    )
+    probs = jax.nn.softmax(
+        jnp.concatenate([logits, self_logit[..., None]], axis=-1), axis=-1
+    )
+    out = jnp.einsum(
+        "bhk,bkc->bhc", probs[..., :ctx].astype(rows.dtype),
+        rows[..., :latent], preferred_element_type=jnp.float32,
+    )
+    out = out + probs[..., ctx:] * new[:, None, :latent].astype(jnp.float32)
+    return out.astype(q.dtype)
+
+
 def paged_decode_attention(
     q: jnp.ndarray,  # [batch, heads, head_dim] — one new token per sequence
     k_pages: jnp.ndarray,  # [layers, num_pages, page_size, kv_heads*head_dim]

@@ -104,6 +104,8 @@ def _decode_kernel(
     inline: bool,
     window: int = 0,
     block_pages: int = 1,
+    latent: int = 0,
+    scale: float = 0.0,
 ):
     """Online-softmax over the sequence's pages. With ``inline`` the new
     token's K/V arrive as two extra inputs ([1, 1, kv_heads * head_dim]
@@ -123,14 +125,32 @@ def _decode_kernel(
     once over the tile. A context of a thousand tokens is scores of
     16-token pages; at one page a step the walk is bound by the latency of
     a 16 KB DMA and the fixed cost of a step, not by bytes. The serving
-    path's value is :func:`decode_block_pages`."""
-    if inline:
-        knew_ref, vnew_ref, *refs = refs
-    # k_hbm, v_hbm: [layers, num_pages, page_size, kv_heads * head_dim] HBM/ANY
-    # o_ref: [1, heads, head_dim] VMEM
-    # k_buf, v_buf: [2, block_pages * page_size, kv_heads * head_dim] VMEM
-    # sems: DMA [2, 2] (one page a step) or [2, 2, block_pages]
-    k_hbm, v_hbm, o_ref, k_buf, v_buf, sems = refs
+    path's value is :func:`decode_block_pages`.
+
+    With ``latent`` (latent attention in its absorbed form: ONE shared "KV
+    head" whose key is the page's whole row, ``head_dim`` lanes, and whose
+    value is the first ``latent`` lanes of the same row) there is no V pool,
+    no V tile and no V copy: a page is read once and serves both, the new
+    token arrives as one row, the output is ``latent`` wide, and ``scale``
+    is the model's (the row's width is not the width the scores are scaled
+    by)."""
+    if latent:
+        # k_hbm: [layers, num_pages, page_size, head_dim]; o_ref: [1, heads,
+        # latent]; k_buf: [2, block_pages * page_size, head_dim]
+        if inline:
+            knew_ref, *refs = refs
+        k_hbm, o_ref, k_buf, sems = refs
+        pools = ((k_buf, k_hbm),)
+    else:
+        if inline:
+            knew_ref, vnew_ref, *refs = refs
+        # k_hbm, v_hbm: [layers, num_pages, page_size, kv_heads * head_dim] HBM/ANY
+        # o_ref: [1, heads, head_dim] VMEM
+        # k_buf, v_buf: [2, block_pages * page_size, kv_heads * head_dim] VMEM
+        # sems: DMA [2, 2] (one page a step) or [2, 2, block_pages]
+        k_hbm, v_hbm, o_ref, k_buf, v_buf, sems = refs
+        pools = ((k_buf, k_hbm), (v_buf, v_hbm))
+    value_dim = latent or head_dim
     blocked = block_pages > 1
     tile = block_pages * page_size
     b = pl.program_id(0)
@@ -167,12 +187,12 @@ def _decode_kernel(
     def step_dmas(slot, step):
         """The K and V copies of one step of the walk."""
         if not blocked:
-            return [page_dma(k_buf, k_hbm, slot, step, 0),
-                    page_dma(v_buf, v_hbm, slot, step, 1)]
+            return [page_dma(buf, hbm, slot, step, row)
+                    for row, (buf, hbm) in enumerate(pools)]
         return [
             page_dma(buf, hbm, slot, step * block_pages + j, row, j)
             for j in range(block_pages)
-            for row, (buf, hbm) in enumerate(((k_buf, k_hbm), (v_buf, v_hbm)))
+            for row, (buf, hbm) in enumerate(pools)
         ]
 
     @pl.when(num_steps > 0)
@@ -180,7 +200,8 @@ def _decode_kernel(
         for dma in step_dmas(0, 0):
             dma.start()
 
-    q = q_ref[0].astype(jnp.float32) * (head_dim**-0.5)  # [heads, head_dim]
+    # [heads, head_dim]
+    q = q_ref[0].astype(jnp.float32) * (scale or head_dim**-0.5)
 
     # Online-softmax state is carried per KV head (tuples over the static
     # kv-head axis) — in-kernel scatter is not lowerable on TPU, whole-array
@@ -211,7 +232,10 @@ def _decode_kernel(
             lanes = pl.ds(g * head_dim, head_dim)
             qg = q[g * group : (g + 1) * group]  # [group, head_dim]
             kg = k_buf[slot, :, lanes].astype(jnp.float32)  # [page, head_dim]
-            vg = v_buf[slot, :, lanes].astype(jnp.float32)
+            if latent:
+                vg = k_buf[slot, :, pl.ds(0, latent)].astype(jnp.float32)
+            else:
+                vg = v_buf[slot, :, lanes].astype(jnp.float32)
             logits = jax.lax.dot_general(
                 qg,
                 kg,
@@ -238,7 +262,7 @@ def _decode_kernel(
     m0 = tuple(jnp.full((group, 1), NEG_INF, jnp.float32) for _ in range(num_kv_heads))
     l0 = tuple(jnp.zeros((group, 1), jnp.float32) for _ in range(num_kv_heads))
     acc0 = tuple(
-        jnp.zeros((group, head_dim), jnp.float32) for _ in range(num_kv_heads)
+        jnp.zeros((group, value_dim), jnp.float32) for _ in range(num_kv_heads)
     )
     ms, ls, accs = jax.lax.fori_loop(0, num_steps, body, (m0, l0, acc0))
 
@@ -249,7 +273,10 @@ def _decode_kernel(
             lanes = pl.ds(g * head_dim, head_dim)
             qg = q[g * group : (g + 1) * group]
             kn = knew_ref[0, :, lanes].astype(jnp.float32)  # [1, head_dim]
-            vn = vnew_ref[0, :, lanes].astype(jnp.float32)
+            if latent:
+                vn = knew_ref[0, :, pl.ds(0, latent)].astype(jnp.float32)
+            else:
+                vn = vnew_ref[0, :, lanes].astype(jnp.float32)
             logit = (qg * kn).sum(axis=-1, keepdims=True)  # [group, 1]
             m_cur = jnp.maximum(ms[g], logit)
             alpha = jnp.exp(ms[g] - m_cur)
@@ -259,20 +286,28 @@ def _decode_kernel(
             ms[g] = m_cur
 
     l = jnp.concatenate(ls, axis=0)  # [heads, 1]
-    acc = jnp.concatenate(accs, axis=0)  # [heads, head_dim]
+    acc = jnp.concatenate(accs, axis=0)  # [heads, value_dim]
     out = jnp.where(l > 0, acc / jnp.where(l > 0, l, 1.0), 0.0)
     o_ref[0] = out.astype(o_ref.dtype)
 
 
 def _paged_decode(
     q, k_pages, v_pages, page_table, kv_lens, layer, new_kv, interpret,
-    window=0, block_pages=1,
+    window=0, block_pages=1, latent=0, scale=0.0,
 ):
+    """``latent`` > 0: ``k_pages`` are latent pages, ``v_pages`` is None and
+    ``new_kv`` one row (:func:`_decode_kernel`)."""
     batch, num_heads, head_dim = q.shape
     _, _, page_size, fused = k_pages.shape
     num_kv_heads = fused // head_dim
     if not interpret:  # the interpreter has no tiling to satisfy
         check_kernel_shape(num_kv_heads, head_dim)
+        if latent % LANES:
+            raise ValueError(
+                f"the value of a latent page is a slice of whole {LANES}-lane "
+                f"tiles; got {latent} lanes"
+            )
+    pools = (k_pages,) if latent else (k_pages, v_pages)
 
     kernel = functools.partial(
         _decode_kernel,
@@ -283,43 +318,70 @@ def _paged_decode(
         inline=bool(new_kv),
         **({"window": int(window)} if window else {}),
         **({"block_pages": int(block_pages)} if block_pages > 1 else {}),
+        **({"latent": int(latent), "scale": float(scale)} if latent else {}),
     )
     tile = block_pages * page_size
     row_spec = lambda shape: pl.BlockSpec(  # noqa: E731
         shape, lambda b, *_: (b,) + (0,) * (len(shape) - 1), memory_space=pltpu.VMEM
     )
+    out_shape = (batch, num_heads, latent or head_dim)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(batch,),
         in_specs=[
             row_spec((1, num_heads, head_dim)),
             *(row_spec((1, 1, fused)) for _ in new_kv),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
+            *(pl.BlockSpec(memory_space=pl.ANY) for _ in pools),
         ],
-        out_specs=row_spec((1, num_heads, head_dim)),
+        out_specs=row_spec((1,) + out_shape[1:]),
         scratch_shapes=[
-            pltpu.VMEM((2, tile, fused), k_pages.dtype),
-            pltpu.VMEM((2, tile, fused), v_pages.dtype),
+            *(pltpu.VMEM((2, tile, fused), pool.dtype) for pool in pools),
             pltpu.SemaphoreType.DMA(
                 (2, 2, block_pages) if block_pages > 1 else (2, 2)
             ),
         ],
     )
+    name = "paged_decode_inline" if new_kv else "paged_decode"
     return pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
-        name="paged_decode_inline" if new_kv else "paged_decode",
+        name="latent_decode_inline" if latent else name,
     )(
         page_table.astype(jnp.int32),
         kv_lens.astype(jnp.int32),
         jnp.asarray(layer, jnp.int32).reshape(1),
         q,
         *(x.reshape(batch, 1, fused) for x in new_kv),
-        k_pages,
-        v_pages,
+        *pools,
+    )
+
+
+@functools.partial(
+    jax.jit, static_argnames=("latent", "scale", "interpret", "block_pages")
+)
+def latent_decode_attention_inline_pallas(
+    q: jnp.ndarray,  # [batch, heads, width]: the absorbed queries, padded
+    pages: jnp.ndarray,  # [layers, num_pages, page_size, width] latent pages
+    new: jnp.ndarray,  # [batch, width] — the new token's row
+    page_table: jnp.ndarray,  # [batch, pages_per_seq] int32
+    positions: jnp.ndarray,  # [batch] int32 — cache holds entries < position
+    layer: jnp.ndarray,  # int32 scalar — the pool layer to read
+    latent: int,  # lanes of a row that are its value (kv_lora_rank)
+    scale: float,  # what the scores are scaled by
+    interpret: bool = False,
+    block_pages: "int | None" = None,
+) -> jnp.ndarray:
+    """Absorbed latent attention for one decode step, on the walk of
+    :func:`paged_decode_attention_inline_pallas`: every query head against
+    the one shared row a token has, read ONCE as key and as value ->
+    [batch, heads, latent]."""
+    if block_pages is None:
+        block_pages = decode_block_pages(pages.shape[2])
+    return _paged_decode(
+        q, pages, None, page_table, positions, layer, (new,), interpret,
+        0, block_pages, latent, scale,
     )
 
 

@@ -311,6 +311,9 @@ def test_no_patterned_program_holds_a_copy_of_either_pool(topo, program, bucket)
     assert temps < smaller * 2, temps  # bf16
 
 
+_CELL_PROGRAMS = {}
+
+
 def _compile_cell_program(topo, name, program, bucket=None):
     """One serving program of a benchmark cell at its real sizes and engine
     options (``bucket`` None: the cell's ``--decode-chunk``), compiled for
@@ -328,7 +331,12 @@ def _compile_cell_program(topo, name, program, bucket=None):
     args = server.make_arg_parser().parse_args(
         ["--model", "tiny", *cell.engine_options(False)]
     )
-    compiled, cfg = _compile_engine_program(
+    # two tests ask for the same program of a cell (the chunk programs of the
+    # batch and the longmix cell): one compile serves both
+    key = (name, program, bucket or args.decode_chunk)
+    if key in _CELL_PROGRAMS:
+        return (*_CELL_PROGRAMS[key], cell, model)
+    compiled, cfg = _CELL_PROGRAMS[key] = _compile_engine_program(
         topo, program, bucket or args.decode_chunk, tp=1, model=model,
         max_batch=args.max_batch, page_size=args.page_size,
         num_pages=args.num_pages, decode_chunk=args.decode_chunk,
@@ -472,6 +480,68 @@ def test_hybridmix_cell_programs_fit_the_chip(topo, program, bucket):
     sized = _pool_sized_ops(text, whole_state)
     assert [row for row in sized if "aliasing" not in lines[row[1]]] == []
     assert all("f32[12,16,30,96,192]" in row[1] for row in sized)
+
+
+@pytest.mark.parametrize(
+    "program,bucket", [("chunk", 8), ("prefill", 1024), ("suffix", 1024)]
+)
+def test_decodemix_cell_programs_fit_the_chip(topo, program, bucket):
+    """The programs of the cell ``kimi-linear-48b.decodemix`` at its real
+    sizes and engine options, compiled for the described chip: the pool is
+    ONE array of the 2 latent layers' pages, stored 640 lanes wide, the 6 KDA
+    layers' recurrent state stands beside it (128 x 128 a head: lane-aligned,
+    stored as reckoned), the latent decode kernel is in the chunk program and
+    reads one 128-token tile of 640 lanes a step and no V tile, nothing the
+    size of a layer of the pool or of the whole state is copied (the state's
+    layers are rewritten in place), no held expert stack is copied, and
+    arguments + temps are under 10 GB."""
+    compiled, cfg, cell, model = _compile_cell_program(
+        topo, "kimi-linear-48b.decodemix", program, bucket
+    )
+    d, lay, keys = cell.dims, cfg.kv_layout, cell.family.keys
+    assert (lay.global_layers, lay.state_layers) == (2, 6) == (
+        model.cache_layers, model.kda_layers)
+    assert (lay.latent_counted, lay.latent_width) == (576, 640)
+    assert lay.table_width == 4096 // 16 + 1
+    pages = keys.kv_bytes(d, cfg.num_pages, cfg.page_size)
+    recurrent = keys.state_bytes(d, cfg.max_batch)
+    assert pages == 16400 * 16 * 2 * 1_280 and recurrent == 64 * 6 * 2_170_880
+    assert recurrent == lay.state_nbytes(cfg.max_batch, 2)
+    state = 2 * keys.param_count(d) + pages + recurrent
+    assert 9.04e9 < state < 9.06e9
+    ma = compiled.memory_analysis()
+    assert state <= ma.argument_size_in_bytes < state + 0.03e9
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 10e9
+    text = compiled.as_text()
+    # the grouped expert matmuls are XLA's ragged-dot custom calls in every
+    # program; the one Pallas kernel of this family is the latent decode
+    assert "tpu_custom_call" in text
+    layer_pool = cfg.num_pages * cfg.page_size * lay.latent_width
+    whole_state = 6 * 64 * 32 * 128 * 128
+    layer_experts = 64 * 2304 * 1024
+    assert layer_experts < layer_pool < whole_state
+    # what writes something the size of a layer's held experts (the smallest
+    # of the three) is the pool's own write or a KDA layer's update of the
+    # state, in place: its output aliases the carried array
+    lines = {line.strip()[:200]: line for line in text.splitlines()}
+    sized = _pool_sized_ops(text, layer_experts)
+    loose = [row for row in sized if "aliasing" not in lines[row[1]]]
+    if program == "chunk":
+        assert loose == []
+    else:
+        # a prompt segment holds the pair decays of its sub-blocks, a sixth
+        # of a GB a KDA layer at a time, and nothing else of that size
+        assert all("f32[16,32,4,16,16,128]" in row[1] for row in loose)
+    assert all(
+        "f32[6,64,32,128,128]" in row[1] or "bf16[524800,640]" in row[1]
+        or "f32[16,32,4,16,16,128]" in row[1] for row in sized)
+    if program == "chunk":
+        kernels_found = _kernel_vmem_args(text, "latent_decode_inline")
+        # q [1, 32, 640], the new row [1, 1, 640], o [1, 32, 512], and ONE
+        # double-buffered tile of 128 tokens of 640 lanes: no V tile
+        assert kernels_found and all(
+            k == [(1, 32, 640), (1, 1, 640), (1, 32, 512), (2, 128, 640)]
+            for k in kernels_found)
 
 
 def _kernel_vmem_args(text, name):

@@ -337,9 +337,11 @@ def test_engine_counts_the_tokens_it_routed(family):
     tokens = sum(len(p) + 5 for p in prompts)
     if family == "mixtral":
         assert stats == {
-            "experts": 4, "tokens": tokens, "routed_tokens": 512,
+            "experts": 4, "experts_held": 4, "router_width": 4,
+            "tokens": tokens, "routed_tokens": 512,
             "assignments": tokens * 2 * 2,
         }
     else:
         assert stats == {
-            "experts": 0, "tokens": 0, "routed_tokens": 0, "assignments": 0}
+            "experts": 0, "experts_held": 0, "router_width": 0, "tokens": 0,
+            "routed_tokens": 0, "assignments": 0}

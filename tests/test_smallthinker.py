@@ -399,8 +399,8 @@ def test_engine_serves_through_both_caches():
     tokens = sum(len(p) + 19 for p in prompts)
     # a family that always routes: every token's experts ran grouped
     assert stats["moe"] == {
-        "experts": 8, "tokens": tokens, "routed_tokens": tokens,
-        "assignments": tokens * 8 * 3}
+        "experts": 8, "experts_held": 8, "router_width": 8, "tokens": tokens,
+        "routed_tokens": tokens, "assignments": tokens * 8 * 3}
     # the window layers never hold more than window + segment a sequence,
     # and the pages of the full-attention layers come back on retire
     assert stats["kv"]["global_pages_in_use"] == 0
