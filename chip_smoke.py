@@ -66,7 +66,7 @@ KERNEL_ATOL = 5e-2
 @dataclasses.dataclass(frozen=True)
 class Plan:
     """What one run drives. The defaults are the chip run; the CPU rehearsal
-    (tests/test_chip_compile.py) passes tiny sizes and ``platform="cpu"``."""
+    (tests/test_chip_smoke_rehearsal.py) passes tiny sizes and ``platform="cpu"``."""
 
     #: the platform every child must report
     platform: str = "tpu"

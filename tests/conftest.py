@@ -5,7 +5,12 @@ validated on 8 virtual CPU devices (the same trick the driver's
 `dryrun_multichip` uses). Env must be set before jax is first imported.
 """
 
+import faulthandler
+import functools
 import os
+import signal
+import sys
+import threading
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 xla_flags = os.environ.get("XLA_FLAGS", "")
@@ -16,13 +21,120 @@ if "xla_force_host_platform_device_count" not in xla_flags:
 
 import pytest  # noqa: E402
 
+from llm_d_fast_model_actuation_tpu.engine import engine as _engine  # noqa: E402
 from llm_d_fast_model_actuation_tpu.ops import attention as _attn  # noqa: E402
+from llm_d_fast_model_actuation_tpu.utils import tracing as _tracing  # noqa: E402
 
 # Pallas kernels run in interpreter mode in this (CPU) suite; the serving
 # path never turns it on by itself (ops/attention.py).
 _attn.set_pallas_interpret(True)
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# -- one set of compiled programs per configuration per test process ----------
+#
+# A ProgramSet is built from static configuration only, and jit caches key on
+# the identity of the jitted function: a second engine of an old configuration
+# would trace, lower and compile every program again. The suite builds such
+# engines by the dozen, so in a test process ``InferenceEngine.__init__`` takes
+# its ProgramSet from a memo keyed by the constructor's arguments, and jax's
+# own cache serves the second engine the first one's executables. The warm-up
+# driver (engine/exec_pool.py) builds its own sets as it does in the product.
+# What is traced depends on more than the key wherever a test changes a
+# trace-time global (``set_pallas_interpret``, a patched model function, a
+# patched constant): such a test, any test that counts compiles, and a test
+# whose outcome hangs on how long a first dispatch takes, names the
+# ``fresh_programs`` fixture (a module-scoped fixture that builds such an
+# engine: ``fresh_programs_for_module``).
+
+ProgramSet = _engine.ProgramSet
+_shared_programs = functools.lru_cache(maxsize=None)(ProgramSet)
+_ENGINE_INIT = _engine.InferenceEngine.__init__.__code__
+
+
+def _program_set(model_cfg, logprobs_topk, eos_token_id, mesh=None):
+    by_engine = sys._getframe(1).f_code is _ENGINE_INIT
+    build = _shared_programs if by_engine else ProgramSet
+    return build(model_cfg, logprobs_topk, eos_token_id, mesh)
+
+
+_engine.ProgramSet = _program_set
+
+
+def _fresh_programs():
+    """Every engine built while this holds compiles its own programs, as in
+    the product: for tests of the compiles themselves, and tests that change
+    what a trace reads besides the ProgramSet's arguments."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(_engine, "ProgramSet", ProgramSet)
+        yield
+
+
+fresh_programs = pytest.fixture()(_fresh_programs)
+#: the same for a module-scoped fixture that builds engines
+fresh_programs_for_module = pytest.fixture(scope="module")(_fresh_programs)
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _the_benchmarks_tests_compile_their_own(request):
+    """``tests/fmabench/`` belongs to the benchmark (BENCHMARK.json ``paths``)
+    and cannot name a fixture of this PR's: its in-process tests read the
+    compiles of a real engine's first request (``real_stats``), so the whole
+    directory keeps the product's one set an engine."""
+    if is_the_benchmarks(request.path):
+        yield from _fresh_programs()
+    else:
+        yield
+
+
+def is_the_benchmarks(path) -> bool:
+    return os.path.join(REPO_ROOT, "tests", "fmabench") in map(str, path.parents)
+
+
+@pytest.fixture(autouse=True)
+def _tracing_switch_as_the_test_found_it():
+    """``tracing.enable()`` / ``disable()`` flip a switch of the process: a
+    test that leaves it off (the families' sleep-and-wake tests do) must not
+    decide whether the next file on this worker finds its spans."""
+    was = _tracing.enabled()
+    yield
+    (_tracing.enable if was else _tracing.disable)()
+
+
+# -- no test without a time limit of its own ----------------------------------
+
+#: seconds a test's setup, call or teardown may take: about twice the slowest
+#: test of the suite. A hang then costs one failure that names the test, and
+#: not the run (the driver's command is cut at 1,470 s and then counts only
+#: as far as it got).
+TEST_TIME_LIMIT_S = 480.0
+
+
+def _time_limited(item):
+    """Run the rest of a runtest hook under ``TEST_TIME_LIMIT_S``: past it,
+    every thread's stack goes to stderr and the test fails by name. The
+    alarm needs the main thread, where pytest and the xdist workers run
+    their tests."""
+    if threading.current_thread() is not threading.main_thread():
+        return (yield)
+    limit = TEST_TIME_LIMIT_S
+
+    def on_alarm(signum, frame):
+        faulthandler.dump_traceback(file=sys.__stderr__, all_threads=True)
+        pytest.fail(f"{item.nodeid} ran past its {limit:g} s", pytrace=False)
+
+    was_handler = signal.signal(signal.SIGALRM, on_alarm)
+    was_timer = signal.setitimer(signal.ITIMER_REAL, limit)
+    try:
+        return (yield)
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, *was_timer)
+        signal.signal(signal.SIGALRM, was_handler)
+
+
+pytest_runtest_setup = pytest.hookimpl(wrapper=True)(_time_limited)
+pytest_runtest_call = pytest.hookimpl(wrapper=True)(_time_limited)
+pytest_runtest_teardown = pytest.hookimpl(wrapper=True)(_time_limited)
 
 
 def stop_listening_to_compiles() -> None:

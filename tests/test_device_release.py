@@ -88,9 +88,10 @@ def test_release_midstream_resumes():
     assert outs[0].out_tokens == gold
 
 
-def test_release_with_mesh_rebuilds_mesh():
+def test_release_with_mesh_rebuilds_mesh(fresh_programs):
     """A TP engine across the virtual CPU mesh survives release: the mesh is
-    rebuilt on the re-created devices and sharded state is restored."""
+    rebuilt on the re-created devices and sharded state is restored (and
+    handed to the engine's ProgramSet, so that is its own)."""
     import jax
 
     from llm_d_fast_model_actuation_tpu.parallel.mesh import MeshPlan, make_mesh

@@ -24,7 +24,8 @@ from llm_d_fast_model_actuation_tpu.engine.exec_pool import (
 from llm_d_fast_model_actuation_tpu.models import llama
 from llm_d_fast_model_actuation_tpu.utils import compile_cache
 
-pytestmark = pytest.mark.warmup
+# what these tests count is compiles: every engine builds its own programs
+pytestmark = [pytest.mark.warmup, pytest.mark.usefixtures("fresh_programs")]
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PROMPTS = [[1, 2, 3], [4, 5, 6, 7]]
@@ -64,7 +65,7 @@ def _pool(spill_dir):
 
 
 @pytest.fixture(scope="module")
-def starts(tmp_path_factory):
+def starts(tmp_path_factory, fresh_programs_for_module):
     """Three engines in one process: the lazy jit, a first start over an
     empty spill directory, a second start over what the first left."""
     mp = pytest.MonkeyPatch()

@@ -102,24 +102,40 @@ def _served_logits(cfg, params, tokens, segments, slot=1, poison=7.0):
             bucket *= 2
         seg = jnp.full((1, bucket), 3, jnp.int32).at[0, :n].set(toks[pos : pos + n])
         if len(segments) == 1:
-            logits, cache = llama.prefill(
-                params, cfg, seg, jnp.asarray([n]), cache, row)
+            logits, cache = _program(cfg, "prefill")(
+                params, seg, jnp.asarray([n]), cache, row)
         else:
-            logits, cache = llama.prefill_continue(
-                params, cfg, seg, jnp.asarray([pos]), jnp.asarray([n]), cache, row)
+            logits, cache = _program(cfg, "suffix")(
+                params, seg, jnp.asarray([pos]), jnp.asarray([n]), cache, row)
         out.append(logits[0, :n])
         pos += n
     full = jnp.asarray(table)
     active = jnp.arange(SLOTS) == slot
-    step = jax.jit(
-        lambda t, p, c: llama.decode_step(params, cfg, t, p, c, full, active)
-    )
+    step = _program(cfg, "decode")
     for pos in range(pos, len(tokens)):
         t = jnp.zeros((SLOTS,), jnp.int32).at[slot].set(toks[pos])
         p = jnp.zeros((SLOTS,), jnp.int32).at[slot].set(pos)
-        logits, cache = step(t, p, cache)
+        logits, cache = step(params, t, p, cache, full, active)
         out.append(logits[slot : slot + 1])
     return jnp.concatenate(out, axis=0), cache
+
+
+_PROGRAMS = {}
+
+
+def _program(cfg, which):
+    """One jitted program a config and kind (the weights an argument, so that
+    every seed and every test of a config shares the compile)."""
+    if (cfg, which) not in _PROGRAMS:
+        fn = {
+            "prefill": lambda params, *a: llama.prefill(params, cfg, *a),
+            "suffix": lambda params, *a: llama.prefill_continue(params, cfg, *a),
+            "decode": lambda params, *a: llama.decode_step(params, cfg, *a),
+            "reference": lambda params, tokens: oh.reference_logits(
+                params, cfg, tokens),
+        }[which]
+        _PROGRAMS[cfg, which] = jax.jit(fn)
+    return _PROGRAMS[cfg, which]
 
 
 def _tokens(seed, n=60):
@@ -132,7 +148,7 @@ def tiny32():
     cfg = _model(dtype=jnp.float32)
     params = init_params_for(jax.random.key(5), cfg)
     tokens = _tokens(0)
-    return cfg, params, tokens, oh.reference_logits(params, cfg, jnp.asarray(tokens))
+    return cfg, params, tokens, _program(cfg, "reference")(params, jnp.asarray(tokens))
 
 
 # -- the mathematics, in float32 ------------------------------------------------------
@@ -209,9 +225,8 @@ def test_a_padded_bucket_row_changes_neither_state_nor_tail(tiny32):
         seg = jnp.full((1, 16), pad, jnp.int32).at[0, :10].set(
             jnp.asarray(tokens[:10], jnp.int32))
         with jax.default_matmul_precision("highest"):
-            _, cache = llama.prefill(
-                params, cfg, seg, jnp.asarray([10]), cache,
-                jnp.asarray(table[1:2]))
+            _, cache = _program(cfg, "prefill")(
+                params, seg, jnp.asarray([10]), cache, jnp.asarray(table[1:2]))
         ends.append((np.asarray(cache[2]), np.asarray(cache[3])))
     assert np.array_equal(ends[0][0], ends[1][0])
     assert np.array_equal(ends[0][1], ends[1][1])
@@ -225,7 +240,7 @@ def test_a_padded_bucket_row_changes_neither_state_nor_tail(tiny32):
         t = jnp.zeros((SLOTS,), jnp.int32).at[1].set(int(tokens[pos]))
         p = jnp.zeros((SLOTS,), jnp.int32).at[1].set(pos)
         with jax.default_matmul_precision("highest"):
-            _, cache = llama.decode_step(params, cfg, t, p, cache, full, active)
+            _, cache = _program(cfg, "decode")(params, t, p, cache, full, active)
     np.testing.assert_allclose(cache[2], state, atol=1e-4)
     np.testing.assert_allclose(cache[3], tail, atol=1e-4)
 
@@ -234,8 +249,8 @@ def test_an_inactive_decode_row_leaves_its_slot_alone(tiny32):
     cfg, params, _, _ = tiny32
     cache, table = _fresh_cache(cfg, poison=2.0)
     active = jnp.asarray([False, True, False])
-    _, after = llama.decode_step(
-        params, cfg, jnp.asarray([7, 8, 9]), jnp.asarray([3, 0, 5]), cache,
+    _, after = _program(cfg, "decode")(
+        params, jnp.asarray([7, 8, 9]), jnp.asarray([3, 0, 5]), cache,
         jnp.asarray(table), active,
     )
     for before, now in zip(cache[2:], after[2:]):
@@ -263,12 +278,12 @@ def readings():
     for seed in SEEDS:
         params = init_params_for(jax.random.key(seed), cfg)
         tokens = _tokens(seed)
-        ref = oh.reference_logits(params, cfg, jnp.asarray(tokens))
+        ref = _program(cfg, "reference")(params, jnp.asarray(tokens))
         got, _ = _served_logits(cfg, params, tokens, (16, 16, 13))
         low = jax.tree.map(
             lambda a: _int8(a) if a.ndim == 3 and a.shape[1] > 8 else a, params)
         low["lm_head"] = _int8(params["lm_head"])
-        ctl = oh.reference_logits(low, cfg, jnp.asarray(tokens))
+        ctl = _program(cfg, "reference")(low, jnp.asarray(tokens))
         rows.append((_row_errors(got, ref), _row_errors(ctl, ref)))
     return rows
 

@@ -82,12 +82,15 @@ def _served_logits(cfg, params, tokens, prompt_len=PROMPT, slot=1):
     )
     out = [logits[0]]
     pos = SEGMENT
+    # one compile for the prompt's suffix segments, as the engine has (traced
+    # in this call: a test may have patched what it traces)
+    suffix = jax.jit(
+        lambda seg, pos, n, c: llama.prefill_continue(params, cfg, seg, pos, n, c, row)
+    )
     while pos < prompt_len:
         n = min(SEGMENT, prompt_len - pos)
         seg = jnp.zeros((1, SEGMENT), jnp.int32).at[0, :n].set(toks[pos : pos + n])
-        logits, cache = llama.prefill_continue(
-            params, cfg, seg, jnp.asarray([pos]), jnp.asarray([n]), cache, row
-        )
+        logits, cache = suffix(seg, jnp.asarray([pos]), jnp.asarray([n]), cache)
         out.append(logits[0, :n])
         pos += n
     full = jnp.asarray(table)

@@ -331,11 +331,15 @@ def _wait_counter(name, labels, floor, timeout=30.0):
     )
 
 
-def test_client_drop_before_release_counts_one_abort_per_side(ckpt):
+def test_client_drop_before_release_counts_one_abort_per_side(
+    ckpt, fresh_programs
+):
     """Client vanishes while the bundle is in flight: the source books
     exactly one reason=client abort + one outcome=aborted (never
     state_loss), and the destination — told via DELETE claim — books
-    exactly its own single client abort."""
+    exactly its own single client abort. (The source's count is read
+    before the destination's asynchronous abort lands in the counter they
+    share here: engines that compile their own programs keep that order.)"""
     src, dst = _service(ckpt), _service(ckpt)
     _wire(src, dst)
     aborts = "fma_engine_aborted_requests_total"

@@ -93,12 +93,14 @@ def _served_logits(cfg, params, tokens, prompt_len, slot=1):
     )
     out.append(logits[0])
     pos = SEGMENT
+    # one compile for the prompt's four suffix segments, as the engine has
+    suffix = jax.jit(
+        lambda seg, pos, n, c: llama.prefill_continue(params, cfg, seg, pos, n, c, row)
+    )
     while pos < prompt_len:
         n = min(SEGMENT, prompt_len - pos)
         seg = jnp.zeros((1, SEGMENT), jnp.int32).at[0, :n].set(toks[pos : pos + n])
-        logits, cache = llama.prefill_continue(
-            params, cfg, seg, jnp.asarray([pos]), jnp.asarray([n]), cache, row
-        )
+        logits, cache = suffix(seg, jnp.asarray([pos]), jnp.asarray([n]), cache)
         out.append(logits[0, :n])
         pos += n
     full = jnp.asarray(table)
@@ -360,7 +362,7 @@ def test_routed_layer_is_the_dense_weighted_sum(case):
 def test_served_program_computes_k_experts_a_token():
     """The expert matmuls of the traced layer are three grouped ones over
     tokens x k rows (the TPU compiler lowers each to one kernel whose flops
-    are the routed ones: tests/test_chip_compile.py), and nothing of the
+    are the routed ones: tests/test_chip_compile_cells.py), and nothing of the
     size [tokens, experts, width] is computed."""
     cfg = _model()
     params = jax.eval_shape(lambda: init_params_for(jax.random.key(0), cfg))
