@@ -485,7 +485,8 @@ def slot_state_kinds(model) -> List[str]:
         )
     if recurrent is not None:
         kinds.append(
-            f"{recurrent[0]} linear-attention layers whose recurrent state "
+            f"{recurrent[0]} {getattr(model, 'recurrent_kind', 'linear-attention')} "
+            "layers whose recurrent state "
             "and convolution tail live in per-slot arrays"
         )
     return kinds
@@ -1374,7 +1375,8 @@ class InferenceEngine:
         if self._has_experts:
             self.moe_tokens += tokens
             if moe.takes_grouped(
-                self._model_cfg, rows, self.params["layers"].get("w_gate"),
+                self._model_cfg, rows,
+                moe.stored_expert_stack(self._model_cfg, self.params),
                 self.mesh,
             ):
                 self.moe_routed_tokens += tokens
@@ -1417,6 +1419,12 @@ class InferenceEngine:
                 "loop_steps": m.loop_steps,
                 "cache_layers": m.cache_layers,
                 "layer_passes": self.layer_passes,
+                # only for a family whose layers are ONE sub-layer each
+                # (models/nemotron_h.py): how many there are of each kind
+                **(
+                    {"layer_kinds": dict(m.layer_kind_counts)}
+                    if hasattr(m, "layer_kind_counts") else {}
+                ),
             },
             "kv": {
                 "bytes_per_token": PagePool.page_nbytes(
@@ -1458,6 +1466,12 @@ class InferenceEngine:
                 "experts_held": experts if experts > 1 else 0,
                 "router_width": (
                     getattr(m, "router_outputs", 0) if experts > 1 else 0
+                ),
+                # only for a family whose routed experts work in a space
+                # narrower than the residual stream: its width
+                **(
+                    {"latent_size": m.latent_size}
+                    if hasattr(m, "latent_size") else {}
                 ),
                 "tokens": self.moe_tokens,
                 "routed_tokens": self.moe_routed_tokens,

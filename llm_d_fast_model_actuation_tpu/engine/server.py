@@ -46,6 +46,7 @@ from prometheus_client import Counter, Gauge, Histogram
 
 from ..models import llama
 from ..models.moe import MoeConfig
+from ..models.nemotron_h import NemotronHConfig
 from ..models.olmo_hybrid import OlmoHybridConfig
 from ..models.kimi_linear import KimiLinearConfig
 from ..models.smallthinker import SmallThinkerConfig
@@ -483,6 +484,8 @@ MODEL_CONFIGS = {
     "olmo-hybrid-7b": OlmoHybridConfig.olmo_hybrid_7b,
     "tiny-kimi-linear": KimiLinearConfig.tiny_kimi_linear,
     "kimi-linear-48b-a3b": KimiLinearConfig.kimi_linear_48b_a3b,
+    "tiny-nemotron-h": NemotronHConfig.tiny_nemotron_h,
+    "nemotron-3-super-120b-a12b": NemotronHConfig.nemotron_3_super_120b_a12b,
 }
 
 

@@ -83,6 +83,9 @@ class KimiLinearConfig(moe.MoeConfig):
     ``head_dim`` and ``num_kv_heads`` are carried as published and size
     nothing: the pages are latent (:attr:`latent_cache`)."""
 
+    #: (llama.patterned) the module of this package that is its forward
+    forward_module = "kimi_linear"
+
     routed_experts: bool = True
     router_scoring: str = "sigmoid"
     #: per layer of one period: "kda" or "mla"

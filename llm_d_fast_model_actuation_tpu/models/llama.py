@@ -415,7 +415,8 @@ def recurrent_state(cfg) -> "Tuple[int, Tuple[int, ...], Tuple[int, ...]] | None
     state (engine/kv_cache.py:KVLayout): (its layers, the shape of one
     layer's recurrent state for one slot, the shape of its convolution tail).
     None for a model whose every layer keeps K and V alone.
-    (models/olmo_hybrid.py and models/kimi_linear.py are those families.)"""
+    (models/olmo_hybrid.py, models/kimi_linear.py and models/nemotron_h.py
+    are those families.)"""
     return getattr(cfg, "recurrent_state", None)
 
 
@@ -431,20 +432,16 @@ def latent_cache(cfg) -> "Tuple[int, int] | None":
 def patterned(cfg):
     """The module whose forward carries a config whose layers are of more
     than one kind, on the trunk's signatures; None for the one block of this
-    file."""
-    if latent_cache(cfg) is not None:
-        from . import kimi_linear
+    file. The config names it (``forward_module``, a module of this
+    package): which kinds of sequence state a family keeps does not tell its
+    forward, since two families keep recurrent state beside pages of K and
+    V."""
+    name = getattr(cfg, "forward_module", None)
+    if name is None:
+        return None
+    import importlib
 
-        return kimi_linear
-    if recurrent_state(cfg) is not None:
-        from . import olmo_hybrid
-
-        return olmo_hybrid
-    if layer_pattern(cfg) is not None:
-        from . import smallthinker
-
-        return smallthinker
-    return None
+    return importlib.import_module(f"{__package__}.{name}")
 
 
 def _project_qkv(

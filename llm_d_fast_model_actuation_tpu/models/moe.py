@@ -67,11 +67,20 @@ class MoeConfig(llama.LlamaConfig):
     num_experts: int = 8
     experts_per_token: int = 2
     #: True: this family always routes (:func:`routed_ffn`, each token's
-    #: top-k only, whatever the row count). False: :func:`expert_ffn` goes
+    #: top-k only, whatever the row count), but for programs traced with
+    #: ``dense_max_rows`` rows or fewer. False: :func:`expert_ffn` goes
     #: by the rows of the trace (:func:`takes_grouped`)
     routed_experts: bool = False
-    #: the experts' gate activation in :func:`routed_ffn`: "silu" or "relu"
+    #: a routing family's programs of this many rows or fewer compute every
+    #: HELD expert on every row (:func:`held_dense_ffn`); 0: none does
+    dense_max_rows: int = 0
+    #: the experts' activation in :func:`routed_ffn`: "silu", "relu" or
+    #: "relu2" (the squared ReLU)
     expert_activation: str = "silu"
+    #: True: an expert is three matrices, ``act(x w_gate) * (x w_up)`` into
+    #: ``w_down``. False: two, ``act(x w_up)`` into ``w_down``
+    #: (:func:`expert_stacks` names them)
+    expert_gated: bool = True
     #: the router's outputs, where ``num_experts`` is the SHARE of them this
     #: config holds (one chip of several that share each layer: experts
     #: ``share_index * num_experts ...`` of ``router_width``); 0: all of them
@@ -94,6 +103,13 @@ class MoeConfig(llama.LlamaConfig):
     def router_outputs(self) -> int:
         """The experts the router chooses among, held here or not."""
         return self.router_width or self.num_experts
+
+    @property
+    def expert_input_size(self) -> int:
+        """The width the routed experts read and write: the residual
+        stream's, unless the family routes in a narrower space
+        (models/nemotron_h.py)."""
+        return self.hidden_size
 
     @classmethod
     def mixtral_8x7b(cls) -> "MoeConfig":
@@ -265,12 +281,22 @@ def route(cfg: MoeConfig, logits: jnp.ndarray, bias=None):
     return weights * cfg.routed_scaling, top_idx
 
 
+def _activate(cfg, u: jnp.ndarray, g: "jnp.ndarray | None") -> jnp.ndarray:
+    """What goes into an expert's down projection, in ``u``'s dtype: ``act(g)
+    * u`` for a gated expert, ``act(u)`` for one of two matrices (``g`` None)."""
+    if g is None:
+        return _gate_act(cfg, u).astype(u.dtype)
+    return _gate_act(cfg, g).astype(u.dtype) * u
+
+
 def _gate_act(cfg, g: jnp.ndarray) -> jnp.ndarray:
-    """The experts' gate activation, float32 math: SiLU (Mixtral) or ReLU
-    (SmallThinker's sparse ReGLU)."""
+    """The experts' activation, float32 math: SiLU (Mixtral), ReLU
+    (SmallThinker's sparse ReGLU) or the squared ReLU (Nemotron-H)."""
     gf = g.astype(jnp.float32)
     if cfg.expert_activation == "relu":
         return jax.nn.relu(gf)
+    if cfg.expert_activation == "relu2":
+        return jnp.square(jax.nn.relu(gf))
     if cfg.expert_activation == "silu":
         return jax.nn.silu(gf)
     raise ValueError(f"unknown expert activation {cfg.expert_activation!r}")
@@ -296,7 +322,7 @@ def _gmm_fits(cfg: MoeConfig) -> bool:
     Every other shape (SmallThinker's 2,560 x 768 experts) and every other
     backend goes to ``jax.lax.ragged_dot``."""
     _, tk, tn = GMM_TILES
-    h, f = cfg.hidden_size, cfg.intermediate_size
+    h, f = cfg.expert_input_size, cfg.intermediate_size
     return cfg.attention_impl == "pallas" and all(
         width % tk == 0 and width % tn == 0 for width in (h, f)
     )
@@ -341,14 +367,17 @@ def routed_ffn(
 ) -> jnp.ndarray:
     """Top-k routed expert FFN that computes only the routed experts.
 
-    x: [..., hidden]. Each of a token's k assignments becomes one row; the
-    rows are sorted by expert (a stable argsort of N*k small integers), the
-    gate, up and down projections are grouped matmuls over the sorted rows,
-    and the k results of a token are gathered back and summed with the
-    router's probabilities in float32. Dropless: a row is never discarded,
+    x: [..., the experts' input width]. Each of a token's k assignments
+    becomes one row; the rows are sorted by expert (a stable argsort of N*k
+    small integers), the gate (where the experts have one:
+    ``cfg.expert_gated``), up and down projections are grouped matmuls over
+    the sorted rows, and the k results of a token are gathered back and
+    summed with the router's probabilities in float32. Dropless: a row is never discarded,
     whether an expert gets every token or none. ``router_logits`` [..., E]
     are given by a family whose router reads something else than ``x``
-    (SmallThinker: the layer's input, before attention). ``layer``: the
+    (SmallThinker: the layer's input, before attention; Nemotron-H: the
+    layer's input, where ``x`` is its projection into the latent space the
+    experts work in). ``layer``: the
     expert matrices in ``lp`` are whole stacks [L, E, ...] and this is the
     layer to compute (:func:`_grouped`).
 
@@ -386,9 +415,12 @@ def routed_ffn(
         pad = -(n * k) % GMM_TILES[0] if kernel else 0
         padded = jnp.pad(order, (0, pad)) if pad else order
         rows = xf[padded // k]  # [N*k (+ pad), h]: row r is token order[r] // k
-        g = _grouped(rows, lp["w_gate"], sizes, layer, kernel)
+        g = (
+            _grouped(rows, lp["w_gate"], sizes, layer, kernel)
+            if cfg.expert_gated else None
+        )
         u = _grouped(rows, lp["w_up"], sizes, layer, kernel)
-        act = _gate_act(cfg, g).astype(x.dtype) * u
+        act = _activate(cfg, u, g)
         y = _grouped(act, lp["w_down"], sizes, layer, kernel)  # [N*k (+), h]
         # back to token order: a gather by the inverse permutation
         inverse = jnp.zeros_like(order).at[order].set(
@@ -404,8 +436,65 @@ def routed_ffn(
     return out.astype(x.dtype).reshape(*lead, h)
 
 
-#: the names of the expert matrices in a layer's parameters
+def held_dense_ffn(
+    cfg: MoeConfig, lp: Dict[str, Any], x: jnp.ndarray,
+    router_logits: jnp.ndarray,
+) -> jnp.ndarray:
+    """The layer :func:`routed_ffn` computes, DENSE-compute and
+    sparse-weight over the experts held here: every held expert runs on
+    every row and the router's weights for it, zero off a row's top k and
+    for an expert that is not here, weight the sum. ``lp`` holds the
+    layer's own expert matrices [E, ...]. For a decode batch far smaller
+    than the assignments it makes: 128 rows x 22 of 512 touch every one of
+    128 held experts, so every matrix is read either way, and the grouped
+    matmul, handed 2,816 assignment rows of which a quarter lie in a group,
+    took 2.8 times the reads' time on the chip where this form is bound by
+    them (PERF.md section 6, PR 42). The same mathematics: the share test
+    holds for both."""
+    E = cfg.num_experts
+    with jax.named_scope("router"):
+        probs, idx = route(cfg, router_logits, lp.get("router_bias"))
+        # an expert that is not here is no column of the one-hot
+        onehot = jax.nn.one_hot(
+            idx - cfg.share_index * E, E, dtype=jnp.float32
+        )
+        weights = jnp.einsum("...k,...ke->...e", probs, onehot)
+    with jax.named_scope("experts"):
+        # the experts are the matmuls' BATCH axis, on both operands: the
+        # stacks are read as they are stored, [E, in, out]. (With the rows
+        # as the free axis of one operand alone, "nh,ehf->nef", the chip's
+        # compiler makes a convolution over the experts and copies every
+        # matrix into another layout first, 3.6 GB of temps a step.)
+        lead, h = x.shape[:-1], x.shape[-1]
+        xb = jnp.broadcast_to(x.reshape(-1, h), (E, math.prod(lead), h))
+        u = jnp.einsum("enh,ehf->enf", xb, lp["w_up"])
+        g = (
+            jnp.einsum("enh,ehf->enf", xb, lp["w_gate"])
+            if cfg.expert_gated else None
+        )
+        y = jnp.einsum("enf,efh->enh", _activate(cfg, u, g), lp["w_down"])
+        out = jnp.einsum(
+            "enh,ne->nh", y.astype(jnp.float32), weights.reshape(-1, E)
+        )
+    return out.astype(x.dtype).reshape(*lead, h)
+
+
+#: the names of a gated expert's matrices in a layer's parameters
 EXPERT_STACKS = ("w_gate", "w_up", "w_down")
+
+
+def expert_stacks(cfg: MoeConfig):
+    """The names of the matrices an expert of ``cfg`` has."""
+    return EXPERT_STACKS if cfg.expert_gated else EXPERT_STACKS[1:]
+
+
+def stored_expert_stack(cfg: MoeConfig, params: Dict[str, Any]) -> Any:
+    """One of the expert stacks as ``params`` store it (:func:`takes_grouped`
+    asks whether it is quantized): among the layers' parameters, or in a
+    stack of the expert layers' own (``experts``) for a family whose layers
+    do not all have experts."""
+    home = params["experts"] if "experts" in params else params["layers"]
+    return home[expert_stacks(cfg)[-1]]
 
 #: rows (tokens of one traced program) from which a family that does not
 #: always route takes the grouped form. The reckoning, per layer of E
@@ -443,7 +532,7 @@ def takes_grouped(cfg: MoeConfig, rows: int, w: Any, mesh=None) -> bool:
     (:func:`expert_ffn`), the engine for its counter with the bucket it
     dispatched (``/v1/stats.moe.routed_tokens``)."""
     if cfg.routed_experts:
-        return True
+        return rows > cfg.dense_max_rows
     # a grouped matmul over int8 stacks, or over a sharded group axis, is
     # not built
     if is_quantized(w) or _shards_experts(mesh):

@@ -62,6 +62,9 @@ _HI = jax.lax.Precision.HIGHEST
 
 @dataclass(frozen=True)
 class OlmoHybridConfig(llama.LlamaConfig):
+    #: (llama.patterned) the module of this package that is its forward
+    forward_module = "olmo_hybrid"
+
     #: per layer of one period: "linear" or "full"
     layer_kinds: Tuple[str, ...] = ("linear", "linear", "linear", "full")
     linear_heads: int = 30
@@ -356,12 +359,16 @@ def _gates(cfg, lp, x):
 
 def _conv(lp, ext, rows: int):
     """Depthwise causal convolution and SiLU: ``ext`` [b, K - 1 + rows, C]
-    is the tail followed by the rows' inputs -> float32 [b, rows, C]."""
+    is the tail followed by the rows' inputs -> float32 [b, rows, C]. A
+    family whose convolution has a bias (models/nemotron_h.py) brings
+    ``conv_bias`` [C]."""
     w = lp["conv"].astype(jnp.float32)
     acc = sum(
         w[i] * ext[:, i : i + rows].astype(jnp.float32)
         for i in range(w.shape[0])
     )
+    if "conv_bias" in lp:
+        acc = acc + lp["conv_bias"].astype(jnp.float32)
     return jax.nn.silu(acc)
 
 

@@ -13,12 +13,14 @@ from typing import Any, Dict
 
 import jax
 
-from . import kimi_linear, llama, moe, olmo_hybrid
+from . import kimi_linear, llama, moe, nemotron_h, olmo_hybrid
 
 
 def init_params_for(key: jax.Array, cfg: llama.LlamaConfig) -> Dict[str, Any]:
     if isinstance(cfg, kimi_linear.KimiLinearConfig):
         params = kimi_linear.init_params(key, cfg)
+    elif isinstance(cfg, nemotron_h.NemotronHConfig):
+        params = nemotron_h.init_params(key, cfg)
     elif isinstance(cfg, moe.MoeConfig):
         params = moe.init_params(key, cfg)
     elif isinstance(cfg, olmo_hybrid.OlmoHybridConfig):
@@ -57,6 +59,8 @@ def _init_fn(cfg: llama.LlamaConfig):
 def logical_axes_for(cfg: llama.LlamaConfig) -> Dict[str, Any]:
     if isinstance(cfg, kimi_linear.KimiLinearConfig):
         axes = kimi_linear.param_logical_axes(cfg)
+    elif isinstance(cfg, nemotron_h.NemotronHConfig):
+        axes = nemotron_h.param_logical_axes(cfg)
     elif isinstance(cfg, moe.MoeConfig):
         axes = moe.param_logical_axes(cfg)
     elif isinstance(cfg, olmo_hybrid.OlmoHybridConfig):

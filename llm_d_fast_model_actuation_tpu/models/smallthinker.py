@@ -43,6 +43,9 @@ from .quant import qmat
 
 @dataclass(frozen=True)
 class SmallThinkerConfig(moe.MoeConfig):
+    #: (llama.patterned) the module of this package that is its forward
+    forward_module = "smallthinker"
+
     routed_experts: bool = True
     #: the experts' gate activation (sparse ReGLU)
     expert_activation: str = "relu"

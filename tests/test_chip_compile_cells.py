@@ -342,6 +342,76 @@ def test_decodemix_cell_programs_fit_the_chip(topo, program, bucket):
             for k in kernels_found)
 
 
+@pytest.mark.parametrize(
+    "program,bucket",
+    [("chunk", 8), _every_chip_run_compiles_it("prefill", 1024), ("suffix", 1024)],
+)
+def test_ssmchat_cell_programs_fit_the_chip(topo, program, bucket):
+    """The programs of the cell ``nemotron-3-super-120b.ssmchat`` at its real
+    sizes and engine options, compiled for the described chip: the pool is
+    the ONE attention layer's pages of 2 KV heads, the 5 Mamba-2 layers'
+    recurrent state stands beside it (128 state channels minor: lane-aligned,
+    stored as reckoned, 2.72 GB at 128 slots), the paged decode and prefill
+    kernels are in at 16 query rows a KV head, nothing the size of a layer of
+    the state (the smallest of the three pools) is held as a temp (the
+    state's layers are rewritten in place), no held expert stack is copied,
+    and arguments + temps are under 13 GB."""
+    compiled, cfg, cell, model = _compile_cell_program(
+        topo, "nemotron-3-super-120b.ssmchat", program, bucket
+    )
+    d, lay, keys = cell.dims, cfg.kv_layout, cell.family.keys
+    assert (lay.global_layers, lay.state_layers) == (1, 5) == (
+        model.cache_layers, model.mamba_layers)
+    assert lay.state_shape == (128, 64, 128) and lay.tail_shape == (3, 10240)
+    assert lay.table_width == 4096 // 16 + 1
+    pages = keys.kv_bytes(d, cfg.num_pages, cfg.page_size)
+    recurrent = keys.state_bytes(d, cfg.max_batch)
+    assert pages == 24592 * 16 * 1_024 and recurrent == 128 * 5 * 4_255_744
+    # state bytes as laid out equal to as counted
+    assert recurrent == lay.state_nbytes(cfg.max_batch, 2)
+    state = 2 * keys.param_count(d) + pages + recurrent
+    assert 12.41e9 < state < 12.43e9
+    ma = compiled.memory_analysis()
+    assert state <= ma.argument_size_in_bytes < state + 0.04e9
+    assert ma.temp_size_in_bytes < 0.3e9
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 13e9
+    text = compiled.as_text()
+    # the grouped expert matmuls are XLA's ragged-dot custom calls in the
+    # prompt programs; the decode chunk's 128 rows compute every held expert
+    # (models/moe.py:held_dense_ffn: batched matmuls that read the stacks as
+    # stored) and hold none; the Pallas kernels are the attention layer's
+    # (the suffix program's attention over the pages is XLA's)
+    assert "tpu_custom_call" in text
+    assert ("ragged-dot" in text) == (program != "chunk")
+    layer_pool = cfg.num_pages * cfg.page_size * model.kv_dim
+    layer_state = 128 * 128 * 64 * 128
+    layer_experts = 128 * 1024 * 2688
+    assert layer_pool < layer_state < layer_experts
+    # what writes something the size of a layer of the pages (the smallest)
+    # is the pool's own write or a Mamba-2 layer's update of the state, in
+    # place: its output aliases the carried array
+    lines = {line.strip()[:200]: line for line in text.splitlines()}
+    sized = pool_sized_ops(text, layer_pool)
+    assert [row for row in sized if "aliasing" not in lines[row[1]]] == []
+    assert all(
+        "f32[5,128,128,64,128]" in row[1] or "bf16[1,24592,16,256]" in row[1]
+        for row in sized)
+    if program == "chunk":
+        kernels_found = _kernel_vmem_args(text, "paged_decode_inline")
+        tile = (2, 128, model.kv_dim)
+        assert len(kernels_found) >= 1 and all(
+            k[-2:] == [tile, tile] for k in kernels_found)
+        # the state's step is TWO ops a layer, which the roofline metric's
+        # reader picks by the whole state as their first operand
+        assert len(set(re.findall(
+            r"%(multiply_reduce_fusion[\w.]*) = f32\[128,128,64\]\S* fusion\(", text
+        ))) == 5
+        # ... and a layer's routed experts ONE, with the stacks as operands
+        assert len(set(re.findall(
+            r"%(fusion[\w.]*) = bf16\[128,1024\]\S* fusion\(%get-tuple-element", text
+        ))) >= 5
+
+
 def _kernel_vmem_args(text, name):
     """For every Mosaic kernel called ``name`` in a compiled program's HLO
     text, the shapes of its VMEM operands in order (blocks in, blocks out,

@@ -40,6 +40,7 @@ DECODE_CASES = [
     (1, 8, 2, 64, 8, 3, 2, 1),  # GQA 4x
     (2, 32, 8, 128, 16, 3, 2, 1),  # Llama-3-8B / Mistral-7B heads
     (2, 32, 4, 64, 16, 3, 3, 1),  # TinyLlama heads
+    (2, 32, 2, 128, 16, 3, 1, 0),  # Nemotron-H heads: 16 query rows a KV head
 ]
 
 
@@ -87,6 +88,7 @@ def test_paged_decode_matches_reference(
         (2, 32, 4, 2, 16, 8),
         (1, 64, 8, 8, 32, 16),  # MHA
         (2, 64, 8, 2, 16, 64),  # single q block
+        (1, 64, 32, 2, 128, 32),  # Nemotron-H heads: 16 query rows a KV head
     ],
 )
 def test_flash_prefill_matches_reference(batch, seq, heads, kv_heads, head_dim, block_q):
