@@ -41,6 +41,7 @@ import numpy as np
 from jax.sharding import Mesh
 
 from ..models import llama, moe
+from ..ops.attention import suffix_columns
 from ..parallel.mesh import shard_pytree
 from ..utils import tracing
 from .kv_cache import KVLayout, OutOfPages, PageAllocator, PagePool
@@ -1141,6 +1142,12 @@ class InferenceEngine:
         #: whose program ran the layers as grouped matmuls, each token
         #: against its own experts only (models/moe.py:takes_grouped)
         self.window_tokens_evicted = 0
+        #: columns of the table rows the suffix program's attention layers
+        #: took, a dispatched segment and layer (/v1/stats "kv"): what a
+        #: form that scores the whole row would, and what the walk over
+        #: column blocks did (ops/attention.py:suffix_columns)
+        self.suffix_cols_row = 0
+        self.suffix_cols_scored = 0
         self.moe_tokens = 0
         self.moe_routed_tokens = 0
         #: layer applications the dispatched programs ran, counted on the
@@ -1386,14 +1393,30 @@ class InferenceEngine:
             ) - max(0, first - self._ring_len)
         self.state_token_updates += tokens * self.kv_layout.state_layers
 
-    def _count_segment(self, start_pos: int) -> None:
+    def _count_segment(self, start_pos: int, suffix_rows: int = 0) -> None:
         """A prefill segment at ``start_pos`` was dispatched: with recurrent
-        state it starts from zero or resumes from its slot's."""
-        if self.kv_layout.state_layers:
+        state it starts from zero or resumes from its slot's, and through
+        the suffix program (``suffix_rows``: its bucket) every layer with
+        pages attends over its table row, plain or a ring (latent pages: the
+        whole row, models/kimi_linear.py:mla_plain_attention)."""
+        lay = self.kv_layout
+        if lay.state_layers:
             if start_pos:
                 self.state_resumed_segments += 1
             else:
                 self.state_first_segments += 1
+        if suffix_rows:
+            ps = self.cfg.page_size
+            for layers, width, ring in (
+                (lay.global_layers, lay.pages_per_seq * ps, False),
+                (lay.window_layers, self._ring_len, True),
+            ):
+                self.suffix_cols_row += layers * width
+                self.suffix_cols_scored += layers * (
+                    width if lay.latent_width else suffix_columns(
+                        start_pos, suffix_rows, width, ps, ring=ring
+                    )
+                )
 
     def _count_passes(self, forwards: int) -> None:
         """``forwards`` whole forwards were dispatched (one a prefill
@@ -1450,6 +1473,11 @@ class InferenceEngine:
                 ),
                 "ring_bytes": self.pool.ring_nbytes(),
                 "window_tokens_evicted": self.window_tokens_evicted,
+                "suffix_cols_row": self.suffix_cols_row,
+                "suffix_cols_scored": self.suffix_cols_scored,
+                "suffix_cols_skipped": (
+                    self.suffix_cols_row - self.suffix_cols_scored
+                ),
             },
             "state": {
                 "layers": lay.state_layers,
@@ -2147,7 +2175,7 @@ class InferenceEngine:
         )
         self.dispatch_tokens["bucketed"] += len(seg)
         self._count_forward(start_pos, len(seg), bucket)
-        self._count_segment(start_pos)
+        self._count_segment(start_pos, suffix_rows=bucket)
         self._count_passes(1)
         tokens = np.zeros((1, bucket), dtype=np.int32)
         tokens[0, : len(seg)] = seg

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.attention import (
+    SUFFIX_Q_BLOCK,
     causal_prefill_attention,
     paged_decode_attention,
     paged_decode_attention_inline,
@@ -557,12 +558,6 @@ def _scan_layers(cfg: LlamaConfig, layer_fn, carry, params):
 # -- what the patterned forwards share (models/smallthinker.py,
 # models/olmo_hybrid.py): a scan over PERIODS of layers whose body unrolls
 # the period's layers statically, each kind with its own state ------------
-
-#: query rows the XLA suffix attention scores at a time: a 1,024-row
-#: segment against a 16k-token table row would otherwise hold 1.9 GB of
-#: float32 scores
-SUFFIX_Q_BLOCK = 128
-
 
 def period_indices(cfg, period: int):
     """The period indices, the scan's xs. The body indexes the stacked

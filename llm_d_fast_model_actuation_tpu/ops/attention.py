@@ -427,6 +427,40 @@ def ragged_paged_attention(
     return out.reshape(t, h, d).astype(q.dtype)
 
 
+#: query rows the XLA suffix attention scores at a time (the patterned
+#: families' suffix segments, models/llama.py:suffix_segment, and every
+#: walked row): a 1,024-row segment against a 16k-token table row would
+#: otherwise hold 1.9 GB of float32 scores, and 117 MB against one column
+#: block of it where 128 rows hold 15
+SUFFIX_Q_BLOCK = 128
+
+#: columns the suffix attention scores a step of its walk (a multiple of
+#: the page size: 64 pages of 16 tokens). A table row no longer than one
+#: step is scored in one shot, as it always was.
+SUFFIX_COL_BLOCK = 1024
+
+
+def _suffix_step(page_size: int, col_block: int):
+    """(pages, columns) of one step of the walk over a row of
+    ``page_size``-token pages: whole pages, at least one."""
+    pages = max(1, col_block // page_size)
+    return pages, pages * page_size
+
+
+def suffix_columns(
+    start: int, s: int, width: int, page_size: int, ring: bool = False,
+    col_block: int = SUFFIX_COL_BLOCK,
+) -> int:
+    """The columns :func:`paged_suffix_attention` scores for a segment of
+    ``s`` rows (its bucket) at ``start`` over a row of ``width`` columns, a
+    ring's or a plain one: the host's twin of the walk's trip count."""
+    _, step = _suffix_step(page_size, col_block)
+    end = start + s
+    if width <= step or (ring and end > width):
+        return width
+    return min(width, -(-end // step) * step)
+
+
 def paged_suffix_attention(
     q: jnp.ndarray,  # [batch, s, heads, head_dim] — suffix queries
     k_pages: jnp.ndarray,  # [layers, num_pages, page_size, kv_heads*head_dim]
@@ -436,6 +470,7 @@ def paged_suffix_attention(
     layer: jnp.ndarray,  # int32 scalar — the pool layer to read
     window: int = 0,  # > 0: sliding window; table rows are rings
     q_block: int = 0,  # > 0: score this many query rows at a time
+    col_block: int = SUFFIX_COL_BLOCK,  # columns a step of the walk
 ) -> jnp.ndarray:
     """Causal attention for a prompt SUFFIX over the paged cache.
 
@@ -445,14 +480,28 @@ def paged_suffix_attention(
     to every cache slot <= its own position. Padding queries (past the
     real suffix) produce garbage the caller ignores — same convention as
     the right-padded full prefill. GQA by head grouping (no repeated
-    K/V), XLA gather over the table — ctx is static so the whole thing is
-    one fused region.
+    K/V), XLA gather over the table.
+
+    A row of at most ``col_block`` columns is gathered whole and scored in
+    one shot. A longer one is WALKED in blocks of ``col_block`` columns: a
+    step gathers the block's pages alone and folds its scores into a
+    running float32 (max, sum, accumulator) a query row, ``q_block`` rows
+    (``SUFFIX_Q_BLOCK`` if the caller names none) at a time, and the walk
+    stops at the last block that can hold a key some query of the batch
+    sees — position ``start + s`` of a plain row, and of a ring that has
+    not wrapped (slot = position); every block of a ring that has. The
+    pages past it are never read.
     """
     b, s, h, d = q.shape
-    k = _gather_context(k_pages, layer, page_table, d)  # [b, ctx, kvh, d]
-    v = _gather_context(v_pages, layer, page_table, d)
-    ctx, kvh = k.shape[1:3]
+    ps = k_pages.shape[2]
+    ctx = page_table.shape[1] * ps
+    kvh = k_pages.shape[3] // d
     g = h // kvh
+    step_pages, step = _suffix_step(ps, col_block)
+    walked = ctx > step
+    if not walked:
+        k = _gather_context(k_pages, layer, page_table, d)  # [b, ctx, kvh, d]
+        v = _gather_context(v_pages, layer, page_table, d)
     qg = (q.astype(jnp.float32) * (d**-0.5)).astype(q.dtype).reshape(
         b, s, kvh, g, d
     )
@@ -463,34 +512,90 @@ def paged_suffix_attention(
         # that position, later than every real query) has been written
         kpos = _ring_positions(ctx, start + s - 1)[:, None, :]  # [b, 1, ctx]
     else:
-        kpos = jnp.arange(ctx)[None, None, :]
+        kpos = jnp.arange(ctx, dtype=jnp.int32)[None, None, :]
 
-    def attend(qg, qpos):  # [b, n, kvh, g, d], [b, n] -> [b, n, kvh, g, d]
+    def scores(qg, qpos, k, kpos):  # -> [b, n, kvh, g, cols] float32
         logits = jnp.einsum(
             "bsngd,bknd->bsngk", qg, k, preferred_element_type=jnp.float32
         )
-        mask = kpos <= qpos[:, :, None]  # [b, n, ctx]
+        mask = kpos <= qpos[:, :, None]  # [b, n, cols]
         if window:
             mask = mask & (kpos >= 0) & (kpos > qpos[:, :, None] - window)
-        logits = jnp.where(mask[:, :, None, None, :], logits, NEG_INF)
-        probs = jax.nn.softmax(logits, axis=-1)
-        return jnp.einsum(
-            "bsngk,bknd->bsngd", probs.astype(v.dtype), v,
-            preferred_element_type=jnp.float32,
-        )
+        return jnp.where(mask[:, :, None, None, :], logits, NEG_INF)
 
-    if q_block and s > q_block and s % q_block == 0:
-        # query rows in blocks, one after another: the [rows, ctx] scores
-        # of a long context exist for one block at a time
-        nb = s // q_block
-        out = jax.lax.map(
-            lambda blk: attend(*blk),
-            (
-                qg.reshape(b, nb, q_block, kvh, g, d).swapaxes(0, 1),
-                qpos.reshape(b, nb, q_block).swapaxes(0, 1),
-            ),
-        )  # [nb, b, q_block, kvh, g, d]
-        out = out.swapaxes(0, 1)
-    else:
-        out = attend(qg, qpos)
+    def blocks(x, n):  # [b, s, ...] -> [s // n, b, n, ...]
+        return x.reshape(b, s // n, n, *x.shape[2:]).swapaxes(0, 1)
+
+    if not walked:
+
+        def attend(blk):  # [b, n, kvh, g, d], [b, n] -> [b, n, kvh, g, d]
+            probs = jax.nn.softmax(scores(*blk, k, kpos), axis=-1)
+            return jnp.einsum(
+                "bsngk,bknd->bsngd", probs.astype(v.dtype), v,
+                preferred_element_type=jnp.float32,
+            )
+
+        if q_block and s > q_block and s % q_block == 0:
+            # query rows in blocks, one after another: the [rows, ctx]
+            # scores of a long context exist for one block at a time
+            out = jax.lax.map(
+                attend, (blocks(qg, q_block), blocks(qpos, q_block))
+            ).swapaxes(0, 1)  # [b, s // q_block, q_block, kvh, g, d]
+        else:
+            out = attend((qg, qpos))
+        return out.reshape(b, s, h, d).astype(q.dtype)
+
+    n = q_block or SUFFIX_Q_BLOCK
+    if s <= n or s % n:
+        n = s
+    qg, qpos = blocks(qg, n), blocks(qpos, n)
+    steps = -(-ctx // step)
+    pad = steps * step - ctx
+    if pad:
+        # a last block of spare columns: they hold no position
+        page_table = jnp.pad(page_table, ((0, 0), (0, pad // ps)))
+        kpos = jnp.pad(
+            kpos, ((0, 0), (0, 0), (0, pad)),
+            constant_values=-1 if window else jnp.iinfo(jnp.int32).max,
+        )
+    # the last column a query sees is its own; a ring that has wrapped
+    # holds visible keys in any of its slots
+    trips = jnp.minimum(steps, (jnp.max(start) + s - 1) // step + 1)
+    if window:
+        trips = jnp.where(jnp.max(start) + s > ctx, steps, trips)
+
+    def fold(j, state):
+        cols = jax.lax.dynamic_slice_in_dim(
+            page_table, j * step_pages, step_pages, axis=1
+        )
+        k = _gather_context(k_pages, layer, cols, d)  # [b, step, kvh, d]
+        v = _gather_context(v_pages, layer, cols, d)
+        kp = jax.lax.dynamic_slice_in_dim(kpos, j * step, step, axis=2)
+
+        def rows(blk):
+            qg, qpos, m, l, acc = blk
+            logits = scores(qg, qpos, k, kp)
+            m_new = jnp.maximum(m, logits.max(axis=-1))
+            # m starts above NEG_INF: a masked score weighs 0 also in a row
+            # that has seen no key yet
+            p = jnp.exp(logits - m_new[..., None])
+            alpha = jnp.exp(m - m_new)
+            acc = alpha[..., None] * acc + jnp.einsum(
+                "bsngk,bknd->bsngd", p.astype(v.dtype), v,
+                preferred_element_type=jnp.float32,
+            )
+            return m_new, alpha * l + p.sum(axis=-1), acc
+
+        return jax.lax.map(rows, (qg, qpos, *state))
+
+    lead = (s // n, b, n, kvh, g)
+    _, l, acc = jax.lax.fori_loop(
+        0, trips, fold,
+        (
+            jnp.full(lead, 0.5 * NEG_INF, jnp.float32),
+            jnp.zeros(lead, jnp.float32),
+            jnp.zeros((*lead, d), jnp.float32),
+        ),
+    )
+    out = (acc / l[..., None]).swapaxes(0, 1)  # [b, s // n, n, kvh, g, d]
     return out.reshape(b, s, h, d).astype(q.dtype)

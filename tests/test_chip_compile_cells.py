@@ -514,3 +514,26 @@ def test_batch_cell_prompt_rows_go_to_their_own_experts(topo, program, bucket):
     routed = rows * model.experts_per_token * per_expert
     flops = compiled.cost_analysis()["flops"]
     assert routed < flops < routed + 0.5 * dense
+
+
+def test_longmix_suffix_program_scores_no_whole_table_row(topo):
+    """The cell's ``suffix(128)`` program, compiled for the described chip:
+    its XLA suffix attention (ops/attention.py:paged_suffix_attention) walks
+    the table row in blocks of 1,024 columns, so no operation holds float32
+    scores as wide as a full-attention layer's row (16,384 columns) or a
+    ring (5,120), the score tiles of one block are there, and the walk is a
+    loop the program did not have (the periods' scan and the query blocks'
+    were its only ones)."""
+    import re
+
+    compiled, cfg, _, _ = _compile_cell_program(
+        topo, "smallthinker-21b.longmix", "suffix", 128
+    )
+    lay = cfg.kv_layout
+    widths = {lay.pages_per_seq * cfg.page_size, lay.ring_pages * cfg.page_size}
+    assert widths == {16384, 5120}
+    text = compiled.as_text()
+    minor = {int(m) for m in re.findall(r"f32\[[\d,]*?(\d+)\]", text)}
+    assert not widths & minor, sorted(widths & minor)
+    assert re.search(r"f32\[1,128,4,7,1024\]", text)
+    assert len(re.findall(r"\bwhile\(", text)) >= 3
