@@ -348,7 +348,9 @@ def kda_chunk_scan(q, k, v, beta, g, S0, chunk: int = gdn.CHUNK, sub: int = SUB)
     of the chunk's g, now a vector of d_k: S_i = Diag(alpha_i) S_{i-1} +
     k_i u_i^T with u_i = beta_i (v_i - (Diag(alpha_i) S_{i-1})^T k_i), so
     (I + A) U = beta V - (beta Gamma K) S with the strictly lower A_ij =
-    beta_i P_ij(k), O = (Gamma Q) S + M U with the lower M = P(q), where
+    beta_i P_ij(k) (solved once for all chunks by blocks, in matmuls:
+    ``olmo_hybrid.unit_lower_solve``), O = (Gamma Q) S + M U with the lower
+    M = P(q), where
 
         P_ij(x) = sum_d x_i[d] k_j[d] exp(G_i[d] - G_j[d])        (j <= i)
 
@@ -409,9 +411,7 @@ def kda_chunk_scan(q, k, v, beta, g, S0, chunk: int = gdn.CHUNK, sub: int = SUB)
     rhs = jnp.concatenate(
         [beta[..., None] * v, beta[..., None] * jnp.exp(G) * k], axis=-1
     )
-    W = jax.lax.linalg.triangular_solve(
-        A, rhs, left_side=True, lower=True, unit_diagonal=True
-    )
+    W = gdn.unit_lower_solve(A, rhs)
     w_v, w_k = W[..., :dv], W[..., dv:]
     q_dec = q * jnp.exp(G)
     k_dec = k * jnp.exp(G[..., -1:, :] - G)

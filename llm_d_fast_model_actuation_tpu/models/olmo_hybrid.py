@@ -55,6 +55,8 @@ from .quant import qmat
 
 #: tokens of one chunk of the chunkwise recurrence
 CHUNK = 64
+#: rows of a diagonal block of a chunk's triangular system (unit_lower_solve)
+SOLVE_BLOCK = 16
 #: inside the sum of squares of the q and k normalisation
 L2_EPS = 1e-6
 _HI = jax.lax.Precision.HIGHEST
@@ -258,6 +260,42 @@ def param_logical_axes(cfg: OlmoHybridConfig) -> Dict[str, Any]:
 # -- the gated delta rule ---------------------------------------------------------
 
 
+def unit_lower_solve(A, rhs):
+    """W of (I + A) W = rhs for strictly lower A [..., C, C] and rhs
+    [..., C, m], float32, by blocked substitution in whole C x C matmuls:
+    nothing runs row after row over the chunk. The inverse T of I + A is
+    built exactly, bottom up, and W = T rhs is one matmul:
+
+      * the diagonal blocks of ``SOLVE_BLOCK`` rows, taken together as one
+        block-diagonal D, are nilpotent, D^16 = 0, so (I + D)^-1 =
+        (I - D)(I + D^2)(I + D^4)(I + D^8), no series cut short;
+      * T holding the inverses of the diagonal blocks of b rows and E the
+        blocks of A that join two neighbours of them, T - T E T holds the
+        inverses of the blocks of 2b rows, [[T1, 0], [-T2 A21 T1, T2]] a
+        pair: 16 -> 32 -> 64.
+
+    The zeros outside the blocks are multiplied along: a 64 x 64 tile suits
+    the chip's matrix unit where sixteen rows do not, and a row of zeros in
+    A (a padded one) keeps its row of rhs bit for bit."""
+    C = A.shape[-1]
+    i = jnp.arange(C)
+    mm = lambda a, b: jnp.matmul(a, b, precision=_HI)  # noqa: E731
+    # rows and columns in one diagonal block of b rows
+    same = lambda b: (i[:, None] // b) == (i[None, :] // b)  # noqa: E731
+    with jax.named_scope("chunk_solve"):
+        b = min(SOLVE_BLOCK, C)
+        D = jnp.where(same(b), A, 0.0)
+        T = jnp.eye(C, dtype=A.dtype) - D
+        P, k = D, 1
+        while 2 * k < b:
+            P, k = mm(P, P), 2 * k
+            T = T + mm(T, P)
+        while b < C:
+            E = jnp.where(same(2 * b) & ~same(b), A, 0.0)
+            T, b = T - mm(T, mm(E, T)), 2 * b
+        return mm(T, rhs)
+
+
 def chunk_scan(q, k, v, beta, g, S0, chunk: int = CHUNK):
     """The recurrence over a segment, chunkwise. q, k [b, s, H, d_k], v
     [b, s, H, d_v], beta and g = log(alpha) [b, s, H], all float32; S0
@@ -268,7 +306,8 @@ def chunk_scan(q, k, v, beta, g, S0, chunk: int = CHUNK):
     product of the chunk's alphas up to i. Then, S the state the chunk starts
     from, (I + A) U = beta V - (beta gamma K) S with the strictly lower
     A_ij = beta_i (gamma_i / gamma_j) k_i.k_j, so U = W_v - W_k S where
-    [W_v | W_k] solves the unit triangular system once for all chunks;
+    [W_v | W_k] solves the unit triangular system once for all chunks
+    (:func:`unit_lower_solve`: the inverse of I + A by blocks, in matmuls);
     O = (gamma Q) S + M U with the lower M_ij = (gamma_i / gamma_j) q_i.k_j;
     and the chunk leaves gamma_C S + (gamma_C / gamma K)^T U. A row with
     beta = 0 and g = 0 (a padded one) changes nothing after it.
@@ -295,9 +334,7 @@ def chunk_scan(q, k, v, beta, g, S0, chunk: int = CHUNK):
     rhs = jnp.concatenate(
         [beta[..., None] * v, (beta * jnp.exp(gc))[..., None] * k], axis=-1
     )
-    W = jax.lax.linalg.triangular_solve(
-        A, rhs, left_side=True, lower=True, unit_diagonal=True
-    )
+    W = unit_lower_solve(A, rhs)
     w_v, w_k = W[..., :dv], W[..., dv:]
     M = jnp.einsum("...id,...jd->...ij", q, k, precision=_HI) * decay
     q_dec = q * jnp.exp(gc)[..., None]

@@ -235,6 +235,22 @@ def test_loopchat_cell_programs_fit_the_chip(topo, program, bucket):
         assert kernels_found and all(k[-2:] == [tile, tile] for k in kernels_found)
 
 
+def _solve_is_matmuls(text):
+    """A delta-rule family's prompt program solves its chunks' triangular
+    systems in float32 matmuls at the highest precision
+    (``olmo_hybrid.unit_lower_solve``) and holds no ``triangular_solve``."""
+    assert "InvertDiagBlocksLowerTriangular" not in text
+    assert "triangular_solve" not in text
+    dots = [
+        line for line in text.splitlines()
+        if "chunk_solve" in line and re.search(r" (convolution|dot)\(", line)
+    ]
+    assert dots and all(
+        "operand_precision={highest,highest}" in line
+        and re.search(r"= f32\[", line) for line in dots
+    )
+
+
 @pytest.mark.parametrize(
     "program,bucket",
     [("chunk", 8), ("prefill", 1024), _every_chip_run_compiles_it("suffix", 1024)],
@@ -277,6 +293,8 @@ def test_hybridmix_cell_programs_fit_the_chip(topo, program, bucket):
     sized = pool_sized_ops(text, whole_state)
     assert [row for row in sized if "aliasing" not in lines[row[1]]] == []
     assert all("f32[12,16,30,96,192]" in row[1] for row in sized)
+    if program != "chunk":
+        _solve_is_matmuls(text)
 
 
 @pytest.mark.parametrize(
@@ -340,6 +358,8 @@ def test_decodemix_cell_programs_fit_the_chip(topo, program, bucket):
         assert kernels_found and all(
             k == [(1, 32, 640), (1, 1, 640), (1, 32, 512), (2, 128, 640)]
             for k in kernels_found)
+    else:
+        _solve_is_matmuls(text)
 
 
 @pytest.mark.parametrize(
