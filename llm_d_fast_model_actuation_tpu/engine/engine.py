@@ -341,11 +341,6 @@ class Request:
     #: (req.pos tracks progress); excluded from decode dispatch until the
     #: final prefill segment samples the first token
     prefilling: bool = False
-    #: co-resident variant handle routing this request's forwards
-    #: (InferenceEngine.attach_variant): 0 = the engine's base params.
-    #: Routed requests require packed serving — the bucketed programs
-    #: always run base params.
-    variant: int = 0
     #: explicit [2] uint32 RNG key data seated instead of the derived
     #: key at admission. Set only on migrated-in requests: a seed-None
     #: request's key is derived from (engine seed, seq_id), both of
@@ -415,45 +410,6 @@ def _stop_holdback(out: List[int], stop_seqs) -> int:
                 best = k
                 break
     return best
-
-
-def _copy_node(node):
-    if isinstance(node, dict):
-        return dict(node)
-    if isinstance(node, (list, tuple)):
-        return list(node)
-    raise TypeError(f"not an interior pytree node: {type(node)!r}")
-
-
-def _leaf_at(params: Any, key: str) -> Any:
-    """Resolve a flat '/'-joined leaf key (the chunk_store digest-map
-    convention) inside a nested param tree. Raises KeyError/IndexError/
-    TypeError when the path does not lead to a leaf."""
-    node = params
-    for p in key.split("/"):
-        node = node[int(p)] if isinstance(node, (list, tuple)) else node[p]
-    return node
-
-
-def _subst_leaves(params: Any, delta: Dict[str, Any]) -> Any:
-    """Copy-on-write substitution of flat-keyed leaves into a nested
-    param tree: the returned tree aliases every untouched subtree of
-    ``params``, so tracing one variant pass per co-resident sibling
-    references each shared base tensor ONCE — the in-program half of
-    the HBM dedup (attach_variant holds only changed leaves on
-    device)."""
-    root = _copy_node(params)
-    for key, leaf in delta.items():
-        parts = key.split("/")
-        node = root
-        for p in parts[:-1]:
-            idx = int(p) if isinstance(node, list) else p
-            child = _copy_node(node[idx])
-            node[idx] = child
-            node = child
-        last = parts[-1]
-        node[int(last) if isinstance(node, list) else last] = leaf
-    return root
 
 
 class EngineAsleep(RuntimeError):
@@ -559,15 +515,6 @@ class ProgramSet:
         #: shapes (exec_pool.warmup_plan)
         self._mixed: Dict[int, Any] = {}
         self._chunks: Dict[int, Any] = {}
-        #: multi-variant twins of the mixed/chunk programs (co-resident
-        #: sibling serving, InferenceEngine.attach_variant): same
-        #: per-width/per-T cache discipline; jit additionally retraces
-        #: per delta-pytree structure (the resident set is an argument).
-        #: Never AOT-pooled — whenever no routed request is live the
-        #: dispatchers fall back to the plain programs above, so the
-        #: warmed executables keep serving base-only traffic untouched.
-        self._mixed_multi: Dict[int, Any] = {}
-        self._chunks_multi: Dict[int, Any] = {}
 
     def _pin_resident(self, *xs):
         """Constrain device-resident scheduler outputs to the replicated
@@ -785,158 +732,6 @@ class ProgramSet:
 
         return _mixed
 
-    def _make_mixed_multi(self, kvp: int):
-        """Multi-variant twin of :meth:`_make_mixed` (co-resident sibling
-        serving): V = 1 + len(deltas) unrolled forward passes over the
-        same packed buffer — pass 0 with the base params, pass v with
-        variant v's delta leaves substituted copy-on-write
-        (:func:`_subst_leaves`: every shared base tensor is referenced,
-        never duplicated). Pass v masks the buffer to its own rows
-        (``row_slot`` forced to -1 elsewhere — a masked row is exactly a
-        padding row: computed, never scattered into the KV pool), so
-        each row's KV is written exactly once, by its own variant's
-        weights, and per-row outputs match a solo dispatch of that
-        variant bit-for-bit (batch-composition invariance is the packed
-        path's existing contract). Logits merge row-wise by variant
-        index and the sampling tail runs ONCE on the merged logits, so
-        the per-slot RNG/count/bias discipline is identical to the plain
-        program."""
-        model_cfg = self.model_cfg
-        alt_k = self.alt_k
-
-        def _mixed_multi(
-            params, deltas, tok_variant, tokens, row_slot, positions,
-            count_row, sample_rows, sample_on, fresh_on, cache,
-            page_table, temps, topps, counts, pres, freq, skeys, bias,
-        ):
-            b = sample_rows.shape[0]
-            pt = jax.lax.slice_in_dim(page_table, 0, kvp, axis=1)
-            fresh = fresh_on > 0
-            counts = jnp.where(fresh[:, None], 0, counts)
-            bias = jnp.where(fresh[:, None], 0.0, bias)
-            add_slot = jnp.where(count_row > 0, row_slot, b)
-            counts = counts.at[add_slot, tokens].add(1, mode="drop")
-            logits = None
-            for v in range(len(deltas) + 1):
-                p_v = (
-                    params if v == 0
-                    else _subst_leaves(params, deltas[v - 1])
-                )
-                mine = tok_variant == v
-                rs_v = jnp.where(mine, row_slot, -1)
-                lg, cache = llama.mixed_step(
-                    p_v, model_cfg, tokens, rs_v, positions, cache,
-                    pt, mesh=self.mesh,
-                )
-                logits = (
-                    lg if logits is None
-                    else jnp.where(mine[:, None], lg, logits)
-                )
-            last = logits[sample_rows]  # [b, vocab]
-            keys = jax.random.wrap_key_data(skeys)
-            pairs = jax.vmap(jax.random.split)(keys)  # [b, 2]
-            subs = pairs[:, 1]
-            new_data = jax.random.key_data(pairs[:, 0])
-            active = sample_on > 0
-            skeys = jnp.where(active[:, None], new_data, skeys)
-            out = sample(
-                last, subs, temps, top_p=topps,
-                counts=counts, presence_penalty=pres,
-                frequency_penalty=freq, alt_k=alt_k, bias=bias,
-            )
-            tok, lp = out[0], out[1]
-            if alt_k > 0:
-                av, ai = out[2], out[3]
-            else:
-                av = jnp.zeros((tok.shape[0], 0), jnp.float32)
-                ai = jnp.zeros((tok.shape[0], 0), jnp.int32)
-            counts = counts.at[jnp.arange(b), tok].add(
-                active.astype(jnp.int32)
-            )
-            counts, bias = self._pin_resident(counts, bias)
-            return tok, lp, av, ai, cache, counts, bias, skeys
-
-        return _mixed_multi
-
-    def _make_chunk_multi(self, T: int):
-        """Multi-variant twin of :meth:`_make_chunk`: per fused step, one
-        decode pass per resident set member with the active mask
-        narrowed to that member's slots (an inactive row's KV write
-        drops inside llama.decode_step), logits merged by slot variant,
-        then the one shared sampling tail — so routed and base slots
-        decode bit-identically to their solo runs while sharing the
-        dispatch."""
-        model_cfg = self.model_cfg
-        eos = self.eos
-        alt_k = self.alt_k
-
-        def chunk_multi(
-            params, deltas, slot_variant, lt, pos, budget, cache,
-            page_table, temps, topps, counts, pres, freq, skeys, eos_on,
-            bias,
-        ):
-            trees = [params] + [_subst_leaves(params, d) for d in deltas]
-
-            def body(carry, _):
-                lt, pos, budget, cache, counts, skeys = carry
-                active = budget > 0
-                logits = None
-                for v, p_v in enumerate(trees):
-                    mine = slot_variant == v
-                    lg, cache = llama.decode_step(
-                        p_v, model_cfg, lt, pos, cache, page_table,
-                        active & mine, mesh=self.mesh,
-                    )
-                    logits = (
-                        lg if logits is None
-                        else jnp.where(mine[:, None], lg, logits)
-                    )
-                keys = jax.random.wrap_key_data(skeys)  # [b] typed keys
-                pairs = jax.vmap(jax.random.split)(keys)  # [b, 2]
-                subs = pairs[:, 1]
-                new_data = jax.random.key_data(pairs[:, 0])
-                skeys = jnp.where(active[:, None], new_data, skeys)
-                out = sample(
-                    logits, subs, temps, top_p=topps,
-                    counts=counts, presence_penalty=pres,
-                    frequency_penalty=freq,
-                    alt_k=alt_k, bias=bias,
-                )
-                nxt, lp = out[0], out[1]
-                if alt_k > 0:
-                    av, ai = out[2], out[3]
-                else:
-                    av = jnp.zeros((nxt.shape[0], 0), jnp.float32)
-                    ai = jnp.zeros((nxt.shape[0], 0), jnp.int32)
-                nxt = jnp.where(active, nxt, lt)
-                a32 = active.astype(jnp.int32)
-                counts = counts.at[jnp.arange(counts.shape[0]), nxt].add(a32)
-                pos = pos + a32
-                budget = budget - a32
-                if eos >= 0:
-                    budget = jnp.where(
-                        active & (nxt == eos) & (eos_on > 0), 0, budget
-                    )
-                return (
-                    (nxt, pos, budget, cache, counts, skeys),
-                    (nxt, lp, av, ai),
-                )
-
-            (
-                (lt, pos, budget, cache, counts, skeys),
-                (toks, lps, avs, ais),
-            ) = jax.lax.scan(
-                body, (lt, pos, budget, cache, counts, skeys), None, length=T
-            )
-            lt, pos, budget, counts, skeys = self._pin_resident(
-                lt, pos, budget, counts, skeys
-            )
-            return (
-                toks, lps, avs, ais, lt, pos, budget, cache, counts, skeys,
-            )
-
-        return chunk_multi
-
     def _make_chunk(self, T: int):
         model_cfg = self.model_cfg
         eos = self.eos
@@ -1026,32 +821,6 @@ class ProgramSet:
             # donate cache + the device-resident counts/bias mirrors
             fn = self._mixed[kvp] = jax.jit(
                 self._make_mixed(kvp), donate_argnums=(8, 12, 16)
-            )
-        return fn
-
-    def mixed_multi(self, kvp: int):
-        """The jitted multi-variant mixed program at page-table width
-        ``kvp`` — dispatched instead of :meth:`mixed` only on steps
-        whose buffer carries at least one routed row."""
-        fn = self._mixed_multi.get(kvp)
-        if fn is None:
-            # same donation set as mixed(), shifted by the two leading
-            # read-only variant args (deltas, tok_variant)
-            fn = self._mixed_multi[kvp] = jax.jit(
-                self._make_mixed_multi(kvp), donate_argnums=(10, 14, 18)
-            )
-        return fn
-
-    def chunk_multi(self, T: int):
-        """The jitted multi-variant T-step decode chunk — dispatched
-        instead of :meth:`chunk` only while a routed request occupies a
-        decodable slot."""
-        fn = self._chunks_multi.get(T)
-        if fn is None:
-            # chunk()'s donation set shifted by (deltas, slot_variant)
-            fn = self._chunks_multi[T] = jax.jit(
-                self._make_chunk_multi(T),
-                donate_argnums=(3, 4, 5, 6, 10, 13),
             )
         return fn
 
@@ -1328,25 +1097,6 @@ class InferenceEngine:
         #: did not dispatch the packed program) — the service mirrors
         #: these into the packed histogram/occupancy metrics and span
         self.last_step_stats: Optional[Dict[str, Any]] = None
-        # -- co-resident sibling variants (attach_variant) ------------------
-        #: variant handle -> {"delta": {flat_key: device leaf}, "nbytes",
-        #: "label"}: per-variant changed leaves, already device-resident
-        #: (device_put at attach is the ONLY H2D a sibling ever pays —
-        #: shared base tensors are the live self.params, held once).
-        #: Handle 0 is implicitly the base params and never appears here.
-        #: Handles are STABLE for a variant's lifetime: requests and the
-        #: service registry hold handles, and a detach re-derives the
-        #: dense dispatch order instead of renumbering anything in
-        #: flight.
-        self._variants: Dict[int, Dict[str, Any]] = {}
-        #: dense dispatch order: _variant_order[v-1] is the handle whose
-        #: delta rides pass v of the multi programs
-        self._variant_order: List[int] = []
-        self._next_variant_handle = 1
-        #: lifetime counters (observability / the coresident flight
-        #: recorder records)
-        self.variant_attaches = 0
-        self.variant_detaches = 0
 
     def _create_pool(self) -> None:
         """A fresh device sequence state (pages of K and V, or latent ones;
@@ -1773,115 +1523,6 @@ class InferenceEngine:
             # one holds dead device handles)
             self.programs.mesh = self.mesh
 
-    # -- co-resident sibling variants ----------------------------------------
-
-    def variant_hbm_bytes(self) -> int:
-        """Device bytes held by attached variant deltas — the accounting
-        basis of the service's --variant-hbm-mib admission."""
-        return sum(v["nbytes"] for v in self._variants.values())
-
-    def variant_handles(self) -> Dict[int, str]:
-        """handle -> label of every attached co-resident variant."""
-        return {h: v["label"] for h, v in self._variants.items()}
-
-    def _variant_live(self, handle: int) -> bool:
-        if any(r.variant == handle for r in self._waiting):
-            return True
-        return any(
-            r is not None and not r.done and r.variant == handle
-            for r in self._slots
-        )
-
-    def attach_variant(self, delta: Dict[str, Any], label: str = "") -> int:
-        """Make a sibling variant co-resident: device_put its changed
-        leaves (flat '/'-keyed host arrays, the chunk_store digest-map
-        convention) next to the shared base params and return a stable
-        routing handle for add_request. Blocks until the transfer lands
-        so the caller's wall clock prices the real H2D. Every delta leaf
-        is validated against the base leaf it replaces — a shape/dtype
-        mismatch would otherwise surface as a trace error deep inside
-        the multi program, unattributable to this attach."""
-        refuse_slot_state(self._model_cfg, "a co-resident variant attach")
-        if not self._packed:
-            raise ValueError(
-                "co-resident variants require packed serving: the "
-                "bucketed programs always run base params"
-            )
-        if self.lockstep is not None:
-            raise ValueError(
-                "co-resident variants are not supported for multi-host "
-                "gangs (the lockstep frame has no variant dimension)"
-            )
-        if self.params is None:
-            raise EngineAsleep("engine state is offloaded (sleeping)")
-        if not delta:
-            raise ValueError(
-                "variant delta is empty — an identical sibling needs no "
-                "co-residency, route its requests to the base"
-            )
-        dev: Dict[str, Any] = {}
-        nbytes = 0
-        for key, leaf in delta.items():
-            try:
-                base = _leaf_at(self.params, key)
-            except (KeyError, IndexError, TypeError):
-                raise ValueError(f"variant delta key {key!r} not in params")
-            arr = np.asarray(leaf)
-            if tuple(arr.shape) != tuple(base.shape) or (
-                np.dtype(arr.dtype) != np.dtype(base.dtype)
-            ):
-                raise ValueError(
-                    f"variant delta leaf {key!r} is "
-                    f"{arr.dtype}{tuple(arr.shape)}, base is "
-                    f"{base.dtype}{tuple(base.shape)}"
-                )
-            # exact placement of the base leaf it substitutes (sharded
-            # on meshes): the multi program's avals must line up
-            dev[key] = jax.device_put(arr, base.sharding)
-            nbytes += int(arr.nbytes)
-        jax.block_until_ready(dev)
-        handle = self._next_variant_handle
-        self._next_variant_handle += 1
-        self._variants[handle] = {
-            "delta": dev,
-            "nbytes": nbytes,
-            "label": label or f"variant-{handle}",
-        }
-        self._variant_order.append(handle)
-        self.variant_attaches += 1
-        return handle
-
-    def detach_variant(self, handle: int) -> int:
-        """Drop a co-resident variant's device deltas (delta-only
-        offload: the host copies live in the tiered pool, nothing moves
-        D2H). Refuses while any live request routes to the handle — the
-        caller drains or aborts first. Returns the device bytes
-        freed."""
-        v = self._variants.get(handle)
-        if v is None:
-            raise KeyError(f"no resident variant with handle {handle}")
-        if self._variant_live(handle):
-            raise ValueError(
-                f"resident variant {handle} has live requests; drain "
-                "before detach"
-            )
-        del self._variants[handle]
-        self._variant_order.remove(handle)
-        for leaf in v["delta"].values():
-            leaf.delete()
-        self.variant_detaches += 1
-        return int(v["nbytes"])
-
-    def _variant_pass_index(self) -> Dict[int, int]:
-        """handle -> pass index v (>= 1) in the multi programs' dense
-        dispatch order; base is always pass 0."""
-        return {h: i + 1 for i, h in enumerate(self._variant_order)}
-
-    def _variant_deltas(self) -> tuple:
-        return tuple(
-            self._variants[h]["delta"] for h in self._variant_order
-        )
-
     # -- request lifecycle --------------------------------------------------
 
     def add_request(
@@ -1901,25 +1542,10 @@ class InferenceEngine:
         ignore_eos: bool = False,
         logit_bias: "Dict[int, float] | None" = None,
         submit_time: Optional[float] = None,
-        variant: int = 0,
         trace: Optional[Any] = None,
     ) -> int:
         if not prompt:
             raise ValueError("empty prompt")
-        if variant:
-            if variant not in self._variants:
-                raise ValueError(f"unknown resident variant {variant}")
-            if not self._packed:
-                raise ValueError(
-                    "per-request variant routing requires packed serving"
-                )
-            if want_prompt_logprobs:
-                # echo falls back to the bucketed prompt-logprob prefill
-                # programs, which always run base params
-                raise ValueError(
-                    "echo (prompt logprobs) is not supported for "
-                    "variant-routed requests"
-                )
         if min(prompt) < 0 or max(prompt) >= self.cfg.model.vocab_size:
             # out-of-range ids would be silently clamped by the embedding
             # gather into garbage output; the HTTP layer pre-clamps, but a
@@ -1971,7 +1597,6 @@ class InferenceEngine:
             seed=seed,
             ignore_eos=ignore_eos,
             logit_bias=logit_bias or {},
-            variant=int(variant),
             trace=trace,
         )
         if submit_time is not None:
@@ -2061,15 +1686,7 @@ class InferenceEngine:
         need = PageAllocator.pages_needed(total, self.cfg.page_size)
         shared: List[int] = []
         hashes: List[str] = []
-        if (
-            self.prefix_cache is not None
-            and not req.want_prompt_logprobs
-            and req.variant == 0
-        ):
-            # routed requests never match: the cache indexes pages by
-            # prompt tokens only, and a page prefilled under one
-            # variant's weights holds that variant's KV — serving it to
-            # a sibling would silently cross-contaminate outputs
+        if self.prefix_cache is not None and not req.want_prompt_logprobs:
             shared, req.cached_tokens, hashes = self.prefix_cache.match(
                 req.prompt
             )
@@ -2318,9 +1935,8 @@ class InferenceEngine:
                         take = len(seg) if not final else len(seg) - 1
                         plp_parts.append((plp, take))
                     pos += len(seg)
-            if self.prefix_cache is not None and req.variant == 0:
-                # the full prompt pages now hold prompt KV: make them
-                # reusable (base-variant KV only — see _admit's match gate)
+            if self.prefix_cache is not None:
+                # the full prompt pages now hold prompt KV: make them reusable
                 self.prefix_cache.register(
                     req.prompt,
                     req.pages,
@@ -2592,11 +2208,6 @@ class InferenceEngine:
         count_row = np.zeros((T,), dtype=np.int32)
         sample_rows = np.zeros((b,), dtype=np.int32)
         sample_on = np.zeros((b,), dtype=np.int32)
-        #: per-row variant pass index (co-resident routing): all-zero
-        #: buffers dispatch the plain mixed program — attach_variant
-        #: with no routed traffic is off-inert, AOT warmup included
-        tok_variant = np.zeros((T,), dtype=np.int32)
-        vmap_idx = self._variant_pass_index() if self._variants else {}
         rows_used = 0
         decode_reqs: List[Request] = []
         segments: List[Tuple[Request, int, bool]] = []
@@ -2615,8 +2226,6 @@ class InferenceEngine:
                 req.pos : req.pos + take
             ]
             row_slot[start : start + take] = req.slot
-            if req.variant:
-                tok_variant[start : start + take] = vmap_idx[req.variant]
             positions[start : start + take] = np.arange(
                 req.pos, req.pos + take, dtype=np.int32
             )
@@ -2639,8 +2248,6 @@ class InferenceEngine:
             tokens[rows_used] = self._last_tokens[slot]
             row_slot[rows_used] = slot
             positions[rows_used] = req.pos
-            if req.variant:
-                tok_variant[rows_used] = vmap_idx[req.variant]
             sample_rows[slot] = rows_used
             sample_on[slot] = 1
             decode_reqs.append(req)
@@ -2737,17 +2344,12 @@ class InferenceEngine:
             if self._rows_stale:
                 self._upload_sched_table()
         d = self._dev
-        # any routed row switches the step to the multi-variant twin —
-        # an all-base buffer keeps the plain (possibly AOT-warmed)
-        # program, so co-residency costs base traffic nothing
-        routed_rows = int((tok_variant[:shape] > 0).sum())
         self.step_h2d_bytes["packed"] += (
             tokens[:shape].nbytes + row_slot[:shape].nbytes
             + positions[:shape].nbytes + count_row[:shape].nbytes
             + sample_rows.nbytes + sample_on.nbytes + fresh_on.nbytes
             + self._temps.nbytes + self._topps.nbytes + self._pres.nbytes
             + self._freqs.nbytes + self._slot_keys.nbytes
-            + (tok_variant[:shape].nbytes if routed_rows else 0)
         )
         self.last_step_stats = {
             "mode": "packed",
@@ -2756,7 +2358,6 @@ class InferenceEngine:
             "pad_rows": shape - valid,
             "decode_rows": len(decode_reqs),
             "prefill_tokens": prefill_tokens,
-            "routed_rows": routed_rows,
         }
         with tracing.span(
             "step.packed", rows=shape, tokens=valid,
@@ -2768,53 +2369,28 @@ class InferenceEngine:
                     prompt_tokens=prefill_tokens,
                 )
                 self._count_passes(1)
-                if routed_rows:
-                    tok, lp, av, ai, cache, counts_dev, bias_dev, skeys = (
-                        self.programs.mixed_multi(kvp)(
-                            self.params,
-                            self._variant_deltas(),
-                            tok_variant[:shape],
-                            tokens[:shape],
-                            row_slot[:shape],
-                            positions[:shape],
-                            count_row[:shape],
-                            sample_rows,
-                            sample_on,
-                            fresh_on,
-                            self.pool.as_tuple(),
-                            d["pt"],
-                            self._temps,
-                            self._topps,
-                            d["counts"],
-                            self._pres,
-                            self._freqs,
-                            self._slot_keys,
-                            d["bias"],
-                        )
+                tok, lp, av, ai, cache, counts_dev, bias_dev, skeys = (
+                    self._call_program(
+                        "mixed", mixed_bucket(shape, kvp),
+                        self.params,
+                        tokens[:shape],
+                        row_slot[:shape],
+                        positions[:shape],
+                        count_row[:shape],
+                        sample_rows,
+                        sample_on,
+                        fresh_on,
+                        self.pool.as_tuple(),
+                        d["pt"],
+                        self._temps,
+                        self._topps,
+                        d["counts"],
+                        self._pres,
+                        self._freqs,
+                        self._slot_keys,
+                        d["bias"],
                     )
-                else:
-                    tok, lp, av, ai, cache, counts_dev, bias_dev, skeys = (
-                        self._call_program(
-                            "mixed", mixed_bucket(shape, kvp),
-                            self.params,
-                            tokens[:shape],
-                            row_slot[:shape],
-                            positions[:shape],
-                            count_row[:shape],
-                            sample_rows,
-                            sample_on,
-                            fresh_on,
-                            self.pool.as_tuple(),
-                            d["pt"],
-                            self._temps,
-                            self._topps,
-                            d["counts"],
-                            self._pres,
-                            self._freqs,
-                            self._slot_keys,
-                            d["bias"],
-                        )
-                    )
+                )
                 self.pool.replace(cache)
                 # the program consumed (donated) and re-emitted the device-
                 # resident mirrors; they stay the between-dispatch truth
@@ -2857,9 +2433,8 @@ class InferenceEngine:
                 if not final:
                     continue
                 req.prefilling = False
-                if self.prefix_cache is not None and req.variant == 0:
+                if self.prefix_cache is not None:
                     # the full prompt's KV is now in pages: make it reusable
-                    # (base-variant KV only — see _admit's match gate)
                     self.prefix_cache.register(
                         req.prompt, req.pages, req.shared_pages,
                         known_hashes=getattr(req, "_prefix_hashes", ()),
@@ -2925,10 +2500,6 @@ class InferenceEngine:
             or r.frequency_penalty != 0.0
             or r.logit_bias
         ):
-            return None
-        if r.variant != 0:
-            # the verify program runs base params; accepting a routed
-            # request's proposals would verify against the wrong weights
             return None
         return r
 
@@ -3215,57 +2786,25 @@ class InferenceEngine:
             ph.set(T=T, live_slots=len(running))
             self._count_passes(T)
             d = self._dev
-            # a routed slot switches the chunk to the multi-variant twin
-            # (the plain program would decode it with base weights); with
-            # none live the plain, possibly AOT-warmed chunk serves as ever
-            if any(r.variant != 0 for r in running.values()):
-                vmap_idx = self._variant_pass_index()
-                slot_variant = np.zeros((self.cfg.max_batch,), dtype=np.int32)
-                for slot, r in running.items():
-                    if r.variant:
-                        slot_variant[slot] = vmap_idx[r.variant]
-                self.step_h2d_bytes[self._h2d_path()] += slot_variant.nbytes
-                (
-                    toks_dev, lps_dev, avs_dev, ais_dev, lt, pos, budget,
-                    cache, counts_dev, skeys_dev,
-                ) = self.programs.chunk_multi(T)(
-                    self.params,
-                    self._variant_deltas(),
-                    slot_variant,
-                    d["lt"],
-                    d["pos"],
-                    d["budget"],
-                    self.pool.as_tuple(),
-                    d["pt"],
-                    d["temps"],
-                    d["topp"],
-                    d["counts"],
-                    d["pres"],
-                    d["freq"],
-                    d["skeys"],
-                    d["eos_on"],
-                    d["bias"],
-                )
-            else:
-                (
-                    toks_dev, lps_dev, avs_dev, ais_dev, lt, pos, budget,
-                    cache, counts_dev, skeys_dev,
-                ) = self._chunk_fn(T)(
-                    self.params,
-                    d["lt"],
-                    d["pos"],
-                    d["budget"],
-                    self.pool.as_tuple(),
-                    d["pt"],
-                    d["temps"],
-                    d["topp"],
-                    d["counts"],
-                    d["pres"],
-                    d["freq"],
-                    d["skeys"],
-                    d["eos_on"],
-                    d["bias"],
-                )
+            (
+                toks_dev, lps_dev, avs_dev, ais_dev, lt, pos, budget,
+                cache, counts_dev, skeys_dev,
+            ) = self._chunk_fn(T)(
+                self.params,
+                d["lt"],
+                d["pos"],
+                d["budget"],
+                self.pool.as_tuple(),
+                d["pt"],
+                d["temps"],
+                d["topp"],
+                d["counts"],
+                d["pres"],
+                d["freq"],
+                d["skeys"],
+                d["eos_on"],
+                d["bias"],
+            )
             self.pool.replace(cache)
             self._dev = {
                 "lt": lt, "pos": pos, "budget": budget,

@@ -386,26 +386,6 @@ class HostModelPool:
         with self._mu:
             return list(self._manifests)
 
-    def staged_manifest(self, key: str) -> Optional[Dict[str, str]]:
-        """Non-consuming copy of an evicted model's flat digest manifest
-        (key -> digest), or None. The co-resident attach path diffs this
-        against the live base's digests WITHOUT popping the manifest —
-        the variant stays tier-rebuildable for a later full swap."""
-        with self._mu:
-            got = self._manifests.get(key)
-            return dict(got[0]) if got is not None else None
-
-    def staged_manifest_match(
-        self, model_id: str
-    ) -> Optional[Tuple[str, Dict[str, str]]]:
-        """:meth:`staged_manifest` under any checkpoint qualifier (most
-        recently evicted first); returns (matched_key, manifest)."""
-        with self._mu:
-            for k in reversed(self._manifests):
-                if k == model_id or k.startswith(model_id + "@"):
-                    return k, dict(self._manifests[k][0])
-        return None
-
     def take_staged(
         self, key: str
     ) -> Optional[Tuple[Dict[str, Any], Dict[str, str], str]]:
@@ -492,110 +472,3 @@ class HostModelPool:
         if self.chunks is not None:
             out["chunks"] = self.chunks.describe()
         return out
-
-
-class ResidentSetLedger:
-    """Device-tier refcounts for co-resident sibling variants
-    (docs/perf.md "Co-resident sibling variants").
-
-    The engine holds one device copy of every base leaf plus per-variant
-    delta leaves; this ledger mirrors that sharing on the host side so
-    observability can answer the acceptance question directly: how many
-    device bytes do N co-resident siblings occupy vs N full copies?
-
-    ``attach(model, shared, deltas)`` records a variant whose digest diff
-    against the live base splits its leaves into ``shared`` (digest ->
-    nbytes held by the base tensor, device bytes NOT re-paid) and
-    ``deltas`` (digest -> nbytes of the variant-private device leaf).
-    Refcounts let two attached variants share an identical delta leaf in
-    the accounting even though today's engine uploads each delta
-    privately — the ledger reports what dedup *saves*, not what a
-    hypothetical further dedup could save (``bytes_if_duplicated`` minus
-    ``bytes_device``).
-
-    Thread-safe: attach/detach run under the engine server's step lock,
-    but /metrics and /v1/stats read from other threads.
-    """
-
-    def __init__(self) -> None:
-        self._mu = threading.Lock()
-        #: digest -> [refs, nbytes] across base-shared leaves
-        self._shared: Dict[str, List[int]] = {}
-        #: model_id -> (shared_digests {d: nbytes}, delta_digests {d: nbytes})
-        self._members: Dict[str, Tuple[Dict[str, int], Dict[str, int]]] = {}
-
-    def attach(
-        self,
-        model_id: str,
-        shared: Dict[str, int],
-        deltas: Dict[str, int],
-    ) -> None:
-        with self._mu:
-            self._members.pop(model_id, None)
-            self._members[model_id] = (dict(shared), dict(deltas))
-            for d, n in shared.items():
-                ref = self._shared.get(d)
-                if ref is None:
-                    self._shared[d] = [1, int(n)]
-                else:
-                    ref[0] += 1
-
-    def detach(self, model_id: str) -> None:
-        with self._mu:
-            got = self._members.pop(model_id, None)
-            if got is None:
-                return
-            shared, _deltas = got
-            for d in shared:
-                ref = self._shared.get(d)
-                if ref is None:
-                    continue
-                ref[0] -= 1
-                if ref[0] <= 0:
-                    del self._shared[d]
-
-    def members(self) -> List[str]:
-        with self._mu:
-            return list(self._members)
-
-    def bytes_device(self) -> int:
-        """Actual variant device bytes: per-variant delta leaves only —
-        shared base leaves are the live engine's own tensors, already
-        counted in its residency, never re-paid per variant."""
-        with self._mu:
-            return sum(
-                sum(deltas.values())
-                for _shared, deltas in self._members.values()
-            )
-
-    def bytes_if_duplicated(self) -> int:
-        """What the same resident set would cost as full per-variant
-        copies: every member's shared + delta bytes, no dedup."""
-        with self._mu:
-            return sum(
-                sum(shared.values()) + sum(deltas.values())
-                for shared, deltas in self._members.values()
-            )
-
-    def bytes_saved(self) -> int:
-        """Device bytes co-residency avoids re-paying (the saved-bytes
-        gauge): duplicated-cost minus actual delta residency."""
-        return max(0, self.bytes_if_duplicated() - self.bytes_device())
-
-    def describe(self) -> Dict[str, Any]:
-        with self._mu:
-            members = {
-                m: {
-                    "shared_bytes": sum(shared.values()),
-                    "delta_bytes": sum(deltas.values()),
-                    "shared_leaves": len(shared),
-                    "delta_leaves": len(deltas),
-                }
-                for m, (shared, deltas) in self._members.items()
-            }
-        return {
-            "members": members,
-            "bytes_device": self.bytes_device(),
-            "bytes_if_duplicated": self.bytes_if_duplicated(),
-            "bytes_saved": self.bytes_saved(),
-        }

@@ -46,8 +46,9 @@ DEFAULT_KV_CHUNK_BYTES = 256 << 20
 #: any incompatible change so a mixed-version fleet rejects the handoff
 #: instead of mis-seating state. 2: ``kv.shape`` is the pool's stored
 #: layout [layers, pages, page_size, kv_heads * head_dim] (1 had the two
-#: minor axes apart; the chunk bytes are the same)
-WIRE_VERSION = 2
+#: minor axes apart; the chunk bytes are the same). 3: a request spec no
+#: longer carries ``variant`` (an importer of 2 requires the field)
+WIRE_VERSION = 3
 
 
 class ParkedResumeFailed(RuntimeError):
@@ -287,7 +288,7 @@ _REQ_WIRE_FIELDS = (
     "presence_penalty", "frequency_penalty", "want_top_logprobs",
     "want_prompt_logprobs", "seed", "ignore_eos", "out_tokens",
     "out_logprobs", "prompt_logprobs", "pos", "cached_tokens",
-    "streamed", "stop_requested", "variant",
+    "streamed", "stop_requested",
 )
 
 
@@ -378,7 +379,6 @@ def decode_request(spec: Dict[str, Any], request_cls: Any) -> Any:
     req.cached_tokens = int(spec["cached_tokens"])
     req.streamed = int(spec["streamed"])
     req.stop_requested = bool(spec["stop_requested"])
-    req.variant = int(spec["variant"])
     req.stop_seqs = tuple(tuple(int(t) for t in s) for s in spec["stop_seqs"])
     req.logit_bias = {int(t): float(v) for t, v in spec["logit_bias"].items()}
     req.out_top_logprobs = [

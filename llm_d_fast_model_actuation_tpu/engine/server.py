@@ -39,7 +39,7 @@ import threading
 import time
 from collections import deque
 from dataclasses import dataclass
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional
 
 from aiohttp import web
 from prometheus_client import Counter, Gauge, Histogram
@@ -404,37 +404,6 @@ ENGINE_STEP_H2D_BYTES = Counter(
     ["model", "path"],
 )
 
-# Co-resident sibling variants (docs/perf.md "Co-resident sibling
-# variants"): one shared base tensor set on device plus per-variant
-# deltas, routed per request inside the packed step — sibling traffic
-# then actuates with zero swaps. The gauges expose the HBM budget's
-# live accounting and the dedup the shared base is buying.
-ENGINE_RESIDENT_VARIANTS = Gauge(
-    "fma_engine_resident_variants",
-    "Device-resident model variants (the base model counts as 1)",
-)
-ENGINE_VARIANT_HBM_BYTES = Gauge(
-    "fma_engine_variant_hbm_bytes",
-    "Device bytes held by co-resident variant deltas (the "
-    "--variant-hbm-mib budget's numerator)",
-)
-ENGINE_CORESIDENT_SAVED_BYTES = Gauge(
-    "fma_engine_coresident_saved_bytes",
-    "Device bytes the shared base is saving vs full per-variant "
-    "copies (sum over residents of base bytes minus their delta)",
-)
-ENGINE_RESIDENT_EVENTS = Counter(
-    "fma_engine_resident_events_total",
-    "Resident-set changes by event",
-    ["event"],  # attach | detach | reject
-)
-ENGINE_ROUTED_REQUESTS = Counter(
-    "fma_engine_routed_requests_total",
-    "Requests routed per-request to a co-resident variant (label = the "
-    "variant model; base-model requests are not counted here)",
-    ["model"],
-)
-
 MODEL_CONFIGS = {
     "tiny": llama.LlamaConfig.tiny,
     "llama3-8b": llama.LlamaConfig.llama3_8b,
@@ -580,29 +549,6 @@ def make_arg_parser() -> argparse.ArgumentParser:
         "(--packed-serving): bounds per-step prefill work like "
         "--max-prefill-tokens bounds segments. 0 = auto (256, floored "
         "so every decode slot plus one prefill block always fits)",
-    )
-    p.add_argument(
-        "--resident-variants",
-        type=int,
-        default=1,
-        help="co-resident sibling variants (docs/perf.md 'Co-resident "
-        "sibling variants'): maximum model variants simultaneously "
-        "device-resident, the base model included — N > 1 enables "
-        "POST /v1/residents (attach a sibling's changed leaves next to "
-        "the shared base tensors) and per-request model routing inside "
-        "the packed step, so sibling traffic actuates with zero swaps. "
-        "1 (default) keeps the one-resident engine byte-for-byte. "
-        "Requires --packed-serving on; incompatible with multi-host "
-        "gangs and --quantization",
-    )
-    p.add_argument(
-        "--variant-hbm-mib",
-        type=int,
-        default=1024,
-        help="device byte budget (MiB) for co-resident variant deltas "
-        "(--resident-variants): an attach whose delta would exceed it "
-        "is REJECTED to the existing swap path (409), never OOMs the "
-        "serving engine",
     )
     p.add_argument(
         "--speculative-ngram",
@@ -889,38 +835,6 @@ def validate_parsed_args(args: argparse.Namespace) -> None:
                 "lockstep control frame); sharded single-process meshes "
                 "via --tensor-parallel-size compose fine"
             )
-    if getattr(args, "resident_variants", 1) < 1:
-        raise ValueError("--resident-variants must be >= 1")
-    if getattr(args, "variant_hbm_mib", 0) < 0:
-        raise ValueError("--variant-hbm-mib must be >= 0")
-    if getattr(args, "resident_variants", 1) > 1:
-        if getattr(args, "packed_serving", "off") != "on":
-            raise ValueError(
-                "--resident-variants > 1 requires --packed-serving on: "
-                "per-request variant routing lives inside the packed "
-                "mixed-batch step (the bucketed programs always run "
-                "base params)"
-            )
-        if getattr(args, "quantization", ""):
-            raise ValueError(
-                "--resident-variants > 1 is incompatible with "
-                "--quantization (variant deltas are content-matched "
-                "against full-precision leaf digests)"
-            )
-        if getattr(args, "content_hash", "on") != "on":
-            raise ValueError(
-                "--resident-variants > 1 requires --content-hash on: "
-                "the shared-base/delta split IS the digest diff"
-            )
-        gang = getattr(args, "num_processes", 0) or int(
-            os.environ.get("FMA_NUM_PROCESSES", "0") or 0
-        )
-        if gang > 1:
-            raise ValueError(
-                "--resident-variants > 1 is not supported for "
-                "multi-host gangs (the lockstep frame has no variant "
-                "dimension)"
-            )
     if getattr(args, "slo_ttft_ms", 0.0) < 0:
         raise ValueError("--slo-ttft-ms must be >= 0 (0 = off)")
     if getattr(args, "slo_tpot_ms", 0.0) < 0:
@@ -1001,18 +915,10 @@ class ProfileConflict(Exception):
     process-global: exactly one concurrent capture), or DELETE with none."""
 
 
-class ResidentRejected(Exception):
-    """POST /v1/residents admission rejection (cap or --variant-hbm-mib
-    budget) or a detach refused while the variant still has live work —
-    surfaced as 409, the explicit reject-to-swap-path contract: the
-    caller falls back to the existing swap verb, the engine never OOMs
-    chasing one more co-resident."""
-
-
 class MigrationRejected(Exception):
     """A migration verb's precondition failed with nothing displaced —
-    identity mismatch, co-resident variants attached, no capacity,
-    spent fence token (the double-resume refusal) — surfaced as 409:
+    identity mismatch, no capacity, spent fence token (the double-resume
+    refusal) — surfaced as 409:
     the orchestrator picks another destination or leaves the streams
     where they are."""
 
@@ -1362,28 +1268,6 @@ class EngineService:
         self._swap_bucket_bytes = (
             max(1, getattr(args, "swap_bucket_mib", 256)) << 20
         )
-        # Co-resident sibling variants (docs/perf.md "Co-resident sibling
-        # variants"): model_id -> {handle, nbytes, tier, keys, attached_at}
-        # for every variant attached via POST /v1/residents. The base
-        # model is NOT an entry here — it is variant handle 0 by
-        # construction. Guarded by _lock (attach/detach hold it around
-        # the device edge, same discipline as swap).
-        self._residents: Dict[str, Dict[str, Any]] = {}
-        #: variant handle -> model id (the engine thread's label lookup
-        #: for per-model metrics on finished routed requests)
-        self._variant_models: Dict[int, str] = {}
-        self._resident_variants_cap = max(
-            1, int(getattr(args, "resident_variants", 1) or 1)
-        )
-        self._variant_hbm_budget = (
-            max(0, int(getattr(args, "variant_hbm_mib", 0) or 0)) << 20
-        )
-        #: device-tier refcounts for shared base leaves vs per-variant
-        #: deltas (engine/model_pool.py ResidentSetLedger): feeds the
-        #: coresident saved-bytes gauge and the launcher's ledger row
-        from .model_pool import ResidentSetLedger
-
-        self.resident_ledger = ResidentSetLedger()
         # AOT executable pool + warmup plan (engine/exec_pool.py): compiled
         # programs pooled beside the host model pool, with spill into the
         # launcher's persistent compile-cache dir so entries survive
@@ -2211,20 +2095,7 @@ class EngineService:
         forever (a swapped-out model showing phantom queue depth /
         occupancy to the HPA and the fleet rollup). Histograms and
         counters are cumulative and stay. The arrival EWMA restarts too:
-        its observations belonged to the outgoing model.
-
-        With co-resident variants the live set is ``{args.model} ∪
-        residents`` — not a single model — so retiring checks membership
-        first: detaching one variant must never drop a series another
-        live variant (or the base) is still writing, and a swap back to
-        a model that happens to also be attached as a variant keeps its
-        series too."""
-        if (
-            previous == self.args.model
-            or previous == self._base_resident_id()
-            or previous in self._residents
-        ):
-            return
+        its observations belonged to the outgoing model."""
         for g in (
             ENGINE_QUEUE_DEPTH,
             ENGINE_SLOT_OCCUPANCY,
@@ -2366,7 +2237,7 @@ class EngineService:
             fut = entry[3]
             if fut is not None and not fut.done():
                 fut.set_exception(exc)
-            tr = entry[16]
+            tr = entry[15]
             if tr is not None:
                 tr.finish(
                     entry[14], now, keep=True, outcome="state_loss",
@@ -2637,8 +2508,7 @@ class EngineService:
         the importer stamps its own clock."""
         (prompt, max_tokens, temperature, _fut, _on_tokens, top_p,
          stop_seqs, presence, freq, want_alts, want_plp, seed,
-         ignore_eos, logit_bias, _submit_t, variant, trace,
-         _stop_watch) = entry
+         ignore_eos, logit_bias, _submit_t, trace, _stop_watch) = entry
         spec = {
             "prompt": [int(t) for t in prompt],
             "max_tokens": int(max_tokens),
@@ -2654,7 +2524,6 @@ class EngineService:
             "logit_bias": {
                 str(t): float(v) for t, v in (logit_bias or {}).items()
             },
-            "variant": int(variant),
         }
         if trace is not None:
             ctx = trace.context()
@@ -2701,7 +2570,6 @@ class EngineService:
             bool(spec["ignore_eos"]),
             {int(t): float(v) for t, v in spec.get("logit_bias", {}).items()},
             time.monotonic(),
-            int(spec.get("variant", 0)),
             trace,
             None,
         )
@@ -2753,12 +2621,6 @@ class EngineService:
             raise MigrationRejected(
                 f"model {model!r} is not the serving base "
                 f"(serving {self.args.model!r})"
-            )
-        if self._residents:
-            raise MigrationRejected(
-                "co-resident variants attached "
-                f"({sorted(self._residents)}); detach them "
-                "(DELETE /v1/residents) before migrating the base"
             )
         if self.sleeper.is_sleeping:
             raise MigrationRejected(
@@ -2910,12 +2772,6 @@ class EngineService:
         if self.sleeper.is_sleeping:
             raise MigrationRejected(
                 "instance is sleeping; wake it before importing"
-            )
-        if self._residents:
-            raise MigrationRejected(
-                "co-resident variants attached "
-                f"({sorted(self._residents)}); detach them "
-                "(DELETE /v1/residents) before importing a parked bundle"
             )
         self._check_identity(doc.get("identity") or {})
         t0 = time.monotonic()
@@ -3153,7 +3009,7 @@ class EngineService:
             for i, entry in enumerate(bundle.pending):
                 fut = entry[3]
                 cid = claims.get(f"p{i}")
-                tr = entry[16]
+                tr = entry[15]
                 if fut is None or fut.done():
                     gone += 1
                     if cid:
@@ -3414,7 +3270,6 @@ class EngineService:
         req.logit_bias = {
             int(t): float(v) for t, v in spec["logit_bias"].items()
         }
-        req.variant = int(spec["variant"])
         req.on_tokens = entry[4]
         req.submit_time = entry[14]
         return req
@@ -3978,28 +3833,6 @@ class EngineService:
             add(name, ck)
         for model, ckpt in extra:
             add(model, ckpt or "")
-        # the coresident tier: every swap candidate re-priced as a
-        # delta-only attach (near-zero vs its full swap row above), plus
-        # zero-cost detach rows for the attached set — the scheduler
-        # compares route-per-request against swap-per-burst from one view
-        coresident: List[Dict[str, Any]] = []
-        if self._resident_variants_cap > 1:
-            for model, ckpt in seen:
-                if model == self.args.model:
-                    continue
-                try:
-                    coresident.append(self.price_attach(model, ckpt))
-                except Exception as e:  # noqa: BLE001 — one bad row never 500s the view
-                    coresident.append(
-                        {
-                            "kind": "attach",
-                            "model": model,
-                            "checkpoint_dir": ckpt,
-                            "error": f"{type(e).__name__}: {e}",
-                        }
-                    )
-            for model in sorted(self._residents):
-                coresident.append(self.price_detach(model))
         return {
             "model": self.args.model,
             "is_sleeping": self.sleeper.is_sleeping,
@@ -4014,7 +3847,6 @@ class EngineService:
                 "compiles_total": exec_desc.get("compiles_total", 0),
             },
             "candidates": candidates,
-            "coresident": coresident,
         }
 
     def _price_migrate_row(self) -> Dict[str, Any]:
@@ -4081,446 +3913,17 @@ class EngineService:
             )
         return rec
 
-    # -- co-resident sibling variants (docs/perf.md "Co-resident sibling
-    # variants"): POST /v1/residents attach/detach, admission, pricing ------
-
-    def _resident_id(self, model: str, checkpoint_dir: str = "") -> str:
-        """A resident's routing identity: the pool key
-        (``model@checkpoint_dir``) when a checkpoint qualifies it, else
-        the bare model name — sibling checkpoints of the SAME named
-        model (the fleet's variant-i layout) must be distinguishable
-        both in the registry and in a request body's ``model`` field."""
-        return (
-            _pool_key(model, checkpoint_dir) if checkpoint_dir else model
-        )
-
-    def _base_resident_id(self) -> str:
-        """The live base's identity in the same namespace (variant 0)."""
-        return self._resident_id(
-            self.args.model, getattr(self.args, "checkpoint_dir", "") or ""
-        )
-
-    def _resident_source(
-        self, model: str, checkpoint_dir: str = ""
-    ) -> Tuple[Optional[Dict[str, str]], str]:
-        """Resolve a variant candidate's flat digest map WITHOUT
-        consuming any tier state: ``(digests, tier)`` where tier is
-        ``"pool"`` (slept pooled runtime), ``"prefetched"`` (staged host
-        weights), or ``"disk"`` (an evicted manifest whose chunks the
-        tiers can still serve) — or ``(None, "cold")``: the attach path
-        rejects rather than cold-read a checkpoint (prefetch first, or
-        swap)."""
-        entry = (
-            self.model_pool.peek(_pool_key(model, checkpoint_dir))
-            if checkpoint_dir
-            else self.model_pool.peek_match(model)
-        )
-        if entry is not None:
-            digests = getattr(entry.runtime, "digests", None)
-            if digests:
-                tier = (
-                    "prefetched"
-                    if isinstance(entry.runtime, _PrefetchedWeights)
-                    else "pool"
-                )
-                return dict(digests), tier
-        if checkpoint_dir:
-            man = self.model_pool.staged_manifest(
-                _pool_key(model, checkpoint_dir)
-            )
-        else:
-            got = self.model_pool.staged_manifest_match(model)
-            man = got[1] if got is not None else None
-        if man:
-            return man, "disk"
-        return None, "cold"
-
-    def _variant_delta_keys(
-        self, digests: Dict[str, str]
-    ) -> Tuple[List[str], int, int]:
-        """Digest-diff a variant's flat map against the live base:
-        ``(delta_keys, delta_bytes, shared_bytes)``. Byte figures come
-        from the BASE engine's device leaves (attach validates each
-        delta leaf to the base leaf's shape+dtype, so this sizing is
-        exact by construction — the same reason delta-swap byte
-        predictions are). Key-set drift (a leaf only one side has) is a
-        structural mismatch, not a delta: siblings share architecture."""
-        base = self._runtime.digests
-        if not base:
+    def check_request_model(self, model: Optional[str]) -> None:
+        """A completions body's ``model`` names the model being served (by
+        name or as ``model@checkpoint_dir``, the pool's key) or is left out;
+        anything else is a ValueError (400)."""
+        ckpt = getattr(self.args, "checkpoint_dir", "") or ""
+        served = _pool_key(self.args.model, ckpt) if ckpt else self.args.model
+        if model and model not in (self.args.model, served):
             raise ValueError(
-                "the live base model carries no content digests "
-                "(random-init or quantized build): co-residency needs "
-                "the digest diff"
+                f"model {model!r} is not served by this engine "
+                f"(serving {served!r}); swap to it first"
             )
-        drift = set(base).symmetric_difference(digests)
-        if drift:
-            raise ValueError(
-                f"variant is not a sibling of {self.args.model}: "
-                f"{len(drift)} weight keys differ structurally "
-                f"(e.g. {sorted(drift)[:4]})"
-            )
-        from .engine import _leaf_at
-
-        delta_keys: List[str] = []
-        delta_bytes = 0
-        shared_bytes = 0
-        for k, d in digests.items():
-            n = int(_leaf_at(self.engine.params, k).nbytes)
-            if base.get(k) != d:
-                delta_keys.append(k)
-                delta_bytes += n
-            else:
-                shared_bytes += n
-        return delta_keys, delta_bytes, shared_bytes
-
-    def price_attach(
-        self, model: str, checkpoint_dir: str = ""
-    ) -> Dict[str, Any]:
-        """Pre-transfer pricing of a co-resident attach: delta wire
-        bytes from the digest diff (byte-exact by construction — the
-        same ``plan_swap`` arithmetic, minus the outgoing leg a swap
-        would pay) and seconds from the ``coresident.h2d`` bandwidth
-        EWMA (h2d family fallback before its first measurement).
-        Read-only: nothing is fetched, nothing moves."""
-        digests, tier = self._resident_source(model, checkpoint_dir)
-        rid = self._resident_id(model, checkpoint_dir)
-        out: Dict[str, Any] = {
-            "kind": "attach",
-            "model": rid,
-            "checkpoint_dir": checkpoint_dir,
-            "tier": "coresident",
-            "source_tier": tier,
-        }
-        if rid in self._residents:
-            return {
-                **out,
-                "predicted_bytes": 0,
-                "predicted_s": 0.0,
-                "measured": True,
-                "attached": True,
-            }
-        if digests is None:
-            raise ValueError(
-                f"{model!r} is not resolvable from the pool or disk "
-                "tiers; prefetch it first (POST /v1/prefetch) or swap"
-            )
-        delta_keys, delta_bytes, shared_bytes = self._variant_delta_keys(
-            digests
-        )
-        s, measured = self.costs.bandwidths.seconds_for(
-            "coresident.h2d", delta_bytes
-        )
-        return {
-            **out,
-            "predicted_bytes": delta_bytes,
-            "predicted_s": round(s, 6),
-            "predicted_delta_leaves": len(delta_keys),
-            "predicted_shared_bytes": shared_bytes,
-            "measured": measured,
-        }
-
-    def price_detach(self, model: str) -> Dict[str, Any]:
-        """Pricing a detach: zero wire bytes — the delta's host copy
-        never left the content-addressed tiers, so dropping the device
-        leaves moves nothing (the near-zero actuation co-residency
-        exists to buy)."""
-        return {
-            "kind": "detach",
-            "model": model,
-            "tier": "coresident",
-            "predicted_bytes": 0,
-            "predicted_s": 0.0,
-            "measured": True,
-        }
-
-    def residents_view(self) -> Dict[str, Any]:
-        """GET /v1/residents: the resident set, its budget, and the
-        shared-base dedup accounting (what the launcher ledger and the
-        fleet rollup carry)."""
-        # lock-free snapshot (GIL-atomic dict reads): callers include
-        # paths already holding the step lock
-        used = self.engine.variant_hbm_bytes()
-        rows = {m: dict(info) for m, info in self._residents.items()}
-        return {
-            "base": self.args.model,
-            "resident_variants": 1 + len(rows),
-            "resident_variants_cap": self._resident_variants_cap,
-            "variant_hbm_budget_bytes": self._variant_hbm_budget,
-            "variant_hbm_bytes": used,
-            "residents": rows,
-            "ledger": self.resident_ledger.describe(),
-        }
-
-    def attach_resident(
-        self, model: str, checkpoint_dir: str = ""
-    ) -> Dict[str, Any]:
-        """POST /v1/residents: attach `model` as a device-resident
-        sibling variant of the live base — upload ONLY the delta leaves
-        (digest diff), share every matching base tensor in place, and
-        route per-request from then on. Admission is explicit: over the
-        ``--resident-variants`` cap or the ``--variant-hbm-mib`` budget
-        raises :class:`ResidentRejected` (HTTP 409) and the caller falls
-        back to the swap path — never OOM."""
-        pred: Optional[Dict[str, Any]] = None
-        try:
-            pred = self.price_attach(model, checkpoint_dir)
-        except Exception:  # noqa: BLE001 — pricing must never block the verb
-            pred = None
-        with tracing.span(
-            "engine.attach_resident", model=model, base=self.args.model
-        ) as sp:
-            if pred is not None:
-                sp.set(
-                    predicted_bytes=pred.get("predicted_bytes"),
-                    predicted_s=pred.get("predicted_s"),
-                )
-            try:
-                out = self._attach_resident_impl(
-                    model, checkpoint_dir, pred
-                )
-            except ResidentRejected as e:
-                ENGINE_RESIDENT_EVENTS.labels(event="reject").inc()
-                self._record_actuation(
-                    "attach", model, trigger="client", tier="coresident",
-                    pred=pred, actual_bytes=0, actual_s=0.0,
-                    outcome="rejected", extra={"reason": str(e)},
-                )
-                raise
-            sp.set(handle=out.get("handle"))
-            return out
-
-    def _attach_resident_impl(
-        self,
-        model: str,
-        checkpoint_dir: str,
-        pred: Optional[Dict[str, Any]],
-    ) -> Dict[str, Any]:
-        if self.is_follower or self.is_gang:
-            raise ValueError(
-                "co-resident variants are not supported for multi-host "
-                "gangs"
-            )
-        if self._resident_variants_cap <= 1:
-            raise ValueError(
-                "co-residency is off (--resident-variants 1); restart "
-                "with --resident-variants N and --packed-serving on"
-            )
-        rid = self._resident_id(model, checkpoint_dir)
-        with self._admin_lock():
-            if self.sleeper.is_sleeping:
-                raise ValueError(
-                    "engine is sleeping; wake_up before attaching "
-                    "residents"
-                )
-            if rid == self._base_resident_id():
-                raise ValueError(
-                    f"{rid!r} is the live base model (variant 0); "
-                    "nothing to attach"
-                )
-            if rid in self._residents:
-                # idempotent: the resident set is declarative state
-                return {
-                    **self.residents_view(),
-                    "model": rid,
-                    "handle": self._residents[rid]["handle"],
-                    "attached": False,
-                }
-            if 1 + len(self._residents) >= self._resident_variants_cap:
-                raise ResidentRejected(
-                    f"resident-set cap reached "
-                    f"({self._resident_variants_cap} including the "
-                    "base); detach a variant or use the swap path"
-                )
-            digests, tier = self._resident_source(model, checkpoint_dir)
-            if digests is None:
-                raise ResidentRejected(
-                    f"{rid!r} is not resolvable from the pool or disk "
-                    "tiers; prefetch it first (POST /v1/prefetch) or "
-                    "swap"
-                )
-            delta_keys, delta_bytes, shared_bytes = (
-                self._variant_delta_keys(digests)
-            )
-            if not delta_keys:
-                raise ValueError(
-                    f"{rid!r} is byte-identical to the live base "
-                    "(empty digest diff); route to the base instead"
-                )
-            used = self.engine.variant_hbm_bytes()
-            if (
-                self._variant_hbm_budget
-                and used + delta_bytes > self._variant_hbm_budget
-            ):
-                raise ResidentRejected(
-                    f"variant delta ~{delta_bytes >> 20} MiB would "
-                    f"exceed --variant-hbm-mib "
-                    f"({self._variant_hbm_budget >> 20} MiB, "
-                    f"{used >> 20} MiB in use); detach a variant or "
-                    "use the swap path"
-                )
-            chunks = self.model_pool.chunks
-            delta: Dict[str, Any] = {}
-            for k in delta_keys:
-                arr = (
-                    chunks.fetch(digests[k])
-                    if chunks is not None
-                    else None
-                )
-                if arr is None:
-                    raise ResidentRejected(
-                        f"variant leaf {k!r} is not resolvable from "
-                        "the host/disk tiers (evicted past the disk "
-                        "budget, or staged quantized); prefetch "
-                        f"{rid!r} or use the swap path"
-                    )
-                delta[k] = arr
-            t0 = time.monotonic()
-            handle = self.engine.attach_variant(delta, label=rid)
-            dt = time.monotonic() - t0
-            wire = sum(int(a.nbytes) for a in delta.values())
-            self.costs.observe_transfer("coresident.h2d", wire, dt)
-            from .engine import _leaf_at
-
-            # shared leaves sized from the base's device tensors (the
-            # exact bytes a full copy would have re-paid); accumulate per
-            # digest — content-identical leaves (e.g. two norm scales
-            # initialized alike) are distinct device tensors, so their
-            # bytes must not collapse into one ledger entry
-            shared_map: Dict[str, int] = {}
-            for k, d in digests.items():
-                if k not in delta:
-                    shared_map[d] = shared_map.get(d, 0) + int(
-                        _leaf_at(self.engine.params, k).nbytes
-                    )
-            delta_map: Dict[str, int] = {}
-            for k, a in delta.items():
-                delta_map[digests[k]] = delta_map.get(
-                    digests[k], 0
-                ) + int(a.nbytes)
-            self.resident_ledger.attach(
-                rid, shared=shared_map, deltas=delta_map
-            )
-            self._residents[rid] = {
-                "handle": handle,
-                "model": model,
-                "checkpoint_dir": checkpoint_dir,
-                "nbytes": wire,
-                "delta_leaves": len(delta),
-                "shared_bytes": shared_bytes,
-                "source_tier": tier,
-                "attached_at": time.time(),
-            }
-            self._variant_models[handle] = rid
-        with self._slo_mu:
-            self._actuations["attach"] = (
-                self._actuations.get("attach", 0) + 1
-            )
-        ENGINE_RESIDENT_EVENTS.labels(event="attach").inc()
-        self._observe_residents()
-        rec = self._record_actuation(
-            "attach", rid, trigger="client", tier="coresident",
-            pred=pred, actual_bytes=wire, actual_s=dt,
-            extra={
-                "source_tier": tier,
-                "handle": handle,
-                "delta_leaves": len(delta),
-                "shared_bytes": shared_bytes,
-            },
-        )
-        return {
-            **self.residents_view(),
-            "model": rid,
-            "handle": handle,
-            "attached": True,
-            "wire_bytes": wire,
-            "attach_s": round(dt, 6),
-            "source_tier": tier,
-            "costs": rec.as_dict(),
-        }
-
-    def detach_resident(
-        self, model: str, checkpoint_dir: str = ""
-    ) -> Dict[str, Any]:
-        """DELETE /v1/residents: drop a variant's device delta leaves.
-        Zero wire bytes — the host tiers still hold every chunk by
-        content, so a re-attach is another delta-only upload and a full
-        swap back remains possible. Refused (409) while the variant has
-        live or queued work."""
-        rid = self._resident_id(model, checkpoint_dir)
-        pred = self.price_detach(rid)
-        with tracing.span(
-            "engine.detach_resident", model=rid
-        ) as sp:
-            with self._admin_lock():
-                info = self._residents.get(rid)
-                if info is None:
-                    raise ValueError(
-                        f"{rid!r} is not an attached resident; "
-                        f"attached: {sorted(self._residents)}"
-                    )
-                handle = info["handle"]
-                t0 = time.monotonic()
-                try:
-                    freed = self.engine.detach_variant(handle)
-                except ValueError as e:
-                    raise ResidentRejected(str(e))
-                dt = time.monotonic() - t0
-                del self._residents[rid]
-                self._variant_models.pop(handle, None)
-                self.resident_ledger.detach(rid)
-            # after the registry drop: the live-set guard must see the
-            # variant as gone, or its gauge series would survive forever
-            self._retire_model_series(rid)
-            with self._slo_mu:
-                self._actuations["detach"] = (
-                    self._actuations.get("detach", 0) + 1
-                )
-            ENGINE_RESIDENT_EVENTS.labels(event="detach").inc()
-            self._observe_residents()
-            rec = self._record_actuation(
-                "detach", rid, trigger="client", tier="coresident",
-                pred=pred, actual_bytes=0, actual_s=dt,
-                extra={"handle": handle, "freed_bytes": freed},
-            )
-            sp.set(freed_bytes=freed)
-            return {
-                **self.residents_view(),
-                "model": rid,
-                "detached": True,
-                "freed_bytes": freed,
-                "detach_s": round(dt, 6),
-                "costs": rec.as_dict(),
-            }
-
-    def _observe_residents(self) -> None:
-        """Mirror the resident set into its gauges (attach/detach edges
-        and swap installs both route here)."""
-        ENGINE_RESIDENT_VARIANTS.set(1 + len(self._residents))
-        ENGINE_VARIANT_HBM_BYTES.set(self.engine.variant_hbm_bytes())
-        ENGINE_CORESIDENT_SAVED_BYTES.set(
-            self.resident_ledger.bytes_saved()
-        )
-
-    def resolve_request_model(self, model: Optional[str]) -> int:
-        """Per-request routing (docs/engine.md "/v1/residents"): a
-        completions body's ``model`` resolves to a variant handle — the
-        base (0), an attached resident, or a 400 naming the live set.
-        Empty/None routes to the base (the pre-coresidency contract)."""
-        if (
-            not model
-            or model == self.args.model
-            or model == self._base_resident_id()
-        ):
-            return 0
-        info = self._residents.get(model)
-        if info is not None:
-            return info["handle"]
-        raise ValueError(
-            f"model {model!r} is not resident on this engine "
-            f"(base: {self._base_resident_id()!r}, residents: "
-            f"{sorted(self._residents)}); attach it via POST "
-            "/v1/residents or swap"
-        )
 
     def swap(
         self, model: str, checkpoint_dir: str = "", request_id: str = ""
@@ -4680,15 +4083,6 @@ class EngineService:
             if self.sleeper.is_sleeping:
                 raise ValueError(
                     "engine is sleeping; wake_up before swapping models"
-                )
-            if self._residents:
-                # a swap would tear down the base whose tensors every
-                # resident's shared leaves alias — and the offload peeks
-                # don't model the variant deltas
-                raise ValueError(
-                    "co-resident variants attached "
-                    f"({sorted(self._residents)}); detach them "
-                    "(DELETE /v1/residents) before swapping the base"
                 )
             t0 = time.monotonic()
             # Zero-drain (docs/perf.md "Zero-drain actuation"): preempt
@@ -5656,9 +5050,9 @@ class EngineService:
             for i, entry in enumerate(self._pending):
                 if entry[3] is fut:
                     self._pending.pop(i)
-                    if entry[16] is not None:
+                    if entry[15] is not None:
                         # tail-keep: aborted lifecycles always retain
-                        entry[16].finish(
+                        entry[15].finish(
                             entry[14], time.monotonic(), keep=True,
                             outcome="aborted",
                         )
@@ -5755,7 +5149,7 @@ class EngineService:
                     prompt, max_tokens, temperature, fut,
                     on_tokens, top_p, stop_seqs, presence, freq,
                     want_alts, want_plp, seed, ignore_eos,
-                    logit_bias, submit_t, variant, trace, stop_watch,
+                    logit_bias, submit_t, trace, stop_watch,
                 ) = self._pending.pop(0)
                 try:
                     seq_id = self.engine.add_request(
@@ -5771,7 +5165,6 @@ class EngineService:
                         ignore_eos=ignore_eos,
                         logit_bias=logit_bias,
                         submit_time=submit_t,
-                        variant=variant,
                         trace=trace,
                     )
                     self._futures[seq_id] = fut
@@ -5790,12 +5183,6 @@ class EngineService:
 
     def _observe_finished(self, req) -> None:
         m = self.args.model
-        v = getattr(req, "variant", 0)
-        if v:
-            # routed requests account under THEIR model label: per-model
-            # SLO/goodput series stay meaningful with N residents live
-            m = self._variant_models.get(v, m)
-            ENGINE_ROUTED_REQUESTS.labels(model=m).inc()
         now = time.monotonic()
         if req.done_time is not None:
             # step() stamps this before resolving the future; direct
@@ -6175,17 +5562,6 @@ class EngineService:
         # "Start-up stages and program compiles"); frozen from then on
         out["startup"] = tracing.startup_stats()
         out["hbm"] = self._hbm_rows()
-        # co-resident set (docs/perf.md "Co-resident sibling variants"):
-        # who is routable on this engine without an actuation, and what
-        # the shared base is saving — the launcher ledger's resident row
-        if self._residents or self._resident_variants_cap > 1:
-            out["residents"] = {
-                "cap": self._resident_variants_cap,
-                "attached": sorted(self._residents),
-                "variant_hbm_bytes": self.engine.variant_hbm_bytes(),
-                "variant_hbm_budget_bytes": self._variant_hbm_budget,
-                "saved_bytes": self.resident_ledger.bytes_saved(),
-            }
         return out
 
     def _hbm_rows(self) -> List[Dict[str, Any]]:
@@ -6232,7 +5608,6 @@ class EngineService:
         seed: "int | None" = None,
         ignore_eos: bool = False,
         logit_bias: "Dict[int, float] | None" = None,
-        variant: int = 0,
         trace_ctx: "tracing.SpanContext | None" = None,
         stop_watch: Optional[Any] = None,
     ) -> concurrent.futures.Future:
@@ -6241,9 +5616,7 @@ class EngineService:
         emitted: its first token, then what each drained decode chunk held
         for it (the streaming hook); keep it to an enqueue.
         `stop_watch(token) -> bool` is asked about every token and ends the
-        request at the next one when it says True. ``variant`` routes to a
-        co-resident sibling (resolve_request_model) — 0 is the base model.
-        ``trace_ctx`` is
+        request at the next one when it says True. ``trace_ctx`` is
         the client's ``traceparent`` (completions handlers): it forces a
         lifecycle trace even at --trace-requests 0 and parents it on the
         caller's span."""
@@ -6278,7 +5651,7 @@ class EngineService:
             (prompt, max_tokens, temperature, fut, on_tokens, top_p, stop_seqs,
              presence_penalty, frequency_penalty, want_top_logprobs,
              want_prompt_logprobs, seed, ignore_eos, logit_bias, now,
-             int(variant), trace, stop_watch)
+             trace, stop_watch)
         )
         self._new_work.set()
         ENGINE_QUEUE_DEPTH.labels(model=self.args.model).set(self.queue_depth())
@@ -6325,16 +5698,6 @@ class EngineService:
             # follower loop, deadlocking the gang's next collective)
             raise ValueError("sleep level must be 1 or 2")
         with self._admin_lock():
-            if self._residents:
-                # the slept state has no variant dimension: an offload
-                # would strand (L1) or leak (L2) the delta leaves.
-                # Detach is the delta-only "offload" — zero d2h, the
-                # content tiers already hold every delta chunk.
-                raise ValueError(
-                    "co-resident variants attached "
-                    f"({sorted(self._residents)}); detach them "
-                    "(DELETE /v1/residents) before sleeping"
-                )
             was_sleeping = self.sleeper.is_sleeping
             prev_level = self.sleeper.level
             parked_for_sleep = None
@@ -6966,73 +6329,13 @@ def build_app(service: EngineService) -> web.Application:
         )
         return web.json_response(info)
 
-    async def residents_get(request: web.Request) -> web.Response:
-        return web.json_response(service.residents_view())
-
-    async def residents_post(request: web.Request) -> web.Response:
-        """POST /v1/residents: attach a sibling variant as co-resident
-        (docs/engine.md "/v1/residents"). Admission rejection (cap /
-        HBM budget / unresolvable source) is a 409: the caller falls
-        back to the swap path."""
-        try:
-            body = await request.json()
-        except Exception:
-            raise web.HTTPBadRequest(text="invalid JSON body")
-        model = body.get("model")
-        if not isinstance(model, str) or not model:
-            raise web.HTTPBadRequest(
-                text="residents requires a 'model' string"
-            )
-        ckpt = body.get("checkpoint_dir") or ""
-        if not isinstance(ckpt, str):
-            raise web.HTTPBadRequest(text="checkpoint_dir must be a string")
-        try:
-            info = await _traced_call(
-                request, lambda: service.attach_resident(model, ckpt)
-            )
-        except ResidentRejected as e:
-            raise web.HTTPConflict(text=str(e))
-        except ValueError as e:
-            raise web.HTTPBadRequest(text=str(e))
-        return web.json_response(info)
-
-    async def residents_delete(request: web.Request) -> web.Response:
-        """DELETE /v1/residents: detach a co-resident variant. 409 while
-        the variant still has live or queued requests (drain first)."""
-        model = request.query.get("model", "")
-        ckpt = request.query.get("checkpoint_dir", "")
-        if not model and request.can_read_body:
-            try:
-                body = await request.json()
-            except Exception:
-                raise web.HTTPBadRequest(text="invalid JSON body")
-            model = body.get("model") or ""
-            ckpt = body.get("checkpoint_dir") or ""
-        if not isinstance(model, str) or not model:
-            raise web.HTTPBadRequest(
-                text="detach requires a 'model' (query or body)"
-            )
-        if not isinstance(ckpt, str):
-            raise web.HTTPBadRequest(text="checkpoint_dir must be a string")
-        try:
-            info = await _traced_call(
-                request, lambda: service.detach_resident(model, ckpt)
-            )
-        except ResidentRejected as e:
-            raise web.HTTPConflict(text=str(e))
-        except ValueError as e:
-            raise web.HTTPBadRequest(text=str(e))
-        return web.json_response(info)
-
     async def models(request: web.Request) -> web.Response:
-        # the base plus every attached co-resident: what a router may
-        # address in a completions body's "model" without an actuation
-        data = [{"id": service.args.model, "object": "model"}]
-        data += [
-            {"id": m, "object": "model", "coresident": True}
-            for m in sorted(service._residents)
-        ]
-        return web.json_response({"object": "list", "data": data})
+        return web.json_response(
+            {
+                "object": "list",
+                "data": [{"id": service.args.model, "object": "model"}],
+            }
+        )
 
     async def engine_stats(request: web.Request) -> web.Response:
         """JSON lifecycle stats (GET /v1/stats): the launcher's fleet
@@ -7257,7 +6560,6 @@ def build_app(service: EngineService) -> web.Application:
         seed=None,
         ignore_eos=False,
         logit_bias=None,
-        variant=0,
         trace_ctx=None,
         usage_chunk=None,
     ) -> web.StreamResponse:
@@ -7292,7 +6594,7 @@ def build_app(service: EngineService) -> web.Application:
             top_p=top_p, stop_seqs=stop_seqs,
             presence_penalty=presence, frequency_penalty=frequency,
             seed=seed, ignore_eos=ignore_eos, logit_bias=logit_bias,
-            variant=variant, trace_ctx=trace_ctx,
+            trace_ctx=trace_ctx,
         )
         afut = asyncio.ensure_future(asyncio.wrap_future(fut))
         resp = web.StreamResponse(
@@ -7478,7 +6780,7 @@ def build_app(service: EngineService) -> web.Application:
         n: int, tokens, max_tokens, temperature, top_p, stop_seqs,
         presence, frequency, stop_texts=(), want_alts=False,
         want_prompt_logprobs=False, seed=None, ignore_eos=False,
-        logit_bias=None, variant=0, trace_ctx=None,
+        logit_bias=None, trace_ctx=None,
     ):
         """n parallel submissions; abort every sibling if any fails or the
         client goes away (no orphan decode cycles). Prefix caching makes
@@ -7504,7 +6806,6 @@ def build_app(service: EngineService) -> web.Application:
                 else ((seed + i + 2**63) % 2**64) - 2**63,
                 ignore_eos=ignore_eos,
                 logit_bias=logit_bias,
-                variant=variant,
                 # the client's traceparent traces choice 0 (whose usage
                 # the response carries); siblings stay on the sampler
                 trace_ctx=trace_ctx if i == 0 else None,
@@ -7533,12 +6834,10 @@ def build_app(service: EngineService) -> web.Application:
         except ValueError as e:
             raise web.HTTPBadRequest(text=str(e))
         raw_prompt = body.get("prompt")
-        # per-request model routing (docs/engine.md "/v1/residents"):
-        # the body's "model" resolves to a co-resident variant handle;
-        # unknown names 400 with the live set, so a router never
-        # silently serves the wrong weights
+        # a body's "model" that is not the model being served is a 400,
+        # so a router never silently gets the wrong weights
         try:
-            variant = service.resolve_request_model(body.get("model"))
+            service.check_request_model(body.get("model"))
         except ValueError as e:
             raise web.HTTPBadRequest(text=str(e))
         resp_model = body.get("model") or service.args.model
@@ -7581,7 +6880,7 @@ def build_app(service: EngineService) -> web.Application:
                 request, tokens, max_tokens, temperature, top_p, stop_seqs,
                 stop_texts, presence, frequency, chunk, seed=seed,
                 ignore_eos=ignore_eos, logit_bias=logit_bias,
-                variant=variant, trace_ctx=trace_ctx,
+                trace_ctx=trace_ctx,
                 usage_chunk=usage_chunk,
             )
 
@@ -7590,7 +6889,7 @@ def build_app(service: EngineService) -> web.Application:
             presence, frequency, stop_texts, want_alts=logprobs_n > 0,
             want_prompt_logprobs=echo and bool(body.get("logprobs")),
             seed=seed, ignore_eos=ignore_eos, logit_bias=logit_bias,
-            variant=variant, trace_ctx=trace_ctx,
+            trace_ctx=trace_ctx,
         )
         req = reqs[0]
         ttft = (
@@ -7679,7 +6978,7 @@ def build_app(service: EngineService) -> web.Application:
         except ValueError as e:
             raise web.HTTPBadRequest(text=str(e))
         try:
-            variant = service.resolve_request_model(body.get("model"))
+            service.check_request_model(body.get("model"))
         except ValueError as e:
             raise web.HTTPBadRequest(text=str(e))
         resp_model = body.get("model") or service.args.model
@@ -7721,14 +7020,14 @@ def build_app(service: EngineService) -> web.Application:
                 request, tokens, max_tokens, temperature, top_p, stop_seqs,
                 stop_texts, presence, frequency, chunk, seed=seed,
                 ignore_eos=ignore_eos, logit_bias=logit_bias,
-                variant=variant, trace_ctx=trace_ctx,
+                trace_ctx=trace_ctx,
                 usage_chunk=usage_chunk,
             )
 
         reqs = await _gather_n(
             n, tokens, max_tokens, temperature, top_p, stop_seqs,
             presence, frequency, stop_texts, want_alts=top_n > 0, seed=seed,
-            ignore_eos=ignore_eos, logit_bias=logit_bias, variant=variant,
+            ignore_eos=ignore_eos, logit_bias=logit_bias,
             trace_ctx=trace_ctx,
         )
         from .tokenizer import truncate_at_text_stop
@@ -7995,9 +7294,6 @@ def build_app(service: EngineService) -> web.Application:
     app.router.add_post("/v1/prefetch", prefetch)
     app.router.add_get("/v1/prefetch", prefetch_status)
     app.router.add_delete("/v1/prefetch", prefetch_abort)
-    app.router.add_get("/v1/residents", residents_get)
-    app.router.add_post("/v1/residents", residents_post)
-    app.router.add_delete("/v1/residents", residents_delete)
     app.router.add_post("/v1/parked", parked_import)
     app.router.add_post("/v1/parked/release", parked_release)
     app.router.add_post("/v1/parked/abort", parked_abort)

@@ -475,18 +475,6 @@ class SleepManager:
         level = SleepLevel(level)
         if level == SleepLevel.AWAKE:
             raise ValueError("sleep level must be 1 or 2")
-        if getattr(getattr(self, "engine", None), "_variants", None):
-            # Co-resident variant deltas are not part of the state tree
-            # this manager stages: an L1 offload would silently strand
-            # them on device, an L2 discard would leak them. The
-            # delta-only "offload" IS detach (engine.detach_variant) —
-            # zero d2h, the content-addressed host tiers already hold
-            # every delta chunk (docs/perf.md "Co-resident sibling
-            # variants").
-            raise ValueError(
-                "engine has attached co-resident variants; detach them "
-                "before sleeping (detach is the delta-only offload)"
-            )
         if release and jax.process_count() > 1:
             raise ValueError(
                 "device release is not supported for multi-host gangs: "
@@ -1822,8 +1810,4 @@ def attach_sleep(
         on_transfer=on_transfer,
         peek_state=peek_state,
     )
-    # back-reference for the co-resident precondition check in sleep():
-    # the state closures above stage params+kv only, so attached variant
-    # deltas must be detached before any offload
-    mgr.engine = engine
     return mgr

@@ -85,16 +85,6 @@ LAUNCHER_FLEET_ACTUATIONS_PER_HOUR = Gauge(
     "Summed per-instance actuation rates (swap+sleep+wake per uptime "
     "hour)",
 )
-LAUNCHER_FLEET_RESIDENT_VARIANTS = Gauge(
-    "fma_launcher_fleet_resident_variants",
-    "Device-resident model variants summed over reporting instances "
-    "(base included per instance)",
-)
-LAUNCHER_FLEET_CORESIDENT_SAVED_BYTES = Gauge(
-    "fma_launcher_fleet_coresident_saved_bytes",
-    "HBM bytes saved fleet-wide by co-resident variants sharing their "
-    "base's device tensors (vs one full copy per variant)",
-)
 
 STATUS_STOPPED = "stopped"
 STATUS_RUNNING = "running"
@@ -145,22 +135,6 @@ class PrefetchFailed(Exception):
         self.detail = detail
 
 
-class ResidentsFailed(Exception):
-    """The engine child rejected (or never answered) a resident-set verb.
-    Status 409 carries the engine's explicit admission rejection (cap /
-    HBM budget / detach-while-live) — the caller's cue to fall back to
-    the swap path."""
-
-    def __init__(self, instance_id: str, status: int, detail: str) -> None:
-        super().__init__(
-            f"residents verb on instance {instance_id} failed "
-            f"({status}): {detail}"
-        )
-        self.instance_id = instance_id
-        self.status = status
-        self.detail = detail
-
-
 class StatsFailed(Exception):
     """The engine child never answered a stats poll (fleet rollup marks
     the instance unreachable instead of failing the whole read)."""
@@ -177,7 +151,7 @@ class StatsFailed(Exception):
 class MigrateFailed(Exception):
     """A live-request migration step failed (or was refused). Status 409
     carries the engine's explicit precondition refusal — identity
-    mismatch, co-resident variants attached, spent fence, no capacity —
+    mismatch, spent fence, no capacity —
     after which nothing was displaced. Any other status means recovery
     already ran on the engines (source resumed locally or aborted the
     fenced bundle); the streams survived, the handoff didn't."""
@@ -284,13 +258,6 @@ class ChipLedger:
         #: (docs/perf.md "Compressed actuation") — the byte-cost signal a
         #: scheduler weighs against the models' numerics requirements.
         self._quant: Dict[str, str] = {}
-        #: instance_id -> resident-set summary from the holder's last
-        #: /v1/residents answer (docs/launcher.md "The resident-set
-        #: ledger"): which sibling variants are device-resident alongside
-        #: the base, the variant HBM budget/usage, and the shared-base
-        #: dedup savings — the zero-actuation routing options a
-        #: multi-model scheduler weighs BEFORE pricing any swap.
-        self._residents: Dict[str, Dict[str, Any]] = {}
 
     def overlapping(
         self, chip_ids: Optional[List[str]], exclude: Optional[str] = None
@@ -316,7 +283,6 @@ class ChipLedger:
         self._prefetched.pop(instance_id, None)
         self._pools.pop(instance_id, None)
         self._quant.pop(instance_id, None)
-        self._residents.pop(instance_id, None)
 
     def set_model(self, instance_id: str, model: str) -> None:
         """Record which model a holder serves (updated on hot-swap). A
@@ -357,33 +323,6 @@ class ChipLedger:
         / unknown answers leave the last known value)."""
         if quant and instance_id in self._held:
             self._quant[instance_id] = quant
-
-    def set_residents(
-        self, instance_id: str, view: Optional[Dict[str, Any]]
-    ) -> None:
-        """Record a holder's resident set from an engine /v1/residents
-        answer (the residents_view block every attach/detach returns).
-        Compacted to what a scheduler reads: membership, budget/usage,
-        and the shared-base savings the co-residency is buying."""
-        if view is None or instance_id not in self._held:
-            return
-        ledger = view.get("ledger") or {}
-        self._residents[instance_id] = {
-            "base": view.get("base"),
-            "resident_variants": int(view.get("resident_variants", 1)),
-            "resident_variants_cap": int(
-                view.get("resident_variants_cap", 1)
-            ),
-            "residents": sorted(view.get("residents") or {}),
-            "variant_hbm_bytes": int(view.get("variant_hbm_bytes", 0)),
-            "variant_hbm_budget_bytes": int(
-                view.get("variant_hbm_budget_bytes", 0)
-            ),
-            "bytes_saved": int(ledger.get("bytes_saved", 0)),
-        }
-
-    def residents(self) -> Dict[str, Dict[str, Any]]:
-        return dict(self._residents)
 
     def quants(self) -> Dict[str, str]:
         return dict(self._quant)
@@ -1073,66 +1012,6 @@ class EngineProcessManager:
             self.ledger.set_prefetched(instance_id, None)
         return {"instance_id": instance_id, "prefetch": body}
 
-    def attach_instance_resident(
-        self,
-        instance_id: str,
-        model: str,
-        checkpoint_dir: str = "",
-        timeout: float = 120,
-    ) -> Dict[str, Any]:
-        """Co-residency attach verb: have a live instance upload `model`'s
-        delta leaves next to its base (engine POST /v1/residents) and
-        route per-request from then on — the zero-swap alternative to
-        swap_instance for sibling-variant traffic. The engine's explicit
-        admission rejection (cap / HBM budget / cold source) surfaces as
-        a 409 ResidentsFailed: the caller falls back to the swap path."""
-        with tracing.span(
-            "launcher.attach_resident", instance=instance_id, model=model
-        ):
-            body = self._engine_request(
-                instance_id, "POST", "/v1/residents",
-                {"model": model, "checkpoint_dir": checkpoint_dir},
-                timeout, ResidentsFailed,
-            )
-        self.ledger.set_residents(instance_id, body)
-        logger.info(
-            "attached resident on instance %s: %s (handle=%s, "
-            "wire_bytes=%s)",
-            instance_id, body.get("model", model), body.get("handle"),
-            body.get("wire_bytes"),
-        )
-        return {"instance_id": instance_id, "residents": body}
-
-    def detach_instance_resident(
-        self,
-        instance_id: str,
-        model: str,
-        checkpoint_dir: str = "",
-        timeout: float = 60,
-    ) -> Dict[str, Any]:
-        """Co-residency detach verb (engine DELETE /v1/residents): drop a
-        variant's device delta — zero wire bytes; the content tiers keep
-        every chunk, so re-attach stays delta-only."""
-        body = self._engine_request(
-            instance_id, "DELETE", "/v1/residents",
-            {"model": model, "checkpoint_dir": checkpoint_dir},
-            timeout, ResidentsFailed,
-        )
-        self.ledger.set_residents(instance_id, body)
-        return {"instance_id": instance_id, "residents": body}
-
-    def get_instance_residents(
-        self, instance_id: str, timeout: float = 10
-    ) -> Dict[str, Any]:
-        """Resident-set passthrough (engine GET /v1/residents); refreshes
-        the ledger's resident-set block as a side effect."""
-        body = self._engine_request(
-            instance_id, "GET", "/v1/residents", None, timeout,
-            ResidentsFailed,
-        )
-        self.ledger.set_residents(instance_id, body)
-        return {"instance_id": instance_id, "residents": body}
-
     # -- live request migration / node drain ---------------------------------
 
     def _parsed_opts(self, instance_id: str):
@@ -1470,8 +1349,6 @@ class EngineProcessManager:
             "requests_out": 0, "requests_in": 0,
             "bytes_out": 0, "bytes_in": 0,
         }
-        resident_variants = 0
-        variant_hbm_bytes = coresident_saved_bytes = 0
         slo_exemplars: List[Dict[str, Any]] = []
         reporting = 0
         for iid, row in per_instance.items():
@@ -1507,10 +1384,6 @@ class EngineProcessManager:
             mg = row.get("migration") or {}
             for k in mig:
                 mig[k] += int(mg.get(k, 0))
-            res = row.get("residents") or {}
-            resident_variants += 1 + len(res.get("attached") or [])
-            variant_hbm_bytes += int(res.get("variant_hbm_bytes", 0))
-            coresident_saved_bytes += int(res.get("saved_bytes", 0))
         judged = met + violated
         attainment = round(met / judged, 6) if judged else None
         fleet = {
@@ -1539,14 +1412,6 @@ class EngineProcessManager:
             # live-migration rollup (engine /v1/stats migration):
             # fleet-wide "did any handoff lose state" in one read
             "migration": mig,
-            # co-residency rollup (engine /v1/stats residents): how many
-            # variants are device-resident fleet-wide, their delta HBM
-            # footprint, and what sharing the base tensors saved
-            "residents": {
-                "resident_variants": resident_variants,
-                "variant_hbm_bytes": variant_hbm_bytes,
-                "coresident_saved_bytes": coresident_saved_bytes,
-            },
             # SLO-violation exemplars lifted from every reporting child
             # (engine /v1/stats slo_exemplars), each tagged with the
             # instance it came from so an operator can pull the trace
@@ -1565,8 +1430,6 @@ class EngineProcessManager:
         )
         LAUNCHER_FLEET_GOODPUT_TOKENS.set(goodput)
         LAUNCHER_FLEET_ACTUATIONS_PER_HOUR.set(actuations_per_hour)
-        LAUNCHER_FLEET_RESIDENT_VARIANTS.set(resident_variants)
-        LAUNCHER_FLEET_CORESIDENT_SAVED_BYTES.set(coresident_saved_bytes)
         with self._fleet_lock:
             self._fleet_cache = (time.monotonic(), fleet)
         return fleet
@@ -1608,11 +1471,6 @@ class EngineProcessManager:
                 # per-holder transfer mode of the last swap ("int8"/"fp8"
                 # when the holder actuates compressed, docs/perf.md)
                 "quant": self.ledger.quants(),
-                # per-holder co-resident variant sets (docs/launcher.md
-                # "The resident-set ledger"): the routes a scheduler can
-                # take WITHOUT any actuation, next to what each costs in
-                # variant HBM and what the shared base saves
-                "residents": self.ledger.residents(),
             },
         }
         if include_fleet:

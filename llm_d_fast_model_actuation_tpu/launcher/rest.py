@@ -27,7 +27,6 @@ from .manager import DrainFailed
 from .manager import EngineProcessManager
 from .manager import MigrateFailed
 from .manager import PrefetchFailed
-from .manager import ResidentsFailed
 from .manager import SwapFailed
 
 logger = logging.getLogger(__name__)
@@ -109,9 +108,6 @@ def build_app(manager: EngineProcessManager) -> web.Application:
                     "abort_prefetch": "DELETE /v2/vllm/instances/{instance_id}/prefetch",
                     "migrate_instance": "POST /v2/vllm/instances/{instance_id}/migrate",
                     "drain_instance": "POST /v2/vllm/instances/{instance_id}/drain",
-                    "attach_resident": "POST /v2/vllm/instances/{instance_id}/residents",
-                    "residents_status": "GET /v2/vllm/instances/{instance_id}/residents",
-                    "detach_resident": "DELETE /v2/vllm/instances/{instance_id}/residents",
                     "watch_instances": "GET /v2/vllm/instances/watch",
                     "faults": "GET/POST/DELETE /v2/vllm/faults",
                     "traces": "GET /v2/vllm/traces",
@@ -360,7 +356,7 @@ def build_app(manager: EngineProcessManager) -> web.Application:
 
     def _map_migrate_error(e):
         # the engines' 409 is an explicit precondition refusal (identity
-        # mismatch, residents attached, spent fence, no capacity / drain
+        # mismatch, spent fence, no capacity / drain
         # not converging) with nothing displaced — preserved verbatim so
         # an orchestrator can react to exactly that signal; 404 is a bad
         # destination id; 504 timed out (recovery already ran on the
@@ -422,82 +418,6 @@ def build_app(manager: EngineProcessManager) -> web.Application:
             raise web.HTTPNotFound(text=f"Instance {instance_id} not found")
         except (DrainFailed, MigrateFailed) as e:
             raise _map_migrate_error(e)
-        return web.json_response(result)
-
-    def _map_residents_error(e: ResidentsFailed):
-        # the engine's 409 is the explicit admission rejection (cap / HBM
-        # budget / detach-while-live) — preserved verbatim so a scheduler
-        # can fall back to the swap path on exactly that signal
-        if e.status == 409:
-            return web.HTTPConflict(text=str(e))
-        if 400 <= e.status < 500:
-            return web.HTTPBadRequest(text=str(e))
-        if e.status == 504:
-            return web.HTTPGatewayTimeout(text=str(e))
-        return web.HTTPBadGateway(text=str(e))
-
-    async def _residents_write(
-        request: web.Request, verb
-    ) -> web.Response:
-        """Shared body/validation for the attach/detach resident verbs
-        (engine POST/DELETE /v1/residents; docs/launcher.md)."""
-        instance_id = request.match_info["instance_id"]
-        try:
-            body = await request.json()
-        except Exception:
-            raise web.HTTPBadRequest(text="invalid JSON body")
-        model = body.get("model")
-        if not isinstance(model, str) or not model:
-            raise web.HTTPUnprocessableEntity(
-                text="residents requires a 'model' string"
-            )
-        checkpoint_dir = body.get("checkpoint_dir") or ""
-        if not isinstance(checkpoint_dir, str):
-            raise web.HTTPUnprocessableEntity(
-                text="checkpoint_dir must be a string"
-            )
-        try:
-            result = await _traced_call(
-                request,
-                lambda: verb(
-                    instance_id, model, checkpoint_dir=checkpoint_dir
-                ),
-            )
-        except KeyError:
-            raise web.HTTPNotFound(text=f"Instance {instance_id} not found")
-        except ResidentsFailed as e:
-            raise _map_residents_error(e)
-        return web.json_response(result)
-
-    async def attach_instance_resident(
-        request: web.Request,
-    ) -> web.Response:
-        """Co-residency attach verb: device-resident sibling variant next
-        to the instance's base (engine POST /v1/residents) — route
-        per-request afterwards, zero actuation per request."""
-        return await _residents_write(
-            request, manager.attach_instance_resident
-        )
-
-    async def detach_instance_resident(
-        request: web.Request,
-    ) -> web.Response:
-        return await _residents_write(
-            request, manager.detach_instance_resident
-        )
-
-    async def get_instance_residents(
-        request: web.Request,
-    ) -> web.Response:
-        instance_id = request.match_info["instance_id"]
-        try:
-            result = await asyncio.get_running_loop().run_in_executor(
-                None, lambda: manager.get_instance_residents(instance_id)
-            )
-        except KeyError:
-            raise web.HTTPNotFound(text=f"Instance {instance_id} not found")
-        except ResidentsFailed as e:
-            raise _map_residents_error(e)
         return web.json_response(result)
 
     async def get_log(request: web.Request) -> web.Response:
@@ -638,18 +558,6 @@ def build_app(manager: EngineProcessManager) -> web.Application:
     )
     app.router.add_post(
         "/v2/vllm/instances/{instance_id}/drain", drain_instance
-    )
-    app.router.add_post(
-        "/v2/vllm/instances/{instance_id}/residents",
-        attach_instance_resident,
-    )
-    app.router.add_get(
-        "/v2/vllm/instances/{instance_id}/residents",
-        get_instance_residents,
-    )
-    app.router.add_delete(
-        "/v2/vllm/instances/{instance_id}/residents",
-        detach_instance_resident,
     )
 
     async def on_shutdown(app: web.Application) -> None:

@@ -13,8 +13,7 @@ This module holds the two pieces every engine keeps:
     exponentially-decayed GiB/s estimates (``swap.d2h``, ``swap.h2d``,
     ``swap.total`` — the whole-verb effective rate pool-hit pricing
     prefers — ``wake.h2d``, ``sleep.d2h``, ``coldload.read``,
-    ``coldload.h2d``, ``coresident.h2d`` (the delta-only upload a
-    variant attach streams), ``migrate.export`` / ``migrate.import``
+    ``coldload.h2d``, ``migrate.export`` / ``migrate.import``
     (a live-migration parked bundle's wire serialization and its
     destination-side page-in), and ``quant.dequant``, the non-hidden
     on-device expansion tail of compressed transfers),
@@ -188,14 +187,12 @@ class ActuationRecord:
 
     seq: int
     t_wall: float  #: unix seconds at record time (the ring is ordered)
-    kind: str  #: swap | sleep | wake | coldload | prefetch | attach | detach | migrate
+    kind: str  #: swap | sleep | wake | coldload | prefetch | migrate
     model: str
     trigger: str  #: client | restart | escalation | startup
     #: where the moved state lived / went: pool | prefetched | host |
-    #: disk | cold | resident | coresident (a sibling variant sharing
-    #: the live base's device tensors) | discard (an L2 sleep drops the
-    #: host copy) | "" (unknown, e.g. a failed swap priced before any
-    #: tier resolved)
+    #: disk | cold | resident | discard (an L2 sleep drops the host copy)
+    #: | "" (unknown, e.g. a failed swap priced before any tier resolved)
     tier: str
     outcome: str  #: committed | rolled_back | failed
     actual_bytes: int = 0

@@ -91,6 +91,22 @@ def is_the_benchmarks(path) -> bool:
     return os.path.join(REPO_ROOT, "tests", "fmabench") in map(str, path.parents)
 
 
+@pytest.fixture
+def numpy_host_staging(monkeypatch):
+    """Every ``SleepManager`` built while this holds stages a slept state as
+    numpy arrays, the staging of a backend without a ``pinned_host`` memory
+    space. This jax's CPU backend has one, as the TPU has, and a slept
+    model's ``pinned_host`` leaves never enter the ``ChunkStore``
+    (``model_pool.intern_tree`` takes numpy leaves alone): a test that asserts
+    interning of a slept model (dedup across pooled siblings, the disk-tier
+    rebuild) names this fixture before it builds its service. The program's
+    probe is not changed; what the backend's own staging does is pinned by
+    the ``own_staging`` tests of ``tests/test_delta_swap.py``."""
+    from llm_d_fast_model_actuation_tpu.engine import sleep
+
+    monkeypatch.setattr(sleep, "_platform_supports_host_memory", lambda: False)
+
+
 @pytest.fixture(autouse=True)
 def _tracing_switch_as_the_test_found_it():
     """``tracing.enable()`` / ``disable()`` flip a switch of the process: a

@@ -10,9 +10,11 @@ import numpy as np
 import pytest
 
 from llm_d_fast_model_actuation_tpu.engine.chunk_store import (
+    QUANT_DIGEST_PREFIX,
     ChunkStore,
     aligned_digests,
     digest_content_hash,
+    digest_spillable,
     digest_tree,
     leaf_digest,
     qualify_digest,
@@ -219,6 +221,45 @@ def test_pool_intern_two_variants_share_base_evict_one_bit_exact():
     ), "surviving variant no longer bit-exact"
 
 
+@pytest.mark.parametrize("staging", ["numpy", "pinned_host"])
+def test_intern_tree_takes_numpy_leaves_and_leaves_pinned_host_alone(staging):
+    """What of a slept model the content-addressed tiers see hangs on how it
+    was staged: numpy leaves are replaced by the store's canonical chunks,
+    ``pinned_host`` jax arrays (the staging of a backend with that memory
+    space: the TPU, and this jax's CPU) come back as they went in, with
+    nothing held and nothing interned."""
+    import jax
+
+    from llm_d_fast_model_actuation_tpu.engine import sleep
+
+    tree = {
+        "base": np.arange(1000, dtype=np.float32),
+        "head": np.ones(10, dtype=np.float32),
+    }
+    digests = digest_tree(tree)
+    nbytes = sum(a.nbytes for a in tree.values())
+    if staging == "pinned_host":
+        if not sleep._platform_supports_host_memory():
+            pytest.skip("this backend has no pinned_host memory space")
+        tree = jax.device_put(
+            tree,
+            jax.sharding.SingleDeviceSharding(
+                jax.devices()[0], memory_kind="pinned_host"
+            ),
+        )
+    cs = ChunkStore()
+    pool = HostModelPool(budget_bytes=1 << 20, chunks=cs)
+    out, held, nominal = pool.intern_tree(tree, digests, prefix="")
+    if staging == "numpy":
+        assert sorted(held) == sorted(digests.values())
+        assert nominal == cs.host_bytes == nbytes
+        assert all(out[k] is cs.fetch(digests[k]) for k in tree)
+    else:
+        assert (held, nominal) == ([], 0)
+        assert all(out[k] is tree[k] for k in tree)
+        assert cs.host_bytes == 0 and digests["base"] not in cs
+
+
 def test_pool_manifest_reconstruction_and_stale_miss(tmp_path):
     """An evicted entry leaves a manifest; take_staged rebuilds the whole
     tree from the tiers, and ANY unresolvable chunk is a miss for the
@@ -298,3 +339,38 @@ def test_pool_bytes_used_running_counter():
     assert pool.bytes_used == 40
     pool.drain()
     assert pool.bytes_used == 0
+
+
+def test_quant_digest_chunks_spill_and_reload_verified(tmp_path):
+    """Satellite regression: transfer-quantized (q:) chunks used to be
+    pinned host-only (their digest is not recomputable from the blob);
+    now they spill with a header-carried content hash and reload
+    verified — corruption is a miss, never silently wrong bytes."""
+    payload = np.arange(512, dtype=np.int8)
+    digest = QUANT_DIGEST_PREFIX + "deadbeef" * 8
+    assert digest_spillable(digest)
+
+    cs = ChunkStore(disk_dir=str(tmp_path), disk_budget_bytes=1 << 20)
+    cs.intern(digest, payload)
+    assert cs.release(digest) == payload.nbytes  # last ref -> spill
+    assert cs.peek_tier(digest) == "disk"
+
+    got = cs.fetch(digest)
+    assert got is not None and np.array_equal(got, payload)
+    assert cs.disk_hits == 1 and cs.verify_failures == 0
+
+    # a fresh store adopting the same disk dir verifies too (restart)
+    cs2 = ChunkStore(disk_dir=str(tmp_path), disk_budget_bytes=1 << 20)
+    got2 = cs2.fetch(digest)
+    assert got2 is not None and np.array_equal(got2, payload)
+
+    # flip payload bytes on disk: the content verify must turn the
+    # reload into a miss and drop the blob
+    (path,) = glob.glob(os.path.join(str(tmp_path), "*"))
+    with open(path, "r+b") as f:
+        f.seek(-8, os.SEEK_END)
+        f.write(b"\xff" * 8)
+    cs3 = ChunkStore(disk_dir=str(tmp_path), disk_budget_bytes=1 << 20)
+    assert cs3.fetch(digest) is None
+    assert cs3.verify_failures == 1
+    assert not os.path.exists(path)

@@ -10,6 +10,7 @@ import jax
 import numpy as np
 import pytest
 
+from llm_d_fast_model_actuation_tpu.engine import sleep
 from llm_d_fast_model_actuation_tpu.engine.chunk_store import digest_tree
 from llm_d_fast_model_actuation_tpu.engine.sleep import (
     SleepManager,
@@ -214,7 +215,9 @@ def _gen(svc):
     return svc.submit([1, 2, 3], 4, 0.0).result(timeout=120).out_tokens
 
 
-def test_service_sibling_variant_swap_moves_only_the_delta(variant_ckpts):
+def test_service_sibling_variant_swap_moves_only_the_delta(
+    variant_ckpts, numpy_host_staging
+):
     """POST /v1/swap between two fine-tune variants: the shared tensors
     are content-matched away (< 50% of full-swap bytes move), generations
     stay bit-exact per variant, and the pooled pair dedupes in host RAM."""
@@ -309,7 +312,9 @@ def test_service_trace_has_delta_span(variant_ckpts):
         svc.shutdown()
 
 
-def test_service_disk_tier_rebuild_after_eviction(variant_ckpts, tmp_path):
+def test_service_disk_tier_rebuild_after_eviction(
+    variant_ckpts, tmp_path, numpy_host_staging
+):
     """An evicted model whose chunks spilled to the disk tier swaps back
     bit-exact with ZERO checkpoint re-reads — the checkpoint directory is
     deleted out from under it to prove the bytes came from the tier."""
@@ -367,7 +372,7 @@ def test_chip_ledger_tracks_pool_summaries():
 # -- sharded meshes: mesh-qualified digests + delta swap ----------------------
 
 
-def test_service_sibling_delta_swap_tp2_mesh(variant_ckpts):
+def test_service_sibling_delta_swap_tp2_mesh(variant_ckpts, numpy_host_staging):
     """The mesh parity bar (ROADMAP item 4): a sibling pool-hit swap on
     a single-process tp=2 CPU mesh content-matches the shared tensors
     away — < 50% of full-swap bytes move, generations stay bit-exact on
@@ -449,7 +454,9 @@ def test_service_delta_swap_rollback_tp2_mesh(variant_ckpts):
         svc.shutdown()
 
 
-def test_service_disk_tier_rebuild_tp2_mesh(variant_ckpts, tmp_path):
+def test_service_disk_tier_rebuild_tp2_mesh(
+    variant_ckpts, tmp_path, numpy_host_staging
+):
     """Mesh restart-shape: an evicted tp=2 model rebuilds bit-exact from
     the disk tier under its shard-qualified digests, checkpoint deleted
     (content re-verification covers the qualified digest's content
@@ -473,6 +480,124 @@ def test_service_disk_tier_rebuild_tp2_mesh(variant_ckpts, tmp_path):
         out = svc.swap("tiny", checkpoint_dir=ckpt_copy)
         assert out["swapped"] and out["tier"] == "disk"
         assert _gen(svc) == gold, "tp=2 disk-tier rebuild not bit-exact"
+    finally:
+        svc.shutdown()
+
+
+# -- the backend's own staging ------------------------------------------------
+#
+# Where the backend has a ``pinned_host`` memory space (the TPU, and this
+# jax's CPU) a slept model's host state is jax arrays in that space, which
+# ``model_pool.intern_tree`` leaves alone: the delta swap works from the
+# digests all the same, but the ChunkStore, its dedup and its disk tier see
+# nothing of a model that was served and slept. The tests above that assert
+# interning name ``numpy_host_staging``; these pin what the chip runs.
+
+
+def _own_staging_is_pinned_host():
+    if not sleep._platform_supports_host_memory():
+        pytest.skip(
+            "this backend stages a slept model in numpy: what that does is "
+            "what the numpy_host_staging tests assert"
+        )
+
+
+@pytest.mark.parametrize("staging", ["numpy_host_staging", "own_staging"])
+def test_host_staging_follows_the_probe_unless_the_fixture_holds(
+    staging, request
+):
+    """The fixture of tests/conftest.py decides the staging of every
+    manager built after it; without it a manager stages as the backend's
+    probe says."""
+    if staging == "numpy_host_staging":
+        request.getfixturevalue(staging)
+        want_memory_kind = False
+    else:
+        want_memory_kind = sleep._platform_supports_host_memory()
+    mgr, _ = _mgr(_variant_params(0, perturb=False), kv_seed=1)
+    assert mgr._use_memory_kind is want_memory_kind
+    mgr.sleep(1)
+    for leaf in jax.tree.leaves(mgr._host_state):
+        if want_memory_kind:
+            assert isinstance(leaf, jax.Array)
+            assert leaf.sharding.memory_kind == "pinned_host"
+        else:
+            assert isinstance(leaf, np.ndarray)
+
+
+@pytest.mark.parametrize(
+    "extra", ["", "--tensor-parallel-size 2"], ids=["single", "tp2_mesh"]
+)
+def test_service_sibling_swap_on_own_staging(variant_ckpts, extra):
+    """A -> B -> A between siblings is a delta swap whatever the staging
+    (the digests ride the runtimes, not the store): under half the bytes
+    move and both generate bit-exact. But nothing of either slept model
+    enters the ChunkStore: no dedup, and each pool entry is charged its
+    whole size."""
+    _own_staging_is_pinned_host()
+    da, db, shared = variant_ckpts
+    svc = _service(da, extra=extra)
+    try:
+        gold_a = _gen(svc)
+        out = svc.swap("tiny", checkpoint_dir=db)
+        assert out["tier"] == "cold" and out["bytes_deduped"] == 0
+        gold_b = _gen(svc)
+        assert gold_b != gold_a
+
+        slept = svc.model_pool.peek(f"tiny@{da}").runtime.sleeper
+        assert slept._use_memory_kind
+        assert all(
+            leaf.sharding.memory_kind == "pinned_host"
+            for leaf in jax.tree.leaves(slept._host_state)
+        )
+
+        out = svc.swap("tiny", checkpoint_dir=da)
+        assert out["pool_hit"] and out["tier"] == "pool"
+        assert out["bytes_deduped"] >= 2 * shared > 0
+        assert out["bytes_moved"] < 0.5 * (out["bytes_out"] + out["bytes_in"])
+        assert _gen(svc) == gold_a, "delta swap changed the numerics"
+        out = svc.swap("tiny", checkpoint_dir=db)
+        assert out["pool_hit"] and out["bytes_moved"] < 0.5 * (
+            out["bytes_out"] + out["bytes_in"]
+        )
+        assert _gen(svc) == gold_b
+
+        svc.swap("tiny-gemma")  # parks B beside A
+        pool = svc.model_pool.describe()
+        nb = {e["model_id"]: e for e in pool["entries"]}
+        assert set(nb) == {f"tiny@{da}", f"tiny@{db}"}
+        assert all(e["resident_bytes"] == e["nbytes"] for e in nb.values())
+        assert pool["bytes_used"] == sum(e["nbytes"] for e in nb.values())
+        assert pool["chunks"]["host_chunks"] == 0
+        assert pool["chunks"]["dedup_saved_bytes"] == 0
+    finally:
+        svc.shutdown()
+
+
+def test_service_eviction_on_own_staging_leaves_no_manifest(
+    variant_ckpts, tmp_path
+):
+    """An evicted model that was slept in ``pinned_host`` spills nothing and
+    leaves no manifest (every chunk of it would be a miss): the swap back is
+    priced and served as a cold load, never from the disk tier."""
+    _own_staging_is_pinned_host()
+    da, db, _ = variant_ckpts
+    disk = str(tmp_path / "pool-tier")
+    svc = _service(da, extra=f"--pool-disk-dir {disk} --pool-disk-mib 64")
+    try:
+        gold = _gen(svc)
+        svc.swap("tiny", checkpoint_dir=db)  # parks A in the pool
+        svc._free_pooled(svc.model_pool.drain(), "test eviction")
+        assert svc.model_pool.staged_keys() == []
+        assert not os.path.isdir(disk) or not os.listdir(disk)
+
+        assert svc.price_swap("tiny", da)["tier"] == "cold"
+        out = svc.swap("tiny", checkpoint_dir=da)
+        assert out["swapped"] and out["tier"] == "cold"
+        assert not out["pool_hit"] and out["bytes_deduped"] == 0
+        pool = svc.model_pool.describe()
+        assert pool["staged_hits"] == 0 and pool["chunks"]["disk_hits"] == 0
+        assert _gen(svc) == gold
     finally:
         svc.shutdown()
 

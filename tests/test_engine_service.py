@@ -75,6 +75,37 @@ def test_completion_roundtrip(service):
     run_async(_client(service, scenario))
 
 
+@pytest.mark.parametrize(
+    "path,body",
+    [
+        ("/v1/completions", {"prompt": [1, 2, 3]}),
+        ("/v1/chat/completions", {"messages": [{"role": "user", "content": "hi"}]}),
+    ],
+    ids=["completions", "chat"],
+)
+def test_request_model_names_the_served_model_or_is_a_400(service, path, body):
+    """A body's ``model`` is the served model (by name, or as the pool keys
+    it, ``model@checkpoint_dir``) or left out; another name is a 400 that
+    says what is served, never a silent answer from the wrong weights."""
+
+    async def scenario(client):
+        for model in (None, "tiny"):
+            named = {"model": model} if model else {}
+            r = await client.post(path, json={**body, **named, "max_tokens": 2})
+            assert r.status == 200, await r.text()
+            assert (await r.json())["model"] == "tiny"
+        r = await client.post(path, json={**body, "model": "tiny-gemma", "max_tokens": 2})
+        assert r.status == 400
+        text = await r.text()
+        assert "tiny-gemma" in text and "serving 'tiny'" in text
+
+    run_async(_client(service, scenario))
+    service.args.checkpoint_dir = "/ckpts/a"
+    service.check_request_model("tiny@/ckpts/a")
+    with pytest.raises(ValueError, match="serving 'tiny@/ckpts/a'"):
+        service.check_request_model("tiny@/ckpts/b")
+
+
 def test_level2_wake_aborts_inflight(service):
     # slow each engine step down so the generation is reliably in flight
     orig_step = service.engine.step

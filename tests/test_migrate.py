@@ -16,8 +16,6 @@ The contract under test:
     the abort-after-double-fault path can degrade further;
   * identity is proved, not assumed: a sibling with different weights
     (or a tampered KV chunk) is refused before anything is displaced;
-  * co-resident variants pin the detach-first contract: migration AND
-    swap refuse while residents are attached;
   * the launcher verbs (POST /v2/vllm/instances/{id}/migrate, /drain)
     drive export -> import -> release with the engine's recovery
     discipline (one fenced blind retry on a 5xx import; abort on
@@ -346,36 +344,6 @@ def test_tampered_kv_chunk_refused(pair):
     assert ab["outcome"] == "resumed_local"
     assert f.result(timeout=120).out_tokens == gold
     _balance(src)
-
-
-# ------------------------------------------------ detach-first contract
-
-
-def test_residents_pin_detach_first_contract(ckpts):
-    """With co-resident variants attached, migration (both directions)
-    and swap all refuse with the same detach-first instruction."""
-    da, db = ckpts
-    svc = _service(
-        da,
-        extra="--packed-serving on --variant-hbm-mib 16 "
-        "--resident-variants 2",
-    )
-    try:
-        svc.swap("tiny", checkpoint_dir=db)  # pool the sibling
-        svc.swap("tiny", checkpoint_dir=da)
-        svc.attach_resident("tiny", checkpoint_dir=db)
-        with pytest.raises(
-            MigrationRejected, match="before migrating the base"
-        ):
-            svc.export_parked("tiny")
-        with pytest.raises(MigrationRejected, match="before importing"):
-            svc.import_parked(
-                {"fence": {"token": "mig-x"}, "identity": {}}
-            )
-        with pytest.raises(ValueError, match="before swapping the base"):
-            svc.swap("tiny", checkpoint_dir=db)
-    finally:
-        svc.shutdown()
 
 
 # ------------------------------------------------ launcher verbs

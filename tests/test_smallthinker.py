@@ -450,9 +450,6 @@ def _refusals():
     def park():
         InferenceEngine(_engine_cfg(), seed=0).park_requests()
 
-    def attach():
-        InferenceEngine(_engine_cfg(), seed=0).attach_variant({})
-
     def mixed():
         cfg = _model()
         llama.mixed_step(None, cfg, jnp.zeros((8,), jnp.int32), None, None, None, None)
@@ -482,7 +479,6 @@ def _refusals():
         "speculative_ngram": engine(speculative_ngram=4),
         "zero_drain_park": park,
         "zero_drain_flag": zero_drain,
-        "co_resident_attach": attach,
         "mixed_step_program": mixed,
     }
 

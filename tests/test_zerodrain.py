@@ -125,7 +125,7 @@ def test_park_wire_resume_round_trip_keeps_the_pool_bytes():
     assert np.abs(bundle.k_host.astype(np.float32)).sum() > 0
 
     doc = parked.encode_wire(bundle, identity={}, chunk_bytes=1)  # 1 page/chunk
-    assert doc["version"] == parked.WIRE_VERSION == 2
+    assert doc["version"] == parked.WIRE_VERSION == 3
     assert doc["kv"]["shape"] == list(bundle.k_host.shape)
     got, _ = parked.decode_wire(json.loads(json.dumps(doc)), Request)
     np.testing.assert_array_equal(got.k_host, bundle.k_host)
