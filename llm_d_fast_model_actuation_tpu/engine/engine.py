@@ -123,6 +123,44 @@ class EngineConfig:
     #: one decode row-block per slot plus one prefill block), rounded up
     #: to the RAGGED_BLOCK alignment).
     token_budget: int = 0
+    #: Self-speculative decoding with the model's OWN multi-token-prediction
+    #: module (models/exaone_moe.py), inside the decode chunk and for every
+    #: live slot: a step verifies a slot's last token and the module's draft
+    #: of the next in one forward over two positions, emits one token or
+    #: two, and drafts again. A greedy slot without penalties or bias
+    #: accepts a draft that is the main model's own argmax, so its tokens
+    #: are those of plain decoding; any other slot never accepts. 1 = on,
+    #: for a model that has a module; 0 (default) serves the main path alone.
+    speculative_mtp: int = 0
+
+    def __post_init__(self) -> None:
+        if self.speculative_mtp not in (0, 1):
+            raise ValueError(
+                f"speculative_mtp {self.speculative_mtp}: 0 or 1 (this "
+                "family's module drafts one token)"
+            )
+        if not self.speculative_mtp:
+            return
+        if not getattr(self.model, "nextn_layers", 0):
+            raise ValueError(
+                f"--speculative-mtp 1: {type(self.model).__name__} has no "
+                "multi-token-prediction module to draft with"
+            )
+        if self.packed_serving:
+            raise ValueError(
+                "--speculative-mtp is incompatible with --packed-serving "
+                "(the mixed program emits one token a slot a step)"
+            )
+        if not self.model.serve_mtp:
+            import dataclasses
+
+            # the module's K and V are a layer of the pages: every size
+            # that follows from the model (KVLayout, avals, signatures)
+            # sees a model that serves its module
+            object.__setattr__(
+                self, "model",
+                dataclasses.replace(self.model, serve_mtp=True),
+            )
 
     @property
     def seq_len(self) -> int:
@@ -498,6 +536,10 @@ class ProgramSet:
         #: from the uploaded/compiled one, and every AOT executable
         #: mismatches after its first call
         self.mesh = mesh
+        #: the model's multi-token-prediction module drafts inside the
+        #: decode chunk (EngineConfig.speculative_mtp): the prompt programs
+        #: return a first draft too, the chunk takes and returns one
+        self.mtp = bool(getattr(model_cfg, "serve_mtp", False))
         self.prefill = jax.jit(self._make_prefill(False), donate_argnums=(3,))
         self.prefill_plp = jax.jit(self._make_prefill(True), donate_argnums=(3,))
         self.suffix = jax.jit(
@@ -557,6 +599,43 @@ class ProgramSet:
         )
         return tok, lp, alts[0], alts[1], jax.random.key_data(key)
 
+    def _sample_hidden(
+        self, params, hidden, lens, temp, topp, counts, pres, freq, skey,
+        bias, targets,
+    ):
+        """``_sample_last`` for a program that has the stream before the
+        final norm (``hidden`` [b, s, h]) and no logits yet: the head over
+        the last valid row alone, or, for prompt logprobs (``targets``),
+        over the whole segment. -> (tok, lp, av, ai, skey, plp)."""
+        cfg = self.model_cfg
+        if targets is None:
+            last = jnp.take_along_axis(
+                hidden, (lens - 1)[:, None, None], axis=1
+            )
+            logits, lens = llama.lm_logits(cfg, params, last), jnp.ones_like(lens)
+            plp = jnp.zeros(hidden.shape[:2], jnp.float32)
+        else:
+            logits = llama.lm_logits(cfg, params, hidden)
+            plp = self._prompt_lps(logits, targets)
+        return (
+            *self._sample_last(
+                logits, lens, temp, topp, counts, pres, freq, skey, bias
+            ),
+            plp,
+        )
+
+    @staticmethod
+    def _split_slot_keys(skeys, active):
+        """Each slot splits its OWN key, and only while ``active``, so a
+        request's draw count is a function of its own progress, not of how
+        long it shared the batch with others -> (the keys to sample with
+        [b], the slots' new key data)."""
+        keys = jax.random.wrap_key_data(skeys)  # [b] typed keys
+        pairs = jax.vmap(jax.random.split)(keys)  # [b, 2]
+        subs = pairs[:, 1]
+        new_data = jax.random.key_data(pairs[:, 0])
+        return subs, jnp.where(active[:, None], new_data, skeys)
+
     @staticmethod
     def _prompt_lps(logits, targets):
         """Per-position logprob of `targets` (the NEXT prompt token at
@@ -597,7 +676,49 @@ class ProgramSet:
                 plp = jnp.zeros(tokens.shape, jnp.float32)
             return tok, lp, av, ai, plp, cache, skey
 
-        return _prefill
+        def _prefill_mtp(
+            params, tokens, seq_lens, cache, page_table, temp, topp,
+            counts, pres, freq, skey, bias,
+        ):
+            """The same, and the module over the prompt shifted by one
+            (the sampled token after its last position): its pages are
+            whole and the first decode step has a draft."""
+            hidden, cache = llama.patterned(model_cfg).prefill(
+                params, model_cfg, tokens, seq_lens, cache, page_table,
+                mesh=self.mesh, hidden=True,
+            )
+            targets = jnp.roll(tokens, -1, axis=1)
+            tok, lp, av, ai, skey, plp = self._sample_hidden(
+                params, hidden, seq_lens, temp, topp, counts, pres, freq,
+                skey, bias, targets if with_plp else None,
+            )
+            draft, cache = self._first_draft(
+                params, hidden, targets, tok, None, seq_lens, cache,
+                page_table, True,
+            )
+            return tok, lp, av, ai, plp, cache, skey, draft
+
+        return _prefill_mtp if self.mtp else _prefill
+
+    def _first_draft(
+        self, params, hidden, targets, tok, start, lens, cache, page_table,
+        final,
+    ):
+        """The prediction module over a prompt segment (``hidden``: the main
+        stack's output before the final norm; ``targets``: the token after
+        each position): its K and V for the segment, and the draft of the
+        token after ``tok``. ``final``: True (a cold prompt: the token after
+        the last position is ``tok``, just sampled), or [b] bool per row (a
+        later segment of a chunked prompt: where False, the last target is
+        the next segment's first token, as given)."""
+        b = tok.shape[0]
+        last = jnp.where(final, tok, targets[jnp.arange(b), lens - 1])
+        dlogits, cache = llama.patterned(self.model_cfg).draft_segment(
+            params, self.model_cfg, hidden,
+            targets.at[jnp.arange(b), lens - 1].set(last), start, lens,
+            cache, page_table, cold=start is None, mesh=self.mesh,
+        )
+        return jnp.argmax(dlogits, axis=-1).astype(jnp.int32), cache
 
     def _make_suffix_prefill(self, with_plp: bool):
         model_cfg = self.model_cfg
@@ -623,7 +744,30 @@ class ProgramSet:
                 plp = jnp.zeros(tokens.shape, jnp.float32)
             return tok, lp, av, ai, plp, cache, skey
 
-        return _suffix_prefill
+        def _suffix_prefill_mtp(
+            params, tokens, targets, start, suffix_lens, cache,
+            page_table, temp, topp, counts, pres, freq, skey, bias,
+        ):
+            """The same, and the module over the segment. A segment that is
+            not its prompt's last brings the next segment's first token as
+            its last target; the last one brings -1 there, and the module
+            takes the token just sampled."""
+            hidden, cache = llama.patterned(model_cfg).prefill_continue(
+                params, model_cfg, tokens, start, suffix_lens, cache,
+                page_table, hidden=True,
+            )
+            tok, lp, av, ai, skey, plp = self._sample_hidden(
+                params, hidden, suffix_lens, temp, topp, counts, pres, freq,
+                skey, bias, jnp.maximum(targets, 0) if with_plp else None,
+            )
+            final = targets[jnp.arange(tok.shape[0]), suffix_lens - 1] < 0
+            draft, cache = self._first_draft(
+                params, hidden, targets, tok, start, suffix_lens, cache,
+                page_table, final,
+            )
+            return tok, lp, av, ai, plp, cache, skey, draft
+
+        return _suffix_prefill_mtp if self.mtp else _suffix_prefill
 
     def _make_verify(self):
         model_cfg = self.model_cfg
@@ -703,14 +847,9 @@ class ProgramSet:
             )
             last = logits[sample_rows]  # [b, vocab]
             # per-slot key split, advanced only for slots that sample this
-            # step (same discipline as the chunk program's active mask):
-            # a request's draw count stays a function of its own progress
-            keys = jax.random.wrap_key_data(skeys)
-            pairs = jax.vmap(jax.random.split)(keys)  # [b, 2]
-            subs = pairs[:, 1]
-            new_data = jax.random.key_data(pairs[:, 0])
+            # step (same discipline as the chunk program's active mask)
             active = sample_on > 0
-            skeys = jnp.where(active[:, None], new_data, skeys)
+            subs, skeys = self._split_slot_keys(skeys, active)
             out = sample(
                 last, subs, temps, top_p=topps,
                 counts=counts, presence_penalty=pres,
@@ -748,14 +887,7 @@ class ProgramSet:
                     params, model_cfg, lt, pos, cache, page_table, active,
                     mesh=self.mesh,
                 )
-                # each slot splits its OWN key — and only while active, so
-                # a request's draw count is a function of its own progress,
-                # not of how long it shared the batch with others
-                keys = jax.random.wrap_key_data(skeys)  # [b] typed keys
-                pairs = jax.vmap(jax.random.split)(keys)  # [b, 2]
-                subs = pairs[:, 1]
-                new_data = jax.random.key_data(pairs[:, 0])
-                skeys = jnp.where(active[:, None], new_data, skeys)
+                subs, skeys = self._split_slot_keys(skeys, active)
                 out = sample(
                     logits, subs, temps, top_p=topps,
                     counts=counts, presence_penalty=pres,
@@ -798,15 +930,127 @@ class ProgramSet:
 
         return chunk
 
+    def _make_chunk_mtp(self, T: int):
+        """The decode chunk of a model that drafts with its own prediction
+        module (``self.mtp``). A slot carries, beside its last token, a
+        draft of the next. One scan step, for every live slot at once:
+
+        1. the main stack over the two positions [last, draft] through the
+           decode path (``verify_step``): logits L_0, L_1;
+        2. g_1 from L_0 by the usual sampler; the draft is ACCEPTED iff it
+           is g_1, the slot is greedy without penalties or bias, g_1 does
+           not end it and its budget holds two; then g_2 = argmax L_1. The
+           slot emits g_1 or g_1, g_2 and ``pos``, ``budget`` and ``counts``
+           move by as many;
+        3. the module over the one or two new positions (``draft_step``)
+           gives the next draft.
+
+        A rejected draft's K and V are overwritten by the next step's first
+        position. Returns per step tokens [b, 2] and how many of them count
+        [b], where ``chunk`` returns one token a slot."""
+        model_cfg = self.model_cfg
+        eos = self.eos
+        alt_k = self.alt_k
+        family = llama.patterned(model_cfg)
+
+        def chunk(
+            params, lt, pos, budget, cache, page_table, temps, topps,
+            counts, pres, freq, skeys, eos_on, bias, draft,
+        ):
+            # a slot that samples, or whose argmax penalties or a bias
+            # move, takes L_0 through the sampler and never accepts
+            plain = (
+                (temps <= 0) & (pres == 0) & (freq == 0)
+                & ~jnp.any(bias != 0, axis=-1)
+            )
+            rows = jnp.arange(counts.shape[0])
+
+            def body(carry, _):
+                lt, draft, pos, budget, cache, counts, skeys = carry
+                active = budget > 0
+                two = active & (budget >= 2)
+                logits, hidden, cache = family.verify_step(
+                    params, model_cfg, jnp.stack([lt, draft], axis=1), pos,
+                    cache, page_table, jnp.stack([active, two], axis=1),
+                    mesh=self.mesh,
+                )
+                subs, skeys = self._split_slot_keys(skeys, active)
+                out = sample(
+                    logits[:, 0], subs, temps, top_p=topps,
+                    counts=counts, presence_penalty=pres,
+                    frequency_penalty=freq,
+                    alt_k=alt_k, bias=bias,
+                )
+                g1 = jnp.where(active, out[0], lt)
+                ends = (
+                    (g1 == eos) & (eos_on > 0) if eos >= 0
+                    else jnp.zeros_like(active)
+                )
+                accept = two & plain & (g1 == draft) & ~ends
+                # the second position, greedy: its raw distribution
+                norm = logits[:, 1] - jax.scipy.special.logsumexp(
+                    logits[:, 1], axis=-1, keepdims=True
+                )
+                g2 = jnp.argmax(norm, axis=-1).astype(jnp.int32)
+                lp2 = jnp.take_along_axis(norm, g2[:, None], axis=-1)[:, 0]
+                if alt_k > 0:
+                    av2, ai2 = jax.lax.top_k(norm, alt_k)
+                    av = jnp.stack([out[2], av2], axis=1)
+                    ai = jnp.stack([out[3], ai2.astype(jnp.int32)], axis=1)
+                else:
+                    av = jnp.zeros((g1.shape[0], 2, 0), jnp.float32)
+                    ai = jnp.zeros((g1.shape[0], 2, 0), jnp.int32)
+                a32, b32 = active.astype(jnp.int32), accept.astype(jnp.int32)
+                # the emitted tokens join the counts the NEXT step penalizes
+                counts = counts.at[rows, g1].add(a32).at[rows, g2].add(b32)
+                nxt, cache = family.draft_step(
+                    params, model_cfg, hidden, jnp.stack([g1, g2], axis=1),
+                    pos, cache, page_table,
+                    jnp.stack([active, accept], axis=1), b32, mesh=self.mesh,
+                )
+                draft = jnp.where(
+                    active, jnp.argmax(nxt, axis=-1).astype(jnp.int32), draft
+                )
+                n = a32 + b32
+                pos = pos + n
+                budget = budget - n
+                if eos >= 0:
+                    ends = (active & ends) | (accept & (g2 == eos) & (eos_on > 0))
+                    budget = jnp.where(ends, 0, budget)
+                return (
+                    (jnp.where(accept, g2, g1), draft, pos, budget, cache,
+                     counts, skeys),
+                    (jnp.stack([g1, g2], axis=1),
+                     jnp.stack([out[1], lp2], axis=1), av, ai, n),
+                )
+
+            (
+                (lt, draft, pos, budget, cache, counts, skeys),
+                (toks, lps, avs, ais, ns),
+            ) = jax.lax.scan(
+                body, (lt, draft, pos, budget, cache, counts, skeys), None,
+                length=T,
+            )
+            lt, pos, budget, counts, skeys, draft = self._pin_resident(
+                lt, pos, budget, counts, skeys, draft
+            )
+            return (
+                toks, lps, avs, ais, lt, pos, budget, cache, counts, skeys,
+                ns, draft,
+            )
+
+        return chunk
+
     def chunk(self, T: int):
         """The jitted T-step decode chunk (cached per T). At most two ever
         compile in serving (T = decode_chunk and T = 1) — compiles are
         expensive on TPU."""
         fn = self._chunks.get(T)
         if fn is None:
+            make = self._make_chunk_mtp if self.mtp else self._make_chunk
             # donate scheduler state + cache + counts + key data
             fn = self._chunks[T] = jax.jit(
-                self._make_chunk(T), donate_argnums=(1, 2, 3, 4, 8, 11)
+                make(T), donate_argnums=(1, 2, 3, 4, 8, 11)
             )
         return fn
 
@@ -942,6 +1186,17 @@ class InferenceEngine:
         #: host-exact mirror of the device copy the chunk program maintains
         self._token_counts = np.zeros((b, cfg.model.vocab_size), dtype=np.int32)
         self._budgets = np.zeros((b,), dtype=np.int32)
+        #: per-slot draft of the token after the last (speculative_mtp): the
+        #: prediction module's, from the prompt programs and then from every
+        #: chunk, carried where the last tokens are
+        self._drafts = np.zeros((b,), dtype=np.int32)
+        #: what the verify steps did (/v1/stats "mtp"), counted from the
+        #: drained chunks: steps dispatched, slot-steps that verified a
+        #: draft, drafts accepted, tokens the steps emitted
+        self.mtp_steps = 0
+        self.mtp_drafted = 0
+        self.mtp_accepted = 0
+        self.mtp_emitted = 0
         #: per-slot eos sensitivity (0 = ignore_eos request): the chunk
         #: program zeroes a slot's budget at eos only when enabled
         self._eos_on = np.ones((b,), dtype=np.int32)
@@ -1255,6 +1510,16 @@ class InferenceEngine:
                 "routed_tokens": self.moe_routed_tokens,
                 "assignments": self.moe_tokens * per_token,
             },
+            # self-speculative decoding (EngineConfig.speculative_mtp):
+            # decode steps dispatched, slot-steps that verified a draft,
+            # drafts accepted, tokens those steps emitted; zeros when off
+            "mtp": {
+                "on": int(self.programs.mtp),
+                "steps": self.mtp_steps,
+                "drafted": self.mtp_drafted,
+                "accepted": self.mtp_accepted,
+                "emitted": self.mtp_emitted,
+            },
         }
 
     # -- compiled-program dispatch (AOT executables > lazy jit) --------------
@@ -1416,7 +1681,7 @@ class InferenceEngine:
         AND the packed path's small-tier refresh (a hand-maintained
         second dict would silently serve stale device state on packed
         engines only)."""
-        return {
+        mirrors = {
             "lt": self._last_tokens,
             "pos": self._positions,
             "budget": self._budgets,
@@ -1430,6 +1695,9 @@ class InferenceEngine:
             "eos_on": self._eos_on,
             "bias": self._bias,
         }
+        if self.programs.mtp:
+            mirrors["draft"] = self._drafts
+        return mirrors
 
     def _upload_sched(self) -> None:
         """Push host scheduler mirrors to device in ONE batched transfer —
@@ -1801,6 +2069,10 @@ class InferenceEngine:
         targets = np.zeros((1, bucket), dtype=np.int32)
         nxt = req.prompt[start_pos + 1 : start_pos + len(seg) + 1]
         targets[0, : len(nxt)] = nxt
+        if final and self.programs.mtp:
+            # the prediction module's token after the prompt's last is the
+            # one this program samples (ProgramSet._make_suffix_prefill)
+            targets[0, len(seg) - 1] = -1
         start = np.array([start_pos], dtype=np.int32)
         seg_lens = np.array([len(seg)], dtype=np.int32)
         if self.lockstep is not None:
@@ -1814,7 +2086,7 @@ class InferenceEngine:
             + pres.nbytes + freq.nbytes + self._slot_keys[req.slot].nbytes
             + self._bias[req.slot : req.slot + 1].nbytes
         )
-        tok, lp, av, ai, plp, cache, new_key = self._call_program(
+        tok, lp, av, ai, plp, cache, new_key, *draft = self._call_program(
             "suffix_plp" if req.want_prompt_logprobs else "suffix",
             bucket,
             self.params,
@@ -1834,7 +2106,7 @@ class InferenceEngine:
         )
         self.pool.replace(cache)
         # key sync is the caller's: it batches it with the other host reads
-        return tok, lp, av, ai, plp, new_key
+        return tok, lp, av, ai, plp, new_key, draft
 
     def _run_prefill(self, req: Request) -> None:
         """One prompt, dispatched and finished at once: the packed
@@ -1891,7 +2163,7 @@ class InferenceEngine:
                     + self._slot_keys[req.slot].nbytes
                     + self._bias[req.slot : req.slot + 1].nbytes
                 )
-                tok, lp, av, ai, plp, cache, new_key = self._call_program(
+                tok, lp, av, ai, plp, cache, new_key, *draft = self._call_program(
                     "prefill_plp" if req.want_prompt_logprobs else "prefill",
                     bucket,
                     self.params,
@@ -1923,7 +2195,9 @@ class InferenceEngine:
                 while pos < n:
                     seg = req.prompt[pos : min(n, pos + limit)]
                     final = pos + len(seg) >= n
-                    tok, lp, av, ai, plp, seg_key = self._run_suffix_segment(
+                    (
+                        tok, lp, av, ai, plp, seg_key, draft,
+                    ) = self._run_suffix_segment(
                         req, pos, seg, temp, topp, counts_row, pres, freq,
                         final=final,
                     )
@@ -1947,7 +2221,8 @@ class InferenceEngine:
             # np.asarray calls are separate round trips on high-latency links,
             # and this is the tail of every TTFT measurement. Prompt-logprob
             # rows (one per prefill segment) ride the same fetch.
-            fetch = [tok, lp, new_key]
+            # (a program that drafts returns its first draft: one more read)
+            fetch = [tok, lp, new_key, *draft]
             if req.want_top_logprobs:
                 fetch += [av, ai]
             if req.want_prompt_logprobs:
@@ -1971,6 +2246,8 @@ class InferenceEngine:
         with tracing.phase("sched.emit", overlapped) as ph:
             tok_h, lp_h, key_h = vals[:3]
             vals = vals[3:]
+            if self.programs.mtp:
+                self._drafts[req.slot] = int(vals.pop(0)[0])
             alts = None
             if req.want_top_logprobs:
                 av_h, ai_h = vals[:2]
@@ -2154,6 +2431,7 @@ class InferenceEngine:
         self._freqs[req.slot] = 0.0
         self._token_counts[req.slot] = 0
         self._budgets[req.slot] = 0
+        self._drafts[req.slot] = 0
         self._slot_keys[req.slot] = 0
         self._eos_on[req.slot] = 1
         self._bias[req.slot] = 0.0
@@ -2786,9 +3064,10 @@ class InferenceEngine:
             ph.set(T=T, live_slots=len(running))
             self._count_passes(T)
             d = self._dev
+            mtp = self.programs.mtp
             (
                 toks_dev, lps_dev, avs_dev, ais_dev, lt, pos, budget,
-                cache, counts_dev, skeys_dev,
+                cache, counts_dev, skeys_dev, *drafted
             ) = self._chunk_fn(T)(
                 self.params,
                 d["lt"],
@@ -2804,6 +3083,7 @@ class InferenceEngine:
                 d["skeys"],
                 d["eos_on"],
                 d["bias"],
+                *([d["draft"]] if mtp else []),
             )
             self.pool.replace(cache)
             self._dev = {
@@ -2812,12 +3092,21 @@ class InferenceEngine:
                 "counts": counts_dev, "pres": d["pres"], "freq": d["freq"],
                 "skeys": skeys_dev, "eos_on": d["eos_on"], "bias": d["bias"],
             }
-        return (toks_dev, lps_dev, avs_dev, ais_dev, skeys_dev, running, T)
+            if mtp:
+                # drafted: (tokens that count a step and slot, the drafts)
+                self._dev["draft"] = drafted[1]
+        return (
+            toks_dev, lps_dev, avs_dev, ais_dev, skeys_dev, running, T,
+            *drafted,
+        )
 
     def _drain_chunk(self, inflight, defer_retire: bool = False):
         """Fetch one dispatched chunk's results (the single blocking host
         sync per chunk) and emit its tokens."""
-        toks_dev, lps_dev, avs_dev, ais_dev, skeys_dev, running, _ = inflight
+        (
+            toks_dev, lps_dev, avs_dev, ais_dev, skeys_dev, running, T,
+            *drafted,
+        ) = inflight
         # The key mirror rides the batched device_get: a dirty re-upload
         # must not rewind any slot's key stream to a pre-chunk state.
         # Pipelined: a later chunk's dispatch DONATES this chunk's skeys
@@ -2828,18 +3117,21 @@ class InferenceEngine:
         with tracing.phase("sched.chunk_fetch", overlapped):
             if skeys_dev.is_deleted():
                 skeys_host = None
-                toks, lps, avs, ais = jax.device_get(
-                    (toks_dev, lps_dev, avs_dev, ais_dev)
+                toks, lps, avs, ais, *drafted = jax.device_get(
+                    (toks_dev, lps_dev, avs_dev, ais_dev, *drafted)
                 )
             else:
-                toks, lps, avs, ais, skeys_host = jax.device_get(
-                    (toks_dev, lps_dev, avs_dev, ais_dev, skeys_dev)
+                toks, lps, avs, ais, skeys_host, *drafted = jax.device_get(
+                    (toks_dev, lps_dev, avs_dev, ais_dev, skeys_dev, *drafted)
                 )
         with tracing.phase("sched.emit", overlapped) as ph:
             emitted = self.total_tokens_emitted
             delivered = tracing.emit_deliveries()
+            if drafted:
+                self.mtp_steps += T
             finished = self._emit_chunk(
-                toks, lps, avs, ais, skeys_host, running, defer_retire
+                toks, lps, avs, ais, skeys_host, running, defer_retire,
+                *drafted,
             )
             ph.set(
                 tokens=self.total_tokens_emitted - emitted,
@@ -2849,7 +3141,8 @@ class InferenceEngine:
         return finished
 
     def _emit_chunk(
-        self, toks, lps, avs, ais, skeys_host, running, defer_retire
+        self, toks, lps, avs, ais, skeys_host, running, defer_retire,
+        ns=None, drafts=None,
     ) -> List[Request]:
         """The host half of a drained chunk: key mirror, one run of tokens
         a request (``_emit_run``, which writes the slot's small mirrors),
@@ -2858,7 +3151,9 @@ class InferenceEngine:
         them wakes the server's loop, and a writer that shares the GIL
         should find this thread past its bookkeeping. Returns the requests
         that finished, in the order a walk step by step would finish
-        them."""
+        them. A chunk that drafts (``ns`` [T, slots]: how many of a step's
+        two tokens count; ``drafts`` [slots]) holds a step's tokens side by
+        side: a slot's run is the tokens that count, in order."""
         if skeys_host is not None:
             # only the rows this chunk actually advanced: a request
             # admitted while the chunk was in flight had its key written
@@ -2866,30 +3161,56 @@ class InferenceEngine:
             # it to the pre-admission (zero) snapshot
             for slot in running:
                 self._slot_keys[slot] = skeys_host[slot]
+        if ns is not None:
+            # [T, slots, 2] -> [T * 2, slots], a step's first token first;
+            # ``keep`` marks the entries that count
+            flat = lambda x: x.swapaxes(1, 2).reshape(  # noqa: E731
+                -1, x.shape[1], *x.shape[3:]
+            )
+            keep = flat(np.arange(2) < ns[..., None])
+            toks, lps, avs, ais = map(flat, (toks, lps, avs, ais))
         # [T, slots] -> one list of T a slot, converted once
         toks_l = toks.T.tolist()
         lps_l = lps.T.tolist()
         ended: List[Tuple[int, Request]] = []
         emitted: List[Request] = []
         taken = np.zeros(toks.shape[1], dtype=np.int64)
+        #: rows the chunk program was traced with (the expert layers' form)
+        rows = self.cfg.max_batch * (1 if ns is None else 2)
         for slot, req in running.items():
             # aborted between dispatch and drain: its tokens are frozen
             # repeats, and abort already handled the retire
             if req.done:
                 continue
+            run_toks, run_lps = toks_l[slot], lps_l[slot]
+            kept = slice(None)
+            if ns is not None:
+                kept = np.nonzero(keep[:, slot])[0].tolist()
+                run_toks = [run_toks[i] for i in kept]
+                run_lps = [run_lps[i] for i in kept]
+                self._drafts[slot] = drafts[slot]
+                self.mtp_drafted += int(np.count_nonzero(ns[:, slot]))
+                self.mtp_accepted += int(np.count_nonzero(ns[:, slot] == 2))
+                self.mtp_emitted += len(kept)
+                if not run_toks:
+                    continue
             alts = None
             if req.want_top_logprobs:
                 alts = [
                     list(zip(i, v))
                     for i, v in zip(
-                        ais[:, slot].tolist(), avs[:, slot].tolist()
+                        ais[kept, slot].tolist(), avs[kept, slot].tolist()
                     )
                 ]
             first = req.pos
-            n = self._emit_run(req, toks_l[slot], lps_l[slot], alts)
+            n = self._emit_run(req, run_toks, run_lps, alts)
             # every token of the run was a step that wrote its position
-            self._count_forward(first, n, self.cfg.max_batch)
-            taken[slot] = n
+            self._count_forward(first, n, rows)
+            if ns is None:
+                taken[slot] = n
+            else:
+                np.add.at(self._token_counts[slot], run_toks[:n], 1)
+                n = kept[n - 1] + 1  # where in the chunk the run ended
             emitted.append(req)
             if req.done:
                 ended.append((n, req))

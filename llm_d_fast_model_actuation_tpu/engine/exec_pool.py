@@ -398,6 +398,8 @@ def abstract_args(cfg, program: str, bucket: int, mesh=None) -> list:
             S((b, p), i32), S((b,), f32), S((b,), f32), S((b, V), i32),
             S((b,), f32), S((b,), f32), S((b, 2), u32), S((b,), i32),
             S((b, V), f32),
+            # a model that serves its prediction module: the slots' drafts
+            *([S((b,), i32)] if getattr(m, "serve_mtp", False) else []),
         ]
     if program == "mixed":
         # bucket = engine.mixed_bucket(buffer rows, page-table width);

@@ -353,6 +353,11 @@ class LockstepLeader:
     calls these hooks immediately before its compiled dispatches."""
 
     def __init__(self, engine: Any) -> None:
+        if engine.cfg.speculative_mtp:
+            raise ValueError(
+                "--speculative-mtp is not supported for multi-host gangs "
+                "(the lockstep frame carries no drafts)"
+            )
         self.engine = engine
         self._template = _frame_template(engine.cfg)
 

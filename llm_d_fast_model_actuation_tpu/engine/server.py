@@ -46,6 +46,7 @@ from prometheus_client import Counter, Gauge, Histogram
 
 from ..models import llama
 from ..models.moe import MoeConfig
+from ..models.exaone_moe import ExaoneMoeConfig
 from ..models.nemotron_h import NemotronHConfig
 from ..models.olmo_hybrid import OlmoHybridConfig
 from ..models.kimi_linear import KimiLinearConfig
@@ -160,6 +161,17 @@ ENGINE_SPEC_PROPOSED = Gauge(
 ENGINE_SPEC_ACCEPTED = Gauge(
     "fma_engine_spec_accepted_tokens",
     "Proposed tokens accepted by the verify forward",
+    ["model"],
+)
+ENGINE_MTP_DRAFTED = Gauge(
+    "fma_engine_mtp_drafted_tokens_total",
+    "Drafts of the model's own prediction module that a verify step ran "
+    "(--speculative-mtp): one a live slot a decode step",
+    ["model"],
+)
+ENGINE_MTP_ACCEPTED = Gauge(
+    "fma_engine_mtp_accepted_tokens_total",
+    "Drafts the verify step accepted: steps that emitted two tokens",
     ["model"],
 )
 
@@ -455,6 +467,8 @@ MODEL_CONFIGS = {
     "kimi-linear-48b-a3b": KimiLinearConfig.kimi_linear_48b_a3b,
     "tiny-nemotron-h": NemotronHConfig.tiny_nemotron_h,
     "nemotron-3-super-120b-a12b": NemotronHConfig.nemotron_3_super_120b_a12b,
+    "tiny-exaone-moe": ExaoneMoeConfig.tiny_exaone_moe,
+    "k-exaone-236b-a23b": ExaoneMoeConfig.k_exaone_236b_a23b,
 }
 
 
@@ -557,6 +571,19 @@ def make_arg_parser() -> argparse.ArgumentParser:
         help="n-gram (prompt-lookup) speculative decoding: verify up to N "
         "proposed tokens per forward on the single-sequence greedy path; "
         "0 = off",
+    )
+    p.add_argument(
+        "--speculative-mtp",
+        type=int,
+        default=0,
+        choices=[0, 1],
+        help="self-speculative decoding with the model's own "
+        "multi-token-prediction module, inside the decode chunk for every "
+        "live slot: a step verifies the last token and the module's draft "
+        "of the next (two positions a slot) and emits one token or two. "
+        "1 = on, for a model that has a module (k-exaone-236b-a23b); 0 "
+        "(default) serves the main path alone. Incompatible with "
+        "--packed-serving and multi-host gangs",
     )
     p.add_argument(
         "--logprobs-topk",
@@ -834,6 +861,20 @@ def validate_parsed_args(args: argparse.Namespace) -> None:
                 "(the per-step packing layout is too large for the "
                 "lockstep control frame); sharded single-process meshes "
                 "via --tensor-parallel-size compose fine"
+            )
+    if getattr(args, "speculative_mtp", 0):
+        if getattr(args, "packed_serving", "off") == "on":
+            raise ValueError(
+                "--speculative-mtp is incompatible with --packed-serving "
+                "(the mixed program emits one token a slot a step)"
+            )
+        gang = getattr(args, "num_processes", 0) or int(
+            os.environ.get("FMA_NUM_PROCESSES", "0") or 0
+        )
+        if gang > 1:
+            raise ValueError(
+                "--speculative-mtp is incompatible with multi-host gangs "
+                "(the lockstep frame carries no drafts)"
             )
     if getattr(args, "slo_ttft_ms", 0.0) < 0:
         raise ValueError("--slo-ttft-ms must be >= 0 (0 = off)")
@@ -1752,6 +1793,7 @@ class EngineService:
             prefix_caching=prefix_caching,
             max_prefill_tokens=args.max_prefill_tokens,
             speculative_ngram=args.speculative_ngram,
+            speculative_mtp=getattr(args, "speculative_mtp", 0),
             logprobs_topk=max(0, getattr(args, "logprobs_topk", 5)),
             packed_serving=(
                 getattr(args, "packed_serving", "off") == "on"
@@ -6395,6 +6437,13 @@ def build_app(service: EngineService) -> web.Application:
             )
             ENGINE_SPEC_ACCEPTED.labels(model=service.args.model).set(
                 service.engine.spec_accepted
+            )
+        if service.engine.cfg.speculative_mtp:
+            ENGINE_MTP_DRAFTED.labels(model=service.args.model).set(
+                service.engine.mtp_drafted
+            )
+            ENGINE_MTP_ACCEPTED.labels(model=service.args.model).set(
+                service.engine.mtp_accepted
             )
         pool = service.model_pool
         ENGINE_POOL_BYTES.set(pool.bytes_used)

@@ -48,6 +48,7 @@ here (``llama.patterned``).
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from typing import Any, Dict, Tuple
 
@@ -583,21 +584,31 @@ def _stack(params, name, i, whole=()):
 def _ffn(cfg, params, layer, h):
     """The FFN of ``layer``: dense (a Python int below ``first_dense``: the
     first period is traced by itself), else the shared expert plus this
-    chip's share of the routed ones, whose stacks the grouped matmul reads
-    whole (models/moe.py:_grouped)."""
+    chip's share of the routed ones: the grouped matmul over the stacks read
+    whole (models/moe.py:_grouped), or, for a family whose programs of few
+    rows compute every held expert on every row (``dense_max_rows``;
+    models/exaone_moe.py), that form over the layer's own matrices
+    (models/moe.py:takes_grouped)."""
     with jax.named_scope("ffn"):
         if isinstance(layer, int) and layer < cfg.first_dense:
             dp = _stack(params, "dense", layer)
             return llama._mlp(cfg, h, dp["w_gate"], dp["w_up"], dp["w_down"])
+        grouped = moe.takes_grouped(
+            cfg, math.prod(h.shape[:-1]), moe.stored_expert_stack(cfg, params)
+        )
         e = jnp.asarray(layer - cfg.first_dense, jnp.int32)
-        ep = _stack(params, "experts", e, whole=moe.EXPERT_STACKS)
+        ep = _stack(
+            params, "experts", e, whole=moe.EXPERT_STACKS if grouped else ()
+        )
         shared = llama._mlp(cfg, h, ep["s_gate"], ep["s_up"], ep["s_down"])
         with jax.named_scope("moe.share"):
             logits = jnp.einsum(
                 "...h,he->...e", h, ep["router"],
                 preferred_element_type=jnp.float32,
             )
-            return shared + moe.routed_ffn(cfg, ep, h, logits, layer=e)
+            if grouped:
+                return shared + moe.routed_ffn(cfg, ep, h, logits, layer=e)
+            return shared + moe.held_dense_ffn(cfg, ep, h, logits)
 
 
 def _periods(cfg, carry, period):

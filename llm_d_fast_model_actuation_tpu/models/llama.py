@@ -609,10 +609,20 @@ def suffix_segment(tokens, start, suffix_lens):
     return positions, valid, attend
 
 
-def scatter_decode_rows(pool, rows, table, pos, active, page_size):
+def scatter_decode_rows(
+    pool, rows, table, pos, active, page_size, first_layer: int = 0
+):
     """The deferred write of a decode step, ONE scatter: ``rows``
     [layers of the pool in order ..., b, kvh, hd] at ``pos`` of ``table``;
-    inactive rows go to the out-of-bounds page and are dropped."""
+    inactive rows go to the out-of-bounds page and are dropped. ``pos`` and
+    ``active`` [b, n] with ``rows`` [..., b, n, kvh, hd]: n positions a slot
+    (a verify step). ``first_layer``: the pool layer the first of ``rows``
+    belongs to."""
+    if pos.ndim == 2:
+        n = pos.shape[1]
+        table = jnp.repeat(table, n, axis=0)
+        pos = pos.reshape(-1)
+        active = None if active is None else active.reshape(-1)
     b = pos.shape[0]
     L = rows.size // (b * pool.shape[-1])
     phys = jnp.take_along_axis(
@@ -620,7 +630,9 @@ def scatter_decode_rows(pool, rows, table, pos, active, page_size):
     )[:, 0]
     if active is not None:
         phys = jnp.where(active, phys, pool.shape[1])
-    li = jnp.broadcast_to(jnp.arange(L)[:, None], (L, b)).reshape(-1)
+    li = jnp.broadcast_to(
+        jnp.arange(first_layer, first_layer + L)[:, None], (L, b)
+    ).reshape(-1)
     pi = jnp.broadcast_to(phys[None, :], (L, b)).reshape(-1)
     si = jnp.broadcast_to((pos % page_size)[None, :], (L, b)).reshape(-1)
     return pool.at[li, pi, si].set(

@@ -13,12 +13,14 @@ from typing import Any, Dict
 
 import jax
 
-from . import kimi_linear, llama, moe, nemotron_h, olmo_hybrid
+from . import exaone_moe, kimi_linear, llama, moe, nemotron_h, olmo_hybrid
 
 
 def init_params_for(key: jax.Array, cfg: llama.LlamaConfig) -> Dict[str, Any]:
     if isinstance(cfg, kimi_linear.KimiLinearConfig):
         params = kimi_linear.init_params(key, cfg)
+    elif isinstance(cfg, exaone_moe.ExaoneMoeConfig):
+        params = exaone_moe.init_params(key, cfg)
     elif isinstance(cfg, nemotron_h.NemotronHConfig):
         params = nemotron_h.init_params(key, cfg)
     elif isinstance(cfg, moe.MoeConfig):
@@ -59,6 +61,8 @@ def _init_fn(cfg: llama.LlamaConfig):
 def logical_axes_for(cfg: llama.LlamaConfig) -> Dict[str, Any]:
     if isinstance(cfg, kimi_linear.KimiLinearConfig):
         axes = kimi_linear.param_logical_axes(cfg)
+    elif isinstance(cfg, exaone_moe.ExaoneMoeConfig):
+        axes = exaone_moe.param_logical_axes(cfg)
     elif isinstance(cfg, nemotron_h.NemotronHConfig):
         axes = nemotron_h.param_logical_axes(cfg)
     elif isinstance(cfg, moe.MoeConfig):

@@ -30,7 +30,7 @@ engine/engine.py's ``ProgramSet`` never branches on the family.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any, Dict, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -151,10 +151,12 @@ def _join_cache(cache, kp, vp, kr, vr):
     return kp, vp, kr.reshape(cache[2].shape), vr.reshape(cache[3].shape)
 
 
-def _periods(cfg):
-    """The scan's xs (a layer's small matrices are sliced by the body, the
-    expert stacks read whole)."""
-    return llama.period_indices(cfg, len(cfg.window_pattern))
+def _periods(cfg, period, carry):
+    """One scan over the periods (a layer's small matrices are sliced by
+    the body, the expert stacks read whole)."""
+    return jax.lax.scan(
+        period, carry, llama.period_indices(cfg, len(cfg.window_pattern))
+    )
 
 
 def _layer_params(cfg, params, pi, j):
@@ -168,7 +170,7 @@ def _layer_params(cfg, params, pi, j):
     }
 
 
-def _router_logits(x, lp):
+def _router_logits(cfg, lp, x):
     """The layer's router, read from its input: float32 out of the MXU's
     accumulator, so that near-ties among 64 logits are not rounded twice."""
     with jax.named_scope("router"):
@@ -178,73 +180,183 @@ def _router_logits(x, lp):
         )
 
 
-def _segment(params, cfg, tokens, positions, valid, cache, page_table, attend):
-    """The forward of one prefill segment [b, s] shared by the cold and the
-    continued program: per layer, project, write K and V into the layer's
-    own kind of cache, ``attend(q, k, v, pools, table, layer, window)``, the
-    routed experts."""
-    b, s = tokens.shape
-    layers, n_global, n_window = _plan(cfg)
-    kp, vp, kr, vr, gtable, rtable = _split_cache(cache, page_table)
-    page_size = kp.shape[2]
-    ring_len = rtable.shape[1] * page_size
-    ring_pos = positions % ring_len
-    cos_tab, sin_tab = rope_table(
+def _routed_ffn(cfg, params, lp, layer, router, x):
+    h = llama._norm(cfg, x, lp["mlp_norm"])
+    return llama._ffn(cfg, lp, h, router_logits=router, layer=layer)
+
+
+class Block(NamedTuple):
+    """What a family that shares this file's period scan, rings and deferred
+    writes brings of its own (models/exaone_moe.py is the other): where its
+    norms sit, what its FFN is, and which periods are traced by
+    themselves."""
+
+    #: (cfg, params, pi, j) -> (layer index, the parameters of layer j of
+    #: period pi)
+    layer_params: Callable
+    #: (cfg, lp, x) -> what the q, k and v projections read of the stream
+    mixer_in: Callable
+    #: (cfg, lp, y) -> what joins the stream of the attention's output
+    mixer_out: Callable
+    #: (cfg, lp, x) -> what the FFN keeps of the LAYER's input, computed
+    #: before attention (this family's router reads it)
+    ffn_in: Callable
+    #: (cfg, params, lp, layer, kept, x) -> what joins the stream of the FFN
+    #: sub-layer, ``x`` the stream after attention
+    ffn: Callable
+    #: (cfg, period, carry) -> (carry, ys stacked over all periods)
+    periods: Callable
+
+
+BLOCK = Block(
+    layer_params=_layer_params,
+    mixer_in=lambda cfg, lp, x: llama._norm(cfg, x, lp["attn_norm"]),
+    mixer_out=lambda cfg, lp, y: y,
+    ffn_in=_router_logits,
+    ffn=_routed_ffn,
+    periods=_periods,
+)
+
+
+#: the pools of :class:`_Caches`, as a scan carries them
+_POOLS = ("kp", "vp", "kr", "vr")
+
+
+class _Caches(NamedTuple):
+    """A step's view of the sequence state: the page pools of the
+    full-attention layers and the ring pools of the window layers, with the
+    table columns that address each."""
+
+    kp: Any
+    vp: Any
+    kr: Any
+    vr: Any
+    gtable: Any
+    rtable: Any
+
+    def of(self, window):
+        """(K pool, V pool, table) of a layer's kind."""
+        if window:
+            return self.kr, self.vr, self.rtable
+        return self.kp, self.vp, self.gtable
+
+    def put(self, window, k, v):
+        if window:
+            return self._replace(kr=k, vr=v)
+        return self._replace(kp=k, vp=v)
+
+
+def _cache_layer(li):
+    """The cache layer of (period, layers of the kind a period has, index
+    among them), made where it is used; or ``li`` itself."""
+    if isinstance(li, tuple):
+        pi, count, nth = li
+        return pi * count + nth
+    return li
+
+
+def rope_tables(cfg):
+    """The rotary tables of a forward, made once outside its scan."""
+    return rope_table(
         cfg.max_seq_len, cfg.head_dim, cfg.rope_theta, cfg.rope_scaling
     )
+
+
+def segment_layer(
+    cfg, params, block, tabs, x, c, lp, layer, window, rope, li, positions,
+    at, valid, attend,
+):
+    """One layer of a prefill segment, ``x`` [b, s, h]: project, write K and
+    V into cache layer ``li`` of the layer's own kind of cache at ``at``
+    (the positions, or their ring slots), ``attend(q, k, v, pools, table,
+    layer, window)``, the FFN. ``tabs``: :func:`rope_tables`; ``li``: (period,
+    layers of the kind a period has, index among them), or the cache layer
+    itself. -> (x, the caches)."""
+    b, s = positions.shape
+    page_size = c.kp.shape[2]
+    cos_tab, sin_tab = tabs
+    kept = block.ffn_in(cfg, lp, x)
+    h = block.mixer_in(cfg, lp, x)
+    scope = "attn.window" if window else "attn.global"
+    with jax.named_scope(scope):
+        q, k, v = llama._project_qkv(
+            cfg, lp, h, positions, cos_tab, sin_tab, rope=rope
+        )
+    with jax.named_scope("kv_write"):
+        li = _cache_layer(li)
+        kpool, vpool, table = c.of(window)
+        kpool = llama._scatter_prefill(
+            kpool, li, k, table, at, valid, page_size)
+        vpool = llama._scatter_prefill(
+            vpool, li, v, table, at, valid, page_size)
+        c = c.put(window, kpool, vpool)
+    with jax.named_scope(scope):
+        attn = attend(q, k, v, (kpool, vpool), table, li, window)
+        x = x + block.mixer_out(
+            cfg, lp, qmat(attn.reshape(b, s, cfg.q_dim), lp["wo"])
+        )
+    return x + block.ffn(cfg, params, lp, layer, kept, x), c
+
+
+def _segment(
+    params, cfg, tokens, positions, valid, cache, page_table, attend,
+    block=BLOCK,
+):
+    """The forward of one prefill segment [b, s] shared by the cold and the
+    continued program, over the periods -> (the stream before the final
+    norm, the four pools)."""
+    layers, n_global, n_window = _plan(cfg)
+    base = _Caches(*_split_cache(cache, page_table))
+    ring_pos = positions % (base.rtable.shape[1] * base.kp.shape[2])
+    tabs = rope_tables(cfg)
     x = llama._embed_tokens(cfg, params, tokens)
 
     def period(carry, pi):
-        x, kp, vp, kr, vr = carry
+        x, c = carry[0], base._replace(**dict(zip(_POOLS, carry[1:])))
         for j, (window, rope, nth) in enumerate(layers):
-            layer, lp = _layer_params(cfg, params, pi, j)
-            router = _router_logits(x, lp)
-            h = llama._norm(cfg, x, lp["attn_norm"])
-            scope = "attn.window" if window else "attn.global"
-            with jax.named_scope(scope):
-                q, k, v = llama._project_qkv(
-                    cfg, lp, h, positions, cos_tab, sin_tab, rope=rope
-                )
-            with jax.named_scope("kv_write"):
-                if window:
-                    li = pi * n_window + nth
-                    kr = llama._scatter_prefill(
-                        kr, li, k, rtable, ring_pos, valid, page_size)
-                    vr = llama._scatter_prefill(
-                        vr, li, v, rtable, ring_pos, valid, page_size)
-                    pools, table = (kr, vr), rtable
-                else:
-                    li = pi * n_global + nth
-                    kp = llama._scatter_prefill(
-                        kp, li, k, gtable, positions, valid, page_size)
-                    vp = llama._scatter_prefill(
-                        vp, li, v, gtable, positions, valid, page_size)
-                    pools, table = (kp, vp), gtable
-            with jax.named_scope(scope):
-                attn = attend(q, k, v, pools, table, li, window)
-                x = x + qmat(attn.reshape(b, s, cfg.q_dim), lp["wo"])
-            h = llama._norm(cfg, x, lp["mlp_norm"])
-            x = x + llama._ffn(cfg, lp, h, router_logits=router, layer=layer)
-        return (x, kp, vp, kr, vr), None
+            layer, lp = block.layer_params(cfg, params, pi, j)
+            li = (pi, n_window if window else n_global, nth)
+            x, c = segment_layer(
+                cfg, params, block, tabs, x, c, lp, layer, window, rope, li,
+                positions, ring_pos if window else positions, valid, attend,
+            )
+        return (x, c.kp, c.vp, c.kr, c.vr), None
 
-    (x, kp, vp, kr, vr), _ = jax.lax.scan(
-        period, (x, kp, vp, kr, vr), _periods(cfg)
+    (x, *pools), _ = block.periods(
+        cfg, period, (x, base.kp, base.vp, base.kr, base.vr)
     )
-    return llama.lm_logits(cfg, params, x), _join_cache(cache, kp, vp, kr, vr)
+    return x, pools
 
 
-def prefill(params, cfg, tokens, seq_lens, cache, page_table, mesh=None):
+def _outputs(cfg, params, x, cache, pools, hidden):
+    """What a forward returns: the logits, or if ``hidden`` the stream
+    before the final norm in their place (the caller makes the logits of
+    the rows it needs), and the cache tuple with ``pools`` in it."""
+    if hidden:
+        return x, _join_cache(cache, *pools)
+    logits = llama.lm_logits(cfg, params, x)
+    return logits, _join_cache(cache, *pools)
+
+
+def prefill(
+    params, cfg, tokens, seq_lens, cache, page_table, mesh=None, block=BLOCK,
+    hidden=False,
+):
     """``llama.prefill`` for a patterned model: a cold segment attends over
     its own K and V (the flash kernel, with the layer's window), and writes
-    both kinds of cache for what follows."""
+    both kinds of cache for what follows. ``hidden``: the stream before the
+    final norm comes back in the logits' place."""
     positions, valid, attend = llama.cold_segment(cfg, tokens, seq_lens, mesh)
-    return _segment(
-        params, cfg, tokens, positions, valid, cache, page_table, attend
+    x, pools = _segment(
+        params, cfg, tokens, positions, valid, cache, page_table, attend,
+        block,
     )
+    return _outputs(cfg, params, x, cache, pools, hidden)
 
 
 def prefill_continue(
-    params, cfg, tokens, start, suffix_lens, cache, page_table
+    params, cfg, tokens, start, suffix_lens, cache, page_table, block=BLOCK,
+    hidden=False,
 ):
     """``llama.prefill_continue`` for a patterned model: a later segment of
     a chunked prefill. Full-attention layers attend over the sequence's
@@ -252,13 +364,55 @@ def prefill_continue(
     been written (a ring holds window + one segment, so nothing a query of
     the segment still sees has been overwritten)."""
     positions, valid, attend = llama.suffix_segment(tokens, start, suffix_lens)
-    return _segment(
-        params, cfg, tokens, positions, valid, cache, page_table, attend
+    x, pools = _segment(
+        params, cfg, tokens, positions, valid, cache, page_table, attend,
+        block,
     )
+    return _outputs(cfg, params, x, cache, pools, hidden)
+
+
+def step_layer(
+    cfg, params, block, tabs, x, c, lp, layer, window, rope, li, positions,
+    mesh,
+):
+    """One layer of a decode step by the deferred write: attention reads
+    cache layer ``li`` (as :func:`segment_layer` takes it) of the layer's
+    kind for the positions before ``positions`` and takes the new K and V
+    inline. ``x`` [b, h], or [b, n,
+    h] for n positions a slot, ``positions + 0 .. n - 1`` (a verify step).
+    -> (x, the new K, the new V [b, (n,) kvh, hd])."""
+    lead = x.shape[:-1]
+    cos_tab, sin_tab = tabs
+    kept = block.ffn_in(cfg, lp, x)
+    h = block.mixer_in(cfg, lp, x)
+    with jax.named_scope("attn.window" if window else "attn.global"):
+        if x.ndim == 2:
+            q, k, v = llama._project_qkv(
+                cfg, lp, h[:, None, :], positions[:, None], cos_tab,
+                sin_tab, rope=rope,
+            )
+            q, k, v = q[:, 0], k[:, 0], v[:, 0]  # [b, heads/kvh, hd]
+        else:
+            offs = jnp.arange(x.shape[1], dtype=positions.dtype)
+            q, k, v = llama._project_qkv(
+                cfg, lp, h, positions[:, None] + offs, cos_tab, sin_tab,
+                rope=rope,
+            )
+        kpool, vpool, table = c.of(window)
+        attn = paged_decode_attention_inline(
+            q, kpool, vpool, k, v, table, positions, _cache_layer(li),
+            impl=cfg.attention_impl, mesh=mesh,
+            **({"window": window} if window else {}),
+        )
+        x = x + block.mixer_out(
+            cfg, lp, qmat(attn.reshape(*lead, cfg.q_dim), lp["wo"])
+        )
+    return x + block.ffn(cfg, params, lp, layer, kept, x), k, v
 
 
 def decode_step(
-    params, cfg, tokens, positions, cache, page_table, active=None, mesh=None
+    params, cfg, tokens, positions, cache, page_table, active=None, mesh=None,
+    block=BLOCK, hidden=False,
 ):
     """``llama.decode_step`` for a patterned model, always by the deferred
     write: attention reads each layer's own cache for positions before the
@@ -266,50 +420,40 @@ def decode_step(
     scatter per kind of cache and direction writes every layer's new row
     (pages of the full-attention layers, ring slot ``position % ring_len``
     of the window layers). Window layers read only the pages of the ring
-    that hold a visible key (ops/pallas/decode.py)."""
-    b = tokens.shape[0]
+    that hold a visible key (ops/pallas/decode.py).
+
+    ``tokens`` [b, n] (and ``active`` [b, n]): n positions a slot, ``positions
+    + 0 .. n - 1``, through the same layers and the same two scatters; a
+    slot's pages and ring are read once for all n (a verify step of
+    speculative decoding: the last token and its drafts). ``hidden``: the
+    stream before the final norm comes back in the logits' place."""
     layers, n_global, n_window = _plan(cfg)
-    kp, vp, kr, vr, gtable, rtable = _split_cache(cache, page_table)
-    page_size = kp.shape[2]
-    ring_len = rtable.shape[1] * page_size
-    cos_tab, sin_tab = rope_table(
-        cfg.max_seq_len, cfg.head_dim, cfg.rope_theta, cfg.rope_scaling
-    )
-    x = llama._embed_tokens(cfg, params, tokens)  # [b, h]
+    c = _Caches(*_split_cache(cache, page_table))
+    page_size = c.kp.shape[2]
+    ring_len = c.rtable.shape[1] * page_size
+    tabs = rope_tables(cfg)
+    x = llama._embed_tokens(cfg, params, tokens)  # [b, (n,) h]
 
     def period(x, pi):
         new_k, new_v = [], []
         for j, (window, rope, nth) in enumerate(layers):
-            layer, lp = _layer_params(cfg, params, pi, j)
-            router = _router_logits(x, lp)
-            h = llama._norm(cfg, x, lp["attn_norm"])
-            with jax.named_scope("attn.window" if window else "attn.global"):
-                q, k, v = llama._project_qkv(
-                    cfg, lp, h[:, None, :], positions[:, None], cos_tab,
-                    sin_tab, rope=rope,
-                )
-                q, k, v = q[:, 0], k[:, 0], v[:, 0]  # [b, heads/kvh, hd]
-                if window:
-                    attn = paged_decode_attention_inline(
-                        q, kr, vr, k, v, rtable, positions,
-                        pi * n_window + nth, impl=cfg.attention_impl,
-                        mesh=mesh, window=window,
-                    )
-                else:
-                    attn = paged_decode_attention_inline(
-                        q, kp, vp, k, v, gtable, positions,
-                        pi * n_global + nth, impl=cfg.attention_impl,
-                        mesh=mesh,
-                    )
-                x = x + qmat(attn.reshape(b, cfg.q_dim), lp["wo"])
-            h = llama._norm(cfg, x, lp["mlp_norm"])
-            x = x + llama._ffn(cfg, lp, h, router_logits=router, layer=layer)
+            layer, lp = block.layer_params(cfg, params, pi, j)
+            li = (pi, n_window if window else n_global, nth)
+            x, k, v = step_layer(
+                cfg, params, block, tabs, x, c, lp, layer, window, rope, li,
+                positions, mesh,
+            )
             new_k.append(k)
             new_v.append(v)
         return x, (jnp.stack(new_k), jnp.stack(new_v))
 
-    # k_all, v_all: [periods, layers of a period, b, kvh, hd]
-    x, (k_all, v_all) = jax.lax.scan(period, x, _periods(cfg))
+    # k_all, v_all: [periods, layers of a period, b, (n,) kvh, hd]
+    x, (k_all, v_all) = block.periods(cfg, period, x)
+    pos = positions
+    if tokens.ndim == 2:
+        pos = positions[:, None] + jnp.arange(
+            tokens.shape[1], dtype=positions.dtype
+        )
 
     def write(pool, new, table, pos, js):
         """The rows ``new[:, js]`` of every layer of one kind, one scatter."""
@@ -318,17 +462,18 @@ def decode_step(
             pool, rows, table, pos, active, page_size
         )
 
+    kp, vp, kr, vr = c.kp, c.vp, c.kr, c.vr
     with jax.named_scope("kv_write"):
         global_js = [j for j, (w, _, _) in enumerate(layers) if not w]
         window_js = [j for j, (w, _, _) in enumerate(layers) if w]
         if global_js:
-            kp = write(kp, k_all, gtable, positions, global_js)
-            vp = write(vp, v_all, gtable, positions, global_js)
+            kp = write(kp, k_all, c.gtable, pos, global_js)
+            vp = write(vp, v_all, c.gtable, pos, global_js)
         if window_js:
-            ring_pos = positions % ring_len
-            kr = write(kr, k_all, rtable, ring_pos, window_js)
-            vr = write(vr, v_all, rtable, ring_pos, window_js)
-    return llama.lm_logits(cfg, params, x), _join_cache(cache, kp, vp, kr, vr)
+            ring_pos = pos % ring_len
+            kr = write(kr, k_all, c.rtable, ring_pos, window_js)
+            vr = write(vr, v_all, c.rtable, ring_pos, window_js)
+    return _outputs(cfg, params, x, cache, (kp, vp, kr, vr), hidden)
 
 
 def reference_logits(

@@ -170,11 +170,12 @@ def causal_prefill_attention(
 
 
 def paged_decode_attention_inline(
-    q: jnp.ndarray,  # [batch, heads, head_dim] — the new token's queries
+    q: jnp.ndarray,  # [batch, heads, head_dim] — the new token's queries;
+    #                  [batch, n, heads, head_dim]: n new positions a slot
     k_pages: jnp.ndarray,  # [layers, num_pages, page_size, kv_heads*head_dim]
     v_pages: jnp.ndarray,  # same
-    k_new: jnp.ndarray,  # [batch, kv_heads, head_dim] — the new token's K
-    v_new: jnp.ndarray,  # [batch, kv_heads, head_dim] — the new token's V
+    k_new: jnp.ndarray,  # [batch, (n,) kv_heads, head_dim] — the new K
+    v_new: jnp.ndarray,  # [batch, (n,) kv_heads, head_dim] — the new V
     page_table: jnp.ndarray,  # [batch, pages_per_seq] int32
     positions: jnp.ndarray,  # [batch] int32 — position of the new token;
     #                          cache entries < position are attended
@@ -197,6 +198,11 @@ def paged_decode_attention_inline(
     materialized `repeat` of K/V, matmuls run bf16 on the MXU with fp32
     accumulation. The pallas kernel walks the pages a 128-token tile a step
     for every caller (ops/pallas/decode.py:decode_block_pages).
+
+    With n new positions a slot (q [batch, n, heads, head_dim], a verify step
+    of speculative decoding) the cache is read ONCE a slot for all n queries,
+    n * group query rows a KV head; query i sits at ``positions + i`` and
+    takes the new rows 0..i inline.
     """
     if (impl or _IMPL) == "pallas":
         from .pallas import paged_decode_attention_inline_pallas
@@ -207,12 +213,18 @@ def paged_decode_attention_inline(
             interpret=_pallas_interpret(),
             **({"window": window} if window else {}),
         )
+        heads = _HEADS3 if q.ndim == 3 else _HEADS4
         return shard_over_tp(
             mesh, kernel,
-            (_HEADS3, POOL_SPEC, POOL_SPEC, _HEADS3, _HEADS3, P(None, None),
+            (heads, POOL_SPEC, POOL_SPEC, heads, heads, P(None, None),
              P(None), P()),
-            _HEADS3,
+            heads,
         )(q, k_pages, v_pages, k_new, v_new, page_table, positions, layer)
+    if q.ndim == 4:
+        return _inline_rows(
+            q, k_pages, v_pages, k_new, v_new, page_table, positions, layer,
+            window,
+        )
     b, h, d = q.shape
     k = _gather_context(k_pages, layer, page_table, d)
     v = _gather_context(v_pages, layer, page_table, d)
@@ -241,6 +253,57 @@ def paged_decode_attention_inline(
     )
     out = out + probs[..., ctx:] * v_new.reshape(b, kvh, 1, d).astype(jnp.float32)
     return out.reshape(b, h, d).astype(q.dtype)
+
+
+def _inline_rows(q, k_pages, v_pages, k_new, v_new, page_table, positions,
+                 layer, window):
+    """The XLA twin of :func:`paged_decode_attention_inline` for SEVERAL new
+    positions a slot (a verify step: the last token and its drafts). q
+    [b, n, heads, d] at ``positions + 0 .. n - 1``, k_new / v_new [b, n,
+    kv_heads, d]: the cache is gathered ONCE a slot for all n queries, and
+    query i takes the new rows 0..i inline. A window layer's query i sees
+    the ring's keys after ``positions + i - window``."""
+    b, n, h, d = q.shape
+    k = _gather_context(k_pages, layer, page_table, d)
+    v = _gather_context(v_pages, layer, page_table, d)
+    ctx, kvh = k.shape[1:3]
+    g = h // kvh
+    qg = (q.astype(jnp.float32) * (d**-0.5)).astype(q.dtype).reshape(
+        b, n, kvh, g, d
+    )
+    logits = jnp.einsum(
+        "bqngd,bknd->bqngk", qg, k, preferred_element_type=jnp.float32
+    )
+    qpos = positions[:, None] + jnp.arange(n)[None, :]  # [b, n]
+    if window:
+        kpos = _ring_positions(ctx, positions - 1)[:, None, :]  # [b, 1, ctx]
+        valid = (kpos >= 0) & (kpos > qpos[:, :, None] - window)
+    else:
+        valid = jnp.broadcast_to(
+            jnp.arange(ctx)[None, None, :] < positions[:, None, None],
+            (b, n, ctx),
+        )
+    logits = jnp.where(valid[:, :, None, None, :], logits, NEG_INF)
+    new_logits = jnp.einsum(
+        "bqngd,bjnd->bqngj", qg, k_new.astype(qg.dtype),
+        preferred_element_type=jnp.float32,
+    )
+    idx = jnp.arange(n)
+    seen = idx[None, :] <= idx[:, None]  # [q, j]: its own row and the earlier
+    if window:
+        seen = seen & (idx[None, :] > idx[:, None] - window)
+    new_logits = jnp.where(seen[None, :, None, None, :], new_logits, NEG_INF)
+    probs = jax.nn.softmax(
+        jnp.concatenate([logits, new_logits], axis=-1), axis=-1
+    )
+    out = jnp.einsum(
+        "bqngk,bknd->bqngd", probs[..., :ctx].astype(v.dtype), v,
+        preferred_element_type=jnp.float32,
+    )
+    out = out + jnp.einsum(
+        "bqngj,bjnd->bqngd", probs[..., ctx:], v_new.astype(jnp.float32)
+    )
+    return out.reshape(b, n, h, d).astype(q.dtype)
 
 
 def latent_decode_attention_inline(

@@ -106,6 +106,7 @@ def _decode_kernel(
     block_pages: int = 1,
     latent: int = 0,
     scale: float = 0.0,
+    queries: int = 1,
 ):
     """Online-softmax over the sequence's pages. With ``inline`` the new
     token's K/V arrive as two extra inputs ([1, 1, kv_heads * head_dim]
@@ -133,7 +134,16 @@ def _decode_kernel(
     no V tile and no V copy: a page is read once and serves both, the new
     token arrives as one row, the output is ``latent`` wide, and ``scale``
     is the model's (the row's width is not the width the scores are scaled
-    by)."""
+    by).
+
+    With ``queries`` n > 1 (inline only: a verify step of speculative
+    decoding) a slot brings n new positions, ``kv_len + 0 .. n - 1``: q_ref
+    holds, a KV head, the n * group query rows of its group, query by query,
+    the new K and V are n rows, and o_ref is laid out as q_ref. The pages
+    are walked ONCE for all n: they hold the positions before ``kv_len``,
+    which every query sees (a window layer's query i from ``lo + i`` on, so
+    the walk starts at query 0's first page); new row j is folded into the
+    rows of queries j and later."""
     if latent:
         # k_hbm: [layers, num_pages, page_size, head_dim]; o_ref: [1, heads,
         # latent]; k_buf: [2, block_pages * page_size, head_dim]
@@ -155,6 +165,13 @@ def _decode_kernel(
     tile = block_pages * page_size
     b = pl.program_id(0)
     group = num_heads // num_kv_heads
+    rows = queries * group  # query rows a KV head
+    if queries > 1:
+        # [rows, 1]: which of the slot's queries a row of a group belongs to
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+        query_of = sum(
+            (row >= j * group).astype(jnp.int32) for j in range(1, queries)
+        )
     kv_len = len_ref[b]
     layer = layer_ref[0]
     num_pages = jax.lax.div(kv_len + page_size - 1, page_size)
@@ -225,12 +242,16 @@ def _decode_kernel(
         tok_idx = tok0 + jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
         valid = tok_idx < kv_len  # [1, tile]
         if window:
-            valid = valid & (tok_idx >= lo)
+            # [rows, tile] for several queries: each from its own edge
+            valid = valid & (
+                tok_idx >= (lo if queries == 1 else jnp.maximum(
+                    kv_len + 1 + query_of - window, 0))
+            )
 
         new_ms, new_ls, new_accs = [], [], []
         for g in range(num_kv_heads):
             lanes = pl.ds(g * head_dim, head_dim)
-            qg = q[g * group : (g + 1) * group]  # [group, head_dim]
+            qg = q[g * rows : (g + 1) * rows]  # [rows, head_dim]
             kg = k_buf[slot, :, lanes].astype(jnp.float32)  # [page, head_dim]
             if latent:
                 vg = k_buf[slot, :, pl.ds(0, latent)].astype(jnp.float32)
@@ -259,10 +280,10 @@ def _decode_kernel(
             new_accs.append(accs[g] * alpha + pv)
         return tuple(new_ms), tuple(new_ls), tuple(new_accs)
 
-    m0 = tuple(jnp.full((group, 1), NEG_INF, jnp.float32) for _ in range(num_kv_heads))
-    l0 = tuple(jnp.zeros((group, 1), jnp.float32) for _ in range(num_kv_heads))
+    m0 = tuple(jnp.full((rows, 1), NEG_INF, jnp.float32) for _ in range(num_kv_heads))
+    l0 = tuple(jnp.zeros((rows, 1), jnp.float32) for _ in range(num_kv_heads))
     acc0 = tuple(
-        jnp.zeros((group, value_dim), jnp.float32) for _ in range(num_kv_heads)
+        jnp.zeros((rows, value_dim), jnp.float32) for _ in range(num_kv_heads)
     )
     ms, ls, accs = jax.lax.fori_loop(0, num_steps, body, (m0, l0, acc0))
 
@@ -271,19 +292,24 @@ def _decode_kernel(
         ms, ls, accs = list(ms), list(ls), list(accs)
         for g in range(num_kv_heads):
             lanes = pl.ds(g * head_dim, head_dim)
-            qg = q[g * group : (g + 1) * group]
-            kn = knew_ref[0, :, lanes].astype(jnp.float32)  # [1, head_dim]
-            if latent:
-                vn = knew_ref[0, :, pl.ds(0, latent)].astype(jnp.float32)
-            else:
-                vn = vnew_ref[0, :, lanes].astype(jnp.float32)
-            logit = (qg * kn).sum(axis=-1, keepdims=True)  # [group, 1]
-            m_cur = jnp.maximum(ms[g], logit)
-            alpha = jnp.exp(ms[g] - m_cur)
-            p_self = jnp.exp(logit - m_cur)
-            ls[g] = ls[g] * alpha + p_self
-            accs[g] = accs[g] * alpha + p_self * vn
-            ms[g] = m_cur
+            qg = q[g * rows : (g + 1) * rows]
+            # new row 0 first: every query sees it, so m is finite after it
+            for j in range(queries):
+                new = pl.ds(j, 1)
+                kn = knew_ref[0, new, lanes].astype(jnp.float32)  # [1, head_dim]
+                if latent:
+                    vn = knew_ref[0, new, pl.ds(0, latent)].astype(jnp.float32)
+                else:
+                    vn = vnew_ref[0, new, lanes].astype(jnp.float32)
+                logit = (qg * kn).sum(axis=-1, keepdims=True)  # [rows, 1]
+                if j:
+                    logit = jnp.where(query_of >= j, logit, NEG_INF)
+                m_cur = jnp.maximum(ms[g], logit)
+                alpha = jnp.exp(ms[g] - m_cur)
+                p_self = jnp.exp(logit - m_cur)
+                ls[g] = ls[g] * alpha + p_self
+                accs[g] = accs[g] * alpha + p_self * vn
+                ms[g] = m_cur
 
     l = jnp.concatenate(ls, axis=0)  # [heads, 1]
     acc = jnp.concatenate(accs, axis=0)  # [heads, value_dim]
@@ -296,10 +322,19 @@ def _paged_decode(
     window=0, block_pages=1, latent=0, scale=0.0,
 ):
     """``latent`` > 0: ``k_pages`` are latent pages, ``v_pages`` is None and
-    ``new_kv`` one row (:func:`_decode_kernel`)."""
-    batch, num_heads, head_dim = q.shape
+    ``new_kv`` one row (:func:`_decode_kernel`). q [batch, n, heads,
+    head_dim] with ``new_kv`` of n rows a slot: n query positions
+    (:func:`_decode_kernel`, ``queries``)."""
+    queries = q.shape[1] if q.ndim == 4 else 1
+    num_heads, head_dim = q.shape[-2:]
+    batch = q.shape[0]
     _, _, page_size, fused = k_pages.shape
     num_kv_heads = fused // head_dim
+    if q.ndim == 4:
+        # a KV head's rows together, query by query: [batch, kvh, n, group]
+        group = num_heads // num_kv_heads
+        q = q.reshape(batch, queries, num_kv_heads, group, head_dim)
+        q = q.swapaxes(1, 2).reshape(batch, queries * num_heads, head_dim)
     if not interpret:  # the interpreter has no tiling to satisfy
         check_kernel_shape(num_kv_heads, head_dim)
         if latent % LANES:
@@ -319,18 +354,19 @@ def _paged_decode(
         **({"window": int(window)} if window else {}),
         **({"block_pages": int(block_pages)} if block_pages > 1 else {}),
         **({"latent": int(latent), "scale": float(scale)} if latent else {}),
+        **({"queries": queries} if queries > 1 else {}),
     )
     tile = block_pages * page_size
     row_spec = lambda shape: pl.BlockSpec(  # noqa: E731
         shape, lambda b, *_: (b,) + (0,) * (len(shape) - 1), memory_space=pltpu.VMEM
     )
-    out_shape = (batch, num_heads, latent or head_dim)
+    out_shape = (batch, queries * num_heads, latent or head_dim)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(batch,),
         in_specs=[
-            row_spec((1, num_heads, head_dim)),
-            *(row_spec((1, 1, fused)) for _ in new_kv),
+            row_spec((1, queries * num_heads, head_dim)),
+            *(row_spec((1, queries, fused)) for _ in new_kv),
             *(pl.BlockSpec(memory_space=pl.ANY) for _ in pools),
         ],
         out_specs=row_spec((1,) + out_shape[1:]),
@@ -342,7 +378,7 @@ def _paged_decode(
         ],
     )
     name = "paged_decode_inline" if new_kv else "paged_decode"
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
         grid_spec=grid_spec,
@@ -353,9 +389,13 @@ def _paged_decode(
         kv_lens.astype(jnp.int32),
         jnp.asarray(layer, jnp.int32).reshape(1),
         q,
-        *(x.reshape(batch, 1, fused) for x in new_kv),
+        *(x.reshape(batch, queries, fused) for x in new_kv),
         *pools,
     )
+    if queries == 1:
+        return out
+    out = out.reshape(batch, num_kv_heads, queries, -1, out.shape[-1])
+    return out.swapaxes(1, 2).reshape(batch, queries, num_heads, -1)
 
 
 @functools.partial(
@@ -389,10 +429,10 @@ def latent_decode_attention_inline_pallas(
     jax.jit, static_argnames=("interpret", "window", "block_pages")
 )
 def paged_decode_attention_inline_pallas(
-    q: jnp.ndarray,  # [batch, heads, head_dim]
+    q: jnp.ndarray,  # [batch, heads, head_dim], or [batch, n, heads, head_dim]
     k_pages: jnp.ndarray,  # [layers, num_pages, page_size, kv_heads*head_dim]
     v_pages: jnp.ndarray,
-    k_new: jnp.ndarray,  # [batch, kv_heads, head_dim]
+    k_new: jnp.ndarray,  # [batch, (n,) kv_heads, head_dim]
     v_new: jnp.ndarray,
     page_table: jnp.ndarray,  # [batch, pages_per_seq] int32
     positions: jnp.ndarray,  # [batch] int32 — cache holds entries < position

@@ -66,6 +66,27 @@ def test_kernel_compiles_for_v5e(topo, kind, shape):
     compile_kernel(KERNELS[kind], *_kernel_args(kind, *shape, one))
 
 
+@pytest.mark.parametrize(
+    "shape,window",
+    [((64, 8, 128), 0), ((64, 8, 128), 128), ((32, 8, 128), 128), ((8, 4, 256), 0)],
+    ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else f"window{v}")
+def test_decode_kernel_with_two_query_positions_compiles_for_v5e(topo, shape, window):
+    """The inline decode kernel of a verify step (speculative decoding with
+    the model's own prediction module): q [batch, 2, heads, head_dim], two new
+    K and V rows a slot, 2 * group query rows a KV head (16 at K-EXAONE's 64 x
+    8 x 128), one row iota that says which query a row belongs to, the second
+    new row masked for the first query's rows; on full layers and on rings."""
+    one = SingleDeviceSharding(topo.devices[0])
+    q, pages, _, new, _, table, lens, layer = _kernel_args(
+        "decode_inline", *shape, one)
+    two = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
+        (a.shape[0], 2, *a.shape[1:]), a.dtype, sharding=one)
+    text = compile_kernel(
+        KERNELS["decode_inline"], two(q), pages, pages, two(new), two(new),
+        table, lens, layer, **({"window": window} if window else {}))
+    assert "paged_decode_inline" in text
+
+
 @pytest.mark.parametrize("shape", [(32, 8, 128), (32, 8, 64)],
                          ids=lambda s: "x".join(map(str, s)))
 @pytest.mark.parametrize("kind", ["decode_inline", "ragged", "prefill"])

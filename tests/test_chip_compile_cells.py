@@ -136,6 +136,7 @@ def _compile_cell_program(topo, name, program, bucket=None):
         max_batch=args.max_batch, page_size=args.page_size,
         num_pages=args.num_pages, decode_chunk=args.decode_chunk,
         max_prefill_tokens=args.max_prefill_tokens,
+        speculative_mtp=args.speculative_mtp,
         # the prefix cache refuses a model with per-slot state
         prefix_caching=llama.patterned(model) is None,
     )
@@ -430,6 +431,82 @@ def test_ssmchat_cell_programs_fit_the_chip(topo, program, bucket):
         assert len(set(re.findall(
             r"%(fusion[\w.]*) = bf16\[128,1024\]\S* fusion\(%get-tuple-element", text
         ))) >= 5
+
+
+@pytest.mark.parametrize(
+    "program,bucket",
+    [("chunk", 8), _every_chip_run_compiles_it("prefill", 1024)],
+)
+def test_mtpmix_cell_programs_fit_the_chip(topo, program, bucket):
+    """The programs of the cell ``k-exaone-236b.mtpmix`` at its real sizes and
+    engine options (``--speculative-mtp 1``), compiled for the described
+    chip: three layers of pages (two full layers and the prediction module's
+    block) beside six rings of 128 + 1,024 positions, the decode kernel in at
+    TWO query positions a slot (16 query rows a KV head), no stack of weights
+    copied into another layout (``wq`` and ``wk`` are stored as the decode
+    step's few rows read them), no held expert stack sliced out (every period
+    is traced by itself), arguments 12.57 GB and arguments + temps under
+    13.65 GB."""
+    compiled, cfg, cell, model = _compile_cell_program(
+        topo, "k-exaone-236b.mtpmix", program, bucket
+    )
+    d, lay, keys = cell.dims, cfg.kv_layout, cell.family.keys
+    assert cfg.speculative_mtp == 1 and cfg.model.serve_mtp
+    assert (lay.global_layers, lay.window_layers, lay.window) == (3, 6, 128)
+    assert lay.ring_pages * cfg.page_size == 128 + 1024
+    assert lay.table_width == 4096 // 16 + 72
+    pages = keys.kv_bytes(d, cfg.num_pages, cfg.page_size)
+    rings = keys.ring_bytes(d, cfg.max_batch, 1024)
+    assert (pages, rings) == (2_419_064_832, 1_358_954_496)
+    state = 2 * keys.param_count(d) + pages + rings
+    assert 12.56e9 < state < 12.58e9
+    ma = compiled.memory_analysis()
+    assert state <= ma.argument_size_in_bytes < state + 0.04e9
+    assert ma.temp_size_in_bytes < 1.1e9
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 13.65e9
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # no stack of weights in another layout: what the chip's compiler did to
+    # ``wq`` and ``wk`` stored [in, out], 0.97 GB once a chunk
+    assert not re.search(r"copy\(%?params__", text)
+    # the held experts: grouped matmuls (the Pallas one at these widths) in
+    # the prompt programs, every held expert on every row in the chunk
+    assert ("gmm" in text) == (program != "chunk")
+    layer_pool = cfg.num_pages * cfg.page_size * model.kv_dim
+    layer_ring = cfg.max_batch * 1152 * model.kv_dim
+    layer_experts = 8 * 6144 * 2048
+    assert layer_ring < layer_experts < layer_pool
+    lines = {line.strip()[:200]: line for line in text.splitlines()}
+    sized = pool_sized_ops(text, layer_experts)
+    # nothing the size of a layer's experts (or of a layer of the pages) is
+    # written but the pools' own writes, in place; the chunk also stages a
+    # layer's ``wq`` / ``wo`` (96 MB, the compiler's own prefetch: same shape
+    # as one expert stack's layer, no copy of it)
+    assert [
+        row for row in sized
+        if "aliasing" not in lines[row[1]] and "bf16[1,8192,6144]" not in row[1]
+        and "bf16[8192,6144]" not in row[1]
+    ] == []
+    if program == "chunk":
+        kernels_found = _kernel_vmem_args(text, "paged_decode_inline")
+        tile = (2, 128, model.kv_dim)
+        # one a layer of the two periods, and the module's
+        assert len(kernels_found) == 9 and all(
+            k[-2:] == [tile, tile] for k in kernels_found)
+        # two query positions: 2 x 64 query rows a slot, two new rows
+        assert all(k[0] == (1, 128, 128) and k[1] == (1, 2, 1024)
+                   for k in kernels_found)
+        # the routed experts' THREE ops a layer (gate and up over every held
+        # expert on the chunk's 96 rows, the down matmul with the weighted
+        # sum), which the roofline metric's reader picks by these results and
+        # by the whole stack as their first operand: eight expert layers a
+        # step, the module's among them
+        shaped = [
+            line for line in text.splitlines()
+            if re.search(r"^\s*%fusion[\w.]* = bf16\[(8,96,2048|96,6144)\]", line)
+            and "kind=kOutput" in line
+        ]
+        assert len(shaped) >= 3 * 8
 
 
 def _kernel_vmem_args(text, name):
