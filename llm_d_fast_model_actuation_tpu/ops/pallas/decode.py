@@ -5,9 +5,29 @@ One decode step against the paged KV cache. The XLA reference in
 materializes GQA-repeated K/V — O(batch * ctx_max) HBM traffic regardless of
 actual sequence lengths. This kernel reads only the pages each sequence
 actually occupies (`ceil(seq_len / page_size)` of them), double-buffering the
-HBM->VMEM page DMA behind the per-page flash-attention accumulation, and
+HBM->VMEM page DMA behind the per-tile flash-attention accumulation, and
 never materializes repeated KV heads. Decode is HBM-bandwidth-bound, so
 bytes-not-read is time-not-spent.
+
+The walk's pipeline. The grid is ``(batch,)``, one sequence a grid step, run
+one after another; a step of a sequence's walk is one tile (a page, or
+``block_pages`` pages). While tile p is in the softmax update, tile p + 1 is
+in flight into the other half of a two-half VMEM buffer — and the pipeline
+does not drain where a sequence ends: a live sequence's LAST tile step starts
+the FIRST tile of the next live sequence (a scalar look-ahead over the
+lengths, past empty slots and slots whose query sees no cached position), so
+only the first live sequence of a call waits for a copy with nothing to
+compute meanwhile. What persists across grid steps is the scratch: the two
+buffer halves, the DMA semaphores, and one SMEM count of the tiles the call
+has walked so far, from which a grid step knows which half its first tile is
+in and whether a sequence before it has already started that copy (the
+count is not 0). The last live sequence starts nothing, so every copy
+started is waited for inside the call (PERF.md section 6, PR 48).
+
+The dots take float32 operands, the tile's K and V slices cast from the
+pool's dtype. On the v5e that is already one bfloat16 pass of the MXU with
+float32 accumulation: handing the dots a bfloat16 pool's slices as stored
+served the same tokens and was 0-4% slower (PERF.md section 6, PR 48).
 
 Layout contract (the engine's KV pool as it is stored,
 engine/kv_cache.py:PagePool.pool_shape):
@@ -114,6 +134,17 @@ def _decode_kernel(
     ops/attention.py:paged_decode_attention_inline) and are folded into the
     running (m, l, acc) state after the page walk.
 
+    The walk is ONE software pipeline over the call's live sequences (the
+    module docstring): step p of this sequence's walk lives in buffer half
+    ``(walked + p) % 2``, ``walked`` the SMEM count of tiles the grid steps
+    before this one walked; every step starts the copies of the walk's next
+    tile into the other half — this sequence's step p + 1, or from its last
+    step the next live sequence's step 0 — and then waits for its own. Only
+    a sequence with none live before it (``walked`` 0) starts its own step
+    0. A wait rebuilds the descriptor its start built, from the same table
+    row. Every mode below changes what a tile is or where a walk starts,
+    not this.
+
     With ``window`` (a layer of sliding-window attention) the query sees
     only the last ``window`` positions, its own among them: the walk STARTS
     at the first page that holds a visible key, and a row of the page table
@@ -149,7 +180,7 @@ def _decode_kernel(
         # latent]; k_buf: [2, block_pages * page_size, head_dim]
         if inline:
             knew_ref, *refs = refs
-        k_hbm, o_ref, k_buf, sems = refs
+        k_hbm, o_ref, k_buf, sems, walked = refs
         pools = ((k_buf, k_hbm),)
     else:
         if inline:
@@ -158,11 +189,13 @@ def _decode_kernel(
         # o_ref: [1, heads, head_dim] VMEM
         # k_buf, v_buf: [2, block_pages * page_size, kv_heads * head_dim] VMEM
         # sems: DMA [2, 2] (one page a step) or [2, 2, block_pages]
-        k_hbm, v_hbm, o_ref, k_buf, v_buf, sems = refs
+        # walked: SMEM [1], tiles the call has walked before this grid step
+        k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, walked = refs
         pools = ((k_buf, k_hbm), (v_buf, v_hbm))
     value_dim = latent or head_dim
     blocked = block_pages > 1
     tile = block_pages * page_size
+    batch = len_ref.shape[0]
     b = pl.program_id(0)
     group = num_heads // num_kv_heads
     rows = queries * group  # query rows a KV head
@@ -172,50 +205,83 @@ def _decode_kernel(
         query_of = sum(
             (row >= j * group).astype(jnp.int32) for j in range(1, queries)
         )
-    kv_len = len_ref[b]
     layer = layer_ref[0]
-    num_pages = jax.lax.div(kv_len + page_size - 1, page_size)
-    if window:
-        # the query's own position is kv_len (inline) or kv_len - 1
-        lo = jnp.maximum(kv_len + (1 if inline else 0) - window, 0)
-        first_page = jax.lax.div(lo, page_size)
-        width = page_table_ref.shape[1]
-        num_pages = num_pages - first_page  # pages walked
-    # steps of the walk
-    num_steps = (
-        jax.lax.div(num_pages + block_pages - 1, block_pages)
-        if blocked else num_pages
-    )
+    width = page_table_ref.shape[1]
 
-    def page_dma(buf, hbm, slot, p, sem_row, j=0):
+    def walk_of(slot):
+        """(first visible position, first page, pages, steps) of a slot's
+        walk; no step where the slot is empty or holds no cached position
+        its query sees."""
+        slot_len = len_ref[slot]
+        pages = jax.lax.div(slot_len + page_size - 1, page_size)
+        lo = first = 0
+        if window:
+            # the query's own position is slot_len (inline) or slot_len - 1
+            lo = jnp.maximum(slot_len + (1 if inline else 0) - window, 0)
+            first = jax.lax.div(lo, page_size)
+            pages = pages - first  # pages walked
+        steps = (
+            jax.lax.div(pages + block_pages - 1, block_pages)
+            if blocked else pages
+        )
+        return lo, first, pages, steps
+
+    kv_len = len_ref[b]
+    lo, first_page, num_pages, num_steps = walk_of(b)
+    mine = (b, first_page, num_pages)
+
+    def page_dma(buf, hbm, half, seq, p, sem_row, j=0):
+        slot, first, pages = seq
         if blocked:
             # the last step's spare pages read its last page again: their
             # positions lie past kv_len and are masked
-            p = jnp.minimum(p, num_pages - 1)
+            p = jnp.minimum(p, pages - 1)
         if window:
-            p = jax.lax.rem(first_page + p, width)
+            p = jax.lax.rem(first + p, width)
         return pltpu.make_async_copy(
-            hbm.at[layer, page_table_ref[b, p]],
-            buf.at[slot, pl.ds(j * page_size, page_size)] if blocked
-            else buf.at[slot],
-            sems.at[sem_row, slot, j] if blocked else sems.at[sem_row, slot],
+            hbm.at[layer, page_table_ref[slot, p]],
+            buf.at[half, pl.ds(j * page_size, page_size)] if blocked
+            else buf.at[half],
+            sems.at[sem_row, half, j] if blocked else sems.at[sem_row, half],
         )
 
-    def step_dmas(slot, step):
-        """The K and V copies of one step of the walk."""
+    def step_dmas(half, seq, step):
+        """The K and V copies of one step of sequence ``seq``'s walk."""
         if not blocked:
-            return [page_dma(buf, hbm, slot, step, row)
+            return [page_dma(buf, hbm, half, seq, step, row)
                     for row, (buf, hbm) in enumerate(pools)]
         return [
-            page_dma(buf, hbm, slot, step * block_pages + j, row, j)
+            page_dma(buf, hbm, half, seq, step * block_pages + j, row, j)
             for j in range(block_pages)
             for row, (buf, hbm) in enumerate(pools)
         ]
 
-    @pl.when(num_steps > 0)
+    @pl.when(b == 0)
     def _():
-        for dma in step_dmas(0, 0):
+        walked[0] = 0
+
+    # Tiles walked by the call's sequences before this one. Step p of this
+    # walk lives in buffer half (done + p) % 2, and any live sequence before
+    # this one has already started this one's step 0 there.
+    done = walked[0]
+
+    @pl.when((num_steps > 0) & (done == 0))
+    def _():
+        for dma in step_dmas(0, mine, 0):
             dma.start()
+
+    # The next live sequence, whose step 0 this walk's last step starts
+    # (scalar work, under the wait for this walk's own first tile; a grid
+    # step that walks nothing looks for none).
+    upcoming = jax.lax.while_loop(
+        lambda s: (num_steps > 0) & (s < batch)
+        & (walk_of(jnp.minimum(s, batch - 1))[3] <= 0),
+        lambda s: s + 1,
+        b + 1,
+    )
+    has_next = upcoming < batch
+    upcoming = jnp.minimum(upcoming, batch - 1)
+    ahead = (upcoming, *walk_of(upcoming)[1:3])
 
     # [heads, head_dim]
     q = q_ref[0].astype(jnp.float32) * (scale or head_dim**-0.5)
@@ -225,15 +291,18 @@ def _decode_kernel(
     # replacement is.
     def body(p, carry):
         ms, ls, accs = carry  # tuples of [group,1], [group,1], [group,d]
-        slot = jax.lax.rem(p, 2)
+        half = jax.lax.rem(done + p, 2)
+        last = p + 1 == num_steps
 
-        @pl.when(p + 1 < num_steps)
+        # the walk's next tile: this sequence's, or step 0 of the next live
+        # sequence's, in flight under this tile's softmax update
+        @pl.when(jnp.logical_not(last) | has_next)
         def _():
-            nxt = jax.lax.rem(p + 1, 2)
-            for dma in step_dmas(nxt, p + 1):
+            seq = tuple(jnp.where(last, a, m) for a, m in zip(ahead, mine))
+            for dma in step_dmas(1 - half, seq, jnp.where(last, 0, p + 1)):
                 dma.start()
 
-        for dma in step_dmas(slot, p):
+        for dma in step_dmas(half, mine, p):
             dma.wait()
 
         # tokens beyond kv_len in the (last) page are masked out
@@ -252,11 +321,11 @@ def _decode_kernel(
         for g in range(num_kv_heads):
             lanes = pl.ds(g * head_dim, head_dim)
             qg = q[g * rows : (g + 1) * rows]  # [rows, head_dim]
-            kg = k_buf[slot, :, lanes].astype(jnp.float32)  # [page, head_dim]
+            kg = k_buf[half, :, lanes].astype(jnp.float32)  # [tile, head_dim]
             if latent:
-                vg = k_buf[slot, :, pl.ds(0, latent)].astype(jnp.float32)
+                vg = k_buf[half, :, pl.ds(0, latent)].astype(jnp.float32)
             else:
-                vg = v_buf[slot, :, lanes].astype(jnp.float32)
+                vg = v_buf[half, :, lanes].astype(jnp.float32)
             logits = jax.lax.dot_general(
                 qg,
                 kg,
@@ -286,6 +355,7 @@ def _decode_kernel(
         jnp.zeros((rows, value_dim), jnp.float32) for _ in range(num_kv_heads)
     )
     ms, ls, accs = jax.lax.fori_loop(0, num_steps, body, (m0, l0, acc0))
+    walked[0] = done + jnp.maximum(num_steps, 0)
 
     if inline:
         # Fold the inline token (always valid; guarantees l > 0 at pos == 0).
@@ -375,6 +445,7 @@ def _paged_decode(
             pltpu.SemaphoreType.DMA(
                 (2, 2, block_pages) if block_pages > 1 else (2, 2)
             ),
+            pltpu.SMEM((1,), jnp.int32),
         ],
     )
     name = "paged_decode_inline" if new_kv else "paged_decode"
@@ -382,6 +453,10 @@ def _paged_decode(
         kernel,
         out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
         grid_spec=grid_spec,
+        # the walk's pipeline runs ACROSS grid steps: one after another
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
         interpret=interpret,
         name="latent_decode_inline" if latent else name,
     )(

@@ -232,7 +232,7 @@ def test_loopchat_cell_programs_fit_the_chip(topo, program, bucket):
     ] == []
     if program == "chunk":
         tile = (2, 128, model.kv_dim)
-        kernels_found = _kernel_vmem_args(text, "paged_decode_inline")
+        kernels_found = _kernel_args(text, "paged_decode_inline")
         assert kernels_found and all(k[-2:] == [tile, tile] for k in kernels_found)
 
 
@@ -353,7 +353,7 @@ def test_decodemix_cell_programs_fit_the_chip(topo, program, bucket):
         "f32[6,64,32,128,128]" in row[1] or "bf16[524800,640]" in row[1]
         or "f32[16,32,4,16,16,128]" in row[1] for row in sized)
     if program == "chunk":
-        kernels_found = _kernel_vmem_args(text, "latent_decode_inline")
+        kernels_found = _kernel_args(text, "latent_decode_inline")
         # q [1, 32, 640], the new row [1, 1, 640], o [1, 32, 512], and ONE
         # double-buffered tile of 128 tokens of 640 lanes: no V tile
         assert kernels_found and all(
@@ -418,7 +418,7 @@ def test_ssmchat_cell_programs_fit_the_chip(topo, program, bucket):
         "f32[5,128,128,64,128]" in row[1] or "bf16[1,24592,16,256]" in row[1]
         for row in sized)
     if program == "chunk":
-        kernels_found = _kernel_vmem_args(text, "paged_decode_inline")
+        kernels_found = _kernel_args(text, "paged_decode_inline")
         tile = (2, 128, model.kv_dim)
         assert len(kernels_found) >= 1 and all(
             k[-2:] == [tile, tile] for k in kernels_found)
@@ -471,7 +471,9 @@ def test_mtpmix_cell_programs_fit_the_chip(topo, program, bucket):
     assert not re.search(r"copy\(%?params__", text)
     # the held experts: grouped matmuls (the Pallas one at these widths) in
     # the prompt programs, every held expert on every row in the chunk
-    assert ("gmm" in text) == (program != "chunk")
+    # (by the op's name: a kernel's serialized body is base64 and may spell
+    # anything)
+    assert bool(re.search(r"%gmm[\w.\-]* = ", text)) == (program != "chunk")
     layer_pool = cfg.num_pages * cfg.page_size * model.kv_dim
     layer_ring = cfg.max_batch * 1152 * model.kv_dim
     layer_experts = 8 * 6144 * 2048
@@ -488,7 +490,7 @@ def test_mtpmix_cell_programs_fit_the_chip(topo, program, bucket):
         and "bf16[8192,6144]" not in row[1]
     ] == []
     if program == "chunk":
-        kernels_found = _kernel_vmem_args(text, "paged_decode_inline")
+        kernels_found = _kernel_args(text, "paged_decode_inline")
         tile = (2, 128, model.kv_dim)
         # one a layer of the two periods, and the module's
         assert len(kernels_found) == 9 and all(
@@ -509,10 +511,12 @@ def test_mtpmix_cell_programs_fit_the_chip(topo, program, bucket):
         assert len(shaped) >= 3 * 8
 
 
-def _kernel_vmem_args(text, name):
+def _kernel_args(text, name, space="vmem"):
     """For every Mosaic kernel called ``name`` in a compiled program's HLO
     text, the shapes of its VMEM operands in order (blocks in, blocks out,
-    then scratch), read from the kernel's own serialized module."""
+    then scratch), or with ``space`` "smem" of its scalar operands
+    (prefetched, then scratch), read from the kernel's own serialized
+    module."""
     import base64
 
     from jax._src.interpreters import mlir
@@ -535,7 +539,7 @@ def _kernel_vmem_args(text, name):
         found.append([
             tuple(int(n) for n in shape.split("x"))
             for shape in re.findall(
-                r"memref<([0-9x]+)x[a-z0-9]+, #tpu.memory_space<vmem>>", args
+                rf"memref<([0-9x]+)x[a-z0-9]+, #tpu.memory_space<{space}>>", args
             )
         ])
     return found
@@ -556,7 +560,7 @@ def test_accepted_cells_decode_walks_a_128_token_tile(topo, name):
     assert cfg.page_size == 16
     text = compiled.as_text()
     tile = (2, 128, model.kv_dim)
-    kernels_found = _kernel_vmem_args(text, "paged_decode_inline")
+    kernels_found = _kernel_args(text, "paged_decode_inline")
     assert kernels_found and all(k[-2:] == [tile, tile] for k in kernels_found)
     layer_pool = cfg.num_pages * cfg.page_size * model.kv_dim
     # ... but for the chat chunk's copy of ``wq`` into another layout, once
@@ -565,6 +569,39 @@ def test_accepted_cells_decode_walks_a_128_token_tile(topo, name):
         row for row in pool_sized_ops(text, layer_pool)
         if "copy(%params__layers____wq__" not in row[1]
     ] == []
+
+
+#: cell -> (the decode kernel's name, its call sites in the ``chunk`` program:
+#: one of a scan's body, one a layer of a traced period, and the module's)
+_DECODE_KERNELS = {
+    "mistral-7b.chat": ("paged_decode_inline", 1),
+    "mixtral-8x7b.batch": ("paged_decode_inline", 1),
+    "smallthinker-21b.longmix": ("paged_decode_inline", 4),
+    "ouro-2.6b.loopchat": ("paged_decode_inline", 1),
+    "olmo-hybrid-7b.hybridmix": ("paged_decode_inline", 1),
+    "kimi-linear-48b.decodemix": ("latent_decode_inline", 2),
+    "nemotron-3-super-120b.ssmchat": ("paged_decode_inline", 1),
+    "k-exaone-236b.mtpmix": ("paged_decode_inline", 9),
+}
+
+
+@pytest.mark.parametrize("name", list(_DECODE_KERNELS))
+def test_every_cell_decode_kernel_carries_the_walk_across_sequences(topo, name):
+    """The ``chunk`` program of every cell at its real sizes and engine
+    options, compiled for the described chip (the compiles the tests above
+    made): the decode kernel lowers at every cell's shape — plain rows,
+    rings, two query positions a slot, latent pages — with the ONE scalar
+    the walk carries from a sequence to the next (the tiles walked so far,
+    SMEM after the prefetched table, lengths and layer), and the program
+    holds the kernels it held: the walk across sequences is the kernel's
+    own, no caller's and no new program's (PERF.md section 6, PR 48)."""
+    compiled, cfg, _, _ = _compile_cell_program(topo, name, "chunk")
+    kernel, sites = _DECODE_KERNELS[name]
+    scalars = _kernel_args(compiled.as_text(), kernel, space="smem")
+    assert len(scalars) == sites
+    for table, lens, layer, walked in scalars:
+        assert table[0] == cfg.max_batch and lens == (cfg.max_batch,)
+        assert layer == walked == (1,)
 
 
 @pytest.mark.parametrize(

@@ -5,6 +5,8 @@ the cpu platform) and checks numerical agreement with `ops/attention.py`
 across GQA ratios, ragged sequence lengths, and partial last pages.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -345,7 +347,7 @@ def test_serving_path_walks_a_128_token_tile(page_size, block_pages):
         lambda *a: attn.paged_decode_attention_inline(*a, impl="pallas"),
         s((batch, heads, d), jnp.float32), pool, pool, new, new,
         s((batch, 4), jnp.int32), s((batch,), jnp.int32), s((), jnp.int32),
-    ) == [[(2, tile, kvh * d), (2, tile, kvh * d), sems]]
+    ) == [[(2, tile, kvh * d), (2, tile, kvh * d), sems, (1,)]]
 
 
 def _masked_decode(q, k, v, positions):
@@ -437,8 +439,158 @@ def test_decode_step_logits_pallas_matches_grouped(preset):
         return lambda *a: llama.decode_step(params, cfg, *a)
 
     tile = (2, 128, base.kv_dim)
-    assert _inline_scratch(step("pallas"), *args) == [[tile, tile, (2, 2, 8)]]
+    assert _inline_scratch(step("pallas"), *args) == [[tile, tile, (2, 2, 8), (1,)]]
     np.testing.assert_allclose(
         step("pallas")(*args)[0], step("grouped")(*args)[0],
         atol=1e-4, rtol=1e-4,
     )
+
+
+# -- the walk across sequences -----------------------------------------------------
+#
+# A live sequence's last tile step starts the first tile of the NEXT live
+# sequence into the other buffer half (ops/pallas/decode.py:_decode_kernel):
+# the batches below put every kind of boundary between two walks.
+
+#: tiles a slot's context fills, slot by slot (0: an empty slot, or one at
+#: position 0: nothing cached)
+WALK_ORDERS = {
+    "empty_first": (0, 0, 2, 1),
+    "empty_last": (2, 1, 0, 0),
+    "empty_between_two_live": (1, 0, 0, 2),
+    "all_empty": (0, 0, 0, 0),
+    "one_live_alone": (0, 3, 0, 0),
+    "one_tile_after_nine": (9, 1, 0, 2),
+    "nine_after_one_tile": (1, 9, 1, 0),
+    "odd_then_even": (3, 2, 1, 4),
+    "even_then_odd": (2, 3, 4, 1),
+    "one_tile_each": (1, 1, 1, 1),
+}
+#: mode -> (window, queries, latent, block_pages, inline)
+WALK_MODES = {
+    "plain": (0, 1, 0, 8, True),  # the serving tile: 8 pages of 16
+    "window": (75, 1, 0, 2, True),
+    "queries2": (0, 2, 0, 2, True),
+    "window_queries2": (75, 2, 0, 2, True),
+    "latent": (0, 1, 32, 2, True),
+    "block_pages1": (0, 1, 0, 1, True),
+    "not_inline": (0, 1, 0, 1, False),
+}
+_WALK_PAGE = 16
+
+
+@functools.partial(jax.jit, static_argnames=("window", "scale"))
+def _masked_walk(q, k, v, positions, window, scale):
+    """Float32 softmax, written without pages: query i of a slot, q [b, n,
+    heads, d], sits at ``positions + i`` and sees the keys at and before it
+    (the last ``window`` of them on a window layer) of k [b, ctx, kvh, d],
+    v [b, ctx, kvh, dv]. A query that sees nothing gives zeros."""
+    n, heads = q.shape[1:3]
+    kk = jnp.repeat(k, heads // k.shape[2], axis=2)
+    vv = jnp.repeat(v, heads // v.shape[2], axis=2)
+    scores = jnp.einsum("bqhd,bthd->bqht", q * scale, kk)
+    t = jnp.arange(k.shape[1])[None, None, :]
+    qpos = (positions[:, None] + jnp.arange(n)[None, :])[:, :, None]
+    mask = (t <= qpos) & ((t > qpos - window) if window else True)
+    scores = jnp.where(mask[:, :, None, :], scores, -jnp.inf)
+    out = jnp.einsum("bqht,bthd->bqhd", jax.nn.softmax(scores, axis=-1), vv)
+    return jnp.where(mask.any(axis=-1)[..., None, None], out, 0.0)
+
+
+def _walk_case(mode, tiles, dtype):
+    """(kernel's output, masked float32 form, the XLA twin to call) for a
+    batch whose slot i holds a context of ``tiles[i]`` tiles, the last one
+    partly filled; every order of a mode has the same shapes, so one
+    compile."""
+    window, n, latent, block_pages, inline = WALK_MODES[mode]
+    page, batch = _WALK_PAGE, len(tiles)
+    tile = block_pages * page
+    heads, kvh, d = (4, 1, 48) if latent else (4, 2, 32)
+    # cached positions: whole tiles, a few short of them, one into the last
+    short = (0, 5, tile - 1, 37 % tile)
+    cached = np.array(
+        [t * tile - short[i] if t else 0 for i, t in enumerate(tiles)], np.int32
+    )
+    # the same arrays under every mode: the longest walk at the serving tile
+    longest = max(map(max, WALK_ORDERS.values())) * 128 + 2
+    # a ring that wraps under the longer contexts; else the whole row
+    width = (window + n) // page + 5 if window else longest // page + 1
+    ks = jax.random.split(jax.random.key(41), 3)
+    q = _rand(ks[0], (batch, n, heads, d))
+    k = _rand(ks[1], (batch, longest, kvh, d))
+    v = k[..., :latent] if latent else _rand(ks[2], (batch, longest, kvh, d))
+    order = np.random.default_rng(9).permutation(batch * width) + 1
+    table = order.reshape(batch, width).astype(np.int32)
+
+    def pool(x):
+        # position t of a slot at ring column (t // page) % width; what was
+        # never written is large and finite: weighed in, it would show
+        stored = np.full((2, batch * width + 1, page, kvh * d), 1e3, np.float32)
+        x = np.asarray(x).reshape(batch, longest, kvh * d)
+        for s in range(batch):
+            t = np.arange(max(0, cached[s] - width * page), cached[s])
+            stored[1, table[s, (t // page) % width], t % page] = x[s, t]
+        return jnp.asarray(stored, dtype)
+
+    positions = jnp.asarray(cached)
+    at = (jnp.arange(batch)[:, None], positions[:, None] + jnp.arange(n)[None, :])
+    q_in = (q if n > 1 else q[:, 0]).astype(dtype)
+    new = lambda x: (x[at] if n > 1 else x[at][:, 0]).astype(dtype)  # noqa: E731
+    table, layer = jnp.asarray(table), jnp.int32(1)
+    scale = 0.11 if latent else d**-0.5
+    if latent:
+        args = (q_in, pool(k), new(k)[:, 0], table, positions, layer)
+        kw = dict(latent=latent, scale=scale)
+        got = attn.latent_decode_attention_inline(*args, **kw, impl="pallas")
+        twin = functools.partial(
+            attn.latent_decode_attention_inline, **kw, impl="grouped")
+    elif inline:
+        from llm_d_fast_model_actuation_tpu.ops.pallas import (
+            paged_decode_attention_inline_pallas,
+        )
+
+        args = (q_in, pool(k), pool(v), new(k), new(v), table, positions, layer)
+        got = paged_decode_attention_inline_pallas(
+            *args, interpret=True, window=window, block_pages=block_pages
+        )
+        twin = functools.partial(
+            attn.paged_decode_attention_inline, impl="grouped", window=window)
+    else:
+        # the cache holds the query's own position: lengths, not positions
+        args = (q_in, pool(k), pool(v), table, positions, layer)
+        got = paged_decode_attention_pallas(*args, interpret=True)
+        twin = functools.partial(attn.paged_decode_attention, impl="reference")
+    want = _masked_walk(
+        q, k, v, positions - (0 if inline else 1), window=window, scale=scale
+    )
+    return got, (want if n > 1 else want[:, 0]), lambda: jax.jit(twin)(*args)
+
+
+@pytest.mark.parametrize("order", list(WALK_ORDERS))
+@pytest.mark.parametrize("mode", list(WALK_MODES))
+def test_walk_runs_across_sequences(mode, order):
+    """The first tile of the next live sequence is in flight under the last
+    tile of the one before: empty slots first, last and between two live
+    ones, none live, one alone, a short walk after a long one and the
+    reverse, an odd and an even number of tiles before a boundary (both
+    buffer halves are entered) — on plain rows, on rings that wrap, with two
+    query positions a slot, on latent pages, a page a step, and in the kernel
+    that reads the query's own position from the cache."""
+    got, want, _ = _walk_case(mode, WALK_ORDERS[order], jnp.float32)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("mode", list(WALK_MODES))
+def test_walk_over_a_bf16_pool_matches_the_xla_form(mode):
+    """A bfloat16 pool under every mode: the kernel's float32 softmax state
+    over tiles cast from the pool against the XLA form of the same attention
+    (bfloat16 operands, float32 accumulation) and against the masked float32
+    form, within bfloat16's rounding of the output
+    (``test_bf16_io_fp32_math``'s tolerance)."""
+    got, want, twin = _walk_case(mode, WALK_ORDERS["odd_then_even"], jnp.bfloat16)
+    assert got.dtype == jnp.bfloat16
+    for other in (twin(), want):
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(other, np.float32),
+            atol=3e-2, rtol=3e-2,
+        )
